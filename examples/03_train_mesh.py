@@ -17,8 +17,9 @@ init_fn, step_fn = make_train_step(
     cfg, mesh, optimizer=default_optimizer(3e-4, warmup=5,
                                            total_steps=100))
 state = init_fn(jax.random.key(0))
-tokens = jax.random.randint(jax.random.key(1), (4, 129), 0,
-                            cfg.vocab_size, dtype=jnp.int32)
+# The batch divides over the data axes, so every device gets a shard.
+tokens = jax.random.randint(jax.random.key(1), (2 * mesh.devices.size, 129),
+                            0, cfg.vocab_size, dtype=jnp.int32)
 for step in range(5):
     state, metrics = step_fn(state, {"tokens": tokens})
     print(f"step {step}: loss={float(metrics['loss']):.3f}")

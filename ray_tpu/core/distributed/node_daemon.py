@@ -1677,11 +1677,13 @@ class NodeDaemon:
                   flush=True)
 
     def _release_demand(self, demand, placement) -> None:
-        if placement is not None:
-            bundle = self._pg_bundles.get(tuple(placement))
-            if bundle is not None:
-                rs.add(bundle["available"], demand)
+        bundle = (self._pg_bundles.get(tuple(placement))
+                  if placement is not None else None)
+        if bundle is not None:
+            rs.add(bundle["available"], demand)
         else:
+            # No bundle, or one that was returned while this holder was
+            # still alive (return_pg_bundle kept its share back).
             rs.add(self.available, demand)
             self._ledger("add:release", demand)
 
@@ -1949,7 +1951,7 @@ class NodeDaemon:
             if bundle.get("committed") or exp is None or now < exp:
                 continue
             self._pg_bundles.pop(key, None)
-            rs.add(self.available, bundle["resources"])
+            rs.add(self.available, bundle["available"])
             logger.warning("prepared pg bundle %s:%d expired after "
                            "%.1fs without commit; resources returned",
                            key[0][:8], key[1],
@@ -1959,7 +1961,12 @@ class NodeDaemon:
     def return_pg_bundle(self, pg_id: str, bundle_idx: int) -> dict:
         bundle = self._pg_bundles.pop((pg_id, bundle_idx), None)
         if bundle is not None:
-            rs.add(self.available, bundle["resources"])
+            # Only what no live worker holds.  The rest comes back with
+            # its holder (_release_demand): a chip is free when the
+            # process that owns it is gone, not when the reservation is —
+            # a killed TPU worker takes seconds to exit, and a process
+            # that starts on the chip meanwhile fails.
+            rs.add(self.available, bundle["available"])
             self._pump_lease_queue()
         return {"ok": True}
 

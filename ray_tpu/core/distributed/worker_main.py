@@ -1422,6 +1422,12 @@ def boot_worker(args) -> None:
     logging.basicConfig(
         level=logging.INFO, force=True,
         format=f"[worker {args.worker_id[:6]}] %(levelname)s %(message)s")
+    # Before any task can compile: every worker of every run agrees on
+    # one persistent compile cache (a replica that restarts, the next
+    # run on this host).
+    from ray_tpu.util import compile_cache
+
+    compile_cache.configure()
     # tpu_profiling runtime env (the nsight analogue): trace the whole
     # worker process with the JAX profiler, like `nsys profile` wraps
     # the reference's worker (_private/runtime_env/nsight.py).

@@ -54,8 +54,12 @@ def make_train_step(
     """Returns (init_fn(rng) -> TrainState, step_fn(state, batch) -> (state, metrics)),
     both jitted against `mesh` with logical-rule shardings.
 
-    Opt-state shardings are left to XLA propagation: Adam moments are
-    elementwise functions of params, so they inherit the param layout.
+    The whole state has a declared layout, going out of `init_fn` and
+    out of every step: parameters by the rule table, and each optimizer
+    leaf that mirrors a parameter (Adam's moments) laid out like it.
+    Left to propagation XLA replicates the moments — they are zeros, a
+    function of no sharded input — and the step then all-reduces full
+    gradients instead of reduce-scattering them.
     """
     optimizer = optimizer or default_optimizer()
     if seq_shards is None:
@@ -66,6 +70,19 @@ def make_train_step(
         params = init_params(rng, cfg)
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=optimizer.init(params))
+
+    abstract = jax.eval_shape(init, jax.random.key(0))
+    replicated = NamedSharding(mesh, P())
+    # Factored moments (adafactor) keep the parameter tree but not the
+    # parameter shapes; a layout only carries over to an equal shape.
+    opt_shard = optax.tree_utils.tree_map_params(
+        optimizer,
+        lambda leaf, like, sharding: (
+            sharding if leaf.shape == like.shape else replicated),
+        abstract.opt_state, abstract.params, p_shard,
+        transform_non_params=lambda _: replicated)
+    state_shard = TrainState(step=replicated, params=p_shard,
+                             opt_state=opt_shard)
 
     loss = functools.partial(loss_fn, cfg=cfg, rules=rules, mesh=mesh,
                              seq_shards=seq_shards)
@@ -79,14 +96,9 @@ def make_train_step(
         return TrainState(state.step + 1, params, opt_state), metrics
 
     with mesh:
-        # Constrain params explicitly; opt_state follows XLA propagation.
-        def init_constrained(rng):
-            st = init(rng)
-            p = jax.lax.with_sharding_constraint(st.params, p_shard)
-            return dataclasses.replace(st, params=p)
-
-        init_fn = jax.jit(init_constrained)
-        step_fn = jax.jit(step, donate_argnums=(0,))
+        init_fn = jax.jit(init, out_shardings=state_shard)
+        step_fn = jax.jit(step, donate_argnums=(0,),
+                          out_shardings=(state_shard, replicated))
     return init_fn, step_fn
 
 

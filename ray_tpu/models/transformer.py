@@ -31,7 +31,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.ring_attention import make_ring_attention
+from ray_tpu.ops.ring_attention import (
+    make_ring_attention, make_sharded_attention)
 from ray_tpu.ops.ulysses import make_ulysses_attention
 from ray_tpu.ops.rotary import apply_rope
 from ray_tpu.parallel.sharding import (
@@ -249,6 +250,14 @@ def forward(params, tokens, cfg: TransformerConfig, *,
                                             causal=True)
     else:
         attn_impl = lambda q, k, v: flash_attention(q, k, v, True, None)  # noqa: E731
+        if mesh is not None:
+            # A Mosaic kernel cannot be partitioned by XLA: under a
+            # sharded jit it must see per-device shards (batch over
+            # dp/fsdp, heads over tp; no collective is added).  T stays
+            # whole: the plain kernel's causal mask is local, so on a
+            # mesh with sp > 1 each sp device attends over the full
+            # sequence, as it did before the wrapper.
+            attn_impl = make_sharded_attention(attn_impl, mesh, axis=None)
 
     x = params["embed"].astype(cd)[tokens]
     x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)

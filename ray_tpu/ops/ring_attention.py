@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 from typing import Sequence
 
 import jax
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel.mesh import AXIS_SEQ
+from ray_tpu.parallel.mesh import AXIS_SEQ, mesh_axis_sizes
 
 _NEG_INF = -1e30
 
@@ -93,23 +94,41 @@ def ring_attention(q, k, v, *, axis: str = AXIS_SEQ, causal: bool = True,
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def make_sharded_attention(local_fn, mesh: Mesh, *, axis: str = AXIS_SEQ,
+def make_sharded_attention(local_fn, mesh: Mesh, *,
+                           axis: str | None = AXIS_SEQ,
                            batch_axes: Sequence[str] = ("dp", "fsdp"),
                            head_axis: str | None = "tp"):
-    """Shared shard_map wrapper for context-parallel attention schemes
-    (`ring_attention`, `ulysses_attention`): one place owns the layout
-    contract so the schemes cannot drift apart.
+    """Shared shard_map wrapper for attention under a mesh — the
+    context-parallel schemes (`ring_attention`, `ulysses_attention`) and
+    the plain flash kernel, which XLA cannot partition on its own: one
+    place owns the layout contract so they cannot drift apart.
 
     Layout: (B, T, H, D) with B over `batch_axes`, T over `axis`, H over
     `head_axis`.  Only axes present in `mesh` are used.  `local_fn`
-    takes per-device (q, k, v) shards.
+    takes per-device (q, k, v) shards.  `axis=None` keeps T whole on
+    every device: required for a `local_fn` that does not exchange
+    blocks over the axis itself (the plain flash kernel).
     """
-    known = set(mesh.axis_names)
-    bspec = tuple(a for a in batch_axes if a in known) or None
-    hspec = head_axis if head_axis in known else None
-    spec = P(bspec, axis, hspec, None)
-    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
+    sizes = mesh_axis_sizes(mesh)
+    bspec = tuple(a for a in batch_axes if a in sizes) or None
+    hspec = head_axis if head_axis in sizes else None
+    data_shards = math.prod(sizes[a] for a in bspec or ())
+
+    def sharded(q, k, v):
+        b = bspec
+        if q.shape[0] % data_shards:
+            # Same outcome as a dropped sharding constraint: every device
+            # of the data axes computes the whole batch.
+            warnings.warn(
+                f"attention batch {q.shape[0]} does not divide over mesh "
+                f"axes {bspec} (x{data_shards}): computed unsharded on "
+                f"each device", stacklevel=2)
+            b = None
+        spec = P(b, axis if axis in sizes else None, hspec, None)
+        return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
+
+    return sharded
 
 
 def make_ring_attention(mesh: Mesh, *, axis: str = AXIS_SEQ, causal: bool = True,

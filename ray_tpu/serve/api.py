@@ -5,6 +5,8 @@ import time
 from typing import Dict, Optional
 
 import ray_tpu
+from ray_tpu.core.config import get_config
+from ray_tpu.exceptions import GetTimeoutError
 from ray_tpu.serve.controller import CONTROLLER_NAME, get_or_create_controller
 from ray_tpu.serve.deployment import Application, Deployment
 from ray_tpu.serve.handle import DeploymentHandle
@@ -150,12 +152,20 @@ def run(app: Application | Deployment, *, name: str = "default",
         app = app.bind()
     controller = get_or_create_controller()
     _deploy_graph(controller, app, name)
-    # wait for at least one replica
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
+    # Wait on what the controller reports, for as long as the controller
+    # itself gives a replica to start (model load and first compiles take
+    # minutes on a chip): "ready" means a replica of THIS deploy finished
+    # its constructor and answered a health probe.
+    grace = get_config().serve_startup_grace_s
+    deadline = time.monotonic() + grace
+    while True:
         st = ray_tpu.get(controller.app_status.remote(name), timeout=30)
-        if st["running"] >= min(1, st["target"]):
+        if st["ready"] >= min(1, st["target"]):
             break
+        if time.monotonic() >= deadline:
+            raise GetTimeoutError(
+                f"serve app {name!r}: no replica ready after {grace:.0f}s "
+                f"(status {st})")
         time.sleep(0.1)
     if _http and route_prefix:
         _update_persisted_routes(lambda r: r.__setitem__(route_prefix,
@@ -191,7 +201,14 @@ def _get_or_start_ingress(cached_handle, actor_cls_path: str,
     handle = ray_tpu.remote(cls).options(
         name=actor_name, lifetime="detached",
         max_concurrency=32).remote(host, port)
-    return handle, ray_tpu.get(handle.port.remote(), timeout=30)
+    # The call parks until the actor is alive (its constructor returns
+    # once the server is listening) or the runtime's own creation
+    # deadline declares it failed — no shorter guess of our own beside a
+    # host busy compiling.
+    cfg = get_config()
+    return handle, ray_tpu.get(
+        handle.port.remote(),
+        timeout=cfg.actor_creation_timeout_s + cfg.worker_register_timeout_s)
 
 
 def start_http_proxy(host: str = "127.0.0.1", port: int = 0):

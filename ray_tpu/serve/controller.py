@@ -221,12 +221,16 @@ class ServeController:
         with self._lock:
             tgt = self._targets.get(app_name)
             st = self._state.get(app_name, {"replicas": {}, "version": 0})
+            gen = tgt["gen"] if tgt else None
+            gens = st.get("gens", {})
             return {
                 "running": len(st["replicas"]),
                 # Constructor finished AND passed a health probe — what
-                # "can serve a request right now" actually means.
+                # "can serve a request right now" actually means — for
+                # the deploy now wanted: a replica of an older one that
+                # is about to be retired does not make a redeploy ready.
                 "ready": sum(1 for n in st["replicas"]
-                             if n in self._ready),
+                             if n in self._ready and gens.get(n) == gen),
                 "target": tgt["num_replicas"] if tgt else 0,
                 "version": st["version"],
             }

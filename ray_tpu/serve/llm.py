@@ -1325,7 +1325,11 @@ class LLMDeployment:
         import jax
 
         from ray_tpu.models import TransformerConfig, configs, init_params
+        from ray_tpu.util import compile_cache
 
+        # Start counting before the first compile, so runtime_report()
+        # can say what this replica's start cost in compiles.
+        compile_cache.counts()
         cfg = (cfg_name if isinstance(cfg_name, TransformerConfig)
                else configs.get(cfg_name))
         params = (params_loader() if params_loader
@@ -1460,6 +1464,16 @@ class LLMDeployment:
 
     def stats(self, _request: Optional[dict] = None) -> dict:
         return self.engine.engine_stats()
+
+    def runtime_report(self, _request: Optional[dict] = None) -> dict:
+        """What this replica's process computes on and what it has taken
+        from / added to the compile cache — asked of the process that
+        owns the chip, because a caller that asked JAX would take it."""
+        from ray_tpu.util import compile_cache
+        from ray_tpu.util.tpu import device_report
+
+        return {"device": device_report(),
+                "compile_cache": compile_cache.counts()}
 
     def serve_state(self) -> dict:
         """Replica gauge-loop hook: disagg role + the digests of this

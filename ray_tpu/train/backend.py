@@ -51,11 +51,7 @@ class JaxBackend(Backend):
         # initialize backends BEFORE distributed.initialize, which
         # pins single-process topology). TPU keeps ICI collectives.
         if "cpu" in (os.environ.get("JAX_PLATFORMS") or ""):
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 knob absent on this jax
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=master_env["RTPU_JAX_COORDINATOR"],
             num_processes=world_size,

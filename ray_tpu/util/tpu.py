@@ -56,6 +56,22 @@ def get_num_tpu_chips_on_node() -> int:
         return 0
 
 
+def device_report() -> Dict[str, object]:
+    """What THIS process computes on, as JAX reports it — for the process
+    that owns the chip to say (a caller that asked JAX itself would take
+    the chip).  Initialises the backend; memory fields are None where the
+    backend reports none (the CPU)."""
+    import jax
+
+    devices = jax.devices()
+    memory = devices[0].memory_stats() or {}
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+            "peak_bytes_in_use": memory.get("peak_bytes_in_use"),
+            "bytes_limit": memory.get("bytes_limit")}
+
+
 # ---------------------------------------------------------------------------
 # driver-side slice discovery + atomic reservation
 # ---------------------------------------------------------------------------

@@ -147,6 +147,62 @@ def test_commit_marks_bundle_usable_and_prewarms(gang_cluster):
             cl.close()
 
 
+@pytest.mark.parametrize("first", ["bundle", "lease"])
+def test_bundle_return_and_holder_release_credit_once(gang_cluster, first):
+    """A bundle gives back only what no live lease holds; the held share
+    comes back with its holder.  Whichever goes first, the node ends at
+    exactly what it started with: a double credit would oversubscribe a
+    chip, a missed one would leak it for good."""
+    clients = _daemons()
+    c = clients[0]
+    pg_id = f"credit-{first}"
+    try:
+        before = c.call("NodeDaemon", "debug_state", timeout=15)
+        cpu = before["available"]["CPU"]
+        assert c.call("NodeDaemon", "reserve_pg_bundle", pg_id=pg_id,
+                      bundle_idx=0, resources={"CPU": 2}, timeout=15)["ok"]
+        assert c.call("NodeDaemon", "commit_pg_bundle", pg_id=pg_id,
+                      bundle_idx=0, timeout=15)["ok"]
+        lease = c.call("NodeDaemon", "request_lease", demand={"CPU": 1},
+                       placement=(pg_id, 0), timeout=30)
+        assert lease.get("granted"), lease
+        state = c.call("NodeDaemon", "debug_state", timeout=15)
+        assert state["available"]["CPU"] == cpu - 2
+
+        def return_bundle():
+            c.call("NodeDaemon", "return_pg_bundle", pg_id=pg_id,
+                   bundle_idx=0, timeout=15)
+
+        def return_lease():
+            c.call("NodeDaemon", "return_lease",
+                   lease_id=lease["lease_id"], timeout=15)
+
+        if first == "bundle":
+            return_bundle()
+            # The unheld CPU is back; the holder's is still out.
+            state = c.call("NodeDaemon", "debug_state", timeout=15)
+            assert state["available"]["CPU"] == cpu - 1
+            return_lease()
+        else:
+            return_lease()
+            # Released into the bundle, which still reserves both.
+            state = c.call("NodeDaemon", "debug_state", timeout=15)
+            assert state["available"]["CPU"] == cpu - 2
+            return_bundle()
+        state = c.call("NodeDaemon", "debug_state", timeout=15)
+        assert state["available"]["CPU"] == cpu
+        assert state["pg_bundles"] == before["pg_bundles"]
+        assert state["leases"] == before["leases"]
+        # Idempotent: a second return of either credits nothing.
+        return_bundle()
+        return_lease()
+        state = c.call("NodeDaemon", "debug_state", timeout=15)
+        assert state["available"]["CPU"] == cpu
+    finally:
+        for cl in clients:
+            cl.close()
+
+
 def test_ready_long_polls_and_wakes_on_capacity(gang_cluster):
     """PlacementGroup.ready() parks in the GCS long-poll (no driver
     sleep loop) and wakes promptly when the missing capacity joins."""

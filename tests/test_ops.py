@@ -1,7 +1,10 @@
 """Attention / ring attention / norm / rope correctness vs references."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import (
@@ -36,6 +39,39 @@ def test_flash_grads_finite():
     # grad of flash == grad of reference
     gq_ref = jax.grad(lambda q_: jnp.sum(mha_reference(q_, k, v, causal=True) ** 2))(q)
     np.testing.assert_allclose(np.asarray(gq), np.asarray(gq_ref), atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_pallas_kernels_match_reference_in_interpret_mode(monkeypatch, causal):
+    """The three Pallas kernels themselves (forward, dq, dk/dv), run by the
+    Pallas interpreter on the CPU: 2x2 blocks of 256, so the causal skip,
+    the online-softmax carry and both backward accumulations are live.
+    Steered from here (no option in the program): `_pallas_eligible` is
+    false off the TPU and `pallas_call` compiles for Mosaic."""
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_pallas_eligible", lambda q, k: True)
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    q, k, v = _qkv(jax.random.key(7), b=1, t=512, h=2, d=64)
+
+    def grads(attn):
+        return jax.grad(lambda q_, k_, v_: jnp.sum(attn(q_, k_, v_) ** 2),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def flash(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal, None)
+
+    def ref(q_, k_, v_):
+        return mha_reference(q_, k_, v_, causal=causal)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=1e-5)
+    for g, g_ref in zip(grads(flash), grads(ref)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                                   atol=1e-4)
 
 
 def test_ring_attention_matches_full():

@@ -1,0 +1,225 @@
+"""Ahead-of-time compiles for a described (not attached) TPU v5e.
+
+The chip's compiler is installed wherever libtpu is, so the kernels and
+step programs of the train and serve main paths are compiled here at
+their real widths without a chip: what Mosaic or XLA:TPU would refuse on
+the device (a kernel that cannot be partitioned, a tile that does not
+align, a program that does not fit 16 GB) fails in this file first.
+Nothing runs, so nothing here is a result or a time.  `chip_smoke.py` is
+the run on the chip.
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.models import configs  # noqa: E402
+from ray_tpu.ops import attention  # noqa: E402
+
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 no libtpu on this host
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run warns and
+    recompiles), so the cache is off around every compile here."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+# (B, T, H, D) of the attention call inside bench-350m at batch 8 and
+# bench-1b4 at batch 4, both at seq 2048.
+FLASH_SHAPES = [(8, 2048, 16, 64), (4, 2048, 16, 128)]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["350m", "1b4"])
+def test_flash_forward_kernel_compiles(topo, shape):
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    fn = jax.jit(lambda q, k, v: attention._flash_pallas(
+        q, k, v, causal=True, sm_scale=shape[-1] ** -0.5))
+    compiled = fn.lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["350m", "1b4"])
+def test_flash_backward_kernels_compile(topo, shape):
+    b, t, h, _ = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((b * h, t, attention._LANES), jnp.float32,
+                               sharding=one)
+    fn = jax.jit(lambda q, k, v, o, lse, g: attention._flash_bwd_pallas(
+        q, k, v, o, lse, g, causal=True, sm_scale=shape[-1] ** -0.5))
+    text = fn.lower(x, x, x, x, lse, x).compile().as_text()
+    # dq and dk/dv are separate kernels.
+    assert text.count("tpu_custom_call") >= 2
+
+
+def _fsdp_mesh(topo):
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    return build_mesh(MeshConfig(fsdp=4), devices=topo.devices)
+
+
+def test_flash_attention_under_fsdp_mesh_compiles(topo, monkeypatch):
+    """The public flash_attention with the batch split over four chips:
+    a bare pallas_call under a sharded jit is refused ("Mosaic kernels
+    cannot be automatically partitioned"), so the model wraps the call in
+    shard_map (`make_sharded_attention`, as `models.transformer.forward`
+    does when it is given a mesh).  The kernel must survive into the
+    compiled program and no collective may be added for it."""
+    from ray_tpu.ops.ring_attention import make_sharded_attention
+
+    # The eligibility check asks jax.default_backend(), which is the CPU
+    # here; steer it in the test, not through an option of the program.
+    monkeypatch.setattr(attention, "_pallas_eligible", lambda q, k: True)
+    mesh = _fsdp_mesh(topo)
+    shape = FLASH_SHAPES[0]
+    x = jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, None, None)))
+    attn = make_sharded_attention(
+        lambda q, k, v: attention.flash_attention(q, k, v, True, None), mesh)
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+
+
+def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
+    """`make_train_step` on `MeshConfig(fsdp=4)` — the README's "FSDP by
+    changing the mesh" — at bench-350m widths (depth cut: the scan makes
+    compile time independent of it).  Kernel in place, parameters and
+    Adam moments split, and the collectives XLA derives from the layout:
+    parameters all-gathered, gradients reduced back to shards.  XLA:TPU
+    writes that reduce-scatter as rings of collective-permutes fused
+    into the matmul that produces the gradient (windowed einsum), so the
+    literal op name is absent from the optimized text — and a windowed
+    all-gather emits the same permutes.  What shows the reduction to
+    shards is what `chip_smoke.py --chips 4` checks on the chip: the only
+    all-reduces left carry norm gains and scalars, never a weight-sized
+    gradient, and the moments leave the step split like they entered."""
+    import chip_smoke
+    from ray_tpu.models.training import make_train_step
+
+    monkeypatch.setattr(attention, "_pallas_eligible", lambda q, k: True)
+    cfg = dataclasses.replace(configs.get("bench-350m"), n_layers=2)
+    mesh = _fsdp_mesh(topo)
+    init_fn, step_fn = make_train_step(cfg, mesh)
+    key = jax.ShapeDtypeStruct(
+        (), jax.random.key(0).dtype,
+        sharding=NamedSharding(mesh, P()))
+    init_c = init_fn.lower(key).compile()
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(init_fn, key), init_c.output_shardings)
+    for wq in (state.params["blocks"]["wq"],
+               state.opt_state[1][0].mu["blocks"]["wq"]):
+        assert wq.sharding.shard_shape(wq.shape)[1] == cfg.d_model // 4
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (8, 2049), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None)))}
+    compiled = step_fn.lower(state, batch).compile()
+    report = chip_smoke._program_report(compiled)
+    assert report["tpu_custom_call_in_step"]
+    assert report["collectives_in_step"].get("all-gather")
+    # Replicated norm gains (d_model f32) are the largest thing
+    # all-reduced; one layer's wq shard is 256x that.
+    assert 0 < report["largest_all_reduce_bytes"] <= 4 * cfg.d_model
+    mu_out = compiled.output_shardings[0].opt_state[1][0].mu["blocks"]["wq"]
+    assert mu_out.shard_shape(wq.shape)[1] == cfg.d_model // 4
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    assert report["argument_bytes_per_device"] < 1.02 * state_bytes / 4
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def _serve_shapes(topo, num_slots=8, max_len=2048, block_size=16):
+    from ray_tpu.models import init_params
+    from ray_tpu.models.decoding import init_paged_cache
+
+    cfg = configs.get("bench-1b4")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    num_blocks = num_slots * max_len // block_size + 1
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_cache(cfg, num_blocks, block_size)))
+    b_max = max_len // block_size
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return cfg, params, cache, b_max, arr
+
+
+def test_paged_decode_burst_fits_one_chip(topo):
+    """bench-1b4 at the smoke's serving shape (8 slots x 2048, block 16,
+    8-step burst): compiles for one v5e chip and fits its 16 GB."""
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    cfg, params, cache, b_max, arr = _serve_shapes(topo)
+    _, burst, _ = make_paged_engine_fns(cfg)
+    w = 8
+    rng = arr((), jax.random.key(0).dtype)
+    compiled = burst.lower(
+        params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
+        arr((w,), jnp.int32), arr((w,), jnp.bool_),
+        arr((w,), jnp.float32), rng, n_steps=8).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_paged_prefill_chunk_fits_one_chip(topo):
+    """The widest prefill tier (128 tokens) at the same serving shape."""
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    cfg, params, cache, b_max, arr = _serve_shapes(topo)
+    chunk, _, _ = make_paged_engine_fns(cfg)
+    compiled = chunk.lower(
+        params, cache, arr((128,), jnp.int32), arr((b_max,), jnp.int32),
+        arr((), jnp.int32), arr((), jnp.int32)).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES

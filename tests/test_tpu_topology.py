@@ -30,6 +30,57 @@ def test_extra_resources_head_vs_worker(monkeypatch):
     assert "TPU-v5e-16-head" not in res
 
 
+# ---------------------------------------------------------------------------
+# unit: chip detection counts device nodes and never opens a chip
+# ---------------------------------------------------------------------------
+
+def _fake_dev(monkeypatch, accel=(), vfio=None):
+    import glob
+    import os
+
+    def listdir(path):
+        if vfio is None:
+            raise FileNotFoundError(path)
+        if isinstance(vfio, Exception):
+            raise vfio
+        return list(vfio)
+
+    monkeypatch.setattr(glob, "glob", lambda pat: list(accel))
+    monkeypatch.setattr(os, "listdir", listdir)
+    for var in ("RAY_TPU_NUM_TPUS", "RAY_TPU_DISABLE_TPU_DETECTION"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+
+
+@pytest.mark.parametrize("accel,vfio,want", [
+    ((), None, 0),                                  # no chip on the host
+    ((), ["vfio", "1"], 1),                         # the one-chip v5e host
+    ((), ["0", "1", "2", "3", "vfio"], 4),          # a four-chip host
+    (["/dev/accel0", "/dev/accel1"], ["vfio"], 2),  # accel nodes win
+])
+def test_chip_count_from_device_nodes(monkeypatch, accel, vfio, want):
+    from ray_tpu.core.distributed import resources
+
+    _fake_dev(monkeypatch, accel, vfio)
+    assert resources.probe_tpu_count() == want
+    total = resources.detect_node_resources(num_cpus=1)
+    assert total.get("TPU", 0) == want
+
+
+def test_chip_detection_overrides_and_logged_failure(monkeypatch, caplog):
+    from ray_tpu.core.distributed import resources
+
+    _fake_dev(monkeypatch, vfio=PermissionError("/dev/vfio"))
+    with caplog.at_level("ERROR"):
+        assert resources.probe_tpu_count() == 0
+    assert "TPU detection failed" in caplog.text
+    _fake_dev(monkeypatch, vfio=["1", "vfio"])
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")      # workers could not use it
+    assert resources.probe_tpu_count() == 0
+    monkeypatch.setenv("RAY_TPU_NUM_TPUS", "4")     # the operator knows
+    assert resources.probe_tpu_count() == 4
+
+
 def test_num_hosts_in_pod():
     assert accelerators.num_hosts_in_pod("v5e-16") == 4
     assert accelerators.num_hosts_in_pod("v4-16") == 2  # cores, 8/host
