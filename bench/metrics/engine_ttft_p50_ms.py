@@ -1,0 +1,3 @@
+"""`engine_ttft_p50_ms`: bench/harness/engine_records.py `request_stat` with the
+arguments of engine_ttft_p50_ms.json."""
+from bench.harness.engine_records import request_stat as read  # noqa: F401

@@ -379,12 +379,22 @@ def decode_burst(params, cache: KVCache, tokens, active, temps, rng,
     return cache, toks, rng
 
 
+def _bind_cfg(f, cfg: TransformerConfig):
+    """`functools.partial(f, cfg=cfg)` under `f`'s own name.  jax.jit
+    names the compiled program after the function it is given, and a
+    bare partial has no name: every engine program would be
+    `jit__unknown` in a profile's `XLA Modules` line and in HLO dumps."""
+    bound = functools.partial(f, cfg=cfg)
+    bound.__name__, bound.__qualname__ = f.__name__, f.__qualname__
+    return bound
+
+
 def make_engine_fns(cfg: TransformerConfig, *, num_slots: int,
                     max_len: int, donate: bool = True):
     """Jitted (prefill_fn, burst_decode_fn) with cache donation.  The
     decode fn takes a static `n_steps` (one compile per distinct burst)."""
-    pf = functools.partial(prefill_and_sample, cfg=cfg)
-    df = functools.partial(decode_burst, cfg=cfg)
+    pf = _bind_cfg(prefill_and_sample, cfg)
+    df = _bind_cfg(decode_burst, cfg)
     prefill_jit = jax.jit(pf, donate_argnums=(1,) if donate else ())
     decode_jit = jax.jit(df, static_argnames=("n_steps",),
                          donate_argnums=(1,) if donate else ())
@@ -410,7 +420,7 @@ def ngram_propose(context, k_minus_1: int, ngram: int = 2):
 def make_spec_fns(cfg: TransformerConfig, donate: bool = True):
     """Jitted speculative verifier (K rides in the candidate shape:
     one compile per K, same discipline as prefill buckets)."""
-    return jax.jit(functools.partial(verify_step, cfg=cfg),
+    return jax.jit(_bind_cfg(verify_step, cfg),
                    donate_argnums=(1,) if donate else ())
 
 
@@ -679,7 +689,7 @@ def make_paged_spec_fns(cfg: TransformerConfig, donate: bool = True):
     """Jitted paged speculative verifier (K rides in the candidate
     shape, slot width S in every row dim: one compile per (S, K) pair,
     the same tier discipline as the paged burst)."""
-    return jax.jit(functools.partial(paged_verify_step, cfg=cfg),
+    return jax.jit(_bind_cfg(paged_verify_step, cfg),
                    donate_argnums=(1,) if donate else ())
 
 
@@ -726,9 +736,9 @@ def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):
     donation.  Chunk width C and table depth B_max ride in the argument
     shapes (one compile per distinct pair, same discipline as prefill
     buckets); the burst takes a static n_steps."""
-    chunk_jit = jax.jit(functools.partial(paged_prefill_chunk, cfg=cfg),
+    chunk_jit = jax.jit(_bind_cfg(paged_prefill_chunk, cfg),
                         donate_argnums=(1,) if donate else ())
-    burst_jit = jax.jit(functools.partial(paged_decode_burst, cfg=cfg),
+    burst_jit = jax.jit(_bind_cfg(paged_decode_burst, cfg),
                         static_argnames=("n_steps",),
                         donate_argnums=(1,) if donate else ())
     copy_jit = jax.jit(copy_block, donate_argnums=(0,) if donate else ())

@@ -49,9 +49,11 @@ from ray_tpu.exceptions import StreamQueueFullError  # noqa: F401
 
 class _Request:
     __slots__ = ("prompt", "max_tokens", "temperature", "out_tokens",
-                 "done", "error", "slot", "submitted_at", "first_token_at",
-                 "token_q", "dropped", "blocks", "pos", "prefilling",
-                 "no_register", "trace", "submitted_wall", "last_emit_wall")
+                 "done", "error", "slot", "submitted_at", "admitted_at",
+                 "prefill_at", "first_token_at", "prefill_span", "chunks",
+                 "chunk_s", "chunk_tokens", "token_q", "dropped", "blocks",
+                 "pos", "prefilling", "no_register", "trace",
+                 "last_emit_wall")
 
     def __init__(self, prompt, max_tokens, temperature, stream=False):
         from ray_tpu.core.config import get_config
@@ -63,13 +65,26 @@ class _Request:
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
         self.slot = -1
-        self.submitted_at = time.perf_counter()
+        # The edges of the request's phases up to its first token, all
+        # on time.time() (the clock of the spans and of a client's own
+        # stamps): submitted -> admitted into a slot (queue_wait) ->
+        # launch of its first prefill chunk (prefill_wait) -> first
+        # token (the prefill span).  Contiguous, so the three phases
+        # sum to the engine's TTFT exactly.
+        self.submitted_at = time.time()
+        self.admitted_at: Optional[float] = None
+        self.prefill_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
+        # The open `serve.engine.prefill` span (None when tracing is
+        # off) and what its chunks summed to: their count, their own
+        # wall time, their tokens.
+        self.prefill_span: Optional[tracing.Span] = None
+        self.chunks = 0
+        self.chunk_s = 0.0
+        self.chunk_tokens = 0
         # Serve trace context ({"trace_id": <request id>, ...}, None when
-        # tracing is off) — engine tick spans parent under it.  Wall
-        # clocks alongside the perf counters: spans need epoch stamps.
+        # tracing is off) — engine tick spans parent under it.
         self.trace: Optional[dict] = None
-        self.submitted_wall = time.time()
         self.last_emit_wall: Optional[float] = None
         # Streaming consumers read tokens as the engine emits them.
         # BOUNDED: a consumer that stops reading must not grow replica
@@ -98,6 +113,36 @@ class _Request:
                     f"behind; stream dropped "
                     f"(RAY_TPU_SERVE_STREAM_QUEUE_MAX)",
                     queue_max=self.token_q.maxsize)
+
+
+# One record of engine_stats()["tick_log"], in this order (the stats
+# carry the names as "tick_fields", so a reader needs no copy of them).
+TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
+               "lanes", "width", "prefill_tokens")
+
+
+class _TickAccounts:
+    """What one paged-engine tick did, written by its phases from the
+    clock reads they take anyway (nothing per lane or per token) and
+    folded into one tick-log record by PagedLLMEngine._tick."""
+    __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
+                 "prefill_tokens")
+
+    def __init__(self):
+        self.decode_s = self.prefill_s = self.sample_s = 0.0
+        self.lanes = self.width = self.prefill_tokens = 0
+
+
+def _snapshot(log: deque) -> tuple:
+    """A foreign thread's copy of a bounded log its one owner thread
+    appends to meanwhile (engine_stats() runs on the replica's gauge
+    thread every second): references only, microseconds, and taken
+    again if an append landed inside the copy."""
+    while True:
+        try:
+            return tuple(log)
+        except RuntimeError:     # deque mutated during iteration
+            continue
 
 
 class _EngineBase:
@@ -182,10 +227,16 @@ class _EngineBase:
                 return
             yield tok
 
-    def engine_stats(self) -> Dict[str, Any]:
+    def engine_stats(self, records: bool = True) -> Dict[str, Any]:
+        """The cumulative counters and, with `records`, the bounded
+        logs and what is computed from them.  The replica's gauge loop
+        asks every second and reads counters only: it passes False."""
         s = dict(self.stats)
-        s["p_ttft_mean"] = (s["ttft_sum"] / s["completed"]
-                            if s["completed"] else None)
+        if records:
+            phases = s["request_phases"] = _snapshot(self._request_phases)
+            s["p_ttft_mean"] = (
+                sum(r["ttft_s"] for r in phases) / len(phases)
+                if phases else None)
         return s
 
     def shutdown(self):
@@ -195,9 +246,6 @@ class _EngineBase:
     def _finish_request(self, req: "_Request") -> None:
         """Complete one request: stats + stream sentinel + done event."""
         self.stats["completed"] += 1
-        if req.first_token_at is not None:
-            self.stats["ttft_sum"] += (req.first_token_at
-                                       - req.submitted_at)
         if req.token_q is not None:
             try:
                 req.token_q.put_nowait(None)  # stream sentinel
@@ -206,13 +254,18 @@ class _EngineBase:
         req.done.set()
 
     # -- serving observability ------------------------------------------
-    # Spans attribute each engine phase (queue_wait / prefill_chunk /
-    # decode_burst) to the request's trace; histograms decompose TTFT /
-    # ITL per app.  Spans gate on req.trace (None when the
-    # RAY_TPU_SERVE_TRACE_ENABLED kill switch is off); histograms record
-    # either way.  The app tag is learned lazily from traced requests —
-    # standalone engines (bench, unit tests) report under "-".
+    # Spans attribute each engine phase (queue_wait / prefill_wait /
+    # prefill > prefill_chunk / decode_burst) to the request's trace;
+    # histograms decompose TTFT / ITL per app; `_request_phases` keeps
+    # the same edges per request for engine_stats().  Spans gate on
+    # req.trace (None when the RAY_TPU_SERVE_TRACE_ENABLED kill switch
+    # is off); histograms and the record fill either way.  The app tag
+    # is learned lazily from traced requests — standalone engines
+    # (bench, unit tests) report under "-".
     _app_hint = "-"
+    # engine_stats()["request_phases"]: the last requests that got a
+    # first token, one dict each (see _obs_first_token).
+    REQUEST_PHASES_KEPT = 1024
 
     def _obs_submit(self, req: "_Request",
                     trace: Optional[dict]) -> None:
@@ -232,29 +285,74 @@ class _EngineBase:
     def _obs_admitted(self, req: "_Request") -> None:
         from ray_tpu.serve import observability
 
-        now = time.time()
+        now = req.admitted_at = time.time()
         tracing.record_serve_span(req.trace, "serve.engine.queue_wait",
-                                  req.submitted_wall, now,
+                                  req.submitted_at, now,
                                   tokens=len(req.prompt))
         observability.observe_phase(self._obs_app(req), "queue_wait",
-                                    now - req.submitted_wall)
-
-    def _obs_first_token(self, req: "_Request") -> None:
-        from ray_tpu.serve import observability
-
-        observability.metrics()["ttft"].observe(
-            req.first_token_at - req.submitted_at,
-            {"app": self._obs_app(req)})
-        req.last_emit_wall = time.time()
+                                    now - req.submitted_at)
 
     def _obs_prefill(self, req: "_Request", t0: float,
-                     n_tokens: int) -> None:
+                     n_tokens: int) -> float:
+        """One prefill chunk launched at `t0`; returns its wall time.
+        A request's first chunk ends its prefill_wait and opens its
+        `serve.engine.prefill` span, the parent of every chunk up to the
+        first token.  A preempted request's re-prefill comes after its
+        first token: its chunks are marked `resumed` and stay out of the
+        TTFT phases."""
         from ray_tpu.serve import observability
 
         t1 = time.time()
-        tracing.record_serve_span(req.trace, "serve.engine.prefill_chunk",
-                                  t0, t1, tokens=n_tokens, pos=req.pos)
-        observability.observe_phase(self._obs_app(req), "prefill", t1 - t0)
+        app = self._obs_app(req)
+        ctx, attrs = req.trace, {}
+        if req.first_token_at is not None:
+            attrs["resumed"] = 1
+        else:
+            if req.prefill_at is None:
+                req.prefill_at = t0
+                tracing.record_serve_span(
+                    ctx, "serve.engine.prefill_wait", req.admitted_at, t0,
+                    tokens=len(req.prompt))
+                observability.observe_phase(app, "prefill_wait",
+                                            t0 - req.admitted_at)
+                req.prefill_span = tracing.open_serve_span(
+                    ctx, "serve.engine.prefill", t0)
+            req.chunks += 1
+            req.chunk_s += t1 - t0
+            req.chunk_tokens += n_tokens
+            ctx = tracing.child_ctx(ctx, req.prefill_span)
+        tracing.record_serve_span(ctx, "serve.engine.prefill_chunk",
+                                  t0, t1, tokens=n_tokens, pos=req.pos,
+                                  **attrs)
+        observability.observe_phase(app, "prefill", t1 - t0)
+        return t1 - t0
+
+    def _obs_first_token(self, req: "_Request") -> None:
+        """Stamp the first token: it ends the prefill span and with it
+        the chain queue_wait + prefill_wait + prefill_span = TTFT, which
+        goes into the request_phases record as one dict.  A whole-prompt
+        prefix hit launches no chunk: it has no prefill_wait, and its
+        prefill_span is the sampling of the stored logits."""
+        from ray_tpu.serve import observability
+
+        now = req.first_token_at = req.last_emit_wall = time.time()
+        if req.prefill_at is None:
+            req.prefill_at = req.admitted_at
+        observability.metrics()["ttft"].observe(
+            now - req.submitted_at, {"app": self._obs_app(req)})
+        if req.prefill_span is not None:
+            req.prefill_span.attrs.update(
+                tokens=req.chunk_tokens, chunks=req.chunks,
+                chunk_s=req.chunk_s)
+            req.prefill_span.finish(now)
+            req.prefill_span = None
+        self._request_phases.append({
+            "id": req.trace["trace_id"] if req.trace else None,
+            "submitted": req.submitted_at,
+            "queue_wait_s": req.admitted_at - req.submitted_at,
+            "prefill_wait_s": req.prefill_at - req.admitted_at,
+            "prefill_span_s": now - req.prefill_at,
+            "ttft_s": now - req.submitted_at})
 
     def _obs_burst(self, req: "_Request", t0: float, t1: float,
                    n_new: int) -> None:
@@ -381,9 +479,11 @@ class LLMEngine(_EngineBase):
         self._stop = False
         self._lock = threading.Lock()
         self.stats = {"requests": 0, "tokens_generated": 0,
-                      "ttft_sum": 0.0, "completed": 0,
+                      "completed": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "spec_proposed": 0, "spec_accepted": 0}
+        self._request_phases: deque = deque(
+            maxlen=self.REQUEST_PHASES_KEPT)
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -454,7 +554,6 @@ class LLMEngine(_EngineBase):
                             self._prefix_cache_size:
                         self._prefix_cache.pop(
                             next(iter(self._prefix_cache)))
-            req.first_token_at = time.perf_counter()
             self._obs_first_token(req)
             req.emit(int(tok))
             req.slot = slot
@@ -622,6 +721,7 @@ class PagedLLMEngine(_EngineBase):
     prompt remainder.  When the pool can't cover it, the request WAITS
     at the head of the queue (no error) until completions free blocks.
     """
+    TICKS_KEPT = 4096
 
     def __init__(self, cfg, params, *, num_slots: int = 32,
                  max_len: int = 1024, block_size: Optional[int] = None,
@@ -717,13 +817,22 @@ class PagedLLMEngine(_EngineBase):
         # race.  Uncontended cost is one lock per tick.
         self._tick_lock = threading.Lock()
         self.stats = {"requests": 0, "tokens_generated": 0,
-                      "ttft_sum": 0.0, "completed": 0,
+                      "completed": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefill_chunks": 0, "queue_waits": 0,
                       "preemptions": 0, "adopted_blocks": 0,
                       "migrated_blocks": 0, "migrate_fallbacks": 0,
                       "disagg_prefills": 0,
                       "spec_proposed": 0, "spec_accepted": 0}
+        self._request_phases: deque = deque(
+            maxlen=self.REQUEST_PHASES_KEPT)
+        # engine_stats()["tick_log"]: one tuple per tick that progressed
+        # (see _tick), from the accounts the tick's phases keep as they
+        # go.  `stats` is cumulative since the process began; a log lets
+        # a reader take any window's ticks by their start and gives a
+        # median where a counter gives a mean.
+        self._tick_log: deque = deque(maxlen=self.TICKS_KEPT)
+        self._acct = _TickAccounts()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -737,9 +846,12 @@ class PagedLLMEngine(_EngineBase):
         self._thread.join(timeout=5)
         self.allocator.release()
 
-    def engine_stats(self) -> Dict[str, Any]:
-        s = super().engine_stats()
+    def engine_stats(self, records: bool = True) -> Dict[str, Any]:
+        s = super().engine_stats(records)
         s.update(self.allocator.snapshot())
+        if records:
+            s["tick_log"] = _snapshot(self._tick_log)
+            s["tick_fields"] = TICK_FIELDS
         s["queue_depth"] = len(self._pending)
         s["active"] = sum(1 for r in self._slots if r is not None)
         return s
@@ -890,7 +1002,6 @@ class PagedLLMEngine(_EngineBase):
         # re-enters here with out_tokens already emitted).
         n_ctx = len(req.prompt) + len(req.out_tokens)
         if req.first_token_at is None:
-            req.first_token_at = time.perf_counter()
             self._obs_first_token(req)
         req.prefilling = False
         req.emit(first_tok)
@@ -967,7 +1078,9 @@ class PagedLLMEngine(_EngineBase):
                 budget -= nv
                 progressed = True
                 self.stats["prefill_chunks"] += 1
-                self._obs_prefill(req, t0, nv)
+                acct = self._acct
+                acct.prefill_s += self._obs_prefill(req, t0, nv)
+                acct.prefill_tokens += nv
                 if req.pos >= n:
                     self._prefillq.popleft()
                     if not req.out_tokens and not req.no_register:
@@ -979,10 +1092,16 @@ class PagedLLMEngine(_EngineBase):
                         self.allocator.register_prefix(
                             req.prompt, req.blocks, meta=last_logits)
                     self._cow_tail(req, n)
+                    t_sample = time.time()
                     tok, self._rng = self._sample_one(
                         last_logits, jnp.float32(req.temperature),
                         self._rng)
-                    self._begin_decode(req, int(tok))
+                    # The host's read of the token waits for every
+                    # chunk still queued on the device: launches return
+                    # long before their chunks have run.
+                    tok = int(tok)
+                    acct.sample_s += time.time() - t_sample
+                    self._begin_decode(req, tok)
             except BaseException as e:  # noqa: BLE001
                 if self._prefillq and self._prefillq[0] == slot:
                     self._prefillq.popleft()
@@ -1037,6 +1156,7 @@ class PagedLLMEngine(_EngineBase):
         # not a num_slots-wide one).  All per-slot state is host-side,
         # so lane mapping is just row selection.
         w = self._tier_for(self._width_tiers, len(idx))
+        self._acct.lanes, self._acct.width = len(idx), w
         tokens = np.zeros((w,), np.int32)
         tables = np.zeros((w, self._b_max), np.int32)
         lengths = np.zeros((w,), np.int32)
@@ -1060,6 +1180,7 @@ class PagedLLMEngine(_EngineBase):
                 n_steps=burst)
             tok_mat = np.asarray(tok_mat)              # (burst, w)
             t1 = time.time()
+            self._acct.decode_s = t1 - t0
             for j, i in enumerate(idx):
                 req = self._slots[i]
                 self._lengths[i] += burst   # KV written for every step
@@ -1126,6 +1247,7 @@ class PagedLLMEngine(_EngineBase):
         tok_out = np.asarray(tok_out)              # (w, k)
         accepted = np.asarray(accepted)            # (w,)
         t1 = time.time()
+        self._acct.decode_s = t1 - t0
         for j, i in enumerate(idx):
             req = self._slots[i]
             a = int(accepted[j])
@@ -1180,15 +1302,39 @@ class PagedLLMEngine(_EngineBase):
             self._finish_request(req)
             self._work.set()   # freed blocks may unblock the queue head
 
+    def _tick(self) -> bool:
+        """One engine tick; the caller holds _tick_lock.  A tick that
+        progressed leaves one record of TICK_FIELDS in the tick log.
+        `start`, `tick_s`: time.time() around this body, never the wait
+        on _work.  `decode_s`: launch of the burst (or spec window) to
+        the host's read of its tokens.  `prefill_s`: the chunks' own
+        launch times summed (a launch returns before its chunk has run;
+        the device's time for it is waited for in the next `decode_s`
+        or `sample_s`).  `sample_s`: the host's reads of the first
+        tokens of the prompts this tick finished.  `lanes` of `width`:
+        decoding lanes in the burst's tier.  `prefill_tokens`: prompt
+        tokens the chunks carried.  So tick_s - decode_s -
+        prefill_s - sample_s is the tick's time in which the host
+        neither waited for the device nor launched a chunk."""
+        start = time.time()
+        acct = self._acct = _TickAccounts()
+        progressed = False
+        # Admit as many waiting requests as slots + blocks allow.
+        while self._admit_one():
+            progressed = True
+        progressed |= self._decode_tick()
+        progressed |= self._prefill_tick()
+        if progressed:
+            self._tick_log.append(
+                (start, time.time() - start, acct.decode_s, acct.prefill_s,
+                 acct.sample_s, acct.lanes, acct.width,
+                 acct.prefill_tokens))
+        return progressed
+
     def _loop(self):
         while not self._stop:
             with self._tick_lock:
-                progressed = False
-                # Admit as many waiting requests as slots + blocks allow.
-                while self._admit_one():
-                    progressed = True
-                progressed |= self._decode_tick()
-                progressed |= self._prefill_tick()
+                progressed = self._tick()
             if not progressed:
                 self._work.wait(timeout=0.02)
                 self._work.clear()
@@ -1486,7 +1632,7 @@ class LLMDeployment:
 
         cfg = get_config()
         state: dict = {"role": self.disagg_role}
-        es = self.engine.engine_stats()
+        es = self.engine.engine_stats(records=False)
         if es.get("spec_proposed"):
             state["spec_accept_rate"] = round(
                 es.get("spec_accepted", 0) / es["spec_proposed"], 4)
@@ -1526,7 +1672,7 @@ class LLMDeployment:
         g = getattr(self.engine, "gauges", None)
         if g is not None:
             return g()
-        s = self.engine.engine_stats()
+        s = self.engine.engine_stats(records=False)
         return {"queue_depth": 0.0,
                 "active": float(s.get("requests", 0)
                                 - s.get("completed", 0)),

@@ -11,7 +11,7 @@ path node metrics already ride, so `ray-tpu metrics --federated` and
 the RAY_TPU_METRICS_GCS_EXPORT_PORT endpoint see serve series with a
 node label and no new RPC plane.
 
-Metric names (phases: queue_wait, prefill, decode_step,
+Metric names (phases: queue_wait, prefill_wait, prefill, decode_step,
 stream_transport):
 
   raytpu_serve_ttft_seconds{app}          first-token latency histogram
@@ -86,7 +86,8 @@ def metrics() -> Dict[str, Any]:
                     "phase": Histogram(
                         "raytpu_serve_phase_seconds",
                         "Serve request time by phase (queue_wait, "
-                        "prefill, decode_step, stream_transport)",
+                        "prefill_wait, prefill, decode_step, "
+                        "stream_transport)",
                         boundaries=_LATENCY_BOUNDARIES,
                         tag_keys=("app", "phase")),
                     "tokens": Counter(
@@ -178,7 +179,7 @@ def mirror_engine(engine: Any, app: str) -> None:
     (1s) and the bench harness; cheap (one stats dict + a few sets)."""
     m = metrics()
     try:
-        s = engine.engine_stats()
+        s = engine.engine_stats(records=False)
     except Exception:  # noqa: BLE001 engine mid-shutdown
         return
     eng = _engine_id(engine)

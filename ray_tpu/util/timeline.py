@@ -143,7 +143,8 @@ def timeline(filename: str = "timeline.json") -> str:
 # request id IS the trace id, so one trace_id filter over the GCS span
 # sink yields the request's whole serving path — proxy admission, handle
 # routing (and failover re-routes), replica hop, engine queue_wait /
-# prefill chunks / per-burst decode, stream batches.
+# prefill_wait / prefill over its chunks / per-burst decode, stream
+# batches.
 # ---------------------------------------------------------------------------
 
 def fetch_spans(trace_id: Optional[str] = None,

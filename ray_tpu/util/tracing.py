@@ -175,17 +175,31 @@ def serve_span(ctx: Optional[Dict[str, Any]], name: str, **attrs):
     """Open a serve-plane span under an explicit request context.
     No-op (yields None) when tracing is off or there is no context —
     the caller never branches."""
-    if ctx is None or not serve_enabled():
+    s = open_serve_span(ctx, name, time.time(), **attrs)
+    if s is None:
         yield None
         return
-    if ctx.get("resumed"):
-        attrs.setdefault("resumed", 1)
-    s = Span(name, trace_id=ctx["trace_id"],
-             parent_id=ctx.get("span_id"), attrs=attrs)
     try:
         yield s
     finally:
         s.finish()
+
+
+def open_serve_span(ctx: Optional[Dict[str, Any]], name: str,
+                    start_ts: float, **attrs) -> Optional["Span"]:
+    """A serve span that began at `start_ts` and ends later, on another
+    tick of the caller's own loop (where `serve_span`'s `with` cannot
+    reach): the caller fills `span.attrs` and calls `span.finish(end)`.
+    None when tracing is off or there is no context; `child_ctx` then
+    parents children where they would have been."""
+    if ctx is None or not serve_enabled():
+        return None
+    if ctx.get("resumed"):
+        attrs.setdefault("resumed", 1)
+    s = Span(name, trace_id=ctx["trace_id"],
+             parent_id=ctx.get("span_id"), attrs=attrs)
+    s.start = start_ts
+    return s
 
 
 def record_serve_span(ctx: Optional[Dict[str, Any]], name: str,
@@ -193,14 +207,9 @@ def record_serve_span(ctx: Optional[Dict[str, Any]], name: str,
                       **attrs) -> None:
     """Record an already-timed serve span (engine ticks measure their
     own wall window; spans are minted after the fact)."""
-    if ctx is None or not serve_enabled():
-        return
-    if ctx.get("resumed"):
-        attrs.setdefault("resumed", 1)
-    s = Span(name, trace_id=ctx["trace_id"],
-             parent_id=ctx.get("span_id"), attrs=attrs)
-    s.start = start_ts
-    s.finish(end_ts)
+    s = open_serve_span(ctx, name, start_ts, **attrs)
+    if s is not None:
+        s.finish(end_ts)
 
 
 def train_enabled() -> bool:

@@ -56,10 +56,7 @@ def _stopped_engine(**kw):
 
 def _tick(e):
     with e._tick_lock:
-        while e._admit_one():
-            pass
-        e._decode_tick()
-        e._prefill_tick()
+        e._tick()
 
 
 def _counter_val(c, tags):

@@ -150,7 +150,7 @@ class _FakeEngine:
                       "blocks_cached": 0, "blocks_active": 0,
                       "occupancy": 0.0}
 
-    def engine_stats(self):
+    def engine_stats(self, records=True):
         return dict(self.stats)
 
 
@@ -269,6 +269,308 @@ def test_paged_engine_emits_phase_spans():
     # decode bursts account for every token after it.
     assert sum(s["attrs"].get("tokens", 0)
                for s in bursts) >= len(out) - 1
+
+
+# ---------------------------------------------------------------------------
+# engine records: request_phases and tick_log of engine_stats().  One
+# scenario, run with serve tracing on and with the kill switch off: six
+# requests of two prompt lengths submitted together (the tick lock is
+# held until all six are pending, so one tick admits them in order and
+# the one prefill lane serves them first in, first out).
+# ---------------------------------------------------------------------------
+_CHUNK = 8
+_PROMPT_LENS = (16, 32, 16, 32, 16, 32)    # whole chunks: no budget is
+#                                            left over for the next prompt
+
+
+def _tiny_paged(**kw):
+    import jax
+
+    from ray_tpu.models import configs, init_params
+    from ray_tpu.serve.llm import PagedLLMEngine
+
+    cfg = configs.get("tiny")
+    kw.setdefault("num_slots", 8)
+    kw.setdefault("max_len", 64)
+    kw.setdefault("block_size", 4)
+    kw.setdefault("prefill_chunk", _CHUNK)
+    kw.setdefault("prefix_sharing", False)
+    return PagedLLMEngine(cfg, init_params(jax.random.key(0), cfg), **kw)
+
+
+def _generate_together(eng, prompts, traces, max_tokens=6):
+    import threading
+
+    outs = [None] * len(prompts)
+
+    def one(i):
+        outs[i] = eng.generate(prompts[i], max_tokens=max_tokens,
+                               timeout=120, trace=traces[i])
+
+    threads = [threading.Thread(target=one, args=(i,))
+               for i in range(len(prompts))]
+    with eng._tick_lock:
+        for i, t in enumerate(threads):
+            t.start()
+            deadline = time.monotonic() + 30
+            while len(eng._pending) <= i and time.monotonic() < deadline:
+                time.sleep(0.001)
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    return outs
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["traced", "kill_switch_off"])
+def six_requests(request):
+    from ray_tpu.core import config as cfg_mod
+
+    traced = request.param
+    minted = []
+    real_init = tracing.Span.__init__
+
+    def counting_init(self, name, *a, **kw):
+        real_init(self, name, *a, **kw)
+        minted.append(self)
+
+    with pytest.MonkeyPatch.context() as mp:
+        if not traced:
+            mp.setenv("RAY_TPU_SERVE_TRACE_ENABLED", "0")
+            cfg_mod.reset_config()
+        mp.setattr(tracing.Span, "__init__", counting_init)
+        eng = _tiny_paged()
+        try:
+            rids = [f"rid-phases-{os.getpid()}-{i}"
+                    for i in range(len(_PROMPT_LENS))]
+            prompts = [[100 * i + j + 1 for j in range(n)]
+                       for i, n in enumerate(_PROMPT_LENS)]
+            outs = _generate_together(
+                eng, prompts, [tracing.serve_ctx(r) for r in rids])
+            stats = eng.engine_stats()
+            counters = eng.engine_stats(records=False)
+        finally:
+            eng.shutdown()
+    cfg_mod.reset_config()
+    assert all(len(o) == 6 for o in outs)
+    return {"traced": traced, "rids": rids, "stats": stats,
+            "counters": counters,
+            "minted": [s for s in minted
+                       if s.name.startswith("serve.engine.")]}
+
+
+def test_request_phases_sum_to_the_engine_ttft(six_requests):
+    recs = six_requests["stats"]["request_phases"]
+    assert len(recs) == len(_PROMPT_LENS)
+    for r in recs:
+        assert set(r) == {"id", "submitted", "queue_wait_s",
+                          "prefill_wait_s", "prefill_span_s", "ttft_s"}
+        assert r["queue_wait_s"] + r["prefill_wait_s"] \
+            + r["prefill_span_s"] == pytest.approx(r["ttft_s"], abs=1e-6)
+        assert min(r["queue_wait_s"], r["prefill_wait_s"],
+                   r["prefill_span_s"]) >= 0.0
+    # one prefill lane, first in first out: a prompt's first chunk waits
+    # for the whole prefill span of the prompt before it
+    for ahead, behind in zip(recs, recs[1:]):
+        assert behind["submitted"] >= ahead["submitted"]
+        assert behind["submitted"] + behind["queue_wait_s"] \
+            + behind["prefill_wait_s"] >= ahead["submitted"] \
+            + ahead["ttft_s"] - 1e-6
+    # the record fills with tracing off too; only the ids go
+    assert [r["id"] for r in recs] == (
+        six_requests["rids"] if six_requests["traced"]
+        else [None] * len(recs))
+
+
+def test_spans_are_minted_only_while_tracing_is_on(six_requests):
+    minted = six_requests["minted"]
+    if not six_requests["traced"]:
+        assert minted == []
+        return
+    names = [s.name for s in minted]
+    n = len(_PROMPT_LENS)
+    for name, count in (("queue_wait", n), ("prefill_wait", n),
+                        ("prefill", n),
+                        ("prefill_chunk", sum(_PROMPT_LENS) // _CHUNK)):
+        assert names.count("serve.engine." + name) == count, name
+    # the prefill span sums its chunks: their count, their tokens and
+    # their own wall time, which its length covers
+    spans = [s for s in minted if s.name == "serve.engine.prefill"]
+    recs = six_requests["stats"]["request_phases"]
+    for s, r, n_prompt in zip(spans, recs, _PROMPT_LENS):
+        assert s.attrs["tokens"] == n_prompt
+        assert s.attrs["chunks"] == -(-n_prompt // _CHUNK)
+        assert 0.0 <= s.attrs["chunk_s"] <= r["prefill_span_s"] + 1e-9
+
+
+def test_p_ttft_mean_is_the_mean_of_the_record(six_requests):
+    stats = six_requests["stats"]
+    recs = stats["request_phases"]
+    assert stats["p_ttft_mean"] == pytest.approx(
+        sum(r["ttft_s"] for r in recs) / len(recs), abs=1e-12)
+    assert "ttft_sum" not in stats
+    # the gauge loop's view: the counters, without the logs
+    counters = six_requests["counters"]
+    assert counters["completed"] == len(_PROMPT_LENS)
+    assert not {"request_phases", "tick_log", "tick_fields",
+                "p_ttft_mean"} & set(counters)
+    assert set(counters) | {"request_phases", "tick_log", "tick_fields",
+                            "p_ttft_mean"} == set(stats)
+
+
+def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
+    from ray_tpu.serve.llm import TICK_FIELDS
+
+    stats = six_requests["stats"]
+    assert stats["tick_fields"] == TICK_FIELDS
+    ticks = [dict(zip(TICK_FIELDS, t)) for t in stats["tick_log"]]
+    assert ticks
+    for t in ticks:
+        assert t["tick_s"] >= t["decode_s"] + t["prefill_s"] \
+            + t["sample_s"] >= 0.0
+        assert t["lanes"] <= t["width"]
+        assert t["prefill_tokens"] <= _CHUNK
+        assert t["lanes"] or t["prefill_tokens"]    # it progressed
+    assert [t["start"] for t in ticks] == sorted(t["start"] for t in ticks)
+    assert sum(t["prefill_tokens"] for t in ticks) == sum(_PROMPT_LENS)
+    # every prompt token went through a chunk the stats counted
+    assert stats["prefill_chunks"] == sum(_PROMPT_LENS) // _CHUNK
+    # a tick's decode burst is launched before its chunk, so the first
+    # tick has no lanes yet.  (The tick that ends the last request may
+    # still be writing its record when generate() returns.)
+    assert ticks[0]["lanes"] == 0
+    # one read of a first token for each prompt, in the tick that
+    # launched its last chunk
+    assert sum(t["sample_s"] > 0.0 for t in ticks) == len(_PROMPT_LENS)
+    assert all(t["prefill_tokens"] for t in ticks if t["sample_s"])
+
+
+@pytest.mark.parametrize("six_requests", [True], indirect=True,
+                         ids=["traced"])
+def test_request_spans_are_contiguous_and_in_order(six_requests):
+    rid = six_requests["rids"][1]            # a prompt that waited
+    rec = six_requests["stats"]["request_phases"][1]
+    n_chunks = _PROMPT_LENS[1] // _CHUNK
+    spans = _poll_spans(
+        rid, {"serve.engine.prefill", "serve.engine.decode_burst"},
+        pred=lambda ss: sum(s["name"] == "serve.engine.prefill_chunk"
+                            for s in ss) == n_chunks)
+    by = {}
+    for s in sorted(spans, key=lambda s: (s["start_ts"], s["end_ts"])):
+        by.setdefault(s["name"].rpartition(".")[2], []).append(s)
+    (queue,), (wait,), (prefill,) = (by["queue_wait"], by["prefill_wait"],
+                                     by["prefill"])
+    chunks, bursts = by["prefill_chunk"], by["decode_burst"]
+    assert len(chunks) == n_chunks
+    # queue_wait | prefill_wait | prefill share their edges exactly
+    assert queue["start_ts"] == rec["submitted"]
+    assert queue["end_ts"] == wait["start_ts"]
+    assert wait["end_ts"] == prefill["start_ts"] == chunks[0]["start_ts"]
+    assert prefill["end_ts"] - queue["start_ts"] == pytest.approx(
+        rec["ttft_s"], abs=1e-6)
+    assert wait["end_ts"] - wait["start_ts"] == pytest.approx(
+        rec["prefill_wait_s"], abs=1e-6)
+    # the chunks lie inside their parent, one after the other
+    assert all(c["parent_id"] == prefill["span_id"] for c in chunks)
+    assert all(a["end_ts"] <= b["start_ts"]
+               for a, b in zip(chunks, chunks[1:]))
+    assert chunks[-1]["end_ts"] <= prefill["end_ts"]
+    assert prefill["attrs"]["chunks"] == n_chunks
+    assert prefill["attrs"]["tokens"] == _PROMPT_LENS[1]
+    assert prefill["attrs"]["chunk_s"] == pytest.approx(
+        sum(c["end_ts"] - c["start_ts"] for c in chunks), abs=1e-6)
+    # decode bursts follow the first token
+    assert bursts and bursts[0]["start_ts"] >= prefill["end_ts"]
+    assert prefill["parent_id"] == queue["parent_id"] == wait["parent_id"]
+
+
+def test_an_idle_iteration_leaves_no_tick_record():
+    eng = _tiny_paged()
+    try:
+        eng._stop = True            # park the loop: ticks by hand
+        eng._work.set()
+        eng._thread.join(timeout=10)
+        assert not eng._thread.is_alive()
+        with eng._tick_lock:
+            assert eng._tick() is False
+        assert eng.engine_stats()["tick_log"] == ()
+        assert eng.engine_stats()["request_phases"] == ()
+        assert eng.engine_stats()["p_ttft_mean"] is None
+    finally:
+        eng.shutdown()
+
+
+def test_a_preempted_requests_reprefill_adds_no_ttft_record():
+    # The pool deadlock of tests/test_paged_kv.py: both requests stall on
+    # growth blocks, the younger is preempted and prefills its whole
+    # context again, after its first token.
+    eng = _tiny_paged(num_slots=2, max_len=32, prefill_chunk=16,
+                      max_burst=4, num_blocks=9)
+    minted = []
+    real_init = tracing.Span.__init__
+
+    def keeping_init(self, name, *a, **kw):
+        real_init(self, name, *a, **kw)
+        minted.append(self)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tracing.Span, "__init__", keeping_init)
+        try:
+            prompts = [list(range(1, 9)), list(range(101, 109))]
+            outs = _generate_together(
+                eng, prompts, [tracing.serve_ctx(f"rid-preempt-{i}")
+                               for i in range(2)], max_tokens=16)
+            stats = eng.engine_stats()
+        finally:
+            eng.shutdown()
+    assert all(len(o) == 16 for o in outs)
+    assert stats["preemptions"] >= 1
+    assert len(stats["request_phases"]) == 2
+    assert [s.attrs["chunks"] for s in minted
+            if s.name == "serve.engine.prefill"] == [1, 1]
+    chunks = [s for s in minted if s.name == "serve.engine.prefill_chunk"]
+    again = [s for s in chunks if s.attrs.get("resumed")]
+    assert len(again) >= 1 and len(chunks) - len(again) == 2
+    assert sum(s.name == "serve.engine.prefill" for s in minted) == 2
+    # the re-prefill's tokens are in the tick log all the same
+    fields = stats["tick_fields"]
+    assert sum(dict(zip(fields, t))["prefill_tokens"]
+               for t in stats["tick_log"]) > sum(map(len, prompts))
+
+
+def test_snapshot_survives_appends_from_the_engine_thread():
+    import collections
+    import sys
+    import threading
+
+    from ray_tpu.serve.llm import _snapshot
+
+    log = collections.deque(maxlen=64)
+    stop = threading.Event()
+
+    def append():
+        i = 0
+        while not stop.is_set():
+            log.append((i, i))
+            i += 1
+
+    writers = [threading.Thread(target=append) for _ in range(3)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for w in writers:
+            w.start()
+        deadline = time.monotonic() + 1.0
+        while time.monotonic() < deadline:
+            snap = _snapshot(log)
+            assert len(snap) <= 64
+            assert all(a == b for a, b in snap)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        for w in writers:
+            w.join(timeout=10)
+    assert not any(w.is_alive() for w in writers)
 
 
 # ---------------------------------------------------------------------------

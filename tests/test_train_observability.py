@@ -48,27 +48,53 @@ def _recorder(**kw):
 # StepPhaseRecorder unit layer
 # ---------------------------------------------------------------------------
 
-def test_recorder_phase_math():
+class _SteppedTime:
+    """`time` as ray_tpu.train.observability reads it, with a
+    perf_counter that moves only when the test moves it: the phase
+    arithmetic is then exact, whatever the machine is doing."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_recorder_phase_math(monkeypatch):
+    clock = _SteppedTime()
+    monkeypatch.setattr(obs, "time", clock)
     rec = _recorder()
     for _ in range(3):
         with obs.step(rec):
+            clock.now += 0.001            # attributed to no phase
             with rec.phase("compute"):
-                time.sleep(0.02)
+                clock.now += 0.02
             with rec.phase("sync"):
-                time.sleep(0.005)
+                clock.now += 0.005
+            clock.now += 0.002
     snap = rec.snapshot()
     assert snap["steps"] == 3
-    assert snap["compute_s"] >= 3 * 0.02
-    assert snap["sync_s"] >= 3 * 0.005
+    assert snap["compute_s"] == pytest.approx(3 * 0.02, abs=1e-6)
+    assert snap["sync_s"] == pytest.approx(3 * 0.005, abs=1e-6)
     # The unattributed remainder goes to `other`, never negative, and
     # the phase sum never exceeds the step wall.
-    assert snap["other_s"] >= 0.0
+    assert snap["other_s"] == pytest.approx(3 * 0.003, abs=1e-6)
+    assert snap["step_s"] == pytest.approx(3 * 0.028, abs=1e-6)
     assert (snap["compute_s"] + snap["sync_s"] + snap["other_s"]
             <= snap["step_s"] + 1e-6)
     # other counts as productive: a stall you did not measure cannot
     # be blamed on the input pipeline.
-    assert snap["busy_fraction"] > 0.7
+    assert snap["busy_fraction"] == pytest.approx(
+        (0.02 + 0.003) / 0.028, abs=1e-4)
     assert snap["window_steps"] == 3
+    # Phases charged beyond the step's wall leave `other` at zero.
+    with obs.step(rec):
+        clock.now += 0.01
+        rec.add_phase("compute", 0.05)
+    assert rec.snapshot()["other_s"] == snap["other_s"] >= 0.0
 
 
 def test_recorder_implicit_step_closed_by_report():
