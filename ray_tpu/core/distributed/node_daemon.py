@@ -32,7 +32,8 @@ from ray_tpu.core.config import get_config
 from ray_tpu.core.ids import ObjectID
 from ray_tpu.core.object_store import ObjectExistsError, ObjectStore
 from ray_tpu.core.distributed import resources as rs
-from ray_tpu.core.distributed.rpc import AsyncRpcClient, RpcServer
+from ray_tpu.core.distributed.rpc import (
+    AsyncRpcClient, RpcError, RpcServer)
 from ray_tpu.core.distributed.transfer import (
     ChunkSink, chunk_ranges, make_transfer_metrics, plan_broadcast_tree)
 from ray_tpu.core.distributed.wire import Raw
@@ -2041,6 +2042,15 @@ class NodeDaemon:
                 max_concurrency=max_concurrency,
                 concurrency_groups=concurrency_groups,
                 timeout=get_config().actor_creation_timeout_s)
+        except RpcError as e:
+            if not isinstance(e.__cause__, OSError):
+                raise
+            # The connect itself failed: a pooled worker that died after it
+            # went idle (a zygote's zombie reads as alive for up to a reap
+            # cycle).  No verdict on the actor: without this the handle kept
+            # the actor's id and the reaper reported the ACTOR dead.
+            reply = {"ok": False, "unreachable": True,
+                     "error": f"actor worker unreachable: {e}"}
         finally:
             await client.close()
         if not reply.get("ok"):
@@ -2048,7 +2058,7 @@ class NodeDaemon:
             self._workers.pop(handle.worker_id, None)
             self._release_demand(demand, placement)
             return {"ok": False, "error": reply.get("error"),
-                    "creation_error": True}
+                    "creation_error": not reply.get("unreachable")}
         # Track so the demand is returned if/when the actor dies.
         lease_id = f"actor-{actor_id}"
         self._leases[lease_id] = Lease(lease_id, demand, handle, placement)

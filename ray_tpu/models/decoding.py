@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops.attention import paged_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
@@ -437,6 +438,14 @@ def make_spec_fns(cfg: TransformerConfig, donate: bool = True):
 # allocator while the decode step stays a single fused program
 # (arXiv:2011.03641: keep the compiled step shape-stable).
 #
+# A served step never moves the pool.  Decode, prefill chunk and verify are
+# one body (`_paged_forward`): the whole pool is the carry of the layer loop
+# (and of the burst's step loop), each layer scatters its new tokens' KV at
+# [layer, block, offset] and then reads, per lane, only the blocks below the
+# lane's length, in the cache dtype (`ops.attention.paged_attention`).  With
+# the cache donated, XLA does all of it in the one buffer: what a step moves
+# is the weights and the live KV, whatever the pool's and the table's size.
+#
 # Convention: pool block 0 is the NULL block.  The allocator never hands it
 # out; unallocated table entries and inactive slots point at it, so every
 # gather/scatter is in-bounds without conditionals.  Writes routed to block
@@ -461,6 +470,49 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                         v=jnp.zeros(shape, dtype))
 
 
+def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
+                   block_tables: jax.Array, positions: jax.Array,
+                   kv_len: jax.Array, cfg: TransformerConfig):
+    """The one served step: `tokens` (S, K) at absolute `positions` (S, K)
+    through every layer, over the tables (S, B_max) of the lanes' blocks.
+    `kv_len` (S,) is each lane's length once its tokens are in (0: an idle
+    lane, which writes the null block).  Returns (cache, hidden (S, K, d)).
+
+    Write-then-read, in place: a layer scatters the tokens' KV into the
+    pool at [layer, table[pos // bs], pos % bs] first, so the attention
+    that follows finds them there and its mask is simply kv_pos <= pos,
+    for the context and the in-call causal prefix alike.  The pool is the
+    layer loop's carry, never its xs/ys: no slice of it is taken out or
+    stacked back.
+    """
+    cd = cfg.compute_dtype
+    bs = cache.k.shape[2]
+    live = (kv_len > 0)[:, None]
+    wb = jnp.where(live, jnp.take_along_axis(
+        block_tables, positions // bs, axis=1), 0)         # (S, K)
+    off = jnp.where(live, positions % bs, 0)
+    x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
+
+    def layer(carry, layer_in):
+        x, k_pool, v_pool = carry
+        bp, li = layer_in
+        q, k, v = _qkv(bp, x, cfg, positions)              # (S,K,H,D)
+        k_pool = k_pool.at[li, wb, off].set(k.astype(k_pool.dtype))
+        v_pool = v_pool.at[li, wb, off].set(v.astype(v_pool.dtype))
+        attn = paged_attention(q, k_pool, v_pool, li, block_tables,
+                               positions, kv_len)
+        attn = attn.reshape(*tokens.shape, cfg.n_heads * cfg.head_dim)
+        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
+                           bp["wo"].astype(cd))
+        x = x + _mlp(bp, x, cfg)
+        return (x, k_pool, v_pool), None
+
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        layer, (x, cache.k, cache.v),
+        (params["blocks"], jnp.arange(cfg.n_layers)))
+    return PagedKVCache(k=k_pool, v=v_pool), x
+
+
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
                       block_tables: jax.Array, lengths: jax.Array,
                       active: jax.Array, cfg: TransformerConfig
@@ -469,55 +521,16 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     block_tables (S, B_max) int32, lengths (S,) int32, active (S,) bool.
     Returns (cache, logits (S, vocab)).
 
-    Scatter-then-gather: each slot's new KV is written to
-    table[len // bs] at offset len % bs FIRST, so the gathered window
-    already contains it and the mask is simply kv_pos <= len.  Inactive
-    slots write the null block and read garbage that the engine drops.
+    Each slot's new KV goes to table[len // bs] at offset len % bs and the
+    slot then attends to its len + 1 positions; what is read is the blocks
+    below the longest active slot's length, not the table's width.
+    Inactive slots write the null block and return garbage that the
+    engine drops.
     """
-    cd = cfg.compute_dtype
-    s_count = tokens.shape[0]
-    bs = cache.k.shape[2]
-    b_max = block_tables.shape[1]
-    t_w = b_max * bs
-    pos = lengths                                        # (S,)
-    positions = pos[:, None]                             # (S, 1)
-    x = params["embed"].astype(cd)[tokens[:, None]]      # (S, 1, d)
-    wb = jnp.take_along_axis(block_tables, (pos // bs)[:, None],
-                             axis=1)[:, 0]               # (S,)
-    wb = jnp.where(active, wb, 0)
-    off = jnp.where(active, pos % bs, 0)
-    kv_pos = jnp.arange(t_w)
-    attn_mask = kv_pos[None, None, :] <= positions[:, :, None]  # (S,1,T_w)
-
-    def layer(carry, layer_in):
-        x = carry
-        bp, k_cache, v_cache = layer_in                  # (N,bs,Hkv,D)
-        q, k, v = _qkv(bp, x, cfg, positions)            # (S,1,H,D)
-        k_cache = k_cache.at[wb, off].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[wb, off].set(v[:, 0].astype(v_cache.dtype))
-        kb = k_cache[block_tables]                       # (S,B,bs,Hkv,D)
-        vb = v_cache[block_tables]
-        kh = kb.reshape(s_count, t_w, *kb.shape[3:])
-        vh = vb.reshape(s_count, t_w, *vb.shape[3:])
-        if cfg.n_kv_heads != cfg.n_heads:
-            rep = cfg.n_heads // cfg.n_kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
-        s = jnp.einsum("sqhd,sthd->sqht", q.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
-        s = jnp.where(attn_mask[:, :, None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("sqht,sthd->sqhd", p, vh.astype(jnp.float32))
-        attn = attn.reshape(s_count, 1, cfg.n_heads * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
-        return x, (k_cache, v_cache)
-
-    x, new_kv = jax.lax.scan(layer, x, (params["blocks"], cache.k, cache.v))
-    new_k, new_v = new_kv
-    logits = _final_logits(params, x, cfg)[:, 0]         # (S, vocab)
-    return PagedKVCache(k=new_k, v=new_v), logits
+    cache, x = _paged_forward(
+        params, cache, tokens[:, None], block_tables, lengths[:, None],
+        jnp.where(active, lengths + 1, 0), cfg)
+    return cache, _final_logits(params, x, cfg)[:, 0]      # (S, vocab)
 
 
 def paged_decode_and_sample(params, cache: PagedKVCache, tokens,
@@ -535,6 +548,7 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
     """`n_steps` fused paged decode+sample ticks in one device call.
     Block tables are static across the burst — the engine pre-extends
     each active slot's table to cover lengths + n_steps before issuing.
+    The pool is the step loop's carry too: n_steps in-place writes.
     Returns (cache, token_matrix (n_steps, S), rng)."""
 
     def tick(carry, _):
@@ -556,53 +570,19 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
                         ) -> Tuple[PagedKVCache, jax.Array]:
     """One chunk of a prompt through the block pool: tokens (C,) (padded
     with zeros past `n_valid`), block_tables (B_max,), start = absolute
-    position of tokens[0].  Chunk KV scatters into the table's blocks at
-    positions start..start+C-1; attention covers the already-prefilled
-    context (kv_pos < start) plus the in-chunk causal prefix — both fall
-    out of the single mask kv_pos <= start+i after the scatter.  Padded
+    position of tokens[0].  The one-lane, C-wide case of the served step:
+    chunk KV goes into the table's blocks at positions start..start+C-1,
+    and attention reads the blocks below start + n_valid: the
+    already-prefilled context plus the in-chunk causal prefix.  Padded
     positions write garbage that the next chunk overwrites and no real
     query's mask reaches.  Returns (cache, logits of token n_valid-1
     (vocab,)) — the engine samples from the FINAL chunk's logits.
     """
-    cd = cfg.compute_dtype
-    c = tokens.shape[0]
-    bs = cache.k.shape[2]
-    t_w = block_tables.shape[0] * bs
-    positions = start + jnp.arange(c, dtype=jnp.int32)   # (C,)
-    x = params["embed"].astype(cd)[tokens][None]         # (1, C, d)
-    wb = block_tables[positions // bs]                   # (C,)
-    off = positions % bs
-    kv_pos = jnp.arange(t_w)
-    attn_mask = kv_pos[None, :] <= positions[:, None]    # (C, T_w)
-
-    def layer(carry, layer_in):
-        x = carry
-        bp, k_cache, v_cache = layer_in
-        q, k, v = _qkv(bp, x, cfg, positions)            # (1,C,H,D)
-        k_cache = k_cache.at[wb, off].set(k[0].astype(k_cache.dtype))
-        v_cache = v_cache.at[wb, off].set(v[0].astype(v_cache.dtype))
-        kh = k_cache[block_tables].reshape(t_w, *k_cache.shape[2:])[None]
-        vh = v_cache[block_tables].reshape(t_w, *v_cache.shape[2:])[None]
-        if cfg.n_kv_heads != cfg.n_heads:
-            rep = cfg.n_heads // cfg.n_kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
-        s = jnp.where(attn_mask[None, None], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", p, vh.astype(jnp.float32))
-        attn = attn.reshape(1, c, cfg.n_heads * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
-        return x, (k_cache, v_cache)
-
-    x, new_kv = jax.lax.scan(layer, x, (params["blocks"], cache.k, cache.v))
-    new_k, new_v = new_kv
-    logits = _final_logits(params, x, cfg)[0]            # (C, vocab)
-    last = logits[n_valid - 1]
-    return PagedKVCache(k=new_k, v=new_v), last
+    positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    cache, x = _paged_forward(
+        params, cache, tokens[None], block_tables[None], positions[None],
+        (start + n_valid)[None], cfg)
+    return cache, _final_logits(params, x, cfg)[0, n_valid - 1]
 
 
 def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
@@ -611,7 +591,8 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
                       cfg: TransformerConfig):
     """Speculative verification through the block pool: K candidate
     tokens PER SLOT in one call (the paged analogue of `verify_step` —
-    same prompt-lookup drafting, same greedy acceptance rule).
+    same prompt-lookup drafting, same greedy acceptance rule), the
+    K-wide case of the served step.
 
     cand_tokens (S, K): column 0 is each slot's last sampled token
     (whose KV is not yet written), columns 1..K-1 the proposals.
@@ -621,7 +602,7 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     a decode burst).
 
     Returns (cache, tok_out (S, K), accepted (S,)).  KV for ALL K
-    candidates scatters into the slot's OWN blocks at positions
+    candidates goes into the slot's OWN blocks at positions
     lengths..lengths+K-1 — rejected drafts need no device rollback:
     the engine advances lengths by accepted+1 and every paged mask
     (kv_pos <= position) treats the stale tail as garbage until the
@@ -629,46 +610,11 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     owned by construction (COW at decode start + fresh growth allocs),
     so stale writes can never corrupt a registered/shared prefix.
     """
-    cd = cfg.compute_dtype
-    s_count, k_w = cand_tokens.shape
-    bs = cache.k.shape[2]
-    t_w = block_tables.shape[1] * bs
+    k_w = cand_tokens.shape[1]
     positions = lengths[:, None] + jnp.arange(k_w, dtype=jnp.int32)  # (S,K)
-    x = params["embed"].astype(cd)[cand_tokens]          # (S, K, d)
-    wb = jnp.take_along_axis(block_tables, positions // bs,
-                             axis=1)                     # (S, K)
-    wb = jnp.where(active[:, None], wb, 0)
-    off = jnp.where(active[:, None], positions % bs, 0)
-    kv_pos = jnp.arange(t_w)
-    attn_mask = kv_pos[None, None, :] <= positions[:, :, None]  # (S,K,T_w)
-
-    def layer(carry, layer_in):
-        x = carry
-        bp, k_cache, v_cache = layer_in                  # (N,bs,Hkv,D)
-        q, k, v = _qkv(bp, x, cfg, positions)            # (S,K,H,D)
-        k_cache = k_cache.at[wb, off].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[wb, off].set(v.astype(v_cache.dtype))
-        kb = k_cache[block_tables]                       # (S,B,bs,Hkv,D)
-        vb = v_cache[block_tables]
-        kh = kb.reshape(s_count, t_w, *kb.shape[3:])
-        vh = vb.reshape(s_count, t_w, *vb.shape[3:])
-        if cfg.n_kv_heads != cfg.n_heads:
-            rep = cfg.n_heads // cfg.n_kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
-        s = jnp.einsum("sqhd,sthd->sqht", q.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
-        s = jnp.where(attn_mask[:, :, None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("sqht,sthd->sqhd", p, vh.astype(jnp.float32))
-        attn = attn.reshape(s_count, k_w, cfg.n_heads * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
-        return x, (k_cache, v_cache)
-
-    x, new_kv = jax.lax.scan(layer, x, (params["blocks"], cache.k, cache.v))
-    new_k, new_v = new_kv
+    cache, x = _paged_forward(
+        params, cache, cand_tokens, block_tables, positions,
+        jnp.where(active, lengths + k_w, 0), cfg)
     logits = _final_logits(params, x, cfg)               # (S, K, vocab)
     # Same acceptance rule as the contiguous verify_step: proposal i is
     # correct iff the model's greedy token at the previous position
@@ -682,7 +628,7 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     rng, sub = jax.random.split(rng)
     first_sampled = sample_per_slot(logits[:, 0], sub, temps)
     tok_out = greedy.at[:, 0].set(first_sampled)
-    return PagedKVCache(k=new_k, v=new_v), tok_out, accepted, rng
+    return cache, tok_out, accepted, rng
 
 
 def make_paged_spec_fns(cfg: TransformerConfig, donate: bool = True):

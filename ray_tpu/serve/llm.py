@@ -1070,10 +1070,13 @@ class PagedLLMEngine(_EngineBase):
                 nv = min(nv, c)
                 toks = np.zeros((c,), np.int32)
                 toks[:nv] = ctx[req.pos:req.pos + nv]
+                # The row is copied: on the CPU backend `jnp.asarray` of
+                # a view shares the host's memory with a program that has
+                # only been launched, and `_cow_tail` below rewrites it.
                 self.cache, last_logits = self._prefill_chunk_fn(
                     self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self._tables[slot]), jnp.int32(req.pos),
-                    jnp.int32(nv))
+                    jnp.asarray(self._tables[slot].copy()),
+                    jnp.int32(req.pos), jnp.int32(nv))
                 req.pos += nv
                 budget -= nv
                 progressed = True

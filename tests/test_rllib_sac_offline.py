@@ -16,6 +16,19 @@ from ray_tpu.rllib.offline import (
 )
 
 
+@pytest.fixture(autouse=True)
+def _shut_down_what_a_test_started():
+    """`record_rollouts` and the offline algorithms call `ray_tpu.init`
+    themselves and nothing here shut it down, so this module handed an
+    initialised runtime to whichever module its worker ran next; one whose
+    fixture calls `init` without `ignore_reinit_error`
+    (test_job_submission, test_lineage) then failed at set-up."""
+    import ray_tpu
+
+    yield
+    ray_tpu.shutdown()
+
+
 def test_pendulum_vec_env_contract():
     env = PendulumVecEnv(num_envs=3, seed=0)
     obs = env.reset()

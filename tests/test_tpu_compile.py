@@ -11,6 +11,7 @@ the run on the chip.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
@@ -173,11 +174,11 @@ def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-def _serve_shapes(topo, num_slots=8, max_len=2048, block_size=16):
+def _serve_shapes(topo, cfg=None, num_slots=8, max_len=2048, block_size=16):
     from ray_tpu.models import init_params
     from ray_tpu.models.decoding import init_paged_cache
 
-    cfg = configs.get("bench-1b4")
+    cfg = cfg or configs.get("bench-1b4")
     one = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(tree):
@@ -223,3 +224,63 @@ def test_paged_prefill_chunk_fits_one_chip(topo):
         params, cache, arr((128,), jnp.int32), arr((b_max,), jnp.int32),
         arr((), jnp.int32), arr((), jnp.int32)).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_served_step_does_not_copy_the_pool(topo, program):
+    """Mistral-7B at the benchmark's serving shape (16 slots x 4096, block
+    16: a 2.15 GB pool; widest burst, 128-token chunk): the pool is updated
+    in place and only live blocks are read, so the program's temporaries
+    are its weight relayouts and one group of blocks.  They were 5.84 GB
+    (burst) and 2.69 GB (chunk) when the pool's slices went through the
+    layer scan and the whole table width was gathered in float32.  In the
+    optimised HLO nothing but the in-place scatter makes an array of the
+    pool's shape, and no float32 array is as large as one lane's KV
+    window (the old body held (S, T, Hkv, rep, D) of them)."""
+    import json
+    import re
+
+    from bench.harness.spec import BENCH_DIR, transformer_config
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    with open(os.path.join(BENCH_DIR, "configs",
+                           "mistral-7b-serve-1chip.json")) as f:
+        config = json.load(f)
+    eng = config["engine"]
+    cfg, params, cache, b_max, arr = _serve_shapes(
+        topo, transformer_config(config), eng["num_slots"], eng["max_len"],
+        eng["block_size"])
+    w = eng["num_slots"]
+    chunk, burst, _ = make_paged_engine_fns(cfg)
+    if program == "paged_decode_burst":
+        lowered = burst.lower(
+            params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
+            arr((w,), jnp.int32), arr((w,), jnp.bool_),
+            arr((w,), jnp.float32), arr((), jax.random.key(0).dtype),
+            n_steps=eng["max_burst"])
+    else:
+        lowered = chunk.lower(
+            params, cache, arr((eng["prefill_chunk"],), jnp.int32),
+            arr((b_max,), jnp.int32), arr((), jnp.int32),
+            arr((), jnp.int32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(s.size * s.dtype.itemsize
+                     for s in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 10**9, mem.temp_size_in_bytes
+
+    hlo = compiled.as_text()
+    pool = "bf16[" + ",".join(map(str, cache.k.shape)) + "]"
+    makers = set(re.findall(
+        r"= " + re.escape(pool) + r"\S* ([a-z-]+)\(", hlo))
+    # `fusion` here is the scatter's own (its root is the `scatter`).
+    assert "scatter" in makers
+    assert makers <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                      "bitcast"}, makers
+    window = eng["max_len"] * cfg.n_kv_heads * cfg.head_dim
+    largest = max(
+        (math.prod(map(int, dims.split(","))), dims)
+        for dims in re.findall(r"f32\[([0-9,]+)\]", hlo))
+    assert largest[0] < window, largest
