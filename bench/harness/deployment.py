@@ -5,14 +5,14 @@ cannot yet do for itself.  Nothing here changes scheduling.
     dtype, through `params_loader`;
   - compilation of only the tiers the cell's traffic can reach, through
     the engine's own `warmup()`;
-  - the logits check against the plain float32 reference (`correct`);
+  - the logits check against the family's plain float32 reference
+    (`correct`);
   - compile counts, engine spans of each request, device facts;
   - with tracing only: `jax.profiler` start/stop in this process, and
     `TraceAnnotation`s around the engine's tick phases.
 """
 from __future__ import annotations
 
-import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -24,82 +24,63 @@ _TICK_PHASES = {"_admit_one": "bench.engine.admit",
 _ENGINE_SPANS = ("serve.engine.queue_wait", "serve.engine.prefill_chunk")
 
 
+# The logits check's sizes, where the configuration file has no `check`
+# block of its own: 4 seeded prompts of 256 tokens, 16 decode steps.
+CHECK_SIZES = {"lanes": 4, "prompt_len": 256, "decode_steps": 16}
+
+
 def logits_check(e, c: Dict[str, Any], seed: int) -> Dict[str, Any]:
-    """`correct`, for the engine `e` of configuration `c`: prefill of 4
-    seeded prompts of 256 tokens through the engine's own jitted chunk
-    program, then 16 teacher-forced decode steps through the paged cache
-    with the program's `paged_decode_step` (the function
-    `paged_decode_burst` scans: the burst itself returns sampled tokens,
-    never logits), the four lanes in one width-4 call, against the plain
-    float32 forward over all 272 tokens of each: 68 positions.  (ISSUE
-    23 asked for 2 prompts and 8 steps; a model with experts needs more
-    positions, reference.py says why.)"""
+    """`correct`, for the engine `e` of configuration `c`: seeded
+    sequences scored by the engine's own programs (the family's `score`:
+    prefill of each prompt, then teacher-forced decode steps, every lane
+    in one call a step) against the family's plain float32 `forward`
+    over all tokens of each, at the last prefill position and every
+    decode step: 4 x (1 + 16) = 68 positions at the default sizes.
+    (ISSUE 23 asked for 2 prompts and 8 steps; a model with experts
+    needs more positions, reference.py says why.)  What is compared, the
+    seeds, the precision of the reference and the verdict are the same
+    for every family; the sizes are the configuration's (`check`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from bench.harness import reference
-    from ray_tpu.models.decoding import paged_decode_step
+    from bench.harness import reference, spec
 
-    n_decode, lanes, width = 16, 4, 4
-    n_prompt = min(256, e.max_len // 2)   # 256 at any real size
+    fam = spec.family(c)
+    sizes = {**CHECK_SIZES, **c.get("check", {})}
+    lanes, n_decode = sizes["lanes"], sizes["decode_steps"]
+    n_prompt = min(sizes["prompt_len"], e.max_len // 2)
     rng = np.random.default_rng(seed + 1)
     seqs = rng.integers(1, c["vocab_size"],
                         (lanes, n_prompt + n_decode), dtype=np.int64)
-    bs, chunk = e.block_size, e.prefill_chunk
-    per_lane = -(-(n_prompt + n_decode) // bs)
-    tables = np.zeros((width, e._b_max), np.int32)
-    for lane in range(lanes):       # blocks 1.. : 0 is the null block
-        tables[lane, :per_lane] = 1 + lane * per_lane + np.arange(per_lane)
-    step = jax.jit(functools.partial(paged_decode_step, cfg=e.cfg),
-                   donate_argnums=(1,))
-    got = {lane: [] for lane in range(lanes)}
-    with e._tick_lock:
-        for lane in range(lanes):
-            for start in range(0, n_prompt, chunk):
-                toks = seqs[lane, start:start + chunk].astype(np.int32)
-                e.cache, last = e._prefill_chunk_fn(
-                    e.params, e.cache, jnp.asarray(toks),
-                    jnp.asarray(tables[lane]), jnp.int32(start),
-                    jnp.int32(len(toks)))
-            got[lane].append(last)                 # position 255
-        active = np.arange(width) < lanes
-        for i in range(n_prompt, n_prompt + n_decode):
-            tok = np.zeros((width,), np.int32)
-            tok[:lanes] = seqs[:, i]
-            e.cache, logits = step(
-                e.params, e.cache, jnp.asarray(tok), jnp.asarray(tables),
-                jnp.asarray(np.where(active, i, 0).astype(np.int32)),
-                jnp.asarray(active))
-            for lane in range(lanes):
-                got[lane].append(logits[lane])     # position i
+    got = fam.score(e, c, seqs, n_prompt)
     errors, margins = [], []
     with jax.default_matmul_precision("highest"):
         for lane in range(lanes):
-            want, margin = reference.forward(
+            want, margin = fam.forward(
                 e.params, jnp.asarray(seqs[lane], jnp.int32), c, jit=jax.jit)
             errors += list(reference.position_errors(
                 jnp.stack(got[lane]), want[n_prompt - 1:]))
             margins += list(margin[n_prompt - 1:])
-    return reference.logits_verdict(errors, margins, c)
+    return reference.logits_verdict(errors, margins, fam)
 
 
 class BenchLLMDeployment(LLMDeployment):
     def __init__(self, config: Dict[str, Any], seed: int,
                  max_concurrency: int, trace: bool):
-        from bench.harness import device
-        from bench.harness.spec import transformer_config
+        from bench.harness import device, spec
 
         self._t = {"init_start": time.time()}
         self._counter = device.CompileCounter()
         self._marks: Dict[str, Dict[str, int]] = {}
-        cfg = transformer_config(config)
+        fam = spec.family(config)
+        cfg = fam.program_config(config)
         eng = config["engine"]
 
         def loader():
             import jax
 
-            params = device.seeded_params(cfg, seed)
+            params = device.seeded_params(fam, cfg, seed)
             jax.block_until_ready(params)
             self._t["params_ready"] = time.time()
             return params

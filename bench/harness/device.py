@@ -53,15 +53,14 @@ def seeded_key(seed: int):
         jax.random.key(seed & 0x7FFFFFFF, impl="rbg"), seed >> 31)
 
 
-def seeded_params(cfg, seed: int):
-    """The parameter tree, made on the device in one jitted call from the
+def seeded_params(family, cfg, seed: int):
+    """The parameter tree of the program configuration `cfg`, made on the
+    device in one jitted call of the family's `init_params` from the
     seed, in the configuration's `param_dtype` (the program's own
-    `init_params` called eagerly draws float32 leaf by leaf and casts)."""
+    initialiser called eagerly draws float32 leaf by leaf and casts)."""
     import jax
 
-    from ray_tpu.models.transformer import init_params
-
-    return jax.jit(lambda k: init_params(k, cfg))(seeded_key(seed))
+    return jax.jit(lambda k: family.init_params(k, cfg))(seeded_key(seed))
 
 
 def device_facts() -> Dict[str, Any]:

@@ -8,14 +8,17 @@ the metric out of the line.
 outcomes, setup_s, ...), `replica` (the replica's / worker's report:
 engine_spans, stats, host times), `trace` (xplane.reduce_planes' result)
 and `device` (platform, kind, count).
+
+The operations and bytes a step needs are the cell's family's to say
+(bench/families/<family>.py): the rooflines here hand it the
+configuration and the counter's event as they have it.
 """
 from __future__ import annotations
 
-import re
 from typing import Dict, Optional
 
-from bench.harness import costs
 from bench.harness.peaks import peaks
+from bench.harness.spec import family
 from bench.harness.stats import mean, median
 
 
@@ -103,8 +106,8 @@ def decode_roofline(ctx, program: str, counter: str):
     if not c or not p["count"]:
         return None
     cfg = ctx["cell"].config
-    burst = cfg["engine"]["max_burst"]
-    least = _per_launch(c, lambda ev: costs.decode_step_bytes(
+    fam, burst = family(cfg), cfg["engine"]["max_burst"]
+    least = _per_launch(c, lambda ev: fam.decode_step_bytes(
         cfg, ev["kv_tokens"] + ev["lanes"] * (burst - 1) / 2, ev["lanes"])
     ) / peaks(ctx["device"]["kind"])["hbm_bytes_per_s"]
     return 100.0 * least / (p["seconds"] / (p["count"] * burst))
@@ -112,24 +115,24 @@ def decode_roofline(ctx, program: str, counter: str):
 
 def moe_ffn_roofline(ctx, program: str, counter: str):
     """Expert weights a decode step needs (the experts its lanes are
-    routed to: costs.expected_routed_experts), at peak bandwidth, over
-    the device time of the expert FFN ops: the ops of `program` whose HLO
-    text reads an operand shaped like a layer's expert weights, [E,d,f]
-    or [E,f,d].  A program that reads all E experts for every token
-    stays far under 100% however fast it streams them."""
+    routed to: the family's `expert_bytes_per_step`), at peak bandwidth,
+    over the device time of the expert FFN ops: the ops of `program`
+    whose HLO text reads an operand shaped like a layer's expert weights
+    (the family's `expert_operand`; None where there are no experts).  A
+    program that reads all E experts for every token stays far under
+    100% however fast it streams them."""
     cfg = ctx["cell"].config
-    e = cfg.get("num_local_experts")
-    if not e:
+    fam = family(cfg)
+    shaped = fam.expert_operand(cfg)
+    if shaped is None:
         return None
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    shaped = re.compile(rf"\[(?:\d+,)?{e},(?:{d},{f}|{f},{d})\]")
     p, c = _program(ctx, program), _counter(ctx, counter)
     seconds = sum(o["seconds"] for o in ctx["trace"]["ops"].values()
                   if o["program"] == program and shaped.search(o["text"]))
     if not seconds or not p["count"] or not c:
         return None
     steps = p["count"] * cfg["engine"]["max_burst"]
-    least = _per_launch(c, lambda ev: costs.expert_bytes_per_step(
+    least = _per_launch(c, lambda ev: fam.expert_bytes_per_step(
         cfg, ev["lanes"])) / peaks(ctx["device"]["kind"])["hbm_bytes_per_s"]
     return 100.0 * least / (seconds / steps)
 
@@ -144,11 +147,8 @@ def prefill_roofline(ctx, program: str, counter: str):
     if not c or not p["seconds"] or not c.get("chunks"):
         return None
     cfg = ctx["cell"].config
-    heads = cfg["num_attention_heads"]
-    hd = cfg["hidden_size"] // heads
-    per_launch = cfg["num_hidden_layers"] * (
-        2 * costs.layer_params(cfg, active_only=True) * c["tokens"]
-        + 4 * heads * hd * c["context"]) / c["chunks"]
+    per_launch = family(cfg).prefill_flops(
+        cfg, c["tokens"], c["context"]) / c["chunks"]
     return 100.0 * per_launch * p["count"] \
         / peaks(ctx["device"]["kind"])["bf16_flops"] / p["seconds"]
 
@@ -178,6 +178,7 @@ def train_mfu(ctx):
     run, cfg = ctx["run"], ctx["cell"].config
     if "tokens" not in run:
         return None
-    per_tok = costs.train_flops_per_token(cfg, ctx["cell"].traffic["seq_len"])
+    per_tok = family(cfg).train_flops_per_token(
+        cfg, ctx["cell"].traffic["seq_len"])
     peak = ctx["device"]["count"] * peaks(ctx["device"]["kind"])["bf16_flops"]
     return 100.0 * run["tokens"] / run["window_s"] * per_tok / peak

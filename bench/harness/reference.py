@@ -1,158 +1,16 @@
-"""Plain float32 references, written from the published descriptions
-(Mistral 7B, arXiv:2310.06825; Mixtral of Experts, arXiv:2401.04088) and
-independent of `ray_tpu/models/`: no kernels, no cache, no batching, no
-scan.  They share only the parameter tree's layout, which is data:
-
-    embed (V,d)  lm_head (d,V)  final_norm (d,)
-    blocks.{attn_norm,mlp_norm} (L,d)  blocks.wq (L,d,H*hd)
-    blocks.{wk,wv} (L,d,Hkv*hd)  blocks.wo (L,H*hd,d)
-    dense:  blocks.{w_gate,w_up} (L,d,f)  blocks.w_down (L,f,d)
-    MoE:    blocks.router (L,d,E)  blocks.{w_gate,w_up} (L,E,d,f)
-            blocks.w_down (L,E,f,d)
-
-`config` is a configuration file's dict.  Callers run these under
-`jax.default_matmul_precision("highest")`: on a TPU a float32 matmul is
-otherwise computed in bfloat16 passes.
-
-Departures from the published models: none in the mathematics.  Rotary
-embedding pairs dimension i with i + hd/2 (the "half-rotated" layout of
-the public Mistral code) -- what `ray_tpu.ops.rotary` also does; with
-random weights the two layouts are the same model up to a permutation of
-wq / wk columns.
+"""The comparison that decides `correct`: what every family is held to.
+The plain references themselves are the families' (bench/families/
+<family>.py: `forward`, `row_loss`); a family may bring tolerances of
+its own (`TOLERANCES`, a dict by the names of the constants below, each
+with its measurement and reason beside it as here), and one that brings
+none is held to these, which were measured on Mistral-7B and
+Mixtral-8x7B.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
-
-
-def _rms_norm(x, gain, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * gain
-
-
-def _rope(x, theta):
-    """x (T, heads, hd): rotate pairs (i, i + hd/2) by pos * theta^(-2i/hd)."""
-    t, _, hd = x.shape
-    half = hd // 2
-    inv = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = jnp.arange(t, dtype=F32)[:, None] * inv[None, :]       # (T, half)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-_QUERY_BLOCK = 512
-
-
-def _attention(x, bp, c):
-    """Causal grouped-query attention over one sequence x (T, d).  The
-    queries are taken _QUERY_BLOCK at a time against the whole context,
-    so that the (heads, T, T) scores of a 4096-token row never exist at
-    once; the mathematics is the plain softmax(QK^T / sqrt(hd)) V."""
-    t = x.shape[0]
-    h, hkv = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c["hidden_size"] // h
-    q = _rope((x @ bp["wq"]).reshape(t, h, hd), c["rope_theta"])
-    k = _rope((x @ bp["wk"]).reshape(t, hkv, hd), c["rope_theta"])
-    v = (x @ bp["wv"]).reshape(t, hkv, hd)
-    k = jnp.repeat(k, h // hkv, axis=1)       # query head j reads kv head
-    v = jnp.repeat(v, h // hkv, axis=1)       # j // (h / hkv)
-    out = []
-    for lo in range(0, t, _QUERY_BLOCK):
-        hi = min(lo + _QUERY_BLOCK, t)
-        s = jnp.einsum("qhd,khd->hqk", q[lo:hi], k) / jnp.sqrt(F32(hd))
-        seen = jnp.arange(t)[None, :] <= jnp.arange(lo, hi)[:, None]
-        p = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
-        out.append(jnp.einsum("hqk,khd->qhd", p, v))
-    return jnp.concatenate(out, 0).reshape(t, h * hd) @ bp["wo"]
-
-
-def _dense_ffn(x, gate, up, down):
-    """SwiGLU.  Weights are cast to float32 here, at their use: one
-    expert's at a time is what fits beside a model at published widths."""
-    return (jax.nn.silu(x @ gate.astype(F32)) * (x @ up.astype(F32))) \
-        @ down.astype(F32)
-
-
-def _moe_ffn(x, bp, c):
-    """Mixtral's sparse block: softmax over the top-k router logits of
-    each token (equal to the renormalised top-k of the full softmax),
-    and the weighted sum of those experts' SwiGLU outputs.  Every expert
-    is evaluated on every token and masked: plain, and exact.  Also
-    returns each token's routing margin: the distance between the last
-    router logit taken and the first left out, as a share of the root
-    mean square of the token's router logits."""
-    k = c["num_experts_per_tok"]
-    logits = x @ bp["router"].astype(F32)                      # (T, E)
-    top, idx = jax.lax.top_k(logits, k + 1)
-    margin = (top[:, k - 1] - top[:, k]) \
-        / jnp.sqrt(jnp.mean(jnp.square(logits), axis=-1))
-    gates = jax.nn.softmax(top[:, :k], axis=-1)               # (T, k)
-    weight = jnp.sum(jax.nn.one_hot(idx[:, :k], logits.shape[-1], dtype=F32)
-                     * gates[..., None], axis=1)               # (T, E)
-    out = jnp.zeros_like(x)
-    for e in range(logits.shape[-1]):
-        out = out + weight[:, e:e + 1] * _dense_ffn(
-            x, bp["w_gate"][e], bp["w_up"][e], bp["w_down"][e])
-    return out, margin
-
-
-_FFN = ("w_gate", "w_up", "w_down", "router")
-
-
-def block(x, bp, c):
-    """One decoder block on one sequence x (T, d); `bp` its parameters.
-    Returns the block's output and each token's routing margin (infinite
-    where the block has no router)."""
-    attn = {n: a.astype(F32) for n, a in bp.items() if n not in _FFN}
-    x = x + _attention(_rms_norm(x, attn["attn_norm"], c["rms_norm_eps"]),
-                       attn, c)
-    h = _rms_norm(x, attn["mlp_norm"], c["rms_norm_eps"])
-    if c.get("num_local_experts"):
-        out, margin = _moe_ffn(h, bp, c)
-        return x + out, margin
-    return (x + _dense_ffn(h, bp["w_gate"], bp["w_up"], bp["w_down"]),
-            jnp.full(x.shape[:1], jnp.inf, F32))
-
-
-def head(x, final_norm, out_matrix, c):
-    return _rms_norm(x, final_norm.astype(F32), c["rms_norm_eps"]) \
-        @ out_matrix.astype(F32)
-
-
-def forward(params, tokens, c, jit=lambda f: f):
-    """tokens (T,) int32 -> (logits (T, V) float32, margin (T,)), one
-    sequence; `margin` is each token's smallest routing margin over the
-    layers.  Parameters are cast to float32 a block at a time, at their
-    use.  `jit=jax.jit` compiles the block once and runs it per layer:
-    the same arithmetic with one layer's temporaries on the device at a
-    time, which is what fits beside a model at published widths."""
-    block_fn = jit(functools.partial(block, c=c))
-    x = params["embed"][tokens].astype(F32)
-    margin = jnp.full(x.shape[:1], jnp.inf, F32)
-    for i in range(c["num_hidden_layers"]):
-        x, m = block_fn(x, {n: a[i] for n, a in params["blocks"].items()})
-        margin = jnp.minimum(margin, m)
-    out = params["embed"].T if c.get("tie_word_embeddings") \
-        else params["lm_head"]
-    return jit(functools.partial(head, c=c))(x, params["final_norm"],
-                                              out), margin
-
-
-def row_loss(params, row, c, jit=lambda f: f):
-    """Mean next-token cross entropy of one row (T+1,), float32.  A
-    batch's loss is the mean over its rows (equal lengths).  (The dense
-    configuration has no auxiliary loss; a MoE training reference would
-    add the router's.)"""
-    logits, _ = forward(params, row[:-1], c, jit=jit)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, row[1:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - tgt)
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +35,8 @@ def row_loss(params, row, c, jit=lambda f: f):
 # that token's logits are then another function's (model-configs guide,
 # section 3.3, warns of this for sampled tokens; with random routers it
 # reaches the logits).  The reference knows where that can happen without
-# asking the program: its own routing margin there (see _moe_ffn) is small
+# asking the program: its own routing margin there (families/mistral.py,
+# `_moe_ffn`; infinite where a block has no router) is small
 # in some layer.  A position whose margin is at least ROUTER_MARGIN in
 # every layer is *decided* and must meet LOGITS_REL_EXPERTS; at least
 # MIN_DECIDED positions must be decided; an *undecided* position is held
@@ -214,6 +73,15 @@ MIN_DECIDED = 6
 LOSS_REL = 1e-3
 
 
+def tolerances(family=None) -> dict:
+    """The constants above by name, with the family's own
+    (`TOLERANCES`) over them where it brings any."""
+    return {"LOGITS_REL": LOGITS_REL,
+            "LOGITS_REL_EXPERTS": LOGITS_REL_EXPERTS,
+            "ROUTER_MARGIN": ROUTER_MARGIN, "MIN_DECIDED": MIN_DECIDED,
+            "LOSS_REL": LOSS_REL, **getattr(family, "TOLERANCES", {})}
+
+
 def position_errors(got, want):
     """Per position (row): rms(got - want) / rms(want), float32."""
     got, want = jnp.asarray(got, F32), jnp.asarray(want, F32)
@@ -221,20 +89,26 @@ def position_errors(got, want):
                     / jnp.mean(jnp.square(want), axis=-1))
 
 
-def logits_verdict(errors, margins, c) -> dict:
+def logits_verdict(errors, margins, family=None) -> dict:
     """`errors`, `margins`: every compared position's error and routing
-    margin (flat lists of equal length).  `each` lists them, so that a
-    verdict can be explained from the output alone."""
+    margin (flat lists of equal length).  A model whose reference found
+    a router anywhere (a finite margin) is held to the experts' bound,
+    one whose margins are all infinite to the dense one.  `family`:
+    the module whose tolerances hold (`tolerances`).  `each` lists the
+    positions, so that a verdict can be explained from the output
+    alone."""
+    t = tolerances(family)
     each = sorted((float(m), float(e)) for m, e in zip(margins, errors))
     errs = sorted(e for _, e in each)
-    decided = [e for m, e in each if m >= ROUTER_MARGIN]
-    bound = LOGITS_REL_EXPERTS if c.get("num_local_experts") else LOGITS_REL
+    decided = [e for m, e in each if m >= t["ROUTER_MARGIN"]]
+    routed = any(m != float("inf") for m, _ in each)
+    bound = t["LOGITS_REL_EXPERTS"] if routed else t["LOGITS_REL"]
     finite = all(e == e and e != float("inf") for e in errs)
     return {"positions": len(errs), "decided": len(decided),
             "median": errs[len(errs) // 2], "worst": errs[-1],
             "worst_decided": max(decided, default=None), "bound": bound,
             "finite": finite,
-            "ok": bool(finite and len(decided) >= MIN_DECIDED
+            "ok": bool(finite and len(decided) >= t["MIN_DECIDED"]
                        and max(decided) <= bound),
             "each": [[m if m != float("inf") else None, e]
                      for m, e in each]}

@@ -1,14 +1,13 @@
 """From a cell's measurements to the last line."""
 from __future__ import annotations
 
-import importlib.util
 import json
 import math
 import sys
 from typing import Any, Dict
 
 from bench.harness import e2e, readers
-from bench.harness.spec import BENCH_DIR, Cell, metric_file
+from bench.harness.spec import BENCH_DIR, Cell, load_file, metric_file
 
 
 def note(kind: str, **fields) -> None:
@@ -19,12 +18,7 @@ def note(kind: str, **fields) -> None:
 def _reader(metric: Dict[str, Any]):
     own = metric_file(BENCH_DIR, metric["name"], ".py")
     if own:
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric["name"].replace(".", "_").replace(
-                "-", "_"), own)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_file(own, "bench_metric_").read
     return getattr(readers, metric["reader"])
 
 

@@ -5,13 +5,18 @@ A cell (an entry of `workloads`) names a configuration and a traffic mix;
 offered rate of an open loop) and `metrics/<metric>.json` are found by
 those names.  A metric that BENCHMARK.json splits by the end-to-end metric
 it moves (`x.serve`, `x.train`) and that has no file of its own takes
-`x`'s.  Nothing here, or anywhere in the harness, branches on a
-name: adding a cell, a configuration, a mix or a per-layer metric is
+`x`'s.  A configuration file names its family (`"family"`), and
+`families/<family>.py` holds everything the harness knows of that model
+(`family` below).  Nothing here, or anywhere in the harness, branches on
+a name or reads a key of a model's `config.json` but `vocab_size`:
+adding a cell, a configuration, a family, a mix or a per-layer metric is
 adding files and entries.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 import os
 from typing import Any, Dict, List
@@ -78,6 +83,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                         f"{entry['config']!r}, which BENCHMARK.json lacks")
     config = _read(os.path.join(root, cfg_entry["file"]))
     bdir = os.path.join(root, bench["paths"][0])
+    config["family_file"] = family_file(config, cfg_entry["file"], bdir)
     traffic = _read(os.path.join(bdir, "traffic", entry["traffic"] + ".json"))
     load_path = os.path.join(bdir, "cells", name + ".json")
     load = _read(load_path) if os.path.exists(load_path) else {}
@@ -95,40 +101,54 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
 
 
 # ---------------------------------------------------------------------------
-# configuration file -> the program's TransformerConfig
+# configuration file -> its family
 # ---------------------------------------------------------------------------
+def family_file(config: Dict[str, Any], where: str,
+                bench_dir: str = BENCH_DIR) -> str:
+    """The path of `families/<config["family"]>.py`: in the tree the
+    configuration came from, else in the harness's own (a tree of data
+    files alone, such as the tests', brings no family).  `where` names
+    the configuration file in the fault."""
+    name = config.get("family")
+    if not isinstance(name, str) or not name:
+        raise SpecError(f'{where} names no "family": the harness knows a '
+                        f"model only by bench/families/<family>.py")
+    for d in (bench_dir, BENCH_DIR):
+        path = os.path.join(d, "families", name + ".py")
+        if os.path.exists(path):
+            return path
+    raise SpecError(f"{where} names the family {name!r}, and there is no "
+                    f"{os.path.join('families', name + '.py')} under "
+                    f"{os.path.relpath(bench_dir, ROOT)}")
+
+
+@functools.lru_cache(maxsize=None)
+def load_file(path: str, prefix: str):
+    """The module of a `.py` that a data file names (a family, a
+    metric's reader), loaded by file: one module a file a process, as
+    `sys.modules` keeps one a name."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        prefix + stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(config: Dict[str, Any]):
+    """The module of the configuration's family: in the replica's and
+    the train worker's process too, where `config` arrives with the `family_file`
+    that `load_cell` found.  What a family file gives is listed at the
+    top of families/mistral.py."""
+    return load_file(config.get("family_file") or family_file(
+        config, f"configuration {config.get('name')!r}"), "bench_family_")
+
+
 def transformer_config(config: Dict[str, Any]):
-    """The configuration file's keys are the source's (`config.json` of
-    the model); this is the one place they meet the program's names."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig
-
-    heads = config["num_attention_heads"]
-    if config.get("head_dim", config["hidden_size"] // heads) * heads \
-            != config["hidden_size"]:
-        raise SpecError("head_dim * num_attention_heads != hidden_size: "
-                        "the program derives the head size")
-    if config.get("sliding_window"):
-        raise SpecError("the program has no sliding-window attention")
-    return TransformerConfig(
-        name=config["name"],
-        vocab_size=config["vocab_size"],
-        d_model=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=heads,
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        max_seq_len=config["max_position_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config.get("tie_word_embeddings", False)),
-        param_dtype=jnp.dtype(config["param_dtype"]),
-        compute_dtype=jnp.dtype(config["compute_dtype"]),
-        remat=bool(config.get("remat", False)),
-        remat_policy=config.get("remat_policy", "full"),
-        n_experts=int(config.get("num_local_experts", 0)),
-        expert_top_k=int(config.get("num_experts_per_tok", 2)))
+    """The program's configuration object: the family's
+    `program_config`.  Kept under this name for
+    tests/test_tpu_compile.py, which a benchmark PR may not edit."""
+    return family(config).program_config(config)
 
 
 def request_limit(engine: Dict[str, Any]) -> int:

@@ -15,6 +15,15 @@ from bench.harness import report, runtime, schedule, spec
 TRACE_AT, TRACE_STEPS = 0.4, 3
 
 
+def step_fns(c: Dict[str, Any], mesh):
+    """(init_fn, step_fn) of the configuration `c` on `mesh`: the train
+    path's entry point, on whatever the family's `program_config`
+    returns."""
+    from ray_tpu.models.training import make_train_step
+
+    return make_train_step(spec.family(c).program_config(c), mesh)
+
+
 def train_loop(config: Dict[str, Any]) -> None:
     import os
 
@@ -22,18 +31,17 @@ def train_loop(config: Dict[str, Any]) -> None:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from bench.harness import device, reference
+    from bench.harness import device
     from ray_tpu import train
-    from ray_tpu.models.training import make_train_step
     from ray_tpu.parallel import MeshConfig, build_mesh
 
     t = {"loop_start": time.time()}
     counter = device.CompileCounter()
     c, traffic = config["config"], config["traffic"]
     seed, seconds = config["seed"], config["seconds"]
-    cfg = spec.transformer_config(c)
+    fam = spec.family(c)
     mesh = build_mesh(MeshConfig(**c["mesh"]))
-    init_fn, step_fn = make_train_step(cfg, mesh)
+    init_fn, step_fn = step_fns(c, mesh)
     state = init_fn(device.seeded_key(seed))
     jax.block_until_ready(state)
     t["state_ready"] = time.time()
@@ -51,7 +59,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     # plain float32 reference on the same parameters, before any update.
     with jax.default_matmul_precision("highest"):
         rows = jax.device_get(first["tokens"])
-        ref_loss = sum(float(reference.row_loss(
+        ref_loss = sum(float(fam.row_loss(
             state.params, jnp.asarray(r), c, jit=jax.jit))
             for r in rows) / len(rows)
     t["reference"] = time.time()
@@ -158,17 +166,18 @@ def run(cell: spec.Cell, *, seed: int, seconds: float, traced: bool,
     opened, closed = r["compile_marks"]["open"], r["compile_marks"]["close"]
     window_compiles = {k: closed[k] - opened[k] for k in opened}
     ln_vocab = math.log(c["vocab_size"])
+    loss_rel = reference.tolerances(spec.family(c))["LOSS_REL"]
     first = r["warm_losses"][0]
     check = {
         "first_loss": first, "ln_vocab": ln_vocab,
         "reference_loss": r["reference_loss"],
         "loss_rel": abs(first - r["reference_loss"]) / r["reference_loss"],
-        "bound": reference.LOSS_REL,
+        "bound": loss_rel,
         "all_finite": not bad and all(math.isfinite(x)
                                       for x in r["warm_losses"])}
     check["ok"] = (check["all_finite"]
                    and abs(first - ln_vocab) <= 0.10 * ln_vocab
-                   and check["loss_rel"] <= reference.LOSS_REL)
+                   and check["loss_rel"] <= loss_rel)
     times = r["times"]
     report.note(
         "phases", attempted=r["steps"], failed=len(bad),

@@ -1,5 +1,6 @@
 """The comparison that decides `correct` (bench/harness/reference.py,
-deployment.logits_check), held to what it claims: through the engine's
+deployment.logits_check, the family's `score` and `forward`), held to
+what it claims: through the engine's
 own programs at a tiny size, in bfloat16, it passes the program as it is
 and fails it with a layer's output dropped, an expert's output dropped,
 or the KV cache kept in 8-bit floats.  The reference always sees the
@@ -31,9 +32,10 @@ def built(request):
     from ray_tpu.serve.llm import PagedLLMEngine
 
     c = _config(request.param)
-    cfg, eng = spec.transformer_config(c), c["engine"]
+    fam = spec.family(c)
+    cfg, eng = fam.program_config(c), c["engine"]
     e = PagedLLMEngine(
-        cfg, device.seeded_params(cfg, SEED), num_slots=eng["num_slots"],
+        cfg, device.seeded_params(fam, cfg, SEED), num_slots=eng["num_slots"],
         max_len=eng["max_len"], block_size=eng["block_size"],
         prefill_chunk=eng["prefill_chunk"])
     return e, c
@@ -78,15 +80,16 @@ def _cache_in_8_bits(e, c):
                          ids=["as_it_is", "layer_dropped", "expert_dropped",
                               "cache_in_8_bits"])
 def test_logits_check(built, fault, monkeypatch):
-    from bench.harness import reference
+    from bench.harness import reference, spec
     from bench.harness.deployment import logits_check
 
     e, c = built
-    true_params, forward = e.params, reference.forward
+    fam = spec.family(c)
+    true_params, forward = e.params, fam.forward
     monkeypatch.setattr(e, "params", true_params)
     monkeypatch.setattr(e, "_prefill_chunk_fn", e._prefill_chunk_fn)
     monkeypatch.setattr(
-        reference, "forward",
+        fam, "forward",
         lambda params, *a, **kw: forward(true_params, *a, **kw))
     if fault:
         fault(e, c)

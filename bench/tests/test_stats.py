@@ -5,7 +5,9 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from bench.harness import client, costs, e2e, stats  # noqa: E402
+from bench.harness import client, e2e, spec, stats  # noqa: E402
+
+costs = spec.family({"family": "mistral"})       # what a step needs
 
 
 def test_pct_and_union():
@@ -62,24 +64,35 @@ def test_costs_at_published_widths():
 def test_logits_verdict_dense_and_experts():
     from bench.harness import reference as R
 
-    moe, inf = {"num_local_experts": 8}, float("inf")
+    inf = float("inf")
     far, near = 10 * R.ROUTER_MARGIN, R.ROUTER_MARGIN / 10
-    assert R.logits_verdict([0.01] * 18, [inf] * 18, {})["ok"]
-    # dense: every position
-    assert not R.logits_verdict([0.01] * 17 + [0.04], [inf] * 18, {})["ok"]
-    assert not R.logits_verdict([0.01] * 17 + [float("nan")], [inf] * 18,
-                                {})["ok"]
+    # no router anywhere (every margin infinite): every position, and
+    # the dense bound
+    dense = R.logits_verdict([0.01] * 18, [inf] * 18)
+    assert dense["ok"] and dense["bound"] == R.LOGITS_REL
+    assert not R.logits_verdict([0.01] * 17 + [0.04], [inf] * 18)["ok"]
+    assert not R.logits_verdict([0.01] * 17 + [float("nan")],
+                                [inf] * 18)["ok"]
     # experts: a position the reference's own margin marks as undecided
     # may be routed otherwise; every decided position is held to the bound
     flips = R.logits_verdict([0.02] * 8 + [0.5] * 10,
-                             [far] * 8 + [near] * 10, moe)
+                             [far] * 8 + [near] * 10)
     assert flips["ok"] and flips["decided"] == 8
+    assert flips["bound"] == R.LOGITS_REL_EXPERTS
     assert flips["each"][0] == [near, 0.5]
     assert not R.logits_verdict([0.02] * 8 + [0.5] * 10,
-                                [far] * 9 + [near] * 9, moe)["ok"]
-    assert not R.logits_verdict([0.02] * 18, [far] * 5 + [near] * 13,
-                                moe)["ok"]            # too few decided
-    assert not R.logits_verdict([0.3] * 18, [far] * 18, moe)["ok"]
+                                [far] * 9 + [near] * 9)["ok"]
+    assert not R.logits_verdict(
+        [0.02] * 18, [far] * 5 + [near] * 13)["ok"]   # too few decided
+    assert not R.logits_verdict([0.3] * 18, [far] * 18)["ok"]
+    # between the two bounds: sound with a router, not without
+    assert R.logits_verdict([0.04] * 18, [far] * 18)["ok"]
+    assert not R.logits_verdict([0.04] * 18, [inf] * 18)["ok"]
+    # a family's own tolerances hold in place of the constants
+    import types
+
+    own = types.SimpleNamespace(TOLERANCES={"LOGITS_REL": 0.05})
+    assert R.logits_verdict([0.04] * 18, [inf] * 18, own)["ok"]
 
 
 def test_decode_rooflines_count_the_routed_experts_only():
@@ -90,7 +103,8 @@ def test_decode_rooflines_count_the_routed_experts_only():
     from bench.harness import readers
     from bench.harness.peaks import peaks
 
-    cfg = {"hidden_size": 4096, "intermediate_size": 14336,
+    cfg = {"family": "mistral",
+           "hidden_size": 4096, "intermediate_size": 14336,
            "num_attention_heads": 32, "num_key_value_heads": 8,
            "num_hidden_layers": 3, "vocab_size": 32000,
            "num_local_experts": 8, "num_experts_per_tok": 2,

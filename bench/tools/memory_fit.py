@@ -3,7 +3,12 @@ a *described* v5e (no chip needed: on-chip-measurement guide, section 2)
 and prints `memory_analysis()` per device beside what the process keeps
 resident (parameters, pool / optimizer state).
 
-    JAX_PLATFORMS=cpu python bench/tools/memory_fit.py <config> [--layers N ...]
+    JAX_PLATFORMS=cpu python bench/tools/memory_fit.py <config> \
+        [--vary num_hidden_layers 8 12 16]
+
+What the programs are is the configuration's family's to say
+(`serve_programs` of bench/families/<family>.py; the train step is
+`train_cell.step_fns`): nothing here names a model.
 """
 from __future__ import annotations
 
@@ -32,49 +37,29 @@ def _report(name, compiled):
     return out
 
 
+def _nbytes(tree) -> int:
+    import jax
+
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
 def serve_fit(config, topo):
     import jax
-    import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from bench.harness.spec import transformer_config
-    from ray_tpu.models.decoding import (
-        init_paged_cache, make_paged_engine_fns)
-    from ray_tpu.models.transformer import init_params
+    from bench.harness import spec
 
-    cfg = transformer_config(config)
-    eng = config["engine"]
     dev = SingleDeviceSharding(topo.devices[0])
-    n_blocks = eng["num_slots"] * eng["max_len"] // eng["block_size"] + 1
-    b_max = -(-eng["max_len"] // eng["block_size"])
 
-    def spec(tree):
+    def place(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=dev), tree)
 
-    params = spec(jax.eval_shape(
-        lambda: init_params(jax.random.key(0), cfg)))
-    cache = spec(jax.eval_shape(
-        lambda: init_paged_cache(cfg, n_blocks, eng["block_size"])))
-    rng = spec(jax.eval_shape(lambda: jax.random.key(0)))
-    chunk_fn, burst_fn, _ = make_paged_engine_fns(cfg)
-
-    def arr(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
-
-    w, c = eng["num_slots"], eng["prefill_chunk"]
-    nbytes = lambda t: sum(x.size * x.dtype.itemsize  # noqa: E731
-                           for x in jax.tree.leaves(t))
-    print(json.dumps({"layers": cfg.n_layers,
-                      "params_GB": nbytes(params) / 1e9,
-                      "pool_GB": nbytes(cache) / 1e9}), flush=True)
-    _report(f"paged_decode_burst w={w}", burst_fn.lower(
-        params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
-        arr((w,), jnp.int32), arr((w,), jnp.bool_),
-        arr((w,), jnp.float32), rng, n_steps=eng["max_burst"]).compile())
-    _report(f"paged_prefill_chunk c={c}", chunk_fn.lower(
-        params, cache, arr((c,), jnp.int32), arr((b_max,), jnp.int32),
-        arr((), jnp.int32), arr((), jnp.int32)).compile())
+    resident, programs = spec.family(config).serve_programs(config, place)
+    print(json.dumps({f"{name}_GB": _nbytes(tree) / 1e9
+                      for name, tree in resident.items()}), flush=True)
+    for name, lowered in programs:
+        _report(name, lowered.compile())
 
 
 def train_fit(config, traffic, topo):
@@ -82,23 +67,21 @@ def train_fit(config, traffic, topo):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from bench.harness.spec import transformer_config
-    from ray_tpu.models.training import make_train_step
+    from bench.harness.train_cell import step_fns
     from ray_tpu.parallel import MeshConfig, build_mesh
 
-    cfg = transformer_config(config)
     mesh = build_mesh(MeshConfig(**config["mesh"]),
                       devices=topo.devices[:4])
-    init_fn, step_fn = make_train_step(cfg, mesh)
+    init_fn, step_fn = step_fns(config, mesh)
     # eval_shape of the jitted init keeps its declared out_shardings
     state = jax.eval_shape(init_fn, jax.random.key(0))
     batch = {"tokens": jax.ShapeDtypeStruct(
         (traffic["global_batch"], traffic["seq_len"] + 1), jnp.int32,
         sharding=NamedSharding(mesh, P(("dp", "fsdp"))))}
-    print(json.dumps({"layers": cfg.n_layers,
-                      "params": cfg.num_params,
+    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    print(json.dumps({"params": n_params,
                       "state_GB_per_device":
-                          16 * cfg.num_params / 4 / 1e9}), flush=True)
+                          16 * n_params / 4 / 1e9}), flush=True)
     # The program asks jax.default_backend() whether to use its Pallas
     # attention kernel; here that is the CPU, so steer it (as
     # tests/test_tpu_compile.py does) or the O(T^2) reference is compiled.
@@ -122,7 +105,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
     ap.add_argument("--traffic", default="sft-4k")
-    ap.add_argument("--layers", type=int, nargs="*")
+    ap.add_argument("--vary", nargs="+", metavar=("KEY", "VALUE"),
+                    help="a key of the configuration file and the values "
+                         "(JSON) to fit it at, one after another")
     args = ap.parse_args()
     from jax.experimental import topologies
 
@@ -132,8 +117,11 @@ def main():
                                         topology_name="v5e:2x2")
     with open(os.path.join(BENCH_DIR, "configs", args.config + ".json")) as f:
         config = json.load(f)
-    for layers in args.layers or [config["num_hidden_layers"]]:
-        c = dict(config, num_hidden_layers=layers)
+    key, *values = args.vary or [None]
+    for value in values or [None]:
+        varied = {} if value is None else {key: json.loads(value)}
+        c = dict(config, **varied)
+        print(json.dumps({"config": args.config, **varied}), flush=True)
         if c["kind"] == "train":
             with open(os.path.join(BENCH_DIR, "traffic",
                                    args.traffic + ".json")) as f:
