@@ -13,12 +13,14 @@ from ray_tpu.models.transformer import (
     forward,
     loss_fn,
 )
+from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models import configs
 from ray_tpu.models.hf_convert import from_hf
 
 __all__ = [
     "Transformer",
     "TransformerConfig",
+    "HybridConfig",
     "init_params",
     "param_logical_axes",
     "forward",

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.transformer import TransformerConfig
 
 TINY = TransformerConfig(
@@ -54,10 +55,22 @@ MIXTRAL_8X7B = TransformerConfig(
     rope_theta=1000000.0, n_experts=8, expert_top_k=2,
 )
 
+# The decoder-hybrid-decoder stack (models/hybrid.py) at test size: two
+# (Mamba, window) pairs, the (Mamba, full) pair, one (GMU, cross) pair.
+TINY_HYBRID = HybridConfig(
+    name="tiny-hybrid", vocab_size=512, d_model=64, n_layers=8, n_heads=8,
+    n_kv_heads=4, d_ff=128, window=24, d_state=4, dt_rank=8,
+    max_seq_len=512, param_dtype=jnp.float32,
+)
+
+# Phi-4-mini-flash-reasoning's published sizes (3.85 B parameters).
+PHI4_MINI_FLASH = HybridConfig(name="phi4-mini-flash")
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
-                                LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B]}
+                                LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
+                                TINY_HYBRID, PHI4_MINI_FLASH]}
 
 
-def get(name: str) -> TransformerConfig:
+def get(name: str):
     return REGISTRY[name]

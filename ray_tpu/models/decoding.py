@@ -457,6 +457,12 @@ class PagedKVCache:
     k: jax.Array          # (L, N_blocks, block_size, Hkv, D)
     v: jax.Array
 
+    def resident_bytes(self) -> dict:
+        """Bytes a replica keeps for its sequences, by kind of state."""
+        return {"kv_paged": int(sum(a.size * a.dtype.itemsize
+                                    for a in (self.k, self.v))),
+                "kv_window": 0, "recurrent": 0}
+
 
 jax.tree_util.register_dataclass(PagedKVCache, ["k", "v"], [])
 
@@ -468,6 +474,37 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
              cfg.head_dim)
     return PagedKVCache(k=jnp.zeros(shape, dtype),
                         v=jnp.zeros(shape, dtype))
+
+
+def init_sequence_state(cfg, num_blocks: int, block_size: int, *,
+                        num_slots: int, prefill_chunk: int):
+    """What the sequences of one engine keep on the device, asked of the
+    model: the paged pool alone for a `TransformerConfig`; whatever
+    `cfg.init_state` says for a model that brings its own (paged KV of
+    the layers that keep every position, bounded window KV and recurrent
+    state by slot: `models.hybrid`).  Either is the `cache` argument of
+    the served programs below."""
+    own = getattr(cfg, "init_state", None)
+    if own is None:
+        return init_paged_cache(cfg, num_blocks, block_size)
+    return own(num_blocks, block_size, num_slots, prefill_chunk)
+
+
+def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
+                    cfg, slots):
+    """The served step of `cfg`'s model: `_paged_forward`, or the model's
+    own over its own sequence state, which also takes the lanes' engine
+    `slots` (S,) (None for a model whose state is the pool alone)."""
+    own = getattr(cfg, "served_step", None)
+    if own is None:
+        return _paged_forward(params, cache, tokens, block_tables, positions,
+                              kv_len, cfg)
+    return own(params, cache, tokens, block_tables, positions, kv_len, slots)
+
+
+def _served_logits(params, x, cfg):
+    own = getattr(cfg, "final_logits", None)
+    return _final_logits(params, x, cfg) if own is None else own(params, x)
 
 
 def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
@@ -515,7 +552,8 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
 
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
                       block_tables: jax.Array, lengths: jax.Array,
-                      active: jax.Array, cfg: TransformerConfig
+                      active: jax.Array, cfg: TransformerConfig,
+                      slots: Optional[jax.Array] = None
                       ) -> Tuple[PagedKVCache, jax.Array]:
     """One token for every slot through the block pool: tokens (S,),
     block_tables (S, B_max) int32, lengths (S,) int32, active (S,) bool.
@@ -525,26 +563,27 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     slot then attends to its len + 1 positions; what is read is the blocks
     below the longest active slot's length, not the table's width.
     Inactive slots write the null block and return garbage that the
-    engine drops.
+    engine drops.  `slots` (S,): the lanes' engine slots, for a model
+    whose sequences keep state by slot (`init_sequence_state`).
     """
-    cache, x = _paged_forward(
+    cache, x = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
-        jnp.where(active, lengths + 1, 0), cfg)
-    return cache, _final_logits(params, x, cfg)[:, 0]      # (S, vocab)
+        jnp.where(active, lengths + 1, 0), cfg, slots)
+    return cache, _served_logits(params, x, cfg)[:, 0]     # (S, vocab)
 
 
 def paged_decode_and_sample(params, cache: PagedKVCache, tokens,
                             block_tables, lengths, active, temps, rng,
-                            cfg: TransformerConfig):
+                            cfg: TransformerConfig, slots=None):
     cache, logits = paged_decode_step(params, cache, tokens, block_tables,
-                                      lengths, active, cfg)
+                                      lengths, active, cfg, slots)
     rng, sub = jax.random.split(rng)
     return cache, sample_per_slot(logits, sub, temps), rng
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
                        lengths, active, temps, rng,
-                       cfg: TransformerConfig, n_steps: int):
+                       cfg: TransformerConfig, n_steps: int, slots=None):
     """`n_steps` fused paged decode+sample ticks in one device call.
     Block tables are static across the burst — the engine pre-extends
     each active slot's table to cover lengths + n_steps before issuing.
@@ -555,7 +594,7 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
         cache, toks, lengths, rng = carry
         cache, nxt, rng = paged_decode_and_sample(
             params, cache, toks, block_tables, lengths, active, temps,
-            rng, cfg)
+            rng, cfg, slots)
         lengths = jnp.where(active, lengths + 1, lengths)
         return (cache, nxt, lengths, rng), nxt
 
@@ -566,7 +605,8 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
 
 def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
                         block_tables: jax.Array, start: jax.Array,
-                        n_valid: jax.Array, cfg: TransformerConfig
+                        n_valid: jax.Array, cfg: TransformerConfig,
+                        slot: Optional[jax.Array] = None
                         ) -> Tuple[PagedKVCache, jax.Array]:
     """One chunk of a prompt through the block pool: tokens (C,) (padded
     with zeros past `n_valid`), block_tables (B_max,), start = absolute
@@ -575,14 +615,22 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     and attention reads the blocks below start + n_valid: the
     already-prefilled context plus the in-chunk causal prefix.  Padded
     positions write garbage that the next chunk overwrites and no real
-    query's mask reaches.  Returns (cache, logits of token n_valid-1
-    (vocab,)) — the engine samples from the FINAL chunk's logits.
+    query's mask reaches (true of KV; a model with state by `slot`, a
+    scalar, leaves it untouched by them).  Returns (cache, logits of
+    token n_valid-1 (vocab,)) — the engine samples from the FINAL
+    chunk's logits.
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x = _paged_forward(
+    cache, x = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
-        (start + n_valid)[None], cfg)
-    return cache, _final_logits(params, x, cfg)[0, n_valid - 1]
+        (start + n_valid)[None], cfg,
+        None if slot is None else jnp.asarray(slot, jnp.int32)[None])
+    if getattr(cfg, "final_logits", None) is None:
+        return cache, _final_logits(params, x, cfg)[0, n_valid - 1]
+    # A model with a head of its own: over the one position asked for.
+    last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1,
+                                        axis=1)
+    return cache, cfg.final_logits(params, last)[0, 0]
 
 
 def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,

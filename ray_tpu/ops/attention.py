@@ -382,6 +382,50 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, *, causal, sm_scale):
 _PAGED_GROUP_BLOCKS = 16
 
 
+def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
+                           kv_len, score, mix, stat, d_out):
+    """The loop both paged bodies share: groups of `_PAGED_GROUP_BLOCKS`
+    table entries of one layer of the pool, read at `[layer, block]` as
+    stored, under a running soft-max whose trip count follows the longest
+    live lane of the call.  `score(kb)` gives a group's scaled scores
+    (S, K, *stat, t) float32 from its keys (S, t, ...), the pool's
+    trailing dimensions as stored; `mix(p, vb)` applies the probabilities
+    to its values, (S, K, *stat, d_out)."""
+    s, k_w = positions.shape
+    bs, row = k_pool.shape[2], k_pool.shape[3:]
+    g = min(_PAGED_GROUP_BLOCKS, block_tables.shape[1])
+    t = g * bs
+    # Whole groups only: dynamic_slice would clamp a ragged last one onto
+    # the entries before it.  The padding names the null block.
+    tables = jnp.pad(block_tables, ((0, 0), (0, -block_tables.shape[1] % g)))
+    over_stat = (slice(None), slice(None)) + (None,) * len(stat)
+
+    def group(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(tables, i * g, g, axis=1)
+        kb = k_pool[layer, ids].reshape(s, t, *row)
+        vb = v_pool[layer, ids].reshape(s, t, *row)
+        sc = score(kb)
+        seen = (i * t + jnp.arange(t)) <= positions[:, :, None]   # (S,K,t)
+        sc = jnp.where(seen[over_stat], sc, _NEG_INF)
+        # kv position 0 is in group 0 and every query sees it, so from the
+        # first trip on `m_new` is a real score and masked entries vanish.
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        p = jnp.exp(sc - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1)
+        acc = alpha[..., None] * acc + mix(p, vb)
+        return m_new, l, acc
+
+    stat = (s, k_w) + tuple(stat)
+    _, l, acc = jax.lax.fori_loop(
+        0, (jnp.max(kv_len) + t - 1) // t, group,
+        (jnp.full(stat, _NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (d_out,), jnp.float32)))
+    # l is 0 only where no trip ran: a call whose every lane is idle.
+    return acc / jnp.where(l > 0, l, 1.0)[..., None]
+
+
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
     """Attention of `q` (S, K, H, D) over one layer of a paged KV pool.
 
@@ -395,7 +439,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
 
     Only live blocks are read: a loop over groups of `_PAGED_GROUP_BLOCKS`
     table entries with a running soft-max, whose trip count follows the
-    longest live lane of this call.  The rep = H // Hkv query heads of a KV
+    longest live lane of this call (`_paged_running_softmax`).  The rep =
+    H // Hkv query heads of a KV
     head are grouped on their own axis against the stored head, so K and V
     are neither repeated nor kept in another dtype; scores, soft-max, the
     probabilities and both products' accumulation are float32 (the compiler
@@ -404,39 +449,92 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
     Returns (S, K, H, D) float32.
     """
     s, k_w, h, d = q.shape
-    bs, hkv = k_pool.shape[2], k_pool.shape[3]
-    g = min(_PAGED_GROUP_BLOCKS, block_tables.shape[1])
-    t = g * bs
-    # Whole groups only: dynamic_slice would clamp a ragged last one onto
-    # the entries before it.  The padding names the null block.
-    tables = jnp.pad(block_tables, ((0, 0), (0, -block_tables.shape[1] % g)))
+    hkv = k_pool.shape[3]
     qg = q.reshape(s, k_w, hkv, h // hkv, d)
     scale = d ** -0.5
+    out = _paged_running_softmax(
+        k_pool, v_pool, layer, block_tables, positions, kv_len,
+        lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,
+                              preferred_element_type=jnp.float32) * scale,
+        lambda p, vb: jnp.einsum("sqhrt,sthd->sqhrd", p, vb,
+                                 preferred_element_type=jnp.float32),
+        (hkv, h // hkv), d)
+    return out.reshape(s, k_w, h, d)
 
-    def group(i, carry):
-        m, l, acc = carry
-        ids = jax.lax.dynamic_slice_in_dim(tables, i * g, g, axis=1)
-        kb = k_pool[layer, ids].reshape(s, t, hkv, d)
-        vb = v_pool[layer, ids].reshape(s, t, hkv, d)
-        sc = jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,
-                        preferred_element_type=jnp.float32) * scale
-        seen = (i * t + jnp.arange(t)) <= positions[:, :, None]   # (S,K,t)
-        sc = jnp.where(seen[:, :, None, None, :], sc, _NEG_INF)
-        # kv position 0 is in group 0 and every query sees it, so from the
-        # first trip on `m_new` is a real score and masked entries vanish.
-        m_new = jnp.maximum(m, sc.max(axis=-1))
-        p = jnp.exp(sc - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l = alpha * l + p.sum(axis=-1)
-        acc = alpha[..., None] * acc + jnp.einsum(
-            "sqhrt,sthd->sqhrd", p, vb,
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
 
-    stat = (s, k_w, hkv, h // hkv)
-    _, l, acc = jax.lax.fori_loop(
-        0, (jnp.max(kv_len) + t - 1) // t, group,
-        (jnp.full(stat, _NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
-         jnp.zeros(stat + (d,), jnp.float32)))
-    # l is 0 only where no trip ran: a call whose every lane is idle.
-    return (acc / jnp.where(l > 0, l, 1.0)[..., None]).reshape(s, k_w, h, d)
+# Differential attention (arXiv:2410.05258): softmax(q1 k1^T / sqrt(D)) v
+# - lambda softmax(q2 k2^T / sqrt(D)) v, with v twice as wide as a key.
+# Stored, a position's keys and values are flat rows of E = G * 2D: KV
+# group g is the D-wide key heads (2g, 2g+1) = (k1, k2) side by side at
+# columns [g * 2D, (g + 1) * 2D), and its value the value heads (2g, 2g+1)
+# side by side there.  The rows stay flat from the pool to the products:
+# a trailing dimension of D = 64, half a lane tile, makes the compiler lay
+# the pool out otherwise and copy all of it into and out of every step,
+# and a gathered group reshaped to (t, G, 2D) is a copy of the group.  So
+# a query is spread over a row of zeros instead: map c of differential
+# head (g, r) is a row of E with q_c at columns g * 2D + c * D, and its dot
+# with a stored key row is q_c k_c alone.  Scores and values are then one
+# batched matmul a lane over whole rows, X = G * rep * 2 query rows against
+# t stored ones; the value product gives (X, E), of which row (g, r, c)
+# keeps its own group's 2D columns.  Ten times the multiply-adds the
+# mathematics needs, on a step that waits for memory.
+# A query comes as (S, K, G, rep, 2, D).  Both bodies return the two maps
+# applied to the group's value, (S, K, G, rep, 2, 2D) float32; the caller
+# subtracts them.  A group's K and V are read once for both maps and all
+# rep heads.
+def _diff_products(q6):
+    s, k_w, g, rep, _, d = q6.shape
+    scale = d ** -0.5
+    zero = jnp.zeros_like(q6[..., 0, :])
+    halves = jnp.stack([jnp.concatenate([q6[..., 0, :], zero], -1),
+                        jnp.concatenate([zero, q6[..., 1, :]], -1)],
+                       axis=-2)                       # (S,K,G,rep,2,2D)
+    own = jnp.eye(g, dtype=q6.dtype)
+    qx = jnp.einsum("sqgrce,gh->sqgrche", halves, own).reshape(
+        s, k_w, g * rep * 2, g * 2 * d)
+
+    def score(kb):                     # (S, t, E) -> (S, K, X, t)
+        return jnp.einsum("sqxe,ste->sqxt", qx, kb,
+                          preferred_element_type=jnp.float32) * scale
+
+    def mix(p, vb):                    # -> (S, K, X, E)
+        return jnp.einsum("sqxt,ste->sqxe", p, vb,
+                          preferred_element_type=jnp.float32)
+
+    def own_columns(out):              # (S, K, X, E) -> (S,K,G,rep,2,2D)
+        out = out.reshape(s, k_w, g, rep * 2, g, 2 * d)
+        return jnp.einsum("sqgxhe,gh->sqgxe", out, own.astype(out.dtype)) \
+            .reshape(s, k_w, g, rep, 2, 2 * d)
+
+    return score, mix, own_columns
+
+
+def paged_diff_attention(q6, k_pool, v_pool, layer, block_tables, positions,
+                         kv_len):
+    """`paged_attention`'s sibling for differential heads over a pool of
+    flat rows (L, N, block_size, E): the same grouped running soft-max
+    over the live blocks of `[layer]`."""
+    score, mix, own_columns = _diff_products(q6)
+    g, rep, d = q6.shape[2], q6.shape[3], q6.shape[5]
+    return own_columns(_paged_running_softmax(
+        k_pool, v_pool, layer, block_tables, positions, kv_len, score, mix,
+        (g * rep * 2,), g * 2 * d))
+
+
+def window_diff_attention(q6, k_ring, v_ring, positions, kv_len, window):
+    """Differential attention over a ring a lane: `k_ring` / `v_ring`
+    (S, R, E) hold position p at row p % R, rows written for the
+    positions below `kv_len` (S,) and no others.  Row j therefore holds
+    the largest position <= kv_len - 1 that is j modulo R (negative:
+    never written), and a query at position t sees the rows whose
+    position p has t - window < p <= t.  The whole ring is read, in one
+    soft-max: R is a window and a chunk, whatever the lane's length.  An
+    idle lane (kv_len 0) sees nothing and returns garbage nobody reads."""
+    r = k_ring.shape[1]
+    top = (kv_len - 1)[:, None]
+    held = top - jnp.mod(top - jnp.arange(r)[None, :], r)          # (S, R)
+    held, pos = held[:, None, :], positions[:, :, None]
+    seen = (held <= pos) & (held > pos - window) & (held >= 0)     # (S,K,R)
+    score, mix, own_columns = _diff_products(q6)
+    sc = jnp.where(seen[:, :, None, :], score(k_ring), _NEG_INF)
+    return own_columns(mix(jax.nn.softmax(sc, axis=-1), v_ring))

@@ -11,6 +11,20 @@ the table entry the allocator hands back).
 Block 0 is the reserved NULL block: never allocated, every unused table
 entry points at it, so the compiled gather/scatter is always in-bounds.
 
+What the blocks are to a sequence depends on the model
+(`models.decoding.init_sequence_state`).  For a model whose every layer
+keeps every position they are the whole sequence, which is what prefix
+sharing, copy-on-write, speculation and KV shipping rest on.  A model
+may keep two more kinds of state that this allocator does not own: a
+bounded window of KV a slot for its sliding-window layers, and recurrent
+state a slot for its state-space layers (`models.hybrid.HybridState`),
+both indexed by the engine's slot and held by whoever holds the slot.
+The blocks then carry only the layers that keep every position, a prefix
+hit would skip positions whose state nobody kept, and
+`PagedLLMEngine` runs this allocator with `prefix_sharing=False` for
+such a model (`lookup_prefix` finds nothing, `register_prefix` keeps
+nothing, `cow` never copies).
+
 The pool's bytes are carved out of the node's shared-memory object store
 through the create-then-fill seam (ObjectStore.create_arena): the arena
 reservation makes KV pressure visible to the store accounting/syncer

@@ -118,7 +118,8 @@ class _Request:
 # One record of engine_stats()["tick_log"], in this order (the stats
 # carry the names as "tick_fields", so a reader needs no copy of them).
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
-               "lanes", "width", "prefill_tokens")
+               "lanes", "width", "prefill_tokens", "kv_read_tokens",
+               "reset_s")
 
 
 class _TickAccounts:
@@ -126,11 +127,12 @@ class _TickAccounts:
     clock reads they take anyway (nothing per lane or per token) and
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
-                 "prefill_tokens")
+                 "prefill_tokens", "kv_read_tokens", "reset_s")
 
     def __init__(self):
-        self.decode_s = self.prefill_s = self.sample_s = 0.0
+        self.decode_s = self.prefill_s = self.sample_s = self.reset_s = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
+        self.kv_read_tokens = 0
 
 
 def _snapshot(log: deque) -> tuple:
@@ -720,6 +722,33 @@ class PagedLLMEngine(_EngineBase):
     Admission: a request needs pool blocks covering its (non-shared)
     prompt remainder.  When the pool can't cover it, the request WAITS
     at the head of the queue (no error) until completions free blocks.
+
+    The state of a sequence is asked of the model
+    (`models.decoding.init_sequence_state`), and has up to three kinds:
+
+    - paged KV (`kv_paged`): every position of the layers that keep
+      them all, in pool blocks the `KVBlockAllocator` hands out and a
+      block table names.  Every model has it; for a `TransformerConfig`
+      it is all there is, and the blocks *are* the sequence: prefix
+      sharing, copy-on-write, speculation (a rejected draft is rolled
+      back by length alone), `export_streams` / `import_prefix` all
+      rest on that.
+    - bounded window KV (`kv_window`): a ring of window + prefill_chunk
+      positions a slot for each sliding-window layer, indexed by the
+      engine's slot, owned by whoever holds the slot.
+    - recurrent state (`recurrent`): a state-space layer's conv rows
+      and state by slot.  It is zeroed when a request is admitted to the
+      slot (`_reset_slot_state`, counted in the tick's `reset_s`),
+      carried from chunk to chunk of a prefill, left untouched by a
+      chunk's padded tail and by idle lanes, and zeroed again when
+      `_preempt` sends a stream to re-prefill.
+
+    With a model whose configuration says `recurrent` the blocks are
+    not the sequence, so the engine turns prefix sharing off itself (a
+    hit would skip positions whose state nobody kept) and refuses, with
+    a ValueError, `speculation_k >= 2` at construction and
+    `export_streams` / `import_prefix` when called: snapshots of state
+    are what each would need.
     """
     TICKS_KEPT = 4096
 
@@ -737,7 +766,7 @@ class PagedLLMEngine(_EngineBase):
 
         from ray_tpu.core.config import get_config
         from ray_tpu.models.decoding import (
-            init_paged_cache,
+            init_sequence_state,
             make_paged_engine_fns,
             make_paged_spec_fns,
             sample_one,
@@ -776,6 +805,13 @@ class PagedLLMEngine(_EngineBase):
         if speculation_ngram is None:
             speculation_ngram = knobs.serve_speculation_ngram
         self._spec_k = speculation_k if speculation_k >= 2 else 0
+        # A model whose sequences keep recurrent state by slot.
+        self._recurrent = bool(getattr(cfg, "recurrent", False))
+        if self._recurrent and self._spec_k:
+            raise ValueError(
+                f"speculation_k={speculation_k} with {cfg.name!r}: a "
+                f"rejected draft has already advanced the recurrent "
+                f"state, and there is no snapshot to roll it back to")
         self._spec_ngram = max(1, speculation_ngram)
         # The free-margin _maybe_finish keeps must cover whichever
         # advance is larger — a burst OR a spec window — without
@@ -784,18 +820,29 @@ class PagedLLMEngine(_EngineBase):
         self._b_max = math.ceil(max_len / self.block_size)
         prefix_sharing = (knobs.kv_block_prefix_sharing
                           if prefix_sharing is None else prefix_sharing)
+        if self._recurrent:
+            # A hit would skip positions whose state nobody kept.
+            prefix_sharing = False
         self._jax = jax
         self._jnp = jnp
         self._rng = jax.random.key(seed)
-        self.cache = init_paged_cache(cfg, self.num_blocks, self.block_size)
+        self.cache = init_sequence_state(
+            cfg, self.num_blocks, self.block_size, num_slots=num_slots,
+            prefill_chunk=self.prefill_chunk)
+        self._state_bytes = self.cache.resident_bytes()
+        self._reset_state = (jax.jit(cfg.reset_slot, donate_argnums=(0,))
+                             if self._recurrent else None)
+        self._score_step = None
+        # KV positions one decode step sees over lanes of given lengths:
+        # the model's own count, or every layer over every position.
+        self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
+            lambda lengths: cfg.n_layers * sum(lengths))
         self._prefill_chunk_fn, self._decode, self._copy_block = \
             make_paged_engine_fns(cfg)
         if self._spec_k:
             self._verify = make_paged_spec_fns(cfg)
         self._sample_one = jax.jit(sample_one)
-        bytes_per_block = (2 * cfg.n_layers * self.block_size
-                           * cfg.n_kv_heads * cfg.head_dim
-                           * jnp.zeros((), cfg.compute_dtype).dtype.itemsize)
+        bytes_per_block = self._state_bytes["kv_paged"] // self.num_blocks
         self.allocator = KVBlockAllocator(
             self.num_blocks, self.block_size, store=store,
             bytes_per_block=bytes_per_block if store is not None else 0,
@@ -823,7 +870,8 @@ class PagedLLMEngine(_EngineBase):
                       "preemptions": 0, "adopted_blocks": 0,
                       "migrated_blocks": 0, "migrate_fallbacks": 0,
                       "disagg_prefills": 0,
-                      "spec_proposed": 0, "spec_accepted": 0}
+                      "spec_proposed": 0, "spec_accepted": 0,
+                      "state_resets": 0, "state_rebuilds": 0}
         self._request_phases: deque = deque(
             maxlen=self.REQUEST_PHASES_KEPT)
         # engine_stats()["tick_log"]: one tuple per tick that progressed
@@ -849,6 +897,12 @@ class PagedLLMEngine(_EngineBase):
     def engine_stats(self, records: bool = True) -> Dict[str, Any]:
         s = super().engine_stats(records)
         s.update(self.allocator.snapshot())
+        # Resident bytes of the sequences' state by kind, and how often
+        # recurrent state was zeroed (admissions and preemptions) and
+        # rebuilt (preempted streams whose re-prefill finished).
+        s["state"] = {**self._state_bytes,
+                      "state_resets": s.pop("state_resets"),
+                      "state_rebuilds": s.pop("state_rebuilds")}
         if records:
             s["tick_log"] = _snapshot(self._tick_log)
             s["tick_fields"] = TICK_FIELDS
@@ -868,7 +922,8 @@ class PagedLLMEngine(_EngineBase):
                 self.params, self.cache, jnp.asarray(z),
                 jnp.zeros((w, self._b_max), jnp.int32), jnp.asarray(z),
                 jnp.zeros((w,), bool), jnp.zeros((w,), jnp.float32),
-                self._rng, n_steps=self.max_burst)
+                self._rng, n_steps=self.max_burst,
+                **self._lanes_kw([], w))
             if self._spec_k:
                 self.cache, _, _, self._rng = self._verify(
                     self.params, self.cache,
@@ -880,7 +935,7 @@ class PagedLLMEngine(_EngineBase):
             self.cache, _ = self._prefill_chunk_fn(
                 self.params, self.cache, jnp.zeros((c,), jnp.int32),
                 jnp.zeros((self._b_max,), jnp.int32), jnp.int32(0),
-                jnp.int32(0))
+                jnp.int32(0), **self._slot_kw(self.num_slots))
 
     def gauges(self) -> Dict[str, float]:
         """Cheap autoscaling signals (riding the syncer push)."""
@@ -916,6 +971,37 @@ class PagedLLMEngine(_EngineBase):
     def _table_row(self, slot: int, blocks: List[int]) -> None:
         self._tables[slot, :] = 0
         self._tables[slot, :len(blocks)] = blocks
+
+    # -- state by slot (models that keep more than paged KV) -------------
+    def _slot_kw(self, slot: int) -> Dict[str, Any]:
+        """The prefill chunk's `slot` argument: the engine slot whose
+        ring and recurrent state the chunk reads and writes (num_slots:
+        the null slot).  Nothing for a model whose state is the pool
+        alone: its programs are called as they always were."""
+        return ({"slot": self._jnp.int32(slot)} if self._recurrent else {})
+
+    def _lanes_kw(self, idx: List[int], width: int) -> Dict[str, Any]:
+        """The burst's `slots` argument: lane j is engine slot idx[j];
+        the lanes past them are idle and point at the null slot."""
+        if not self._recurrent:
+            return {}
+        slots = np.full((width,), self.num_slots, np.int32)
+        slots[:len(idx)] = idx
+        return {"slots": self._jnp.asarray(slots)}
+
+    def _reset_slot_state(self, req: "_Request", slot: int) -> None:
+        """Zero `slot`'s recurrent state: `req` was admitted to it, or
+        was preempted and will re-prefill.  A launch, counted in the
+        tick's `reset_s`; the span is the request's own."""
+        if not self._recurrent:
+            return
+        t0 = time.time()
+        self.cache = self._reset_state(self.cache, self._jnp.int32(slot))
+        t1 = time.time()
+        self._acct.reset_s += t1 - t0
+        self.stats["state_resets"] += 1
+        tracing.record_serve_span(req.trace, "serve.engine.state_reset",
+                                  t0, t1, slot=slot)
 
     def _admit_one(self) -> bool:
         import jax.numpy as jnp
@@ -960,6 +1046,7 @@ class PagedLLMEngine(_EngineBase):
         self._slots[slot] = req
         self._table_row(slot, blocks)
         self._lengths[slot] = 0
+        self._reset_slot_state(req, slot)
         if covered > 0:
             self.stats["prefix_hits"] += 1
         if covered == n:
@@ -1076,7 +1163,8 @@ class PagedLLMEngine(_EngineBase):
                 self.cache, last_logits = self._prefill_chunk_fn(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(self._tables[slot].copy()),
-                    jnp.int32(req.pos), jnp.int32(nv))
+                    jnp.int32(req.pos), jnp.int32(nv),
+                    **self._slot_kw(slot))
                 req.pos += nv
                 budget -= nv
                 progressed = True
@@ -1086,6 +1174,8 @@ class PagedLLMEngine(_EngineBase):
                 acct.prefill_tokens += nv
                 if req.pos >= n:
                     self._prefillq.popleft()
+                    if self._recurrent and req.out_tokens:
+                        self.stats["state_rebuilds"] += 1
                     if not req.out_tokens and not req.no_register:
                         # Publish the prompt's blocks for prefix reuse
                         # BEFORE our own appends diverge the tail (COW
@@ -1160,6 +1250,8 @@ class PagedLLMEngine(_EngineBase):
         # so lane mapping is just row selection.
         w = self._tier_for(self._width_tiers, len(idx))
         self._acct.lanes, self._acct.width = len(idx), w
+        self._acct.kv_read_tokens = self._kv_read_tokens(
+            [int(self._lengths[i]) for i in idx])
         tokens = np.zeros((w,), np.int32)
         tables = np.zeros((w, self._b_max), np.int32)
         lengths = np.zeros((w,), np.int32)
@@ -1180,7 +1272,7 @@ class PagedLLMEngine(_EngineBase):
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(active), jnp.asarray(temps), self._rng,
-                n_steps=burst)
+                n_steps=burst, **self._lanes_kw(idx, w))
             tok_mat = np.asarray(tok_mat)              # (burst, w)
             t1 = time.time()
             self._acct.decode_s = t1 - t0
@@ -1283,6 +1375,7 @@ class PagedLLMEngine(_EngineBase):
         req.blocks = []
         self._tables[slot, :] = 0
         self._lengths[slot] = 0
+        self._reset_slot_state(req, slot)
         req.pos = 0
         req.prefilling = True
         self._prefillq.append(slot)
@@ -1318,7 +1411,13 @@ class PagedLLMEngine(_EngineBase):
         decoding lanes in the burst's tier.  `prefill_tokens`: prompt
         tokens the chunks carried.  So tick_s - decode_s -
         prefill_s - sample_s is the tick's time in which the host
-        neither waited for the device nor launched a chunk."""
+        neither waited for the device nor launched a chunk.
+        `kv_read_tokens`: KV positions one step of the burst sees, summed
+        over its lanes and the layers that read (the model's count;
+        n_layers x the lanes' lengths where every layer keeps every
+        position).  `reset_s`: launches that zeroed the recurrent state
+        of slots this tick admitted to or preempted (0 for a model that
+        has none)."""
         start = time.time()
         acct = self._acct = _TickAccounts()
         progressed = False
@@ -1331,7 +1430,7 @@ class PagedLLMEngine(_EngineBase):
             self._tick_log.append(
                 (start, time.time() - start, acct.decode_s, acct.prefill_s,
                  acct.sample_s, acct.lanes, acct.width,
-                 acct.prefill_tokens))
+                 acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s))
         return progressed
 
     def _loop(self):
@@ -1342,7 +1441,89 @@ class PagedLLMEngine(_EngineBase):
                 self._work.wait(timeout=0.02)
                 self._work.clear()
 
+    # -- scoring -----------------------------------------------------------
+    def score(self, seqs, n_prompt: int) -> List[List[Any]]:
+        """Logits by the engine's own programs, for a comparison with a
+        reference.  Each row of `seqs` (lanes, n_prompt + steps) gets a
+        slot and blocks of its own: its first `n_prompt` tokens are
+        prefilled through the engine's jitted chunk program, in the
+        engine's chunks and chunk tiers (so a last chunk is padded as a
+        served one is), then the rest is teacher-forced, all lanes a
+        step, through `paged_decode_step` (the function the burst scans;
+        the burst itself returns sampled tokens, never logits) at the
+        engine's width tier, every kind of sequence state included.
+        Returns per lane the logits at positions n_prompt - 1 .. the
+        last but one: 1 + steps arrays of (V,).  The engine must be
+        idle; its state is left as after requests that finished."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models.decoding import _bind_cfg, paged_decode_step
+
+        seqs = np.asarray(seqs)
+        lanes, total = seqs.shape
+        if lanes > self.num_slots or total > self.max_len:
+            raise ValueError(f"score(): {lanes} lanes of {total} tokens do "
+                             f"not fit {self.num_slots} slots of "
+                             f"{self.max_len}")
+        if self._score_step is None:
+            self._score_step = jax.jit(
+                _bind_cfg(paged_decode_step, self.cfg), donate_argnums=(1,))
+        per_lane = math.ceil(total / self.block_size)
+        got: List[List[Any]] = [[] for _ in range(lanes)]
+        with self._tick_lock:
+            if any(r is not None for r in self._slots) or self._pending:
+                raise RuntimeError("score() needs an idle engine")
+            blocks = self.allocator.alloc(lanes * per_lane)
+            if blocks is None:
+                raise RuntimeError("score(): the pool cannot hold "
+                                   f"{lanes} x {total} positions")
+            try:
+                w = self._tier_for(self._width_tiers, lanes)
+                tables = np.zeros((w, self._b_max), np.int32)
+                for lane in range(lanes):
+                    tables[lane, :per_lane] = blocks[
+                        lane * per_lane:(lane + 1) * per_lane]
+                    if self._recurrent:
+                        self.cache = self._reset_state(self.cache,
+                                                       jnp.int32(lane))
+                    for start in range(0, n_prompt, self.prefill_chunk):
+                        nv = min(self.prefill_chunk, n_prompt - start)
+                        toks = np.zeros(
+                            (self._tier_for(self._chunk_tiers, nv),),
+                            np.int32)
+                        toks[:nv] = seqs[lane, start:start + nv]
+                        self.cache, last = self._prefill_chunk_fn(
+                            self.params, self.cache, jnp.asarray(toks),
+                            jnp.asarray(tables[lane]), jnp.int32(start),
+                            jnp.int32(nv), **self._slot_kw(lane))
+                    got[lane].append(last)         # position n_prompt - 1
+                active = np.arange(w) < lanes
+                on_device = (jnp.asarray(tables), jnp.asarray(active))
+                lanes_kw = self._lanes_kw(list(range(lanes)), w)
+                for i in range(n_prompt, total):
+                    tok = np.zeros((w,), np.int32)
+                    tok[:lanes] = seqs[:, i]
+                    self.cache, logits = self._score_step(
+                        self.params, self.cache, jnp.asarray(tok),
+                        on_device[0],
+                        jnp.asarray(np.where(active, i, 0).astype(np.int32)),
+                        on_device[1], **lanes_kw)
+                    for lane in range(lanes):
+                        got[lane].append(logits[lane])     # position i
+            finally:
+                self.allocator.free(blocks)
+        return got
+
     # -- disaggregated serving / live migration -------------------------
+    def _refuse_if_recurrent(self, what: str) -> None:
+        if self._recurrent:
+            raise ValueError(
+                f"{what} with {self.cfg.name!r}: its sequences keep "
+                f"recurrent state by slot, and pool blocks alone are not "
+                f"a sequence; shipping or adopting one needs a snapshot "
+                f"of that state, which this engine does not take")
+
     def import_prefix(self, tokens: List[int], kv, block_size: int,
                       last_logits=None) -> int:
         """Adopt a KV frame computed by ANOTHER engine (a dedicated
@@ -1360,6 +1541,7 @@ class PagedLLMEngine(_EngineBase):
 
         from ray_tpu.models.decoding import scatter_blocks
 
+        self._refuse_if_recurrent("import_prefix")
         kv = np.asarray(kv)
         n_need = -(-len(tokens) // self.block_size)
         if (block_size != self.block_size or kv.ndim != 6
@@ -1399,6 +1581,7 @@ class PagedLLMEngine(_EngineBase):
 
         from ray_tpu.models.decoding import gather_blocks
 
+        self._refuse_if_recurrent("export_streams")
         out: List[Dict[str, Any]] = []
         bs = self.block_size
         with self._tick_lock:
@@ -1468,21 +1651,34 @@ class LLMDeployment:
                  disagg: Optional[bool] = None,
                  params_loader: Optional[Callable] = None):
         """`cfg_name`: a registry name (ray_tpu.models.configs) or a
-        TransformerConfig instance — e.g. the config half of
+        configuration object (a TransformerConfig, or a model that
+        brings its own sequence state such as models.hybrid.HybridConfig)
+        — e.g. the config half of
         `ray_tpu.models.from_hf(...)`, with `params_loader` returning
         the converted weights (serve real HF checkpoints)."""
         import jax
 
-        from ray_tpu.models import TransformerConfig, configs, init_params
+        from ray_tpu.models import configs, init_params
         from ray_tpu.util import compile_cache
 
         # Start counting before the first compile, so runtime_report()
         # can say what this replica's start cost in compiles.
         compile_cache.counts()
-        cfg = (cfg_name if isinstance(cfg_name, TransformerConfig)
-               else configs.get(cfg_name))
-        params = (params_loader() if params_loader
-                  else init_params(jax.random.key(seed), cfg))
+        cfg = (configs.get(cfg_name) if isinstance(cfg_name, str)
+               else cfg_name)
+        recurrent = bool(getattr(cfg, "recurrent", False))
+        if recurrent and (engine != "paged" or tensor_parallel > 1
+                          or disagg):
+            raise ValueError(
+                f"{cfg.name!r} keeps recurrent state by slot: it is served "
+                f"by the paged engine on one device, without disaggregated "
+                f"prefill (a shipped KV frame is not its sequence)")
+        if params_loader:
+            params = params_loader()
+        else:       # a model that brings its own stack brings its own
+            own_init = getattr(cfg, "init_params", None)
+            params = (own_init(jax.random.key(seed)) if own_init
+                      else init_params(jax.random.key(seed), cfg))
         mesh = None
         if tensor_parallel > 1:
             # Claim N local chips as a tp mesh for this replica (the
@@ -1536,7 +1732,7 @@ class LLMDeployment:
         from ray_tpu.core.config import get_config
 
         if disagg is None:
-            disagg = get_config().serve_disagg_enabled
+            disagg = get_config().serve_disagg_enabled and not recurrent
         self._disagg = None
         self.disagg_role = "unified"
         # Prefill actors re-derive weights from (cfg, seed); a custom

@@ -284,3 +284,44 @@ def test_served_step_does_not_copy_the_pool(topo, program):
         (math.prod(map(int, dims.split(","))), dims)
         for dims in re.findall(r"f32\[([0-9,]+)\]", hlo))
     assert largest[0] < window, largest
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_hybrid_served_programs_fit_one_chip(topo, program):
+    """Phi-4-mini-flash-reasoning whole (32 layers, 200,064 tokens) at the
+    benchmark's serving shape (32 slots x 8192, block 16: layer 17's pool
+    1.34 GB, eight rings of 640 rows a slot 0.87 GB, recurrent state 0.11
+    GB beside 7.71 GB of weights): the widest burst and the 128-token
+    chunk compile for one v5e chip and their live bytes fit its 15.75 GB
+    usable.  Pool, rings and state are updated in place (their bytes are
+    aliased), and neither program holds a copy of the pool or of a ring
+    among its temporaries: with a trailing dimension of 64 the compiler
+    laid them out otherwise and copied all of them, 5.7 GB of temporaries
+    a burst and 8.5 GB a chunk, which did not fit."""
+    import json
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "phi4-mini-flash-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    resident, programs = spec.family(config).serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(resident["sequence_state"]))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize
+        for s in jax.tree.leaves(resident["params"]))
+    assert abs(resident_bytes - 10.0e9) < 1.0e9, resident_bytes
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
