@@ -86,7 +86,24 @@ def _qkv(bp, x, cfg, positions):
     return q, k, v
 
 
-def _mlp(bp, x, cfg):
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _layer_xs(blocks, cfg):
+    """`blocks` split for a scan over layers: (the scan's xs, the expert
+    weights of all layers kept whole, None without experts).  The second
+    goes to `_mlp` with the layer's index: see `moe_mlp_dropless`."""
+    if cfg.n_experts <= 0:
+        return blocks, None
+    return ({k: v for k, v in blocks.items() if k not in _EXPERT_WEIGHTS},
+            {k: blocks[k] for k in _EXPERT_WEIGHTS})
+
+
+def _mlp(bp, x, cfg, experts=None, li=None, live=None):
+    """The block's FFN over x (S, K, d).  Returns (out, experts visited):
+    with `experts` (the stacks of `_layer_xs`; `li` the layer), only
+    those that a row of a `live` lane (S,) bool is routed to are read
+    (None: every lane is live); 0 for a dense FFN."""
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["mlp_norm"], eps=cfg.norm_eps)
     if cfg.n_experts > 0:
@@ -95,13 +112,12 @@ def _mlp(bp, x, cfg):
         # see moe_mlp_dropless.
         from ray_tpu.ops.moe import moe_mlp_dropless
 
-        return moe_mlp_dropless(
-            h, {"router": bp["router"], "w_gate": bp["w_gate"],
-                "w_up": bp["w_up"], "w_down": bp["w_down"]}, cfg.moe)
+        return moe_mlp_dropless(h, {"router": bp["router"], **experts},
+                                cfg.moe, live=live, layer=li)
     gate = jnp.einsum("btd,df->btf", h, bp["w_gate"].astype(cd))
     up = jnp.einsum("btd,df->btf", h, bp["w_up"].astype(cd))
     return jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * up,
-                      bp["w_down"].astype(cd))
+                      bp["w_down"].astype(cd)), jnp.int32(0)
 
 
 def _gqa(q, k, v, cfg):
@@ -145,11 +161,12 @@ def prefill(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
         attn = jnp.einsum("bhqk,bkhd->bqhd", p, vh.astype(jnp.float32))
         attn = attn.reshape(1, t, cfg.n_heads * cfg.head_dim).astype(cd)
         x = x + jnp.einsum("bth,hd->btd", attn, bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
+        x = x + _mlp(bp, x, cfg, experts, li)[0]
         return x, (k[0], v[0])  # (T, Hkv, D) for cache write
 
     idx = jnp.arange(cfg.n_layers)
-    x, kv = jax.lax.scan(layer, x, (params["blocks"], idx))
+    blocks, experts = _layer_xs(params["blocks"], cfg)
+    x, kv = jax.lax.scan(layer, x, (blocks, idx))
     k_new, v_new = kv  # (L, T, Hkv, D)
     t_cache = cache.k.shape[2]
     pad = t_cache - t
@@ -187,7 +204,7 @@ def _wide_decode(params, cache: KVCache, tokens: jax.Array,
 
     def layer(carry, layer_in):
         x = carry
-        bp, k_cache, v_cache = layer_in
+        bp, li, k_cache, v_cache = layer_in
         q, k, v = _qkv(bp, x, cfg, positions)              # (S,K,H,D)
         k_cache = jax.vmap(
             lambda kc, kn, p: jax.lax.dynamic_update_slice(
@@ -208,10 +225,12 @@ def _wide_decode(params, cache: KVCache, tokens: jax.Array,
         attn = attn.reshape(s_count, k_w, cfg.n_heads * cfg.head_dim)
         x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
                            bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
+        x = x + _mlp(bp, x, cfg, experts, li)[0]
         return x, (k_cache, v_cache)
 
-    x, new_kv = jax.lax.scan(layer, x, (params["blocks"], cache.k, cache.v))
+    blocks, experts = _layer_xs(params["blocks"], cfg)
+    x, new_kv = jax.lax.scan(
+        layer, x, (blocks, jnp.arange(cfg.n_layers), cache.k, cache.v))
     new_k, new_v = new_kv
     logits = _final_logits(params, x, cfg)                 # (S, K, vocab)
     return logits, new_k, new_v
@@ -494,12 +513,14 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
                     cfg, slots):
     """The served step of `cfg`'s model: `_paged_forward`, or the model's
     own over its own sequence state, which also takes the lanes' engine
-    `slots` (S,) (None for a model whose state is the pool alone)."""
+    `slots` (S,) (None for a model whose state is the pool alone).
+    Returns (cache, hidden, experts visited: 0 for a model's own step)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
         return _paged_forward(params, cache, tokens, block_tables, positions,
                               kv_len, cfg)
-    return own(params, cache, tokens, block_tables, positions, kv_len, slots)
+    return (*own(params, cache, tokens, block_tables, positions, kv_len,
+                 slots), jnp.int32(0))
 
 
 def _served_logits(params, x, cfg):
@@ -513,7 +534,9 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     """The one served step: `tokens` (S, K) at absolute `positions` (S, K)
     through every layer, over the tables (S, B_max) of the lanes' blocks.
     `kv_len` (S,) is each lane's length once its tokens are in (0: an idle
-    lane, which writes the null block).  Returns (cache, hidden (S, K, d)).
+    lane, which writes the null block and is routed to no expert).
+    Returns (cache, hidden (S, K, d), experts visited summed over the
+    layers: `ops.moe.moe_mlp_dropless`; 0 without experts).
 
     Write-then-read, in place: a layer scatters the tokens' KV into the
     pool at [layer, table[pos // bs], pos % bs] first, so the attention
@@ -524,14 +547,15 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     """
     cd = cfg.compute_dtype
     bs = cache.k.shape[2]
-    live = (kv_len > 0)[:, None]
+    live_lane = kv_len > 0
+    live = live_lane[:, None]
     wb = jnp.where(live, jnp.take_along_axis(
         block_tables, positions // bs, axis=1), 0)         # (S, K)
     off = jnp.where(live, positions % bs, 0)
     x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
 
     def layer(carry, layer_in):
-        x, k_pool, v_pool = carry
+        x, k_pool, v_pool, visited = carry
         bp, li = layer_in
         q, k, v = _qkv(bp, x, cfg, positions)              # (S,K,H,D)
         k_pool = k_pool.at[li, wb, off].set(k.astype(k_pool.dtype))
@@ -541,13 +565,14 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         attn = attn.reshape(*tokens.shape, cfg.n_heads * cfg.head_dim)
         x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
                            bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg)
-        return (x, k_pool, v_pool), None
+        out, n = _mlp(bp, x, cfg, experts, li, live_lane)
+        return (x + out, k_pool, v_pool, visited + n), None
 
-    (x, k_pool, v_pool), _ = jax.lax.scan(
-        layer, (x, cache.k, cache.v),
-        (params["blocks"], jnp.arange(cfg.n_layers)))
-    return PagedKVCache(k=k_pool, v=v_pool), x
+    blocks, experts = _layer_xs(params["blocks"], cfg)
+    (x, k_pool, v_pool, visited), _ = jax.lax.scan(
+        layer, (x, cache.k, cache.v, jnp.int32(0)),
+        (blocks, jnp.arange(cfg.n_layers)))
+    return PagedKVCache(k=k_pool, v=v_pool), x, visited
 
 
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
@@ -566,19 +591,18 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     engine drops.  `slots` (S,): the lanes' engine slots, for a model
     whose sequences keep state by slot (`init_sequence_state`).
     """
-    cache, x = _served_forward(
+    cache, logits, _ = _paged_decode_logits(
+        params, cache, tokens, block_tables, lengths, active, cfg, slots)
+    return cache, logits
+
+
+def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
+                         active, cfg, slots):
+    """`paged_decode_step` with the step's count of experts visited."""
+    cache, x, visited = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
         jnp.where(active, lengths + 1, 0), cfg, slots)
-    return cache, _served_logits(params, x, cfg)[:, 0]     # (S, vocab)
-
-
-def paged_decode_and_sample(params, cache: PagedKVCache, tokens,
-                            block_tables, lengths, active, temps, rng,
-                            cfg: TransformerConfig, slots=None):
-    cache, logits = paged_decode_step(params, cache, tokens, block_tables,
-                                      lengths, active, cfg, slots)
-    rng, sub = jax.random.split(rng)
-    return cache, sample_per_slot(logits, sub, temps), rng
+    return cache, _served_logits(params, x, cfg)[:, 0], visited  # (S, V)
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
@@ -588,19 +612,22 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
     Block tables are static across the burst — the engine pre-extends
     each active slot's table to cover lengths + n_steps before issuing.
     The pool is the step loop's carry too: n_steps in-place writes.
-    Returns (cache, token_matrix (n_steps, S), rng)."""
+    Returns (cache, token_matrix (n_steps, S), rng, experts visited:
+    int32, summed over the steps and the layers)."""
 
     def tick(carry, _):
-        cache, toks, lengths, rng = carry
-        cache, nxt, rng = paged_decode_and_sample(
-            params, cache, toks, block_tables, lengths, active, temps,
-            rng, cfg, slots)
+        cache, toks, lengths, rng, visited = carry
+        cache, logits, n = _paged_decode_logits(
+            params, cache, toks, block_tables, lengths, active, cfg, slots)
+        rng, sub = jax.random.split(rng)
+        nxt = sample_per_slot(logits, sub, temps)
         lengths = jnp.where(active, lengths + 1, lengths)
-        return (cache, nxt, lengths, rng), nxt
+        return (cache, nxt, lengths, rng, visited + n), nxt
 
-    (cache, _, _, rng), toks = jax.lax.scan(
-        tick, (cache, tokens, lengths, rng), None, length=n_steps)
-    return cache, toks, rng
+    (cache, _, _, rng, visited), toks = jax.lax.scan(
+        tick, (cache, tokens, lengths, rng, jnp.int32(0)), None,
+        length=n_steps)
+    return cache, toks, rng, visited
 
 
 def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
@@ -621,7 +648,7 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     chunk's logits.
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x = _served_forward(
+    cache, x, _ = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
         (start + n_valid)[None], cfg,
         None if slot is None else jnp.asarray(slot, jnp.int32)[None])
@@ -660,7 +687,7 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     """
     k_w = cand_tokens.shape[1]
     positions = lengths[:, None] + jnp.arange(k_w, dtype=jnp.int32)  # (S,K)
-    cache, x = _paged_forward(
+    cache, x, _ = _paged_forward(
         params, cache, cand_tokens, block_tables, positions,
         jnp.where(active, lengths + k_w, 0), cfg)
     logits = _final_logits(params, x, cfg)               # (S, K, vocab)

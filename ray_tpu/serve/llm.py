@@ -119,7 +119,7 @@ class _Request:
 # carry the names as "tick_fields", so a reader needs no copy of them).
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "kv_read_tokens",
-               "reset_s")
+               "reset_s", "experts_read")
 
 
 class _TickAccounts:
@@ -127,10 +127,12 @@ class _TickAccounts:
     clock reads they take anyway (nothing per lane or per token) and
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
-                 "prefill_tokens", "kv_read_tokens", "reset_s")
+                 "prefill_tokens", "kv_read_tokens", "reset_s",
+                 "experts_read")
 
     def __init__(self):
-        self.decode_s = self.prefill_s = self.sample_s = self.reset_s = 0.0
+        self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
+            self.experts_read = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = 0
 
@@ -837,6 +839,10 @@ class PagedLLMEngine(_EngineBase):
         # the model's own count, or every layer over every position.
         self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
             lambda lengths: cfg.n_layers * sum(lengths))
+        # Layers whose FFN is a set of experts: the burst's count of
+        # experts visited is per layer and step over these.
+        self._expert_layers = (cfg.n_layers
+                               if getattr(cfg, "n_experts", 0) > 0 else 0)
         self._prefill_chunk_fn, self._decode, self._copy_block = \
             make_paged_engine_fns(cfg)
         if self._spec_k:
@@ -918,7 +924,7 @@ class PagedLLMEngine(_EngineBase):
 
         for w in self._width_tiers:
             z = np.zeros((w,), np.int32)
-            self.cache, _, self._rng = self._decode(
+            self.cache, _, self._rng, _ = self._decode(
                 self.params, self.cache, jnp.asarray(z),
                 jnp.zeros((w, self._b_max), jnp.int32), jnp.asarray(z),
                 jnp.zeros((w,), bool), jnp.zeros((w,), jnp.float32),
@@ -1268,12 +1274,19 @@ class PagedLLMEngine(_EngineBase):
                                                 active, temps):
                 return True
             t0 = time.time()
-            self.cache, tok_mat, self._rng = self._decode(
+            self.cache, tok_mat, self._rng, visited = self._decode(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(active), jnp.asarray(temps), self._rng,
                 n_steps=burst, **self._lanes_kw(idx, w))
-            tok_mat = np.asarray(tok_mat)              # (burst, w)
+            if self._expert_layers:
+                # Tokens and count, both copies started before either is
+                # waited for: a second read after the first costs 0.6 ms.
+                tok_mat, visited = self._jax.device_get((tok_mat, visited))
+                self._acct.experts_read = int(visited) / (
+                    burst * self._expert_layers)
+            else:
+                tok_mat = np.asarray(tok_mat)          # (burst, w)
             t1 = time.time()
             self._acct.decode_s = t1 - t0
             for j, i in enumerate(idx):
@@ -1417,7 +1430,10 @@ class PagedLLMEngine(_EngineBase):
         n_layers x the lanes' lengths where every layer keeps every
         position).  `reset_s`: launches that zeroed the recurrent state
         of slots this tick admitted to or preempted (0 for a model that
-        has none)."""
+        has none).  `experts_read`: distinct experts the burst's live
+        lanes were routed to, and so read, per layer and step: the mean
+        over the burst's steps and the model's expert layers (0.0 for a
+        model without experts, or a tick without a burst)."""
         start = time.time()
         acct = self._acct = _TickAccounts()
         progressed = False
@@ -1430,7 +1446,8 @@ class PagedLLMEngine(_EngineBase):
             self._tick_log.append(
                 (start, time.time() - start, acct.decode_s, acct.prefill_s,
                  acct.sample_s, acct.lanes, acct.width,
-                 acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s))
+                 acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s,
+                 acct.experts_read))
         return progressed
 
     def _loop(self):

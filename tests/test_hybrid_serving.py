@@ -182,7 +182,7 @@ def test_a_burst_equals_its_steps_on_every_kind_of_state(served):
     key = jax.random.key(0)
     burst = jax.jit(decoding._bind_cfg(decoding.paged_decode_burst, cfg),
                     static_argnames=("n_steps",))
-    b_state, b_toks, _ = burst(e.params, state, toks, tables, lengths,
+    b_state, b_toks, _, _ = burst(e.params, state, toks, tables, lengths,
                                active, temps, key, n_steps=3, slots=slots)
     step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, cfg))
     s_toks = []
@@ -224,7 +224,7 @@ def test_a_slot_reused_by_a_second_request(served):
     assert stats["state"]["state_resets"] == resets + 2
     assert stats["prefix_hits"] == 0
     fields = stats["tick_fields"]
-    assert fields[-2:] == ("kv_read_tokens", "reset_s")
+    assert fields[-3:-1] == ("kv_read_tokens", "reset_s")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     assert any(t["reset_s"] > 0 for t in ticks)
     one = [t for t in ticks if t["lanes"] == 1][-1]

@@ -72,17 +72,19 @@ def ref_forward(params, cache, tokens, block_tables, positions, active, cfg):
     off = jnp.where(active[:, None], positions % bs, 0)
 
     def layer(x, layer_in):
-        bp, k_layer, v_layer = layer_in
+        bp, li, k_layer, v_layer = layer_in
         q, k, v = decoding._qkv(bp, x, cfg, positions)
         k_layer = k_layer.at[wb, off].set(k.astype(k_layer.dtype))
         v_layer = v_layer.at[wb, off].set(v.astype(v_layer.dtype))
         attn = ref_attention(q, k_layer, v_layer, block_tables, positions)
         attn = attn.reshape(*tokens.shape, -1).astype(cd)
         x = x + jnp.einsum("bth,hd->btd", attn, bp["wo"].astype(cd))
-        x = x + decoding._mlp(bp, x, cfg)
+        x = x + decoding._mlp(bp, x, cfg, experts, li, active)[0]
         return x, (k_layer, v_layer)
 
-    x, (k, v) = jax.lax.scan(layer, x, (params["blocks"], cache.k, cache.v))
+    blocks, experts = decoding._layer_xs(params["blocks"], cfg)
+    x, (k, v) = jax.lax.scan(
+        layer, x, (blocks, jnp.arange(cfg.n_layers), cache.k, cache.v))
     return PagedKVCache(k=k, v=v), decoding._final_logits(params, x, cfg)
 
 
@@ -322,7 +324,7 @@ def test_burst_pool_equals_steps_and_spares_other_blocks(model):
     owned = np.unique(np.asarray(tables))
     spare = np.setdiff1d(np.arange(1, cache.k.shape[1]), owned)
     temps = jnp.zeros((len(LENGTHS),), jnp.float32)
-    burst_cache, tok_mat, _ = paged_decode_burst(
+    burst_cache, tok_mat, _, _ = paged_decode_burst(
         params, cache, toks[:, 0], tables, lengths, active, temps,
         jax.random.key(0), cfg=cfg, n_steps=8)
     step_cache, cur, cur_len = cache, toks[:, 0], lengths

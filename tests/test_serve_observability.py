@@ -283,13 +283,13 @@ _PROMPT_LENS = (16, 32, 16, 32, 16, 32)    # whole chunks: no budget is
 #                                            left over for the next prompt
 
 
-def _tiny_paged(**kw):
+def _tiny_paged(model="tiny", **kw):
     import jax
 
     from ray_tpu.models import configs, init_params
     from ray_tpu.serve.llm import PagedLLMEngine
 
-    cfg = configs.get("tiny")
+    cfg = configs.get(model)
     kw.setdefault("num_slots", 8)
     kw.setdefault("max_len", 64)
     kw.setdefault("block_size", 4)
@@ -443,6 +443,43 @@ def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
     # launched its last chunk
     assert sum(t["sample_s"] > 0.0 for t in ticks) == len(_PROMPT_LENS)
     assert all(t["prefill_tokens"] for t in ticks if t["sample_s"])
+
+
+@pytest.mark.parametrize("model", ["tiny", "tiny-moe"])
+def test_tick_log_says_how_many_experts_a_burst_read(model):
+    """`experts_read`, the last of `tick_fields`: distinct experts the
+    burst's live lanes were routed to, per expert layer and step.  0.0
+    for a model without experts; with them between top_k (one live
+    token) and all of them on every tick that decoded, whatever the
+    burst's idle lanes hold."""
+    from ray_tpu.models import configs
+
+    cfg = configs.get(model)
+    eng = _tiny_paged(model)
+    try:
+        prompts = [[50 * i + j + 1 for j in range(n)]
+                   for i, n in enumerate((8, 16, 8))]
+        outs = _generate_together(
+            eng, prompts, [None] * len(prompts), max_tokens=12)
+        stats = eng.engine_stats()
+    finally:
+        eng.shutdown()
+    assert all(len(o) == 12 for o in outs)
+    fields = stats["tick_fields"]
+    assert fields[-1] == "experts_read"
+    ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
+    decoded = [t for t in ticks if t["lanes"] > 0]
+    assert decoded and len(decoded) < len(ticks)
+    assert all(t["experts_read"] == 0.0 for t in ticks if not t["lanes"])
+    if cfg.n_experts <= 0:
+        assert all(t["experts_read"] == 0.0 for t in decoded)
+        return
+    for t in decoded:
+        assert cfg.expert_top_k <= t["experts_read"] <= cfg.n_experts, t
+    # one or three of a width tier's four lanes are live in these ticks:
+    # the idle ones are routed nowhere, so a lone lane reads top_k
+    assert {t["experts_read"] for t in decoded if t["lanes"] == 1} \
+        <= {float(cfg.expert_top_k)}
 
 
 @pytest.mark.parametrize("six_requests", [True], indirect=True,

@@ -288,6 +288,66 @@ def test_served_step_does_not_copy_the_pool(topo, program):
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
                                      "paged_prefill_chunk"])
+def test_expert_ffn_reads_experts_in_place(topo, program):
+    """Mixtral-8x7B at its published widths, depth 2, at the benchmark's
+    serving shape: the width-4 burst (the tier `mixtral-chat` decodes at)
+    and the 128-token chunk fit one v5e chip, and their temporaries stay
+    under ONE expert matrix (4096 x 14336 bf16 = 117 MB): no expert's
+    weights are copied out before their product and nothing of the
+    weights is laid out again.  (A layer's (8, ..) slice taken by the
+    layer scan was copied whole, 2.9 GB of temporaries, before
+    `moe_mlp_dropless` was handed the stacks of all layers.)  The products
+    read the stack itself, sliced inside the fusion: at least one fused
+    product has an operand shaped like a layer's experts, one leading
+    dimension allowed, which is what the benchmark's `moe_ffn_roofline`
+    looks for in an op's text."""
+    import json
+    import re
+
+    from bench.harness.spec import BENCH_DIR, transformer_config
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    with open(os.path.join(BENCH_DIR, "configs",
+                           "mixtral-8x7b-serve-1chip.json")) as f:
+        config = json.load(f)
+    config["num_hidden_layers"] = 2
+    eng = config["engine"]
+    cfg, params, cache, b_max, arr = _serve_shapes(
+        topo, transformer_config(config), eng["num_slots"], eng["max_len"],
+        eng["block_size"])
+    assert (cfg.n_experts, cfg.d_model, cfg.d_ff) == (8, 4096, 14336)
+    chunk, burst, _ = make_paged_engine_fns(cfg)
+    if program == "paged_decode_burst":
+        w = 4
+        lowered = burst.lower(
+            params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
+            arr((w,), jnp.int32), arr((w,), jnp.bool_),
+            arr((w,), jnp.float32), arr((), jax.random.key(0).dtype),
+            n_steps=eng["max_burst"])
+    else:
+        lowered = chunk.lower(
+            params, cache, arr((eng["prefill_chunk"],), jnp.int32),
+            arr((b_max,), jnp.int32), arr((), jnp.int32),
+            arr((), jnp.int32))
+    compiled = lowered.compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    one_expert_matrix = 4096 * 14336 * 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < one_expert_matrix, mem.temp_size_in_bytes
+
+    expert_operand = re.compile(
+        r"\[(?:\d+,)?8,(?:4096,14336|14336,4096)\]")
+    products = [
+        body for body in compiled.as_text().split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and expert_operand.search(body.lstrip().split("\n", 1)[0])]
+    # gate, up and down of a visit
+    assert len(products) >= 3, len(products)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
 def test_hybrid_served_programs_fit_one_chip(topo, program):
     """Phi-4-mini-flash-reasoning whole (32 layers, 200,064 tokens) at the
     benchmark's serving shape (32 slots x 8192, block 16: layer 17's pool
