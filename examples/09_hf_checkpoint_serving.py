@@ -30,8 +30,7 @@ cfg = dataclasses.replace(cfg, compute_dtype=jnp.float32, remat=False)
 ray_tpu.init(num_cpus=2)
 handle = serve.run(
     serve.deployment(LLMDeployment).bind(
-        cfg, num_slots=2, max_len=64, prefix_cache_size=0,
-        params_loader=lambda: params),
+        cfg, num_slots=2, max_len=64, params_loader=lambda: params),
     name="hf_demo")
 
 prompt = [11, 42, 7, 99]

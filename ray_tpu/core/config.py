@@ -370,8 +370,7 @@ class Config:
     # but deepen block tables; 16 matches the vLLM default.
     kv_block_size: int = 16
     # Blocks in the pool (block 0 is the reserved null block and never
-    # allocated). 0 => derived from the engine's num_slots * max_len
-    # budget so paged and fixed-slot engines reserve equal HBM.
+    # allocated). 0 => derived from the engine's num_slots * max_len.
     kv_block_count: int = 0
     # Refcounted prefix-block sharing + copy-on-write (vLLM automatic
     # prefix caching at block granularity). 0 disables: every request

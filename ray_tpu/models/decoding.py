@@ -1,22 +1,32 @@
-"""Autoregressive decoding: KV cache, compiled prefill/decode steps.
+"""Autoregressive decoding: the paged KV pool and the served step.
 
-The serving-side compute path (reference has none in-repo; BASELINE.json
-north-star names "Serve req/s + p50 TTFT" with continuous batching).
-Design for XLA: fixed-shape slot-batched KV cache — `prefill` fills one
-slot from a (padded) prompt, `decode_step` advances ALL active slots one
-token in a single fused program.  Shapes never depend on request count, so
-both functions compile once per (slot_count, bucket) and the continuous-
-batching engine (ray_tpu.serve.llm) swaps requests in and out of slots
-between steps.
+KV lives in a flat pool of fixed-size blocks, (L, N_blocks, block_size,
+Hkv, D), and each request holds an int32 block table that maps its
+positions to pool blocks (vLLM-style; `serve/kv_cache.py` is the
+allocator).  Compiled shapes depend only on (S, B_max, block_size), so
+memory management (alloc/free/share/COW) lives on the host while the step
+stays one fused program (arXiv:2011.03641: keep the compiled step
+shape-stable) and the engine (`ray_tpu.serve.llm.PagedLLMEngine`) swaps
+requests in and out of lanes between steps.
 
-Cache layout: k/v (L, S, T_max, H_kv, D) with S = slots; per-slot lengths
-(S,) drive the attention mask.
+A served step never moves the pool.  Decode, prefill chunk and verify are
+one body (`_paged_forward`): the whole pool is the carry of the layer loop
+(and of the burst's step loop), each layer scatters its new tokens' KV at
+[layer, block, offset] and then reads, per lane, only the blocks below the
+lane's length, in the cache dtype (`ops.attention.paged_attention`).  With
+the cache donated, XLA does all of it in the one buffer: what a step moves
+is the weights and the live KV, whatever the pool's and the table's size.
+
+Convention: pool block 0 is the NULL block.  The allocator never hands it
+out; unallocated table entries and inactive slots point at it, so every
+gather/scatter is in-bounds without conditionals.  Writes routed to block
+0 are garbage that no attention mask ever reads.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,48 +37,6 @@ from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
 _NEG_INF = -1e30
-
-
-@dataclasses.dataclass
-class KVCache:
-    k: jax.Array          # (L, S, T, Hkv, D)
-    v: jax.Array
-    lengths: jax.Array    # (S,) int32 — tokens currently in each slot
-
-
-jax.tree_util.register_dataclass(KVCache, ["k", "v", "lengths"], [])
-
-
-def cache_shardings(mesh):
-    """NamedShardings for the KVCache leaves, defined NEXT TO the
-    (L, S, T, Hkv, D) layout they index: kv-heads split over the mesh
-    `tp` axis, lengths replicated (tensor-parallel serving)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ray_tpu.parallel.mesh import AXIS_TENSOR
-
-    kv = NamedSharding(mesh, P(None, None, None, AXIS_TENSOR, None))
-    return KVCache(k=kv, v=kv, lengths=NamedSharding(mesh, P()))
-
-
-def init_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
-               dtype=None, shardings: "KVCache | None" = None) -> KVCache:
-    """Zero cache; with `shardings` the arrays are allocated DIRECTLY
-    sharded (no single-device materialization — a cache that only fits
-    split across chips must never exist whole on chip 0)."""
-    dtype = dtype or cfg.compute_dtype
-    shape = (cfg.n_layers, num_slots, max_len, cfg.n_kv_heads, cfg.head_dim)
-
-    def zeros(s, d, sh):
-        return jnp.zeros(s, d, device=sh) if sh is not None else \
-            jnp.zeros(s, d)
-
-    k_sh = shardings.k if shardings else None
-    v_sh = shardings.v if shardings else None
-    l_sh = shardings.lengths if shardings else None
-    return KVCache(k=zeros(shape, dtype, k_sh),
-                   v=zeros(shape, dtype, v_sh),
-                   lengths=zeros((num_slots,), jnp.int32, l_sh))
 
 
 def _qkv(bp, x, cfg, positions):
@@ -120,187 +88,12 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None):
                       bp["w_down"].astype(cd)), jnp.int32(0)
 
 
-def _gqa(q, k, v, cfg):
-    if cfg.n_kv_heads != cfg.n_heads:
-        rep = cfg.n_heads // cfg.n_kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    return q, k, v
-
-
 def _final_logits(params, x, cfg):
     cd = cfg.compute_dtype
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
     if cfg.tie_embeddings:
         return jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
     return jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(cd))
-
-
-def prefill(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
-            length: jax.Array, cfg: TransformerConfig
-            ) -> Tuple[KVCache, jax.Array]:
-    """Run a (1, T_pad) prompt through the model, writing k/v into `slot`.
-
-    `length` is the true prompt length (<= T_pad); returns (cache, logits
-    of the last real token (vocab,))."""
-    cd = cfg.compute_dtype
-    _, t = tokens.shape
-    positions = jnp.arange(t, dtype=jnp.int32)
-    x = params["embed"].astype(cd)[tokens]
-    mask = (positions[:, None] >= positions[None, :]) \
-        & (positions[None, :] < length)
-
-    def layer(x, layer_params_and_idx):
-        bp, li = layer_params_and_idx
-        q, k, v = _qkv(bp, x, cfg, positions)
-        qh, kh, vh = _gqa(q, k, v, cfg)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qh.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
-        s = jnp.where(mask[None, None], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", p, vh.astype(jnp.float32))
-        attn = attn.reshape(1, t, cfg.n_heads * cfg.head_dim).astype(cd)
-        x = x + jnp.einsum("bth,hd->btd", attn, bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg, experts, li)[0]
-        return x, (k[0], v[0])  # (T, Hkv, D) for cache write
-
-    idx = jnp.arange(cfg.n_layers)
-    blocks, experts = _layer_xs(params["blocks"], cfg)
-    x, kv = jax.lax.scan(layer, x, (blocks, idx))
-    k_new, v_new = kv  # (L, T, Hkv, D)
-    t_cache = cache.k.shape[2]
-    pad = t_cache - t
-    k_new = jnp.pad(k_new.astype(cache.k.dtype),
-                    ((0, 0), (0, pad), (0, 0), (0, 0)))
-    v_new = jnp.pad(v_new.astype(cache.v.dtype),
-                    ((0, 0), (0, pad), (0, 0), (0, 0)))
-    new_cache = KVCache(
-        k=jax.lax.dynamic_update_index_in_dim(cache.k, k_new, slot, 1),
-        v=jax.lax.dynamic_update_index_in_dim(cache.v, v_new, slot, 1),
-        lengths=cache.lengths.at[slot].set(length))
-    logits = _final_logits(params, x, cfg)[0]          # (T, vocab)
-    last = logits[length - 1]                           # (vocab,)
-    return new_cache, last
-
-
-def _wide_decode(params, cache: KVCache, tokens: jax.Array,
-                 cfg: TransformerConfig):
-    """Shared width-K decode core: process `tokens` (S, K) at positions
-    lengths[s]..lengths[s]+K-1, writing their KV into each slot and
-    attending to cache[:len] plus the in-window causal prefix. Returns
-    (logits (S, K, vocab), new_k, new_v) — callers decide how far
-    `lengths` advances (decode: +1; speculative verify: +accepted+1).
-    decode_step is exactly the K=1 case."""
-    cd = cfg.compute_dtype
-    s_count, k_w = tokens.shape
-    t_cache = cache.k.shape[2]
-    start = cache.lengths                                  # (S,)
-    positions = start[:, None] + jnp.arange(k_w)           # (S, K)
-    x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
-
-    kv_pos = jnp.arange(t_cache)
-    # window token i attends to cache[:len] plus window tokens 0..i.
-    attn_mask = kv_pos[None, None, :] <= positions[:, :, None]  # (S,K,T)
-
-    def layer(carry, layer_in):
-        x = carry
-        bp, li, k_cache, v_cache = layer_in
-        q, k, v = _qkv(bp, x, cfg, positions)              # (S,K,H,D)
-        k_cache = jax.vmap(
-            lambda kc, kn, p: jax.lax.dynamic_update_slice(
-                kc, kn.astype(kc.dtype), (p, 0, 0)))(k_cache, k, start)
-        v_cache = jax.vmap(
-            lambda vc, vn, p: jax.lax.dynamic_update_slice(
-                vc, vn.astype(vc.dtype), (p, 0, 0)))(v_cache, v, start)
-        kh, vh = k_cache, v_cache
-        if cfg.n_kv_heads != cfg.n_heads:
-            rep = cfg.n_heads // cfg.n_kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
-        s = jnp.einsum("sqhd,sthd->sqht", q.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * (cfg.head_dim ** -0.5)
-        s = jnp.where(attn_mask[:, :, None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("sqht,sthd->sqhd", p, vh.astype(jnp.float32))
-        attn = attn.reshape(s_count, k_w, cfg.n_heads * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
-        x = x + _mlp(bp, x, cfg, experts, li)[0]
-        return x, (k_cache, v_cache)
-
-    blocks, experts = _layer_xs(params["blocks"], cfg)
-    x, new_kv = jax.lax.scan(
-        layer, x, (blocks, jnp.arange(cfg.n_layers), cache.k, cache.v))
-    new_k, new_v = new_kv
-    logits = _final_logits(params, x, cfg)                 # (S, K, vocab)
-    return logits, new_k, new_v
-
-
-def decode_step(params, cache: KVCache, tokens: jax.Array,
-                active: jax.Array, cfg: TransformerConfig
-                ) -> Tuple[KVCache, jax.Array]:
-    """One token for every slot: tokens (S,) int32 (last sampled token per
-    slot), active (S,) bool.  Returns (cache, logits (S, vocab)).
-
-    Inactive slots still flow through the matmuls (fixed shapes) but their
-    cache/lengths are left untouched."""
-    logits, new_k, new_v = _wide_decode(params, cache, tokens[:, None],
-                                        cfg)
-    keep = active[None, :, None, None, None]
-    new_cache = KVCache(
-        k=jnp.where(keep, new_k, cache.k),
-        v=jnp.where(keep, new_v, cache.v),
-        lengths=jnp.where(active, cache.lengths + 1, cache.lengths))
-    return new_cache, logits[:, 0]
-
-
-def verify_step(params, cache: KVCache, cand_tokens: jax.Array,
-                active: jax.Array, temps: jax.Array, rng: jax.Array,
-                cfg: TransformerConfig):
-    """Speculative verification: K candidate tokens PER SLOT in one
-    call (prompt-lookup decoding — the draft comes from n-gram matches
-    in the slot's own context, no draft model; ref: the role vLLM's
-    ngram speculator fills).
-
-    cand_tokens (S, K): column 0 is each slot's last sampled token
-    (whose KV is not yet written), columns 1..K-1 are the proposals.
-    Returns (cache, tok_out (S, K), accepted (S,)):
-      - tok_out[s, i] = the model's token at position len+i+1 (greedy;
-        for temps>0 column 0 is properly sampled and acceptance is
-        forced to 0, degenerating to an exact normal decode step)
-      - accepted[s] = a — proposals 1..a matched, so the engine emits
-        tok_out[s, :a+1] (a accepted + 1 bonus) and lengths advance by
-        a+1. KV for ALL K candidates is written; positions beyond the
-        new length hold stale values that every attention mask already
-        ignores — acceptance is just length arithmetic, no rollback
-        copy.
-
-    Cost intuition: decode is HBM-bandwidth-bound; widening the query
-    from 1 to K reuses the same weight/cache streams, so a verify call
-    costs about one decode step while advancing up to K tokens.
-    """
-    start = cache.lengths                                  # (S,)
-    logits, new_k, new_v = _wide_decode(params, cache, cand_tokens, cfg)
-
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S,K)
-    # Proposal i (column i of cand) is correct iff the model's greedy
-    # token at the PREVIOUS position equals it; acceptance is the run
-    # of correct proposals. Sampling slots accept nothing.
-    match = (cand_tokens[:, 1:] == greedy[:, :-1])
-    acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
-    accepted = jnp.where(temps > 0.0, 0, acc.sum(axis=1))   # (S,)
-    rng, sub = jax.random.split(rng)
-    first_sampled = sample_per_slot(logits[:, 0], sub, temps)
-    tok_out = greedy.at[:, 0].set(first_sampled)
-
-    keep = active[None, :, None, None, None]
-    new_lengths = jnp.where(
-        active, start + 1 + accepted.astype(jnp.int32), start)
-    new_cache = KVCache(
-        k=jnp.where(keep, new_k, cache.k),
-        v=jnp.where(keep, new_v, cache.v),
-        lengths=new_lengths)
-    return new_cache, tok_out, accepted, rng
 
 
 def sample_logits(logits: jax.Array, rng: jax.Array, *,
@@ -327,76 +120,10 @@ def sample_per_slot(logits: jax.Array, rng: jax.Array,
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
-def decode_and_sample(params, cache: KVCache, tokens, active, temps, rng,
-                      cfg: TransformerConfig):
-    """One fused device call per engine tick: decode + per-slot sampling.
-    Returns (cache, next_tokens (S,), rng').  Keeps the host↔device
-    traffic to (S,) int32 per tick: the round trip, not the transfer,
-    bounds tick rate."""
-    cache, logits = decode_step(params, cache, tokens, active, cfg)
-    rng, sub = jax.random.split(rng)
-    return cache, sample_per_slot(logits, sub, temps), rng
-
-
-def prefill_and_sample(params, cache: KVCache, tokens, slot, length, temp,
-                       rng, cfg: TransformerConfig):
-    """Returns (cache, first_token, last_logits, rng) — the logits ride
-    back so the engine's prefix cache can re-sample them under a
-    different temperature on a later hit."""
-    cache, last_logits = prefill(params, cache, tokens, slot, length, cfg)
-    rng, sub = jax.random.split(rng)
-    tok = sample_per_slot(last_logits[None], sub, temp[None])[0]
-    return cache, tok, last_logits, rng
-
-
-def extract_prefix(cache: KVCache, slot, t: int):
-    """Snapshot the first `t` positions of one slot's KV
-    (L, t, Hkv, D) — `t` is the prompt's prefill bucket (static: one
-    compile per bucket, like prefill itself), so an entry costs
-    t/max_len of a slot's HBM rather than a whole slot. Jit outputs
-    are fresh buffers, so the snapshot survives later donation of
-    `cache`."""
-    k = jax.lax.dynamic_index_in_dim(cache.k, slot, 1, keepdims=False)
-    v = jax.lax.dynamic_index_in_dim(cache.v, slot, 1, keepdims=False)
-    return k[:, :t], v[:, :t]
-
-
-def insert_prefix(cache: KVCache, k_slice, v_slice, slot, length
-                  ) -> KVCache:
-    """Write a snapshotted prefix back into `slot` (prefix-cache hit:
-    replaces the whole prefill computation with one HBM copy). Only
-    the snapshot's positions are written; staler KV beyond `length`
-    is masked out by the per-slot length exactly as prefill padding
-    is."""
-    zero = jnp.zeros((), jnp.int32)
-    start = (zero, jnp.asarray(slot, jnp.int32), zero, zero, zero)
-    return KVCache(
-        k=jax.lax.dynamic_update_slice(cache.k, k_slice[:, None], start),
-        v=jax.lax.dynamic_update_slice(cache.v, v_slice[:, None], start),
-        lengths=cache.lengths.at[slot].set(length))
-
-
 def sample_one(last_logits, temp, rng):
     """Re-sample a stored last-logits vector (prefix-cache hit path)."""
     rng, sub = jax.random.split(rng)
     return sample_per_slot(last_logits[None], sub, temp[None])[0], rng
-
-
-def decode_burst(params, cache: KVCache, tokens, active, temps, rng,
-                 cfg: TransformerConfig, n_steps: int):
-    """`n_steps` fused decode+sample ticks in ONE device call (lax.scan) —
-    amortizes the host↔device round trip of a tick over n_steps tokens.
-    Returns (cache, token_matrix (n_steps, S), rng)."""
-
-    def tick(carry, _):
-        cache, toks, rng = carry
-        cache, nxt, rng = decode_and_sample(params, cache, toks, active,
-                                            temps, rng, cfg)
-        return (cache, nxt, rng), nxt
-
-    (cache, _, rng), toks = jax.lax.scan(
-        tick, (cache, tokens, rng), None, length=n_steps)
-    return cache, toks, rng
 
 
 def _bind_cfg(f, cfg: TransformerConfig):
@@ -407,18 +134,6 @@ def _bind_cfg(f, cfg: TransformerConfig):
     bound = functools.partial(f, cfg=cfg)
     bound.__name__, bound.__qualname__ = f.__name__, f.__qualname__
     return bound
-
-
-def make_engine_fns(cfg: TransformerConfig, *, num_slots: int,
-                    max_len: int, donate: bool = True):
-    """Jitted (prefill_fn, burst_decode_fn) with cache donation.  The
-    decode fn takes a static `n_steps` (one compile per distinct burst)."""
-    pf = _bind_cfg(prefill_and_sample, cfg)
-    df = _bind_cfg(decode_burst, cfg)
-    prefill_jit = jax.jit(pf, donate_argnums=(1,) if donate else ())
-    decode_jit = jax.jit(df, static_argnames=("n_steps",),
-                         donate_argnums=(1,) if donate else ())
-    return prefill_jit, decode_jit
 
 
 def ngram_propose(context, k_minus_1: int, ngram: int = 2):
@@ -437,40 +152,6 @@ def ngram_propose(context, k_minus_1: int, ngram: int = 2):
     return []
 
 
-def make_spec_fns(cfg: TransformerConfig, donate: bool = True):
-    """Jitted speculative verifier (K rides in the candidate shape:
-    one compile per K, same discipline as prefill buckets)."""
-    return jax.jit(_bind_cfg(verify_step, cfg),
-                   donate_argnums=(1,) if donate else ())
-
-
-# ---------------------------------------------------------------------------
-# Paged KV cache (vLLM-style block tables; serve/kv_cache.py allocator)
-# ---------------------------------------------------------------------------
-#
-# The contiguous cache above reserves S * T_max positions of HBM up front
-# and caps concurrency at the slot count.  The paged layout stores KV in a
-# flat pool of fixed-size blocks — (L, N_blocks, block_size, Hkv, D) — and
-# each request holds an int32 block table mapping its sequence positions to
-# pool blocks.  Compiled shapes depend only on (S, B_max, block_size), so
-# memory management (alloc/free/share/COW) moves entirely to the host-side
-# allocator while the decode step stays a single fused program
-# (arXiv:2011.03641: keep the compiled step shape-stable).
-#
-# A served step never moves the pool.  Decode, prefill chunk and verify are
-# one body (`_paged_forward`): the whole pool is the carry of the layer loop
-# (and of the burst's step loop), each layer scatters its new tokens' KV at
-# [layer, block, offset] and then reads, per lane, only the blocks below the
-# lane's length, in the cache dtype (`ops.attention.paged_attention`).  With
-# the cache donated, XLA does all of it in the one buffer: what a step moves
-# is the weights and the live KV, whatever the pool's and the table's size.
-#
-# Convention: pool block 0 is the NULL block.  The allocator never hands it
-# out; unallocated table entries and inactive slots point at it, so every
-# gather/scatter is in-bounds without conditionals.  Writes routed to block
-# 0 are garbage that no attention mask ever reads.
-
-
 @dataclasses.dataclass
 class PagedKVCache:
     k: jax.Array          # (L, N_blocks, block_size, Hkv, D)
@@ -486,26 +167,47 @@ class PagedKVCache:
 jax.tree_util.register_dataclass(PagedKVCache, ["k", "v"], [])
 
 
+def paged_cache_shardings(mesh) -> PagedKVCache:
+    """NamedShardings for the pool's leaves, defined next to the
+    (L, N_blocks, block_size, Hkv, D) layout they index: KV heads split
+    over the mesh's `tp` axis (tensor-parallel serving)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import AXIS_TENSOR
+
+    kv = NamedSharding(mesh, P(None, None, None, AXIS_TENSOR, None))
+    return PagedKVCache(k=kv, v=kv)
+
+
 def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
-                     block_size: int, dtype=None) -> PagedKVCache:
+                     block_size: int, dtype=None,
+                     shardings: Optional[PagedKVCache] = None
+                     ) -> PagedKVCache:
+    """Zero pool; with `shardings` (`paged_cache_shardings`) it is
+    allocated directly sharded: a pool that fits only across chips never
+    exists whole on chip 0."""
     dtype = dtype or cfg.compute_dtype
     shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
-    return PagedKVCache(k=jnp.zeros(shape, dtype),
-                        v=jnp.zeros(shape, dtype))
+    k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
+    return PagedKVCache(k=jnp.zeros(shape, dtype, device=k_sh),
+                        v=jnp.zeros(shape, dtype, device=v_sh))
 
 
 def init_sequence_state(cfg, num_blocks: int, block_size: int, *,
-                        num_slots: int, prefill_chunk: int):
+                        num_slots: int, prefill_chunk: int,
+                        shardings: Optional[PagedKVCache] = None):
     """What the sequences of one engine keep on the device, asked of the
-    model: the paged pool alone for a `TransformerConfig`; whatever
-    `cfg.init_state` says for a model that brings its own (paged KV of
-    the layers that keep every position, bounded window KV and recurrent
-    state by slot: `models.hybrid`).  Either is the `cache` argument of
-    the served programs below."""
+    model: the paged pool alone for a `TransformerConfig` (sharded as
+    `shardings` says); whatever `cfg.init_state` says for a model that
+    brings its own (paged KV of the layers that keep every position,
+    bounded window KV and recurrent state by slot: `models.hybrid`; the
+    engine gives such a model no mesh).  Either is the `cache` argument
+    of the served programs below."""
     own = getattr(cfg, "init_state", None)
     if own is None:
-        return init_paged_cache(cfg, num_blocks, block_size)
+        return init_paged_cache(cfg, num_blocks, block_size,
+                                shardings=shardings)
     return own(num_blocks, block_size, num_slots, prefill_chunk)
 
 
@@ -665,9 +367,11 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
                       active: jax.Array, temps: jax.Array, rng: jax.Array,
                       cfg: TransformerConfig):
     """Speculative verification through the block pool: K candidate
-    tokens PER SLOT in one call (the paged analogue of `verify_step` —
-    same prompt-lookup drafting, same greedy acceptance rule), the
-    K-wide case of the served step.
+    tokens PER SLOT in one call (prompt-lookup decoding: the draft comes
+    from n-gram matches in the slot's own context, no draft model), the
+    K-wide case of the served step.  Decode is HBM-bandwidth-bound, and
+    widening the query from 1 to K reuses the same weight and KV streams:
+    a verify call costs about one decode step and advances up to K tokens.
 
     cand_tokens (S, K): column 0 is each slot's last sampled token
     (whose KV is not yet written), columns 1..K-1 the proposals.
@@ -691,11 +395,10 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
         params, cache, cand_tokens, block_tables, positions,
         jnp.where(active, lengths + k_w, 0), cfg)
     logits = _final_logits(params, x, cfg)               # (S, K, vocab)
-    # Same acceptance rule as the contiguous verify_step: proposal i is
-    # correct iff the model's greedy token at the previous position
-    # equals it; acceptance is the run of correct proposals.  Sampling
-    # slots (temps > 0) accept nothing and degrade to an exact normal
-    # decode step via the properly-sampled column 0.
+    # Proposal i is correct iff the model's greedy token at the previous
+    # position equals it; acceptance is the run of correct proposals.
+    # Sampling slots (temps > 0) accept nothing and degrade to an exact
+    # normal decode step via the properly-sampled column 0.
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, K)
     match = (cand_tokens[:, 1:] == greedy[:, :-1])
     acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
@@ -764,14 +467,3 @@ def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):
                         donate_argnums=(1,) if donate else ())
     copy_jit = jax.jit(copy_block, donate_argnums=(0,) if donate else ())
     return chunk_jit, burst_jit, copy_jit
-
-
-def make_prefix_cache_fns(donate: bool = True):
-    """Jitted (extract, insert, sample) for the engine's prefix cache.
-    Insert donates the live cache (it is immediately replaced); extract
-    never donates — its output must outlive the donated original."""
-    extract_jit = jax.jit(extract_prefix, static_argnames=("t",))
-    insert_jit = jax.jit(insert_prefix,
-                         donate_argnums=(0,) if donate else ())
-    sample_jit = jax.jit(sample_one)
-    return extract_jit, insert_jit, sample_jit

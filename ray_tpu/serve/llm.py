@@ -1,29 +1,17 @@
-"""Continuous-batching LLM engines for TPU serving.
+"""Continuous-batching LLM engine for TPU serving.
 
-Two engines share one public surface (generate / generate_stream /
-engine_stats):
+`PagedLLMEngine` (generate / generate_stream / engine_stats): KV lives in
+a flat pool of fixed-size blocks (models/decoding.py PagedKVCache); each
+request holds a block table, blocks are allocated on demand
+(serve/kv_cache.py KVBlockAllocator), shared between requests with a
+common prompt prefix (refcounted copy-on-write), and long prompts prefill
+in chunks interleaved with decode bursts so active streams' inter-token
+latency stays bounded during prefill storms.  Concurrency is bounded by
+pool occupancy, not slot count.  Given a mesh, the same programs run
+tensor-parallel: parameters and the pool's KV heads split over `tp`.
 
-  LLMEngine       fixed-slot: requests share a fixed pool of contiguous
-                  KV-cache slots, prefill admits whole (bucket-padded)
-                  prompts, every tick advances ALL active slots with one
-                  fused decode burst.  HBM is reserved for worst-case
-                  sequence length and concurrency is capped at the slot
-                  count.
-
-  PagedLLMEngine  paged/block KV cache: KV lives in a flat pool of
-                  fixed-size blocks (models/decoding.py PagedKVCache);
-                  each request holds a block table, blocks are allocated
-                  on demand (serve/kv_cache.py KVBlockAllocator), shared
-                  between requests with a common prompt prefix
-                  (refcounted copy-on-write), and long prompts prefill
-                  in chunks interleaved with decode bursts so active
-                  streams' inter-token latency stays bounded during
-                  prefill storms.  Concurrency is bounded by pool
-                  occupancy, not slot count.
-
-Use standalone or as a Serve deployment (`LLMDeployment`, paged by
-default) — replicas each own an engine; the pow-2 router spreads
-requests.
+Use standalone or as a Serve deployment (`LLMDeployment`) — replicas
+each own an engine; the pow-2 router spreads requests.
 """
 from __future__ import annotations
 
@@ -32,7 +20,6 @@ import queue
 import threading
 import time
 import uuid
-import warnings
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
@@ -149,11 +136,224 @@ def _snapshot(log: deque) -> tuple:
             continue
 
 
-class _EngineBase:
-    """Shared request-facing surface of both engines. Subclasses provide
-    `max_len`, `stats`, `_pending_put(req)`, and a background loop that
-    completes requests."""
+class PagedLLMEngine:
+    """Paged/block KV-cache engine: the one served engine.
 
+    Engine tick: [admit waiting requests] -> [one fused decode burst
+    over every DECODING slot] -> [one prefill chunk for the oldest
+    PREFILLING slot].  Decode never waits for a whole prompt: a
+    max-length prompt occupies at most `prefill_chunk` tokens of device
+    time per tick, bounding the inter-token latency of active streams.
+
+    Admission: a request needs pool blocks covering its (non-shared)
+    prompt remainder.  When the pool can't cover it, the request WAITS
+    at the head of the queue (no error) until completions free blocks.
+
+    The state of a sequence is asked of the model
+    (`models.decoding.init_sequence_state`), and has up to three kinds:
+
+    - paged KV (`kv_paged`): every position of the layers that keep
+      them all, in pool blocks the `KVBlockAllocator` hands out and a
+      block table names.  Every model has it; for a `TransformerConfig`
+      it is all there is, and the blocks *are* the sequence: prefix
+      sharing, copy-on-write, speculation (a rejected draft is rolled
+      back by length alone), `export_streams` / `import_prefix` all
+      rest on that.
+    - bounded window KV (`kv_window`): a ring of window + prefill_chunk
+      positions a slot for each sliding-window layer, indexed by the
+      engine's slot, owned by whoever holds the slot.
+    - recurrent state (`recurrent`): a state-space layer's conv rows
+      and state by slot.  It is zeroed when a request is admitted to the
+      slot (`_reset_slot_state`, counted in the tick's `reset_s`),
+      carried from chunk to chunk of a prefill, left untouched by a
+      chunk's padded tail and by idle lanes, and zeroed again when
+      `_preempt` sends a stream to re-prefill.
+
+    With a model whose configuration says `recurrent` the blocks are
+    not the sequence, so the engine turns prefix sharing off itself (a
+    hit would skip positions whose state nobody kept) and refuses, with
+    a ValueError, `speculation_k >= 2` at construction and
+    `export_streams` / `import_prefix` when called: snapshots of state
+    are what each would need.
+    """
+    TICKS_KEPT = 4096
+
+    def __init__(self, cfg, params, *, num_slots: int = 32,
+                 max_len: int = 1024, block_size: Optional[int] = None,
+                 num_blocks: Optional[int] = None,
+                 prefill_chunk: Optional[int] = None,
+                 eos_id: Optional[int] = None, seed: int = 0,
+                 max_burst: int = 8, prefix_sharing: Optional[bool] = None,
+                 speculation_k: Optional[int] = None,
+                 speculation_ngram: Optional[int] = None,
+                 store=None, mesh=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.core.config import get_config
+        from ray_tpu.models.decoding import (
+            init_sequence_state,
+            make_paged_engine_fns,
+            make_paged_spec_fns,
+            paged_cache_shardings,
+            sample_one,
+        )
+        from ray_tpu.serve.kv_cache import KVBlockAllocator
+
+        knobs = get_config()
+        self.cfg = cfg
+        self.num_slots = num_slots
+        self.max_len = max_len
+        self.block_size = block_size or knobs.kv_block_size
+        # Default pool budget: every slot at max_len, +1 for the null
+        # block.
+        self.num_blocks = (num_blocks or knobs.kv_block_count
+                           or (num_slots * max_len) // self.block_size + 1)
+        self.prefill_chunk = prefill_chunk or knobs.serve_prefill_chunk
+        # Shape tiers (power-of-two) keep device work proportional to
+        # LOAD, not capacity: a burst over 3 active streams runs at
+        # width 4, not num_slots; a 16-token chunk compiles at width 32,
+        # not prefill_chunk.  One compile per tier.
+        self._width_tiers = self._tiers(4, num_slots)
+        self._chunk_tiers = self._tiers(32, self.prefill_chunk)
+        self.eos_id = eos_id
+        self.max_burst = max(1, max_burst if eos_id is None else
+                             min(max_burst, 4))
+        # Prompt-lookup speculative decoding on the paged pool (opt-in,
+        # knob-defaulted): each tick verifies K candidates per slot in
+        # one width-K call; drafts come from n-gram matches in the
+        # slot's own context.  Exact under greedy decoding; sampling
+        # slots degrade to normal decode.
+        if speculation_k is None:
+            speculation_k = knobs.serve_speculation_k
+        if speculation_ngram is None:
+            speculation_ngram = knobs.serve_speculation_ngram
+        self._spec_k = speculation_k if speculation_k >= 2 else 0
+        # A model whose sequences keep recurrent state by slot.
+        self._recurrent = bool(getattr(cfg, "recurrent", False))
+        if self._recurrent and self._spec_k:
+            raise ValueError(
+                f"speculation_k={speculation_k} with {cfg.name!r}: a "
+                f"rejected draft has already advanced the recurrent "
+                f"state, and there is no snapshot to roll it back to")
+        self._spec_ngram = max(1, speculation_ngram)
+        # The free-margin _maybe_finish keeps must cover whichever
+        # advance is larger — a burst OR a spec window — without
+        # inflating the burst depth itself.
+        self._advance_margin = max(self.max_burst, self._spec_k)
+        self._b_max = math.ceil(max_len / self.block_size)
+        prefix_sharing = (knobs.kv_block_prefix_sharing
+                          if prefix_sharing is None else prefix_sharing)
+        if self._recurrent:
+            # A hit would skip positions whose state nobody kept.
+            prefix_sharing = False
+        self._jax = jax
+        self._jnp = jnp
+        self._rng = jax.random.key(seed)
+        cache_sh = None
+        if mesh is not None:
+            # Tensor-parallel serving: params split over the mesh `tp`
+            # axis (TP_RULES), the pool split on its kv-heads axis — the
+            # SAME jitted engine programs run unchanged; GSPMD propagates
+            # the shardings and inserts the all-reduces after wo/w_down.
+            # This is how a model too big for one chip serves: a sharding
+            # annotation, not an engine fork.
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from ray_tpu.models.transformer import param_logical_axes
+            from ray_tpu.parallel.mesh import AXIS_TENSOR
+            from ray_tpu.parallel.sharding import (
+                TP_RULES,
+                param_shardings,
+                shard_pytree,
+            )
+
+            if self._recurrent:
+                raise ValueError(
+                    f"{cfg.name!r} keeps recurrent state by slot: it is "
+                    f"served by the paged engine on one device (no mesh)")
+            tp = int(mesh.shape.get(AXIS_TENSOR, 1))
+            for dim_name, dim in (("n_kv_heads", cfg.n_kv_heads),
+                                  ("n_heads", cfg.n_heads),
+                                  ("d_ff", cfg.d_ff),
+                                  ("vocab_size", cfg.vocab_size)):
+                if dim % tp:
+                    raise ValueError(
+                        f"tensor parallelism {tp} does not divide "
+                        f"{dim_name}={dim} for model {cfg.name!r} — "
+                        f"pick a tp that divides all sharded dims")
+            # Shard from HOST copies so the unsharded model never has
+            # to fit on one chip (pass host arrays from params_loader
+            # for models that genuinely don't).
+            params = shard_pytree(
+                jax.device_get(params),
+                param_shardings(param_logical_axes(cfg), mesh, TP_RULES))
+            cache_sh = paged_cache_shardings(mesh)
+            self._rng = jax.device_put(self._rng, NamedSharding(mesh, P()))
+        self.params = params
+        self.cache = init_sequence_state(
+            cfg, self.num_blocks, self.block_size, num_slots=num_slots,
+            prefill_chunk=self.prefill_chunk, shardings=cache_sh)
+        self._state_bytes = self.cache.resident_bytes()
+        self._reset_state = (jax.jit(cfg.reset_slot, donate_argnums=(0,))
+                             if self._recurrent else None)
+        self._score_step = None
+        # KV positions one decode step sees over lanes of given lengths:
+        # the model's own count, or every layer over every position.
+        self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
+            lambda lengths: cfg.n_layers * sum(lengths))
+        # Layers whose FFN is a set of experts: the burst's count of
+        # experts visited is per layer and step over these.
+        self._expert_layers = (cfg.n_layers
+                               if getattr(cfg, "n_experts", 0) > 0 else 0)
+        self._prefill_chunk_fn, self._decode, self._copy_block = \
+            make_paged_engine_fns(cfg)
+        if self._spec_k:
+            self._verify = make_paged_spec_fns(cfg)
+        self._sample_one = jax.jit(sample_one)
+        bytes_per_block = self._state_bytes["kv_paged"] // self.num_blocks
+        self.allocator = KVBlockAllocator(
+            self.num_blocks, self.block_size, store=store,
+            bytes_per_block=bytes_per_block if store is not None else 0,
+            prefix_sharing=prefix_sharing)
+        # Host-side engine state: per-slot block tables + lengths (the
+        # compiled step only ever sees fixed (S, B_max) arrays).
+        self._tables = np.zeros((num_slots, self._b_max), np.int32)
+        self._lengths = np.zeros((num_slots,), np.int32)
+        self._last_tokens = np.zeros((num_slots,), np.int32)
+        self._slots: List[Optional[_Request]] = [None] * num_slots
+        self._prefillq: deque = deque()   # slots awaiting prefill chunks
+        self._pending: deque = deque()
+        self._pending_lock = threading.Lock()
+        self._work = threading.Event()
+        self._stop = False
+        # Serializes whole engine ticks against the foreign-thread KV
+        # surface (import_prefix / export_streams): those read and
+        # replace self.cache, which a mid-tick decode would otherwise
+        # race.  Uncontended cost is one lock per tick.
+        self._tick_lock = threading.Lock()
+        self.stats = {"requests": 0, "tokens_generated": 0,
+                      "completed": 0,
+                      "prefix_hits": 0, "prefix_misses": 0,
+                      "prefill_chunks": 0, "queue_waits": 0,
+                      "preemptions": 0, "adopted_blocks": 0,
+                      "migrated_blocks": 0, "migrate_fallbacks": 0,
+                      "disagg_prefills": 0,
+                      "spec_proposed": 0, "spec_accepted": 0,
+                      "state_resets": 0, "state_rebuilds": 0}
+        self._request_phases: deque = deque(
+            maxlen=self.REQUEST_PHASES_KEPT)
+        # engine_stats()["tick_log"]: one tuple per tick that progressed
+        # (see _tick), from the accounts the tick's phases keep as they
+        # go.  `stats` is cumulative since the process began; a log lets
+        # a reader take any window's ticks by their start and gives a
+        # median where a counter gives a mean.
+        self._tick_log: deque = deque(maxlen=self.TICKS_KEPT)
+        self._acct = _TickAccounts()
+        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._thread.start()
+
+    # -- the request-facing surface --------------------------------------
     @staticmethod
     def _resume_ctx(prompt_tokens, max_tokens, resume_tokens):
         """Fold an interrupted stream's already-emitted tokens into the
@@ -231,6 +431,17 @@ class _EngineBase:
                 return
             yield tok
 
+    def _pending_put(self, req: "_Request") -> None:
+        with self._pending_lock:
+            self._pending.append(req)
+        self._work.set()
+
+    def shutdown(self):
+        self._stop = True
+        self._work.set()
+        self._thread.join(timeout=5)
+        self.allocator.release()
+
     def engine_stats(self, records: bool = True) -> Dict[str, Any]:
         """The cumulative counters and, with `records`, the bounded
         logs and what is computed from them.  The replica's gauge loop
@@ -241,667 +452,6 @@ class _EngineBase:
             s["p_ttft_mean"] = (
                 sum(r["ttft_s"] for r in phases) / len(phases)
                 if phases else None)
-        return s
-
-    def shutdown(self):
-        self._stop = True
-        self._work.set()
-
-    def _finish_request(self, req: "_Request") -> None:
-        """Complete one request: stats + stream sentinel + done event."""
-        self.stats["completed"] += 1
-        if req.token_q is not None:
-            try:
-                req.token_q.put_nowait(None)  # stream sentinel
-            except queue.Full:
-                pass  # dropped stream: done event carries the signal
-        req.done.set()
-
-    # -- serving observability ------------------------------------------
-    # Spans attribute each engine phase (queue_wait / prefill_wait /
-    # prefill > prefill_chunk / decode_burst) to the request's trace;
-    # histograms decompose TTFT / ITL per app; `_request_phases` keeps
-    # the same edges per request for engine_stats().  Spans gate on
-    # req.trace (None when the RAY_TPU_SERVE_TRACE_ENABLED kill switch
-    # is off); histograms and the record fill either way.  The app tag
-    # is learned lazily from traced requests — standalone engines
-    # (bench, unit tests) report under "-".
-    _app_hint = "-"
-    # engine_stats()["request_phases"]: the last requests that got a
-    # first token, one dict each (see _obs_first_token).
-    REQUEST_PHASES_KEPT = 1024
-
-    def _obs_submit(self, req: "_Request",
-                    trace: Optional[dict]) -> None:
-        # Direct engine use (no proxy/handle upstream) mints its own
-        # trace so span coverage — and the overhead the kill switch
-        # removes — is identical with and without the HTTP front.
-        req.trace = (trace if trace is not None
-                     else tracing.serve_ctx(uuid.uuid4().hex))
-
-    def _obs_app(self, req: "_Request") -> str:
-        app = req.trace.get("app") if req.trace else None
-        if app:
-            self._app_hint = app
-            return app
-        return self._app_hint
-
-    def _obs_admitted(self, req: "_Request") -> None:
-        from ray_tpu.serve import observability
-
-        now = req.admitted_at = time.time()
-        tracing.record_serve_span(req.trace, "serve.engine.queue_wait",
-                                  req.submitted_at, now,
-                                  tokens=len(req.prompt))
-        observability.observe_phase(self._obs_app(req), "queue_wait",
-                                    now - req.submitted_at)
-
-    def _obs_prefill(self, req: "_Request", t0: float,
-                     n_tokens: int) -> float:
-        """One prefill chunk launched at `t0`; returns its wall time.
-        A request's first chunk ends its prefill_wait and opens its
-        `serve.engine.prefill` span, the parent of every chunk up to the
-        first token.  A preempted request's re-prefill comes after its
-        first token: its chunks are marked `resumed` and stay out of the
-        TTFT phases."""
-        from ray_tpu.serve import observability
-
-        t1 = time.time()
-        app = self._obs_app(req)
-        ctx, attrs = req.trace, {}
-        if req.first_token_at is not None:
-            attrs["resumed"] = 1
-        else:
-            if req.prefill_at is None:
-                req.prefill_at = t0
-                tracing.record_serve_span(
-                    ctx, "serve.engine.prefill_wait", req.admitted_at, t0,
-                    tokens=len(req.prompt))
-                observability.observe_phase(app, "prefill_wait",
-                                            t0 - req.admitted_at)
-                req.prefill_span = tracing.open_serve_span(
-                    ctx, "serve.engine.prefill", t0)
-            req.chunks += 1
-            req.chunk_s += t1 - t0
-            req.chunk_tokens += n_tokens
-            ctx = tracing.child_ctx(ctx, req.prefill_span)
-        tracing.record_serve_span(ctx, "serve.engine.prefill_chunk",
-                                  t0, t1, tokens=n_tokens, pos=req.pos,
-                                  **attrs)
-        observability.observe_phase(app, "prefill", t1 - t0)
-        return t1 - t0
-
-    def _obs_first_token(self, req: "_Request") -> None:
-        """Stamp the first token: it ends the prefill span and with it
-        the chain queue_wait + prefill_wait + prefill_span = TTFT, which
-        goes into the request_phases record as one dict.  A whole-prompt
-        prefix hit launches no chunk: it has no prefill_wait, and its
-        prefill_span is the sampling of the stored logits."""
-        from ray_tpu.serve import observability
-
-        now = req.first_token_at = req.last_emit_wall = time.time()
-        if req.prefill_at is None:
-            req.prefill_at = req.admitted_at
-        observability.metrics()["ttft"].observe(
-            now - req.submitted_at, {"app": self._obs_app(req)})
-        if req.prefill_span is not None:
-            req.prefill_span.attrs.update(
-                tokens=req.chunk_tokens, chunks=req.chunks,
-                chunk_s=req.chunk_s)
-            req.prefill_span.finish(now)
-            req.prefill_span = None
-        self._request_phases.append({
-            "id": req.trace["trace_id"] if req.trace else None,
-            "submitted": req.submitted_at,
-            "queue_wait_s": req.admitted_at - req.submitted_at,
-            "prefill_wait_s": req.prefill_at - req.admitted_at,
-            "prefill_span_s": now - req.prefill_at,
-            "ttft_s": now - req.submitted_at})
-
-    def _obs_burst(self, req: "_Request", t0: float, t1: float,
-                   n_new: int) -> None:
-        """Per fused-burst, per-request: one decode_burst span, one
-        decode_step phase sample, and ONE inter-token-latency sample at
-        the burst-mean gap (per-token observes would cost more than the
-        decode itself at small models)."""
-        if n_new <= 0:
-            return
-        from ray_tpu.serve import observability
-
-        app = self._obs_app(req)
-        tracing.record_serve_span(req.trace, "serve.engine.decode_burst",
-                                  t0, t1, tokens=n_new)
-        observability.observe_phase(app, "decode_step", t1 - t0)
-        if req.last_emit_wall is not None and t1 > req.last_emit_wall:
-            observability.metrics()["itl"].observe(
-                (t1 - req.last_emit_wall) / n_new, {"app": app})
-        req.last_emit_wall = t1
-
-
-class LLMEngine(_EngineBase):
-    def __init__(self, cfg, params, *, num_slots: int = 8,
-                 max_len: int = 1024, prefill_buckets=(64, 128, 256, 512),
-                 eos_id: Optional[int] = None, seed: int = 0,
-                 max_burst: int = 8, prefix_cache_size: int = 4,
-                 speculation_k: int = 0, speculation_ngram: int = 2,
-                 mesh=None):
-        import jax
-
-        from ray_tpu.models.decoding import (
-            init_cache,
-            make_engine_fns,
-            make_prefix_cache_fns,
-            make_spec_fns,
-        )
-
-        self.cfg = cfg
-        # self.params is assigned below, after optional tp resharding.
-        self.num_slots = num_slots
-        self.max_len = max_len
-        self.buckets = tuple(b for b in sorted(prefill_buckets)
-                             if b <= max_len)
-        self.eos_id = eos_id
-        # Burst size: decode ticks fused per device call.  EOS is only
-        # checked between bursts, so with an eos_id short bursts trade
-        # throughput for less overshoot; without one there is no waste.
-        self.max_burst = max(1, max_burst if eos_id is None else
-                             min(max_burst, 4))
-        self._jax = jax
-        self._rng = jax.random.key(seed)
-        if mesh is not None:
-            # Tensor-parallel serving: params split over the mesh `tp`
-            # axis (TP_RULES), KV cache split on its kv-heads axis —
-            # the SAME jitted engine programs run unchanged; GSPMD
-            # propagates the shardings and inserts the all-reduces
-            # after wo/w_down. This is how a model too big for one
-            # chip serves: a sharding annotation, not an engine fork.
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from ray_tpu.models.decoding import cache_shardings
-            from ray_tpu.models.transformer import param_logical_axes
-            from ray_tpu.parallel.mesh import AXIS_TENSOR
-            from ray_tpu.parallel.sharding import (
-                TP_RULES,
-                param_shardings,
-                shard_pytree,
-            )
-
-            tp = int(mesh.shape.get(AXIS_TENSOR, 1))
-            for dim_name, dim in (("n_kv_heads", cfg.n_kv_heads),
-                                  ("n_heads", cfg.n_heads),
-                                  ("d_ff", cfg.d_ff),
-                                  ("vocab_size", cfg.vocab_size)):
-                if dim % tp:
-                    raise ValueError(
-                        f"tensor parallelism {tp} does not divide "
-                        f"{dim_name}={dim} for model {cfg.name!r} — "
-                        f"pick a tp that divides all sharded dims")
-            shardings = param_shardings(param_logical_axes(cfg), mesh,
-                                        TP_RULES)
-            # Shard from HOST copies so the unsharded model never has
-            # to fit on one chip (pass host arrays from params_loader
-            # for models that genuinely don't).
-            params = shard_pytree(jax.device_get(params), shardings)
-            self.cache = init_cache(cfg, num_slots, max_len,
-                                    shardings=cache_shardings(mesh))
-            self._rng = jax.device_put(
-                self._rng, NamedSharding(mesh, P()))
-        else:
-            self.cache = init_cache(cfg, num_slots, max_len)
-        self.params = params
-        self._prefill, self._decode = make_engine_fns(
-            cfg, num_slots=num_slots, max_len=max_len)
-        # Prefix cache (the vLLM automatic-prefix-caching analogue,
-        # scoped to WHOLE prompts): repeated prompts — shared system
-        # prompts, retries, bench warmups — skip prefill entirely; a
-        # hit costs one HBM slot-write + one sampling call instead of
-        # the full prompt forward. LRU-bounded; 0 disables.
-        self._prefix_cache_size = max(0, prefix_cache_size)
-        # Insertion-ordered dict IS the LRU: re-insert on hit, pop the
-        # oldest key on overflow.
-        self._prefix_cache: "Dict[tuple, dict]" = {}
-        if self._prefix_cache_size:
-            (self._px_extract, self._px_insert,
-             self._px_sample) = make_prefix_cache_fns()
-        # Prompt-lookup speculative decoding (opt-in): each tick
-        # verifies K candidate tokens per slot in one call; drafts come
-        # from n-gram matches in the slot's own context. Exact under
-        # greedy decoding; sampling slots degrade to normal decode.
-        self._spec_k = speculation_k if speculation_k >= 2 else 0
-        self._spec_ngram = max(1, speculation_ngram)
-        # The cache margin _maybe_finish keeps free must cover whichever
-        # advance is larger — a burst OR a spec window — WITHOUT
-        # inflating the actual burst depth (the EOS-overshoot cap on
-        # max_burst stays meaningful).
-        self._advance_margin = max(self.max_burst, self._spec_k)
-        if self._spec_k:
-            self._verify = make_spec_fns(cfg)
-        self._pending: "queue.Queue[_Request]" = queue.Queue()
-        self._slots: List[Optional[_Request]] = [None] * num_slots
-        self._last_tokens = np.zeros((num_slots,), np.int32)
-        self._work = threading.Event()
-        self._stop = False
-        self._lock = threading.Lock()
-        self.stats = {"requests": 0, "tokens_generated": 0,
-                      "completed": 0,
-                      "prefix_hits": 0, "prefix_misses": 0,
-                      "spec_proposed": 0, "spec_accepted": 0}
-        self._request_phases: deque = deque(
-            maxlen=self.REQUEST_PHASES_KEPT)
-        self._thread = threading.Thread(target=self._loop, daemon=True)
-        self._thread.start()
-
-    def _pending_put(self, req: "_Request") -> None:
-        self._pending.put(req)
-        self._work.set()
-
-    # -- engine loop ----------------------------------------------------
-    def _bucket_for(self, n: int) -> int:
-        for b in self.buckets:
-            if n <= b:
-                return b
-        return self.max_len
-
-    def _free_slot(self) -> int:
-        for i, r in enumerate(self._slots):
-            if r is None:
-                return i
-        return -1
-
-    def _admit(self) -> bool:
-        import jax.numpy as jnp
-
-        slot = self._free_slot()
-        if slot < 0:
-            return False
-        try:
-            req = self._pending.get_nowait()
-        except queue.Empty:
-            return False
-        try:
-            self._obs_admitted(req)
-            n = len(req.prompt)
-            key = tuple(req.prompt)
-            entry = (self._prefix_cache.get(key)
-                     if self._prefix_cache_size else None)
-            if entry is not None:
-                # Hit: HBM copy of the snapshotted KV + re-sample the
-                # stored last-token logits under THIS request's
-                # temperature — no prompt forward at all.
-                self.cache = self._px_insert(
-                    self.cache, entry["k"], entry["v"],
-                    jnp.int32(slot), jnp.int32(n))
-                tok, self._rng = self._px_sample(
-                    entry["logits"], jnp.float32(req.temperature),
-                    self._rng)
-                self._prefix_cache[key] = self._prefix_cache.pop(key)
-                self.stats["prefix_hits"] += 1
-            else:
-                t0 = time.time()
-                bucket = self._bucket_for(n)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :n] = req.prompt
-                self.cache, tok, last_logits, self._rng = self._prefill(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.int32(slot), jnp.int32(n),
-                    jnp.float32(req.temperature), self._rng)
-                self.stats["prefix_misses"] += 1
-                self._obs_prefill(req, t0, n)
-                if self._prefix_cache_size:
-                    # Snapshot only the prompt's bucket worth of KV.
-                    k_slice, v_slice = self._px_extract(
-                        self.cache, jnp.int32(slot), t=bucket)
-                    self._prefix_cache[key] = {
-                        "k": k_slice, "v": v_slice,
-                        "logits": last_logits}
-                    while len(self._prefix_cache) > \
-                            self._prefix_cache_size:
-                        self._prefix_cache.pop(
-                            next(iter(self._prefix_cache)))
-            self._obs_first_token(req)
-            req.emit(int(tok))
-            req.slot = slot
-            self._slots[slot] = req
-            self._last_tokens[slot] = int(tok)
-            self._maybe_finish(slot)
-        except BaseException as e:  # noqa: BLE001
-            req.error = e
-            if req.token_q is not None:
-                try:
-                    req.token_q.put_nowait(None)
-                except queue.Full:
-                    pass
-            req.done.set()
-        return True
-
-    def _maybe_finish(self, slot: int) -> None:
-        req = self._slots[slot]
-        if req is None:
-            return
-        tok = req.out_tokens[-1] if req.out_tokens else None
-        hit_eos = self.eos_id is not None and tok == self.eos_id
-        # Margin of one full advance (burst or spec window) below
-        # max_len so a fixed-size tick can never run the cache past
-        # its capacity.
-        full = (len(req.prompt) + len(req.out_tokens)
-                >= self.max_len - 1 - getattr(self, "_advance_margin",
-                                              self.max_burst))
-        if hit_eos or full or len(req.out_tokens) >= req.max_tokens \
-                or req.dropped:
-            self._slots[slot] = None
-            self._finish_request(req)
-
-    def _spec_tick(self, active_mask, temps) -> bool:
-        """One speculative verify tick. Returns False when NO slot has
-        a draft (caller falls back to the plain burst — no wasted
-        K-wide call). Greedy acceptance is exact; any accidentally-
-        accepted padding token is by definition the true greedy
-        continuation, so padding needs no masking."""
-        import jax.numpy as jnp
-
-        from ray_tpu.models.decoding import ngram_propose
-
-        k = self._spec_k
-        cand = np.zeros((self.num_slots, k), np.int32)
-        drafted = 0
-        greedy_active = 0
-        for i, req in enumerate(self._slots):
-            if req is None:
-                continue
-            cand[i, 0] = self._last_tokens[i]
-            props = []
-            if req.temperature == 0.0:
-                greedy_active += 1
-                ctx = req.prompt + req.out_tokens
-                props = ngram_propose(ctx, k - 1, self._spec_ngram)
-            for j in range(1, k):
-                cand[i, j] = (props[j - 1] if j - 1 < len(props)
-                              else self._last_tokens[i])
-            if props:
-                drafted += 1
-        # Run the verify tick only when a MAJORITY of active greedy
-        # slots carry a draft: slots without one (and sampling slots)
-        # advance a single token per spec tick, so a lone drafted slot
-        # must not preempt the max_burst-deep decode for everyone else.
-        total_active = int(active_mask.sum())
-        if drafted == 0 or 2 * drafted < greedy_active \
-                or 2 * greedy_active < total_active:
-            return False
-        # All k-1 candidate columns of every GREEDY slot count as
-        # proposed — padding (last-token repeats) can legitimately
-        # accept too, and accepted must never exceed proposed.
-        self.stats["spec_proposed"] += (k - 1) * greedy_active
-        self.cache, tok_out, accepted, self._rng = self._verify(
-            self.params, self.cache, jnp.asarray(cand),
-            jnp.asarray(active_mask), jnp.asarray(temps), self._rng)
-        tok_out = np.asarray(tok_out)
-        accepted = np.asarray(accepted)
-        for i, req in enumerate(self._slots):
-            if req is None:
-                continue
-            a = int(accepted[i])
-            self.stats["spec_accepted"] += a
-            for tok in tok_out[i, :a + 1]:
-                tok = int(tok)
-                if len(req.out_tokens) >= req.max_tokens:
-                    break  # over-generated tail: trim
-                req.emit(tok)
-                self._last_tokens[i] = tok
-                self.stats["tokens_generated"] += 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    break
-            self._maybe_finish(i)
-        return True
-
-    def _loop(self):
-        import jax.numpy as jnp
-
-        while not self._stop:
-            admitted = self._admit()
-            active_mask = np.array([r is not None for r in self._slots])
-            if not active_mask.any():
-                if not admitted:
-                    self._work.wait(timeout=0.05)
-                    self._work.clear()
-                continue
-            try:
-                temps = np.array(
-                    [r.temperature if r else 0.0 for r in self._slots],
-                    np.float32)
-                if self._spec_k and self._spec_tick(active_mask, temps):
-                    continue
-                # Fixed burst size: exactly ONE decode executable (compiles
-                # are expensive, especially via remote-compile).  Slots that
-                # hit max_tokens mid-burst over-generate and are trimmed;
-                # cache overflow is prevented by _maybe_finish's margin.
-                burst = self.max_burst
-                t0 = time.time()
-                self.cache, tok_mat, self._rng = self._decode(
-                    self.params, self.cache,
-                    jnp.asarray(self._last_tokens),
-                    jnp.asarray(active_mask), jnp.asarray(temps), self._rng,
-                    n_steps=burst)
-                tok_mat = np.asarray(tok_mat)          # (burst, S)
-                t1 = time.time()
-                for i, req in enumerate(self._slots):
-                    if req is None:
-                        continue
-                    n0 = len(req.out_tokens)
-                    for step in range(burst):
-                        tok = int(tok_mat[step, i])
-                        if len(req.out_tokens) >= req.max_tokens:
-                            break  # over-generated tail: trim
-                        req.emit(tok)
-                        self._last_tokens[i] = tok
-                        self.stats["tokens_generated"] += 1
-                        if (self.eos_id is not None
-                                and tok == self.eos_id):
-                            break
-                    self._obs_burst(req, t0, t1, len(req.out_tokens) - n0)
-                    self._maybe_finish(i)
-            except BaseException as e:  # noqa: BLE001
-                for i, req in enumerate(self._slots):
-                    if req is not None:
-                        req.error = e
-                        if req.token_q is not None:
-                            try:
-                                req.token_q.put_nowait(None)
-                            except queue.Full:
-                                pass
-                        req.done.set()
-                        self._slots[i] = None
-
-
-class PagedLLMEngine(_EngineBase):
-    """Paged/block KV-cache engine (the tentpole of ROADMAP item 1).
-
-    Engine tick: [admit waiting requests] -> [one fused decode burst
-    over every DECODING slot] -> [one prefill chunk for the oldest
-    PREFILLING slot].  Decode never waits for a whole prompt: a
-    max-length prompt occupies at most `prefill_chunk` tokens of device
-    time per tick, bounding the inter-token latency of active streams.
-
-    Admission: a request needs pool blocks covering its (non-shared)
-    prompt remainder.  When the pool can't cover it, the request WAITS
-    at the head of the queue (no error) until completions free blocks.
-
-    The state of a sequence is asked of the model
-    (`models.decoding.init_sequence_state`), and has up to three kinds:
-
-    - paged KV (`kv_paged`): every position of the layers that keep
-      them all, in pool blocks the `KVBlockAllocator` hands out and a
-      block table names.  Every model has it; for a `TransformerConfig`
-      it is all there is, and the blocks *are* the sequence: prefix
-      sharing, copy-on-write, speculation (a rejected draft is rolled
-      back by length alone), `export_streams` / `import_prefix` all
-      rest on that.
-    - bounded window KV (`kv_window`): a ring of window + prefill_chunk
-      positions a slot for each sliding-window layer, indexed by the
-      engine's slot, owned by whoever holds the slot.
-    - recurrent state (`recurrent`): a state-space layer's conv rows
-      and state by slot.  It is zeroed when a request is admitted to the
-      slot (`_reset_slot_state`, counted in the tick's `reset_s`),
-      carried from chunk to chunk of a prefill, left untouched by a
-      chunk's padded tail and by idle lanes, and zeroed again when
-      `_preempt` sends a stream to re-prefill.
-
-    With a model whose configuration says `recurrent` the blocks are
-    not the sequence, so the engine turns prefix sharing off itself (a
-    hit would skip positions whose state nobody kept) and refuses, with
-    a ValueError, `speculation_k >= 2` at construction and
-    `export_streams` / `import_prefix` when called: snapshots of state
-    are what each would need.
-    """
-    TICKS_KEPT = 4096
-
-    def __init__(self, cfg, params, *, num_slots: int = 32,
-                 max_len: int = 1024, block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 prefill_chunk: Optional[int] = None,
-                 eos_id: Optional[int] = None, seed: int = 0,
-                 max_burst: int = 8, prefix_sharing: Optional[bool] = None,
-                 speculation_k: Optional[int] = None,
-                 speculation_ngram: Optional[int] = None,
-                 store=None):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.core.config import get_config
-        from ray_tpu.models.decoding import (
-            init_sequence_state,
-            make_paged_engine_fns,
-            make_paged_spec_fns,
-            sample_one,
-        )
-        from ray_tpu.serve.kv_cache import KVBlockAllocator
-
-        knobs = get_config()
-        self.cfg = cfg
-        self.params = params
-        self.num_slots = num_slots
-        self.max_len = max_len
-        self.block_size = block_size or knobs.kv_block_size
-        # Default pool budget == the fixed-slot engine's reservation for
-        # the same (num_slots, max_len): equal-HBM comparisons are the
-        # bench's apples-to-apples claim.  +1 for the null block.
-        self.num_blocks = (num_blocks or knobs.kv_block_count
-                           or (num_slots * max_len) // self.block_size + 1)
-        self.prefill_chunk = prefill_chunk or knobs.serve_prefill_chunk
-        # Shape tiers (power-of-two) keep device work proportional to
-        # LOAD, not capacity: a burst over 3 active streams runs at
-        # width 4, not num_slots; a 16-token chunk compiles at width 32,
-        # not prefill_chunk.  One compile per tier — the same bucket
-        # discipline as fixed-engine prefill.
-        self._width_tiers = self._tiers(4, num_slots)
-        self._chunk_tiers = self._tiers(32, self.prefill_chunk)
-        self.eos_id = eos_id
-        self.max_burst = max(1, max_burst if eos_id is None else
-                             min(max_burst, 4))
-        # Prompt-lookup speculative decoding on the paged pool (opt-in,
-        # knob-defaulted): each tick verifies K candidates per slot in
-        # one width-K call; drafts come from n-gram matches in the
-        # slot's own context.  Exact under greedy decoding; sampling
-        # slots degrade to normal decode.
-        if speculation_k is None:
-            speculation_k = knobs.serve_speculation_k
-        if speculation_ngram is None:
-            speculation_ngram = knobs.serve_speculation_ngram
-        self._spec_k = speculation_k if speculation_k >= 2 else 0
-        # A model whose sequences keep recurrent state by slot.
-        self._recurrent = bool(getattr(cfg, "recurrent", False))
-        if self._recurrent and self._spec_k:
-            raise ValueError(
-                f"speculation_k={speculation_k} with {cfg.name!r}: a "
-                f"rejected draft has already advanced the recurrent "
-                f"state, and there is no snapshot to roll it back to")
-        self._spec_ngram = max(1, speculation_ngram)
-        # The free-margin _maybe_finish keeps must cover whichever
-        # advance is larger — a burst OR a spec window — without
-        # inflating the burst depth itself.
-        self._advance_margin = max(self.max_burst, self._spec_k)
-        self._b_max = math.ceil(max_len / self.block_size)
-        prefix_sharing = (knobs.kv_block_prefix_sharing
-                          if prefix_sharing is None else prefix_sharing)
-        if self._recurrent:
-            # A hit would skip positions whose state nobody kept.
-            prefix_sharing = False
-        self._jax = jax
-        self._jnp = jnp
-        self._rng = jax.random.key(seed)
-        self.cache = init_sequence_state(
-            cfg, self.num_blocks, self.block_size, num_slots=num_slots,
-            prefill_chunk=self.prefill_chunk)
-        self._state_bytes = self.cache.resident_bytes()
-        self._reset_state = (jax.jit(cfg.reset_slot, donate_argnums=(0,))
-                             if self._recurrent else None)
-        self._score_step = None
-        # KV positions one decode step sees over lanes of given lengths:
-        # the model's own count, or every layer over every position.
-        self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
-            lambda lengths: cfg.n_layers * sum(lengths))
-        # Layers whose FFN is a set of experts: the burst's count of
-        # experts visited is per layer and step over these.
-        self._expert_layers = (cfg.n_layers
-                               if getattr(cfg, "n_experts", 0) > 0 else 0)
-        self._prefill_chunk_fn, self._decode, self._copy_block = \
-            make_paged_engine_fns(cfg)
-        if self._spec_k:
-            self._verify = make_paged_spec_fns(cfg)
-        self._sample_one = jax.jit(sample_one)
-        bytes_per_block = self._state_bytes["kv_paged"] // self.num_blocks
-        self.allocator = KVBlockAllocator(
-            self.num_blocks, self.block_size, store=store,
-            bytes_per_block=bytes_per_block if store is not None else 0,
-            prefix_sharing=prefix_sharing)
-        # Host-side engine state: per-slot block tables + lengths (the
-        # compiled step only ever sees fixed (S, B_max) arrays).
-        self._tables = np.zeros((num_slots, self._b_max), np.int32)
-        self._lengths = np.zeros((num_slots,), np.int32)
-        self._last_tokens = np.zeros((num_slots,), np.int32)
-        self._slots: List[Optional[_Request]] = [None] * num_slots
-        self._prefillq: deque = deque()   # slots awaiting prefill chunks
-        self._pending: deque = deque()
-        self._pending_lock = threading.Lock()
-        self._work = threading.Event()
-        self._stop = False
-        # Serializes whole engine ticks against the foreign-thread KV
-        # surface (import_prefix / export_streams): those read and
-        # replace self.cache, which a mid-tick decode would otherwise
-        # race.  Uncontended cost is one lock per tick.
-        self._tick_lock = threading.Lock()
-        self.stats = {"requests": 0, "tokens_generated": 0,
-                      "completed": 0,
-                      "prefix_hits": 0, "prefix_misses": 0,
-                      "prefill_chunks": 0, "queue_waits": 0,
-                      "preemptions": 0, "adopted_blocks": 0,
-                      "migrated_blocks": 0, "migrate_fallbacks": 0,
-                      "disagg_prefills": 0,
-                      "spec_proposed": 0, "spec_accepted": 0,
-                      "state_resets": 0, "state_rebuilds": 0}
-        self._request_phases: deque = deque(
-            maxlen=self.REQUEST_PHASES_KEPT)
-        # engine_stats()["tick_log"]: one tuple per tick that progressed
-        # (see _tick), from the accounts the tick's phases keep as they
-        # go.  `stats` is cumulative since the process began; a log lets
-        # a reader take any window's ticks by their start and gives a
-        # median where a counter gives a mean.
-        self._tick_log: deque = deque(maxlen=self.TICKS_KEPT)
-        self._acct = _TickAccounts()
-        self._thread = threading.Thread(target=self._loop, daemon=True)
-        self._thread.start()
-
-    def _pending_put(self, req: "_Request") -> None:
-        with self._pending_lock:
-            self._pending.append(req)
-        self._work.set()
-
-    def shutdown(self):
-        super().shutdown()
-        self._thread.join(timeout=5)
-        self.allocator.release()
-
-    def engine_stats(self, records: bool = True) -> Dict[str, Any]:
-        s = super().engine_stats(records)
         s.update(self.allocator.snapshot())
         # Resident bytes of the sequences' state by kind, and how often
         # recurrent state was zeroed (admissions and preemptions) and
@@ -1314,9 +864,10 @@ class PagedLLMEngine(_EngineBase):
                    temps) -> bool:
         """One speculative verify tick over the compacted decode lanes.
         Returns False when too few slots carry a draft (caller falls
-        back to the plain burst — no wasted K-wide call); the majority
-        rule mirrors the fixed engine's.  Called from inside
-        _decode_tick's try block after _ensure_blocks already extended
+        back to the plain burst — no wasted K-wide call).  Greedy
+        acceptance is exact; an accepted padding token is by definition
+        the true greedy continuation, so padding needs no masking.  Called
+        from inside _decode_tick's try block after _ensure_blocks extended
         every participating table to cover the K window, so the kernel's
         scatter is always in-bounds and always lands in exclusively-
         owned blocks (COW at decode start + fresh growth allocs) —
@@ -1343,9 +894,16 @@ class PagedLLMEngine(_EngineBase):
                                 else self._last_tokens[i])
             if props:
                 drafted += 1
+        # Only when a MAJORITY of the greedy lanes carry a draft: lanes
+        # without one (and sampling lanes) advance a single token per
+        # spec tick, so a lone drafted lane must not preempt the
+        # max_burst-deep decode for everyone else.
         if drafted == 0 or 2 * drafted < greedy_active \
                 or 2 * greedy_active < len(idx):
             return False
+        # All k-1 candidate columns of every greedy lane count as
+        # proposed: padding can accept too, and accepted must never
+        # exceed proposed.
         self.stats["spec_proposed"] += (k - 1) * greedy_active
         t0 = time.time()
         self.cache, tok_out, accepted, self._rng = self._verify(
@@ -1410,6 +968,16 @@ class PagedLLMEngine(_EngineBase):
             req.blocks = []
             self._finish_request(req)
             self._work.set()   # freed blocks may unblock the queue head
+
+    def _finish_request(self, req: "_Request") -> None:
+        """Complete one request: stats + stream sentinel + done event."""
+        self.stats["completed"] += 1
+        if req.token_q is not None:
+            try:
+                req.token_q.put_nowait(None)  # stream sentinel
+            except queue.Full:
+                pass  # dropped stream: done event carries the signal
+        req.done.set()
 
     def _tick(self) -> bool:
         """One engine tick; the caller holds _tick_lock.  A tick that
@@ -1621,6 +1189,126 @@ class PagedLLMEngine(_EngineBase):
                             "block_size": bs, "kv": frame})
         return out
 
+    # -- serving observability ------------------------------------------
+    # Spans attribute each engine phase (queue_wait / prefill_wait /
+    # prefill > prefill_chunk / decode_burst) to the request's trace;
+    # histograms decompose TTFT / ITL per app; `_request_phases` keeps
+    # the same edges per request for engine_stats().  Spans gate on
+    # req.trace (None when the RAY_TPU_SERVE_TRACE_ENABLED kill switch
+    # is off); histograms and the record fill either way.  The app tag
+    # is learned lazily from traced requests — standalone engines
+    # (bench, unit tests) report under "-".
+    _app_hint = "-"
+    # engine_stats()["request_phases"]: the last requests that got a
+    # first token, one dict each (see _obs_first_token).
+    REQUEST_PHASES_KEPT = 1024
+
+    def _obs_submit(self, req: "_Request",
+                    trace: Optional[dict]) -> None:
+        # Direct engine use (no proxy/handle upstream) mints its own
+        # trace so span coverage — and the overhead the kill switch
+        # removes — is identical with and without the HTTP front.
+        req.trace = (trace if trace is not None
+                     else tracing.serve_ctx(uuid.uuid4().hex))
+
+    def _obs_app(self, req: "_Request") -> str:
+        app = req.trace.get("app") if req.trace else None
+        if app:
+            self._app_hint = app
+            return app
+        return self._app_hint
+
+    def _obs_admitted(self, req: "_Request") -> None:
+        from ray_tpu.serve import observability
+
+        now = req.admitted_at = time.time()
+        tracing.record_serve_span(req.trace, "serve.engine.queue_wait",
+                                  req.submitted_at, now,
+                                  tokens=len(req.prompt))
+        observability.observe_phase(self._obs_app(req), "queue_wait",
+                                    now - req.submitted_at)
+
+    def _obs_prefill(self, req: "_Request", t0: float,
+                     n_tokens: int) -> float:
+        """One prefill chunk launched at `t0`; returns its wall time.
+        A request's first chunk ends its prefill_wait and opens its
+        `serve.engine.prefill` span, the parent of every chunk up to the
+        first token.  A preempted request's re-prefill comes after its
+        first token: its chunks are marked `resumed` and stay out of the
+        TTFT phases."""
+        from ray_tpu.serve import observability
+
+        t1 = time.time()
+        app = self._obs_app(req)
+        ctx, attrs = req.trace, {}
+        if req.first_token_at is not None:
+            attrs["resumed"] = 1
+        else:
+            if req.prefill_at is None:
+                req.prefill_at = t0
+                tracing.record_serve_span(
+                    ctx, "serve.engine.prefill_wait", req.admitted_at, t0,
+                    tokens=len(req.prompt))
+                observability.observe_phase(app, "prefill_wait",
+                                            t0 - req.admitted_at)
+                req.prefill_span = tracing.open_serve_span(
+                    ctx, "serve.engine.prefill", t0)
+            req.chunks += 1
+            req.chunk_s += t1 - t0
+            req.chunk_tokens += n_tokens
+            ctx = tracing.child_ctx(ctx, req.prefill_span)
+        tracing.record_serve_span(ctx, "serve.engine.prefill_chunk",
+                                  t0, t1, tokens=n_tokens, pos=req.pos,
+                                  **attrs)
+        observability.observe_phase(app, "prefill", t1 - t0)
+        return t1 - t0
+
+    def _obs_first_token(self, req: "_Request") -> None:
+        """Stamp the first token: it ends the prefill span and with it
+        the chain queue_wait + prefill_wait + prefill_span = TTFT, which
+        goes into the request_phases record as one dict.  A whole-prompt
+        prefix hit launches no chunk: it has no prefill_wait, and its
+        prefill_span is the sampling of the stored logits."""
+        from ray_tpu.serve import observability
+
+        now = req.first_token_at = req.last_emit_wall = time.time()
+        if req.prefill_at is None:
+            req.prefill_at = req.admitted_at
+        observability.metrics()["ttft"].observe(
+            now - req.submitted_at, {"app": self._obs_app(req)})
+        if req.prefill_span is not None:
+            req.prefill_span.attrs.update(
+                tokens=req.chunk_tokens, chunks=req.chunks,
+                chunk_s=req.chunk_s)
+            req.prefill_span.finish(now)
+            req.prefill_span = None
+        self._request_phases.append({
+            "id": req.trace["trace_id"] if req.trace else None,
+            "submitted": req.submitted_at,
+            "queue_wait_s": req.admitted_at - req.submitted_at,
+            "prefill_wait_s": req.prefill_at - req.admitted_at,
+            "prefill_span_s": now - req.prefill_at,
+            "ttft_s": now - req.submitted_at})
+
+    def _obs_burst(self, req: "_Request", t0: float, t1: float,
+                   n_new: int) -> None:
+        """Per fused-burst, per-request: one decode_burst span, one
+        decode_step phase sample, and ONE inter-token-latency sample at
+        the burst-mean gap (per-token observes would cost more than the
+        decode itself at small models)."""
+        if n_new <= 0:
+            return
+        from ray_tpu.serve import observability
+
+        app = self._obs_app(req)
+        tracing.record_serve_span(req.trace, "serve.engine.decode_burst",
+                                  t0, t1, tokens=n_new)
+        observability.observe_phase(app, "decode_step", t1 - t0)
+        if req.last_emit_wall is not None and t1 > req.last_emit_wall:
+            observability.metrics()["itl"].observe(
+                (t1 - req.last_emit_wall) / n_new, {"app": app})
+        req.last_emit_wall = t1
+
 
 def dryrun_tp_serving(cfg, tp: int, *, timeout: float = 45.0) -> None:
     """Compile-and-run check for tensor-parallel serving on the current
@@ -1635,9 +1323,9 @@ def dryrun_tp_serving(cfg, tp: int, *, timeout: float = 45.0) -> None:
 
     mesh = build_mesh(MeshConfig(tp=tp, fsdp=1),
                       devices=jax.devices()[:tp])
-    eng = LLMEngine(cfg, init_params(jax.random.key(1), cfg),
-                    num_slots=2, max_len=64, prefill_buckets=(16,),
-                    prefix_cache_size=0, mesh=mesh)
+    eng = PagedLLMEngine(cfg, init_params(jax.random.key(1), cfg),
+                         num_slots=2, max_len=64, block_size=8,
+                         prefill_chunk=16, mesh=mesh)
     try:
         out = eng.generate([1, 2, 3], max_tokens=4, timeout=timeout)
         assert len(out) == 4, out
@@ -1649,19 +1337,15 @@ class LLMDeployment:
     """Serve-deployable wrapper: __call__({"tokens": [...], ...}) →
     {"tokens": [...]}.  Build with serve.deployment(LLMDeployment).bind(...).
 
-    `engine="paged"` (default) serves through the paged KV-cache engine;
-    `engine="fixed"` is DEPRECATED explicit opt-in to the fixed-slot
-    engine (emits a DeprecationWarning — the paged engine covers its
-    whole feature set at equal HBM, including speculative decoding).
-    Tensor-parallel deployments still fall back to the fixed engine
-    without a warning (the paged kernels are single-device for now)."""
+    Every deployment serves through `PagedLLMEngine`; `engine` accepts
+    `"paged"` only (ROADMAP D14).  `tensor_parallel=N` claims N local
+    chips as a `tp` mesh and hands it to the engine."""
 
     def __init__(self, cfg_name, *, engine: str = "paged",
                  num_slots: int = 8, max_len: int = 512, seed: int = 0,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
-                 prefix_cache_size: int = 4,
                  speculation_k: Optional[int] = None,
                  tensor_parallel: int = 0,
                  prefix_sharing: Optional[bool] = None,
@@ -1678,14 +1362,17 @@ class LLMDeployment:
         from ray_tpu.models import configs, init_params
         from ray_tpu.util import compile_cache
 
+        if engine != "paged":
+            raise ValueError(
+                f"engine={engine!r}: every deployment is served by the "
+                f"paged engine (engine='paged', PagedLLMEngine)")
         # Start counting before the first compile, so runtime_report()
         # can say what this replica's start cost in compiles.
         compile_cache.counts()
         cfg = (configs.get(cfg_name) if isinstance(cfg_name, str)
                else cfg_name)
         recurrent = bool(getattr(cfg, "recurrent", False))
-        if recurrent and (engine != "paged" or tensor_parallel > 1
-                          or disagg):
+        if recurrent and (tensor_parallel > 1 or disagg):
             raise ValueError(
                 f"{cfg.name!r} keeps recurrent state by slot: it is served "
                 f"by the paged engine on one device, without disaggregated "
@@ -1712,37 +1399,20 @@ class LLMDeployment:
                     f"{len(jax.devices())} visible devices")
             mesh = build_mesh(MeshConfig(tp=tensor_parallel, fsdp=1),
                               devices=devs)
-            engine = "fixed"
-        elif engine == "fixed":
-            warnings.warn(
-                "LLMDeployment(engine='fixed') is deprecated: the paged "
-                "engine is the default and covers the fixed engine's "
-                "feature set (prefix caching, speculative decoding) at "
-                "equal HBM with block-granular sharing. The fixed "
-                "engine remains only as the tensor-parallel fallback "
-                "and for explicit opt-in.",
-                DeprecationWarning, stacklevel=2)
-        if engine == "paged":
-            store = None
-            try:
-                import ray_tpu.api as _api
+        store = None
+        try:
+            import ray_tpu.api as _api
 
-                if _api.is_initialized():
-                    store = getattr(_api._global_worker(), "store", None)
-            except Exception:  # noqa: BLE001 standalone use
-                store = None
-            self.engine = PagedLLMEngine(
-                cfg, params, num_slots=num_slots, max_len=max_len,
-                block_size=block_size, num_blocks=num_blocks,
-                prefill_chunk=prefill_chunk, seed=seed,
-                prefix_sharing=prefix_sharing,
-                speculation_k=speculation_k, store=store)
-        else:
-            self.engine = LLMEngine(cfg, params, num_slots=num_slots,
-                                    max_len=max_len,
-                                    prefix_cache_size=prefix_cache_size,
-                                    speculation_k=speculation_k or 0,
-                                    mesh=mesh)
+            if _api.is_initialized():
+                store = getattr(_api._global_worker(), "store", None)
+        except Exception:  # noqa: BLE001 standalone use
+            store = None
+        self.engine = PagedLLMEngine(
+            cfg, params, num_slots=num_slots, max_len=max_len,
+            block_size=block_size, num_blocks=num_blocks,
+            prefill_chunk=prefill_chunk, seed=seed,
+            prefix_sharing=prefix_sharing,
+            speculation_k=speculation_k, store=store, mesh=mesh)
         # Disaggregated serving: this replica decodes; chunked prefill
         # of long prompts offloads to dedicated prefill actors whose
         # finished KV blocks ship back as frames (serve/disagg.py).
@@ -1755,7 +1425,7 @@ class LLMDeployment:
         # Prefill actors re-derive weights from (cfg, seed); a custom
         # params_loader would hand them different weights than this
         # replica decodes with — KV frames would silently mismatch.
-        if disagg and engine == "paged" and params_loader is None:
+        if disagg and params_loader is None:
             from ray_tpu.serve.disagg import DisaggPrefillClient
 
             self._disagg = DisaggPrefillClient(
@@ -1852,10 +1522,9 @@ class LLMDeployment:
         if es.get("spec_proposed"):
             state["spec_accept_rate"] = round(
                 es.get("spec_accepted", 0) / es["spec_proposed"], 4)
-        alloc = getattr(self.engine, "allocator", None)
-        if alloc is not None and cfg.serve_prefix_registry_enabled:
+        if cfg.serve_prefix_registry_enabled:
             state["block_size"] = int(self.engine.block_size)
-            state["prefixes"] = alloc.prefix_digests(
+            state["prefixes"] = self.engine.allocator.prefix_digests(
                 limit=cfg.serve_prefix_registry_max_entries)
         return state
 
@@ -1867,11 +1536,8 @@ class LLMDeployment:
         over.  Returns the number of blocks imported."""
         from ray_tpu.exceptions import KVMigrationError
 
-        imp = getattr(self.engine, "import_prefix", None)
-        if imp is None:
-            raise KVMigrationError(
-                reason="engine has no paged block pool to adopt into")
-        n = imp(tokens, kv, block_size, last_logits=last_logits)
+        n = self.engine.import_prefix(tokens, kv, block_size,
+                                      last_logits=last_logits)
         if n <= 0:
             raise KVMigrationError(
                 reason=f"import_prefix rejected frame "
@@ -1885,11 +1551,4 @@ class LLMDeployment:
     def engine_gauges(self) -> dict:
         """Replica gauge hook: the Replica actor piggybacks these on the
         node daemon's syncer push (serve autoscaling input)."""
-        g = getattr(self.engine, "gauges", None)
-        if g is not None:
-            return g()
-        s = self.engine.engine_stats(records=False)
-        return {"queue_depth": 0.0,
-                "active": float(s.get("requests", 0)
-                                - s.get("completed", 0)),
-                "occupancy": 0.0}
+        return self.engine.gauges()

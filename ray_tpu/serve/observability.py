@@ -204,15 +204,12 @@ def mirror_engine(engine: Any, app: str) -> None:
         m["tokens_per_s"].set(max(0.0, dtok) / (now - t0), tags)
     last["tokens_generated"] = tok
     last["_t"] = now
-    m["inflight"].set(float(s.get("active",
-                                  s.get("requests", 0)
-                                  - s.get("completed", 0))), tags)
-    # Paged-pool internals (absent on the fixed-slot engine).
-    if "blocks_total" in s:
-        for state in ("total", "free", "cached", "active"):
-            m["kv_blocks"].set(float(s.get(f"blocks_{state}", 0.0)),
-                               {**tags, "state": state})
-        m["kv_occupancy"].set(float(s.get("occupancy", 0.0)), tags)
+    m["inflight"].set(float(s["active"]), tags)
+    # The pool's internals.
+    for state in ("total", "free", "cached", "active"):
+        m["kv_blocks"].set(float(s[f"blocks_{state}"]),
+                           {**tags, "state": state})
+    m["kv_occupancy"].set(float(s["occupancy"]), tags)
 
 
 def _engine_id(engine: Any) -> str:

@@ -117,13 +117,13 @@ def test_serve_engine_matches_transformers_generate():
     import jax.numpy as jnp
 
     from ray_tpu.models.hf_convert import from_hf
-    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm import PagedLLMEngine
 
     model = _tiny_llama()
     cfg, params = from_hf(model)
     cfg = dataclasses.replace(cfg, compute_dtype=jnp.float32, remat=False)
-    eng = LLMEngine(cfg, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16,), prefix_cache_size=0)
+    eng = PagedLLMEngine(cfg, params, num_slots=2, max_len=64,
+                         block_size=4, prefill_chunk=16)
     try:
         prompt = [3, 17, 42, 7]
         ours = eng.generate(prompt, max_tokens=6, temperature=0.0,

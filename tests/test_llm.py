@@ -1,4 +1,6 @@
-"""LLM decoding path: prefill/decode vs full forward; continuous batching."""
+"""LLM decoding path: the paged programs vs the full forward; continuous
+batching; tensor-parallel serving on the one engine."""
+import dataclasses
 import threading
 
 import jax
@@ -7,11 +9,18 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import configs, forward, init_params
-from ray_tpu.models.decoding import (decode_step, init_cache, prefill,
-                                     sample_logits)
-from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.models.decoding import (
+    init_paged_cache,
+    paged_cache_shardings,
+    paged_decode_step,
+    paged_prefill_chunk,
+    sample_logits,
+)
+from ray_tpu.serve.llm import LLMDeployment, PagedLLMEngine
 
 CFG = configs.TINY
+BS = 4                                            # block size
+TABLE = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)   # 32 positions
 
 
 @pytest.fixture(scope="module")
@@ -19,21 +28,51 @@ def params():
     return init_params(jax.random.key(0), CFG)
 
 
+def _prefilled(cfg, params, prompt, width=16):
+    """A fresh pool with `prompt` prefilled as one padded chunk into
+    TABLE's blocks: (cache, logits of the last real token)."""
+    cache = init_paged_cache(cfg, num_blocks=17, block_size=BS)
+    toks = jnp.zeros((width,), jnp.int32).at[:len(prompt)].set(
+        jnp.asarray(prompt, jnp.int32))
+    return paged_prefill_chunk(params, cache, toks, TABLE[0], jnp.int32(0),
+                               jnp.int32(len(prompt)), cfg)
+
+
+def _greedy(cfg, params, prompt, n):
+    """`n` greedy tokens by one prefill chunk and n - 1 decode steps."""
+    cache, last = _prefilled(cfg, params, prompt)
+    out = [int(jnp.argmax(last))]
+    lengths = jnp.asarray([len(prompt)], jnp.int32)
+    for _ in range(n - 1):
+        cache, logits = paged_decode_step(
+            params, cache, jnp.asarray([out[-1]], jnp.int32), TABLE,
+            lengths, jnp.asarray([True]), cfg)
+        out.append(int(jnp.argmax(logits[0])))
+        lengths = lengths + 1
+    return out
+
+
+def _engine(cfg, params, **kw):
+    kw = dict(dict(num_slots=2, max_len=64, block_size=BS,
+                   prefill_chunk=16), **kw)
+    return PagedLLMEngine(cfg, params, **kw)
+
+
 def test_prefill_matches_forward(params):
     toks = jax.random.randint(jax.random.key(1), (1, 10), 0, CFG.vocab_size)
-    cache = init_cache(CFG, num_slots=2, max_len=32)
-    padded = jnp.zeros((1, 16), jnp.int32).at[:, :10].set(toks)
-    cache, last_logits = prefill(params, cache, padded, jnp.int32(1),
-                                 jnp.int32(10), CFG)
+    cache, last_logits = _prefilled(CFG, params, np.asarray(toks)[0])
     ref = forward(params, toks, CFG)[0, -1]
     np.testing.assert_allclose(np.asarray(last_logits, np.float32),
                                np.asarray(ref, np.float32), atol=0.15)
-    assert int(cache.lengths[1]) == 10
-    assert int(cache.lengths[0]) == 0
+    # The chunk (its padded tail too) went into the table's blocks; the
+    # blocks of no table are as they were.
+    k = np.asarray(cache.k, np.float32)
+    assert np.abs(k[:, 1:4]).sum() > 0
+    assert np.abs(k[:, 9:]).sum() == 0
 
 
 def test_decode_matches_forward(params):
-    """Greedy decode via cache == greedy decode via full re-forward."""
+    """Greedy decode via the pool == greedy decode via full re-forward."""
     prompt = jax.random.randint(jax.random.key(2), (1, 8), 0,
                                 CFG.vocab_size)
     # reference: iterative full forward
@@ -41,20 +80,7 @@ def test_decode_matches_forward(params):
     for _ in range(5):
         logits = forward(params, jnp.asarray([seq]), CFG)
         seq.append(int(jnp.argmax(logits[0, -1])))
-    ref_out = seq[8:]
-
-    # cache path
-    cache = init_cache(CFG, num_slots=1, max_len=32)
-    padded = jnp.zeros((1, 16), jnp.int32).at[:, :8].set(prompt)
-    cache, last = prefill(params, cache, padded, jnp.int32(0),
-                          jnp.int32(8), CFG)
-    out = [int(jnp.argmax(last))]
-    for _ in range(4):
-        cache, logits = decode_step(params, cache,
-                                    jnp.asarray([out[-1]], jnp.int32),
-                                    jnp.asarray([True]), CFG)
-        out.append(int(jnp.argmax(logits[0])))
-    assert out == ref_out
+    assert _greedy(CFG, params, seq[:8], 5) == seq[8:]
 
 
 def test_sample_logits_greedy_and_topk():
@@ -67,8 +93,7 @@ def test_sample_logits_greedy_and_topk():
 
 
 def test_engine_single_and_concurrent(params):
-    eng = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16, 32))
+    eng = _engine(CFG, params)
     out = eng.generate([1, 2, 3], max_tokens=5)
     assert len(out) == 5
 
@@ -89,123 +114,12 @@ def test_engine_single_and_concurrent(params):
 
 
 def test_engine_determinism_matches_decode(params):
-    """Engine greedy output equals the manual cache path (same tokens)."""
-    eng = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16,))
+    """Engine greedy output equals the manual pool path (same tokens)."""
+    eng = _engine(CFG, params)
     prompt = [5, 6, 7, 8]
     out = eng.generate(prompt, max_tokens=6)
     eng.shutdown()
-
-    cache = init_cache(CFG, num_slots=1, max_len=64)
-    padded = jnp.zeros((1, 16), jnp.int32).at[:, :4].set(
-        jnp.asarray([prompt]))
-    cache, last = prefill(params, cache, padded, jnp.int32(0),
-                          jnp.int32(4), CFG)
-    ref = [int(jnp.argmax(last))]
-    for _ in range(5):
-        cache, logits = decode_step(params, cache,
-                                    jnp.asarray([ref[-1]], jnp.int32),
-                                    jnp.asarray([True]), CFG)
-        ref.append(int(jnp.argmax(logits[0])))
-    assert out == ref
-
-
-def test_prefix_cache_hit_skips_prefill_and_matches(params):
-    """Second generation of the SAME prompt is a prefix-cache hit (no
-    prompt forward) and, under greedy decoding, produces the identical
-    continuation. Distinct prompts miss; LRU bounds the entries
-    (the vLLM automatic-prefix-caching analogue)."""
-    eng = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16,), prefix_cache_size=2)
-    prompt = [5, 6, 7, 8]
-    first = eng.generate(prompt, max_tokens=6)
-    assert eng.stats["prefix_misses"] == 1
-    second = eng.generate(prompt, max_tokens=6)
-    assert eng.stats["prefix_hits"] == 1
-    assert second == first                  # greedy: bitwise-identical
-
-    other = eng.generate([9, 10], max_tokens=4)
-    assert eng.stats["prefix_misses"] == 2
-    assert len(other) == 4
-
-    # LRU eviction at capacity 2: a third prompt evicts the oldest.
-    eng.generate([11, 12, 13], max_tokens=2)
-    assert len(eng._prefix_cache) == 2
-    assert tuple(prompt) not in eng._prefix_cache
-    # Hit path still interleaves correctly with fresh admissions.
-    assert eng.generate([9, 10], max_tokens=4) == other
-    assert eng.stats["prefix_hits"] == 2
-    eng.shutdown()
-
-
-def test_prefix_cache_disabled(params):
-    eng = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16,), prefix_cache_size=0)
-    p = [1, 2, 3]
-    a = eng.generate(p, max_tokens=4)
-    b = eng.generate(p, max_tokens=4)
-    assert a == b
-    assert eng.stats["prefix_hits"] == 0
-    eng.shutdown()
-
-
-def test_verify_step_exact_speculative_acceptance(params):
-    """Speculative verification is EXACT under greedy decoding: correct
-    proposals accept (advancing several tokens in one call), the first
-    wrong proposal rejects, and the continuation equals sequential
-    decode bit-for-bit."""
-    from ray_tpu.models.decoding import verify_step
-
-    prompt = [5, 6, 7, 8]
-    # Reference: sequential greedy decode of 6 tokens.
-    cache = init_cache(CFG, num_slots=1, max_len=64)
-    padded = jnp.zeros((1, 16), jnp.int32).at[:, :4].set(
-        jnp.asarray([prompt]))
-    cache, last = prefill(params, cache, padded, jnp.int32(0),
-                          jnp.int32(4), CFG)
-    ref = [int(jnp.argmax(last))]
-    for _ in range(5):
-        cache, logits = decode_step(params, cache,
-                                    jnp.asarray([ref[-1]], jnp.int32),
-                                    jnp.asarray([True]), CFG)
-        ref.append(int(jnp.argmax(logits[0])))
-
-    # Speculative: candidates = [t0, ref[1], ref[2], WRONG].
-    cache2 = init_cache(CFG, num_slots=1, max_len=64)
-    cache2, last2 = prefill(params, cache2, padded, jnp.int32(0),
-                            jnp.int32(4), CFG)
-    t0 = int(jnp.argmax(last2))
-    assert t0 == ref[0]
-    wrong = (ref[3] + 1) % CFG.vocab_size
-    cand = jnp.asarray([[t0, ref[1], ref[2], wrong]], jnp.int32)
-    rng = jax.random.key(0)
-    cache2, tok_out, accepted, rng = verify_step(
-        params, cache2, cand, jnp.asarray([True]),
-        jnp.asarray([0.0], jnp.float32), rng, CFG)
-    a = int(accepted[0])
-    assert a == 2                        # two correct proposals
-    emitted = [int(t) for t in np.asarray(tok_out[0, :a + 1])]
-    assert emitted == ref[1:4]           # accepted + bonus == reference
-    assert int(cache2.lengths[0]) == 4 + 1 + a   # prompt+t0+accepted
-
-    # Continue decoding after the verify call: still exact.
-    cont = [emitted[-1]]
-    for _ in range(2):
-        cache2, logits = decode_step(params, cache2,
-                                     jnp.asarray([cont[-1]], jnp.int32),
-                                     jnp.asarray([True]), CFG)
-        cont.append(int(jnp.argmax(logits[0])))
-    assert cont[1:] == ref[4:6]
-
-    # A sampling slot (temp>0) accepts nothing — exact fallback.
-    cache3 = init_cache(CFG, num_slots=1, max_len=64)
-    cache3, _ = prefill(params, cache3, padded, jnp.int32(0),
-                        jnp.int32(4), CFG)
-    cache3, tok_out3, accepted3, _ = verify_step(
-        params, cache3, cand, jnp.asarray([True]),
-        jnp.asarray([0.7], jnp.float32), jax.random.key(1), CFG)
-    assert int(accepted3[0]) == 0
-    assert int(cache3.lengths[0]) == 5   # advanced exactly one
+    assert out == _greedy(CFG, params, prompt, 6)
 
 
 def test_paged_verify_step_exact_acceptance(params):
@@ -280,23 +194,23 @@ def test_paged_verify_step_exact_acceptance(params):
     assert int(accepted3[0]) == 0
 
 
-def test_engine_speculative_matches_plain_greedy(params):
+def test_engine_speculative_matches_plain_greedy():
     """With prompt-lookup speculation on, greedy generation must be
     BIT-IDENTICAL to the plain engine (speculation is exact — only
     faster), and drafts must actually be proposed on a repetitive
-    prompt."""
+    prompt.  On the experts here (`tests/test_paged_kv.py` has the dense
+    twin): a verify window routes K rows a lane, a burst one."""
+    mcfg = dataclasses.replace(configs.TINY_MOE, compute_dtype=jnp.float32)
+    mparams = init_params(jax.random.key(0), mcfg)
     # Small bursts make the drafter check often; a long-enough greedy
     # continuation settles into repetition the n-gram lookup can mine.
     prompt = [1, 2, 3, 1, 2, 3, 1, 2]
-    plain = LLMEngine(CFG, params, num_slots=2, max_len=256,
-                      prefill_buckets=(16,), prefix_cache_size=0,
-                      max_burst=2)
+    kw = dict(max_len=256, max_burst=2, prefix_sharing=False)
+    plain = _engine(mcfg, mparams, **kw)
     ref = plain.generate(prompt, max_tokens=96)
     plain.shutdown()
 
-    spec = LLMEngine(CFG, params, num_slots=2, max_len=256,
-                     prefill_buckets=(16,), prefix_cache_size=0,
-                     max_burst=2, speculation_k=4)
+    spec = _engine(mcfg, mparams, speculation_k=4, **kw)
     out = spec.generate(prompt, max_tokens=96)
     assert out == ref
     st = spec.engine_stats()
@@ -307,49 +221,114 @@ def test_engine_speculative_matches_plain_greedy(params):
     spec.shutdown()
 
 
-def test_tensor_parallel_engine_matches_single_device(params):
-    """TP serving: the engine with params/KV sharded over a 2-way tp
-    mesh produces the same greedy generation as the single-device
-    engine — the sharding is a layout change, not a math change (XLA
-    inserts the all-reduces)."""
-    import numpy as np
+# ---------------------------------------------------------------------------
+# tensor-parallel serving: the same engine, given a mesh
+# ---------------------------------------------------------------------------
+def _tp_mesh(tp):
     from jax.sharding import Mesh
 
     from ray_tpu.parallel.mesh import AXIS_TENSOR
 
-    prompt = [4, 5, 6, 7]
-    plain = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                      prefill_buckets=(16,), prefix_cache_size=0)
-    ref = plain.generate(prompt, max_tokens=10)
+    return Mesh(np.array(jax.devices()[:tp]), (AXIS_TENSOR,))
+
+
+def _assert_pool_is_split(cache, tp):
+    """The pool spans the mesh and every shard holds 1/tp of the KV
+    heads."""
+    for a in (cache.k, cache.v):
+        assert len(a.sharding.device_set) == tp
+        assert {s.data.shape[3] for s in a.addressable_shards} == {
+            a.shape[3] // tp}
+
+
+@pytest.mark.parametrize("name,tp", [("tiny", 2), ("tiny-moe", 2),
+                                     ("tiny-moe", 4)])
+def test_tensor_parallel_engine_matches_single_device(name, tp):
+    """TP serving: the engine with params and the pool's KV heads sharded
+    over a tp mesh gives the single-device engine's greedy tokens — the
+    sharding is a layout change, not a math change (XLA inserts the
+    all-reduces).  float32 compute: in bfloat16 each shard's partial sum
+    is rounded before the all-reduce, and a router's near tie then flips
+    on some prompts.  A 39-token prompt in chunks of 16, then bursts."""
+    cfg = dataclasses.replace(configs.get(name), compute_dtype=jnp.float32)
+    params = init_params(jax.random.key(0), cfg)
+    prompt = [(7 * i + 3) % 200 + 1 for i in range(39)]
+    rep = [1, 2, 3, 1, 2, 3, 1, 2]
+    kw = dict(max_len=128, block_size=8)
+    plain = _engine(cfg, params, **kw)
+    ref = plain.generate(prompt, max_tokens=12)
+    ref_rep = plain.generate(rep, max_tokens=24)
     plain.shutdown()
 
-    mesh = Mesh(np.array(jax.devices()[:2]), (AXIS_TENSOR,))
-    tp = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                   prefill_buckets=(16,), prefix_cache_size=0,
-                   mesh=mesh)
-    out = tp.generate(prompt, max_tokens=10)
-    assert out == ref
-    # Params really are distributed: a tp-sharded weight spans devices.
-    wq = tp.params["blocks"]["wq"]
-    assert len(wq.sharding.device_set) == 2
-    # Prefix cache + speculation compose with the sharded layout.
-    tp.shutdown()
+    mesh = _tp_mesh(tp)
+    tp_eng = _engine(cfg, params, mesh=mesh, **kw)
+    try:
+        assert tp_eng.generate(prompt, max_tokens=12) == ref
+        # Params really are distributed: a tp-sharded weight spans the
+        # mesh, and the pool is still split after the donated bursts.
+        wq = tp_eng.params["blocks"]["wq"]
+        assert len(wq.sharding.device_set) == tp
+        _assert_pool_is_split(tp_eng.cache, tp)
+    finally:
+        tp_eng.shutdown()
 
     # Indivisible tp fails with a clear error, not a sharding crash.
-    bad = Mesh(np.array(jax.devices()[:3]), (AXIS_TENSOR,))
     with pytest.raises(ValueError, match="does not divide"):
-        LLMEngine(CFG, params, num_slots=2, max_len=64,
-                  prefill_buckets=(16,), mesh=bad)
+        _engine(cfg, params, mesh=_tp_mesh(3), **kw)
 
-    tp2 = LLMEngine(CFG, params, num_slots=2, max_len=64,
-                    prefill_buckets=(16,), prefix_cache_size=2,
-                    speculation_k=4, mesh=mesh)
-    rep = [1, 2, 3, 1, 2, 3, 1, 2]
-    a = tp2.generate(rep, max_tokens=8)
-    b = tp2.generate(rep, max_tokens=8)   # prefix-cache hit
-    assert a == b
-    assert tp2.stats["prefix_hits"] == 1
-    tp2.shutdown()
+    # Prefix sharing (a whole-prompt hit, its tail block copied) and
+    # speculation compose with the sharded layout.
+    tp2 = _engine(cfg, params, mesh=mesh, speculation_k=4, max_burst=2,
+                  prefix_sharing=True, **kw)
+    try:
+        assert tp2.generate(rep, max_tokens=24) == ref_rep
+        assert tp2.generate(rep, max_tokens=24) == ref_rep
+        assert tp2.stats["prefix_hits"] == 1
+        assert tp2.stats["spec_proposed"] > 0
+        _assert_pool_is_split(tp2.cache, tp)
+    finally:
+        tp2.shutdown()
+
+
+def test_deployment_tensor_parallel_serves_through_the_paged_engine():
+    """`LLMDeployment(tensor_parallel=2)` builds the mesh and hands it to
+    the one engine; its tokens are `tensor_parallel=0`'s."""
+    prompt = [4, 5, 6, 7]
+    kw = dict(num_slots=2, max_len=64, block_size=BS, prefill_chunk=16)
+    one = LLMDeployment("tiny", **kw)
+    ref = one({"tokens": prompt, "max_tokens": 10})["tokens"]
+    one.engine.shutdown()
+    dep = LLMDeployment("tiny", tensor_parallel=2, **kw)
+    try:
+        assert isinstance(dep.engine, PagedLLMEngine)
+        _assert_pool_is_split(dep.engine.cache, 2)
+        assert dep({"tokens": prompt, "max_tokens": 10})["tokens"] == ref
+        assert dep.engine_gauges()["occupancy"] >= 0.0
+    finally:
+        dep.engine.shutdown()
+
+
+def test_a_model_with_state_of_its_own_is_refused_a_mesh():
+    cfg = configs.get("tiny-hybrid")
+    with pytest.raises(ValueError, match="recurrent state"):
+        PagedLLMEngine(cfg, cfg.init_params(jax.random.key(0)),
+                       num_slots=2, max_len=64, block_size=8,
+                       prefill_chunk=16, mesh=_tp_mesh(2))
+
+
+def test_sharded_pool_is_never_whole_on_one_device():
+    """`init_paged_cache` with shardings allocates shard by shard: a pool
+    that fits only across chips never exists whole on chip 0."""
+    shape = (CFG.n_layers, 33, 8, CFG.n_kv_heads, CFG.head_dim)
+    before = {id(a) for a in jax.live_arrays()}
+    cache = init_paged_cache(CFG, 33, 8,
+                             shardings=paged_cache_shardings(_tp_mesh(2)))
+    assert cache.k.shape == cache.v.shape == shape
+    _assert_pool_is_split(cache, 2)
+    whole = [a for a in jax.live_arrays() if id(a) not in before
+             and a.shape == shape and len(a.sharding.device_set) == 1]
+    assert not whole
+    assert float(jnp.abs(cache.k).sum() + jnp.abs(cache.v).sum()) == 0.0
 
 
 def test_moe_engine_decode_matches_reprefill():
@@ -369,38 +348,21 @@ def test_moe_engine_decode_matches_reprefill():
     seq = np.asarray(prompt)[0].tolist()
     ref_out = []
     for _ in range(4):
-        n = len(seq)
-        pad = 16 if n <= 16 else 32
-        c = init_cache(mcfg, num_slots=1, max_len=32)
-        padded = jnp.zeros((1, pad), jnp.int32).at[:, :n].set(
-            jnp.asarray([seq]))
-        _, last = prefill(mparams, c, padded, jnp.int32(0),
-                          jnp.int32(n), mcfg)
-        nxt = int(jnp.argmax(last))
-        ref_out.append(nxt)
-        seq.append(nxt)
+        _, last = _prefilled(mcfg, mparams, seq)
+        ref_out.append(int(jnp.argmax(last)))
+        seq.append(ref_out[-1])
 
     # cached path: one prefill + incremental decode
-    cache = init_cache(mcfg, num_slots=1, max_len=32)
-    padded = jnp.zeros((1, 16), jnp.int32).at[:, :8].set(prompt)
-    cache, last = prefill(mparams, cache, padded, jnp.int32(0),
-                          jnp.int32(8), mcfg)
-    out = [int(jnp.argmax(last))]
-    for _ in range(3):
-        cache, logits = decode_step(mparams, cache,
-                                    jnp.asarray([out[-1]], jnp.int32),
-                                    jnp.asarray([True]), mcfg)
-        out.append(int(jnp.argmax(logits[0])))
-    assert out == ref_out
+    assert _greedy(mcfg, mparams, seq[:8], 4) == ref_out
 
 
 def test_moe_engine_generates():
-    """End-to-end LLMEngine generation on the MoE config."""
+    """End-to-end engine generation on the MoE config."""
     mcfg = configs.TINY_MOE
     mparams = init_params(jax.random.key(5), mcfg)
-    engine = LLMEngine(mcfg, mparams, num_slots=2, max_len=32,
-                       prefill_buckets=(16,))
+    engine = _engine(mcfg, mparams, max_len=32)
     out = engine.generate([3, 1, 4, 1, 5], max_tokens=6,
                           temperature=0.0)
     assert len(out) == 6
     assert all(0 <= t < mcfg.vocab_size for t in out)
+    engine.shutdown()

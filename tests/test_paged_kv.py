@@ -11,11 +11,7 @@ import jax
 from ray_tpu.core.config import reset_config
 from ray_tpu.models import configs, init_params
 from ray_tpu.serve.kv_cache import KVBlockAllocator
-from ray_tpu.serve.llm import (
-    LLMEngine,
-    PagedLLMEngine,
-    StreamQueueFullError,
-)
+from ray_tpu.serve.llm import PagedLLMEngine, StreamQueueFullError
 
 
 @pytest.fixture(scope="module")
@@ -250,24 +246,21 @@ def test_paged_spec_rejected_drafts_with_shared_prefix_cow(tiny_model):
     eng.shutdown()
 
 
-def test_fixed_engine_explicit_optin_deprecated(tiny_model):
-    """engine='fixed' on LLMDeployment is explicit opt-in and warns;
-    the default (paged) does not."""
+def test_engine_fixed_is_refused_and_the_default_is_paged():
+    """There is one served engine: `engine='fixed'` (any value but
+    'paged') raises and names it; the default builds a PagedLLMEngine
+    without a warning."""
     import warnings as _warnings
 
     from ray_tpu.serve.llm import LLMDeployment
 
-    cfg, _ = tiny_model
     with _warnings.catch_warnings():
-        _warnings.simplefilter("error", DeprecationWarning)
+        _warnings.simplefilter("error")
         dep = LLMDeployment("tiny", num_slots=2, max_len=32)
         assert isinstance(dep.engine, PagedLLMEngine)
         dep.engine.shutdown()
-    with pytest.warns(DeprecationWarning, match="engine='fixed'"):
-        dep = LLMDeployment("tiny", engine="fixed", num_slots=2,
-                            max_len=32)
-    assert isinstance(dep.engine, LLMEngine)
-    dep.engine.shutdown()
+    with pytest.raises(ValueError, match="PagedLLMEngine"):
+        LLMDeployment("tiny", engine="fixed", num_slots=2, max_len=32)
 
 
 # ---------------------------------------------------------------------------
@@ -394,20 +387,6 @@ def test_stream_queue_bound_paged(tiny_model, monkeypatch):
     reset_config()
     try:
         eng = make_engine(tiny_model)
-        _slow_consumer_drops(eng)
-        eng.shutdown()
-    finally:
-        monkeypatch.delenv("RAY_TPU_SERVE_STREAM_QUEUE_MAX")
-        reset_config()
-
-
-def test_stream_queue_bound_fixed(tiny_model, monkeypatch):
-    monkeypatch.setenv("RAY_TPU_SERVE_STREAM_QUEUE_MAX", "4")
-    reset_config()
-    try:
-        cfg, params = tiny_model
-        eng = LLMEngine(cfg, params, num_slots=2, max_len=128,
-                        prefill_buckets=(16,), prefix_cache_size=0)
         _slow_consumer_drops(eng)
         eng.shutdown()
     finally:

@@ -146,7 +146,7 @@ class _FakeEngine:
     def __init__(self):
         self.stats = {"tokens_generated": 0, "reuse_hits": 0,
                       "preemptions": 0, "requests": 0, "completed": 0,
-                      "blocks_total": 8, "blocks_free": 8,
+                      "active": 0, "blocks_total": 8, "blocks_free": 8,
                       "blocks_cached": 0, "blocks_active": 0,
                       "occupancy": 0.0}
 

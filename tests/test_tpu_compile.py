@@ -198,6 +198,24 @@ def _serve_shapes(topo, cfg=None, num_slots=8, max_len=2048, block_size=16):
     return cfg, params, cache, b_max, arr
 
 
+def _lower_served(program, cfg, eng, params, cache, arr, width):
+    """`paged_decode_burst` at `width` lanes or `paged_prefill_chunk` at
+    the configuration's chunk, lowered for the shapes `arr` places."""
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    b_max = eng["max_len"] // eng["block_size"]
+    chunk, burst, _ = make_paged_engine_fns(cfg)
+    if program == "paged_decode_burst":
+        return burst.lower(
+            params, cache, arr((width,), jnp.int32),
+            arr((width, b_max), jnp.int32), arr((width,), jnp.int32),
+            arr((width,), jnp.bool_), arr((width,), jnp.float32),
+            arr((), jax.random.key(0).dtype), n_steps=eng["max_burst"])
+    return chunk.lower(
+        params, cache, arr((eng["prefill_chunk"],), jnp.int32),
+        arr((b_max,), jnp.int32), arr((), jnp.int32), arr((), jnp.int32))
+
+
 def test_paged_decode_burst_fits_one_chip(topo):
     """bench-1b4 at the smoke's serving shape (8 slots x 2048, block 16,
     8-step burst): compiles for one v5e chip and fits its 16 GB."""
@@ -242,29 +260,16 @@ def test_served_step_does_not_copy_the_pool(topo, program):
     import re
 
     from bench.harness.spec import BENCH_DIR, transformer_config
-    from ray_tpu.models.decoding import make_paged_engine_fns
 
     with open(os.path.join(BENCH_DIR, "configs",
                            "mistral-7b-serve-1chip.json")) as f:
         config = json.load(f)
     eng = config["engine"]
-    cfg, params, cache, b_max, arr = _serve_shapes(
+    cfg, params, cache, _, arr = _serve_shapes(
         topo, transformer_config(config), eng["num_slots"], eng["max_len"],
         eng["block_size"])
-    w = eng["num_slots"]
-    chunk, burst, _ = make_paged_engine_fns(cfg)
-    if program == "paged_decode_burst":
-        lowered = burst.lower(
-            params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
-            arr((w,), jnp.int32), arr((w,), jnp.bool_),
-            arr((w,), jnp.float32), arr((), jax.random.key(0).dtype),
-            n_steps=eng["max_burst"])
-    else:
-        lowered = chunk.lower(
-            params, cache, arr((eng["prefill_chunk"],), jnp.int32),
-            arr((b_max,), jnp.int32), arr((), jnp.int32),
-            arr((), jnp.int32))
-    compiled = lowered.compile()
+    compiled = _lower_served(program, cfg, eng, params, cache, arr,
+                             eng["num_slots"]).compile()
     mem = compiled.memory_analysis()
     pool_bytes = sum(s.size * s.dtype.itemsize
                      for s in jax.tree.leaves(cache))
@@ -288,6 +293,76 @@ def test_served_step_does_not_copy_the_pool(topo, program):
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
                                      "paged_prefill_chunk"])
+def test_tensor_parallel_served_step_compiles_for_four_chips(topo, program):
+    """What `PagedLLMEngine(mesh=...)` hands the two served programs on
+    `MeshConfig(tp=4)`, at Mistral-7B's widths and the benchmark's serving
+    shape: parameters laid out by `TP_RULES`, the pool's KV heads over
+    `tp`, everything else replicated.  The single-device programs compile
+    unchanged: a device holds a quarter of the weights and of the pool,
+    the pool leaves the step split as it entered (and is still updated in
+    place), and the all-reduces XLA derives carry activations, never a
+    weight-sized array."""
+    import json
+
+    import chip_smoke
+    from bench.harness.spec import BENCH_DIR, transformer_config
+    from ray_tpu.models import init_params
+    from ray_tpu.models.decoding import (
+        init_paged_cache,
+        paged_cache_shardings,
+    )
+    from ray_tpu.models.transformer import param_logical_axes
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import TP_RULES, param_shardings
+
+    with open(os.path.join(BENCH_DIR, "configs",
+                           "mistral-7b-serve-1chip.json")) as f:
+        config = json.load(f)
+    eng = config["engine"]
+    cfg = transformer_config(config)
+    mesh = build_mesh(MeshConfig(tp=4, fsdp=1), devices=topo.devices)
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = placed(
+        jax.eval_shape(lambda: init_params(jax.random.key(0), cfg)),
+        param_shardings(param_logical_axes(cfg), mesh, TP_RULES))
+    num_blocks = eng["num_slots"] * eng["max_len"] // eng["block_size"] + 1
+    cache = placed(
+        jax.eval_shape(lambda: init_paged_cache(cfg, num_blocks,
+                                                eng["block_size"])),
+        paged_cache_shardings(mesh))
+    w = eng["num_slots"]
+    compiled = _lower_served(program, cfg, eng, params, cache, arr,
+                             w).compile()
+    pool_out = compiled.output_shardings[0]
+    for a, sh in ((cache.k, pool_out.k), (cache.v, pool_out.v)):
+        assert sh.shard_shape(a.shape)[3] == cfg.n_kv_heads // 4
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(s.size * s.dtype.itemsize
+                     for s in jax.tree.leaves(cache))
+    state_bytes = pool_bytes + sum(s.size * s.dtype.itemsize
+                                   for s in jax.tree.leaves(params))
+    assert mem.alias_size_in_bytes >= pool_bytes // 4
+    assert mem.argument_size_in_bytes < 1.02 * state_bytes / 4
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    report = chip_smoke._program_report(compiled)
+    assert report["collectives_in_step"].get("all-reduce")
+    # The widest thing reduced is a lane's (or a chunk's) activations or
+    # logits; one layer's wq shard is 4096 x 1024 bf16.
+    rows = w if program == "paged_decode_burst" else eng["prefill_chunk"]
+    assert 0 < report["largest_all_reduce_bytes"] <= (
+        4 * rows * max(cfg.d_model, cfg.vocab_size))
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
 def test_expert_ffn_reads_experts_in_place(topo, program):
     """Mixtral-8x7B at its published widths, depth 2, at the benchmark's
     serving shape: the width-4 burst (the tier `mixtral-chat` decodes at)
@@ -305,31 +380,18 @@ def test_expert_ffn_reads_experts_in_place(topo, program):
     import re
 
     from bench.harness.spec import BENCH_DIR, transformer_config
-    from ray_tpu.models.decoding import make_paged_engine_fns
 
     with open(os.path.join(BENCH_DIR, "configs",
                            "mixtral-8x7b-serve-1chip.json")) as f:
         config = json.load(f)
     config["num_hidden_layers"] = 2
     eng = config["engine"]
-    cfg, params, cache, b_max, arr = _serve_shapes(
+    cfg, params, cache, _, arr = _serve_shapes(
         topo, transformer_config(config), eng["num_slots"], eng["max_len"],
         eng["block_size"])
     assert (cfg.n_experts, cfg.d_model, cfg.d_ff) == (8, 4096, 14336)
-    chunk, burst, _ = make_paged_engine_fns(cfg)
-    if program == "paged_decode_burst":
-        w = 4
-        lowered = burst.lower(
-            params, cache, arr((w,), jnp.int32), arr((w, b_max), jnp.int32),
-            arr((w,), jnp.int32), arr((w,), jnp.bool_),
-            arr((w,), jnp.float32), arr((), jax.random.key(0).dtype),
-            n_steps=eng["max_burst"])
-    else:
-        lowered = chunk.lower(
-            params, cache, arr((eng["prefill_chunk"],), jnp.int32),
-            arr((b_max,), jnp.int32), arr((), jnp.int32),
-            arr((), jnp.int32))
-    compiled = lowered.compile()
+    compiled = _lower_served(program, cfg, eng, params, cache, arr,
+                             4).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     one_expert_matrix = 4096 * 14336 * 2
     mem = compiled.memory_analysis()
