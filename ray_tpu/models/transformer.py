@@ -169,13 +169,17 @@ def param_logical_axes(cfg: TransformerConfig):
     return axes
 
 
-def _attention(q, k, v, cfg: TransformerConfig, *, attn_impl, positions):
-    """q: (B,T,nh,hd), k/v: (B,T,nkv,hd) — GQA broadcast then fused attention."""
-    if cfg.n_kv_heads != cfg.n_heads:
-        rep = cfg.n_heads // cfg.n_kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    return attn_impl(q, k, v)
+def _repeated_kv(attn_impl):
+    """For an `attn_impl` that takes K/V at the query heads' count (ring,
+    Ulysses): GQA broadcast before the call.  The flash kernel takes the KV
+    heads as they are and shares each among its group itself."""
+    def impl(q, k, v):
+        rep = q.shape[2] // k.shape[2]
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        return attn_impl(q, k, v)
+    return impl
 
 
 def _block(x, bp, cfg: TransformerConfig, rules: LogicalRules, *,
@@ -192,7 +196,7 @@ def _block(x, bp, cfg: TransformerConfig, rules: LogicalRules, *,
     q = apply_rope(q, positions, theta=cfg.rope_theta)
     k = apply_rope(k, positions, theta=cfg.rope_theta)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), rules)
-    attn = _attention(q, k, v, cfg, attn_impl=attn_impl, positions=positions)
+    attn = attn_impl(q, k, v)     # k/v at n_kv_heads: (B,T,nkv,hd)
     attn = attn.reshape(b, t, cfg.n_heads * cfg.head_dim)
     x = x + jnp.einsum("bth,hd->btd", attn, bp["wo"].astype(cd))
     x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
@@ -248,13 +252,14 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         else:
             attn_impl = make_ring_attention(mesh, axis=AXIS_SEQ,
                                             causal=True)
+        attn_impl = _repeated_kv(attn_impl)
     else:
         attn_impl = lambda q, k, v: flash_attention(q, k, v, True, None)  # noqa: E731
         if mesh is not None:
             # A Mosaic kernel cannot be partitioned by XLA: under a
             # sharded jit it must see per-device shards (batch over
-            # dp/fsdp, heads over tp; no collective is added).  T stays
-            # whole: the plain kernel's causal mask is local, so on a
+            # dp/fsdp, query and KV heads over tp; no collective is added).
+            # T stays whole: the plain kernel's causal mask is local, so on a
             # mesh with sp > 1 each sp device attends over the full
             # sequence, as it did before the wrapper.
             attn_impl = make_sharded_attention(attn_impl, mesh, axis=None)

@@ -1,16 +1,22 @@
-"""Flash attention: Pallas TPU kernel + XLA reference.
+"""Flash attention: Pallas TPU kernels + XLA reference.
 
-Online-softmax blockwise attention (Dao et al.) laid out for the MXU:
-queries stream through VMEM in `block_q` rows while key/value blocks of
-`block_kv` rows are swept in the innermost grid dimension, with the running
-max/denominator/accumulator held in VMEM scratch across the sweep.  Causal
-sweeps skip fully-masked kv blocks.
+Online-softmax blockwise attention (Dao et al.) laid out for the MXU, with
+one KV head as the unit of work: K and V come at their own head count, and
+a query tile holds the rows of all `g = H // Hkv` query heads of the group
+for one range of positions, so a fetched K/V tile serves `g` times the
+rows and no repeated K/V exists in HBM.  Key/value tiles are swept in the
+innermost grid dimension with the running max / denominator / accumulator
+in VMEM scratch.  Causal sweeps neither compute nor fetch a tile above the
+diagonal (its index map names the tile already resident), and only tiles
+the diagonal crosses pay for the mask.
 
-Autodiff: the forward kernel also emits per-row logsumexp; the backward is
-two more Pallas kernels (Dao-style): dq accumulates over kv blocks, dk/dv
-accumulate over q blocks, with delta = rowsum(do*o) precomputed.  Off the
-TPU both directions use the XLA reference; on it, a shape the kernel
-cannot take does too, and says so once (`_pallas_eligible`).
+Autodiff: the forward kernel also emits per-row logsumexp, one float32 a
+row; the backward is two more kernels (Dao-style): dq accumulates over kv
+tiles, dk/dv hold one KV head's tile and sweep the q tiles of its `g`
+heads, so dK/dV come out at the KV heads' own shape.  delta = rowsum(do*o)
+is one float32 a row too.  Off the TPU both directions use the XLA
+reference; on it, a shape the kernels cannot take does too, and says so
+once (`_pallas_eligible`).
 
 Reference framework has no attention op (compute is torch's problem there);
 this is greenfield per SURVEY.md §2.4.
@@ -28,28 +34,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANES = 128  # TPU lane width; scratch stats are replicated across lanes.
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
                   kv_offset: int = 0):
     """Plain-XLA multi-head attention, numerically stable softmax.
 
-    Shapes: q (B, Tq, H, D), k/v (B, Tkv, H, D).  `kv_offset` shifts kv
-    global positions for causal masking (used by ring attention where the
-    local kv block starts at a nonzero global index; q is assumed to start
-    at global index `kv_offset=0` frame of its caller).
+    Shapes: q (B, Tq, H, D), k/v (B, Tkv, Hkv, D) with H a multiple of Hkv:
+    query head h reads KV head h // (H // Hkv), as `flash_attention` does.
+    `kv_offset` shifts kv global positions for causal masking (used by ring
+    attention where the local kv block starts at a nonzero global index; q
+    is assumed to start at global index `kv_offset=0` frame of its caller).
     """
+    b, tq, h, d = q.shape
+    tk, hkv = k.shape[1], k.shape[2]
     if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+        sm_scale = 1.0 / math.sqrt(d)
+    qg = q.reshape(b, tq, hkv, h // hkv, d)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                   preferred_element_type=jnp.float32)
     s = s * sm_scale
     if causal:
-        tq, tk = q.shape[1], k.shape[1]
         q_pos = jnp.arange(tq)[:, None]
         k_pos = jnp.arange(tk)[None, :] + kv_offset
         s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
+    return out.reshape(b, tq, h, d)
+
+
+def _on_live_tiles(causal, qi, ki, block_q, block_kv, compute):
+    """Run `compute(masked)` once if the tile at q tile `qi` x kv tile `ki`
+    has an entry with q position >= kv position, with the mask only where
+    it has one without: where the diagonal crosses the tile."""
+    if not causal:
+        compute(False)
+        return
+    live = (qi + 1) * block_q > ki * block_kv
+    full = qi * block_q >= (ki + 1) * block_kv - 1
+    pl.when(full)(lambda: compute(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
+        lambda: compute(True))
+
+
+def _seen(qi, ki, block_q, block_kv, q_axis):
+    """The causal mask of a tile, q positions along `q_axis` of its two."""
+    shape = (block_q, block_kv) if q_axis == 0 else (block_kv, block_q)
+    return (qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            >= ki * block_kv
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+
+
+def _group_scores(q, k, qi, ki, block_q, block_kv, sm_scale, masked):
+    """Scaled scores (g * block_q, block_kv) float32 of a group's q tile
+    (g, block_q, d) against one K tile; one position mask for all g heads."""
+    g, _, d = q.shape
+    s = jax.lax.dot_general(q.reshape(g * block_q, d), k, _NT,
+                            preferred_element_type=jnp.float32) * sm_scale
+    if masked:
+        seen = _seen(qi, ki, block_q, block_kv, 0)
+        s = jnp.where(seen[None], s.reshape(g, block_q, block_kv),
+                      _NEG_INF).reshape(g * block_q, block_kv)
+    return s
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -57,6 +105,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                num_kv_blocks: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    g, _, d = q_ref.shape[1:]
 
     @pl.when(ki == 0)
     def _init():
@@ -64,120 +113,114 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: kv block is live iff its first row index <= q block's last row.
-    live = (qi + 1) * block_q > ki * block_kv if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]                             # native dtype -> MXU
-        k = k_ref[0]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale                         # (block_q, block_kv)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[...]                      # (block_q, LANES)
+    def compute(masked):
+        v = v_ref[0]                             # native dtype -> MXU
+        s = _group_scores(q_ref[0], k_ref[0], qi, ki, block_q, block_kv,
+                          sm_scale, masked)
+        m_prev = m_scr[...]                      # (rows, LANES)
         l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)          # (block_q, 1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)          # (rows, 1)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)                     # (block_q, LANES)
-        p = jnp.exp(s - m_new[:, :1])                       # (block_q, block_kv)
+        alpha = jnp.exp(m_prev - m_new)                     # (rows, LANES)
+        p = jnp.exp(s - m_new[:, :1])                       # (rows, block_kv)
         l_new = alpha * l_prev + jnp.broadcast_to(
             jnp.sum(p, axis=-1, keepdims=True), l_prev.shape)
         acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
         l_scr[...] = l_new
 
+    _on_live_tiles(causal, qi, ki, block_q, block_kv, compute)
+
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
-        o_ref[0, ...] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
-        # logsumexp per row, lane-replicated (TPU tiling wants a 128 lane
-        # dim — same layout as the in-tree pallas flash attention)
-        lse_ref[0, ...] = m_scr[...] + jnp.log(l_scr[...])
+        o_ref[0, ...] = (acc_scr[...] / l_scr[:, :1]).reshape(
+            g, block_q, d).astype(o_ref.dtype)
+        # logsumexp, one float32 a row with the rows along the lanes: the
+        # lane-replicated column turned over, a head a sublane.
+        lse = (m_scr[...] + jnp.log(l_scr[...])).T          # (LANES, rows)
+        for j in range(g):
+            lse_ref[0, j:j + 1, :] = lse[:1, j * block_q:(j + 1) * block_q]
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr, *, sm_scale: float, causal: bool,
-                   block_q: int, block_kv: int, num_kv_blocks: int):
+                   dq_ref, dq_scr, lse_scr, delta_scr, *, sm_scale: float,
+                   causal: bool, block_q: int, block_kv: int,
+                   num_kv_blocks: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    g, _, d = q_ref.shape[1:]
+    rows = g * block_q
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # The row statistics come with the rows along the lanes; this sweep
+        # wants them a row a sublane, turned once a q tile.
+        for j in range(g):
+            at = slice(j * block_q, (j + 1) * block_q)
+            lse_scr[at, :] = jnp.expand_dims(lse_ref[0, j], -1)
+            delta_scr[at, :] = jnp.expand_dims(delta_ref[0, j], -1)
 
-    live = (qi + 1) * block_q > ki * block_kv if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
+    def compute(masked):
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                          # (block_q, 1)
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        s = _group_scores(q_ref[0], k, qi, ki, block_q, block_kv, sm_scale,
+                          masked)
+        p = jnp.exp(s - lse_scr[...])                    # (rows, block_kv)
+        dp = jax.lax.dot_general(do_ref[0].reshape(rows, d), v_ref[0], _NT,
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
+        ds = (p * (dp - delta_scr[...])).astype(k.dtype)
         dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            ds, k, _NN, preferred_element_type=jnp.float32)
+
+    _on_live_tiles(causal, qi, ki, block_q, block_kv, compute)
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
-        dq_ref[0, ...] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[0, ...] = (dq_scr[...] * sm_scale).reshape(
+            g, block_q, d).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
                     causal: bool, block_q: int, block_kv: int,
                     num_q_blocks: int):
+    """One KV head's tile against the q tiles of its g query heads.  The
+    scores are held transposed, (block_kv, block_q): both accumulations are
+    then plain products, and the row statistics are used as they are stored,
+    along the lanes."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    g = q_ref.shape[1]
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (qi + 1) * block_q > ki * block_kv if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
+    def compute(masked):
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                              # (bq, bkv)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # p^T @ do
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # ds^T @ q
+        if masked:
+            seen = _seen(qi, ki, block_q, block_kv, 1)
+        for j in range(g):
+            q = q_ref[0, j]                               # (block_q, d)
+            do = do_ref[0, j]
+            st = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                st = jnp.where(seen, st, _NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, j:j + 1, :])     # (block_kv, block_q)
+            dv_scr[...] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)       # p^T @ do
+            dpt = jax.lax.dot_general(v, do, _NT,
+                                      preferred_element_type=jnp.float32)
+            dst = (pt * (dpt - delta_ref[0, j:j + 1, :])).astype(q.dtype)
+            dk_scr[...] += jax.lax.dot_general(
+                dst, q, _NN, preferred_element_type=jnp.float32)  # ds^T @ q
+
+    _on_live_tiles(causal, qi, ki, block_q, block_kv, compute)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -187,15 +230,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None):
-    """Fused attention.  q,k,v: (B, T, H, D) → (B, T, H, D).
+    """Fused attention.  q (B, T, H, D), k/v (B, Tkv, Hkv, D) → (B, T, H, D).
 
-    Uses the Pallas TPU kernel on TPU, XLA reference elsewhere.  GQA/MQA:
-    callers repeat kv heads before the call (XLA folds the broadcast).
+    Uses the Pallas TPU kernels on TPU, XLA reference elsewhere.  GQA/MQA:
+    K/V come at their own head count (H a multiple of Hkv; query head h reads
+    KV head h // (H // Hkv)) and the gradients of K/V have that shape too.
     """
     return _flash_fwd(q, k, v, causal, sm_scale)[0]
 
 
 def _flash_fwd(q, k, v, causal, sm_scale):
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(f"flash_attention: {q.shape[2]} query heads are not "
+                         f"a multiple of {k.shape[2]} KV heads")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if _pallas_eligible(q, k):
@@ -224,7 +271,7 @@ def _pallas_eligible(q, k) -> bool:
     if jax.default_backend() != "tpu":
         return False
     t, tkv = q.shape[1], k.shape[1]
-    if t % 128 or tkv % 128:   # blocks are 128 or 256 rows (_blocks_for)
+    if t % _LANES or tkv % _LANES:   # tiles are multiples of 128 rows
         # Decided at trace time, again for every layer and retrace: the
         # warnings registry shows each distinct message once.
         warnings.warn(
@@ -236,138 +283,253 @@ def _pallas_eligible(q, k) -> bool:
     return True
 
 
-def _blocks_for(t: int, tkv: int) -> tuple[int, int]:
-    # Block sizes must divide the sequence lengths exactly (the grid floors
-    # otherwise and partial blocks would be silently skipped); callers
-    # guarantee t, tkv are multiples of 128.
-    return (256 if t % 256 == 0 else 128), (256 if tkv % 256 == 0 else 128)
+# What a kernel's tiles, scratch and score-sized temporaries may take of
+# VMEM by `_working_set`'s count, and what Mosaic is told it may use.  A v5e
+# core has 128 MiB; Mosaic's own default is 16 MiB of it.
+_VMEM_WORKING_SET = 40 * 2**20
+_VMEM_LIMIT = 64 * 2**20
+_GRID_PARAMS = pltpu.CompilerParams(    # grids are (bh, outer tile, swept tile)
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT)
+
+# Tiles, from a sweep on one v5e (`TPU v5 lite`, 2026-09-28, PR 33): bf16,
+# causal, ms a call of forward / dq / dk-dv with the folds (and, for the
+# two backward kernels, delta) included, each kernel alone in its jit; the
+# parent's 256 x 256 kernels on K/V repeated to the query heads beside them.
+# A step's q rows are g * block_q.
+#
+# (2, 4096, 32/8, 128), g = 4: parent 256 x 256  12.09 / 9.79 / 16.39
+#   block_q \ block_kv      256                  512                 1024                 2048
+#     128          8.62 / 6.06 / 6.43   5.22 / 4.95 / 5.39   3.66 / 4.78 / 5.11   3.98 / 4.89 / 5.50
+#     256          9.21 / 4.89 / 5.15   5.25 / 4.39 / 4.73   3.30 / 4.41 / 4.75   3.87 / 4.71 / 5.31
+#     512          7.08 / 4.43 / 4.50   4.32 / 4.13 / 4.28   3.22 / 4.23 / 4.53   3.77 / 4.62 / 5.21
+#    1024          7.82 / 4.39 / 4.66   4.32 / 4.23 / 4.54   3.15 / 4.17 / 4.51   6.99 / 7.59 / 8.33
+# (8, 2048, 16/16, 64), g = 1: parent 256 x 256  6.61 / 5.56 / 8.93
+#     256          6.45 / 5.18 / 5.40   4.21 / 4.13 / 4.32   3.03 / 3.63 / 4.06   3.08 / 3.64 / 4.43
+#     512          5.46 / 4.04 / 3.89   3.47 / 3.43 / 3.45   2.55 / 3.29 / 3.61   3.00 / 3.48 / 4.12
+#    1024          6.76 / 3.63 / 3.82   3.91 / 3.28 / 3.60   2.40 / 3.13 / 3.48   2.93 / 3.41 / 4.04
+#    2048          6.35 / 3.67 / 4.29   3.98 / 3.49 / 4.10   2.89 / 3.39 / 4.03   2.36 / 3.22 / 4.07
+# (4, 2048, 16/16, 128), g = 1: parent 256 x 256  3.26 / 2.91 / 4.48
+#     256          3.38 / 2.80 / 2.99   2.25 / 2.27 / 2.45   1.66 / 2.03 / 2.32   1.66 / 2.02 / 2.50
+#     512          2.87 / 2.23 / 2.25   1.86 / 1.92 / 2.00   1.42 / 1.85 / 2.09   1.64 / 1.93 / 2.35
+#    1024          3.53 / 2.01 / 2.21   2.14 / 1.85 / 2.09   1.32 / 1.77 / 2.06   1.62 / 1.90 / 2.33
+#    2048          3.33 / 2.03 / 2.43   2.15 / 1.93 / 2.34   1.58 / 1.92 / 2.34   1.27 / 1.76 / 2.26
+#
+# What a step costs whatever it reads (rescaling the accumulator, the row
+# statistics: all by the q rows) is paid once a kv tile, so the forward
+# wants 1024 kv rows before anything else; past 2048 q rows or 1024
+# positions nothing is won (a tile the diagonal crosses is computed whole:
+# wider tiles waste more of it), and 1024 x 2048 at g = 4 no longer fits.
+# The dk/dv kernel works a head at a time and is best at 512 x 512
+# everywhere.  Chosen: forward and dq 512 x 1024 at g = 4 (3.22 / 4.23) and
+# 1024 x 1024 at g = 1 (2.40 / 3.13, 1.32 / 1.77), dk/dv 512 x 512 (4.28,
+# 3.45, 2.00): within 3% of the best cell that takes no more VMEM.  Inside
+# the train step (2 x 4096 tokens a device, full remat; the same chip) a
+# layer's four calls take 2.67 + 2.67 + 3.33 + 3.40 ms where the parent's
+# took 11.2 + 11.2 + 8.2 + 14.3.
+_TILE_CAPS = {"fwd": (1024, 1024), "dq": (1024, 1024), "dkv": (512, 512)}
+_GROUP_ROWS = 2048   # forward, dq: g * block_q, the rows of one score tile
 
 
-def _fold(x):
+def _working_set(kernel: str, block_q: int, block_kv: int, d: int, g: int,
+                 itemsize: int) -> int:
+    """Bytes of VMEM a grid step of `kernel` holds: the double-buffered
+    tiles, the scratch, and the score-sized temporaries (float32 but for the
+    copies handed to the MXU)."""
+    rows = g * block_q
+    q_tile, kv_tile = rows * d * itemsize, block_kv * d * itemsize
+    stat_tile = 8 * block_q * 4              # (g, block_q) f32, 8 sublanes
+    column = rows * _LANES * 4               # a (rows, 1) or lane-replicated
+    if kernel == "fwd":                      # q, o | k, v | lse
+        tiles = 2 * q_tile + 2 * kv_tile + stat_tile
+        scratch = 2 * column + rows * d * 4
+        scores = rows * block_kv * (2 * 4 + itemsize)
+    elif kernel == "dq":                     # q, do, dq | k, v | lse, delta
+        tiles = 3 * q_tile + 2 * kv_tile + 2 * stat_tile
+        scratch = 2 * column + rows * d * 4
+        scores = rows * block_kv * (3 * 4 + itemsize)
+    else:                                    # q, do | k, v, dk, dv | lse, delta
+        tiles = 2 * q_tile + 4 * kv_tile + 2 * stat_tile
+        scratch = 2 * block_kv * d * 4
+        scores = block_q * block_kv * (3 * 4 + 2 * itemsize)  # a head a time
+    return 2 * tiles + scratch + scores
+
+
+def _largest_tile(n: int, cap: int) -> int:
+    """Largest multiple of 128 that divides `n` and is at most `cap` (the
+    grid floors otherwise and a partial tile would be silently skipped);
+    callers guarantee n is a multiple of 128."""
+    return max(b for b in range(_LANES, max(cap, _LANES) + 1, _LANES)
+               if n % b == 0)
+
+
+def _blocks_for(t: int, tkv: int, d: int, g: int, itemsize: int = 2):
+    """(block_q, block_kv) of the forward, dq and dk/dv kernels, from the
+    shapes alone: the sweep's best tiles (`_TILE_CAPS`, `_GROUP_ROWS`) cut
+    to divisors of the lengths, then stepped down to the next divisor, the
+    side with more rows first, while the working set is over
+    `_VMEM_WORKING_SET`."""
+    blocks = []
+    for kernel, (q_cap, kv_cap) in _TILE_CAPS.items():
+        if kernel != "dkv":
+            q_cap = min(q_cap, _GROUP_ROWS // g)
+        block_q = _largest_tile(t, q_cap)
+        block_kv = _largest_tile(tkv, kv_cap)
+        while (_working_set(kernel, block_q, block_kv, d, g, itemsize)
+               > _VMEM_WORKING_SET and max(block_q, block_kv) > _LANES):
+            if g * block_q >= block_kv and block_q > _LANES:
+                block_q = _largest_tile(t, block_q - _LANES)
+            else:
+                block_kv = _largest_tile(tkv, block_kv - _LANES)
+        blocks.append((block_q, block_kv))
+    return tuple(blocks)
+
+
+def _fold_q(x, hkv):
+    """(B, T, H, D) -> (B * Hkv, g, T, D): a KV head's query heads together."""
+    b, t, h, d = x.shape
+    return x.reshape(b, t, hkv, h // hkv, d).transpose(0, 2, 3, 1, 4).reshape(
+        b * hkv, h // hkv, t, d)
+
+
+def _unfold_q(x, b):
+    bh, g, t, d = x.shape
+    return x.reshape(b, bh // b, g, t, d).transpose(0, 3, 1, 2, 4).reshape(
+        b, t, (bh // b) * g, d)
+
+
+def _fold_kv(x):
     b, t, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
+def _unfold_kv(x, b):
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
+def _q_sweep_maps(causal, block_q, block_kv):
+    """Index maps of a (bh, q tile, kv tile) grid, the forward's and dq's:
+    a group's q-side tile, its row statistics, and the K/V tile."""
+    def q_map(bh, qi, ki):
+        return (bh, 0, qi, 0)
+
+    def stat_map(bh, qi, ki):
+        return (bh, 0, qi)
+
+    def kv_map(bh, qi, ki):
+        # Above the diagonal nothing is computed: name the q tile's last
+        # live kv tile, already resident, so nothing is fetched either.
+        if causal:
+            ki = jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_kv)
+        return (bh, ki, 0)
+
+    return q_map, stat_map, kv_map
+
+
 def _flash_pallas(q, k, v, *, causal, sm_scale):
+    """-> out (B, T, H, D), lse (B * Hkv, g, T) float32."""
     b, t, h, d = q.shape
-    tkv = k.shape[1]
-    block_q, block_kv = _blocks_for(t, tkv)
-    num_q = t // block_q
+    tkv, hkv = k.shape[1], k.shape[2]
+    g = h // hkv
+    (block_q, block_kv), _, _ = _blocks_for(t, tkv, d, g, q.dtype.itemsize)
     num_kv = tkv // block_kv
-
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-
-    kernel = functools.partial(
-        _fa_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, num_kv_blocks=num_kv)
-
+    q_map, stat_map, kv_map = _q_sweep_maps(causal, block_q, block_kv)
+    q_spec = pl.BlockSpec((1, g, block_q, d), q_map)
+    kv_spec = pl.BlockSpec((1, block_kv, d), kv_map)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, num_q, num_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-        ],
+        functools.partial(
+            _fa_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_kv=block_kv, num_kv_blocks=num_kv),
+        grid=(b * hkv, t // block_q, num_kv),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, pl.BlockSpec((1, g, block_q), stat_map)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * hkv, g, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * hkv, g, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((g * block_q, _LANES), jnp.float32),
+            pltpu.VMEM((g * block_q, _LANES), jnp.float32),
+            pltpu.VMEM((g * block_q, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(qf, kf, vf)
-
-    def unfold(x):
-        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-
-    return unfold(out), lse
+        compiler_params=_GRID_PARAMS,
+    )(_fold_q(q, hkv), _fold_kv(k), _fold_kv(v))
+    return _unfold_q(out, b), lse
 
 
-def _flash_bwd_pallas(q, k, v, out, lse, g, *, causal, sm_scale):
-    """Dao-style backward: one kernel accumulating dq over kv blocks, one
-    accumulating dk/dv over q blocks.  delta = rowsum(do * o)."""
+def _flash_bwd_pallas(q, k, v, out, lse, g_out, *, causal, sm_scale):
+    """Dao-style backward: one kernel accumulating dq over kv tiles, one
+    accumulating dk/dv over the q tiles of a KV head's whole group.
+    delta = rowsum(do * o), one float32 a row like `lse`."""
     b, t, h, d = q.shape
-    tkv = k.shape[1]
-    block_q, block_kv = _blocks_for(t, tkv)
-    num_q, num_kv = t // block_q, tkv // block_kv
-    bh = b * h
+    tkv, hkv = k.shape[1], k.shape[2]
+    g = h // hkv
+    _, dq_blocks, dkv_blocks = _blocks_for(t, tkv, d, g, q.dtype.itemsize)
+    delta = jnp.sum(g_out.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)                               # (B, T, H)
+    folded = (_fold_q(q, hkv), _fold_kv(k), _fold_kv(v), _fold_q(g_out, hkv),
+              lse, delta.transpose(0, 2, 1).reshape(b * hkv, g, t))
+    dqf = _dq_call(folded, *dq_blocks, causal=causal, sm_scale=sm_scale)
+    dkf, dvf = _dkv_call(folded, *dkv_blocks, causal=causal,
+                         sm_scale=sm_scale)
+    return _unfold_q(dqf, b), _unfold_kv(dkf, b), _unfold_kv(dvf, b)
 
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    dof, of = _fold(g), _fold(out)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1)                               # (BH, T)
-    delta = jnp.broadcast_to(delta[..., None], (bh, t, _LANES))
 
-    common_in = [qf, kf, vf, dof, lse, delta]
+def _dq_call(folded, block_q, block_kv, *, causal, sm_scale):
+    qf, kf = folded[:2]
+    bh, g, t, d = qf.shape
+    num_kv = kf.shape[1] // block_kv
+    q_map, stat_map, kv_map = _q_sweep_maps(causal, block_q, block_kv)
+    q_spec = pl.BlockSpec((1, g, block_q, d), q_map)
+    kv_spec = pl.BlockSpec((1, block_kv, d), kv_map)
+    stat_spec = pl.BlockSpec((1, g, block_q), stat_map)
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_kv=block_kv, num_kv_blocks=num_kv),
+        grid=(bh, t // block_q, num_kv),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+        scratch_shapes=[pltpu.VMEM((g * block_q, d), jnp.float32),
+                        pltpu.VMEM((g * block_q, 1), jnp.float32),
+                        pltpu.VMEM((g * block_q, 1), jnp.float32)],
+        compiler_params=_GRID_PARAMS,
+    )(*folded)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, num_kv_blocks=num_kv)
-    dqf = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, num_q, num_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*common_in)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_kv=block_kv, num_q_blocks=num_q)
-    dkf, dvf = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, num_kv, num_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, ki, qi: (bh, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tkv, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tkv, d), v.dtype),
-        ],
+def _dkv_call(folded, block_q, block_kv, *, causal, sm_scale):
+    qf, kf, vf = folded[:3]
+    bh, g, t, d = qf.shape
+    num_q = t // block_q
+
+    def first_live_q(ki, qi):
+        # Below the first q tile that sees this kv tile nothing is computed:
+        # name that tile, which the sweep needs next anyway.
+        if causal:
+            qi = jnp.maximum(qi, jnp.minimum(ki * block_kv // block_q,
+                                             num_q - 1))
+        return qi
+
+    q_spec = pl.BlockSpec((1, g, block_q, d),
+                          lambda bh, ki, qi: (bh, 0, first_live_q(ki, qi), 0))
+    stat_spec = pl.BlockSpec((1, g, block_q),
+                             lambda bh, ki, qi: (bh, 0, first_live_q(ki, qi)))
+    kv_spec = pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_kv=block_kv, num_q_blocks=num_q),
+        grid=(bh, kf.shape[1] // block_kv, num_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+                   jax.ShapeDtypeStruct(vf.shape, vf.dtype)],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*common_in)
-
-    def unfold(x, tt):
-        return x.reshape(b, h, tt, d).transpose(0, 2, 1, 3)
-
-    return unfold(dqf, t), unfold(dkf, tkv), unfold(dvf, tkv)
+        compiler_params=_GRID_PARAMS,
+    )(*folded)
 
 
 # Pool blocks a lane reads per trip of `paged_attention`'s loop.  A trip

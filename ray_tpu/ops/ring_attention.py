@@ -107,7 +107,10 @@ def make_sharded_attention(local_fn, mesh: Mesh, *,
     `head_axis`.  Only axes present in `mesh` are used.  `local_fn`
     takes per-device (q, k, v) shards.  `axis=None` keeps T whole on
     every device: required for a `local_fn` that does not exchange
-    blocks over the axis itself (the plain flash kernel).
+    blocks over the axis itself (the plain flash kernel).  K/V may come
+    at fewer heads than q (GQA): where their count does not divide over
+    `head_axis` they are repeated just enough that it does, so a device
+    holds the KV heads of its own query heads.
     """
     sizes = mesh_axis_sizes(mesh)
     bspec = tuple(a for a in batch_axes if a in sizes) or None
@@ -124,6 +127,10 @@ def make_sharded_attention(local_fn, mesh: Mesh, *,
                 f"axes {bspec} (x{data_shards}): computed unsharded on "
                 f"each device", stacklevel=2)
             b = None
+        hkv = math.lcm(k.shape[2], sizes[hspec]) if hspec else k.shape[2]
+        if hkv != k.shape[2] and q.shape[2] % hkv == 0:
+            k = jnp.repeat(k, hkv // k.shape[2], axis=2)
+            v = jnp.repeat(v, hkv // v.shape[2], axis=2)
         spec = P(b, axis if axis in sizes else None, hspec, None)
         return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)

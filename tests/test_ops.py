@@ -41,13 +41,21 @@ def test_flash_grads_finite():
     np.testing.assert_allclose(np.asarray(gq), np.asarray(gq_ref), atol=1e-4)
 
 
+@pytest.mark.parametrize("t", [640, 768])
 @pytest.mark.parametrize("causal", [True, False])
-def test_pallas_kernels_match_reference_in_interpret_mode(monkeypatch, causal):
+@pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["mha", "gqa4"])
+def test_pallas_kernels_match_reference_in_interpret_mode(monkeypatch, heads,
+                                                          causal, t):
     """The three Pallas kernels themselves (forward, dq, dk/dv), run by the
-    Pallas interpreter on the CPU: 2x2 blocks of 256, so the causal skip,
-    the online-softmax carry and both backward accumulations are live.
-    Steered from here (no option in the program): `_pallas_eligible` is
-    false off the TPU and `pallas_call` compiles for Mosaic."""
+    Pallas interpreter on the CPU, MHA and a group of four query heads a KV
+    head, against the reference on explicitly repeated K/V; dK/dV at the KV
+    heads' own shape.  The tile caps are cut so that both lengths span
+    several tiles: 640 only 128 divides (5 x 5 tiles), 768 takes 384 x 256
+    (forward, dq) and 256 x 384 (dk/dv) for MHA, so the diagonal crosses
+    tiles off their corners; the causal skip and clamp, the online-softmax
+    carry and both backward accumulations are live.  Steered from here (no
+    option in the program): `_pallas_eligible` is false off the TPU and
+    `pallas_call` compiles for Mosaic."""
     from jax.experimental import pallas as pl
 
     from ray_tpu.ops import attention
@@ -55,7 +63,11 @@ def test_pallas_kernels_match_reference_in_interpret_mode(monkeypatch, causal):
     monkeypatch.setattr(attention, "_pallas_eligible", lambda q, k: True)
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
-    q, k, v = _qkv(jax.random.key(7), b=1, t=512, h=2, d=64)
+    monkeypatch.setattr(attention, "_TILE_CAPS", {
+        "fwd": (512, 256), "dq": (512, 256), "dkv": (256, 384)})
+    h, h_kv = heads
+    q, k, v = _qkv(jax.random.key(7), b=1, t=t, h=h, d=64)
+    k, v = k[:, :, :h_kv], v[:, :, :h_kv]
 
     def grads(attn):
         return jax.grad(lambda q_, k_, v_: jnp.sum(attn(q_, k_, v_) ** 2),
@@ -65,13 +77,62 @@ def test_pallas_kernels_match_reference_in_interpret_mode(monkeypatch, causal):
         return flash_attention(q_, k_, v_, causal, None)
 
     def ref(q_, k_, v_):
-        return mha_reference(q_, k_, v_, causal=causal)
+        rep = h // h_kv
+        return mha_reference(q_, jnp.repeat(k_, rep, axis=2),
+                             jnp.repeat(v_, rep, axis=2), causal=causal)
 
     np.testing.assert_allclose(np.asarray(flash(q, k, v)),
                                np.asarray(ref(q, k, v)), atol=1e-5)
-    for g, g_ref in zip(grads(flash), grads(ref)):
+    # The reference's own grouped form is the same contract.
+    np.testing.assert_allclose(
+        np.asarray(mha_reference(q, k, v, causal=causal)),
+        np.asarray(ref(q, k, v)), atol=1e-5)
+    for g, g_ref, like in zip(grads(flash), grads(ref), (q, k, v)):
+        assert g.shape == like.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
                                    atol=1e-4)
+
+
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t", [128, 384, 640, 2048, 4096])
+def test_flash_blocks_divide_the_lengths_and_fit_vmem(t, d, g):
+    """`_blocks_for` only ever names tiles that divide the lengths (the grid
+    floors: a partial tile would be skipped in silence), in multiples of the
+    128 lanes the row statistics are laid along, and whose working set, by
+    the code's own count, is under the VMEM figure the code states."""
+    from ray_tpu.ops import attention
+
+    for tkv in (t, 2 * t):
+        blocks = attention._blocks_for(t, tkv, d, g)
+        assert len(blocks) == 3
+        for kernel, (block_q, block_kv) in zip(("fwd", "dq", "dkv"), blocks):
+            assert t % block_q == 0 and block_q % 128 == 0
+            assert tkv % block_kv == 0 and block_kv % 128 == 0
+            assert attention._working_set(
+                kernel, block_q, block_kv, d, g,
+                2) <= attention._VMEM_WORKING_SET < attention._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("tp,h_kv", [(2, 2), (4, 2), (8, 2)])
+def test_sharded_flash_attention_shards_kv_heads_over_tp(tp, h_kv):
+    """Under `tp` the KV heads shard with their query heads; where their
+    count does not divide, K/V are repeated just enough that it does (2 KV
+    heads over tp=4 become 4, over tp=8 one a device), so every device
+    holds the KV heads of its own query heads."""
+    from ray_tpu.ops.ring_attention import make_sharded_attention
+
+    mesh = build_mesh(MeshConfig(fsdp=1, tp=tp), devices=jax.devices()[:tp])
+    q, k, v = _qkv(jax.random.key(11), b=2, t=32, h=8, d=16)
+    k, v = k[:, :, :h_kv], v[:, :, :h_kv]
+    attn = make_sharded_attention(
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, True, None), mesh,
+        axis=None)
+    with mesh:
+        out = jax.jit(attn)(q, k, v)
+    ref = mha_reference(q, jnp.repeat(k, 8 // h_kv, axis=2),
+                        jnp.repeat(v, 8 // h_kv, axis=2), causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_ring_attention_matches_full():

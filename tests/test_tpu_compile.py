@@ -60,31 +60,41 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-# (B, T, H, D) of the attention call inside bench-350m at batch 8 and
-# bench-1b4 at batch 4, both at seq 2048.
-FLASH_SHAPES = [(8, 2048, 16, 64), (4, 2048, 16, 128)]
+# (B, T, H, Hkv, D) of the attention call inside bench-350m at batch 8 and
+# bench-1b4 at batch 4, both at seq 2048 (MHA), and a device's share of the
+# `mistral7b-sft-fsdp4` cell's: four query heads a KV head at seq 4096.
+FLASH_SHAPES = [(8, 2048, 16, 16, 64), (4, 2048, 16, 16, 128),
+                (2, 4096, 32, 8, 128)]
+FLASH_IDS = ["350m", "1b4", "mistral-gqa"]
 
 
-@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["350m", "1b4"])
+def _flash_args(shape, sharding, batch=None):
+    b, t, h, h_kv, d = shape
+    b = batch or b
+    return (jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=sharding),
+            jax.ShapeDtypeStruct((b, t, h_kv, d), jnp.bfloat16,
+                                 sharding=sharding))
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=FLASH_IDS)
 def test_flash_forward_kernel_compiles(topo, shape):
-    one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    q, kv = _flash_args(shape, SingleDeviceSharding(topo.devices[0]))
     fn = jax.jit(lambda q, k, v: attention._flash_pallas(
         q, k, v, causal=True, sm_scale=shape[-1] ** -0.5))
-    compiled = fn.lower(x, x, x).compile()
+    compiled = fn.lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["350m", "1b4"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=FLASH_IDS)
 def test_flash_backward_kernels_compile(topo, shape):
-    b, t, h, _ = shape
+    b, t, h, h_kv, _ = shape
     one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
-    lse = jax.ShapeDtypeStruct((b * h, t, attention._LANES), jnp.float32,
+    q, kv = _flash_args(shape, one)
+    lse = jax.ShapeDtypeStruct((b * h_kv, h // h_kv, t), jnp.float32,
                                sharding=one)
     fn = jax.jit(lambda q, k, v, o, lse, g: attention._flash_bwd_pallas(
         q, k, v, o, lse, g, causal=True, sm_scale=shape[-1] ** -0.5))
-    text = fn.lower(x, x, x, x, lse, x).compile().as_text()
+    text = fn.lower(q, kv, kv, q, lse, q).compile().as_text()
     # dq and dk/dv are separate kernels.
     assert text.count("tpu_custom_call") >= 2
 
@@ -95,23 +105,27 @@ def _fsdp_mesh(topo):
     return build_mesh(MeshConfig(fsdp=4), devices=topo.devices)
 
 
-def test_flash_attention_under_fsdp_mesh_compiles(topo, monkeypatch):
+@pytest.mark.parametrize("shape", [FLASH_SHAPES[0], FLASH_SHAPES[2]],
+                         ids=[FLASH_IDS[0], FLASH_IDS[2]])
+def test_flash_attention_under_fsdp_mesh_compiles(topo, monkeypatch, shape):
     """The public flash_attention with the batch split over four chips:
     a bare pallas_call under a sharded jit is refused ("Mosaic kernels
     cannot be automatically partitioned"), so the model wraps the call in
     shard_map (`make_sharded_attention`, as `models.transformer.forward`
     does when it is given a mesh).  The kernel must survive into the
-    compiled program and no collective may be added for it."""
+    compiled program and no collective may be added for it.  With grouped
+    heads K/V reach the kernels at their own head count: nothing in the
+    program has a K or V of the query heads' count (the repeat is gone)."""
     from ray_tpu.ops.ring_attention import make_sharded_attention
 
     # The eligibility check asks jax.default_backend(), which is the CPU
     # here; steer it in the test, not through an option of the program.
     monkeypatch.setattr(attention, "_pallas_eligible", lambda q, k: True)
     mesh = _fsdp_mesh(topo)
-    shape = FLASH_SHAPES[0]
-    x = jax.ShapeDtypeStruct(
-        shape, jnp.bfloat16,
-        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, None, None)))
+    _, t, h, h_kv, d = shape
+    q, kv = _flash_args(
+        shape, NamedSharding(mesh, P(("dp", "fsdp"), None, None, None)),
+        batch=8)
     attn = make_sharded_attention(
         lambda q, k, v: attention.flash_attention(q, k, v, True, None), mesh)
 
@@ -119,11 +133,16 @@ def test_flash_attention_under_fsdp_mesh_compiles(topo, monkeypatch):
         return jnp.sum(attn(q, k, v).astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile().as_text()
+        q, kv, kv).compile().as_text()
     assert text.count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
     for collective in ("all-gather", "all-reduce", "all-to-all",
                        "collective-permute"):
         assert collective not in text, collective
+    if h != h_kv:
+        # A device's K/V, folded for the kernels, is (2 * h_kv, t, d); a
+        # repeat ahead of them would make it (2 * h, t, d).
+        assert f"bf16[{2 * h_kv},{t},{d}]" in text
+        assert f"bf16[{2 * h},{t},{d}]" not in text
 
 
 def test_sharded_train_step_compiles_for_four_chips(topo, monkeypatch):
