@@ -2,10 +2,13 @@
 Llama-2-7B-class, plus test/bench sizes)."""
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops.rotary import YarnScaling
 
 TINY = TransformerConfig(
     name="tiny", vocab_size=512, d_model=64, n_layers=2, n_heads=4,
@@ -66,10 +69,38 @@ TINY_HYBRID = HybridConfig(
 # Phi-4-mini-flash-reasoning's published sizes (3.85 B parameters).
 PHI4_MINI_FLASH = HybridConfig(name="phi4-mini-flash")
 
+# A layer pattern in the homogeneous stack (three window layers to one
+# full layer with its own YaRN rope), a head size the width does not
+# give, QK-norm and many small experts, at test size: the window (12) is
+# shorter than the tests' prompts and so is one turn of its ring.
+TINY_WINDOW_MOE = TransformerConfig(
+    name="tiny-window-moe", vocab_size=512, d_model=48, n_layers=8,
+    n_heads=4, n_kv_heads=2, d_head=16, d_ff=160, d_expert=24,
+    n_experts=8, expert_top_k=4, qk_norm=True,
+    layer_pattern=("window", "window", "window", "full"), window=12,
+    rope_theta=10000.0,
+    yarn=YarnScaling(factor=4.0, original_max_len=32, beta_fast=4.0,
+                     beta_slow=1.0, attention_factor=1.138629436111989),
+    norm_eps=1e-6, max_seq_len=512, remat=False,
+)
+
+# Mellum2-12B-A2.5B-Instruct's published sizes (12.15 B parameters, 2.43 B
+# active a token): 64 experts of width 896, top-8; `d_ff` (the config's
+# intermediate_size) is used by no layer.
+MELLUM2_12B = dataclasses.replace(
+    TINY_WINDOW_MOE, name="mellum2-12b", vocab_size=98304, d_model=2304,
+    n_layers=28, n_heads=32, n_kv_heads=4, d_head=128, d_ff=7168,
+    d_expert=896, n_experts=64, expert_top_k=8, window=1024,
+    rope_theta=500000.0,
+    yarn=YarnScaling(factor=16.0, original_max_len=8192, beta_fast=32.0,
+                     beta_slow=1.0, attention_factor=1.2772588722239782),
+    max_seq_len=131072, param_dtype=jnp.bfloat16)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
-                                TINY_HYBRID, PHI4_MINI_FLASH]}
+                                TINY_HYBRID, PHI4_MINI_FLASH,
+                                TINY_WINDOW_MOE, MELLUM2_12B]}
 
 
 def get(name: str):

@@ -17,6 +17,12 @@ lane's length, in the cache dtype (`ops.attention.paged_attention`).  With
 the cache donated, XLA does all of it in the one buffer: what a step moves
 is the weights and the live KV, whatever the pool's and the table's size.
 
+A model with sliding-window layers (`TransformerConfig.layer_pattern`)
+keeps, for those layers, a ring of window + prefill_chunk rows by the
+engine's slot instead of pool blocks (`ops.attention`, above
+`ring_rows`): the pool then holds the full layers alone, and a served
+call also takes the lanes' `slots`.
+
 Convention: pool block 0 is the NULL block.  The allocator never hands it
 out; unallocated table entries and inactive slots point at it, so every
 gather/scatter is in-bounds without conditionals.  Writes routed to block
@@ -26,20 +32,21 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import TransformerConfig
-from ray_tpu.ops.attention import paged_attention
+from ray_tpu.models.transformer import TransformerConfig, qk_normed
+from ray_tpu.ops.attention import (
+    paged_attention, ring_rows, slot_ring_reader, window_attention)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
 _NEG_INF = -1e30
 
 
-def _qkv(bp, x, cfg, positions):
+def _qkv(bp, x, cfg, positions, kind="full"):
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
     b, t = x.shape[:2]
@@ -49,8 +56,9 @@ def _qkv(bp, x, cfg, positions):
         b, t, cfg.n_kv_heads, cfg.head_dim)
     v = jnp.einsum("btd,dh->bth", h, bp["wv"].astype(cd)).reshape(
         b, t, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, theta=cfg.rope_theta)
-    k = apply_rope(k, positions, theta=cfg.rope_theta)
+    q, k = qk_normed(bp, q, k, cfg)
+    q = apply_rope(q, positions, **cfg.rope(kind))
+    k = apply_rope(k, positions, **cfg.rope(kind))
     return q, k, v
 
 
@@ -67,11 +75,12 @@ def _layer_xs(blocks, cfg):
             {k: blocks[k] for k in _EXPERT_WEIGHTS})
 
 
-def _mlp(bp, x, cfg, experts=None, li=None, live=None):
-    """The block's FFN over x (S, K, d).  Returns (out, experts visited):
+def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
+    """The block's FFN over x (S, K, d).  Returns (out, experts visited,
+    the experts each row took (S, K, top_k) if `routing`, else None):
     with `experts` (the stacks of `_layer_xs`; `li` the layer), only
     those that a row of a `live` lane (S,) bool is routed to are read
-    (None: every lane is live); 0 for a dense FFN."""
+    (None: every lane is live); 0 visited for a dense FFN."""
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["mlp_norm"], eps=cfg.norm_eps)
     if cfg.n_experts > 0:
@@ -80,12 +89,17 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None):
         # see moe_mlp_dropless.
         from ray_tpu.ops.moe import moe_mlp_dropless
 
-        return moe_mlp_dropless(h, {"router": bp["router"], **experts},
-                                cfg.moe, live=live, layer=li)
+        with jax.named_scope("moe"):
+            out = moe_mlp_dropless(h, {"router": bp["router"], **experts},
+                                   cfg.moe, live=live, layer=li,
+                                   return_routing=routing)
+        return out if routing else (*out, None)
+    if routing:
+        raise ValueError(f"{cfg.name!r} has no experts: no routing to give")
     gate = jnp.einsum("btd,df->btf", h, bp["w_gate"].astype(cd))
     up = jnp.einsum("btd,df->btf", h, bp["w_up"].astype(cd))
     return jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * up,
-                      bp["w_down"].astype(cd)), jnp.int32(0)
+                      bp["w_down"].astype(cd)), jnp.int32(0), None
 
 
 def _final_logits(params, x, cfg):
@@ -154,17 +168,24 @@ def ngram_propose(context, k_minus_1: int, ngram: int = 2):
 
 @dataclasses.dataclass
 class PagedKVCache:
-    k: jax.Array          # (L, N_blocks, block_size, Hkv, D)
+    k: jax.Array          # (L_full, N_blocks, block_size, Hkv, D)
     v: jax.Array
+    # The window layers' rings, by slot (the null slot last); None for a
+    # model whose every layer is full, whose state is the pool alone.
+    wk: Optional[jax.Array] = None    # (L_window, S + 1, R, Hkv, D)
+    wv: Optional[jax.Array] = None
 
     def resident_bytes(self) -> dict:
         """Bytes a replica keeps for its sequences, by kind of state."""
-        return {"kv_paged": int(sum(a.size * a.dtype.itemsize
-                                    for a in (self.k, self.v))),
-                "kv_window": 0, "recurrent": 0}
+        def nbytes(*arrays):
+            return int(sum(a.size * a.dtype.itemsize for a in arrays
+                           if a is not None))
+
+        return {"kv_paged": nbytes(self.k, self.v),
+                "kv_window": nbytes(self.wk, self.wv), "recurrent": 0}
 
 
-jax.tree_util.register_dataclass(PagedKVCache, ["k", "v"], [])
+jax.tree_util.register_dataclass(PagedKVCache, ["k", "v", "wk", "wv"], [])
 
 
 def paged_cache_shardings(mesh) -> PagedKVCache:
@@ -181,48 +202,68 @@ def paged_cache_shardings(mesh) -> PagedKVCache:
 
 def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                      block_size: int, dtype=None,
-                     shardings: Optional[PagedKVCache] = None
+                     shardings: Optional[PagedKVCache] = None, *,
+                     num_slots: int = 0, prefill_chunk: int = 0
                      ) -> PagedKVCache:
-    """Zero pool; with `shardings` (`paged_cache_shardings`) it is
-    allocated directly sharded: a pool that fits only across chips never
-    exists whole on chip 0."""
+    """Zero pool of the full layers; with `shardings`
+    (`paged_cache_shardings`) it is allocated directly sharded: a pool
+    that fits only across chips never exists whole on chip 0.  A model
+    with window layers also gets their rings, `num_slots` + 1 of
+    window + `prefill_chunk` rows each (a row is a position's (Hkv, D),
+    as in the pool: D is a whole lane tile or the layout is the
+    compiler's and copied every step, `ops.attention` says)."""
     dtype = dtype or cfg.compute_dtype
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
-             cfg.head_dim)
+    row = (cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_of("full"), num_blocks, block_size, *row)
     k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
+    rings = {}
+    if cfg.state_by_slot:
+        if not (num_slots and prefill_chunk):
+            raise ValueError(f"{cfg.name!r} keeps a ring a slot for its "
+                             f"window layers: num_slots and prefill_chunk "
+                             f"size them")
+        ring = (cfg.n_of("window"), num_slots + 1,
+                cfg.window + prefill_chunk, *row)
+        rings = {"wk": jnp.zeros(ring, dtype), "wv": jnp.zeros(ring, dtype)}
     return PagedKVCache(k=jnp.zeros(shape, dtype, device=k_sh),
-                        v=jnp.zeros(shape, dtype, device=v_sh))
+                        v=jnp.zeros(shape, dtype, device=v_sh), **rings)
 
 
 def init_sequence_state(cfg, num_blocks: int, block_size: int, *,
                         num_slots: int, prefill_chunk: int,
                         shardings: Optional[PagedKVCache] = None):
     """What the sequences of one engine keep on the device, asked of the
-    model: the paged pool alone for a `TransformerConfig` (sharded as
-    `shardings` says); whatever `cfg.init_state` says for a model that
-    brings its own (paged KV of the layers that keep every position,
-    bounded window KV and recurrent state by slot: `models.hybrid`; the
-    engine gives such a model no mesh).  Either is the `cache` argument
-    of the served programs below."""
+    model.  For a `TransformerConfig`: the paged pool of its full layers
+    (sharded as `shardings` says), and a ring a slot for each window
+    layer where it has any (state by slot that is not recurrent: never
+    zeroed, owned by whoever holds the slot).  Whatever `cfg.init_state`
+    says for a model that brings its own (paged KV, rings and recurrent
+    state by slot: `models.hybrid`).  The engine gives a model with
+    state by slot no mesh.  Either is the `cache` argument of the served
+    programs below."""
     own = getattr(cfg, "init_state", None)
     if own is None:
         return init_paged_cache(cfg, num_blocks, block_size,
-                                shardings=shardings)
+                                shardings=shardings, num_slots=num_slots,
+                                prefill_chunk=prefill_chunk)
     return own(num_blocks, block_size, num_slots, prefill_chunk)
 
 
 def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
-                    cfg, slots):
+                    cfg, slots, routing=False):
     """The served step of `cfg`'s model: `_paged_forward`, or the model's
-    own over its own sequence state, which also takes the lanes' engine
-    `slots` (S,) (None for a model whose state is the pool alone).
-    Returns (cache, hidden, experts visited: 0 for a model's own step)."""
+    own over its own sequence state.  Both take the lanes' engine `slots`
+    (S,) where the sequence keeps state by slot (None for a model whose
+    state is the pool alone).  Returns (cache, hidden, experts visited:
+    0 for a model's own step, the routing if asked: `_paged_forward`)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
         return _paged_forward(params, cache, tokens, block_tables, positions,
-                              kv_len, cfg)
+                              kv_len, cfg, slots, routing)
+    if routing:
+        raise ValueError(f"{cfg.name!r} has no experts: no routing to give")
     return (*own(params, cache, tokens, block_tables, positions, kv_len,
-                 slots), jnp.int32(0))
+                 slots), jnp.int32(0), None)
 
 
 def _served_logits(params, x, cfg):
@@ -230,23 +271,37 @@ def _served_logits(params, x, cfg):
     return _final_logits(params, x, cfg) if own is None else own(params, x)
 
 
+def _nth(i, per: int, rank: int):
+    """Index of the `rank`-th of `per` layers a period in period `i`."""
+    return i if per == 1 else i * per + rank
+
+
 def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
                    block_tables: jax.Array, positions: jax.Array,
-                   kv_len: jax.Array, cfg: TransformerConfig):
+                   kv_len: jax.Array, cfg: TransformerConfig, slots=None,
+                   routing: bool = False):
     """The one served step: `tokens` (S, K) at absolute `positions` (S, K)
     through every layer, over the tables (S, B_max) of the lanes' blocks.
     `kv_len` (S,) is each lane's length once its tokens are in (0: an idle
     lane, which writes the null block and is routed to no expert).
+    `slots` (S,): the lanes' engine slots, whose rings a model with window
+    layers reads and writes (the null slot for an idle lane).
     Returns (cache, hidden (S, K, d), experts visited summed over the
-    layers: `ops.moe.moe_mlp_dropless`; 0 without experts).
+    layers: `ops.moe.moe_mlp_dropless`; 0 without experts, and with
+    `routing` the experts every row took, (L, S, K, top_k), else None).
 
-    Write-then-read, in place: a layer scatters the tokens' KV into the
-    pool at [layer, table[pos // bs], pos % bs] first, so the attention
+    Write-then-read, in place: a full layer scatters the tokens' KV into
+    the pool at [layer, table[pos // bs], pos % bs] first, so the attention
     that follows finds them there and its mask is simply kv_pos <= pos,
-    for the context and the in-call causal prefix alike.  The pool is the
-    layer loop's carry, never its xs/ys: no slice of it is taken out or
-    stacked back.
+    for the context and the in-call causal prefix alike; a window layer
+    writes its slot's ring at [layer, slot, pos % R] and reads the ring.
+    Pool and rings are the layer loop's carry, never its xs/ys: no slice
+    of them is taken out or stacked back.  The loop runs over periods of
+    the layer pattern (`cfg.period`), its body the period's layers.
     """
+    if cfg.state_by_slot and slots is None:
+        raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
+                         f"needs the lanes' slots")
     cd = cfg.compute_dtype
     bs = cache.k.shape[2]
     live_lane = kv_len > 0
@@ -255,33 +310,67 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         block_tables, positions // bs, axis=1), 0)         # (S, K)
     off = jnp.where(live, positions % bs, 0)
     x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
+    period = cfg.period
+    per = {kind: period.count(kind) for kind in set(period)}
+    if cfg.state_by_slot:
+        ring_row = ring_rows(positions, kv_len, cache.wk.shape[2])
+        lane = slots[:, None]
+        read_ring = slot_ring_reader(window_attention, slots, positions,
+                                     kv_len, cfg.window, cache.wk.shape[1])
 
     def layer(carry, layer_in):
-        x, k_pool, v_pool, visited = carry
-        bp, li = layer_in
-        q, k, v = _qkv(bp, x, cfg, positions)              # (S,K,H,D)
-        k_pool = k_pool.at[li, wb, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[li, wb, off].set(v.astype(v_pool.dtype))
-        attn = paged_attention(q, k_pool, v_pool, li, block_tables,
-                               positions, kv_len)
-        attn = attn.reshape(*tokens.shape, cfg.n_heads * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
-        out, n = _mlp(bp, x, cfg, experts, li, live_lane)
-        return (x + out, k_pool, v_pool, visited + n), None
+        x, k_pool, v_pool, wk, wv, visited = carry
+        bps, i = layer_in
+        taken = []
+        for j, kind in enumerate(period):
+            li = _nth(i, len(period), j)
+            # A period's layers index the stacks themselves: the scan's
+            # slice of a period, (p, ..), is copied out before a layer of
+            # it can be taken (AOT for a v5e, PR 34).
+            bp = bps if len(period) == 1 else jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, False),
+                blocks)
+            at = _nth(i, per[kind], period[:j].count(kind))
+            q, k, v = _qkv(bp, x, cfg, positions, kind)    # (S,K,H,D)
+            if kind == "full":
+                with jax.named_scope("full_attn"):
+                    k_pool = k_pool.at[at, wb, off].set(
+                        k.astype(k_pool.dtype))
+                    v_pool = v_pool.at[at, wb, off].set(
+                        v.astype(v_pool.dtype))
+                    attn = paged_attention(q, k_pool, v_pool, at,
+                                           block_tables, positions, kv_len)
+            else:
+                with jax.named_scope("swa"):
+                    wk = wk.at[at, lane, ring_row].set(
+                        k.astype(wk.dtype), mode="drop")
+                    wv = wv.at[at, lane, ring_row].set(
+                        v.astype(wv.dtype), mode="drop")
+                    attn = read_ring(q, wk, wv, at)
+            attn = attn.reshape(*tokens.shape, cfg.n_heads * cfg.head_dim)
+            x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
+                               bp["wo"].astype(cd))
+            out, n, idx = _mlp(bp, x, cfg, experts, li, live_lane, routing)
+            x, visited = x + out, visited + n
+            taken.append(idx)
+        return (x, k_pool, v_pool, wk, wv, visited), \
+            (jnp.stack(taken) if routing else None)
 
     blocks, experts = _layer_xs(params["blocks"], cfg)
-    (x, k_pool, v_pool, visited), _ = jax.lax.scan(
-        layer, (x, cache.k, cache.v, jnp.int32(0)),
-        (blocks, jnp.arange(cfg.n_layers)))
-    return PagedKVCache(k=k_pool, v=v_pool), x, visited
+    (x, k_pool, v_pool, wk, wv, visited), taken = jax.lax.scan(
+        layer, (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0)),
+        (blocks if len(period) == 1 else None,
+         jnp.arange(cfg.n_layers // len(period))))
+    if routing:                      # (periods, p, S, K, k) -> (L, S, K, k)
+        taken = taken.reshape(cfg.n_layers, *taken.shape[2:])
+    return PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv), x, visited, taken
 
 
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
                       block_tables: jax.Array, lengths: jax.Array,
                       active: jax.Array, cfg: TransformerConfig,
-                      slots: Optional[jax.Array] = None
-                      ) -> Tuple[PagedKVCache, jax.Array]:
+                      slots: Optional[jax.Array] = None,
+                      routing: bool = False):
     """One token for every slot through the block pool: tokens (S,),
     block_tables (S, B_max) int32, lengths (S,) int32, active (S,) bool.
     Returns (cache, logits (S, vocab)).
@@ -292,19 +381,22 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     Inactive slots write the null block and return garbage that the
     engine drops.  `slots` (S,): the lanes' engine slots, for a model
     whose sequences keep state by slot (`init_sequence_state`).
+    `routing` (a scoring entry's): also the experts each lane took in
+    every layer, (L, S, top_k).
     """
-    cache, logits, _ = _paged_decode_logits(
-        params, cache, tokens, block_tables, lengths, active, cfg, slots)
-    return cache, logits
+    cache, logits, _, taken = _paged_decode_logits(
+        params, cache, tokens, block_tables, lengths, active, cfg, slots,
+        routing)
+    return (cache, logits, taken[:, :, 0]) if routing else (cache, logits)
 
 
 def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
-                         active, cfg, slots):
+                         active, cfg, slots, routing=False):
     """`paged_decode_step` with the step's count of experts visited."""
-    cache, x, visited = _served_forward(
+    cache, x, visited, taken = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
-        jnp.where(active, lengths + 1, 0), cfg, slots)
-    return cache, _served_logits(params, x, cfg)[:, 0], visited  # (S, V)
+        jnp.where(active, lengths + 1, 0), cfg, slots, routing)
+    return cache, _served_logits(params, x, cfg)[:, 0], visited, taken
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
@@ -319,7 +411,7 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
 
     def tick(carry, _):
         cache, toks, lengths, rng, visited = carry
-        cache, logits, n = _paged_decode_logits(
+        cache, logits, n, _ = _paged_decode_logits(
             params, cache, toks, block_tables, lengths, active, cfg, slots)
         rng, sub = jax.random.split(rng)
         nxt = sample_per_slot(logits, sub, temps)
@@ -335,8 +427,8 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
 def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
                         block_tables: jax.Array, start: jax.Array,
                         n_valid: jax.Array, cfg: TransformerConfig,
-                        slot: Optional[jax.Array] = None
-                        ) -> Tuple[PagedKVCache, jax.Array]:
+                        slot: Optional[jax.Array] = None,
+                        routing: bool = False):
     """One chunk of a prompt through the block pool: tokens (C,) (padded
     with zeros past `n_valid`), block_tables (B_max,), start = absolute
     position of tokens[0].  The one-lane, C-wide case of the served step:
@@ -347,15 +439,18 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     query's mask reaches (true of KV; a model with state by `slot`, a
     scalar, leaves it untouched by them).  Returns (cache, logits of
     token n_valid-1 (vocab,)) — the engine samples from the FINAL
-    chunk's logits.
+    chunk's logits — and with `routing` (a scoring entry's) the experts
+    each position took in every layer, (L, C, top_k).
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x, _ = _served_forward(
+    cache, x, _, taken = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
         (start + n_valid)[None], cfg,
-        None if slot is None else jnp.asarray(slot, jnp.int32)[None])
+        None if slot is None else jnp.asarray(slot, jnp.int32)[None],
+        routing)
     if getattr(cfg, "final_logits", None) is None:
-        return cache, _final_logits(params, x, cfg)[0, n_valid - 1]
+        last = _final_logits(params, x, cfg)[0, n_valid - 1]
+        return (cache, last, taken[:, 0]) if routing else (cache, last)
     # A model with a head of its own: over the one position asked for.
     last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1,
                                         axis=1)
@@ -391,7 +486,7 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     """
     k_w = cand_tokens.shape[1]
     positions = lengths[:, None] + jnp.arange(k_w, dtype=jnp.int32)  # (S,K)
-    cache, x, _ = _paged_forward(
+    cache, x, _, _ = _paged_forward(
         params, cache, cand_tokens, block_tables, positions,
         jnp.where(active, lengths + k_w, 0), cfg)
     logits = _final_logits(params, x, cfg)               # (S, K, vocab)
@@ -422,8 +517,9 @@ def copy_block(cache: PagedKVCache, dst: jax.Array, src: jax.Array
     """Copy one pool block across all layers (the device half of
     copy-on-write: a shared partial block is duplicated before its new
     owner appends into it)."""
-    return PagedKVCache(k=cache.k.at[:, dst].set(cache.k[:, src]),
-                        v=cache.v.at[:, dst].set(cache.v[:, src]))
+    return dataclasses.replace(cache,
+                               k=cache.k.at[:, dst].set(cache.k[:, src]),
+                               v=cache.v.at[:, dst].set(cache.v[:, src]))
 
 
 def gather_blocks(cache: PagedKVCache, block_ids) -> "jnp.ndarray":
@@ -451,8 +547,8 @@ def scatter_blocks(cache: PagedKVCache, block_ids, frame) -> PagedKVCache:
 
     ids = jnp.asarray(np.asarray(block_ids, np.int32))
     frame = jnp.asarray(frame, cache.k.dtype)
-    return PagedKVCache(k=cache.k.at[:, ids].set(frame[0]),
-                        v=cache.v.at[:, ids].set(frame[1]))
+    return dataclasses.replace(cache, k=cache.k.at[:, ids].set(frame[0]),
+                               v=cache.v.at[:, ids].set(frame[1]))
 
 
 def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):

@@ -53,7 +53,8 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import paged_diff_attention, window_diff_attention
+from ray_tpu.ops.attention import (
+    paged_diff_attention, ring_rows, slot_ring_reader, window_diff_attention)
 from ray_tpu.ops.norms import layer_norm, rms_norm
 
 F32 = jnp.float32
@@ -79,8 +80,11 @@ class HybridConfig:
     state_dtype: Any = jnp.float32
     name: str = "hybrid"
 
-    # A sequence of this model keeps recurrent state: the engine turns
-    # prefix sharing off and refuses speculation and KV shipping.
+    # A sequence of this model keeps state by the engine's slot (rings and
+    # recurrent state: the engine turns prefix sharing off and refuses KV
+    # shipping and a mesh), and some of it is recurrent (zeroed when a
+    # slot changes hands; speculation refused).
+    state_by_slot: ClassVar[bool] = True
     recurrent: ClassVar[bool] = True
 
     def __post_init__(self):
@@ -415,36 +419,14 @@ def _served_step(params, state: HybridState, tokens, block_tables, positions,
     wb = jnp.where(live, jnp.take_along_axis(
         block_tables, positions // bs, axis=1), 0)
     off = jnp.where(live, positions % bs, 0)
-    row = jnp.where(valid, positions % ring, ring)         # ring: dropped
+    row = ring_rows(positions, kv_len, ring)
     lane = slots[:, None]
     x = params["embed"].astype(cd)[tokens]
     n_half = cfg.n_layers // 2
-
-    # A window layer reads its rings where they lie.  One lane (a chunk):
-    # its slot's ring, a slice.  Several (a burst): every slot's, in slot
-    # order, with the lanes' queries put at their slots and the answers
-    # taken back; a gather of the lanes' rings would be a copy of them
-    # (and the compiler makes it one of the whole ring).  Slots that are
-    # no lane of this call have length 0 and see nothing.
-    if tokens.shape[0] == 1:
-        def ring_attention(q6, wk, wv, li):
-            at = (li, slots[0], 0, 0)
-            size = (1, 1) + wk.shape[2:]
-            return window_diff_attention(
-                q6, jax.lax.dynamic_slice(wk, at, size)[0],
-                jax.lax.dynamic_slice(wv, at, size)[0], positions, kv_len,
-                cfg.window)
-    else:
-        n_all = state.wk.shape[1]
-        pos_all = jnp.zeros((n_all,) + positions.shape[1:],
-                            positions.dtype).at[slots].set(positions)
-        len_all = jnp.zeros((n_all,), kv_len.dtype).at[slots].set(kv_len)
-
-        def ring_attention(q6, wk, wv, li):
-            q_all = jnp.zeros((n_all,) + q6.shape[1:],
-                              q6.dtype).at[slots].set(q6)
-            return window_diff_attention(q_all, wk[li], wv[li], pos_all,
-                                         len_all, cfg.window)[slots]
+    # A window layer reads its rings where they lie (ops.attention).
+    ring_attention = slot_ring_reader(
+        window_diff_attention, slots, positions, kv_len, cfg.window,
+        state.wk.shape[1])
 
     def mamba_layer(x, bp, li, conv, h):
         with jax.named_scope("mamba"):

@@ -17,12 +17,19 @@ reference: python/ray/train/torch/train_loop_utils.py:158):
   backward, trading MXU FLOPs for HBM.
 - GQA via kv-head broadcast; RoPE with explicit positions (sequence shards
   feed global offsets).
+- **A layer pattern is a period**: layers of two kinds of attention
+  (`full`: causal; `window`: causal over the last `window` positions,
+  unscaled rope) repeat with period `layer_pattern`, and the stack is one
+  scan over periods whose body is the period's layers, so compile time
+  stays O(1) in depth.  Period 1 (every model without a pattern) is the
+  scan over layers it always was.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+import warnings
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +41,7 @@ from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import (
     make_ring_attention, make_sharded_attention)
 from ray_tpu.ops.ulysses import make_ulysses_attention
-from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.ops.rotary import YarnScaling, apply_rope
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES, LogicalRules, with_logical_constraint)
 from ray_tpu.parallel.mesh import AXIS_SEQ
@@ -68,10 +75,67 @@ class TransformerConfig:
     expert_top_k: int = 2
     capacity_factor: float = 1.25
     name: str = "transformer"
+    # The size of a head (`head_dim`) where the width does not give it;
+    # 0: d_model // n_heads.
+    d_head: int = 0
+    # Width of one expert's FFN; 0: d_ff, as Mixtral's.
+    d_expert: int = 0
+    # RMSNorm over each head of q and of k, learned gain, before the rope.
+    qk_norm: bool = False
+    # The kinds of attention of one period of layers, "full" or "window",
+    # repeated n_layers // len(layer_pattern) times; (): every layer full.
+    layer_pattern: Tuple[str, ...] = ()
+    # A window layer's query at position t sees t - window < p <= t.
+    window: int = 0
+    # The full layers' rope scaling (a window layer's rope is unscaled).
+    yarn: Optional[YarnScaling] = None
+
+    def __post_init__(self):
+        pattern = tuple(self.layer_pattern)
+        object.__setattr__(self, "layer_pattern", pattern)
+        if set(pattern) - {"full", "window"}:
+            raise ValueError(f"layer_pattern {pattern}: a layer is 'full' "
+                             f"or 'window'")
+        if pattern and self.n_layers % len(pattern):
+            raise ValueError(f"n_layers {self.n_layers} is not whole "
+                             f"periods of {pattern}")
+        if ("window" in pattern) != (self.window > 0):
+            raise ValueError("window layers and a window come together: "
+                             f"layer_pattern {pattern}, window {self.window}")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        return self.layer_pattern or ("full",)
+
+    def n_of(self, kind: str) -> int:
+        """Layers of `kind` in the model."""
+        return self.period.count(kind) * (self.n_layers // len(self.period))
+
+    @property
+    def state_by_slot(self) -> bool:
+        """Whether a served sequence keeps more than pool blocks: a ring a
+        slot for each window layer (`models.decoding`)."""
+        return "window" in self.period
+
+    def kv_read_tokens(self, lengths) -> int:
+        """KV positions one decode step sees over lanes of `lengths`:
+        every position in a full layer, at most the window in a window
+        layer."""
+        return int(self.n_of("full") * sum(lengths) + self.n_of("window")
+                   * sum(min(int(n), self.window) for n in lengths))
+
+    def rope(self, kind: str) -> dict:
+        """`apply_rope`'s keywords for a layer of `kind`."""
+        return {"theta": self.rope_theta,
+                "yarn": self.yarn if kind == "full" else None}
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
 
     @property
     def moe(self):
@@ -85,10 +149,11 @@ class TransformerConfig:
     @property
     def num_params(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        kv = self.n_kv_heads * self.head_dim
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         mlp = 3 * d * f if self.n_experts <= 0 else \
-            self.n_experts * 3 * d * f + d * self.n_experts
-        per_layer = d * d * 2 + d * kv * 2 + mlp + 2 * d
+            self.n_experts * 3 * d * self.expert_width + d * self.n_experts
+        per_layer = d * q * 2 + d * kv * 2 + mlp + 2 * d \
+            + (2 * self.head_dim if self.qk_norm else 0)
         emb = v * d * (1 if self.tie_embeddings else 2)
         return self.n_layers * per_layer + emb + d
 
@@ -112,8 +177,14 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
         "wo": dense(keys[4], (l, nh * hd, d), nh * hd),
         "mlp_norm": jnp.ones((l, d), dt),
     }
+    if cfg.qk_norm:
+        # Drawn away from 1, so that a comparison notices a gain left out.
+        for i, name in enumerate(("q_norm", "k_norm")):
+            blocks[name] = (1.0 + 0.1 * jax.random.normal(
+                jax.random.fold_in(keys[1], 1 + i), (l, hd),
+                jnp.float32)).astype(dt)
     if cfg.n_experts > 0:
-        e = cfg.n_experts
+        e, f = cfg.n_experts, cfg.expert_width
         blocks.update({
             "router": dense(jax.random.fold_in(keys[5], 1), (l, d, e), d),
             "w_gate": dense(keys[5], (l, e, d, f), d),
@@ -146,6 +217,9 @@ def param_logical_axes(cfg: TransformerConfig):
         "wo": ("layers", "heads", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if cfg.qk_norm:
+        blocks.update({"q_norm": ("layers", "head_dim"),
+                       "k_norm": ("layers", "head_dim")})
     if cfg.n_experts > 0:
         blocks.update({
             "router": ("layers", "embed", "expert"),
@@ -182,8 +256,16 @@ def _repeated_kv(attn_impl):
     return impl
 
 
+def qk_normed(bp, q, k, cfg: TransformerConfig):
+    """q, k (B, T, heads, D) under the block's QK-norm, where it has one."""
+    if not cfg.qk_norm:
+        return q, k
+    return (rms_norm(q, bp["q_norm"], eps=cfg.norm_eps),
+            rms_norm(k, bp["k_norm"], eps=cfg.norm_eps))
+
+
 def _block(x, bp, cfg: TransformerConfig, rules: LogicalRules, *,
-           attn_impl, positions):
+           attn_impl, positions, kind: str = "full"):
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
     q = jnp.einsum("btd,dh->bth", h, bp["wq"].astype(cd))
@@ -193,8 +275,9 @@ def _block(x, bp, cfg: TransformerConfig, rules: LogicalRules, *,
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, theta=cfg.rope_theta)
-    k = apply_rope(k, positions, theta=cfg.rope_theta)
+    q, k = qk_normed(bp, q, k, cfg)
+    q = apply_rope(q, positions, **cfg.rope(kind))
+    k = apply_rope(k, positions, **cfg.rope(kind))
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), rules)
     attn = attn_impl(q, k, v)     # k/v at n_kv_heads: (B,T,nkv,hd)
     attn = attn.reshape(b, t, cfg.n_heads * cfg.head_dim)
@@ -238,6 +321,10 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         positions = jnp.arange(t, dtype=jnp.int32)
 
     if seq_shards > 1:
+        if cfg.state_by_slot:
+            raise ValueError(
+                f"{cfg.name!r} has sliding-window layers: neither ring nor "
+                f"Ulysses attention masks a window (seq_shards must be 1)")
         if mesh is None:
             raise ValueError("sequence parallelism requires a mesh")
         if cfg.sp_attention not in ("ring", "ulysses"):
@@ -267,8 +354,60 @@ def forward(params, tokens, cfg: TransformerConfig, *,
     x = params["embed"].astype(cd)[tokens]
     x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
 
-    block_fn = functools.partial(_block, cfg=cfg, rules=rules,
-                                 attn_impl=attn_impl, positions=positions)
+    period = cfg.period
+    block_fns = {kind: _remat(functools.partial(
+        _block, cfg=cfg, rules=rules, positions=positions, kind=kind,
+        attn_impl=attn_impl if kind == "full" else _window_attention(cfg)),
+        cfg) for kind in set(period)}
+
+    def scan_body(x, bp):
+        aux = None
+        for j, kind in enumerate(period):
+            x, aux_j = block_fns[kind](x, _layer_of(bp, j, len(period)))
+            aux = aux_j if aux is None else jax.tree.map(jnp.add, aux, aux_j)
+        return x, aux
+
+    x, aux_stacked = jax.lax.scan(
+        scan_body, x, _by_period(params["blocks"], len(period)))
+    if return_aux is not None:
+        return_aux.update({k: jnp.sum(v)
+                           for k, v in (aux_stacked or {}).items()})
+    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
+    else:
+        logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(cd))
+    return with_logical_constraint(logits, ("batch", "seq", "vocab"), rules)
+
+
+def _by_period(blocks, p: int):
+    """The layers' stacks (L, ..) as the xs of a scan over periods of `p`
+    layers, (L // p, p, ..): a reshape, no copy.  Period 1: as they are."""
+    if p == 1:
+        return blocks
+    return jax.tree.map(
+        lambda a: a.reshape(a.shape[0] // p, p, *a.shape[1:]), blocks)
+
+
+def _layer_of(bp, j: int, p: int):
+    """Layer `j` of one period's slice of `_by_period`."""
+    return bp if p == 1 else jax.tree.map(lambda a: a[j], bp)
+
+
+def _window_attention(cfg: TransformerConfig):
+    """A window layer's attention on the train and offline path: the
+    masked XLA reference, and it says so; never full attention in
+    silence.  The Pallas kernel has no window mask yet (ROADMAP R4)."""
+    def impl(q, k, v):
+        warnings.warn(
+            f"window attention (window {cfg.window}) takes the XLA "
+            f"reference (O(T^2) memory) for q{q.shape}: the Pallas kernel "
+            f"has no window mask", stacklevel=2)
+        return mha_reference(q, k, v, causal=True, window=cfg.window)
+    return impl
+
+
+def _remat(block_fn, cfg: TransformerConfig):
     if cfg.remat:
         if cfg.remat_policy == "dots":
             block_fn = jax.checkpoint(
@@ -288,21 +427,7 @@ def forward(params, tokens, cfg: TransformerConfig, *,
                     "ff_hidden"))
         else:
             block_fn = jax.checkpoint(block_fn)
-
-    def scan_body(x, bp):
-        x, aux = block_fn(x, bp)
-        return x, aux
-
-    x, aux_stacked = jax.lax.scan(scan_body, x, params["blocks"])
-    if return_aux is not None:
-        return_aux.update({k: jnp.sum(v)
-                           for k, v in (aux_stacked or {}).items()})
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
-    else:
-        logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(cd))
-    return with_logical_constraint(logits, ("batch", "seq", "vocab"), rules)
+    return block_fn
 
 
 def loss_fn(params, batch, cfg: TransformerConfig, *,

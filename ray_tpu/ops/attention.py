@@ -39,7 +39,7 @@ _NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
-                  kv_offset: int = 0):
+                  kv_offset: int = 0, window: int | None = None):
     """Plain-XLA multi-head attention, numerically stable softmax.
 
     Shapes: q (B, Tq, H, D), k/v (B, Tkv, Hkv, D) with H a multiple of Hkv:
@@ -47,6 +47,8 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
     `kv_offset` shifts kv global positions for causal masking (used by ring
     attention where the local kv block starts at a nonzero global index; q
     is assumed to start at global index `kv_offset=0` frame of its caller).
+    `window` (with `causal`): a query at position t sees only the kv
+    positions p with t - window < p <= t, itself counted.
     """
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
@@ -59,7 +61,12 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
     if causal:
         q_pos = jnp.arange(tq)[:, None]
         k_pos = jnp.arange(tk)[None, :] + kv_offset
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (k_pos > q_pos - window)
+        s = jnp.where(seen, s, _NEG_INF)
+    elif window is not None:
+        raise ValueError("a window is a causal mask's lower edge")
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
     return out.reshape(b, tq, h, d)
@@ -683,20 +690,91 @@ def paged_diff_attention(q6, k_pool, v_pool, layer, block_tables, positions,
         (g * rep * 2,), g * 2 * d))
 
 
-def window_diff_attention(q6, k_ring, v_ring, positions, kv_len, window):
-    """Differential attention over a ring a lane: `k_ring` / `v_ring`
-    (S, R, E) hold position p at row p % R, rows written for the
-    positions below `kv_len` (S,) and no others.  Row j therefore holds
-    the largest position <= kv_len - 1 that is j modulo R (negative:
-    never written), and a query at position t sees the rows whose
-    position p has t - window < p <= t.  The whole ring is read, in one
-    soft-max: R is a window and a chunk, whatever the lane's length.  An
-    idle lane (kv_len 0) sees nothing and returns garbage nobody reads."""
-    r = k_ring.shape[1]
+# A sliding-window layer keeps a ring a slot instead of pool blocks: rings
+# (L_window, S + 1, R, ...) hold position p of the sequence in engine slot s
+# at [layer, s, p % R], R = window + prefill_chunk (a chunk is written
+# before it is read, and its first query still sees a whole window); the
+# last slot is the null slot, where idle lanes point.  Rows are written for
+# the positions below a lane's length and no others, so row j holds the
+# largest position <= length - 1 that is j modulo R (negative: never written
+# by this sequence, whatever an earlier owner of the slot left there), and a
+# row is seen by the position it holds: nothing is zeroed when a slot
+# changes hands.  `models.hybrid` (differential heads, flat rows) and
+# `models.decoding` (plain GQA, rows of (Hkv, D)) share the three functions
+# below and differ in the `attend` they hand to `slot_ring_reader`.
+def ring_rows(positions, kv_len, ring: int):
+    """The ring row each of `positions` (S, K) is written to: p % ring,
+    and `ring` itself (out of bounds: a `.at[].set(mode="drop")` drops it)
+    for a position at or past the lane's `kv_len` (S,), which is the
+    zero-padded tail of a chunk or an idle lane."""
+    return jnp.where(positions < kv_len[:, None], positions % ring, ring)
+
+
+def ring_seen(positions, kv_len, ring: int, window: int):
+    """(S, K, ring) bool: which rows of its lane's ring a query at
+    `positions` (S, K) sees, the lane holding `kv_len` (S,) positions:
+    those whose held position p has t - window < p <= t."""
     top = (kv_len - 1)[:, None]
-    held = top - jnp.mod(top - jnp.arange(r)[None, :], r)          # (S, R)
+    held = top - jnp.mod(top - jnp.arange(ring)[None, :], ring)    # (S, R)
     held, pos = held[:, None, :], positions[:, :, None]
-    seen = (held <= pos) & (held > pos - window) & (held >= 0)     # (S,K,R)
+    return (held <= pos) & (held > pos - window) & (held >= 0)
+
+
+def slot_ring_reader(attend, slots, positions, kv_len, window: int,
+                     n_slots: int):
+    """`read(q, k_rings, v_rings, layer)`: `attend(q, k_ring, v_ring,
+    positions, kv_len, window)` of the lanes' queries over their slots'
+    rings of `[layer]`, read where they lie.  One lane (a prefill chunk):
+    its slot's ring, a slice.  Several (a burst): every slot's ring, in
+    slot order, with the lanes' queries put at their `slots` (S,) and the
+    answers taken back; a gather of the lanes' rings would be a copy of
+    them (and the compiler makes it one of the whole array).  Slots that
+    are no lane of the call have length 0 and see nothing.  `n_slots`
+    counts the null slot."""
+    if positions.shape[0] == 1:
+        def read(q, k_rings, v_rings, layer):
+            at = (layer, slots[0]) + (0,) * (k_rings.ndim - 2)
+            size = (1, 1) + k_rings.shape[2:]
+            return attend(q, jax.lax.dynamic_slice(k_rings, at, size)[0],
+                          jax.lax.dynamic_slice(v_rings, at, size)[0],
+                          positions, kv_len, window)
+        return read
+    pos_all = jnp.zeros((n_slots,) + positions.shape[1:],
+                        positions.dtype).at[slots].set(positions)
+    len_all = jnp.zeros((n_slots,), kv_len.dtype).at[slots].set(kv_len)
+
+    def read(q, k_rings, v_rings, layer):
+        q_all = jnp.zeros((n_slots,) + q.shape[1:], q.dtype).at[slots].set(q)
+        return attend(q_all, k_rings[layer], v_rings[layer], pos_all,
+                      len_all, window)[slots]
+    return read
+
+
+def window_attention(q, k_ring, v_ring, positions, kv_len, window):
+    """Grouped-query attention of `q` (S, K, H, D) over a ring a lane:
+    `k_ring` / `v_ring` (S, R, Hkv, D), rows as `ring_seen` reads them.
+    The whole ring is read, in one soft-max: R is a window and a chunk,
+    whatever the lane's length.  As `paged_attention`: the rep = H // Hkv
+    query heads of a KV head on their own axis against the stored head,
+    scores and both products' accumulation float32.  An idle lane
+    (kv_len 0) sees nothing and returns garbage nobody reads.  Returns
+    (S, K, H, D) float32."""
+    s, k_w, h, d = q.shape
+    hkv = k_ring.shape[2]
+    qg = q.reshape(s, k_w, hkv, h // hkv, d)
+    seen = ring_seen(positions, kv_len, k_ring.shape[1], window)
+    sc = jnp.einsum("sqhrd,sthd->sqhrt", qg, k_ring,
+                    preferred_element_type=jnp.float32) * d ** -0.5
+    sc = jnp.where(seen[:, :, None, None, :], sc, _NEG_INF)
+    out = jnp.einsum("sqhrt,sthd->sqhrd", jax.nn.softmax(sc, axis=-1),
+                     v_ring, preferred_element_type=jnp.float32)
+    return out.reshape(s, k_w, h, d)
+
+
+def window_diff_attention(q6, k_ring, v_ring, positions, kv_len, window):
+    """`window_attention`'s sibling for differential heads over rings of
+    flat rows (S, R, E): both maps in one soft-max over the whole ring."""
+    seen = ring_seen(positions, kv_len, k_ring.shape[1], window)
     score, mix, own_columns = _diff_products(q6)
     sc = jnp.where(seen[:, :, None, :], score(k_ring), _NEG_INF)
     return own_columns(mix(jax.nn.softmax(sc, axis=-1), v_ring))

@@ -109,8 +109,38 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # so there is no crossover and no second path; nothing here needed Pallas:
 # each product is one fusion whose operand is the stack with the slice
 # inside (AOT for a v5e; tests/test_tpu_compile.py holds it).
+#
+# The same loop at 64 small experts (Mellum2-12B-A2.5B's widths: three
+# 2304 x 896 bf16 matrices an expert, 12.4 MB, top-8; depth 8, six window
+# layers and two full; PR 34, the bare served programs on a v5e, ms a
+# decode step with the experts read a layer beside it / ms a chunk):
+#
+#   burst at ~2,000 positions   1 live       2 live        all live
+#     width 4                   4.06 (8.0)   5.32 (15.2)   7.34 (26.6)
+#     width 8 / 16 / 32, all live:  9.99 (40.8) / 12.74 (54.3) / 14.74 (60.8)
+#     width 8, 4 live 7.48 (26.6); width 16, 8 live 10.38 (40.8)
+#   at ~200 positions: width 4 3.88 / 5.08 / 6.77 (8.0 / 14.8 / 24.5),
+#     width 8 / 16 / 32 all live 8.84 (36.1) / 10.87 (47.1) / 12.89 (57.5)
+#   prefill chunk, seeded tokens (all 64 read), at position 0 / 1,920 /
+#   5,888: 128 tokens 13.77 / 13.56 / 14.43, 64 tokens 11.40 / 11.25 /
+#   12.31, 32 tokens 10.24 / 9.91 / 10.40
+#
+# Experts read follow 64 (1 - (7/8)^lanes) (8 / 15 / 26.5 / 42 / 56 / 63)
+# to within 2.5.  One more expert a layer costs 22 us (7.34 - 4.06 ms over
+# 18.6 experts x 8 layers; 12.74 - 10.38 over 13.5 x 8) where its 12.4 MB
+# take 15.1 us at the chip's 819 GB/s: 563 GB/s, 69%, against Mixtral's
+# 92%.  The 7 us a trip beyond its bytes are the loop's own: three small
+# fusions, three dynamic slices, a row of the combine weights, at 8 rows
+# of work.  A 128-token chunk makes 512 trips in at most 13.8 ms (27 us a
+# trip, 20 at 32 tokens): every one of the 64 experts multiplies all 128
+# rows though 16 are routed to it, 8 x the products the model needs, and
+# still waits for memory more than for the MXU.  Tokens grouped by expert
+# (a sort and one product a group) would win the 8 x and most of the 7 us;
+# that is a form of its own and a `perf_opt` PR's (ROADMAP R1), not a
+# repair of this one: at Mixtral's widths this loop is at the roofline.
 def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
-                     live: "jnp.ndarray | None" = None, layer=None):
+                     live: "jnp.ndarray | None" = None, layer=None,
+                     return_routing: bool = False):
     """Exact (dropless) top-k MoE for INFERENCE: every live token reaches
     all of its top-k experts, so the result is independent of how many
     other tokens share the batch — a cached decode step computes the same
@@ -122,7 +152,11 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     x (B, T, d); `live` (B,) bool says which lanes carry a real token
     (None: all).  An idle lane's rows select no expert and come out zero.
     Returns (out (B, T, d), visited): `visited` (int32 scalar) is the
-    number of distinct experts the live rows are routed to.  With `layer`
+    number of distinct experts the live rows are routed to; with
+    `return_routing` also the experts each row took, (B, T, top_k) int32
+    (a scoring entry hands them to a reference, which then computes the
+    same function where two router logits lie closer than the program's
+    rounding).  With `layer`
     (a traced index) the three expert weights are the stacks of all
     layers (L, E, ..) and the visit slices [layer, expert]: a caller
     inside a scan over layers hands the stacks whole, because a layer's
@@ -180,7 +214,8 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
 
     out = jax.lax.fori_loop(0, visited, visit,
                             jnp.zeros((b * t, d), jnp.float32))
-    return out.reshape(b, t, d).astype(dtype), visited
+    out = out.reshape(b, t, d).astype(dtype)
+    return (out, visited, expert_idx) if return_routing else (out, visited)
 
 
 def moe_mlp(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,

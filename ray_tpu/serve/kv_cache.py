@@ -16,8 +16,10 @@ What the blocks are to a sequence depends on the model
 keeps every position they are the whole sequence, which is what prefix
 sharing, copy-on-write, speculation and KV shipping rest on.  A model
 may keep two more kinds of state that this allocator does not own: a
-bounded window of KV a slot for its sliding-window layers, and recurrent
-state a slot for its state-space layers (`models.hybrid.HybridState`),
+bounded window of KV a slot for its sliding-window layers (a ring, not
+recurrent and never zeroed: `models.decoding.PagedKVCache.wk / wv` for a
+`TransformerConfig` with a layer pattern, `models.hybrid.HybridState`),
+and recurrent state a slot for its state-space layers (`HybridState`),
 both indexed by the engine's slot and held by whoever holds the slot.
 The blocks then carry only the layers that keep every position, a prefix
 hit would skip positions whose state nobody kept, and

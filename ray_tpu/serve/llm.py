@@ -124,6 +124,12 @@ class _TickAccounts:
         self.kv_read_tokens = 0
 
 
+def _slot_state(cfg) -> str:
+    """What a sequence of `cfg` keeps by slot, for a refusal's message."""
+    return ("recurrent state" if getattr(cfg, "recurrent", False)
+            else "a ring of window KV")
+
+
 def _snapshot(log: deque) -> tuple:
     """A foreign thread's copy of a bounded log its one owner thread
     appends to meanwhile (engine_stats() runs on the replica's gauge
@@ -161,7 +167,11 @@ class PagedLLMEngine:
       rest on that.
     - bounded window KV (`kv_window`): a ring of window + prefill_chunk
       positions a slot for each sliding-window layer, indexed by the
-      engine's slot, owned by whoever holds the slot.
+      engine's slot, owned by whoever holds the slot.  State by slot
+      that is not recurrent: a row is seen only at the position it was
+      last written for, so nothing is zeroed when a slot changes hands
+      (a stale row lies at a position the new sequence has not reached,
+      and is masked) or when `_preempt` sends a stream to re-prefill.
     - recurrent state (`recurrent`): a state-space layer's conv rows
       and state by slot.  It is zeroed when a request is admitted to the
       slot (`_reset_slot_state`, counted in the tick's `reset_s`),
@@ -169,12 +179,16 @@ class PagedLLMEngine:
       chunk's padded tail and by idle lanes, and zeroed again when
       `_preempt` sends a stream to re-prefill.
 
-    With a model whose configuration says `recurrent` the blocks are
-    not the sequence, so the engine turns prefix sharing off itself (a
-    hit would skip positions whose state nobody kept) and refuses, with
-    a ValueError, `speculation_k >= 2` at construction and
+    Two questions are asked of the configuration.  `state_by_slot`
+    (rings or recurrent state): the blocks are not the sequence, so the
+    engine turns prefix sharing off itself (a hit would skip positions
+    whose state nobody kept), hands the served programs the lanes'
+    slots, and refuses, with a ValueError, a mesh at construction and
     `export_streams` / `import_prefix` when called: snapshots of state
-    are what each would need.
+    are what each would need.  `recurrent`: state is zeroed at admission
+    and preemption as above.  `speculation_k >= 2` is refused for
+    either: a rejected draft has advanced a recurrence, and has written
+    ring rows that no test yet shows are never seen.
     """
     TICKS_KEPT = 4096
 
@@ -229,13 +243,18 @@ class PagedLLMEngine:
         if speculation_ngram is None:
             speculation_ngram = knobs.serve_speculation_ngram
         self._spec_k = speculation_k if speculation_k >= 2 else 0
-        # A model whose sequences keep recurrent state by slot.
+        # A model whose sequences keep state by slot (window rings,
+        # recurrent state), and whether some of it is recurrent.
+        self._by_slot = bool(getattr(cfg, "state_by_slot", False))
         self._recurrent = bool(getattr(cfg, "recurrent", False))
-        if self._recurrent and self._spec_k:
+        if self._by_slot and self._spec_k:
             raise ValueError(
                 f"speculation_k={speculation_k} with {cfg.name!r}: a "
-                f"rejected draft has already advanced the recurrent "
-                f"state, and there is no snapshot to roll it back to")
+                f"rejected draft has already " + (
+                    "advanced the recurrent state, and there is no "
+                    "snapshot to roll it back to" if self._recurrent else
+                    "written rows of the window rings, and nothing yet "
+                    "shows that no later query sees them"))
         self._spec_ngram = max(1, speculation_ngram)
         # The free-margin _maybe_finish keeps must cover whichever
         # advance is larger — a burst OR a spec window — without
@@ -244,7 +263,7 @@ class PagedLLMEngine:
         self._b_max = math.ceil(max_len / self.block_size)
         prefix_sharing = (knobs.kv_block_prefix_sharing
                           if prefix_sharing is None else prefix_sharing)
-        if self._recurrent:
+        if self._by_slot:
             # A hit would skip positions whose state nobody kept.
             prefix_sharing = False
         self._jax = jax
@@ -268,9 +287,9 @@ class PagedLLMEngine:
                 shard_pytree,
             )
 
-            if self._recurrent:
+            if self._by_slot:
                 raise ValueError(
-                    f"{cfg.name!r} keeps recurrent state by slot: it is "
+                    f"{cfg.name!r} keeps {_slot_state(cfg)} by slot: it is "
                     f"served by the paged engine on one device (no mesh)")
             tp = int(mesh.shape.get(AXIS_TENSOR, 1))
             for dim_name, dim in (("n_kv_heads", cfg.n_kv_heads),
@@ -297,7 +316,7 @@ class PagedLLMEngine:
         self._state_bytes = self.cache.resident_bytes()
         self._reset_state = (jax.jit(cfg.reset_slot, donate_argnums=(0,))
                              if self._recurrent else None)
-        self._score_step = None
+        self._score_step = self._score_chunk = None
         # KV positions one decode step sees over lanes of given lengths:
         # the model's own count, or every layer over every position.
         self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
@@ -534,12 +553,12 @@ class PagedLLMEngine:
         ring and recurrent state the chunk reads and writes (num_slots:
         the null slot).  Nothing for a model whose state is the pool
         alone: its programs are called as they always were."""
-        return ({"slot": self._jnp.int32(slot)} if self._recurrent else {})
+        return ({"slot": self._jnp.int32(slot)} if self._by_slot else {})
 
     def _lanes_kw(self, idx: List[int], width: int) -> Dict[str, Any]:
         """The burst's `slots` argument: lane j is engine slot idx[j];
         the lanes past them are idle and point at the null slot."""
-        if not self._recurrent:
+        if not self._by_slot:
             return {}
         slots = np.full((width,), self.num_slots, np.int32)
         slots[:len(idx)] = idx
@@ -1027,7 +1046,7 @@ class PagedLLMEngine:
                 self._work.clear()
 
     # -- scoring -----------------------------------------------------------
-    def score(self, seqs, n_prompt: int) -> List[List[Any]]:
+    def score(self, seqs, n_prompt: int, routing: bool = False):
         """Logits by the engine's own programs, for a comparison with a
         reference.  Each row of `seqs` (lanes, n_prompt + steps) gets a
         slot and blocks of its own: its first `n_prompt` tokens are
@@ -1038,12 +1057,17 @@ class PagedLLMEngine:
         the burst itself returns sampled tokens, never logits) at the
         engine's width tier, every kind of sequence state included.
         Returns per lane the logits at positions n_prompt - 1 .. the
-        last but one: 1 + steps arrays of (V,).  The engine must be
-        idle; its state is left as after requests that finished."""
+        last: 1 + steps arrays of (V,).  With `routing` (a model with
+        experts) returns (those, per lane the experts the program took
+        at every position, prompt positions too, in every layer:
+        (n_prompt + steps, L, top_k) int32), through the same two
+        programs compiled to hand them out.  The engine must be idle;
+        its state is left as after requests that finished."""
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.decoding import _bind_cfg, paged_decode_step
+        from ray_tpu.models.decoding import (
+            _bind_cfg, paged_decode_step, paged_prefill_chunk)
 
         seqs = np.asarray(seqs)
         lanes, total = seqs.shape
@@ -1053,9 +1077,18 @@ class PagedLLMEngine:
                              f"{self.max_len}")
         if self._score_step is None:
             self._score_step = jax.jit(
-                _bind_cfg(paged_decode_step, self.cfg), donate_argnums=(1,))
+                _bind_cfg(paged_decode_step, self.cfg), donate_argnums=(1,),
+                static_argnames=("routing",))
+        chunk_fn, route_kw = self._prefill_chunk_fn, {}
+        if routing:
+            if self._score_chunk is None:
+                self._score_chunk = jax.jit(
+                    _bind_cfg(paged_prefill_chunk, self.cfg),
+                    donate_argnums=(1,), static_argnames=("routing",))
+            chunk_fn, route_kw = self._score_chunk, {"routing": True}
         per_lane = math.ceil(total / self.block_size)
         got: List[List[Any]] = [[] for _ in range(lanes)]
+        taken: List[List[Any]] = [[] for _ in range(lanes)]
         with self._tick_lock:
             if any(r is not None for r in self._slots) or self._pending:
                 raise RuntimeError("score() needs an idle engine")
@@ -1078,10 +1111,14 @@ class PagedLLMEngine:
                             (self._tier_for(self._chunk_tiers, nv),),
                             np.int32)
                         toks[:nv] = seqs[lane, start:start + nv]
-                        self.cache, last = self._prefill_chunk_fn(
+                        self.cache, last, *route = chunk_fn(
                             self.params, self.cache, jnp.asarray(toks),
                             jnp.asarray(tables[lane]), jnp.int32(start),
-                            jnp.int32(nv), **self._slot_kw(lane))
+                            jnp.int32(nv), **self._slot_kw(lane),
+                            **route_kw)
+                        if routing:            # (L, C, k) -> (nv, L, k)
+                            taken[lane].append(
+                                np.asarray(route[0]).swapaxes(0, 1)[:nv])
                     got[lane].append(last)         # position n_prompt - 1
                 active = np.arange(w) < lanes
                 on_device = (jnp.asarray(tables), jnp.asarray(active))
@@ -1089,25 +1126,31 @@ class PagedLLMEngine:
                 for i in range(n_prompt, total):
                     tok = np.zeros((w,), np.int32)
                     tok[:lanes] = seqs[:, i]
-                    self.cache, logits = self._score_step(
+                    self.cache, logits, *route = self._score_step(
                         self.params, self.cache, jnp.asarray(tok),
                         on_device[0],
                         jnp.asarray(np.where(active, i, 0).astype(np.int32)),
-                        on_device[1], **lanes_kw)
+                        on_device[1], **lanes_kw, **route_kw)
+                    if routing:                # (L, w, k) -> (w, L, k)
+                        route = np.asarray(route[0]).swapaxes(0, 1)
                     for lane in range(lanes):
                         got[lane].append(logits[lane])     # position i
+                        if routing:
+                            taken[lane].append(route[lane][None])
             finally:
                 self.allocator.free(blocks)
+        if routing:
+            return got, [np.concatenate(t) for t in taken]
         return got
 
     # -- disaggregated serving / live migration -------------------------
-    def _refuse_if_recurrent(self, what: str) -> None:
-        if self._recurrent:
+    def _refuse_if_by_slot(self, what: str) -> None:
+        if self._by_slot:
             raise ValueError(
                 f"{what} with {self.cfg.name!r}: its sequences keep "
-                f"recurrent state by slot, and pool blocks alone are not "
-                f"a sequence; shipping or adopting one needs a snapshot "
-                f"of that state, which this engine does not take")
+                f"{_slot_state(self.cfg)} by slot, and pool blocks alone "
+                f"are not a sequence; shipping or adopting one needs a "
+                f"snapshot of that state, which this engine does not take")
 
     def import_prefix(self, tokens: List[int], kv, block_size: int,
                       last_logits=None) -> int:
@@ -1126,7 +1169,7 @@ class PagedLLMEngine:
 
         from ray_tpu.models.decoding import scatter_blocks
 
-        self._refuse_if_recurrent("import_prefix")
+        self._refuse_if_by_slot("import_prefix")
         kv = np.asarray(kv)
         n_need = -(-len(tokens) // self.block_size)
         if (block_size != self.block_size or kv.ndim != 6
@@ -1166,7 +1209,7 @@ class PagedLLMEngine:
 
         from ray_tpu.models.decoding import gather_blocks
 
-        self._refuse_if_recurrent("export_streams")
+        self._refuse_if_by_slot("export_streams")
         out: List[Dict[str, Any]] = []
         bs = self.block_size
         with self._tick_lock:
@@ -1371,12 +1414,13 @@ class LLMDeployment:
         compile_cache.counts()
         cfg = (configs.get(cfg_name) if isinstance(cfg_name, str)
                else cfg_name)
-        recurrent = bool(getattr(cfg, "recurrent", False))
-        if recurrent and (tensor_parallel > 1 or disagg):
+        by_slot = bool(getattr(cfg, "state_by_slot", False))
+        if by_slot and (tensor_parallel > 1 or disagg):
             raise ValueError(
-                f"{cfg.name!r} keeps recurrent state by slot: it is served "
-                f"by the paged engine on one device, without disaggregated "
-                f"prefill (a shipped KV frame is not its sequence)")
+                f"{cfg.name!r} keeps {_slot_state(cfg)} by slot: it is "
+                f"served by the paged engine on one device, without "
+                f"disaggregated prefill (a shipped KV frame is not its "
+                f"sequence)")
         if params_loader:
             params = params_loader()
         else:       # a model that brings its own stack brings its own
@@ -1419,7 +1463,7 @@ class LLMDeployment:
         from ray_tpu.core.config import get_config
 
         if disagg is None:
-            disagg = get_config().serve_disagg_enabled and not recurrent
+            disagg = get_config().serve_disagg_enabled and not by_slot
         self._disagg = None
         self.disagg_role = "unified"
         # Prefill actors re-derive weights from (cfg, seed); a custom

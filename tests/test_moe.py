@@ -96,9 +96,10 @@ _LIVE = {"all": lambda s: np.ones((s,), bool),
 
 
 @pytest.mark.parametrize("live_kind", list(_LIVE))
-@pytest.mark.parametrize("lanes,width", [(1, 1), (4, 1), (16, 1), (1, 16)],
+@pytest.mark.parametrize("lanes,width", [(1, 1), (4, 1), (8, 1), (16, 1),
+                                         (32, 1), (1, 16)],
                          ids=lambda v: str(v))
-@pytest.mark.parametrize("n_experts,top_k", [(4, 2), (8, 2)])
+@pytest.mark.parametrize("n_experts,top_k", [(4, 2), (8, 2), (64, 8)])
 def test_served_expert_ffn_visits_the_live_rows_experts(
         n_experts, top_k, lanes, width, live_kind):
     from ray_tpu.ops.moe import init_moe_params, moe_mlp_dropless
@@ -131,6 +132,13 @@ def test_served_expert_ffn_visits_the_live_rows_experts(
     np.testing.assert_array_equal(np.asarray(moved, np.float32)[live],
                                   got[live])
     assert int(visited_2) == int(visited)
+    # asked for the routing, it gives the experts every row took and the
+    # same output
+    routed, n, taken = jax.jit(lambda x, live: moe_mlp_dropless(
+        x, params, cfg, live=live, return_routing=True))(x, jnp.asarray(live))
+    np.testing.assert_array_equal(np.asarray(routed, np.float32), got)
+    assert int(n) == int(visited) and taken.shape == (lanes, width, top_k)
+    np.testing.assert_array_equal(np.asarray(taken), np.asarray(chosen))
     # no mask means every lane is live
     if live_kind == "all":
         unmasked, n = moe_mlp_dropless(x, params, cfg)

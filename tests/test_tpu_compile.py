@@ -466,3 +466,61 @@ def test_hybrid_served_programs_fit_one_chip(topo, program):
     assert mem.alias_size_in_bytes >= state_bytes
     assert _device_bytes(compiled) < 15.75e9
     assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_window_moe_served_programs_fit_one_chip(topo, program):
+    """Mellum2-12B-A2.5B at the benchmark's cut (8 of 28 layers: six
+    window layers, two full) and serving shape (32 slots x 8192, block 16:
+    two layers' pool 1.07 GB, six rings of 1152 rows a slot 0.47 GB beside
+    7.59 GB of weights): the width-32 burst and the 128-token chunk
+    compile for one v5e chip and fit its 15.75 GB usable.  Pool and rings
+    are updated in place (their bytes are aliased), and the temporaries
+    together stay under one ring array (234 MB), which is less than the
+    pool (537 MB a side) and than one matrix of a layer's expert stack
+    (264 MB): nothing of the three is copied whole.  (The layers of a
+    period index the weight stacks themselves: with a period's slice taken
+    by the scan the burst held 301 MB.)  The expert products read the
+    stacks in place, as Mixtral's."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "mellum2-12b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    state = resident["sequence_state"]
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize
+        for s in jax.tree.leaves(resident["params"]))
+    assert state.k.shape == (2, 16385, 16, 4, 128)
+    assert state.wk.shape == (6, 33, 1152, 4, 128)
+    assert abs(resident_bytes - 9.13e9) < 0.01e9, resident_bytes
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    ring_array = state.wk.size * 2
+    assert mem.temp_size_in_bytes < ring_array, mem.temp_size_in_bytes
+    products = [
+        body for body in compiled.as_text().split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and fam.expert_operand(config).search(
+            body.lstrip().split("\n", 1)[0])]
+    assert len(products) >= 3, len(products)
+    ring_ops = re.findall(r"= bf16\[[0-9,]*1152,4,128\]", compiled.as_text())
+    assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
