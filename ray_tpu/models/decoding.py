@@ -140,6 +140,23 @@ def sample_one(last_logits, temp, rng):
     return sample_per_slot(last_logits[None], sub, temp[None])[0], rng
 
 
+def take_last(last: jax.Array, slots: jax.Array, host: jax.Array
+              ) -> jax.Array:
+    """A burst's input tokens without a read by the host: lane j carries
+    on engine slot slots[j] from `last` (num_slots + 1,), the last token
+    each slot sampled (`put_last`), unless the host holds its token
+    (host[j] >= 0: a prompt's first token).  One shape a width tier."""
+    return jnp.where(host >= 0, host, last[slots])
+
+
+def put_last(last: jax.Array, slots: jax.Array, tok_mat: jax.Array
+             ) -> jax.Array:
+    """`last` with the final row of a burst's token matrix (n_steps, S)
+    written at the lanes' slots; idle lanes (slot num_slots) write the
+    entry that nobody reads."""
+    return last.at[slots].set(tok_mat[-1])
+
+
 def _bind_cfg(f, cfg: TransformerConfig):
     """`functools.partial(f, cfg=cfg)` under `f`'s own name.  jax.jit
     names the compiled program after the function it is given, and a

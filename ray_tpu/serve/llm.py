@@ -40,7 +40,7 @@ class _Request:
                  "prefill_at", "first_token_at", "prefill_span", "chunks",
                  "chunk_s", "chunk_tokens", "token_q", "dropped", "blocks",
                  "pos", "prefilling", "no_register", "trace",
-                 "last_emit_wall")
+                 "last_emit_wall", "ahead")
 
     def __init__(self, prompt, max_tokens, temperature, stream=False):
         from ray_tpu.core.config import get_config
@@ -84,6 +84,9 @@ class _Request:
         self.blocks: List[int] = []   # paged engine: owned pool blocks
         self.pos = 0                  # paged engine: tokens prefilled
         self.prefilling = True        # paged engine: not yet decoding
+        # Tokens of this request in a burst that is launched and not yet
+        # read: the host has counted them, only the device holds them.
+        self.ahead = 0
         # Resumed contexts embed generated tokens in `prompt` — never
         # publish them as a reusable prompt prefix.
         self.no_register = False
@@ -106,7 +109,8 @@ class _Request:
 # carry the names as "tick_fields", so a reader needs no copy of them).
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "kv_read_tokens",
-               "reset_s", "experts_read")
+               "reset_s", "experts_read", "ahead")
+_EXPERTS_READ = TICK_FIELDS.index("experts_read")
 
 
 class _TickAccounts:
@@ -115,13 +119,28 @@ class _TickAccounts:
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
-                 "experts_read")
+                 "experts_read", "ahead")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
             self.experts_read = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
-        self.kv_read_tokens = 0
+        self.kv_read_tokens = self.ahead = 0
+
+
+class _Burst:
+    """A decode burst that is launched and not yet read, and what its
+    read needs: the token matrix and the count of experts visited (both
+    still on the device), lane by lane the request, the tokens the host
+    counted for it at the launch and whether they are its last, the
+    launch's time, and the record of the tick that launched it, which
+    enters the tick log at the read, when `experts_read` is known."""
+    __slots__ = ("tok_mat", "visited", "lanes", "t0", "row")
+
+    def __init__(self, tok_mat, visited, lanes, t0):
+        self.tok_mat, self.visited = tok_mat, visited
+        self.lanes, self.t0 = lanes, t0
+        self.row: Optional[list] = None
 
 
 def _slot_state(cfg) -> str:
@@ -145,11 +164,30 @@ def _snapshot(log: deque) -> tuple:
 class PagedLLMEngine:
     """Paged/block KV-cache engine: the one served engine.
 
-    Engine tick: [admit waiting requests] -> [one fused decode burst
-    over every DECODING slot] -> [one prefill chunk for the oldest
+    Engine tick: [admit waiting requests] -> [launch one fused decode
+    burst over every DECODING slot] -> [read the burst launched a tick
+    ago, emit its tokens] -> [one prefill chunk for the oldest
     PREFILLING slot].  Decode never waits for a whole prompt: a
     max-length prompt occupies at most `prefill_chunk` tokens of device
     time per tick, bounding the inter-token latency of active streams.
+
+    The host runs one burst ahead of its own reads, never more: the
+    next burst needs nothing the host has to read first.  Lengths,
+    tables and the lane set are the host's own arithmetic, advanced at
+    the launch; a request that ends by `max_tokens` or `max_len` ends at
+    a step known before its last burst runs, and leaves its slot at that
+    launch (its blocks go at the read); each lane's input token is the
+    last one its slot sampled, kept on the device (`_last_dev`, by slot)
+    and gathered there.  So while one burst runs the next is queued
+    behind it, and a tick lasts as long as the device's work or the
+    host's, whichever is longer.  Only EOS and a dropped stream are
+    learnt from the tokens, one burst late: the burst ahead's tokens for
+    such a lane are dropped at their read, matched to the request and
+    not to the slot.  Whatever needs the tokens on the host reads the
+    burst in flight first (`_drain`): speculation (drafts come from the
+    emitted context, so with `speculation_k` the engine reads every
+    burst in the tick that launched it), `_preempt`, `score`, `warmup`,
+    `export_streams`, `import_prefix`, `shutdown`.
 
     Admission: a request needs pool blocks covering its (non-shared)
     prompt remainder.  When the pool can't cover it, the request WAITS
@@ -210,7 +248,9 @@ class PagedLLMEngine:
             make_paged_engine_fns,
             make_paged_spec_fns,
             paged_cache_shardings,
+            put_last,
             sample_one,
+            take_last,
         )
         from ray_tpu.serve.kv_cache import KVBlockAllocator
 
@@ -339,7 +379,20 @@ class PagedLLMEngine:
         # compiled step only ever sees fixed (S, B_max) arrays).
         self._tables = np.zeros((num_slots, self._b_max), np.int32)
         self._lengths = np.zeros((num_slots,), np.int32)
+        # The last token each slot sampled, on the host (what the reads
+        # have seen) and on the device (what the bursts have sampled;
+        # entry num_slots is the idle lanes').  A lane's next input is
+        # the host's where nothing of its request is in flight, else
+        # the device's.
         self._last_tokens = np.zeros((num_slots,), np.int32)
+        self._last_dev = jnp.zeros((num_slots + 1,), jnp.int32)
+        if mesh is not None:
+            self._last_dev = jax.device_put(self._last_dev,
+                                            self._rng.sharding)
+        self._take_last = jax.jit(take_last)
+        self._put_last = jax.jit(put_last)
+        self._inflight: Optional[_Burst] = None
+        self._read_at = 0.0           # when the last burst was read
         self._slots: List[Optional[_Request]] = [None] * num_slots
         self._prefillq: deque = deque()   # slots awaiting prefill chunks
         self._pending: deque = deque()
@@ -354,7 +407,8 @@ class PagedLLMEngine:
         self.stats = {"requests": 0, "tokens_generated": 0,
                       "completed": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
-                      "prefill_chunks": 0, "queue_waits": 0,
+                      "prefill_chunks": 0, "bursts_ahead": 0,
+                      "queue_waits": 0,
                       "preemptions": 0, "adopted_blocks": 0,
                       "migrated_blocks": 0, "migrate_fallbacks": 0,
                       "disagg_prefills": 0,
@@ -459,7 +513,16 @@ class PagedLLMEngine:
         self._stop = True
         self._work.set()
         self._thread.join(timeout=5)
-        self.allocator.release()
+        # A request that left its slot at its last burst's launch ends
+        # at that burst's read.
+        try:
+            if self._tick_lock.acquire(timeout=5):
+                try:
+                    self._drain()
+                finally:
+                    self._tick_lock.release()
+        finally:
+            self.allocator.release()
 
     def engine_stats(self, records: bool = True) -> Dict[str, Any]:
         """The cumulative counters and, with `records`, the bounded
@@ -491,14 +554,12 @@ class PagedLLMEngine:
         scatter into the null block — garbage no request reads."""
         import jax.numpy as jnp
 
+        self._drain()
         for w in self._width_tiers:
             z = np.zeros((w,), np.int32)
-            self.cache, _, self._rng, _ = self._decode(
-                self.params, self.cache, jnp.asarray(z),
-                jnp.zeros((w, self._b_max), jnp.int32), jnp.asarray(z),
-                jnp.zeros((w,), bool), jnp.zeros((w,), jnp.float32),
-                self._rng, n_steps=self.max_burst,
-                **self._lanes_kw([], w))
+            self._launch_burst(
+                [], w, z, np.zeros((w, self._b_max), np.int32), z,
+                np.zeros((w,), bool), np.zeros((w,), np.float32))
             if self._spec_k:
                 self.cache, _, _, self._rng = self._verify(
                     self.params, self.cache,
@@ -555,14 +616,36 @@ class PagedLLMEngine:
         alone: its programs are called as they always were."""
         return ({"slot": self._jnp.int32(slot)} if self._by_slot else {})
 
-    def _lanes_kw(self, idx: List[int], width: int) -> Dict[str, Any]:
-        """The burst's `slots` argument: lane j is engine slot idx[j];
-        the lanes past them are idle and point at the null slot."""
-        if not self._by_slot:
-            return {}
+    def _lane_slots(self, idx: List[int], width: int) -> np.ndarray:
+        """Lane j is engine slot idx[j]; the lanes past them are idle
+        and point at the null slot."""
         slots = np.full((width,), self.num_slots, np.int32)
         slots[:len(idx)] = idx
-        return {"slots": self._jnp.asarray(slots)}
+        return slots
+
+    def _lanes_kw(self, idx: List[int], width: int) -> Dict[str, Any]:
+        """The burst's `slots` argument, for a model that keeps state
+        by slot."""
+        if not self._by_slot:
+            return {}
+        return {"slots": self._jnp.asarray(self._lane_slots(idx, width))}
+
+    def _launch_burst(self, idx: List[int], width: int, host_tok, tables,
+                      lengths, active, temps):
+        """Three launches and no read: the gather of the lanes' input
+        tokens (`host_tok[j]` where it is >= 0, else the last token slot
+        idx[j] sampled), the burst, the scatter of its last row back by
+        slot.  Returns (token matrix, experts visited), on the device."""
+        jnp = self._jnp
+        slots = jnp.asarray(self._lane_slots(idx, width))
+        self.cache, tok_mat, self._rng, visited = self._decode(
+            self.params, self.cache,
+            self._take_last(self._last_dev, slots, jnp.asarray(host_tok)),
+            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
+            jnp.asarray(temps), self._rng, n_steps=self.max_burst,
+            **({"slots": slots} if self._by_slot else {}))
+        self._last_dev = self._put_last(self._last_dev, slots, tok_mat)
+        return tok_mat, visited
 
     def _reset_slot_state(self, req: "_Request", slot: int) -> None:
         """Zero `slot`'s recurrent state: `req` was admitted to it, or
@@ -791,12 +874,14 @@ class PagedLLMEngine:
         return True
 
     def _decode_tick(self) -> bool:
-        import jax.numpy as jnp
-
+        """Launch the next burst over the decoding slots, then read the
+        one launched a tick ago (see the class docstring)."""
         burst = self.max_burst
         # One tick advances either a burst (burst tokens of KV) or a
         # spec window (K tokens of KV); cover whichever is larger so
         # the spec/burst choice below never re-runs allocation.
+        # `_lengths` already counts the burst in flight, so this covers
+        # the burst ahead of it.
         adv = max(burst, self._spec_k)
         idx: List[int] = []
         stalled: List[int] = []
@@ -807,77 +892,150 @@ class PagedLLMEngine:
                 idx.append(i)
             else:
                 stalled.append(i)
-        if not idx:
-            if len(stalled) >= 2:
-                # Deadlock: every decoder needs growth blocks and the
-                # pool is exhausted by the decoders themselves — nobody
-                # can finish to free blocks.  Preempt the youngest
-                # (vLLM-style recompute preemption): its blocks free the
-                # others; it re-prefills prompt+emitted later.
-                self._preempt(max(stalled,
-                                  key=lambda i:
-                                  self._slots[i].submitted_at))
-            return False
-        # Compact the active slots into the smallest width tier: device
-        # work tracks the number of LIVE streams, not the configured
-        # capacity (a ramp-up tick with 3 decoders runs a width-4 burst,
-        # not a num_slots-wide one).  All per-slot state is host-side,
-        # so lane mapping is just row selection.
-        w = self._tier_for(self._width_tiers, len(idx))
-        self._acct.lanes, self._acct.width = len(idx), w
-        self._acct.kv_read_tokens = self._kv_read_tokens(
-            [int(self._lengths[i]) for i in idx])
-        tokens = np.zeros((w,), np.int32)
-        tables = np.zeros((w, self._b_max), np.int32)
-        lengths = np.zeros((w,), np.int32)
-        active = np.zeros((w,), bool)
-        temps = np.zeros((w,), np.float32)
-        for j, i in enumerate(idx):
-            tokens[j] = self._last_tokens[i]
-            tables[j] = self._tables[i]
-            lengths[j] = self._lengths[i]
-            active[j] = True
-            temps[j] = self._slots[i].temperature
+        prev = self._inflight
         try:
+            if not idx:
+                if prev is not None:
+                    # Nothing to launch: the burst in flight is read,
+                    # and what its requests free is seen next tick.
+                    self._drain()
+                    return True
+                if len(stalled) >= 2:
+                    # Deadlock: every decoder needs growth blocks and
+                    # the pool is exhausted by the decoders themselves
+                    # -- nobody can finish to free blocks.  Preempt the
+                    # youngest (vLLM-style recompute preemption): its
+                    # blocks free the others; it re-prefills
+                    # prompt+emitted later.
+                    self._preempt(max(stalled,
+                                      key=lambda i:
+                                      self._slots[i].submitted_at))
+                return False
+            # Compact the active slots into the smallest width tier:
+            # device work tracks the number of LIVE streams, not the
+            # configured capacity (a ramp-up tick with 3 decoders runs a
+            # width-4 burst, not a num_slots-wide one).  All per-slot
+            # state but the last tokens is host-side, so lane mapping is
+            # row selection, and a gather by slot for the tokens.
+            w = self._tier_for(self._width_tiers, len(idx))
+            self._acct.lanes, self._acct.width = len(idx), w
+            self._acct.kv_read_tokens = self._kv_read_tokens(
+                [int(self._lengths[i]) for i in idx])
+            # A lane's token from the host where the host holds it (a
+            # first token, or every burst of the request is read), else
+            # (-1) from the device's vector.
+            host_tok = np.full((w,), -1, np.int32)
+            tables = np.zeros((w, self._b_max), np.int32)
+            lengths = np.zeros((w,), np.int32)
+            active = np.zeros((w,), bool)
+            temps = np.zeros((w,), np.float32)
+            for j, i in enumerate(idx):
+                req = self._slots[i]
+                if not req.ahead:
+                    host_tok[j] = self._last_tokens[i]
+                tables[j] = self._tables[i]
+                lengths[j] = self._lengths[i]
+                active[j] = True
+                temps[j] = req.temperature
             if self._spec_k and self._spec_tick(idx, tables, lengths,
                                                 active, temps):
                 return True
             t0 = time.time()
-            self.cache, tok_mat, self._rng, visited = self._decode(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(active), jnp.asarray(temps), self._rng,
-                n_steps=burst, **self._lanes_kw(idx, w))
-            if self._expert_layers:
-                # Tokens and count, both copies started before either is
-                # waited for: a second read after the first costs 0.6 ms.
-                tok_mat, visited = self._jax.device_get((tok_mat, visited))
-                self._acct.experts_read = int(visited) / (
-                    burst * self._expert_layers)
-            else:
-                tok_mat = np.asarray(tok_mat)          # (burst, w)
-            t1 = time.time()
-            self._acct.decode_s = t1 - t0
-            for j, i in enumerate(idx):
+            tok_mat, visited = self._launch_burst(
+                idx, w, host_tok, tables, lengths, active, temps)
+            self._acct.decode_s = time.time() - t0
+            # The host's books move at the launch.  A request whose
+            # last token is in this burst leaves its slot now: the next
+            # launch is without it, and whoever takes the slot is
+            # launched after this burst, so runs after it.
+            lanes = []
+            for i in idx:
                 req = self._slots[i]
                 self._lengths[i] += burst   # KV written for every step
-                n0 = len(req.out_tokens)
-                for step in range(burst):
-                    tok = int(tok_mat[step, j])
-                    if len(req.out_tokens) >= req.max_tokens:
-                        break  # over-generated tail: trim
-                    req.emit(tok)
-                    self._last_tokens[i] = tok
-                    self.stats["tokens_generated"] += 1
-                    if self.eos_id is not None and tok == self.eos_id:
-                        break
-                self._obs_burst(req, t0, t1, len(req.out_tokens) - n0)
-                self._maybe_finish(i)
+                n = min(burst, req.max_tokens - len(req.out_tokens)
+                        - req.ahead)
+                req.ahead += n
+                last = self._ends_at(req, len(req.out_tokens) + req.ahead)
+                if last:
+                    self._slots[i] = None
+                    self._tables[i, :] = 0
+                lanes.append((req, n, last))
+            self._inflight = _Burst(
+                tok_mat, visited if self._expert_layers else None, lanes,
+                t0)
+            if prev is not None:
+                self._acct.ahead = 1
+                self.stats["bursts_ahead"] += 1
+                self._harvest(prev)
+            if self._spec_k:
+                self._drain()       # drafts come from the emitted context
         except BaseException as e:  # noqa: BLE001
-            for i, req in enumerate(self._slots):
-                if req is not None:
-                    self._fail_request(req, e)
+            self._fail_all(e, prev)
         return True
+
+    def _harvest(self, b: "_Burst") -> None:
+        """Read a launched burst's tokens and emit them, each lane's to
+        the request that was in it at the launch."""
+        t0 = time.time()
+        if b.visited is not None:
+            # Tokens and count, both copies started before either is
+            # waited for: a second read after the first costs 0.6 ms.
+            tok_mat, visited = self._jax.device_get((b.tok_mat, b.visited))
+            experts = int(visited) / (self.max_burst * self._expert_layers)
+        else:
+            tok_mat, experts = np.asarray(b.tok_mat), 0.0   # (burst, w)
+        t1 = time.time()
+        self._acct.decode_s += t1 - t0
+        if b.row is None:           # read in the tick that launched it
+            self._acct.experts_read = experts
+        else:
+            b.row[_EXPERTS_READ] = experts
+            self._tick_log.append(tuple(b.row))
+        # The burst's turn on the device began when the one before it
+        # was read, if that was after its launch.
+        began, self._read_at = max(b.t0, self._read_at), t1
+        for j, (req, n, last) in enumerate(b.lanes):
+            if req.done.is_set():
+                continue    # ended at an earlier read: EOS, a dropped
+                #             stream; these are the burst ahead's tokens
+            req.ahead -= n
+            n0, eos = len(req.out_tokens), False
+            for tok in tok_mat[:n, j].tolist():
+                req.emit(tok)
+                self.stats["tokens_generated"] += 1
+                if self.eos_id is not None and tok == self.eos_id:
+                    eos = True
+                    break
+            self._obs_burst(req, began, t1, len(req.out_tokens) - n0)
+            if self._slots[req.slot] is req:
+                self._last_tokens[req.slot] = req.out_tokens[-1]
+            if last or eos or req.dropped:
+                self._finish(req)
+
+    def _drain(self) -> None:
+        """Read the burst in flight, if any: after this the host holds
+        every token the device has sampled."""
+        b, self._inflight = self._inflight, None
+        if b is None:
+            return
+        try:
+            self._harvest(b)
+        except BaseException as e:  # noqa: BLE001
+            self._fail_all(e, b)
+            raise
+
+    def _fail_all(self, e: BaseException,
+                  also: Optional["_Burst"] = None) -> None:
+        """Fail every request the engine holds: in a slot, or only in a
+        burst that is launched and not read (`also`: one the caller has
+        taken out of `_inflight`)."""
+        bursts, self._inflight = (self._inflight, also), None
+        held = [r for r in self._slots if r is not None]
+        held += [lane[0] for b in bursts if b is not None
+                 for lane in b.lanes]
+        for req in held:
+            if not req.done.is_set():
+                self._fail_request(req, e)
 
     def _spec_tick(self, idx: List[int], tables, lengths, active,
                    temps) -> bool:
@@ -961,6 +1119,11 @@ class PagedLLMEngine:
         others) and queue it for full-context re-prefill.  The stream
         keeps every emitted token — only KV is recomputed."""
         req = self._slots[slot]
+        # Its re-prefill is over prompt + emitted: every token of it
+        # that the device holds is read first.
+        self._drain()
+        if self._slots[slot] is not req:
+            return                  # that read ended it
         self.allocator.free(req.blocks)
         req.blocks = []
         self._tables[slot, :] = 0
@@ -971,22 +1134,37 @@ class PagedLLMEngine:
         self._prefillq.append(slot)
         self.stats["preemptions"] += 1
 
+    def _ends_at(self, req: "_Request", n_out: int) -> bool:
+        """Whether `req` ends once it has `n_out` tokens, by count
+        alone: `max_tokens`, or the room `max_len` leaves."""
+        return (n_out >= req.max_tokens
+                or len(req.prompt) + n_out
+                >= self.max_len - 1 - self._advance_margin)
+
     def _maybe_finish(self, slot: int) -> None:
+        """End the request in `slot` if what the host has read of it
+        says so (nothing of it is in flight)."""
         req = self._slots[slot]
         if req is None:
             return
         tok = req.out_tokens[-1] if req.out_tokens else None
         hit_eos = self.eos_id is not None and tok == self.eos_id
-        full = (len(req.prompt) + len(req.out_tokens)
-                >= self.max_len - 1 - self._advance_margin)
-        if hit_eos or full or len(req.out_tokens) >= req.max_tokens \
-                or req.dropped:
-            self._slots[slot] = None
-            self._tables[slot, :] = 0
-            self.allocator.free(req.blocks)
-            req.blocks = []
-            self._finish_request(req)
-            self._work.set()   # freed blocks may unblock the queue head
+        if hit_eos or req.dropped \
+                or self._ends_at(req, len(req.out_tokens)):
+            self._finish(req)
+
+    def _finish(self, req: "_Request") -> None:
+        """`req` is over: its slot, if it still holds one, and its
+        blocks are free.  A burst that is launched and not read may
+        still write those blocks: whatever takes them is launched
+        later, so runs later."""
+        if self._slots[req.slot] is req:
+            self._slots[req.slot] = None
+            self._tables[req.slot, :] = 0
+        self.allocator.free(req.blocks)
+        req.blocks = []
+        self._finish_request(req)
+        self._work.set()   # freed blocks may unblock the queue head
 
     def _finish_request(self, req: "_Request") -> None:
         """Complete one request: stats + stream sentinel + done event."""
@@ -1000,27 +1178,37 @@ class PagedLLMEngine:
 
     def _tick(self) -> bool:
         """One engine tick; the caller holds _tick_lock.  A tick that
-        progressed leaves one record of TICK_FIELDS in the tick log.
+        progressed leaves one record of TICK_FIELDS in the tick log; the
+        record of a tick whose burst is still unread at its end enters
+        the log when that burst is read (the next tick), in its place.
         `start`, `tick_s`: time.time() around this body, never the wait
-        on _work.  `decode_s`: launch of the burst (or spec window) to
-        the host's read of its tokens.  `prefill_s`: the chunks' own
+        on _work.  `decode_s`: the launch of this tick's burst (or spec
+        window) plus the wait for the read of the burst before it (of
+        this tick's own, where the engine reads every burst at once:
+        speculation).  `prefill_s`: the chunks' own
         launch times summed (a launch returns before its chunk has run;
         the device's time for it is waited for in the next `decode_s`
         or `sample_s`).  `sample_s`: the host's reads of the first
         tokens of the prompts this tick finished.  `lanes` of `width`:
-        decoding lanes in the burst's tier.  `prefill_tokens`: prompt
+        decoding lanes in the tier of the burst this tick launched (0
+        of 0 for a tick that only read one).  `prefill_tokens`: prompt
         tokens the chunks carried.  So tick_s - decode_s -
         prefill_s - sample_s is the tick's time in which the host
-        neither waited for the device nor launched a chunk.
+        neither waited for the device nor launched: with a burst ahead
+        it is no longer time the device stood still for.
         `kv_read_tokens`: KV positions one step of the burst sees, summed
         over its lanes and the layers that read (the model's count;
         n_layers x the lanes' lengths where every layer keeps every
         position).  `reset_s`: launches that zeroed the recurrent state
         of slots this tick admitted to or preempted (0 for a model that
-        has none).  `experts_read`: distinct experts the burst's live
-        lanes were routed to, and so read, per layer and step: the mean
-        over the burst's steps and the model's expert layers (0.0 for a
-        model without experts, or a tick without a burst)."""
+        has none).  `experts_read`: distinct experts the live lanes of
+        the burst this tick launched were routed to, and so read, per
+        layer and step: the mean over the burst's steps and the model's
+        expert layers (0.0 for a model without experts, or a tick
+        without a burst).  `ahead`: 1 where the tick launched its burst
+        while the one before it was still unread, so that the device had
+        it queued behind that one; 0 for a busy period's first burst and
+        for a tick without a burst (`stats["bursts_ahead"]` sums it)."""
         start = time.time()
         acct = self._acct = _TickAccounts()
         progressed = False
@@ -1030,11 +1218,15 @@ class PagedLLMEngine:
         progressed |= self._decode_tick()
         progressed |= self._prefill_tick()
         if progressed:
-            self._tick_log.append(
-                (start, time.time() - start, acct.decode_s, acct.prefill_s,
-                 acct.sample_s, acct.lanes, acct.width,
-                 acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s,
-                 acct.experts_read))
+            row = [start, time.time() - start, acct.decode_s,
+                   acct.prefill_s, acct.sample_s, acct.lanes, acct.width,
+                   acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s,
+                   acct.experts_read, acct.ahead]
+            b = self._inflight
+            if b is not None and b.row is None:
+                b.row = row     # this tick's burst: logged at its read
+            else:
+                self._tick_log.append(tuple(row))
         return progressed
 
     def _loop(self):
@@ -1090,6 +1282,7 @@ class PagedLLMEngine:
         got: List[List[Any]] = [[] for _ in range(lanes)]
         taken: List[List[Any]] = [[] for _ in range(lanes)]
         with self._tick_lock:
+            self._drain()
             if any(r is not None for r in self._slots) or self._pending:
                 raise RuntimeError("score() needs an idle engine")
             blocks = self.allocator.alloc(lanes * per_lane)
@@ -1182,6 +1375,7 @@ class PagedLLMEngine:
         meta = (self._jnp.asarray(last_logits)
                 if last_logits is not None else None)
         with self._tick_lock:
+            self._drain()
             blocks = self.allocator.adopt(tokens, meta=meta)
             if blocks is None:
                 return 0
@@ -1213,6 +1407,7 @@ class PagedLLMEngine:
         out: List[Dict[str, Any]] = []
         bs = self.block_size
         with self._tick_lock:
+            self._drain()
             for i, req in enumerate(self._slots):
                 if req is None or req.prefilling or req.token_q is None:
                     continue

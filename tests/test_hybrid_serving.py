@@ -224,7 +224,7 @@ def test_a_slot_reused_by_a_second_request(served):
     assert stats["state"]["state_resets"] == resets + 2
     assert stats["prefix_hits"] == 0
     fields = stats["tick_fields"]
-    assert fields[-3:-1] == ("kv_read_tokens", "reset_s")
+    assert fields[-4:-2] == ("kv_read_tokens", "reset_s")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     assert any(t["reset_s"] > 0 for t in ticks)
     one = [t for t in ticks if t["lanes"] == 1][-1]
@@ -259,6 +259,21 @@ def test_a_preempted_stream_equals_the_undisturbed_one():
         assert stats["state"]["state_rebuilds"] >= 1
         assert all(len(o) == 24 for o in outs)
         assert all(_is_greedy(e, c, p, o) for p, o in zip(prompts, outs))
+    finally:
+        e.shutdown()
+
+
+def test_streams_equal_the_step_reference_while_lanes_join_and_leave():
+    """The engine launches a burst before it has read the one before
+    (tests/test_burst_ahead.py), here on slots that hold rings, conv rows
+    and recurrent state: requests of different lengths join and leave
+    mid-stream, the tiers go 4, 8, 4, a slot changes hands while its last
+    burst is unread, and every stream is the step-by-step reference's."""
+    from burst_ahead_cases import join_and_leave, park
+
+    e = park(_engine(_config(), num_slots=8))
+    try:
+        join_and_leave(e)
     finally:
         e.shutdown()
 

@@ -430,7 +430,10 @@ def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
             + t["sample_s"] >= 0.0
         assert t["lanes"] <= t["width"]
         assert t["prefill_tokens"] <= _CHUNK
-        assert t["lanes"] or t["prefill_tokens"]    # it progressed
+        # it progressed: launched a burst, launched a chunk, or (the
+        # tick after a busy period's last launch) only read a burst
+        assert t["lanes"] or t["prefill_tokens"] or t["decode_s"] > 0.0
+        assert t["ahead"] in (0, 1) and t["ahead"] <= t["lanes"]
     assert [t["start"] for t in ticks] == sorted(t["start"] for t in ticks)
     assert sum(t["prefill_tokens"] for t in ticks) == sum(_PROMPT_LENS)
     # every prompt token went through a chunk the stats counted
@@ -447,8 +450,9 @@ def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
 
 @pytest.mark.parametrize("model", ["tiny", "tiny-moe"])
 def test_tick_log_says_how_many_experts_a_burst_read(model):
-    """`experts_read`, the last of `tick_fields`: distinct experts the
-    burst's live lanes were routed to, per expert layer and step.  0.0
+    """`experts_read` of `tick_fields`: distinct experts the live lanes
+    of the burst a tick launched were routed to, per expert layer and
+    step, logged with that burst's `lanes` though read a tick later.  0.0
     for a model without experts; with them between top_k (one live
     token) and all of them on every tick that decoded, whatever the
     burst's idle lanes hold."""
@@ -466,7 +470,7 @@ def test_tick_log_says_how_many_experts_a_burst_read(model):
         eng.shutdown()
     assert all(len(o) == 12 for o in outs)
     fields = stats["tick_fields"]
-    assert fields[-1] == "experts_read"
+    assert fields[-2:] == ("experts_read", "ahead")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     decoded = [t for t in ticks if t["lanes"] > 0]
     assert decoded and len(decoded) < len(ticks)

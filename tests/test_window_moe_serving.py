@@ -319,6 +319,22 @@ def test_a_preempted_stream_equals_the_undisturbed_one():
         e.shutdown()
 
 
+def test_streams_equal_the_step_reference_while_lanes_join_and_leave():
+    """The engine launches a burst before it has read the one before
+    (tests/test_burst_ahead.py), here on slots that hold rings beside the
+    pool, with experts whose count is read with the tokens: requests of
+    different lengths join and leave mid-stream, the tiers go 4, 8, 4, a
+    slot changes hands while its last burst is unread, and every stream
+    is the step-by-step reference's."""
+    from burst_ahead_cases import join_and_leave, park
+
+    e = park(_engine(_config(), num_slots=8))
+    try:
+        join_and_leave(e)
+    finally:
+        e.shutdown()
+
+
 # -- (vi) what a ring forbids is refused, and says why --------------------------
 def test_refusals():
     c = _config()
