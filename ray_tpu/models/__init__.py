@@ -14,6 +14,7 @@ from ray_tpu.models.transformer import (
     loss_fn,
 )
 from ray_tpu.models.hybrid import HybridConfig
+from ray_tpu.models.mamba2_moe import Mamba2MoEConfig
 from ray_tpu.models import configs
 from ray_tpu.models.hf_convert import from_hf
 
@@ -21,6 +22,7 @@ __all__ = [
     "Transformer",
     "TransformerConfig",
     "HybridConfig",
+    "Mamba2MoEConfig",
     "init_params",
     "param_logical_axes",
     "forward",

@@ -7,6 +7,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from ray_tpu.models.hybrid import HybridConfig
+from ray_tpu.models.mamba2_moe import Mamba2MoEConfig
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops.rotary import YarnScaling
 
@@ -96,11 +97,29 @@ MELLUM2_12B = dataclasses.replace(
                      beta_slow=1.0, attention_factor=1.2772588722239782),
     max_seq_len=131072, param_dtype=jnp.bfloat16)
 
+# The state-space / attention hybrid with routed experts
+# (models/mamba2_moe.py) at test size: two periods of (Mamba, Mamba,
+# attention, Mamba), 8 experts top-3 of which this rank holds the upper
+# four, a shared expert, multipliers away from 1.
+TINY_MAMBA2_MOE = Mamba2MoEConfig(
+    name="tiny-mamba2-moe", vocab_size=512, d_model=64, n_layers=8,
+    layer_pattern=("mamba", "mamba", "attention", "mamba"), n_heads=4,
+    n_kv_heads=2, d_head=16, attention_scale=1.0 / 16, ssm_heads=8,
+    ssm_head_dim=16, d_state=8, n_experts=8, expert_top_k=3, d_expert=24,
+    d_shared=48, experts_held=(4, 4), embedding_multiplier=3.0,
+    residual_multiplier=0.5, logits_scaling=4.0, max_seq_len=512,
+    param_dtype=jnp.float32)
+
+# granite-4.0-h-small's published sizes (32 B parameters, 9 B active a
+# token), every expert held.
+GRANITE4_H_SMALL = Mamba2MoEConfig(name="granite-4.0-h-small")
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
                                 TINY_HYBRID, PHI4_MINI_FLASH,
-                                TINY_WINDOW_MOE, MELLUM2_12B]}
+                                TINY_WINDOW_MOE, MELLUM2_12B,
+                                TINY_MAMBA2_MOE, GRANITE4_H_SMALL]}
 
 
 def get(name: str):

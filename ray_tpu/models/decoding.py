@@ -271,16 +271,30 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
     """The served step of `cfg`'s model: `_paged_forward`, or the model's
     own over its own sequence state.  Both take the lanes' engine `slots`
     (S,) where the sequence keeps state by slot (None for a model whose
-    state is the pool alone).  Returns (cache, hidden, experts visited:
-    0 for a model's own step, the routing if asked: `_paged_forward`)."""
+    state is the pool alone).  Returns (cache, hidden, experts visited,
+    the routing if asked: `_paged_forward`; the top-k choices that fell
+    on experts held here, None from a model that does not count them).
+    A model's own step that has experts (`n_experts`) takes `routing`
+    and returns all five itself; one without returns (cache, hidden)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
-        return _paged_forward(params, cache, tokens, block_tables, positions,
-                              kv_len, cfg, slots, routing)
+        return (*_paged_forward(params, cache, tokens, block_tables,
+                                positions, kv_len, cfg, slots, routing),
+                None)
+    if getattr(cfg, "n_experts", 0) > 0:
+        return own(params, cache, tokens, block_tables, positions, kv_len,
+                   slots, routing=routing)
     if routing:
         raise ValueError(f"{cfg.name!r} has no experts: no routing to give")
     return (*own(params, cache, tokens, block_tables, positions, kv_len,
-                 slots), jnp.int32(0), None)
+                 slots), jnp.int32(0), None, None)
+
+
+def counts_routed(cfg) -> bool:
+    """Whether the served programs of `cfg` hand out, as a last output,
+    the top-k choices that fell on experts held here: a model that holds
+    one rank's share of its experts (`experts_held`) does."""
+    return getattr(cfg, "experts_held", None) is not None
 
 
 def _served_logits(params, x, cfg):
@@ -401,7 +415,7 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     `routing` (a scoring entry's): also the experts each lane took in
     every layer, (L, S, top_k).
     """
-    cache, logits, _, taken = _paged_decode_logits(
+    cache, logits, _, taken, _ = _paged_decode_logits(
         params, cache, tokens, block_tables, lengths, active, cfg, slots,
         routing)
     return (cache, logits, taken[:, :, 0]) if routing else (cache, logits)
@@ -409,11 +423,13 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
 
 def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
                          active, cfg, slots, routing=False):
-    """`paged_decode_step` with the step's count of experts visited."""
-    cache, x, visited, taken = _served_forward(
+    """`paged_decode_step` with the step's count of experts visited and,
+    from a model that counts them, of top-k choices routed here."""
+    cache, x, visited, taken, routed = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
         jnp.where(active, lengths + 1, 0), cfg, slots, routing)
-    return cache, _served_logits(params, x, cfg)[:, 0], visited, taken
+    return (cache, _served_logits(params, x, cfg)[:, 0], visited, taken,
+            routed)
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
@@ -424,20 +440,27 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
     each active slot's table to cover lengths + n_steps before issuing.
     The pool is the step loop's carry too: n_steps in-place writes.
     Returns (cache, token_matrix (n_steps, S), rng, experts visited:
-    int32, summed over the steps and the layers)."""
+    int32, summed over the steps and the layers) and, from a model that
+    holds a share of its experts (`counts_routed`), the top-k choices
+    that fell on it as a fifth, summed likewise."""
+    counted = counts_routed(cfg)
 
     def tick(carry, _):
-        cache, toks, lengths, rng, visited = carry
-        cache, logits, n, _ = _paged_decode_logits(
+        cache, toks, lengths, rng, visited, routed = carry
+        cache, logits, n, _, r = _paged_decode_logits(
             params, cache, toks, block_tables, lengths, active, cfg, slots)
         rng, sub = jax.random.split(rng)
         nxt = sample_per_slot(logits, sub, temps)
         lengths = jnp.where(active, lengths + 1, lengths)
-        return (cache, nxt, lengths, rng, visited + n), nxt
+        return (cache, nxt, lengths, rng, visited + n,
+                routed + r if counted else None), nxt
 
-    (cache, _, _, rng, visited), toks = jax.lax.scan(
-        tick, (cache, tokens, lengths, rng, jnp.int32(0)), None,
+    (cache, _, _, rng, visited, routed), toks = jax.lax.scan(
+        tick, (cache, tokens, lengths, rng, jnp.int32(0),
+               jnp.int32(0) if counted else None), None,
         length=n_steps)
+    if counted:
+        return cache, toks, rng, visited, routed
     return cache, toks, rng, visited
 
 
@@ -460,7 +483,7 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     each position took in every layer, (L, C, top_k).
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x, _, taken = _served_forward(
+    cache, x, _, taken, routed = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
         (start + n_valid)[None], cfg,
         None if slot is None else jnp.asarray(slot, jnp.int32)[None],
@@ -471,7 +494,12 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     # A model with a head of its own: over the one position asked for.
     last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1,
                                         axis=1)
-    return cache, cfg.final_logits(params, last)[0, 0]
+    out = (cache, cfg.final_logits(params, last)[0, 0])
+    if routing:
+        out += (taken[:, 0],)
+    # From a model that counts them, last: the chunk's top-k choices
+    # that fell on experts held here (`_served_forward`).
+    return (*out, routed) if counts_routed(cfg) else out
 
 
 def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,

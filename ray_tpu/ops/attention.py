@@ -595,8 +595,13 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     return acc / jnp.where(l > 0, l, 1.0)[..., None]
 
 
-def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
+def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
+                    scale=None):
     """Attention of `q` (S, K, H, D) over one layer of a paged KV pool.
+    `scale` multiplies the scores (None: D ** -0.5; a model that
+    publishes another, such as 1 / D, hands it over: folded into a
+    bfloat16 `q` it would round every query once more unless it is a
+    power of two).
 
     `k_pool` / `v_pool` are the whole pool (L, N, block_size, Hkv, D), read
     at `[layer, block]` as stored: no slice of it is taken out and no copy in
@@ -620,7 +625,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
     s, k_w, h, d = q.shape
     hkv = k_pool.shape[3]
     qg = q.reshape(s, k_w, hkv, h // hkv, d)
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
     out = _paged_running_softmax(
         k_pool, v_pool, layer, block_tables, positions, kv_len,
         lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,

@@ -11,12 +11,18 @@ batch axes; dispatch (G, S, E, C); expert compute (E, G, C, d).
 
 That is `moe_mlp`, the train-time form.  Serving routes exactly and is
 bound by the bytes of expert weights it reads: `moe_mlp_dropless` visits
-only the experts that live tokens are routed to.
+only the experts that live tokens are routed to, and of those only the
+ones held here: `MoEConfig.held` names one rank's contiguous share of the
+experts (the router keeps its published width, the stacks hold the share,
+the result is the share's partial sum; no exchange and nothing in its
+place).  A shared expert that every token takes is not an expert of this
+loop: it is the model's own dense FFN, added to the routed sum by the
+model (`models/mamba2_moe.py`).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,18 @@ class MoEConfig:
     top_k: int = 2
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
+    # One rank's share of an expert-parallel layer: (first, count), the
+    # contiguous range of the `num_experts` that is held here.  The
+    # router stays `num_experts` wide; the weight stacks hold the `count`
+    # experts alone.  None: all of them (`moe_mlp_dropless`).
+    held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.held is not None:
+            first, count = self.held
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(f"held={self.held}: not a range of "
+                                 f"{self.num_experts} experts")
 
 
 def init_moe_params(rng, d_model: int, d_ff: int, cfg: MoEConfig, dtype):
@@ -138,9 +156,40 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # (a sort and one product a group) would win the 8 x and most of the 7 us;
 # that is a form of its own and a `perf_opt` PR's (ROADMAP R1), not a
 # repair of this one: at Mixtral's widths this loop is at the roofline.
+#
+# The same loop over one rank's share, 36 held of 72 experts, top-10
+# (granite-4.0-h-small's widths: three 4096 x 768 bf16 matrices an expert,
+# 18.9 MB; one period of ten layers, nine Mamba-2 and one attention, a
+# shared expert of width 1536 beside the routed ones; PR 36, the bare
+# served programs on a v5e, ms a decode step with the held experts read a
+# layer beside it / ms a chunk):
+#
+#   burst at ~300 / ~3,000 positions, width 4:
+#     1 live 6.73 / 6.72 (5.3 / 5.1)   2 live 7.90 / 7.97 (9.2 / 8.8)
+#     4 live 10.10 / 10.08 (16.5 / 16.5)
+#   width 8, all live 13.92 / 13.87 (24.9 / 24.2); width 16, all live
+#     19.28 / 19.86 (32.3 / 33.2)
+#   prefill chunk, seeded tokens (all 36 read), at position 0 / 1,920 /
+#   3,840: 256 tokens 21.9 / 22.0 / 22.1, 128 tokens 18.1 / 18.1 / 18.1,
+#   64 tokens 18.0, 32 tokens 16.4 / 16.2 / 16.5
+#
+# Held experts read follow 36 (1 - (62/72)^lanes) (5.0 / 9.3 / 16.2 / 25.1
+# / 32.7) to within 0.5: half of what the router chooses falls here
+# (`routed_here` / the choices made: 49.3% in the served cell).  One more
+# held expert a layer costs 30 us (10.10 - 6.73 ms over 11.2 experts x 10
+# layers) where its 18.9 MB take 23 us at 819 GB/s: 77%, between Mellum's
+# 69% at 12.4 MB an expert and Mixtral's 92% at 352 MB: the trip's own
+# ~7 us again.  A 256-token chunk makes 360 trips in some 15 of its 22 ms
+# (42 us a trip; `moe_chunk_roofline` 55% in the served cell's trace): at
+# 256 rows a trip also reads and writes the float32 accumulator (256 x 4096
+# x 4 B each way, 8 MB beside the 18.9 MB of weights) and multiplies all
+# 256 rows where 36 are routed to the expert.  Rows grouped by expert
+# would win both; still ROADMAP S11's, with the numbers above as its
+# baseline.
 def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
                      live: "jnp.ndarray | None" = None, layer=None,
-                     return_routing: bool = False):
+                     return_routing: bool = False,
+                     return_routed: bool = False):
     """Exact (dropless) top-k MoE for INFERENCE: every live token reaches
     all of its top-k experts, so the result is independent of how many
     other tokens share the batch — a cached decode step computes the same
@@ -150,7 +199,9 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     serving engines route exactly, ref: Mixtral inference).
 
     x (B, T, d); `live` (B,) bool says which lanes carry a real token
-    (None: all).  An idle lane's rows select no expert and come out zero.
+    (None: all), or (B, T) which rows do (a chunk's padded tail is then
+    routed nowhere).  An idle lane's rows select no expert and come out
+    zero.
     Returns (out (B, T, d), visited): `visited` (int32 scalar) is the
     number of distinct experts the live rows are routed to; with
     `return_routing` also the experts each row took, (B, T, top_k) int32
@@ -173,6 +224,19 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     expert (zero where it was not chosen).  An expert no live row chose
     is never read.  When every expert is hit (a prefill chunk) the loop
     is the dense form, expert by expert.
+
+    **One rank's share** (`cfg.held = (first, count)`): the weight
+    stacks hold experts first .. first + count - 1 of `num_experts` and
+    no others.  The router keeps all `num_experts` outputs and its
+    top-k; a choice that fell on an expert held elsewhere gets combine
+    weight zero here, the order and the loop run over the held experts
+    alone, and `visited` counts held experts hit.  What the absent
+    experts would add is left out: the result is this rank's partial
+    sum, and nothing stands in for the other ranks or their exchange.
+    With `return_routed` one more output, last: the top-k choices of
+    live rows that fell on held experts (int32; every live choice where
+    all are held).  `held=None` traces the very operations it traced
+    before there was a share.
     """
     b, t, d = x.shape
     dtype = x.dtype
@@ -185,7 +249,13 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
     chosen = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)  # (B,T,k,E)
     if live is not None:
-        chosen = chosen * live.astype(jnp.float32)[:, None, None, None]
+        mask = live.astype(jnp.float32)
+        chosen = chosen * (mask[:, None, None, None] if live.ndim == 1
+                           else mask[:, :, None, None])
+    if cfg.held is not None:
+        # This rank's columns: (B,T,k,count), in the stacks' own order.
+        first, e = cfg.held
+        chosen = chosen[..., first:first + e]
     # (N,E) combine weights: zero for unselected experts and idle rows
     w = jnp.sum(chosen * gate_vals[..., None], axis=2).reshape(b * t, e)
     hit = jnp.any(chosen.reshape(-1, e) > 0, axis=0)           # (E,)
@@ -215,7 +285,10 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     out = jax.lax.fori_loop(0, visited, visit,
                             jnp.zeros((b * t, d), jnp.float32))
     out = out.reshape(b, t, d).astype(dtype)
-    return (out, visited, expert_idx) if return_routing else (out, visited)
+    res = (out, visited, expert_idx) if return_routing else (out, visited)
+    if return_routed:
+        res += (jnp.sum(chosen).astype(jnp.int32),)
+    return res
 
 
 def moe_mlp(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,

@@ -108,9 +108,10 @@ class _Request:
 # One record of engine_stats()["tick_log"], in this order (the stats
 # carry the names as "tick_fields", so a reader needs no copy of them).
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
-               "lanes", "width", "prefill_tokens", "kv_read_tokens",
-               "reset_s", "experts_read", "ahead")
+               "lanes", "width", "prefill_tokens", "routed_here",
+               "kv_read_tokens", "reset_s", "experts_read", "ahead")
 _EXPERTS_READ = TICK_FIELDS.index("experts_read")
+_ROUTED_HERE = TICK_FIELDS.index("routed_here")
 
 
 class _TickAccounts:
@@ -119,13 +120,16 @@ class _TickAccounts:
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
-                 "experts_read", "ahead")
+                 "routed_at", "experts_read", "ahead")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
             self.experts_read = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
+        # Where the tick's `routed_here` is summed on the device
+        # (`_count_routed`); -1: the tick launched nothing that counts.
+        self.routed_at = -1
 
 
 class _Burst:
@@ -226,7 +230,11 @@ class PagedLLMEngine:
     are what each would need.  `recurrent`: state is zeroed at admission
     and preemption as above.  `speculation_k >= 2` is refused for
     either: a rejected draft has advanced a recurrence, and has written
-    ring rows that no test yet shows are never seen.
+    ring rows that no test yet shows are never seen.  A third, of a
+    model with experts: does it hold one rank's share of them
+    (`models.decoding.counts_routed`)?  Its chunk and burst then hand
+    out the top-k choices that fell on the share, and a tick's record
+    carries their sum (`routed_here`, `_count_routed`).
     """
     TICKS_KEPT = 4096
 
@@ -244,6 +252,7 @@ class PagedLLMEngine:
 
         from ray_tpu.core.config import get_config
         from ray_tpu.models.decoding import (
+            counts_routed,
             init_sequence_state,
             make_paged_engine_fns,
             make_paged_spec_fns,
@@ -365,6 +374,17 @@ class PagedLLMEngine:
         # experts visited is per layer and step over these.
         self._expert_layers = (cfg.n_layers
                                if getattr(cfg, "n_experts", 0) > 0 else 0)
+        # A model that holds one rank's share of its experts: its chunk
+        # and burst also hand out the top-k choices that fell on the
+        # share.  They are summed a tick on the device and read with the
+        # records, never inside a tick (`_count_routed`).
+        self._routed_sums = None
+        if counts_routed(cfg):
+            self._routed_sums = jnp.zeros((self.TICKS_KEPT + 8,), jnp.int32)
+            self._routed_next = 0
+            self._add_routed = jax.jit(
+                lambda sums, at, n, fresh: sums.at[at].set(
+                    jnp.where(fresh, 0, sums[at]) + n))
         self._prefill_chunk_fn, self._decode, self._copy_block = \
             make_paged_engine_fns(cfg)
         if self._spec_k:
@@ -542,7 +562,7 @@ class PagedLLMEngine:
                       "state_resets": s.pop("state_resets"),
                       "state_rebuilds": s.pop("state_rebuilds")}
         if records:
-            s["tick_log"] = _snapshot(self._tick_log)
+            s["tick_log"] = self._with_routed(_snapshot(self._tick_log))
             s["tick_fields"] = TICK_FIELDS
         s["queue_depth"] = len(self._pending)
         s["active"] = sum(1 for r in self._slots if r is not None)
@@ -568,10 +588,11 @@ class PagedLLMEngine:
                     jnp.asarray(z), jnp.zeros((w,), bool),
                     jnp.zeros((w,), jnp.float32), self._rng)
         for c in self._chunk_tiers:
-            self.cache, _ = self._prefill_chunk_fn(
+            self.cache, _, *routed = self._prefill_chunk_fn(
                 self.params, self.cache, jnp.zeros((c,), jnp.int32),
                 jnp.zeros((self._b_max,), jnp.int32), jnp.int32(0),
                 jnp.int32(0), **self._slot_kw(self.num_slots))
+            self._count_routed(routed)
 
     def gauges(self) -> Dict[str, float]:
         """Cheap autoscaling signals (riding the syncer push)."""
@@ -638,14 +659,45 @@ class PagedLLMEngine:
         slot.  Returns (token matrix, experts visited), on the device."""
         jnp = self._jnp
         slots = jnp.asarray(self._lane_slots(idx, width))
-        self.cache, tok_mat, self._rng, visited = self._decode(
+        self.cache, tok_mat, self._rng, visited, *routed = self._decode(
             self.params, self.cache,
             self._take_last(self._last_dev, slots, jnp.asarray(host_tok)),
             jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
             jnp.asarray(temps), self._rng, n_steps=self.max_burst,
             **({"slots": slots} if self._by_slot else {}))
         self._last_dev = self._put_last(self._last_dev, slots, tok_mat)
+        self._count_routed(routed)
         return tok_mat, visited
+
+    def _count_routed(self, routed) -> None:
+        """`routed`: what a chunk or a burst of a model that holds a
+        share of its experts handed out last (the top-k choices that
+        fell on the share; nothing from any other model).  Added, on the
+        device, to the sum of the tick that launched it: one small
+        launch and no read, so that a chunk stays queued behind the
+        host and a burst ahead of its read.  `_with_routed` reads the
+        sums when the records are asked for."""
+        if not routed:
+            return
+        acct = self._acct
+        fresh = acct.routed_at < 0
+        if fresh:
+            acct.routed_at = self._routed_next % self._routed_sums.shape[0]
+            self._routed_next += 1
+        self._routed_sums = self._add_routed(
+            self._routed_sums, self._jnp.int32(acct.routed_at), routed[0],
+            fresh)
+
+    def _with_routed(self, log: tuple) -> tuple:
+        """The tick log with each record's `routed_here` read from the
+        device's sums (0 where the tick counted none).  A record is
+        among the last TICKS_KEPT, so its sum has not been reused."""
+        if self._routed_sums is None or not log:
+            return log
+        sums = np.asarray(self._routed_sums)
+        at = _ROUTED_HERE
+        return tuple(t[:at] + (int(sums[t[at]]) if t[at] >= 0 else 0,)
+                     + t[at + 1:] for t in log)
 
     def _reset_slot_state(self, req: "_Request", slot: int) -> None:
         """Zero `slot`'s recurrent state: `req` was admitted to it, or
@@ -818,11 +870,12 @@ class PagedLLMEngine:
                 # The row is copied: on the CPU backend `jnp.asarray` of
                 # a view shares the host's memory with a program that has
                 # only been launched, and `_cow_tail` below rewrites it.
-                self.cache, last_logits = self._prefill_chunk_fn(
+                self.cache, last_logits, *routed = self._prefill_chunk_fn(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(self._tables[slot].copy()),
                     jnp.int32(req.pos), jnp.int32(nv),
                     **self._slot_kw(slot))
+                self._count_routed(routed)
                 req.pos += nv
                 budget -= nv
                 progressed = True
@@ -1192,7 +1245,12 @@ class PagedLLMEngine:
         tokens of the prompts this tick finished.  `lanes` of `width`:
         decoding lanes in the tier of the burst this tick launched (0
         of 0 for a tick that only read one).  `prefill_tokens`: prompt
-        tokens the chunks carried.  So tick_s - decode_s -
+        tokens the chunks carried.  `routed_here`: top-k choices of
+        those prompt rows and of the burst's lanes, over the burst's
+        steps and the expert layers, that fell on experts held here (a
+        model that holds one rank's share of its experts counts them; 0
+        from any other; the total is top_k x the rows x the layers).
+        So tick_s - decode_s -
         prefill_s - sample_s is the tick's time in which the host
         neither waited for the device nor launched: with a burst ahead
         it is no longer time the device stood still for.
@@ -1220,8 +1278,10 @@ class PagedLLMEngine:
         if progressed:
             row = [start, time.time() - start, acct.decode_s,
                    acct.prefill_s, acct.sample_s, acct.lanes, acct.width,
-                   acct.prefill_tokens, acct.kv_read_tokens, acct.reset_s,
-                   acct.experts_read, acct.ahead]
+                   acct.prefill_tokens,
+                   acct.routed_at if self._routed_sums is not None else 0,
+                   acct.kv_read_tokens, acct.reset_s, acct.experts_read,
+                   acct.ahead]
             b = self._inflight
             if b is not None and b.row is None:
                 b.row = row     # this tick's burst: logged at its read

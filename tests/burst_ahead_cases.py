@@ -63,7 +63,7 @@ def step_reference(e, prompt, n_tokens):
         toks = np.zeros((e.prefill_chunk,), np.int32)
         nv = min(e.prefill_chunk, len(prompt) - start)
         toks[:nv] = prompt[start:start + nv]
-        state, last = chunk(
+        state, last, *_ = chunk(
             e.params, state, jnp.asarray(toks), table, jnp.int32(start),
             jnp.int32(nv), **({"slot": jnp.int32(0)} if by_slot else {}))
     out = [int(jnp.argmax(last))]

@@ -524,3 +524,72 @@ def test_window_moe_served_programs_fit_one_chip(topo, program):
     assert len(products) >= 3, len(products)
     ring_ops = re.findall(r"= bf16\[[0-9,]*1152,4,128\]", compiled.as_text())
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_mamba2_moe_served_programs_fit_one_chip(topo, program):
+    """granite-4.0-h-small at the benchmark's cut (one period of ten
+    layers: nine Mamba-2, one attention; 36 of 72 experts, half the
+    vocabulary) and serving shape (16 slots x 8192, block 16: one layer's
+    pool 0.54 GB, nine layers' state 0.65 GB beside 9.51 GB of weights):
+    the width-16 burst and the 256-token chunk compile for one v5e chip
+    and fit its 15.75 GB usable.  Pool and state are updated in place
+    (their bytes are aliased) and the temporaries together stay under the
+    state array (0.64 GB) and under one layer's expert matrix stack (226
+    MB): nothing of the two is copied whole.  (Stored heads first, every
+    slot's state was copied into the compiler's layout and back a chunk,
+    0.65 GB of temporaries; stored as one (H * P, N) matrix the burst's
+    gather split all of it in four a layer, 0.71 GB.)  The expert products
+    read the held stacks in place.  **The chunk is not a scan over
+    positions**: none of its loops lies in the `ssd` scope (they are the
+    runs of layers, the expert visits and the paged attention's groups),
+    and the chunk's decay matrix [H, Q, Q] is there."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "granite-4.0-h-small-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    state = resident["sequence_state"]
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize
+        for s in jax.tree.leaves(resident["params"]))
+    assert state.k.shape == (1, 8193, 16, 8, 128)
+    assert state.h.shape == (9, 17, 64, 128, 128)
+    assert state.conv.shape == (9, 17, 3, 8448)
+    assert abs(resident_bytes - 10.70e9) < 0.01e9, resident_bytes
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    expert_stack = 36 * 4096 * 768 * 2
+    assert mem.temp_size_in_bytes < expert_stack, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    products = [
+        body for body in text.split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and fam.expert_operand(config).search(
+            body.lstrip().split("\n", 1)[0])]
+    assert len(products) >= 3, len(products)
+    loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
+    assert loops and not [name for name in loops if "/ssd" in name], loops
+    if program == "paged_prefill_chunk":
+        assert re.search(r"\[128,256,256\]", text)
+        assert fam.scan_operand(config).search(text)
+    else:
+        assert fam.state_operand(config).search(text)
