@@ -376,9 +376,12 @@ class Config:
     # prefix caching at block granularity). 0 disables: every request
     # prefills from scratch.
     kv_block_prefix_sharing: bool = True
-    # Prompt tokens admitted per engine tick during prefill: long
-    # prompts prefill in chunks interleaved with decode bursts so
-    # active streams' inter-token latency stays bounded.
+    # The unit and floor of the engine's prefill budget per tick, and
+    # the widest launch of a model that keeps state by slot (its rings
+    # and recurrence are laid out for it).  The budget itself is the
+    # engine's to choose (serve/llm.py `_prefill_budget`): long prompts
+    # prefill in launches interleaved with decode bursts so active
+    # streams' inter-token latency stays bounded.
     serve_prefill_chunk: int = 128
     # Per-request streaming token queue bound: a consumer that falls
     # this many tokens behind has its stream dropped with an explicit

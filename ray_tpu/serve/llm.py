@@ -165,15 +165,63 @@ def _snapshot(log: deque) -> tuple:
             continue
 
 
+# The rows one `paged_prefill_chunk` launch may carry, for a model whose
+# sequences keep only paged KV.  A launch streams every weight once,
+# whatever its rows: at 128 rows a bf16 weight does 128 multiply-adds
+# where a v5e balances at ~240 (197 TFLOP/s over 819 GB/s), so the launch
+# waits for memory and a 3,000-token prompt reads the model 24 times.
+# Measured (PR 37, one TPU v5e, the bare jitted program at published
+# widths, median of 7 launches each waited for: ms a launch, the host's
+# round trip of ~1.1 ms included; us a token beside it):
+#
+#          Mistral-7B widths, depth 8 (3.8 GB a launch)   Mixtral-8x7B, depth
+#   rows   start 0       2,048        3,584-3,840         3 (9.2 GB), start 0
+#     32    7.67 (240)    8.15 (255)   8.45 (264)         12.78 (399)
+#     64    7.78 (122)    8.22 (128)   8.51 (133)         13.72 (214)
+#    128    7.95 (62.1)   8.52 (66.6)  9.05 (70.7)        14.00 (109)
+#    256    8.91 (34.8)   9.77 (38.2) 10.72 (41.9)        15.96 (62.3)
+#    512   14.55 (28.4)  16.05 (31.4) 16.85 (32.9)        27.55 (53.8)
+#   1024   26.29 (25.7)  28.76 (28.1) 30.01 (29.3)        52.12 (50.9)
+#
+#   one decode burst of 8 steps at width 4, ms (a step): Mistral 1 lane at
+#   500 / 3,000 positions 47.1 (5.89) / 55.4 (6.92), 3-4 lanes the same;
+#   Mixtral 1 lane 35.6 (4.45) / 38.6 (4.83), 3-4 lanes 52.9-56.8
+#
+# (Mixtral's chunk multiplies every row by every expert that any row is
+# routed to, all 8 at start 0 as in traffic; at later starts, over a pool
+# of like rows, fewer were hit and a launch of 32-256 rows read 8-13 ms.)
+# (i) A token's time falls by 41-44% from 128 to 256 rows, by 14-21% from
+# 256 to 512, and by 4-8% of the device's own time from 512 to 1,024 (5-11%
+# with the round trip), in both shapes: past 512 rows it has stopped
+# falling (less than a tenth a doubling), while the launch a decoding lane
+# waits behind doubles.  (ii) Beside a burst a launch may not take the
+# device longer than the burst: a decode step is worth 83 rows of a
+# 512-row launch at Mixtral's widths (4.45 ms over 53.8 us) and 179 at
+# Mistral's (5.89 over 32.9), so 64 rows a step of the burst is safe in
+# both, and 512 rows beside 8 steps read 14.6-27.6 ms beside 35.6-55.4.
+_CHUNK_TOP_ROWS = 512   # (i) past it the device's time a token stops falling
+_ROWS_A_STEP = 64       # (ii) prompt rows that cost no more than a decode step
+
+
 class PagedLLMEngine:
     """Paged/block KV-cache engine: the one served engine.
 
     Engine tick: [admit waiting requests] -> [launch one fused decode
     burst over every DECODING slot] -> [read the burst launched a tick
-    ago, emit its tokens] -> [one prefill chunk for the oldest
-    PREFILLING slot].  Decode never waits for a whole prompt: a
-    max-length prompt occupies at most `prefill_chunk` tokens of device
-    time per tick, bounding the inter-token latency of active streams.
+    ago, emit its tokens] -> [one prefill launch for the oldest
+    PREFILLING slot, as wide as the tick's budget].  Decode never waits
+    for a whole prompt: a max-length prompt occupies one launch of
+    device time per tick, and beside a burst that launch is held under
+    the burst's own time (`_prefill_budget`), bounding the inter-token
+    latency of active streams.
+
+    `prefill_chunk` is the unit and the floor of that budget, and the
+    widest launch of a model that keeps state by slot (its rings hold
+    window + prefill_chunk rows, its recurrence takes one chunk of that
+    many positions).  A model whose sequences are pool blocks alone is
+    given wider tiers above it, in powers of two, up to the rows that
+    make a launch compute-bound: at `prefill_chunk` = 128 a launch
+    streams every weight to multiply 128 rows by it.
 
     The host runs one burst ahead of its own reads, never more: the
     next burst needs nothing the host has to read first.  Lengths,
@@ -282,6 +330,23 @@ class PagedLLMEngine:
         self.eos_id = eos_id
         self.max_burst = max(1, max_burst if eos_id is None else
                              min(max_burst, 4))
+        # A model whose sequences keep state by slot (window rings,
+        # recurrent state), and whether some of it is recurrent.
+        self._by_slot = bool(getattr(cfg, "state_by_slot", False))
+        self._recurrent = bool(getattr(cfg, "recurrent", False))
+        if not self._by_slot:
+            # Pool blocks alone: a launch is generic in its rows, so the
+            # tiers go on above `prefill_chunk` (no prompt reaches
+            # max_len rows).  State by slot was laid out for
+            # `prefill_chunk` rows a launch and stops there.
+            wide = 2 * self.prefill_chunk
+            while wide <= min(_CHUNK_TOP_ROWS, max_len):
+                self._chunk_tiers.append(wide)
+                wide *= 2
+        # The widest launch beside a decode burst: see _prefill_budget.
+        beside = max(self.prefill_chunk, _ROWS_A_STEP * self.max_burst)
+        self._chunk_beside_burst = max(
+            t for t in self._chunk_tiers if t <= beside)
         # Prompt-lookup speculative decoding on the paged pool (opt-in,
         # knob-defaulted): each tick verifies K candidates per slot in
         # one width-K call; drafts come from n-gram matches in the
@@ -292,10 +357,6 @@ class PagedLLMEngine:
         if speculation_ngram is None:
             speculation_ngram = knobs.serve_speculation_ngram
         self._spec_k = speculation_k if speculation_k >= 2 else 0
-        # A model whose sequences keep state by slot (window rings,
-        # recurrent state), and whether some of it is recurrent.
-        self._by_slot = bool(getattr(cfg, "state_by_slot", False))
-        self._recurrent = bool(getattr(cfg, "recurrent", False))
         if self._by_slot and self._spec_k:
             raise ValueError(
                 f"speculation_k={speculation_k} with {cfg.name!r}: a "
@@ -428,6 +489,9 @@ class PagedLLMEngine:
                       "completed": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefill_chunks": 0, "bursts_ahead": 0,
+                      # prompt tokens carried, by the launch's rows
+                      "prefill_launch_tokens": dict.fromkeys(
+                          self._chunk_tiers, 0),
                       "queue_waits": 0,
                       "preemptions": 0, "adopted_blocks": 0,
                       "migrated_blocks": 0, "migrate_fallbacks": 0,
@@ -549,6 +613,7 @@ class PagedLLMEngine:
         logs and what is computed from them.  The replica's gauge loop
         asks every second and reads counters only: it passes False."""
         s = dict(self.stats)
+        s["prefill_launch_tokens"] = dict(s["prefill_launch_tokens"])
         if records:
             phases = s["request_phases"] = _snapshot(self._request_phases)
             s["p_ttft_mean"] = (
@@ -823,16 +888,32 @@ class PagedLLMEngine:
                 pass
         req.done.set()
 
+    def _prefill_budget(self) -> int:
+        """Prompt tokens this tick's prefill launches may carry.  (i)
+        With nobody decoding: the widest chunk tier, the rows past which
+        the device's time a token stops falling (`_CHUNK_TOP_ROWS`; a
+        model with state by slot has no tier above `prefill_chunk`).
+        (ii) Beside a burst (this tick launched one): no more rows than
+        take the device as long as the burst does, `_ROWS_A_STEP` a
+        step of it -- the ITL bound, in the one form the host can
+        check without waiting for the device -- and never under
+        `prefill_chunk`, the budget's unit and floor."""
+        if self._acct.lanes:
+            return self._chunk_beside_burst
+        return self._chunk_tiers[-1]
+
     def _prefill_tick(self) -> bool:
-        """Prefill chunks in FIFO order under a TOKEN budget of
-        `prefill_chunk` per engine tick: a max-length prompt consumes
-        the whole budget in one wide chunk (then yields the device back
-        to decode — the ITL bound), while a tickful of short prompts
-        batches several narrow chunks into the same budget (admission
-        isn't serialized to one prompt per tick)."""
+        """Prefill launches in FIFO order under the tick's TOKEN budget
+        (`_prefill_budget`): the prompt at the head of the queue gets
+        one launch of the widest tier the budget allows, so a long
+        prompt consumes the whole budget in one wide launch (then
+        yields the device back to decode -- the ITL bound), while a
+        tickful of short prompts batches several narrow launches into
+        the same budget (admission isn't serialized to one prompt per
+        tick)."""
         import jax.numpy as jnp
 
-        budget = self.prefill_chunk
+        budget = self._prefill_budget()
         progressed = False
         while self._prefillq and budget > 0:
             slot = self._prefillq[0]
@@ -880,8 +961,9 @@ class PagedLLMEngine:
                 budget -= nv
                 progressed = True
                 self.stats["prefill_chunks"] += 1
+                self.stats["prefill_launch_tokens"][c] += nv
                 acct = self._acct
-                acct.prefill_s += self._obs_prefill(req, t0, nv)
+                acct.prefill_s += self._obs_prefill(req, t0, nv, c)
                 acct.prefill_tokens += nv
                 if req.pos >= n:
                     self._prefillq.popleft()
@@ -1303,8 +1385,9 @@ class PagedLLMEngine:
         reference.  Each row of `seqs` (lanes, n_prompt + steps) gets a
         slot and blocks of its own: its first `n_prompt` tokens are
         prefilled through the engine's jitted chunk program, in the
-        engine's chunks and chunk tiers (so a last chunk is padded as a
-        served one is), then the rest is teacher-forced, all lanes a
+        launches an idle engine's tick would use (its widest chunk tier,
+        then the last launch padded to its tier as a served one is),
+        then the rest is teacher-forced, all lanes a
         step, through `paged_decode_step` (the function the burst scans;
         the burst itself returns sampled tokens, never logits) at the
         engine's width tier, every kind of sequence state included.
@@ -1339,6 +1422,7 @@ class PagedLLMEngine:
                     donate_argnums=(1,), static_argnames=("routing",))
             chunk_fn, route_kw = self._score_chunk, {"routing": True}
         per_lane = math.ceil(total / self.block_size)
+        top = self._chunk_tiers[-1]     # an idle engine's budget
         got: List[List[Any]] = [[] for _ in range(lanes)]
         taken: List[List[Any]] = [[] for _ in range(lanes)]
         with self._tick_lock:
@@ -1358,8 +1442,8 @@ class PagedLLMEngine:
                     if self._recurrent:
                         self.cache = self._reset_state(self.cache,
                                                        jnp.int32(lane))
-                    for start in range(0, n_prompt, self.prefill_chunk):
-                        nv = min(self.prefill_chunk, n_prompt - start)
+                    for start in range(0, n_prompt, top):
+                        nv = min(top, n_prompt - start)
                         toks = np.zeros(
                             (self._tier_for(self._chunk_tiers, nv),),
                             np.int32)
@@ -1527,8 +1611,9 @@ class PagedLLMEngine:
                                     now - req.submitted_at)
 
     def _obs_prefill(self, req: "_Request", t0: float,
-                     n_tokens: int) -> float:
-        """One prefill chunk launched at `t0`; returns its wall time.
+                     n_tokens: int, rows: int) -> float:
+        """One prefill chunk of `n_tokens` prompt tokens in a launch of
+        `rows` (its tier) launched at `t0`; returns its wall time.
         A request's first chunk ends its prefill_wait and opens its
         `serve.engine.prefill` span, the parent of every chunk up to the
         first token.  A preempted request's re-prefill comes after its
@@ -1556,8 +1641,8 @@ class PagedLLMEngine:
             req.chunk_tokens += n_tokens
             ctx = tracing.child_ctx(ctx, req.prefill_span)
         tracing.record_serve_span(ctx, "serve.engine.prefill_chunk",
-                                  t0, t1, tokens=n_tokens, pos=req.pos,
-                                  **attrs)
+                                  t0, t1, tokens=n_tokens, rows=rows,
+                                  pos=req.pos, **attrs)
         observability.observe_phase(app, "prefill", t1 - t0)
         return t1 - t0
 

@@ -289,13 +289,23 @@ def _tiny_paged(model="tiny", **kw):
     from ray_tpu.models import configs, init_params
     from ray_tpu.serve.llm import PagedLLMEngine
 
+    from ray_tpu.serve import llm
+
     cfg = configs.get(model)
     kw.setdefault("num_slots", 8)
     kw.setdefault("max_len", 64)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", _CHUNK)
     kw.setdefault("prefix_sharing", False)
-    return PagedLLMEngine(cfg, init_params(jax.random.key(0), cfg), **kw)
+    # These tests count a prompt's chunks: an engine whose tiers stop at
+    # `prefill_chunk`, so that a prompt is several launches (the wider
+    # tiers are tests/test_prefill_budget.py's).
+    top, llm._CHUNK_TOP_ROWS = llm._CHUNK_TOP_ROWS, 0
+    try:
+        return PagedLLMEngine(cfg, init_params(jax.random.key(0), cfg),
+                              **kw)
+    finally:
+        llm._CHUNK_TOP_ROWS = top
 
 
 def _generate_together(eng, prompts, traces, max_tokens=6):
