@@ -1,0 +1,3 @@
+"""`tpot_first_read_share`: bench/harness/decode_records.py `decode_share` with the
+arguments of tpot_first_read_share.json."""
+from bench.harness.decode_records import decode_share as read  # noqa: F401

@@ -392,13 +392,32 @@ def cmd_metrics(gcs: _Gcs, args) -> None:
             print(f"# unreachable: {e}")
 
 
+def _phase_shares(seconds) -> str:
+    """An engine's `phase_seconds` (its loop thread's seconds by leaf
+    phase since it began) as shares of the whole, and beside them how
+    much of its time with work to do went to the host: every leaf but
+    `wait` (no work) and the two reads (the device's turn)."""
+    total = sum((seconds or {}).values())
+    if not total:
+        return ""
+    out = "  ".join(f"{k}={100 * v / total:.1f}%"
+                    for k, v in seconds.items() if v)
+    device = seconds.get("burst_read", 0.0) + seconds.get("first_read", 0.0)
+    working = total - seconds.get("wait", 0.0)
+    if working > 0:
+        out += f"  (host-bound {100 * (working - device) / working:.1f}%)"
+    return out
+
+
 def cmd_serve(gcs: _Gcs, args) -> None:
     """Serving-plane observability (`ray-tpu serve status|trace`):
     status renders the GCS rollup (per-app autoscaling gauges + the
     TTFT/ITL/phase means and counter totals mined from the federated
-    serve metrics); trace dumps ONE request's end-to-end span track
-    (proxy -> handle -> replica -> engine, resumed hops on their own
-    rows) as a perfetto/chrome trace."""
+    serve metrics, and per replica its engine thread's seconds by leaf
+    phase as shares: `phase_seconds` of the engine's stats, riding the
+    replica's state push); trace dumps ONE request's end-to-end span
+    track (proxy -> handle -> replica -> engine, resumed hops on their
+    own rows) as a perfetto/chrome trace."""
     if args.serve_cmd == "trace":
         from ray_tpu.util.timeline import request_chrome_trace
 
@@ -453,6 +472,9 @@ def cmd_serve(gcs: _Gcs, args) -> None:
                 parts.append(
                     f"spec_accept={100 * ent['spec_accept_rate']:.0f}%")
             print(f"    replica {rid}: " + "  ".join(parts))
+            shares = _phase_shares(ent.get("phase_seconds"))
+            if shares:
+                print(f"      engine thread: {shares}")
         lat = latency.get(app) or {}
         line = []
         if "ttft_mean_s" in lat:
@@ -1025,8 +1047,19 @@ def main(argv: Optional[List[str]] = None) -> None:
                       "KV rollup (status) and per-request span traces "
                       "(trace <request-id>)")
     ssub = svp.add_subparsers(dest="serve_cmd", required=True)
-    ssub.add_parser("status")
-    stp = ssub.add_parser("trace")
+    ssub.add_parser(
+        "status", help="per app: gauges, latency and phase means, "
+                       "counters; per replica: role, rails, and its "
+                       "engine thread's time by leaf phase (wait, admit, "
+                       "burst_launch, burst_read, emit, chunk_launch, "
+                       "first_read, book) as shares, with the share of "
+                       "its working time that the host, not the device, "
+                       "took")
+    stp = ssub.add_parser(
+        "trace", help="one request's spans as a chrome trace; its "
+                      "serve.engine.decode span carries what the engine "
+                      "thread did while it decoded (burst_read_s, "
+                      "first_read_s, host_s, lanes_seen)")
     stp.add_argument("request_id", help="request id (== trace id; the "
                                         "X-Request-Id header value)")
     stp.add_argument("-o", "--out", default=None,

@@ -40,7 +40,8 @@ class _Request:
                  "prefill_at", "first_token_at", "prefill_span", "chunks",
                  "chunk_s", "chunk_tokens", "token_q", "dropped", "blocks",
                  "pos", "prefilling", "no_register", "trace",
-                 "last_emit_wall", "ahead")
+                 "last_emit_wall", "ahead", "record", "decode_from",
+                 "decode_span", "lanes_sum", "bursts")
 
     def __init__(self, prompt, max_tokens, temperature, stream=False):
         from ray_tpu.core.config import get_config
@@ -87,6 +88,14 @@ class _Request:
         # Tokens of this request in a burst that is launched and not yet
         # read: the host has counted them, only the device holds them.
         self.ahead = 0
+        # From the first token on: the request's `request_phases` record
+        # (completed at its end), the phase clock's reading at the first
+        # token, the open `serve.engine.decode` span (None when tracing
+        # is off), and the lanes of the bursts it was read from, summed.
+        self.record: Optional[dict] = None
+        self.decode_from: Optional[List[float]] = None
+        self.decode_span: Optional[tracing.Span] = None
+        self.lanes_sum = self.bursts = 0
         # Resumed contexts embed generated tokens in `prompt` — never
         # publish them as a reusable prompt prefix.
         self.no_register = False
@@ -109,7 +118,11 @@ class _Request:
 # carry the names as "tick_fields", so a reader needs no copy of them).
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "routed_here",
-               "kv_read_tokens", "reset_s", "experts_read", "ahead")
+               "kv_read_tokens", "reset_s", "experts_read", "ahead",
+               "starved_s")
+# What a request's record gains at its end (None until then).
+_DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
+                "host_s", "lanes_seen")
 _EXPERTS_READ = TICK_FIELDS.index("experts_read")
 _ROUTED_HERE = TICK_FIELDS.index("routed_here")
 
@@ -120,11 +133,11 @@ class _TickAccounts:
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
-                 "routed_at", "experts_read", "ahead")
+                 "routed_at", "experts_read", "ahead", "starved_s")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
-            self.experts_read = 0.0
+            self.experts_read = self.starved_s = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
         # Where the tick's `routed_here` is summed on the device
@@ -137,14 +150,87 @@ class _Burst:
     read needs: the token matrix and the count of experts visited (both
     still on the device), lane by lane the request, the tokens the host
     counted for it at the launch and whether they are its last, the
-    launch's time, and the record of the tick that launched it, which
-    enters the tick log at the read, when `experts_read` is known."""
-    __slots__ = ("tok_mat", "visited", "lanes", "t0", "row")
+    launch's time and its number among the engine's launches, and the
+    record of the tick that launched it, which enters the tick log at
+    the read, when `experts_read` is known."""
+    __slots__ = ("tok_mat", "visited", "lanes", "t0", "seq", "row")
 
-    def __init__(self, tok_mat, visited, lanes, t0):
+    def __init__(self, tok_mat, visited, lanes, t0, seq):
         self.tok_mat, self.visited = tok_mat, visited
-        self.lanes, self.t0 = lanes, t0
+        self.lanes, self.t0, self.seq = lanes, t0, seq
         self.row: Optional[list] = None
+
+
+# The leaf phases of the engine's loop thread, in the order of
+# engine_stats()["phase_seconds"] (see _PhaseClock).
+PHASES = ("wait", "admit", "burst_launch", "burst_read", "emit",
+          "chunk_launch", "first_read", "book")
+(_WAIT, _ADMIT, _BURST_LAUNCH, _BURST_READ, _EMIT, _CHUNK_LAUNCH,
+ _FIRST_READ, _BOOK) = range(len(PHASES))
+
+
+class _PhaseClock:
+    """Where the engine's loop thread spends its time.  The thread is at
+    every moment in exactly one leaf of PHASES; `enter` credits the time
+    since the last switch to the leaf that ends.  A leaf is at once an
+    entry of `seconds` (cumulative, on time.time(), the clock of the
+    records and the spans) and a `jax.profiler.TraceAnnotation` named
+    `serve.engine.phase.<leaf>`: an event on the device trace's clock
+    whenever anyone profiles the process, one check of an atomic when
+    nobody does.  The leaves are flat: the open annotation is closed
+    before the next is opened, so the seconds sum to the thread's wall
+    time and a reduction that charges an idle gap to every span over it
+    charges it once.
+
+      wait          `_work.wait` with nothing to do, and taking the tick
+                    lock (another thread holds it: score, warmup, ...)
+      admit         the `_admit_one` loop
+      burst_launch  `_decode_tick` up to the read: blocks, lane arrays,
+                    the dispatch of the burst (or the verify window),
+                    the host's books of the launch
+      burst_read    the blocking read of a burst's tokens
+      emit          tokens to their requests: the loop over a read
+                    burst's lanes, a first token's `_begin_decode`,
+                    `_finish`
+      chunk_launch  `_prefill_tick` up to and including the chunk's
+                    dispatch and `_obs_prefill`
+      first_read    a finished prompt's `_sample_one` and `int(tok)`
+      book          the rest of a tick: prefix registration, the tail
+                    block's copy, preemption, the tick's record
+
+    The clock moves only inside `_tick` (`ticking`): what another thread
+    does under the tick lock (`score`, `warmup`, `import_prefix`,
+    `export_streams`, `shutdown`'s last read) switches nothing, and the
+    loop thread is in `wait` meanwhile."""
+    __slots__ = ("seconds", "leaf", "since", "ticking", "note", "_open")
+
+    def __init__(self, note):
+        self.seconds = [0.0] * len(PHASES)
+        self.leaf, self.since, self.ticking = _WAIT, time.time(), False
+        self.note, self._open = note, None
+
+    def enter(self, leaf: int) -> float:
+        """Switch to `leaf`; returns the time of the switch."""
+        now = time.time()
+        if not self.ticking:
+            return now
+        self.seconds[self.leaf] += now - self.since
+        self.since = now
+        if leaf != self.leaf:
+            if self._open is not None:
+                self._open.__exit__(None, None, None)
+            self._open = self.note("serve.engine.phase." + PHASES[leaf])
+            self._open.__enter__()
+            self.leaf = leaf
+        return now
+
+    def read(self, now: float) -> List[float]:
+        """The seconds by leaf as at `now`: the running leaf's part
+        included, so two readings differ by exactly the time between
+        them."""
+        out = list(self.seconds)
+        out[self.leaf] += now - self.since
+        return out
 
 
 def _slot_state(cfg) -> str:
@@ -488,7 +574,7 @@ class PagedLLMEngine:
         self.stats = {"requests": 0, "tokens_generated": 0,
                       "completed": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
-                      "prefill_chunks": 0, "bursts_ahead": 0,
+                      "prefill_chunks": 0,
                       # prompt tokens carried, by the launch's rows
                       "prefill_launch_tokens": dict.fromkeys(
                           self._chunk_tiers, 0),
@@ -507,6 +593,14 @@ class PagedLLMEngine:
         # median where a counter gives a mean.
         self._tick_log: deque = deque(maxlen=self.TICKS_KEPT)
         self._acct = _TickAccounts()
+        self._clock = _PhaseClock(jax.profiler.TraceAnnotation)
+        # What the host knows of the device's queue: the programs it has
+        # launched, counted, and since when the queue is known to be
+        # empty (0.0: not known).  A blocking read of the newest
+        # launch's result returns to an empty queue; the next launch
+        # ends the stretch, summed a tick as `starved_s` (_mark_launch).
+        self._launches = 0
+        self._idle_from = 0.0
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
@@ -615,10 +709,11 @@ class PagedLLMEngine:
         s = dict(self.stats)
         s["prefill_launch_tokens"] = dict(s["prefill_launch_tokens"])
         if records:
-            phases = s["request_phases"] = _snapshot(self._request_phases)
-            s["p_ttft_mean"] = (
-                sum(r["ttft_s"] for r in phases) / len(phases)
-                if phases else None)
+            s["request_phases"] = _snapshot(self._request_phases)
+        # Seconds of the loop thread by leaf phase since the engine
+        # began, the running leaf's part included.
+        s["phase_seconds"] = dict(zip(
+            PHASES, self._clock.read(time.time())))
         s.update(self.allocator.snapshot())
         # Resident bytes of the sequences' state by kind, and how often
         # recurrent state was zeroed (admissions and preemptions) and
@@ -724,6 +819,7 @@ class PagedLLMEngine:
         slot.  Returns (token matrix, experts visited), on the device."""
         jnp = self._jnp
         slots = jnp.asarray(self._lane_slots(idx, width))
+        self._mark_launch()
         self.cache, tok_mat, self._rng, visited, *routed = self._decode(
             self.params, self.cache,
             self._take_last(self._last_dev, slots, jnp.asarray(host_tok)),
@@ -733,6 +829,16 @@ class PagedLLMEngine:
         self._last_dev = self._put_last(self._last_dev, slots, tok_mat)
         self._count_routed(routed)
         return tok_mat, visited
+
+    def _mark_launch(self) -> None:
+        """A burst, a verify window or a prefill chunk is about to be
+        dispatched.  If the device's queue was known to be empty, it was
+        so since `_idle_from`, with work in the engine's hands: the
+        stretch goes to the tick's `starved_s`."""
+        self._launches += 1
+        if self._idle_from:
+            self._acct.starved_s += time.time() - self._idle_from
+            self._idle_from = 0.0
 
     def _count_routed(self, routed) -> None:
         """`routed`: what a chunk or a burst of a model that holds a
@@ -829,13 +935,18 @@ class PagedLLMEngine:
             # last-logits under THIS request's temperature — no prompt
             # forward at all.  COW the (shared) partial tail before
             # decode appends into it.
+            clock = self._clock
             try:
                 self._cow_tail(req)
+                clock.enter(_FIRST_READ)
                 tok, self._rng = self._sample_one(
                     meta, jnp.float32(req.temperature), self._rng)
-                self._begin_decode(req, int(tok))
+                tok = int(tok)
+                self._idle_from = clock.enter(_EMIT)
+                self._begin_decode(req, tok)
             except BaseException as e:  # noqa: BLE001
                 self._fail_request(req, e)
+            clock.enter(_ADMIT)
             return True
         if covered == 0:
             self.stats["prefix_misses"] += 1
@@ -913,6 +1024,8 @@ class PagedLLMEngine:
         tick)."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        clock.enter(_CHUNK_LAUNCH)
         budget = self._prefill_budget()
         progressed = False
         while self._prefillq and budget > 0:
@@ -922,7 +1035,7 @@ class PagedLLMEngine:
                 self._prefillq.popleft()
                 continue
             try:
-                t0 = time.time()
+                t0 = clock.enter(_CHUNK_LAUNCH)
                 # Preempted requests re-prefill their WHOLE context —
                 # prompt plus the tokens already emitted (the stream
                 # keeps every token; only the KV is recomputed).
@@ -951,6 +1064,7 @@ class PagedLLMEngine:
                 # The row is copied: on the CPU backend `jnp.asarray` of
                 # a view shares the host's memory with a program that has
                 # only been launched, and `_cow_tail` below rewrites it.
+                self._mark_launch()
                 self.cache, last_logits, *routed = self._prefill_chunk_fn(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(self._tables[slot].copy()),
@@ -966,6 +1080,7 @@ class PagedLLMEngine:
                 acct.prefill_s += self._obs_prefill(req, t0, nv, c)
                 acct.prefill_tokens += nv
                 if req.pos >= n:
+                    clock.enter(_BOOK)
                     self._prefillq.popleft()
                     if self._recurrent and req.out_tokens:
                         self.stats["state_rebuilds"] += 1
@@ -978,15 +1093,18 @@ class PagedLLMEngine:
                         self.allocator.register_prefix(
                             req.prompt, req.blocks, meta=last_logits)
                     self._cow_tail(req, n)
-                    t_sample = time.time()
+                    t_sample = clock.enter(_FIRST_READ)
                     tok, self._rng = self._sample_one(
                         last_logits, jnp.float32(req.temperature),
                         self._rng)
                     # The host's read of the token waits for every
                     # chunk still queued on the device: launches return
-                    # long before their chunks have run.
+                    # long before their chunks have run.  The sampler
+                    # was the newest launch: the read returns to an
+                    # empty queue.
                     tok = int(tok)
-                    acct.sample_s += time.time() - t_sample
+                    self._idle_from = clock.enter(_EMIT)
+                    acct.sample_s += self._idle_from - t_sample
                     self._begin_decode(req, tok)
             except BaseException as e:  # noqa: BLE001
                 if self._prefillq and self._prefillq[0] == slot:
@@ -1011,6 +1129,7 @@ class PagedLLMEngine:
     def _decode_tick(self) -> bool:
         """Launch the next burst over the decoding slots, then read the
         one launched a tick ago (see the class docstring)."""
+        self._clock.enter(_BURST_LAUNCH)
         burst = self.max_burst
         # One tick advances either a burst (burst tokens of KV) or a
         # spec window (K tokens of KV); cover whichever is larger so
@@ -1097,10 +1216,9 @@ class PagedLLMEngine:
                 lanes.append((req, n, last))
             self._inflight = _Burst(
                 tok_mat, visited if self._expert_layers else None, lanes,
-                t0)
+                t0, self._launches)
             if prev is not None:
                 self._acct.ahead = 1
-                self.stats["bursts_ahead"] += 1
                 self._harvest(prev)
             if self._spec_k:
                 self._drain()       # drafts come from the emitted context
@@ -1111,7 +1229,7 @@ class PagedLLMEngine:
     def _harvest(self, b: "_Burst") -> None:
         """Read a launched burst's tokens and emit them, each lane's to
         the request that was in it at the launch."""
-        t0 = time.time()
+        t0 = self._clock.enter(_BURST_READ)
         if b.visited is not None:
             # Tokens and count, both copies started before either is
             # waited for: a second read after the first costs 0.6 ms.
@@ -1119,7 +1237,9 @@ class PagedLLMEngine:
             experts = int(visited) / (self.max_burst * self._expert_layers)
         else:
             tok_mat, experts = np.asarray(b.tok_mat), 0.0   # (burst, w)
-        t1 = time.time()
+        t1 = self._clock.enter(_EMIT)
+        if b.seq == self._launches and self._clock.ticking:
+            self._idle_from = t1    # nothing was launched behind it
         self._acct.decode_s += t1 - t0
         if b.row is None:           # read in the tick that launched it
             self._acct.experts_read = experts
@@ -1134,6 +1254,8 @@ class PagedLLMEngine:
                 continue    # ended at an earlier read: EOS, a dropped
                 #             stream; these are the burst ahead's tokens
             req.ahead -= n
+            req.lanes_sum += len(b.lanes)
+            req.bursts += 1
             n0, eos = len(req.out_tokens), False
             for tok in tok_mat[:n, j].tolist():
                 req.emit(tok)
@@ -1218,13 +1340,16 @@ class PagedLLMEngine:
         # exceed proposed.
         self.stats["spec_proposed"] += (k - 1) * greedy_active
         t0 = time.time()
+        self._mark_launch()
         self.cache, tok_out, accepted, self._rng = self._verify(
             self.params, self.cache, jnp.asarray(cand),
             jnp.asarray(tables), jnp.asarray(lengths),
             jnp.asarray(active), jnp.asarray(temps), self._rng)
+        self._clock.enter(_BURST_READ)
         tok_out = np.asarray(tok_out)              # (w, k)
         accepted = np.asarray(accepted)            # (w,)
-        t1 = time.time()
+        # The window was the newest launch: the queue is empty.
+        t1 = self._idle_from = self._clock.enter(_EMIT)
         self._acct.decode_s = t1 - t0
         for j, i in enumerate(idx):
             req = self._slots[i]
@@ -1235,6 +1360,8 @@ class PagedLLMEngine:
             # paged masks (kv_pos <= position) treat the stale tail as
             # garbage and the next decode overwrites it in place.
             self._lengths[i] += a + 1
+            req.lanes_sum += len(idx)
+            req.bursts += 1
             n0 = len(req.out_tokens)
             for tok in tok_out[j, :a + 1]:
                 tok = int(tok)
@@ -1257,6 +1384,7 @@ class PagedLLMEngine:
         # Its re-prefill is over prompt + emitted: every token of it
         # that the device holds is read first.
         self._drain()
+        self._clock.enter(_BOOK)
         if self._slots[slot] is not req:
             return                  # that read ended it
         self.allocator.free(req.blocks)
@@ -1304,6 +1432,8 @@ class PagedLLMEngine:
     def _finish_request(self, req: "_Request") -> None:
         """Complete one request: stats + stream sentinel + done event."""
         self.stats["completed"] += 1
+        if req.record is not None:
+            self._obs_decode_end(req)
         if req.token_q is not None:
             try:
                 req.token_q.put_nowait(None)  # stream sentinel
@@ -1348,27 +1478,56 @@ class PagedLLMEngine:
         without a burst).  `ahead`: 1 where the tick launched its burst
         while the one before it was still unread, so that the device had
         it queued behind that one; 0 for a busy period's first burst and
-        for a tick without a burst (`stats["bursts_ahead"]` sums it)."""
-        start = time.time()
+        for a tick without a burst.
+        `starved_s`: the part of the tick (and of the wait before it) in
+        which the host knew the device's queue to be empty while the
+        engine held work: from the return of a blocking read of the
+        newest launch's result (a finished prompt's first token, a
+        burst with nothing launched behind it) to the dispatch of the
+        next burst or chunk, or to the tick's end.  The lower bound of
+        the device's idle time that the host causes: what the device
+        idled before such a read returned only a trace can show.
+
+        The tick runs on the phase clock (`_PhaseClock`): it starts in
+        `admit`, its parts switch the leaf as they go, and it ends in
+        `wait`, in which the loop stays until its next tick."""
+        clock = self._clock
+        clock.ticking = True
+        start = clock.enter(_ADMIT)
         acct = self._acct = _TickAccounts()
         progressed = False
-        # Admit as many waiting requests as slots + blocks allow.
-        while self._admit_one():
-            progressed = True
-        progressed |= self._decode_tick()
-        progressed |= self._prefill_tick()
-        if progressed:
-            row = [start, time.time() - start, acct.decode_s,
-                   acct.prefill_s, acct.sample_s, acct.lanes, acct.width,
-                   acct.prefill_tokens,
-                   acct.routed_at if self._routed_sums is not None else 0,
-                   acct.kv_read_tokens, acct.reset_s, acct.experts_read,
-                   acct.ahead]
-            b = self._inflight
-            if b is not None and b.row is None:
-                b.row = row     # this tick's burst: logged at its read
-            else:
-                self._tick_log.append(tuple(row))
+        try:
+            # Admit as many waiting requests as slots + blocks allow.
+            while self._admit_one():
+                progressed = True
+            progressed |= self._decode_tick()
+            progressed |= self._prefill_tick()
+            end = clock.enter(_BOOK)
+            if self._idle_from:
+                # Starved up to here and on into the next tick, if the
+                # engine still holds work; else idle for want of it.
+                if self._pending or any(r is not None
+                                        for r in self._slots):
+                    acct.starved_s += end - self._idle_from
+                    self._idle_from = end
+                else:
+                    self._idle_from = 0.0
+            if progressed:
+                row = [start, end - start, acct.decode_s,
+                       acct.prefill_s, acct.sample_s, acct.lanes,
+                       acct.width, acct.prefill_tokens,
+                       acct.routed_at if self._routed_sums is not None
+                       else 0,
+                       acct.kv_read_tokens, acct.reset_s,
+                       acct.experts_read, acct.ahead, acct.starved_s]
+                b = self._inflight
+                if b is not None and b.row is None:
+                    b.row = row     # this tick's burst: logged at its read
+                else:
+                    self._tick_log.append(tuple(row))
+        finally:
+            clock.enter(_WAIT)
+            clock.ticking = False
         return progressed
 
     def _loop(self):
@@ -1582,7 +1741,7 @@ class PagedLLMEngine:
     # (bench, unit tests) report under "-".
     _app_hint = "-"
     # engine_stats()["request_phases"]: the last requests that got a
-    # first token, one dict each (see _obs_first_token).
+    # first token, one dict each (see _obs_first_token, _obs_decode_end).
     REQUEST_PHASES_KEPT = 1024
 
     def _obs_submit(self, req: "_Request",
@@ -1665,13 +1824,47 @@ class PagedLLMEngine:
                 chunk_s=req.chunk_s)
             req.prefill_span.finish(now)
             req.prefill_span = None
-        self._request_phases.append({
+        # The decode half opens here and is filled in at the request's
+        # end (_finish_request); until then its keys read None.
+        req.decode_from = self._clock.read(now)
+        req.decode_span = tracing.open_serve_span(
+            req.trace, "serve.engine.decode", now)
+        req.record = {
             "id": req.trace["trace_id"] if req.trace else None,
             "submitted": req.submitted_at,
             "queue_wait_s": req.admitted_at - req.submitted_at,
             "prefill_wait_s": req.prefill_at - req.admitted_at,
             "prefill_span_s": now - req.prefill_at,
-            "ttft_s": now - req.submitted_at})
+            "ttft_s": now - req.submitted_at,
+            **dict.fromkeys(_DECODE_KEYS)}
+        self._request_phases.append(req.record)
+
+    def _obs_decode_end(self, req: "_Request") -> None:
+        """The request is over: what the engine's thread did between its
+        first token and now, from two readings of the phase clock.  The
+        three sums are the whole of `decode_s`, exactly: the time the
+        thread waited for a burst's tokens, the time it waited for other
+        prompts' first tokens, and every other leaf (launches, emit,
+        admit, book, wait).  They complete the request's record and go
+        onto its `serve.engine.decode` span, the parent of its
+        `decode_burst` spans."""
+        now = time.time()
+        spent = [b - a for a, b in zip(req.decode_from,
+                                       self._clock.read(now))]
+        sums = {
+            "decode_s": now - req.first_token_at,
+            "n_out": len(req.out_tokens),
+            "burst_read_s": spent[_BURST_READ],
+            "first_read_s": spent[_FIRST_READ],
+            "host_s": sum(spent) - spent[_BURST_READ] - spent[_FIRST_READ],
+            "lanes_seen": (req.lanes_sum / req.bursts if req.bursts
+                           else 0.0)}
+        req.record.update(sums)     # the keys are there: no resize under
+        #                             a reader on another thread
+        if req.decode_span is not None:
+            req.decode_span.attrs.update(sums)
+            req.decode_span.finish(now)
+            req.decode_span = None
 
     def _obs_burst(self, req: "_Request", t0: float, t1: float,
                    n_new: int) -> None:
@@ -1684,8 +1877,9 @@ class PagedLLMEngine:
         from ray_tpu.serve import observability
 
         app = self._obs_app(req)
-        tracing.record_serve_span(req.trace, "serve.engine.decode_burst",
-                                  t0, t1, tokens=n_new)
+        tracing.record_serve_span(
+            tracing.child_ctx(req.trace, req.decode_span),
+            "serve.engine.decode_burst", t0, t1, tokens=n_new)
         observability.observe_phase(app, "decode_step", t1 - t0)
         if req.last_emit_wall is not None and t1 > req.last_emit_wall:
             observability.metrics()["itl"].observe(
@@ -1906,6 +2100,10 @@ class LLMDeployment:
         if es.get("spec_proposed"):
             state["spec_accept_rate"] = round(
                 es.get("spec_accepted", 0) / es["spec_proposed"], 4)
+        # The loop thread's seconds by leaf phase: `ray-tpu serve
+        # status` shows them as shares.
+        state["phase_seconds"] = {k: round(v, 3) for k, v in
+                                  es["phase_seconds"].items()}
         if cfg.serve_prefix_registry_enabled:
             state["block_size"] = int(self.engine.block_size)
             state["prefixes"] = self.engine.allocator.prefix_digests(

@@ -374,7 +374,9 @@ def test_request_phases_sum_to_the_engine_ttft(six_requests):
     assert len(recs) == len(_PROMPT_LENS)
     for r in recs:
         assert set(r) == {"id", "submitted", "queue_wait_s",
-                          "prefill_wait_s", "prefill_span_s", "ttft_s"}
+                          "prefill_wait_s", "prefill_span_s", "ttft_s",
+                          "decode_s", "n_out", "burst_read_s",
+                          "first_read_s", "host_s", "lanes_seen"}
         assert r["queue_wait_s"] + r["prefill_wait_s"] \
             + r["prefill_span_s"] == pytest.approx(r["ttft_s"], abs=1e-6)
         assert min(r["queue_wait_s"], r["prefill_wait_s"],
@@ -413,19 +415,25 @@ def test_spans_are_minted_only_while_tracing_is_on(six_requests):
         assert 0.0 <= s.attrs["chunk_s"] <= r["prefill_span_s"] + 1e-9
 
 
-def test_p_ttft_mean_is_the_mean_of_the_record(six_requests):
+def test_the_counters_carry_the_phase_seconds_and_neither_log(
+        six_requests):
+    from ray_tpu.serve.llm import PHASES
+
     stats = six_requests["stats"]
-    recs = stats["request_phases"]
-    assert stats["p_ttft_mean"] == pytest.approx(
-        sum(r["ttft_s"] for r in recs) / len(recs), abs=1e-12)
-    assert "ttft_sum" not in stats
+    assert "p_ttft_mean" not in stats and "ttft_sum" not in stats
     # the gauge loop's view: the counters, without the logs
     counters = six_requests["counters"]
     assert counters["completed"] == len(_PROMPT_LENS)
-    assert not {"request_phases", "tick_log", "tick_fields",
-                "p_ttft_mean"} & set(counters)
-    assert set(counters) | {"request_phases", "tick_log", "tick_fields",
-                            "p_ttft_mean"} == set(stats)
+    logs = {"request_phases", "tick_log", "tick_fields"}
+    assert not logs & set(counters)
+    assert set(counters) | logs == set(stats)
+    seconds = counters["phase_seconds"]
+    assert tuple(seconds) == PHASES
+    assert all(v >= 0.0 for v in seconds.values())
+    # six prompts were prefilled, read and decoded
+    for leaf in ("admit", "burst_launch", "burst_read", "emit",
+                 "chunk_launch", "first_read", "book"):
+        assert seconds[leaf] > 0.0, leaf
 
 
 def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
@@ -444,6 +452,7 @@ def test_tick_log_accounts_for_every_tick_that_progressed(six_requests):
         # tick after a busy period's last launch) only read a burst
         assert t["lanes"] or t["prefill_tokens"] or t["decode_s"] > 0.0
         assert t["ahead"] in (0, 1) and t["ahead"] <= t["lanes"]
+        assert 0.0 <= t["starved_s"]
     assert [t["start"] for t in ticks] == sorted(t["start"] for t in ticks)
     assert sum(t["prefill_tokens"] for t in ticks) == sum(_PROMPT_LENS)
     # every prompt token went through a chunk the stats counted
@@ -480,7 +489,7 @@ def test_tick_log_says_how_many_experts_a_burst_read(model):
         eng.shutdown()
     assert all(len(o) == 12 for o in outs)
     fields = stats["tick_fields"]
-    assert fields[-2:] == ("experts_read", "ahead")
+    assert fields[-3:] == ("experts_read", "ahead", "starved_s")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     decoded = [t for t in ticks if t["lanes"] > 0]
     assert decoded and len(decoded) < len(ticks)
@@ -546,7 +555,6 @@ def test_an_idle_iteration_leaves_no_tick_record():
             assert eng._tick() is False
         assert eng.engine_stats()["tick_log"] == ()
         assert eng.engine_stats()["request_phases"] == ()
-        assert eng.engine_stats()["p_ttft_mean"] is None
     finally:
         eng.shutdown()
 
