@@ -48,7 +48,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from ray_tpu.serve.kv_cache import prefix_digest
+from ray_tpu.serve.kv_cache import block_digests, prefix_digest
 
 # GCS KV key prefix for migration tickets ("serve" namespace, beside
 # the controller's app:*/routes/status keys).
@@ -61,11 +61,13 @@ def request_digests(tokens, block_size: int,
     prefix boundaries, LONGEST first — the handle probes these against
     the cluster owner map and routes to the deepest match.  Bounded to
     the last `max_bounds` boundaries so routing cost stays O(1)-ish for
-    very long prompts."""
+    very long prompts.  One pass over the prompt whatever the number
+    of boundaries (`kv_cache.block_digests`)."""
     n_full = len(tokens) // block_size
-    bounds = range(max(1, n_full - max_bounds + 1), n_full + 1)
-    return [(k * block_size, prefix_digest(tokens[:k * block_size]))
-            for k in reversed(list(bounds))] if n_full else []
+    first = max(1, n_full - max_bounds + 1)
+    digests = block_digests(tokens, block_size, first)
+    return [((first + j) * block_size, d)
+            for j, d in enumerate(digests)][::-1]
 
 
 def _worker():

@@ -11,6 +11,16 @@ the table entry the allocator hands back).
 Block 0 is the reserved NULL block: never allocated, every unused table
 entry points at it, so the compiled gather/scatter is always in-bounds.
 
+A registered prefix is a chain of blocks, and everything about it costs
+one pass over the prompt.  A block's KEY is (the registration of the
+block before it, the block's own tokens): exact, because a registration
+number is never handed out twice, yet one block's worth of tokens to
+build, hash and hold.  A boundary's DIGEST is what the cluster exchanges
+(`prefix_digests()` -> the handle's owner map <- `disagg.request_digests`;
+a prefill actor's choice): SHA-1 over every token up to the boundary,
+8 bytes each, cut to 16 hex digits.  The strings are a contract between
+processes; `block_digests` reads a prompt's off one running hash.
+
 What the blocks are to a sequence depends on the model
 (`models.decoding.init_sequence_state`).  For a model whose every layer
 keeps every position they are the whole sequence, which is what prefix
@@ -37,9 +47,21 @@ without a store (standalone, unit tests) skip the arena.
 from __future__ import annotations
 
 import hashlib
+import struct
 import threading
 from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Tuple
+
+
+def _token_bytes(tokens) -> bytes:
+    """The bytes every digest is taken over: each token as 8 bytes,
+    little-endian, signed.  Packed by `struct`, not numpy: the handle
+    and the proxy hash a request's prefixes through this module and
+    import no numpy otherwise (its import cost the first routed request
+    of a process ~0.4 s)."""
+    if not hasattr(tokens, "__len__"):
+        tokens = list(tokens)
+    return struct.pack("<%dq" % len(tokens), *tokens)
 
 
 def prefix_digest(tokens) -> str:
@@ -49,17 +71,46 @@ def prefix_digest(tokens) -> str:
     it — derive the SAME digest and the prefix registry can match them
     without ever moving token lists through the GCS."""
     h = hashlib.sha1()
-    for t in tokens:
-        h.update(int(t).to_bytes(8, "little", signed=True))
+    h.update(_token_bytes(tokens))
     return h.hexdigest()[:16]
+
+
+def block_digests(tokens, block_size: int, first: int = 1) -> List[str]:
+    """``prefix_digest(tokens[:k * block_size])`` for k = ``first`` ..
+    ``len(tokens) // block_size``, from ONE running hash: a block's
+    bytes go in once and the boundary's digest is read off a copy, so
+    the whole list costs one pass over the prompt, not one a block.
+    The strings are `prefix_digest`'s own (SHA-1 is a stream: the copy
+    after k blocks has seen exactly the first k blocks' bytes)."""
+    n_full = len(tokens) // block_size
+    if first > n_full:
+        return []
+    step = 8 * block_size
+    buf = memoryview(_token_bytes(tokens))
+    h = hashlib.sha1()
+    h.update(buf[:(first - 1) * step])
+    out = []
+    for k in range(first, n_full + 1):
+        h.update(buf[(k - 1) * step:k * step])
+        out.append(h.copy().hexdigest()[:16])
+    return out
 
 
 class KVBlockAllocator:
     """Free-list + refcounts + prefix map over ``num_blocks`` pool blocks
     of ``block_size`` tokens each (block 0 reserved).
 
-    Prefix map: key = tuple of ALL prompt tokens up to and including a
-    block's chunk (cumulative keys make lookups exact, not positional).
+    Prefix map: a block's key = (the registration of the block before
+    it, the block's own <= ``block_size`` tokens); the first block's
+    parent is registration 0.  A registration number is handed out once
+    per (block, key) and never again, so a key names one exact token
+    prefix: a hit means every token of the covered prefix is equal
+    (exact, not positional, and no digest stands in for identity), yet
+    a key costs a block, not a prefix, to build, hash and hold.  Every
+    walk starts at the root and follows the prompt block by block; a
+    block that lost its registration (evicted, unregistered, re-keyed)
+    ends the chain there, and what hung below it stays unreachable
+    until it is evicted in its turn or the prompt is registered again.
     Freed blocks that carry a prefix key become "cached-free": refcount
     0, contents intact, LRU-evictable when the free list runs dry.  A
     lookup hit on a cached-free block revives it (refcount 1) without
@@ -78,15 +129,21 @@ class KVBlockAllocator:
         self._lock = threading.Lock()
         self._free: deque = deque(range(1, num_blocks))
         self._ref = [0] * num_blocks
-        # prefix key -> block id; insertion order over CACHED (refcount
-        # 0) entries is the eviction LRU.
+        # prefix key (parent registration, block tokens) -> block id;
+        # insertion order over CACHED (refcount 0) entries is the
+        # eviction LRU.
         self._by_key: Dict[tuple, int] = {}
         self._key_of: Dict[int, tuple] = {}
-        # key -> cluster-stable digest (computed once at registration;
-        # the gauge loop publishes these to the cluster prefix registry).
+        # block id -> its registration (0: none), the parent half of
+        # its children's keys; never reused, unlike the block id.
+        self._reg = [0] * num_blocks
+        self._next_reg = 1
+        # aligned key -> cluster-stable digest of the token prefix it
+        # ends (computed once at registration; the gauge loop publishes
+        # these to the cluster prefix registry).
         self._digest_of: Dict[tuple, str] = {}
         self._cached: "OrderedDict[int, None]" = OrderedDict()
-        # full-prompt key -> metadata (last-token logits) so a whole-
+        # last block's key -> metadata (last-token logits) so a whole-
         # prompt hit can sample its first token without any forward.
         self._meta: Dict[tuple, Any] = {}
         self.stats = {"reuse_hits": 0, "reuse_misses": 0, "cow_copies": 0,
@@ -122,13 +179,18 @@ class KVBlockAllocator:
         if not self._cached:
             return None
         blk, _ = self._cached.popitem(last=False)
+        self._forget_locked(blk)
+        self.stats["evictions"] += 1
+        return blk
+
+    def _forget_locked(self, blk: int) -> None:
+        """Drop a block's registration: key, meta and digest together."""
         key = self._key_of.pop(blk, None)
         if key is not None:
             self._by_key.pop(key, None)
             self._meta.pop(key, None)
             self._digest_of.pop(key, None)
-        self.stats["evictions"] += 1
-        return blk
+        self._reg[blk] = 0
 
     def can_alloc(self, n: int) -> bool:
         with self._lock:
@@ -179,96 +241,90 @@ class KVBlockAllocator:
         if not self.prefix_sharing:
             return [], 0, None
         bs = self.block_size
+        n = len(tokens)
         with self._lock:
-            whole = tuple(tokens)
-            if whole in self._by_key and len(tokens) % bs:
-                # Whole-prompt key with a partial tail: grab the aligned
-                # chain plus the tail.
-                chain = self._chain_locked(tokens, len(tokens) // bs)
-                if chain is not None:
-                    tail = self._by_key[whole]
-                    self._take_locked(tail)
-                    blocks = chain + [tail]
-                    self.stats["reuse_hits"] += len(blocks)
-                    return blocks, len(tokens), self._meta.get(whole)
-            # Longest aligned chain.
-            n_full = len(tokens) // bs
-            for k in range(n_full, 0, -1):
-                chain = self._chain_locked(tokens, k)
-                if chain is not None:
-                    self.stats["reuse_hits"] += len(chain)
-                    meta = (self._meta.get(whole)
-                            if k * bs == len(tokens) else None)
-                    return chain, k * bs, meta
-            self.stats["reuse_misses"] += 1
-            return [], 0, None
+            blocks, key, parent = self._walk_locked(tokens)
+            if len(blocks) == n // bs and n % bs:
+                # The whole aligned chain: a whole-prompt key on the
+                # partial tail extends it.
+                tail_key = (parent, tuple(tokens[n - n % bs:]))
+                tail = self._by_key.get(tail_key)
+                if tail is not None:
+                    blocks.append(tail)
+                    key = tail_key
+            if not blocks:
+                self.stats["reuse_misses"] += 1
+                return [], 0, None
+            # Nothing was taken during the walk, so a chain is all or
+            # nothing and refcounts stay balanced.
+            for blk in blocks:
+                if self._ref[blk] == 0:
+                    self._cached.pop(blk, None)
+                self._ref[blk] += 1
+            self.stats["reuse_hits"] += len(blocks)
+            covered = min(len(blocks) * bs, n)
+            meta = self._meta.get(key) if covered == n else None
+            return blocks, covered, meta
 
-    def _chain_locked(self, tokens, k: int) -> Optional[List[int]]:
-        """Incref + return the first k aligned blocks, or None if any
-        link is missing (all-or-nothing so refcounts stay balanced)."""
+    def _walk_locked(self, tokens
+                     ) -> Tuple[List[int], Optional[tuple], int]:
+        """Follow ``tokens`` from the root through the registered
+        aligned blocks: (the chain up to the first missing link, the
+        last link's key, its registration).  One block's tokens are
+        built and hashed a step, whatever the depth."""
         bs = self.block_size
-        blocks = []
-        for i in range(k):
-            blk = self._by_key.get(tuple(tokens[:(i + 1) * bs]))
+        blocks, key, parent = [], None, 0
+        for i in range(len(tokens) // bs):
+            k = (parent, tuple(tokens[i * bs:(i + 1) * bs]))
+            blk = self._by_key.get(k)
             if blk is None:
-                for b in blocks:          # roll back increfs
-                    self._drop_locked(b)
-                return None
+                break
             blocks.append(blk)
-        for b in blocks:
-            self._take_locked(b)
-        return blocks
-
-    def _take_locked(self, blk: int) -> None:
-        if self._ref[blk] == 0:
-            self._cached.pop(blk, None)
-        self._ref[blk] += 1
-
-    def _drop_locked(self, blk: int) -> None:
-        # Undo a _take_locked during chain rollback (no LRU re-park —
-        # the block never left the caller's view).
-        if self._ref[blk] > 0:
-            self._ref[blk] -= 1
-            if self._ref[blk] == 0 and blk in self._key_of:
-                self._cached[blk] = None
+            key, parent = k, self._reg[blk]
+        return blocks, key, parent
 
     def register_prefix(self, tokens: List[int], blocks: List[int],
                         meta: Any = None) -> None:
-        """Publish a prefilled prompt's blocks for reuse: aligned chunks
-        keyed cumulatively, plus the whole-prompt key on the tail (which
-        may be partial).  ``meta`` (last-token logits) is stored under
-        the whole-prompt key.  Does NOT change refcounts — the caller
-        still owns its references; blocks become cached-free when the
-        last owner frees them."""
+        """Publish a prefilled prompt's blocks for reuse: each aligned
+        chunk keyed under the registration of the chunk before it, plus
+        the whole-prompt key on the tail (which may be partial).
+        ``meta`` (last-token logits) is stored under the last block's
+        key.  Does NOT change refcounts — the caller still owns its
+        references; blocks become cached-free when the last owner frees
+        them."""
         if not self.prefix_sharing:
             return
         bs = self.block_size
+        n_full = len(tokens) // bs
+        n_keys = -(-len(tokens) // bs)
         with self._lock:
-            n_full = len(tokens) // bs
-            for i in range(n_full):
-                key = tuple(tokens[:(i + 1) * bs])
-                self._register_locked(key, blocks[i])
-            if len(tokens) % bs and len(blocks) > n_full:
-                self._register_locked(tuple(tokens), blocks[n_full])
-            if meta is not None:
-                self._meta[tuple(tokens)] = meta
+            digests = None
+            key, parent = None, 0
+            for i, blk in enumerate(blocks[:n_keys]):
+                key = (parent, tuple(tokens[i * bs:(i + 1) * bs]))
+                if self._register_locked(key, blk) and i < n_full:
+                    # One pass over the prompt, and only for a prompt
+                    # that registers something new.
+                    digests = digests or block_digests(tokens, bs)
+                    self._digest_of[key] = digests[i]
+                parent = self._reg[self._by_key[key]]
+            if meta is not None and len(blocks) >= n_keys > 0:
+                self._meta[key] = meta
 
-    def _register_locked(self, key: tuple, blk: int) -> None:
-        old = self._by_key.get(key)
-        if old == blk:
-            return
-        if old is not None:
-            # Key collision with a different block: keep the existing
-            # registration (its content already matches the key).
-            return
-        prev_key = self._key_of.get(blk)
-        if prev_key is not None and prev_key != key:
-            self._by_key.pop(prev_key, None)
-            self._meta.pop(prev_key, None)
-            self._digest_of.pop(prev_key, None)
+    def _register_locked(self, key: tuple, blk: int) -> bool:
+        """Register ``blk`` under ``key`` unless the key is taken: by
+        ``blk`` itself (nothing to do) or by another block (the first
+        registration wins; its content already matches the key).  True
+        when a new registration was made."""
+        if key in self._by_key:
+            return False
+        # A block registered under another key gives that one up.
+        self._forget_locked(blk)
         self._by_key[key] = blk
         self._key_of[blk] = key
-        self._digest_of[key] = prefix_digest(key)
+        self._reg[blk] = self._next_reg
+        self._next_reg += 1
+        return True
 
     def adopt(self, tokens: List[int], meta: Any = None
               ) -> Optional[List[int]]:
@@ -298,8 +354,7 @@ class KVBlockAllocator:
         chains into a longer prompt).  Most-recently-registered last;
         ``limit`` > 0 keeps the newest that many (gauge-payload bound)."""
         with self._lock:
-            out = [d for k, d in self._digest_of.items()
-                   if len(k) % self.block_size == 0]
+            out = list(self._digest_of.values())
         if limit > 0 and len(out) > limit:
             out = out[-limit:]
         return out
@@ -308,11 +363,7 @@ class KVBlockAllocator:
         """Drop a block's prefix key (its content is about to diverge
         from the key — the sole-owner in-place-append path)."""
         with self._lock:
-            key = self._key_of.pop(blk, None)
-            if key is not None:
-                self._by_key.pop(key, None)
-                self._meta.pop(key, None)
-                self._digest_of.pop(key, None)
+            self._forget_locked(blk)
             self._cached.pop(blk, None)
 
     def cow(self, blk: int) -> Tuple[int, bool]:
@@ -332,10 +383,7 @@ class KVBlockAllocator:
                 # pristine copy for future hits only when a spare block
                 # exists; otherwise just unregister and write in place.
                 if not self._free and not self._cached:
-                    key = self._key_of.pop(blk)
-                    self._by_key.pop(key, None)
-                    self._meta.pop(key, None)
-                    self._digest_of.pop(key, None)
+                    self._forget_locked(blk)
                     return blk, False
             new = self._free.popleft() if self._free \
                 else self._evict_cached()

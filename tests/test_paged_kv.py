@@ -1,9 +1,16 @@
 """Paged KV-cache serving engine: block allocator, prefix sharing + COW,
 allocator-full admission queueing, chunked-prefill ITL bound, bounded
 stream queues, controller autoscale-stats TTL."""
+import hashlib
+import os
+import random
+import subprocess
+import sys
 import threading
 import time
+import types
 
+import numpy as np
 import pytest
 
 import jax
@@ -103,6 +110,208 @@ def test_cached_prefix_evicted_under_pressure():
     got, covered, _ = a.lookup_prefix(prompt)
     assert got == [] and covered == 0   # registration gone with eviction
     a.free(more)
+
+
+# ---------------------------------------------------------------------------
+# prefix keys and digests in one pass over a prompt's blocks (PR 39)
+# ---------------------------------------------------------------------------
+def _digest_per_token(tokens):
+    """The digest as it was defined: SHA-1 fed 8 bytes a token."""
+    h = hashlib.sha1()
+    for t in tokens:
+        h.update(int(t).to_bytes(8, "little", signed=True))
+    return h.hexdigest()[:16]
+
+
+_ODD_TOKENS = (0, -1, 2 ** 31, 2 ** 40)
+_AS = {"list": list, "tuple": tuple,
+       "array": lambda t: np.asarray(t, dtype=np.int64)}
+
+
+@pytest.mark.parametrize("kind", sorted(_AS))
+@pytest.mark.parametrize("n", [1, 15, 16, 17, 3968])
+def test_digests_equal_the_per_token_definition(n, kind):
+    """`prefix_digest`, the digests a registration stores and the
+    handle's `request_digests` are a cluster-wide contract: every string
+    equals the per-token loop's, whatever the container and the token
+    values."""
+    from ray_tpu.serve.disagg import request_digests
+    from ray_tpu.serve.kv_cache import prefix_digest
+
+    bs = 16
+    rng = random.Random(n)
+    plain = [_ODD_TOKENS[i % 7] if i % 7 < 4 else rng.randrange(32768)
+             for i in range(n)]
+    tokens = _AS[kind](plain)
+    want = [_digest_per_token(plain[:k * bs])
+            for k in range(1, n // bs + 1)]
+    assert prefix_digest(tokens) == _digest_per_token(plain)
+    a = KVBlockAllocator(n // bs + 3, bs)
+    a.register_prefix(tokens, a.alloc(-(-n // bs)))
+    assert a.prefix_digests() == want
+    assert request_digests(tokens, bs) == [
+        (k * bs, want[k - 1])
+        for k in range(n // bs, max(0, n // bs - 8), -1)]
+    assert request_digests(tokens, bs, max_bounds=3) == \
+        request_digests(tokens, bs)[:3]
+
+
+def test_digest_path_imports_no_numpy():
+    """The handle and the proxy import no numpy until they hash a
+    request's prefixes through `disagg.request_digests`; that path has
+    to stay that way (the import cost the first routed request of a
+    front process ~0.4 s on the chip's host)."""
+    code = ("import sys; from ray_tpu.serve.disagg import request_digests; "
+            "assert request_digests(list(range(40)), 16); "
+            "sys.exit('numpy' in sys.modules)")
+    assert subprocess.run([sys.executable, "-c", code], timeout=120,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu")
+                          ).returncode == 0
+
+
+class _CountingSha1:
+    """`hashlib.sha1` that adds up the bytes passed to `update`."""
+
+    def __init__(self, hashed, inner=None):
+        self._hashed, self._inner = hashed, inner or hashlib.sha1()
+
+    def update(self, data):
+        self._hashed[0] += memoryview(data).nbytes
+        self._inner.update(data)
+
+    def copy(self):
+        return _CountingSha1(self._hashed, self._inner.copy())
+
+    def hexdigest(self):
+        return self._inner.hexdigest()
+
+
+@pytest.fixture
+def hashed(monkeypatch):
+    """Bytes `kv_cache` (and nobody else) fed to SHA-1, as a one-item
+    list."""
+    from ray_tpu.serve import kv_cache
+
+    count = [0]
+    monkeypatch.setattr(kv_cache, "hashlib", types.SimpleNamespace(
+        sha1=lambda: _CountingSha1(count)))
+    return count
+
+
+def _token_slots(key):
+    return sum(map(_token_slots, key)) if isinstance(key, tuple) else 1
+
+
+def test_register_and_lookup_are_linear_in_the_prompt(hashed):
+    n, bs = 3968, 16
+    prompt = [(7 * i) % 32768 for i in range(n)]
+    a = KVBlockAllocator(n // bs + 2, bs)
+    blocks = a.alloc(n // bs)
+    a.register_prefix(prompt, blocks, meta="logits")
+    assert a.lookup_prefix(prompt) == (blocks, n, "logits")
+    assert 8 * n <= hashed[0] <= 8 * n + 64 * len(blocks)
+    assert len(a._by_key) == len(blocks)
+    held = sum(_token_slots(k) for k in a._by_key)
+    assert n <= held <= n + 4 * len(blocks)
+    # A second owner of the same prompt registers nothing and hashes
+    # nothing; a hit never hashes.
+    a.register_prefix(prompt, blocks)
+    assert hashed[0] <= 8 * n + 64 * len(blocks)
+
+
+def test_lookup_is_exact_not_positional():
+    """Two prompts that differ only in their first block share no block,
+    though every later block holds equal tokens."""
+    bs = 4
+    a = KVBlockAllocator(9, bs)
+    one = [1, 2, 3, 4] + list(range(10, 18))
+    two = [1, 2, 3, 5] + list(range(10, 18))
+    b_one = a.alloc(3)
+    a.register_prefix(one, b_one)
+    assert a.lookup_prefix(two) == ([], 0, None)
+    b_two = a.alloc(3)
+    a.register_prefix(two, b_two)
+    assert a.lookup_prefix(two)[:2] == (b_two, 12)
+    assert a.lookup_prefix(one)[:2] == (b_one, 12)
+    assert not set(b_one) & set(b_two)
+    assert len(a._by_key) == 6
+    a.free(b_one + b_one + b_two + b_two)
+    snap = a.snapshot()
+    assert snap["blocks_active"] == 0 and snap["blocks_cached"] == 6
+
+
+def test_evicted_middle_block_ends_the_chain_until_reregistered():
+    bs = 4
+    a = KVBlockAllocator(6, bs)    # 5 usable
+    prompt = list(range(1, 13))    # 3 aligned blocks
+    b0, b1, b2 = blocks = a.alloc(3)
+    a.register_prefix(prompt, blocks)
+    a.free([b1])                   # parked first: the LRU's next victim
+    a.free([b0, b2])
+    other = a.alloc(3)             # 2 free + the evicted middle block
+    assert a.stats["evictions"] == 1 and b1 in other
+    got, covered, _ = a.lookup_prefix(prompt)
+    assert got == [b0] and covered == bs   # nothing beyond the break
+    a.free(got)
+    assert a.snapshot()["blocks_active"] == 3
+    a.free(other)
+    # Registered again, the whole chain is reachable: the surviving
+    # head keeps its block (first registration wins), the rest is new.
+    again = a.alloc(3)
+    a.register_prefix(prompt, again)
+    got, covered, _ = a.lookup_prefix(prompt)
+    assert got == [b0, again[1], again[2]] and covered == 12
+    a.free(got)
+    a.free(again)
+    snap = a.snapshot()
+    assert snap["blocks_active"] == 0
+    assert snap["blocks_free"] + snap["blocks_cached"] == 5
+    # What hung below the break (b2) was never reachable again, and is
+    # reclaimed like any cached block.
+    assert a.alloc(5) is not None
+    assert a.snapshot()["prefixes_registered"] == 0
+
+
+def test_whole_prompt_hit_on_partial_tail_returns_meta():
+    bs = 4
+    a = KVBlockAllocator(9, bs)
+    prompt = list(range(1, 11))    # 2 aligned blocks + a tail of 2
+    blocks = a.alloc(3)
+    a.register_prefix(prompt, blocks, meta="logits")
+    assert a.lookup_prefix(prompt) == (blocks, 10, "logits")
+    # The tail is a whole-prompt key: a longer prompt takes the aligned
+    # chain alone, and so does this one once the tail's key is gone.
+    assert a.lookup_prefix(prompt + [11]) == (blocks[:2], 8, None)
+    assert a.prefix_digests() == [_digest_per_token(prompt[:4]),
+                                  _digest_per_token(prompt[:8])]
+    a.unregister_block(blocks[2])
+    assert a.lookup_prefix(prompt) == (blocks[:2], 8, None)
+    short = [21, 22, 23]           # no aligned block at all
+    tail = a.alloc(1)
+    a.register_prefix(short, tail, meta="m")
+    assert a.lookup_prefix(short) == (tail, 3, "m")
+
+
+def test_engine_registers_a_finished_prompt_in_one_pass(tiny_model,
+                                                        hashed):
+    """Through the engine: the leaf `book` of a finished prompt hashes
+    the prompt once, and the same prompt again hits the whole prefix."""
+    n, bs = 300, 16
+    prompt = [(11 * i) % 250 + 1 for i in range(n)]
+    eng = make_engine(tiny_model, max_len=512, block_size=bs,
+                      prefill_chunk=64)
+    try:
+        first = eng.generate(prompt, max_tokens=4, timeout=120)
+        n_blocks = -(-n // bs)
+        assert 8 * (n - n % bs) <= hashed[0] <= 8 * n + 64 * n_blocks
+        assert eng.stats["prefix_hits"] == 0
+        assert len(eng.allocator.prefix_digests()) == n // bs
+        assert eng.generate(prompt, max_tokens=4, timeout=120) == first
+        assert eng.stats["prefix_hits"] == 1
+        assert eng.allocator.stats["reuse_hits"] == n_blocks
+        assert hashed[0] <= 8 * n + 64 * n_blocks
+    finally:
+        eng.shutdown()
 
 
 # ---------------------------------------------------------------------------
