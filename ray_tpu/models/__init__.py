@@ -15,6 +15,7 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.mamba2_moe import Mamba2MoEConfig
+from ray_tpu.models.mla_moe import MLAMoEConfig
 from ray_tpu.models import configs
 from ray_tpu.models.hf_convert import from_hf
 
@@ -23,6 +24,7 @@ __all__ = [
     "TransformerConfig",
     "HybridConfig",
     "Mamba2MoEConfig",
+    "MLAMoEConfig",
     "init_params",
     "param_logical_axes",
     "forward",

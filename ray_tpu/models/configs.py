@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.mamba2_moe import Mamba2MoEConfig
+from ray_tpu.models.mla_moe import MLAMoEConfig
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops.rotary import YarnScaling
 
@@ -114,12 +115,29 @@ TINY_MAMBA2_MOE = Mamba2MoEConfig(
 # token), every expert held.
 GRANITE4_H_SMALL = Mamba2MoEConfig(name="granite-4.0-h-small")
 
+# Latent attention with scored experts (models/mla_moe.py) at test size:
+# a dense first layer and three expert layers, 8 experts top-3 by biased
+# sigmoid scores of which this rank holds the lower four, a shared
+# expert; a stored row of 24 + 8 values padded to a tile of 128.
+TINY_MLA_MOE = MLAMoEConfig(
+    name="tiny-mla-moe", vocab_size=512, d_model=64, n_layers=4,
+    n_dense_layers=1, n_heads=4, q_rank=32, kv_rank=24, d_nope=12,
+    d_rope=8, d_v=16, d_ff=96, n_experts=8, expert_top_k=3, d_expert=24,
+    d_shared=24, route_scale=1.8, experts_held=(0, 4), rope_theta=10000.0,
+    max_seq_len=512, param_dtype=jnp.float32)
+
+# GLM-4.7-Flash's published sizes (29.9 B parameters, ~3 B active a
+# token), every expert held; its multi-token-prediction module is not
+# part of the stack.
+GLM_4_7_FLASH = MLAMoEConfig(name="glm-4.7-flash")
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
                                 TINY_HYBRID, PHI4_MINI_FLASH,
                                 TINY_WINDOW_MOE, MELLUM2_12B,
-                                TINY_MAMBA2_MOE, GRANITE4_H_SMALL]}
+                                TINY_MAMBA2_MOE, GRANITE4_H_SMALL,
+                                TINY_MLA_MOE, GLM_4_7_FLASH]}
 
 
 def get(name: str):

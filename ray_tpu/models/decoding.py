@@ -1,9 +1,14 @@
 """Autoregressive decoding: the paged KV pool and the served step.
 
 KV lives in a flat pool of fixed-size blocks, (L, N_blocks, block_size,
-Hkv, D), and each request holds an int32 block table that maps its
+*row), and each request holds an int32 block table that maps its
 positions to pool blocks (vLLM-style; `serve/kv_cache.py` is the
-allocator).  Compiled shapes depend only on (S, B_max, block_size), so
+allocator).  What a row is the model chooses: (Hkv, D) of K and of V for
+a `TransformerConfig` (`PagedKVCache.k` / `.v`), one latent row of all
+heads for `models.mla_moe` (`LatentState.kv`); a state names its pooled
+leaves (`pooled_leaves`) and the block operations (`copy_block`,
+`gather_blocks`, `scatter_blocks`) act on those, whatever their row.
+Compiled shapes depend only on (S, B_max, block_size), so
 memory management (alloc/free/share/COW) lives on the host while the step
 stays one fused program (arXiv:2011.03641: keep the compiled step
 shape-stable) and the engine (`ray_tpu.serve.llm.PagedLLMEngine`) swaps
@@ -185,6 +190,14 @@ def ngram_propose(context, k_minus_1: int, ngram: int = 2):
 
 @dataclasses.dataclass
 class PagedKVCache:
+    """What a `TransformerConfig`'s sequences keep: the `k` / `v` pool of
+    its full layers and, with window layers, their rings by slot.  A
+    model that brings its own state (`cfg.init_state`) chooses its own
+    pooled leaves, of any row shape (`models.mla_moe`: one latent row a
+    position), and names them in `pooled`; the engine and the block
+    operations below ask `pooled_leaves` and `resident_bytes()`
+    (`kv_paged`: the pooled leaves' bytes, which the allocator's
+    `bytes_per_block` is taken from) and never a leaf by name."""
     k: jax.Array          # (L_full, N_blocks, block_size, Hkv, D)
     v: jax.Array
     # The window layers' rings, by slot (the null slot last); None for a
@@ -531,10 +544,10 @@ def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,
     """
     k_w = cand_tokens.shape[1]
     positions = lengths[:, None] + jnp.arange(k_w, dtype=jnp.int32)  # (S,K)
-    cache, x, _, _ = _paged_forward(
+    cache, x, *_ = _served_forward(
         params, cache, cand_tokens, block_tables, positions,
-        jnp.where(active, lengths + k_w, 0), cfg)
-    logits = _final_logits(params, x, cfg)               # (S, K, vocab)
+        jnp.where(active, lengths + k_w, 0), cfg, None)
+    logits = _served_logits(params, x, cfg)              # (S, K, vocab)
     # Proposal i is correct iff the model's greedy token at the previous
     # position equals it; acceptance is the run of correct proposals.
     # Sampling slots (temps > 0) accept nothing and degrade to an exact
@@ -557,43 +570,69 @@ def make_paged_spec_fns(cfg: TransformerConfig, donate: bool = True):
                    donate_argnums=(1,) if donate else ())
 
 
-def copy_block(cache: PagedKVCache, dst: jax.Array, src: jax.Array
-               ) -> PagedKVCache:
-    """Copy one pool block across all layers (the device half of
-    copy-on-write: a shared partial block is duplicated before its new
-    owner appends into it)."""
-    return dataclasses.replace(cache,
-                               k=cache.k.at[:, dst].set(cache.k[:, src]),
-                               v=cache.v.at[:, dst].set(cache.v[:, src]))
+def pooled_leaves(cache) -> tuple:
+    """The names of `cache`'s leaves that are pool blocks, (L, N_blocks,
+    block_size, ...): what a block table indexes, and so what copy-on-
+    write, prefix reuse and a shipped frame must carry.  A state says so
+    itself (`pooled`); one that does not has the `k` / `v` pair."""
+    return tuple(getattr(cache, "pooled", ("k", "v")))
 
 
-def gather_blocks(cache: PagedKVCache, block_ids) -> "jnp.ndarray":
-    """Extract pool blocks as one host-transferable KV frame: shape
-    (2, L, n, block_size, Hkv, D) with k stacked over v.  The frame is
+def _with_pooled(cache, update):
+    return dataclasses.replace(cache, **{
+        name: update(getattr(cache, name), i)
+        for i, name in enumerate(pooled_leaves(cache))})
+
+
+def copy_block(cache, dst: jax.Array, src: jax.Array):
+    """Copy one pool block across all layers, in every pooled leaf of the
+    model's state (the device half of copy-on-write: a shared partial
+    block is duplicated before its new owner appends into it)."""
+    return _with_pooled(cache, lambda a, _: a.at[:, dst].set(a[:, src]))
+
+
+def gather_blocks(cache, block_ids) -> "jnp.ndarray":
+    """Extract pool blocks as one host-transferable KV frame: the pooled
+    leaves stacked, (n_leaves, L, n, block_size, *row): (2, L, n,
+    block_size, Hkv, D) with k over v for the `k` / `v` pair, (1, L, n,
+    block_size, W) for a latent pool.  The frame is
     the disaggregated-serving wire unit — a prefill actor gathers its
     finished blocks, `jax.device_get` turns them into a plain ndarray,
     and the bytes ride the zero-copy transfer plane like any sealed shm
     object (serve/disagg.py ships them; import is `scatter_blocks`).
     Exact roundtrip: no dtype change, so a migrated stream's decode is
-    bit-identical to never having moved."""
+    bit-identical to never having moved.  Pooled leaves of unlike shapes
+    do not stack: such a state would need a frame a leaf."""
     import numpy as np
 
     ids = jnp.asarray(np.asarray(block_ids, np.int32))
-    return jnp.stack([cache.k[:, ids], cache.v[:, ids]])
+    return jnp.stack([getattr(cache, name)[:, ids]
+                      for name in pooled_leaves(cache)])
 
 
-def scatter_blocks(cache: PagedKVCache, block_ids, frame) -> PagedKVCache:
+def frame_fits(cache, frame_shape) -> bool:
+    """Whether a `gather_blocks` frame of `frame_shape` is of `cache`'s
+    geometry: its leaves, layers, block size and row."""
+    leaves = [getattr(cache, name) for name in pooled_leaves(cache)]
+    want = leaves[0].shape
+    return (len(frame_shape) == len(want) + 1
+            and frame_shape[0] == len(leaves)
+            and frame_shape[1] == want[0]
+            and tuple(frame_shape[3:]) == tuple(want[2:]))
+
+
+def scatter_blocks(cache, block_ids, frame):
     """Write a `gather_blocks` frame into freshly-allocated pool blocks
     of ANOTHER engine's cache (the decode-side adopt path).  The frame's
-    layer/head/dim geometry must match the receiving cache — the caller
-    (PagedLLMEngine.import_prefix) validates shapes before touching the
-    device."""
+    geometry must match the receiving cache — the caller
+    (PagedLLMEngine.import_prefix) holds it to `frame_fits` before
+    touching the device."""
     import numpy as np
 
     ids = jnp.asarray(np.asarray(block_ids, np.int32))
-    frame = jnp.asarray(frame, cache.k.dtype)
-    return dataclasses.replace(cache, k=cache.k.at[:, ids].set(frame[0]),
-                               v=cache.v.at[:, ids].set(frame[1]))
+    first = getattr(cache, pooled_leaves(cache)[0])
+    frame = jnp.asarray(frame, first.dtype)
+    return _with_pooled(cache, lambda a, i: a.at[:, ids].set(frame[i]))
 
 
 def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):

@@ -552,8 +552,9 @@ _PAGED_GROUP_BLOCKS = 16
 
 
 def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
-                           kv_len, score, mix, stat, d_out):
-    """The loop both paged bodies share: groups of `_PAGED_GROUP_BLOCKS`
+                           kv_len, score, mix, stat, d_out,
+                           group_blocks=_PAGED_GROUP_BLOCKS):
+    """The loop both paged bodies share: groups of `group_blocks`
     table entries of one layer of the pool, read at `[layer, block]` as
     stored, under a running soft-max whose trip count follows the longest
     live lane of the call.  `score(kb)` gives a group's scaled scores
@@ -562,7 +563,7 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     to its values, (S, K, *stat, d_out)."""
     s, k_w = positions.shape
     bs, row = k_pool.shape[2], k_pool.shape[3:]
-    g = min(_PAGED_GROUP_BLOCKS, block_tables.shape[1])
+    g = min(group_blocks, block_tables.shape[1])
     t = g * bs
     # Whole groups only: dynamic_slice would clamp a ragged last one onto
     # the entries before it.  The padding names the null block.
@@ -573,7 +574,10 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
         m, l, acc = carry
         ids = jax.lax.dynamic_slice_in_dim(tables, i * g, g, axis=1)
         kb = k_pool[layer, ids].reshape(s, t, *row)
-        vb = v_pool[layer, ids].reshape(s, t, *row)
+        # One pool given as both: its rows carry key and value together
+        # (`paged_latent_attention`) and a group is gathered once.
+        vb = kb if v_pool is k_pool \
+            else v_pool[layer, ids].reshape(s, t, *row)
         sc = score(kb)
         seen = (i * t + jnp.arange(t)) <= positions[:, :, None]   # (S,K,t)
         sc = jnp.where(seen[over_stat], sc, _NEG_INF)
@@ -635,6 +639,107 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
                                  preferred_element_type=jnp.float32),
         (hkv, h // hkv), d)
     return out.reshape(s, k_w, h, d)
+
+
+# Pool blocks a lane reads per trip over a latent pool.  A latent row is a
+# third of the bytes of a position's K and V above, so a trip of the same
+# cost holds more blocks.  Measured on a v5e at GLM-4.7-Flash widths (12
+# layers, block 16, rows of 640; PR 40, one call), groups of 16 / 32 / 64 /
+# 128 blocks: a decode step of 8 lanes at 5,200-12,600 live positions takes
+# 11.67 / 10.42 / 11.42 / 11.81 ms, one lane at 9,000 7.10 / 6.22 / 6.71 /
+# 7.14, a 512-row chunk at position 8,192 42.9 / 41.6 / 42.4 / 44.6.
+_LATENT_GROUP_BLOCKS = 32
+
+
+def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
+                           *, d_v: int, scale: float):
+    """`paged_attention`'s sibling for a latent (MLA, arXiv:2405.04434)
+    pool in absorbed form: multi-query attention of `q` (S, K, H, W) over
+    one layer of a pool of flat rows (L, N, block_size, W), each row one
+    position's key for every head, **whose first `d_v` columns are also
+    its value**: a row is (normalised latent | roped key | padding), a
+    query (its no-rope part times the key up-projection | its roped part
+    | zeros), and what comes back is the probabilities applied to the
+    latent, (S, K, H, d_v) float32, for the caller's value up-projection.
+    A decode step (one query row a lane) lowered for a TPU is one Pallas
+    kernel a layer (`_latent_decode_kernel`); a chunk, a verify step and
+    every other platform take the same grouped running soft-max over the
+    live blocks of `[layer]` as `paged_attention`, `_LATENT_GROUP_BLOCKS`
+    a trip, the group gathered once for both products.  The platform is
+    the one the program is lowered for (`jax.lax.platform_dependent`), so
+    a program compiled ahead of time for a described chip holds what the
+    chip runs.  `scale` multiplies the scores (the published head size's,
+    not W's).  W is whole lane tiles: the caller pads a row of 576 to 640,
+    or the compiler lays the pool out its own way and copies it whole
+    around every step (`models.mla_moe` has the numbers), and the kernel
+    is not to be had."""
+    def loop(q, pool, layer, block_tables, positions, kv_len):
+        return _paged_running_softmax(
+            pool, pool, layer, block_tables, positions, kv_len,
+            lambda kb: jnp.einsum("sqhe,ste->sqht", q, kb,
+                                  preferred_element_type=jnp.float32) * scale,
+            lambda p, vb: jnp.einsum("sqht,ste->sqhe", p, vb[..., :d_v],
+                                     preferred_element_type=jnp.float32),
+            (q.shape[2],), d_v, group_blocks=_LATENT_GROUP_BLOCKS)
+
+    def kernel(q, pool, layer, block_tables, positions, kv_len):
+        return _latent_decode_kernel(q, pool, layer, block_tables, kv_len,
+                                     d_v=d_v, scale=scale)
+
+    args = (q, pool, layer, block_tables, positions, kv_len)
+    if q.shape[1] != 1:
+        return loop(*args)
+    if pool.shape[-1] % _LANES:
+        # Decided at trace time, as `_pallas_eligible`'s: the warnings
+        # registry shows each distinct message once.
+        warnings.warn(
+            f"paged_latent_attention: a pool row of {pool.shape[-1]} is not "
+            f"whole tiles of {_LANES} lanes, so a decode step over "
+            f"pool{pool.shape} takes the block loop on a TPU too, not the "
+            f"Pallas kernel (about a fifth slower at GLM-4.7-Flash widths)",
+            stacklevel=2)
+        return loop(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
+
+
+# Pool blocks a grid step of the decode kernel copies in and multiplies.
+# Measured on a v5e at GLM-4.7-Flash widths (PR 40, one call; the block
+# loop above at its best trip beside it): a decode step of 8 lanes at
+# 5,200-12,600 live positions takes 9.05 / 8.57 / 8.75 / 8.89 ms with 16 /
+# 32 / 64 / 128 blocks a step against the loop's 10.42, one lane at 9,000
+# 3.11 / 3.04 / 3.09 / 3.16 against 6.19; the two agree to 0.2% of the
+# output's rms (bfloat16 rows either way).  The loop is eleven device ops
+# a trip, the kernel one a layer: a 3 s profile of the loop's bursts held
+# 1.36 M device events, 64% of them the trips'.
+_LATENT_KERNEL_PAGES = 32
+
+
+def _latent_decode_kernel(q, pool, layer, block_tables, kv_len, *, d_v,
+                          scale):
+    """A decode step's attention over the latent pool as one kernel a
+    layer (JAX's Pallas paged attention, multi-query: one "KV head" whose
+    pages are the pool's blocks of every layer, (1, L * N, block_size, W),
+    a free reshape; the layer enters through the page numbers).  The pool
+    is given as keys and as values, and the caller keeps the first `d_v`
+    columns of what comes back.  Pages are copied to VMEM by DMA,
+    `_LATENT_KERNEL_PAGES` a step, double buffered, and only a lane's live
+    pages are read; an idle lane (`kv_len` 0) is skipped and reads 0.  The
+    kernel wants a table of whole steps: one that is not is padded with
+    the null block, which no length reaches."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention as pallas_paged_attention)
+
+    n_layers, n_blocks, bs, w = pool.shape
+    pages = pool.reshape(1, n_layers * n_blocks, bs, w)
+    tables = jnp.pad(
+        block_tables, ((0, 0), (0, -block_tables.shape[1]
+                                % _LATENT_KERNEL_PAGES)))
+    out = pallas_paged_attention(
+        (q[:, 0].astype(jnp.float32) * scale).astype(q.dtype), pages, pages,
+        kv_len.astype(jnp.int32), tables + layer * n_blocks,
+        pages_per_compute_block=_LATENT_KERNEL_PAGES)
+    out = jnp.where((kv_len > 0)[:, None, None], out, 0)
+    return out[:, None, :, :d_v].astype(jnp.float32)
 
 
 # Differential attention (arXiv:2410.05258): softmax(q1 k1^T / sqrt(D)) v

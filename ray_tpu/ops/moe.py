@@ -41,8 +41,19 @@ class MoEConfig:
     # router stays `num_experts` wide; the weight stacks hold the `count`
     # experts alone.  None: all of them (`moe_mlp_dropless`).
     held: Optional[Tuple[int, int]] = None
+    # How `moe_mlp_dropless` scores the experts.  "softmax": the top_k
+    # largest probabilities, renormalised.  "sigmoid": s = sigmoid(logit)
+    # a router output; the top_k largest of s + `router_bias` (a learned
+    # (E,) vector beside the router in the parameters, which selects and
+    # does not gate) are taken, gated by their own s renormalised and
+    # multiplied by `route_scale`.
+    scoring: str = "softmax"
+    route_scale: float = 1.0
 
     def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}: 'softmax' or "
+                             f"'sigmoid'")
         if self.held is not None:
             first, count = self.held
             if first < 0 or count < 1 or first + count > self.num_experts:
@@ -237,16 +248,32 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     live rows that fell on held experts (int32; every live choice where
     all are held).  `held=None` traces the very operations it traced
     before there was a share.
+
+    **The scoring** is `cfg.scoring`'s (`MoEConfig`): the lines between
+    the router's product and `chosen` and nothing after them, so a share,
+    `live`, `return_routing` and `return_routed` mean the same under
+    either.  "sigmoid" reads `params["router_bias"]` (E,).  Soft-max
+    scoring with `route_scale` 1 traces what it traced before there was
+    a choice.
     """
     b, t, d = x.shape
     dtype = x.dtype
     e = cfg.num_experts
 
     logits = jnp.einsum("btd,de->bte", x, params["router"].astype(dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        gate_vals, expert_idx = jax.lax.top_k(probs, cfg.top_k)
+    else:
+        # The bias moves the selection; the gates are the unbiased scores.
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, expert_idx = jax.lax.top_k(
+            probs + params["router_bias"].astype(jnp.float32), cfg.top_k)
+        gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
     gate_vals = gate_vals / jnp.maximum(
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if cfg.route_scale != 1.0:
+        gate_vals = gate_vals * cfg.route_scale
     chosen = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)  # (B,T,k,E)
     if live is not None:
         mask = live.astype(jnp.float32)

@@ -38,7 +38,11 @@ such a model (`lookup_prefix` finds nothing, `register_prefix` keeps
 nothing, `cow` never copies).
 
 The pool's bytes are carved out of the node's shared-memory object store
-through the create-then-fill seam (ObjectStore.create_arena): the arena
+through the create-then-fill seam (ObjectStore.create_arena).
+`bytes_per_block` is the engine's to say and is the pooled leaves' bytes
+over the blocks (`resident_bytes()["kv_paged"]` of whatever state the
+model brought: K and V rows of the full layers, or one latent row a
+layer), never a product of head counts taken here.  The arena
 reservation makes KV pressure visible to the store accounting/syncer
 plane, and releasing it returns the store to quiescence — the leak-guard
 test asserts used/num_objects return to baseline.  Engines running

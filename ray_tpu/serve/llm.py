@@ -336,11 +336,14 @@ class PagedLLMEngine:
 
     - paged KV (`kv_paged`): every position of the layers that keep
       them all, in pool blocks the `KVBlockAllocator` hands out and a
-      block table names.  Every model has it; for a `TransformerConfig`
-      it is all there is, and the blocks *are* the sequence: prefix
-      sharing, copy-on-write, speculation (a rejected draft is rolled
-      back by length alone), `export_streams` / `import_prefix` all
-      rest on that.
+      block table names, rows of the model's own shape (K and V by
+      head; one latent row for `models.mla_moe`, which brings its own
+      state and step and keeps nothing else).  Every model has it; for
+      a `TransformerConfig` and for such a model it is all there is,
+      and the blocks *are* the sequence: prefix sharing, copy-on-write,
+      speculation (a rejected draft is rolled back by length alone),
+      `export_streams` / `import_prefix` all rest on that, and act on
+      whatever leaves the state pools (`models.decoding.pooled_leaves`).
     - bounded window KV (`kv_window`): a ring of window + prefill_chunk
       positions a slot for each sliding-window layer, indexed by the
       engine's slot, owned by whoever holds the slot.  State by slot
@@ -487,6 +490,13 @@ class PagedLLMEngine:
                 raise ValueError(
                     f"{cfg.name!r} keeps {_slot_state(cfg)} by slot: it is "
                     f"served by the paged engine on one device (no mesh)")
+            if getattr(cfg, "init_state", None) is not None:
+                raise ValueError(
+                    f"{cfg.name!r} brings its own sequence state: the "
+                    f"mesh's `tp` split is of the KV heads of a k / v pool "
+                    f"(`paged_cache_shardings`), and this state has none "
+                    f"to split (a latent row is one head's); it is served "
+                    f"on one device (no mesh)")
             tp = int(mesh.shape.get(AXIS_TENSOR, 1))
             for dim_name, dim in (("n_kv_heads", cfg.n_kv_heads),
                                   ("n_heads", cfg.n_heads),
@@ -519,8 +529,9 @@ class PagedLLMEngine:
             lambda lengths: cfg.n_layers * sum(lengths))
         # Layers whose FFN is a set of experts: the burst's count of
         # experts visited is per layer and step over these.
-        self._expert_layers = (cfg.n_layers
-                               if getattr(cfg, "n_experts", 0) > 0 else 0)
+        self._expert_layers = (
+            getattr(cfg, "n_expert_layers", cfg.n_layers)
+            if getattr(cfg, "n_experts", 0) > 0 else 0)
         # A model that holds one rank's share of its experts: its chunk
         # and burst also hand out the top-k choices that fell on the
         # share.  They are summed a tick on the device and read with the
@@ -1663,16 +1674,13 @@ class PagedLLMEngine:
         engine loop (tick lock)."""
         import numpy as np
 
-        from ray_tpu.models.decoding import scatter_blocks
+        from ray_tpu.models.decoding import frame_fits, scatter_blocks
 
         self._refuse_if_by_slot("import_prefix")
         kv = np.asarray(kv)
         n_need = -(-len(tokens) // self.block_size)
-        if (block_size != self.block_size or kv.ndim != 6
-                or kv.shape[0] != 2
-                or kv.shape[1:] != (self.cfg.n_layers, kv.shape[2],
-                                    self.block_size, self.cfg.n_kv_heads,
-                                    self.cfg.head_dim)
+        if (block_size != self.block_size
+                or not frame_fits(self.cache, kv.shape)
                 or kv.shape[2] < n_need):
             return 0
         meta = (self._jnp.asarray(last_logits)

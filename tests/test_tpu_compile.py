@@ -593,3 +593,75 @@ def test_mamba2_moe_served_programs_fit_one_chip(topo, program):
         assert fam.scan_operand(config).search(text)
     else:
         assert fam.state_operand(config).search(text)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk c=512"])
+def test_mla_moe_served_programs_fit_one_chip(topo, program):
+    """GLM-4.7-Flash at the benchmark's cut (12 of 47 layers: the dense
+    first layer and 11 expert layers; 32 of 64 experts, half the
+    vocabulary) and serving shape (8 slots x 16,384, block 16: a latent
+    pool of 8,193 blocks x 16 rows of 640 = 2.01 GB beside 8.14 GB of
+    weights): the width-8 burst and the widest chunk tier (512 rows)
+    compile for one v5e chip and fit its 15.75 GB usable.  **No program
+    copies the pool whole into another layout**: the pool's bytes are
+    aliased in and out, the temporaries stay under a quarter of one
+    layer's pool (33 MB in the burst, 1.4 MB in the chunk), and in the optimised HLO nothing but the in-place scatter makes
+    an array of the pool's shape.  (With flat rows of 576 the same
+    programs hold a 2.02 GB temporary: the pool re-laid in whole tiles
+    around every step; `models/mla_moe.py` has the numbers.)  The expert
+    products read the held stacks in place, and the ops that read the
+    pool show the stored row as the family's `latent_operand` says.  The
+    burst's latent read is the Pallas kernel (a `tpu_custom_call` for
+    layer 0 and one in the scan's body), which Mosaic compiles here: the
+    program takes the kernel or the loop by the platform it is lowered
+    for, so this host's CPU has no say."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "glm-4.7-flash-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state = resident["sequence_state"]
+    assert state.kv.shape == (12, 8193, 16, 640)
+    # the burst reads the pool by the kernel (layer 0's and the scan's),
+    # the chunk by the block loop
+    assert text.count("tpu_custom_call") == (
+        2 if program == "paged_decode_burst" else 0)
+    state_bytes = state.kv.size * 2
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize
+        for s in jax.tree.leaves(resident["params"]))
+    assert abs(resident_bytes - 10.15e9) < 0.01e9, resident_bytes
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < state_bytes / 12 / 4, \
+        mem.temp_size_in_bytes
+    pool = "bf16[" + ",".join(map(str, state.kv.shape)) + "]"
+    makers = set(re.findall(r"= " + re.escape(pool) + r"\S* ([a-z-]+)\(",
+                            text))
+    assert "scatter" in makers or "fusion" in makers, makers
+    assert makers <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                      "bitcast", "while", "dynamic-update-slice"}, makers
+    assert fam.latent_operand(config).search(text)
+    products = [
+        body for body in text.split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and fam.expert_operand(config).search(
+            body.lstrip().split("\n", 1)[0])]
+    assert len(products) >= 3, len(products)
