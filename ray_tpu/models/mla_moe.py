@@ -39,8 +39,9 @@ multi-query: H query rows of that width against one stored row whose
 first kv_rank columns are also the value
 (`ops.attention.paged_latent_attention`: a chunk through the block loop
 `paged_attention` has, a decode step lowered for a TPU through a Pallas
-kernel a layer that copies a lane's live blocks to VMEM and nothing else,
-lowered for anything else through the loop too).
+kernel a layer that copies a lane's live blocks to VMEM, each once for
+both products, and nothing else; lowered for anything else through the
+loop too).
 
 **A stored row is padded to whole lane tiles** (`row_width`: 576 ->
 640).  AOT for a described v5e at the published widths (PR 40; 12

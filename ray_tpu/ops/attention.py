@@ -662,10 +662,12 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
     | zeros), and what comes back is the probabilities applied to the
     latent, (S, K, H, d_v) float32, for the caller's value up-projection.
     A decode step (one query row a lane) lowered for a TPU is one Pallas
-    kernel a layer (`_latent_decode_kernel`); a chunk, a verify step and
-    every other platform take the same grouped running soft-max over the
-    live blocks of `[layer]` as `paged_attention`, `_LATENT_GROUP_BLOCKS`
-    a trip, the group gathered once for both products.  The platform is
+    kernel a layer, this file's own (`_latent_decode_kernel`: a live page
+    copied to VMEM once, that buffer both products' operand); a chunk, a
+    verify step and every other platform take the same grouped running
+    soft-max over the live blocks of `[layer]` as `paged_attention`,
+    `_LATENT_GROUP_BLOCKS` a trip, the group gathered once for both
+    products: one algorithm in two tilings.  The platform is
     the one the program is lowered for (`jax.lax.platform_dependent`), so
     a program compiled ahead of time for a described chip holds what the
     chip runs.  `scale` multiplies the scores (the published head size's,
@@ -696,50 +698,216 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
             f"paged_latent_attention: a pool row of {pool.shape[-1]} is not "
             f"whole tiles of {_LANES} lanes, so a decode step over "
             f"pool{pool.shape} takes the block loop on a TPU too, not the "
-            f"Pallas kernel (about a fifth slower at GLM-4.7-Flash widths)",
+            f"Pallas kernel (a step of 8 lanes takes over half as long "
+            f"again at GLM-4.7-Flash widths: 10.4 ms against 6.6)",
             stacklevel=2)
         return loop(*args)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
 
 
-# Pool blocks a grid step of the decode kernel copies in and multiplies.
-# Measured on a v5e at GLM-4.7-Flash widths (PR 40, one call; the block
-# loop above at its best trip beside it): a decode step of 8 lanes at
-# 5,200-12,600 live positions takes 9.05 / 8.57 / 8.75 / 8.89 ms with 16 /
-# 32 / 64 / 128 blocks a step against the loop's 10.42, one lane at 9,000
-# 3.11 / 3.04 / 3.09 / 3.16 against 6.19; the two agree to 0.2% of the
-# output's rms (bfloat16 rows either way).  The loop is eleven device ops
-# a trip, the kernel one a layer: a 3 s profile of the loop's bursts held
-# 1.36 M device events, 64% of them the trips'.
-_LATENT_KERNEL_PAGES = 32
+# Pool blocks a step of the decode kernel copies in and multiplies.
+# Measured on a v5e at GLM-4.7-Flash widths (`TPU v5 lite`, 2026-09-30,
+# PR 43, two calls; bfloat16 pool of 12 x 8,193 blocks of 16 rows of 640,
+# 20 heads), 16 / 32 / 64 / 128 blocks a step.  The kernel alone, ms a
+# call (one layer), 8 lanes at 5,200-12,600 live positions (71,200 rows =
+# 91 MB = 0.111 ms at 819 GB/s) | one lane of the 8 at 9,000:
+#   JAX's paged attention, the pool as keys and again as values (PR 40's)
+#                         0.307 0.264 0.284 0.304 | 0.047 0.041 0.042 0.047
+#   this kernel, copies issued from a loop
+#                         0.293 0.241 0.221 0.212 | 0.041 0.034 0.032 0.032
+#   this kernel, a page's copy under its own `pl.when` (one form for whole
+#   and part steps)             0.197 0.184 0.180 |       0.029 0.026 0.027
+#   this kernel, a whole step's copies written out
+#                         0.223 0.163 0.143 0.140 | 0.033 0.025 0.022 0.024
+#   the same with `q` standing still in the MXU (scores as rows x q^T,
+#   heads padded to a tile, turned back)
+#                         0.355 0.298 0.276 0.275 | 0.050 0.042 0.039 0.041
+#   the block loop above at its 32          0.422 |             0.304
+# The whole decode step of the benchmark's 12 layers (a burst of 8 / 8):
+#   PR 40's kernel at its 32                 8.05 |              2.99
+#   this kernel, copies written out
+#                          7.59  6.87  6.63  6.60 |  2.89  2.79  2.77  2.79
+# As committed (a third call: the fetch-ahead one site, the per-page
+# code one add and one table read) the kernel alone reads 0.137 | 0.022
+# and the step 6.57 | 2.77 at 64.
+# One copy a page is worth a tenth by itself: the copies' issue is the
+# rest.  A start or a wait issued from a loop (or behind a branch) costs
+# the kernel's one instruction stream ~9 ns, 128 of them a step of 64
+# pages 1.1 us where the step's bytes take 1.6, and the products wait
+# behind them; written out they overlap.  64 pages are within 2% of 128
+# at half the VMEM and better on a single lane.  The rows stand still in
+# the MXU for both products (20 query rows stream past each 128 x 128
+# tile of rows): with `q` still the scores come out turned and cost a
+# transposition a step.  Against the loop the kernel's output differs by
+# under 0.1% of its rms (PR 40's kernel, float32 products on a
+# bfloat16-rounded `q * scale`: 0.4%).
+_LATENT_KERNEL_PAGES = 64
 
 
+def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
+                        o_ref, buf, sems, slot_ref, *, pages, d_v, scale):
+    """One lane of `_latent_decode_kernel`'s grid: a loop over the lane's
+    steps of `pages` pool blocks under a running soft-max.  A step's live
+    blocks `[layer, table[lane, j]]` are copied, a DMA a block, into one
+    of the two halves of `buf` (2, pages * block_size, W) while the other
+    half is multiplied; the copy of a lane's first step is started by the
+    live lane before it (by lane 0 for the first), so the pipeline runs
+    through the lanes of the call.  `slot_ref` carries the half in turn
+    from lane to lane."""
+    lane, n_lanes = pl.program_id(0), pl.num_programs(0)
+    n_entries = tables_ref.shape[0] // n_lanes
+    t = buf.shape[1]                       # positions a step
+    bs = t // pages
+    length = len_ref[lane]
+
+    def next_live(start):
+        """The first live lane at or after `start`; `n_lanes`: none."""
+        return jax.lax.fori_loop(
+            start, n_lanes,
+            lambda s, found: jnp.where(
+                (found == n_lanes) & (len_ref[s] > 0), s, found), n_lanes)
+
+    def copies(of_lane, step, slot, go, written_out=True):
+        """Start (`go`) or await the copies of step `step` of `of_lane`:
+        its blocks that hold a live position, no others.  A whole step's
+        are written out one by one: issued from a loop they cost a call
+        two thirds of what its bytes do (the table above)."""
+        first = step * pages
+        live = jnp.minimum(pages, pl.cdiv(len_ref[of_lane], bs) - first)
+        entry = of_lane * n_entries + first
+        layer, half, sem = pool_ref.at[layer_ref[0]], buf.at[slot], sems.at[slot]
+
+        def one(j, _=None):
+            at = j * bs
+            if not isinstance(j, int):
+                at = pl.multiple_of(at, bs)
+            dma = pltpu.make_async_copy(
+                layer.at[tables_ref[entry + j]], half.at[pl.ds(at, bs)], sem)
+            if go:
+                dma.start()
+            else:
+                dma.wait()
+
+        def from_a_loop():
+            jax.lax.fori_loop(0, live, one, None)
+
+        if not written_out:
+            return from_a_loop()
+        pl.when(live < pages)(from_a_loop)
+
+        @pl.when(live == pages)
+        def _whole():
+            for j in range(pages):
+                one(j)
+
+    @pl.when(lane == 0)
+    def _open():
+        # Rows past a lane's length are masked out of the scores and
+        # meet a probability of 0 in the value product: they have to be
+        # numbers.  What a half holds there from then on is an earlier
+        # step's rows.
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        first = next_live(0)
+
+        @pl.when(first < n_lanes)
+        def _first_copy():              # once a call: from the loop
+            copies(first, 0, 0, True, written_out=False)
+
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _live():
+        after = next_live(lane + 1)
+        q = q_ref[0]                                       # (H, W)
+        n_steps = pl.cdiv(length, t)
+
+        def step(i, carry):
+            m, l, acc, slot = carry
+
+            more = i + 1 < n_steps        # else the next live lane's first
+
+            @pl.when(more | (after < n_lanes))
+            def _fetch_ahead():
+                copies(jnp.where(more, lane, after),
+                       jnp.where(more, i + 1, 0), 1 - slot, True)
+
+            copies(lane, i, slot, False)
+            rows = buf[slot]                               # (t, W)
+            s = jax.lax.dot_general(
+                q, rows, _NT, preferred_element_type=jnp.float32) * scale
+            seen = i * t + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) < length
+            s = jnp.where(seen, s, _NEG_INF)
+            # Position 0 is in step 0 and seen, so `m_new` is a real
+            # score from the first step on and masked entries vanish.
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = alpha * acc + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :d_v], _NN,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - slot
+
+        h = q.shape[0]
+        _, l, acc, slot = jax.lax.fori_loop(
+            0, n_steps, step,
+            (jnp.full((h, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((h, 1), jnp.float32),
+             jnp.zeros((h, d_v), jnp.float32), slot_ref[0]))
+        slot_ref[0] = slot
+        o_ref[0] = acc / l
+
+
+@functools.partial(jax.jit, static_argnames=("d_v", "scale"))
 def _latent_decode_kernel(q, pool, layer, block_tables, kv_len, *, d_v,
                           scale):
     """A decode step's attention over the latent pool as one kernel a
-    layer (JAX's Pallas paged attention, multi-query: one "KV head" whose
-    pages are the pool's blocks of every layer, (1, L * N, block_size, W),
-    a free reshape; the layer enters through the page numbers).  The pool
-    is given as keys and as values, and the caller keeps the first `d_v`
-    columns of what comes back.  Pages are copied to VMEM by DMA,
-    `_LATENT_KERNEL_PAGES` a step, double buffered, and only a lane's live
-    pages are read; an idle lane (`kv_len` 0) is skipped and reads 0.  The
-    kernel wants a table of whole steps: one that is not is padded with
-    the null block, which no length reaches."""
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention as pallas_paged_attention)
-
-    n_layers, n_blocks, bs, w = pool.shape
-    pages = pool.reshape(1, n_layers * n_blocks, bs, w)
-    tables = jnp.pad(
-        block_tables, ((0, 0), (0, -block_tables.shape[1]
-                                % _LATENT_KERNEL_PAGES)))
-    out = pallas_paged_attention(
-        (q[:, 0].astype(jnp.float32) * scale).astype(q.dtype), pages, pages,
-        kv_len.astype(jnp.int32), tables + layer * n_blocks,
-        pages_per_compute_block=_LATENT_KERNEL_PAGES)
-    out = jnp.where((kv_len > 0)[:, None, None], out, 0)
-    return out[:, None, :, :d_v].astype(jnp.float32)
+    layer, of this file's own: `q` (S, 1, H, W) against the pool where it
+    lies, (L, N, block_size, W) in HBM, with `layer`, the tables and the
+    lengths as scalars the kernel reads.  A page is copied to VMEM once
+    and that one buffer gives both products: the scores `q` against its
+    rows and the probabilities against the rows' first `d_v` columns,
+    operands in the pool's dtype, accumulation, statistics and rescaling
+    float32, the scale applied to the float32 scores (`_fa_kernel`'s
+    convention, and what the block loop's einsums are on a TPU at
+    default precision).  The grid is the lanes; `_latent_decode_body` has
+    the pipeline.  Only a lane's live pages are read; an idle lane
+    (`kv_len` 0) reads none and gets 0.  The two halves of the buffer
+    are 2.6 MB at GLM-4.7-Flash widths, so Mosaic's default share of
+    VMEM holds them (asked for `_VMEM_LIMIT`, the compiler counts 95 MB
+    more among the burst's temporaries).  Jitted, so that a program's
+    call sites (layer 0's and the scan's) trace the written-out copies
+    once a shape: traced at each, they added 13 s to a replica's
+    start.  Returns (S, 1, H, d_v) float32."""
+    s, _, h, w = q.shape
+    bs = pool.shape[2]
+    pages = min(_LATENT_KERNEL_PAGES, block_tables.shape[1])
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_body, pages=pages, d_v=d_v,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s,),
+            in_specs=[
+                pl.BlockSpec((1, h, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, d_v), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, h, d_v), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="latent_decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_tables.astype(jnp.int32).reshape(-1), kv_len.astype(jnp.int32),
+      q[:, 0].astype(pool.dtype), pool)
+    return out[:, None]
 
 
 # Differential attention (arXiv:2410.05258): softmax(q1 k1^T / sqrt(D)) v

@@ -220,46 +220,79 @@ def test_the_absorbed_and_the_plain_form_agree():
         assert not np.asarray(pool[:li]).any()       # its own layer alone
 
 
-@pytest.mark.parametrize("entries", [5, 32])
-def test_the_decode_kernel_agrees_with_the_block_loop(entries):
-    """What a TPU runs in a decode step (`_latent_decode_kernel`, JAX's
-    Pallas paged attention over the pool as pages of every layer) against
-    what every other platform runs (the block loop), on the CPU in
-    Pallas's TPU interpret mode: scattered blocks, lanes of unlike
-    lengths, a layer other than the first, a table that is and is not
-    whole steps of the kernel, rows in bfloat16 as they are served (the
-    kernel rounds wider rows to it).  An idle lane reads 0 from the
-    kernel."""
+# (heads, row width, d_v, block size, table entries, lengths, layer of 3),
+# entries and lengths as (whole steps of the kernel, more): a step is
+# `_LATENT_KERNEL_PAGES` table entries of `block size` positions.
+_SMALL = (4, 128, 96, 8)
+_KERNEL_CASES = {
+    "a table shorter than one step": (
+        *_SMALL, (0, 5), ((0, 37), (0, 0), (0, 9)), 2),
+    "a table of one whole step": (
+        *_SMALL, (1, 0), ((1, -3), (0, 0), (0, 9)), 2),
+    "a table of whole steps and a part": (
+        *_SMALL, (1, 13), ((1, 101), (0, 0), (1, 44)), 1),
+    "20 heads at 640 / 512": (
+        20, 640, 512, 16, (1, 8), ((1, 125), (0, 0), (0, 9)), 1),
+    "a whole number of steps and one over": (
+        *_SMALL, (2, 6), ((2, 0), (2, 1), (1, 0)), 2),
+    "every lane idle": (*_SMALL, (1, 0), ((0, 0), (0, 0), (0, 0)), 1),
+    "idle lanes before the first live one": (
+        *_SMALL, (1, 8), ((0, 0), (0, 0), (1, 34)), 1),
+    "layer 0": (*_SMALL, (1, 2), ((1, 13), (0, 0), (0, 9)), 0),
+    "the last layer": (*_SMALL, (1, 2), ((0, 9), (1, 13), (0, 1)), 2),
+}
+
+
+@pytest.mark.parametrize("case", _KERNEL_CASES.values(), ids=_KERNEL_CASES)
+def test_the_decode_kernel_agrees_with_the_block_loop(case):
+    """What a TPU runs in a decode step (`_latent_decode_kernel`, this
+    repo's Pallas kernel over the pool where it lies) against what every
+    other platform runs (the block loop), on the CPU in Pallas's TPU
+    interpret mode: scattered blocks, lanes of unlike lengths, rows in
+    bfloat16 as they are served.  An idle lane reads 0 from the kernel,
+    and the pool is what it was."""
     from jax.experimental.pallas import tpu as pltpu
 
     from ray_tpu.ops import attention
 
-    n_layers, n_blocks, bs, w, d_v, heads = 3, 120, 8, 128, 96, 4
+    heads, w, d_v, bs, entries, lengths, layer = case
+    pages = attention._LATENT_KERNEL_PAGES
+    entries = entries[0] * pages + entries[1]
+    lengths = [steps * pages * bs + more for steps, more in lengths]
+    n_layers, n_blocks = 3, 3 * entries + 1
     pool = jax.random.normal(jax.random.key(0), (n_layers, n_blocks, bs, w),
                              jnp.bfloat16)
+    before = np.asarray(pool.astype(jnp.float32))
     q = jax.random.normal(jax.random.key(1), (3, 1, heads, w), jnp.bfloat16)
     tables = jnp.asarray(
         np.random.default_rng(0).permutation(np.arange(1, n_blocks))
-        [:3 * entries].reshape(3, entries), jnp.int32)
-    kv_len = jnp.asarray([bs * entries - 3, 0, 9], jnp.int32)
-    args = (q, pool, 2, tables)
+        .reshape(3, entries), jnp.int32)
+    kv_len = jnp.asarray(lengths, jnp.int32)
+    assert int(kv_len.max()) <= entries * bs
+    args = (q, pool, layer, tables)
     with pltpu.force_tpu_interpret_mode():
         kernel = attention._latent_decode_kernel(
             *args, kv_len, d_v=d_v, scale=0.25)
+    assert kernel.shape == (3, 1, heads, d_v) and kernel.dtype == jnp.float32
+    live = np.asarray(kv_len) > 0
+    assert not np.asarray(kernel[~live]).any()
+    np.testing.assert_array_equal(np.asarray(pool.astype(jnp.float32)),
+                                  before)
+    if not live.any():
+        return
     loop = attention.paged_latent_attention(
         *args, (kv_len - 1)[:, None], kv_len, d_v=d_v, scale=0.25)
-    assert kernel.shape == loop.shape == (3, 1, heads, d_v)
-    live = np.asarray(kv_len) > 0
+    assert loop.shape == kernel.shape
     rms = float(jnp.sqrt(jnp.mean(loop[live] ** 2)))
-    # the kernel hands back bfloat16 (2**-9 of an element)
+    # the kernel rounds the probabilities to the rows' dtype (2**-9)
     assert float(jnp.abs(kernel - loop)[live].max()) < 1e-2 * rms
-    assert not np.asarray(kernel[~live]).any()
     # and the loop is the plain soft-max over the lane's own rows
-    rows = pool[2][tables[0]].reshape(-1, w)[:int(kv_len[0])].astype(
-        jnp.float32)
+    lane = int(np.argmax(live))
+    rows = pool[layer][tables[lane]].reshape(-1, w)[
+        :int(kv_len[lane])].astype(jnp.float32)
     p_ = jax.nn.softmax(jnp.einsum(
-        "he,te->ht", q[0, 0].astype(jnp.float32), rows) * 0.25, -1)
-    np.testing.assert_allclose(np.asarray(loop[0, 0]),
+        "he,te->ht", q[lane, 0].astype(jnp.float32), rows) * 0.25, -1)
+    np.testing.assert_allclose(np.asarray(loop[lane, 0]),
                                np.asarray(p_ @ rows[:, :d_v]), atol=1e-5)
 
 

@@ -612,10 +612,11 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
     around every step; `models/mla_moe.py` has the numbers.)  The expert
     products read the held stacks in place, and the ops that read the
     pool show the stored row as the family's `latent_operand` says.  The
-    burst's latent read is the Pallas kernel (a `tpu_custom_call` for
-    layer 0 and one in the scan's body), which Mosaic compiles here: the
-    program takes the kernel or the loop by the platform it is lowered
-    for, so this host's CPU has no say."""
+    burst's latent read is this repo's Pallas kernel (a `tpu_custom_call`
+    for layer 0 and one in the scan's body, each handed the pool once, as
+    it is stored), which Mosaic compiles here: the program takes the
+    kernel or the loop by the platform it is lowered for, so this host's
+    CPU has no say."""
     import json
     import re
 
@@ -640,7 +641,9 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
     assert state.kv.shape == (12, 8193, 16, 640)
     # the burst reads the pool by the kernel (layer 0's and the scan's),
     # the chunk by the block loop
-    assert text.count("tpu_custom_call") == (
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == text.count("tpu_custom_call") == (
         2 if program == "paged_decode_burst" else 0)
     state_bytes = state.kv.size * 2
     resident_bytes = state_bytes + sum(
@@ -658,6 +661,16 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
     assert makers <= {"parameter", "get-tuple-element", "scatter", "fusion",
                       "bitcast", "while", "dynamic-update-slice"}, makers
     assert fam.latent_operand(config).search(text)
+    # the kernel is handed the pool as it is stored, once (the borrowed
+    # kernel took it twice, as keys and as values, every layer's pages in
+    # one row), and nothing holds the pool's bytes in another shape
+    for call in calls:
+        operands = call.split("operand_layout_constraints=")[1].split(
+            "metadata=")[0]
+        assert re.findall(rf"bf16\[(?:\d+,){{3}}{state.kv.shape[-1]}\]",
+                          operands) == [pool], call
+    every_page = state.kv.shape[0] * state.kv.shape[1]
+    assert not re.search(rf"bf16\[(?:\d+,)*{every_page}[,\]]", text)
     products = [
         body for body in text.split("\n\n")
         if body.lstrip().startswith("%fused_computation")
