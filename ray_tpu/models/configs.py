@@ -131,13 +131,48 @@ TINY_MLA_MOE = MLAMoEConfig(
 # part of the stack.
 GLM_4_7_FLASH = MLAMoEConfig(name="glm-4.7-flash")
 
+# What a layer can have of its own in the homogeneous stack, at test
+# size: a dense first layer before two periods of three window layers to
+# one full layer, 8 query heads in a window layer and 6 in a full one over
+# 2 KV heads (groups of 4 and of 3), a full layer's rope under YaRN over
+# half a head and a window layer's unscaled over the whole, a gate a head
+# on the attention's output, 8 experts top-3 by sigmoid scores x 2.5 of
+# which this rank holds the lower four, a shared expert.
+TINY_GATED_MOE = TransformerConfig(
+    name="tiny-gated-moe", vocab_size=512, d_model=48, n_layers=9,
+    n_heads=6, n_heads_window=8, n_kv_heads=2, d_head=16, d_ff=160,
+    d_expert=24, d_shared=24, n_experts=8, expert_top_k=3,
+    experts_held=(0, 4), expert_scoring="sigmoid", route_scale=2.5,
+    attn_gate=True, lead_pattern=("full",),
+    layer_pattern=("window", "window", "window", "full"), window=12,
+    rope_theta=50000.0, rope_theta_window=10000.0, rotary_dim=8,
+    rotary_dim_window=16,
+    yarn=YarnScaling(factor=4.0, original_max_len=32, beta_fast=4.0,
+                     beta_slow=1.0, attention_factor=1.138629436111989),
+    norm_eps=1e-6, max_seq_len=512, remat=False,
+)
+
+# Laguna-XS.2's published sizes (33.44 B parameters, 3.0 B active a
+# token): every expert held.  40 layers are the dense one, nine periods
+# and three window layers more.
+LAGUNA_XS_2 = dataclasses.replace(
+    TINY_GATED_MOE, name="laguna-xs.2", vocab_size=100352, d_model=2048,
+    n_layers=40, n_heads=48, n_heads_window=64, n_kv_heads=8, d_head=128,
+    d_ff=8192, d_expert=512, d_shared=512, n_experts=256, expert_top_k=8,
+    experts_held=None, window=512, rope_theta=500000.0, rotary_dim=64,
+    rotary_dim_window=128,
+    yarn=YarnScaling(factor=64.0, original_max_len=4096, beta_fast=64.0,
+                     beta_slow=1.0, attention_factor=1.4158883083359672),
+    max_seq_len=262144, param_dtype=jnp.bfloat16)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
                                 TINY_HYBRID, PHI4_MINI_FLASH,
                                 TINY_WINDOW_MOE, MELLUM2_12B,
                                 TINY_MAMBA2_MOE, GRANITE4_H_SMALL,
-                                TINY_MLA_MOE, GLM_4_7_FLASH]}
+                                TINY_MLA_MOE, GLM_4_7_FLASH,
+                                TINY_GATED_MOE, LAGUNA_XS_2]}
 
 
 def get(name: str):

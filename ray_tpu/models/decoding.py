@@ -52,11 +52,12 @@ _NEG_INF = -1e30
 
 
 def _qkv(bp, x, cfg, positions, kind="full"):
+    """A layer of `kind`'s roped queries, keys and values of x (S, K, d)."""
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
     b, t = x.shape[:2]
     q = jnp.einsum("btd,dh->bth", h, bp["wq"].astype(cd)).reshape(
-        b, t, cfg.n_heads, cfg.head_dim)
+        b, t, cfg.heads(kind), cfg.head_dim)
     k = jnp.einsum("btd,dh->bth", h, bp["wk"].astype(cd)).reshape(
         b, t, cfg.n_kv_heads, cfg.head_dim)
     v = jnp.einsum("btd,dh->bth", h, bp["wv"].astype(cd)).reshape(
@@ -65,6 +66,18 @@ def _qkv(bp, x, cfg, positions, kind="full"):
     q = apply_rope(q, positions, **cfg.rope(kind))
     k = apply_rope(k, positions, **cfg.rope(kind))
     return q, k, v
+
+
+def _head_gate(bp, x, cfg):
+    """The gate on a layer's attention output, (S, K, H, 1) float32: one
+    value a query head, the sigmoid of the layer's normed input (the norm
+    `_qkv` takes too: one computation once compiled) through `head_gate`."""
+    cd = cfg.compute_dtype
+    h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
+    with jax.named_scope("attn_gate"):
+        return jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", h, bp["head_gate"].astype(cd)
+        ).astype(jnp.float32))[..., None]
 
 
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -80,31 +93,44 @@ def _layer_xs(blocks, cfg):
             {k: blocks[k] for k in _EXPERT_WEIGHTS})
 
 
+def _swiglu(bp, h, cd, prefix="w_"):
+    gate = jnp.einsum("btd,df->btf", h, bp[prefix + "gate"].astype(cd))
+    up = jnp.einsum("btd,df->btf", h, bp[prefix + "up"].astype(cd))
+    return jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * up,
+                      bp[prefix + "down"].astype(cd))
+
+
 def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
     """The block's FFN over x (S, K, d).  Returns (out, experts visited,
-    the experts each row took (S, K, top_k) if `routing`, else None):
-    with `experts` (the stacks of `_layer_xs`; `li` the layer), only
-    those that a row of a `live` lane (S,) bool is routed to are read
-    (None: every lane is live); 0 visited for a dense FFN."""
+    the experts each row took (S, K, top_k) if `routing`, else None, the
+    top-k choices that fell on experts held here from a model that
+    counts them (`counts_routed`), else None): with `experts` (the
+    stacks of `_layer_xs`; `li` the layer), only those that a `live`
+    row ((S,) bool by lane or (S, K) by row; None: every row) is routed
+    to are read, and the shared expert, where the model has one, is
+    added once; without (a model that has none, or a leading layer of
+    one that has), the dense FFN, 0 visited."""
     cd = cfg.compute_dtype
     h = rms_norm(x, bp["mlp_norm"], eps=cfg.norm_eps)
-    if cfg.n_experts > 0:
+    if experts is not None:
         # Dropless exact routing: decode must compute the same function
         # regardless of batch size (capacity routing is train-only) —
         # see moe_mlp_dropless.
         from ray_tpu.ops.moe import moe_mlp_dropless
 
+        counted = counts_routed(cfg)
         with jax.named_scope("moe"):
-            out = moe_mlp_dropless(h, {"router": bp["router"], **experts},
-                                   cfg.moe, live=live, layer=li,
-                                   return_routing=routing)
-        return out if routing else (*out, None)
-    if routing:
+            out, visited, *more = moe_mlp_dropless(
+                h, {"router": bp["router"], **experts}, cfg.moe, live=live,
+                layer=li, return_routing=routing, return_routed=counted)
+        if cfg.d_shared:
+            with jax.named_scope("shared_mlp"):
+                out = out + _swiglu(bp, h, cd, "shared_")
+        return (out, visited, more[0] if routing else None,
+                more[-1] if counted else None)
+    if routing and cfg.n_experts <= 0:
         raise ValueError(f"{cfg.name!r} has no experts: no routing to give")
-    gate = jnp.einsum("btd,df->btf", h, bp["w_gate"].astype(cd))
-    up = jnp.einsum("btd,df->btf", h, bp["w_up"].astype(cd))
-    return jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * up,
-                      bp["w_down"].astype(cd)), jnp.int32(0), None
+    return _swiglu(bp, h, cd), jnp.int32(0), None, None
 
 
 def _final_logits(params, x, cfg):
@@ -285,15 +311,14 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
     own over its own sequence state.  Both take the lanes' engine `slots`
     (S,) where the sequence keeps state by slot (None for a model whose
     state is the pool alone).  Returns (cache, hidden, experts visited,
-    the routing if asked: `_paged_forward`; the top-k choices that fell
-    on experts held here, None from a model that does not count them).
+    the routing if asked, the top-k choices that fell on experts held
+    here or None from a model that does not count them: `_paged_forward`).
     A model's own step that has experts (`n_experts`) takes `routing`
     and returns all five itself; one without returns (cache, hidden)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
-        return (*_paged_forward(params, cache, tokens, block_tables,
-                                positions, kv_len, cfg, slots, routing),
-                None)
+        return _paged_forward(params, cache, tokens, block_tables,
+                              positions, kv_len, cfg, slots, routing)
     if getattr(cfg, "n_experts", 0) > 0:
         return own(params, cache, tokens, block_tables, positions, kv_len,
                    slots, routing=routing)
@@ -315,6 +340,12 @@ def _served_logits(params, x, cfg):
     return _final_logits(params, x, cfg) if own is None else own(params, x)
 
 
+def _take(tree, i):
+    """Layer `i` of stacks (L, ..): an index, traced or not."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), tree)
+
+
 def _nth(i, per: int, rank: int):
     """Index of the `rank`-th of `per` layers a period in period `i`."""
     return i if per == 1 else i * per + rank
@@ -331,8 +362,11 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     `slots` (S,): the lanes' engine slots, whose rings a model with window
     layers reads and writes (the null slot for an idle lane).
     Returns (cache, hidden (S, K, d), experts visited summed over the
-    layers: `ops.moe.moe_mlp_dropless`; 0 without experts, and with
-    `routing` the experts every row took, (L, S, K, top_k), else None).
+    layers: `ops.moe.moe_mlp_dropless`; 0 without experts, with `routing`
+    the experts every row took, (expert layers, S, K, top_k), else None,
+    and from a model that holds a share of its experts (`counts_routed`)
+    the top-k choices of its real rows that fell on the share, summed
+    likewise, else None).
 
     Write-then-read, in place: a full layer scatters the tokens' KV into
     the pool at [layer, table[pos // bs], pos % bs] first, so the attention
@@ -341,7 +375,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     writes its slot's ring at [layer, slot, pos % R] and reads the ring.
     Pool and rings are the layer loop's carry, never its xs/ys: no slice
     of them is taken out or stacked back.  The loop runs over periods of
-    the layer pattern (`cfg.period`), its body the period's layers.
+    the layer pattern (`cfg.period`), its body the period's layers; the
+    leading layers (`cfg.lead_pattern`: blocks of their own, a dense FFN)
+    run before it and the layers behind the last whole period after it,
+    through the same body (`one`).
     """
     if cfg.state_by_slot and slots is None:
         raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
@@ -354,60 +391,95 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         block_tables, positions // bs, axis=1), 0)         # (S, K)
     off = jnp.where(live, positions % bs, 0)
     x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
-    period = cfg.period
+    period, lead, tail = cfg.period, cfg.lead_pattern, cfg.tail_pattern
     per = {kind: period.count(kind) for kind in set(period)}
+    counted = counts_routed(cfg)
+    # Whom an expert layer routes: a model that counts its choices, the
+    # real rows (a chunk's padded tail is none); else the live lanes.
+    routed_rows = positions < kv_len[:, None] if counted else live_lane
     if cfg.state_by_slot:
         ring_row = ring_rows(positions, kv_len, cache.wk.shape[2])
         lane = slots[:, None]
         read_ring = slot_ring_reader(window_attention, slots, positions,
                                      kv_len, cfg.window, cache.wk.shape[1])
 
+    def one(carry, bp, kind, at, li=None):
+        """One layer of `kind` with the weights `bp`, the `at`-th of its
+        kind (its layer of the pool or of the rings), the `li`-th of the
+        expert stacks (None: a leading layer)."""
+        x, k_pool, v_pool, wk, wv, visited, routed = carry
+        q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)
+        if kind == "full":
+            with jax.named_scope("full_attn"):
+                k_pool = k_pool.at[at, wb, off].set(
+                    k.astype(k_pool.dtype))
+                v_pool = v_pool.at[at, wb, off].set(
+                    v.astype(v_pool.dtype))
+                attn = paged_attention(q, k_pool, v_pool, at,
+                                       block_tables, positions, kv_len)
+        else:
+            with jax.named_scope("swa"):
+                wk = wk.at[at, lane, ring_row].set(
+                    k.astype(wk.dtype), mode="drop")
+                wv = wv.at[at, lane, ring_row].set(
+                    v.astype(wv.dtype), mode="drop")
+                attn = read_ring(q, wk, wv, at)
+        if cfg.attn_gate:
+            attn = attn * _head_gate(bp, x, cfg)
+        attn = attn.reshape(*tokens.shape, cfg.heads(kind) * cfg.head_dim)
+        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
+                           bp["wo"].astype(cd))
+        if li is None:
+            with jax.named_scope("dense_mlp"):
+                out, n, idx, r = _mlp(bp, x, cfg)
+        else:
+            out, n, idx, r = _mlp(bp, x, cfg, experts, li, routed_rows,
+                                  routing)
+        return (x + out, k_pool, v_pool, wk, wv, visited + n,
+                routed if r is None else routed + r), idx
+
+    def behind(i, j, kind, bps=None):
+        """Layer `j` of period `i` behind the leading layers (a traced
+        `i`: inside the scan; `cfg.n_periods`: the tail), as `one` takes
+        it: its weights (`bps` where the scan hands them), its kind, its
+        layer of the pool or of the rings, its index in the stacks."""
+        li = _nth(i, len(period), j)
+        # A period's layers index the stacks themselves: the scan's
+        # slice of a period, (p, ..), is copied out before a layer of
+        # it can be taken (AOT for a v5e, PR 34).
+        bp = bps if bps is not None else _take(blocks, li)
+        at = _nth(i, per[kind], period[:j].count(kind))
+        if cfg.heads_by_kind:
+            bp = {**bp, **_take(params["kinds"][kind], at)}
+        if kind in lead:                 # the leading layers' come first
+            at = at + lead.count(kind)
+        return bp, kind, at, li
+
     def layer(carry, layer_in):
-        x, k_pool, v_pool, wk, wv, visited = carry
         bps, i = layer_in
         taken = []
         for j, kind in enumerate(period):
-            li = _nth(i, len(period), j)
-            # A period's layers index the stacks themselves: the scan's
-            # slice of a period, (p, ..), is copied out before a layer of
-            # it can be taken (AOT for a v5e, PR 34).
-            bp = bps if len(period) == 1 else jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, False),
-                blocks)
-            at = _nth(i, per[kind], period[:j].count(kind))
-            q, k, v = _qkv(bp, x, cfg, positions, kind)    # (S,K,H,D)
-            if kind == "full":
-                with jax.named_scope("full_attn"):
-                    k_pool = k_pool.at[at, wb, off].set(
-                        k.astype(k_pool.dtype))
-                    v_pool = v_pool.at[at, wb, off].set(
-                        v.astype(v_pool.dtype))
-                    attn = paged_attention(q, k_pool, v_pool, at,
-                                           block_tables, positions, kv_len)
-            else:
-                with jax.named_scope("swa"):
-                    wk = wk.at[at, lane, ring_row].set(
-                        k.astype(wk.dtype), mode="drop")
-                    wv = wv.at[at, lane, ring_row].set(
-                        v.astype(wv.dtype), mode="drop")
-                    attn = read_ring(q, wk, wv, at)
-            attn = attn.reshape(*tokens.shape, cfg.n_heads * cfg.head_dim)
-            x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                               bp["wo"].astype(cd))
-            out, n, idx = _mlp(bp, x, cfg, experts, li, live_lane, routing)
-            x, visited = x + out, visited + n
+            carry, idx = one(carry, *behind(i, j, kind, bps))
             taken.append(idx)
-        return (x, k_pool, v_pool, wk, wv, visited), \
-            (jnp.stack(taken) if routing else None)
+        return carry, (jnp.stack(taken) if routing else None)
 
     blocks, experts = _layer_xs(params["blocks"], cfg)
-    (x, k_pool, v_pool, wk, wv, visited), taken = jax.lax.scan(
-        layer, (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0)),
-        (blocks if len(period) == 1 else None,
-         jnp.arange(cfg.n_layers // len(period))))
+    carry = (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0),
+             jnp.int32(0) if counted else None)
+    for j, kind in enumerate(lead):
+        carry, _ = one(carry, params["lead"][j], kind, lead[:j].count(kind))
+    carry, taken = jax.lax.scan(
+        layer, carry, (blocks if len(period) == 1 else None,
+                       jnp.arange(cfg.n_periods)))
     if routing:                      # (periods, p, S, K, k) -> (L, S, K, k)
-        taken = taken.reshape(cfg.n_layers, *taken.shape[2:])
-    return PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv), x, visited, taken
+        taken = taken.reshape(cfg.n_periods * len(period), *taken.shape[2:])
+    for j, kind in enumerate(tail):
+        carry, idx = one(carry, *behind(cfg.n_periods, j, kind))
+        if routing:
+            taken = jnp.concatenate([taken, idx[None]])
+    x, k_pool, v_pool, wk, wv, visited, routed = carry
+    return (PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv), x, visited,
+            taken, routed)
 
 
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
@@ -502,12 +574,11 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
         None if slot is None else jnp.asarray(slot, jnp.int32)[None],
         routing)
     if getattr(cfg, "final_logits", None) is None:
-        last = _final_logits(params, x, cfg)[0, n_valid - 1]
-        return (cache, last, taken[:, 0]) if routing else (cache, last)
-    # A model with a head of its own: over the one position asked for.
-    last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1,
-                                        axis=1)
-    out = (cache, cfg.final_logits(params, last)[0, 0])
+        out = (cache, _final_logits(params, x, cfg)[0, n_valid - 1])
+    else:   # A model with a head of its own: over the one position asked for.
+        last = jax.lax.dynamic_slice_in_dim(
+            x, jnp.maximum(n_valid - 1, 0), 1, axis=1)
+        out = (cache, cfg.final_logits(params, last)[0, 0])
     if routing:
         out += (taken[:, 0],)
     # From a model that counts them, last: the chunk's top-k choices

@@ -23,6 +23,13 @@ reference: python/ray/train/torch/train_loop_utils.py:158):
   scan over periods whose body is the period's layers, so compile time
   stays O(1) in depth.  Period 1 (every model without a pattern) is the
   scan over layers it always was.
+- **What differs by layer lives outside the layers' stacks**: leading
+  layers whose FFN is dense (`lead_pattern`) are blocks of their own
+  before the scan; where the kinds differ in query heads
+  (`n_heads_window`), what has a kind's width (`wq`, `wo`, the head gate)
+  is stacked by kind beside the stacks every layer shares.  Such a model
+  is served (`models.decoding`); `forward` below, the train and offline
+  path, says that it does not take it.
 """
 from __future__ import annotations
 
@@ -89,19 +96,56 @@ class TransformerConfig:
     window: int = 0
     # The full layers' rope scaling (a window layer's rope is unscaled).
     yarn: Optional[YarnScaling] = None
+    # What a window layer has of its own, 0: as a full layer.  Its query
+    # heads (`n_heads` is the full layers'; KV heads do not differ by
+    # kind), its rope's base, and how many of a head's dimensions its
+    # rope turns.
+    n_heads_window: int = 0
+    rope_theta_window: float = 0.0
+    rotary_dim_window: int = 0
+    # How many of a head's dimensions a full layer's rope turns, the
+    # first that many (`ops.rotary.apply_rope`); 0: all.
+    rotary_dim: int = 0
+    # A gate on the attention's output, one value a query head:
+    # sigmoid(the layer's normed input x head_gate), arXiv:2505.06708.
+    attn_gate: bool = False
+    # The kinds of the leading layers, before the periods, whose FFN is
+    # dense at width d_ff whatever n_experts says.  They count in
+    # n_layers; what follows them is whole periods and then, behind
+    # leading layers alone, the first layers of one more (40 = 1 + 9 x 4
+    # + 3; without them n_layers is whole periods or a mistake).
+    lead_pattern: Tuple[str, ...] = ()
+    # Width of a SwiGLU beside the routed experts that every token
+    # takes, added once to their sum; 0: none.
+    d_shared: int = 0
+    # One rank's share of the experts, (first, count), and how they are
+    # scored and scaled: `ops.moe.MoEConfig`'s `held`, `scoring` and
+    # `route_scale`, served only (`ops.moe.moe_mlp_dropless`).
+    experts_held: Optional[Tuple[int, int]] = None
+    expert_scoring: str = "softmax"
+    route_scale: float = 1.0
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)
+        lead = tuple(self.lead_pattern)
         object.__setattr__(self, "layer_pattern", pattern)
-        if set(pattern) - {"full", "window"}:
-            raise ValueError(f"layer_pattern {pattern}: a layer is 'full' "
-                             f"or 'window'")
-        if pattern and self.n_layers % len(pattern):
+        object.__setattr__(self, "lead_pattern", lead)
+        if set(pattern + lead) - {"full", "window"}:
+            raise ValueError(f"layer_pattern {pattern}, lead_pattern {lead}: "
+                             f"a layer is 'full' or 'window'")
+        if pattern and not lead and self.n_layers % len(pattern):
             raise ValueError(f"n_layers {self.n_layers} is not whole "
                              f"periods of {pattern}")
-        if ("window" in pattern) != (self.window > 0):
+        if len(lead) >= self.n_layers:
+            raise ValueError(f"lead_pattern {lead} leaves none of "
+                             f"{self.n_layers} layers to the periods")
+        if ("window" in pattern + lead) != (self.window > 0):
             raise ValueError("window layers and a window come together: "
                              f"layer_pattern {pattern}, window {self.window}")
+        if self.n_experts <= 0 and (self.d_shared or self.experts_held):
+            raise ValueError("a shared expert and a held share stand "
+                             "beside routed experts: n_experts is 0")
+        self.moe                        # MoEConfig checks share and scoring
 
     @property
     def head_dim(self) -> int:
@@ -111,9 +155,48 @@ class TransformerConfig:
     def period(self) -> Tuple[str, ...]:
         return self.layer_pattern or ("full",)
 
+    @property
+    def n_periods(self) -> int:
+        """Whole periods after the leading layers: the scan's length."""
+        return (self.n_layers - len(self.lead_pattern)) // len(self.period)
+
+    @property
+    def tail_pattern(self) -> Tuple[str, ...]:
+        """The layers behind the last whole period: the first of one more."""
+        return self.period[:(self.n_layers - len(self.lead_pattern))
+                           % len(self.period)]
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The kind of every layer, in order."""
+        return (self.lead_pattern + self.period * self.n_periods
+                + self.tail_pattern)
+
     def n_of(self, kind: str) -> int:
         """Layers of `kind` in the model."""
-        return self.period.count(kind) * (self.n_layers // len(self.period))
+        return self.kinds.count(kind)
+
+    def heads(self, kind: str) -> int:
+        """Query heads of a layer of `kind`."""
+        return (self.n_heads_window if kind == "window" else 0) \
+            or self.n_heads
+
+    @property
+    def heads_by_kind(self) -> bool:
+        """Whether the kinds differ in query heads, so that what has a
+        kind's width is stacked by kind (`init_params`)."""
+        return self.heads("window") != self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        """Experts whose weights are here: the share, or all."""
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Layers whose FFN is the experts: all but the leading ones."""
+        return self.n_layers - len(self.lead_pattern) \
+            if self.n_experts > 0 else 0
 
     @property
     def state_by_slot(self) -> bool:
@@ -130,8 +213,13 @@ class TransformerConfig:
 
     def rope(self, kind: str) -> dict:
         """`apply_rope`'s keywords for a layer of `kind`."""
-        return {"theta": self.rope_theta,
-                "yarn": self.yarn if kind == "full" else None}
+        full = kind == "full"
+        part = self.rotary_dim if full else \
+            self.rotary_dim_window or self.rotary_dim
+        return {"theta": self.rope_theta if full
+                else self.rope_theta_window or self.rope_theta,
+                "yarn": self.yarn if full else None,
+                **({"rotary_dim": part} if part else {})}
 
     @property
     def expert_width(self) -> int:
@@ -144,64 +232,111 @@ class TransformerConfig:
         from ray_tpu.ops.moe import MoEConfig
 
         return MoEConfig(num_experts=self.n_experts, top_k=self.expert_top_k,
-                         capacity_factor=self.capacity_factor)
+                         capacity_factor=self.capacity_factor,
+                         held=self.experts_held, scoring=self.expert_scoring,
+                         route_scale=self.route_scale)
 
     @property
     def num_params(self) -> int:
+        """Parameters held here: of the experts, the share."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-        mlp = 3 * d * f if self.n_experts <= 0 else \
-            self.n_experts * 3 * d * self.expert_width + d * self.n_experts
-        per_layer = d * q * 2 + d * kv * 2 + mlp + 2 * d \
-            + (2 * self.head_dim if self.qk_norm else 0)
-        emb = v * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * per_layer + emb + d
+        kv = self.n_kv_heads * self.head_dim
+        experts = self.n_experts_held * 3 * d * self.expert_width \
+            + d * self.n_experts + 3 * d * self.d_shared
+        total = v * d * (1 if self.tie_embeddings else 2) + d
+        for i, kind in enumerate(self.kinds):
+            h = self.heads(kind)
+            dense = self.n_experts <= 0 or i < len(self.lead_pattern)
+            total += d * h * self.head_dim * 2 + d * kv * 2 + 2 * d \
+                + (d * h if self.attn_gate else 0) \
+                + (2 * self.head_dim if self.qk_norm else 0) \
+                + (3 * d * f if dense else experts)
+        return total
 
 
 def init_params(rng: jax.Array, cfg: TransformerConfig):
-    """Parameter pytree; per-layer tensors stacked on a leading L axis."""
-    d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
+    """Parameter pytree; per-layer tensors stacked on a leading L axis:
+    `blocks`, over the layers behind the leading ones.  A leading layer
+    (`cfg.lead_pattern`) is a block of its own, unstacked, in the list
+    `lead`.  Where the kinds differ in query heads, `blocks` lacks what
+    has a kind's width (`wq`, `wo`, `head_gate`), which `kinds[kind]`
+    stacks over the layers of that kind behind the leading ones."""
+    d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
-    nh, nkv = cfg.n_heads, cfg.n_kv_heads
+    nkv = cfg.n_kv_heads
     keys = jax.random.split(rng, 8)
     dt = cfg.param_dtype
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)).astype(dt)
 
-    blocks = {
-        "attn_norm": jnp.ones((l, d), dt),
-        "wq": dense(keys[1], (l, d, nh * hd), d),
-        "wk": dense(keys[2], (l, d, nkv * hd), d),
-        "wv": dense(keys[3], (l, d, nkv * hd), d),
-        "wo": dense(keys[4], (l, nh * hd, d), nh * hd),
-        "mlp_norm": jnp.ones((l, d), dt),
-    }
-    if cfg.qk_norm:
-        # Drawn away from 1, so that a comparison notices a gain left out.
-        for i, name in enumerate(("q_norm", "k_norm")):
-            blocks[name] = (1.0 + 0.1 * jax.random.normal(
-                jax.random.fold_in(keys[1], 1 + i), (l, hd),
-                jnp.float32)).astype(dt)
+    def wide(k, l, nh):
+        """What of `l` layers (() for one) is `nh` query heads wide."""
+        out = {"wq": dense(k[1], (*l, d, nh * hd), d),
+               "wo": dense(k[4], (*l, nh * hd, d), nh * hd)}
+        if cfg.attn_gate:
+            out["head_gate"] = dense(jax.random.fold_in(k[4], 2),
+                                     (*l, d, nh), d)
+        return out
+
+    def attention(k, l, nh):
+        """`l` layers' attention and norms; `nh` None: without what is
+        stacked by kind."""
+        out = {
+            "attn_norm": jnp.ones((*l, d), dt),
+            **({} if nh is None else wide(k, l, nh)),
+            "wk": dense(k[2], (*l, d, nkv * hd), d),
+            "wv": dense(k[3], (*l, d, nkv * hd), d),
+            "mlp_norm": jnp.ones((*l, d), dt),
+        }
+        if cfg.qk_norm:
+            # Drawn away from 1, so that a comparison notices a gain left out.
+            for i, name in enumerate(("q_norm", "k_norm")):
+                out[name] = (1.0 + 0.1 * jax.random.normal(
+                    jax.random.fold_in(k[1], 1 + i), (*l, hd),
+                    jnp.float32)).astype(dt)
+        return out
+
+    def swiglu(k, l, width, prefix="w_"):
+        return {prefix + "gate": dense(k[5], (*l, d, width), d),
+                prefix + "up": dense(k[6], (*l, d, width), d),
+                prefix + "down": dense(k[7], (*l, width, d), width)}
+
+    def keys_of(i):
+        return jax.random.split(jax.random.fold_in(rng, i), 8)
+
+    n_lead = len(cfg.lead_pattern)
+    l = (cfg.n_layers - n_lead,)
+    blocks = attention(keys, l, None if cfg.heads_by_kind else cfg.n_heads)
     if cfg.n_experts > 0:
         e, f = cfg.n_experts, cfg.expert_width
+        held = cfg.n_experts_held
         blocks.update({
-            "router": dense(jax.random.fold_in(keys[5], 1), (l, d, e), d),
-            "w_gate": dense(keys[5], (l, e, d, f), d),
-            "w_up": dense(keys[6], (l, e, d, f), d),
-            "w_down": dense(keys[7], (l, e, f, d), f),
+            "router": dense(jax.random.fold_in(keys[5], 1), (*l, d, e), d),
+            "w_gate": dense(keys[5], (*l, held, d, f), d),
+            "w_up": dense(keys[6], (*l, held, d, f), d),
+            "w_down": dense(keys[7], (*l, held, f, d), f),
         })
+        if cfg.d_shared:
+            blocks.update(swiglu(keys_of(98), l, cfg.d_shared, "shared_"))
     else:
-        blocks.update({
-            "w_gate": dense(keys[5], (l, d, f), d),
-            "w_up": dense(keys[6], (l, d, f), d),
-            "w_down": dense(keys[7], (l, f, d), f),
-        })
+        blocks.update(swiglu(keys, l, f))
     params = {
         "embed": dense(keys[0], (cfg.vocab_size, d), d ** 0.5 * d),  # ~N(0, 1/sqrt(d))
         "blocks": blocks,
         "final_norm": jnp.ones((d,), dt),
     }
+    if n_lead:
+        params["lead"] = [
+            {**attention(keys_of(100 + i), (), cfg.heads(kind)),
+             **swiglu(keys_of(100 + i), (), cfg.d_ff)}
+            for i, kind in enumerate(cfg.lead_pattern)]
+    if cfg.heads_by_kind:
+        behind = cfg.kinds[n_lead:]
+        params["kinds"] = {
+            kind: wide(keys_of(200 + i), (behind.count(kind),),
+                       cfg.heads(kind))
+            for i, kind in enumerate(sorted(set(behind)))}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
     return params
@@ -209,17 +344,32 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
 
 def param_logical_axes(cfg: TransformerConfig):
     """Pytree of logical-axis tuples matching `init_params` exactly."""
-    blocks = {
-        "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", "embed"),
-    }
-    if cfg.qk_norm:
-        blocks.update({"q_norm": ("layers", "head_dim"),
-                       "k_norm": ("layers", "head_dim")})
+    def wide(l):
+        out = {"wq": (*l, "embed", "heads"), "wo": (*l, "heads", "embed")}
+        if cfg.attn_gate:
+            out["head_gate"] = (*l, "embed", "heads")
+        return out
+
+    def attention(l, by_kind):
+        out = {
+            "attn_norm": (*l, "embed"),
+            **({} if by_kind else wide(l)),
+            "wk": (*l, "embed", "kv_heads"),
+            "wv": (*l, "embed", "kv_heads"),
+            "mlp_norm": (*l, "embed"),
+        }
+        if cfg.qk_norm:
+            out.update({"q_norm": (*l, "head_dim"),
+                        "k_norm": (*l, "head_dim")})
+        return out
+
+    def swiglu(l, prefix="w_"):
+        return {prefix + "gate": (*l, "embed", "mlp"),
+                prefix + "up": (*l, "embed", "mlp"),
+                prefix + "down": (*l, "mlp", "embed")}
+
+    l = ("layers",)
+    blocks = attention(l, cfg.heads_by_kind)
     if cfg.n_experts > 0:
         blocks.update({
             "router": ("layers", "embed", "expert"),
@@ -227,17 +377,21 @@ def param_logical_axes(cfg: TransformerConfig):
             "w_up": ("layers", "expert", "embed", "mlp"),
             "w_down": ("layers", "expert", "mlp", "embed"),
         })
+        if cfg.d_shared:
+            blocks.update(swiglu(l, "shared_"))
     else:
-        blocks.update({
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        })
+        blocks.update(swiglu(l))
     axes = {
         "embed": ("vocab", "embed"),
         "blocks": blocks,
         "final_norm": ("embed",),
     }
+    if cfg.lead_pattern:
+        axes["lead"] = [{**attention((), False), **swiglu(())}
+                        for _ in cfg.lead_pattern]
+    if cfg.heads_by_kind:
+        axes["kinds"] = {kind: wide(l) for kind in sorted(
+            set(cfg.kinds[len(cfg.lead_pattern):]))}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -315,6 +469,16 @@ def forward(params, tokens, cfg: TransformerConfig, *,
     caller passes globally-consistent `positions` or we default to 0..T-1
     of the *global* view (pjit global shapes make this automatic).
     """
+    served_only = [name for name, differs in (
+        ("lead_pattern", cfg.lead_pattern), ("n_layers", cfg.tail_pattern),
+        ("n_heads_window", cfg.heads_by_kind), ("attn_gate", cfg.attn_gate),
+        ("d_shared", cfg.d_shared), ("experts_held", cfg.experts_held),
+        ("expert_scoring", cfg.expert_scoring != "softmax"),
+        ("route_scale", cfg.route_scale != 1.0)) if differs]
+    if served_only:
+        raise ValueError(
+            f"{cfg.name!r} is a served model (`models.decoding`): the train "
+            f"and offline path has no form of its {served_only}")
     cd = cfg.compute_dtype
     b, t = tokens.shape
     if positions is None:

@@ -549,20 +549,45 @@ def _dkv_call(folded, block_q, block_kv, *, causal, sm_scale):
 # 4 lanes differ by under 0.1 ms at 200 and take 7.72 / 7.02 / 6.96 / 6.61
 # at 4000; a 128-token chunk at position 3840 8.55 / 7.88 / 7.50 / 7.30.
 _PAGED_GROUP_BLOCKS = 16
+# A table of more than `_PAGED_LONG_TABLE` entries (an engine whose
+# sequences pass 8,192 positions at block 16; no cell before PR 45 has one)
+# is read in groups of `_PAGED_GROUP_BLOCKS_LONG`.  Measured on a v5e at
+# Laguna-XS.2's widths (three full layers of 48 query heads over 8 KV heads
+# among nine, 1,024 table entries; PR 45, the bare served programs), groups
+# of 16 / 32 / 64 / 128 blocks: a decode step of 8 lanes at 5,200-12,600
+# positions takes 8.53 / 8.03 / 8.36 / 8.61 ms, of 4 lanes 5.96 / 5.51 / 5.37
+# / 5.44, of 8 lanes at 300 5.46 / 5.38 / 5.51 / 5.75; a 512-row chunk at
+# position 8,192 40.2 / 39.9 / 43.2 / 50.3.  32 is the fastest at 8 lanes
+# and for the chunk, by 4% and 8% over 64.  **64 is taken for what a trip
+# costs the profiler**: a trip is 14 device ops whatever it holds, 3 s of
+# back-to-back bursts are 1.18 M device events at 16 blocks a trip (3,660 a
+# step) and 0.76 M at 64 (2,340), and the benchmark's harness waits 120 s
+# for a 3 s profile to stop: at 16 (and 128-row chunks) the traced run of
+# `lagunaxs2-agent` failed there, at 64 it stops in ~90 s (PERF.md section
+# 8, PR 40 (1) and PR 45).  A kernel a layer in place of the loop, as the
+# latent pool has, would give the 4% back and most of the events.
+_PAGED_LONG_TABLE = 512
+_PAGED_GROUP_BLOCKS_LONG = 64
 
 
 def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
                            kv_len, score, mix, stat, d_out,
-                           group_blocks=_PAGED_GROUP_BLOCKS):
+                           group_blocks=None):
     """The loop both paged bodies share: groups of `group_blocks`
-    table entries of one layer of the pool, read at `[layer, block]` as
-    stored, under a running soft-max whose trip count follows the longest
-    live lane of the call.  `score(kb)` gives a group's scaled scores
-    (S, K, *stat, t) float32 from its keys (S, t, ...), the pool's
-    trailing dimensions as stored; `mix(p, vb)` applies the probabilities
-    to its values, (S, K, *stat, d_out)."""
+    table entries (None: `_PAGED_GROUP_BLOCKS`, or
+    `_PAGED_GROUP_BLOCKS_LONG` under a table wider than
+    `_PAGED_LONG_TABLE`) of one layer of the pool, read at `[layer,
+    block]` as stored, under a running soft-max whose trip count follows
+    the longest live lane of the call.  `score(kb)` gives a group's
+    scaled scores (S, K, *stat, t) float32 from its keys (S, t, ...),
+    the pool's trailing dimensions as stored; `mix(p, vb)` applies the
+    probabilities to its values, (S, K, *stat, d_out)."""
     s, k_w = positions.shape
     bs, row = k_pool.shape[2], k_pool.shape[3:]
+    if group_blocks is None:
+        group_blocks = _PAGED_GROUP_BLOCKS \
+            if block_tables.shape[1] <= _PAGED_LONG_TABLE \
+            else _PAGED_GROUP_BLOCKS_LONG
     g = min(group_blocks, block_tables.shape[1])
     t = g * bs
     # Whole groups only: dynamic_slice would clamp a ragged last one onto

@@ -45,8 +45,8 @@ class MoEConfig:
     # largest probabilities, renormalised.  "sigmoid": s = sigmoid(logit)
     # a router output; the top_k largest of s + `router_bias` (a learned
     # (E,) vector beside the router in the parameters, which selects and
-    # does not gate) are taken, gated by their own s renormalised and
-    # multiplied by `route_scale`.
+    # does not gate; of s alone where the parameters have none) are taken,
+    # gated by their own s renormalised and multiplied by `route_scale`.
     scoring: str = "softmax"
     route_scale: float = 1.0
 
@@ -197,6 +197,36 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # 256 rows where 36 are routed to the expert.  Rows grouped by expert
 # would win both; still ROADMAP S11's, with the numbers above as its
 # baseline.
+#
+# The same loop over one rank's share, 128 held of 256 experts, top-8,
+# scored by a sigmoid (Laguna-XS.2's widths: three 2048 x 512 bf16 matrices
+# an expert, 6.3 MB; a dense first layer and two periods of three window
+# layers to one full layer, eight expert layers, a shared expert of width
+# 512 beside the routed ones; PR 45, the bare served programs on a v5e, ms a
+# decode step with the held experts read a layer beside it / ms a chunk):
+#
+#   burst at ~8,000 positions, width 4:
+#     1 live 3.95 (3.9)   2 live 4.63 (8.1)   4 live 5.32 (15.6)
+#   width 8: 4 live 6.20 (15.6), all live 7.49 (29.3); all live at ~300
+#     positions 5.21 (28.5), at ~12,000 8.70 (28.5); 1 live at ~300 2.79 (3.9)
+#   prefill chunk, seeded tokens (all 128 read), at position 0 / 4,096 /
+#   8,192: 128 tokens 19.7 / 20.7 / 21.3, 256 tokens 25.4 / 26.4 / 27.3,
+#   512 tokens 37.5 / 38.8 / 40.2 (154 / 99 / 73 us a token at position 0)
+#
+# Held experts read follow 128 (1 - (31/32)^lanes) (4.0 / 7.9 / 15.3 / 28.7)
+# to within 0.6.  One more held expert a layer costs 12-15 us (5.32 - 3.95
+# ms over 11.7 experts x 8 layers; 7.49 - 6.20 over 13.7 x 8) where its 6.3
+# MB take 7.7 us at 819 GB/s: 52-65%, under Mellum's 69% at 12.4 MB and
+# granite's 77% at 18.9 MB: the trip's own ~5-7 us again, now as long as
+# the trip's bytes.  A 128-token chunk makes 1,024 trips in some 15 of its
+# 20 ms (~15 us a trip); a 512-token chunk the same 1,024 trips in ~30 of
+# its 37.5 ms (~29 us a trip, where 512 rows x three 2048 x 512 products are
+# 3.2 GFLOP = 16 us at the chip's 197 TFLOP/s and the float32 accumulator
+# 512 x 2048 x 4 B each way is 8 MB beside the 6.3 MB of weights): every one
+# of the 128 held experts multiplies all 512 rows where 16 are routed to it
+# (4 of a row's 8 choices fall here), 32 x the products the model needs.
+# The baseline ROADMAP S11 is judged on at the smallest expert the
+# benchmark holds.
 def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
                      live: "jnp.ndarray | None" = None, layer=None,
                      return_routing: bool = False,
@@ -252,9 +282,9 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     **The scoring** is `cfg.scoring`'s (`MoEConfig`): the lines between
     the router's product and `chosen` and nothing after them, so a share,
     `live`, `return_routing` and `return_routed` mean the same under
-    either.  "sigmoid" reads `params["router_bias"]` (E,).  Soft-max
-    scoring with `route_scale` 1 traces what it traced before there was
-    a choice.
+    either.  "sigmoid" reads `params["router_bias"]` (E,) where the
+    parameters have one.  Soft-max scoring with `route_scale` 1 traces
+    what it traced before there was a choice.
     """
     b, t, d = x.shape
     dtype = x.dtype
@@ -267,8 +297,10 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     else:
         # The bias moves the selection; the gates are the unbiased scores.
         probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        bias = params.get("router_bias")
         _, expert_idx = jax.lax.top_k(
-            probs + params["router_bias"].astype(jnp.float32), cfg.top_k)
+            probs if bias is None else probs + bias.astype(jnp.float32),
+            cfg.top_k)
         gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
     gate_vals = gate_vals / jnp.maximum(
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)

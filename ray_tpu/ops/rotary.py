@@ -58,8 +58,15 @@ def rope_frequencies(head_dim: int, *, theta: float = 10000.0,
 
 
 def apply_rope(x, positions, *, theta: float = 10000.0,
-               yarn: YarnScaling | None = None):
-    """x: (B, T, H, D); positions: (B, T) or (T,) int32 global positions."""
+               yarn: YarnScaling | None = None, rotary_dim: int = 0):
+    """x: (B, T, H, D); positions: (B, T) or (T,) int32 global positions.
+    `rotary_dim` (0: all of D): the rope turns the first that many
+    dimensions of a head, paired and with frequencies (YaRN's ramp too)
+    as a head of that size would have them, and passes the rest through."""
+    if 0 < rotary_dim < x.shape[-1]:
+        return jnp.concatenate([
+            apply_rope(x[..., :rotary_dim], positions, theta=theta,
+                       yarn=yarn), x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv_freq = rope_frequencies(d, theta=theta, yarn=yarn)
     if positions.ndim == 1:

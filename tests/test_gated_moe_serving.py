@@ -1,0 +1,516 @@
+"""What a layer can have of its own in `TransformerConfig`'s stack, on the
+served path (`configs.get("tiny-gated-moe")`: a dense first layer outside
+the scan, then periods of three window layers to one full layer that
+differ in query heads (8 and 6 over 2 KV heads: groups of 4 and of 3) and
+in rope (a full layer's under YaRN over half a head, a window layer's
+unscaled over the whole), a gate a head on the attention's output, and
+one rank's share of 8 experts chosen by sigmoid scores beside a shared
+expert), held to the laguna family's plain float32 reference
+(`bench/families/laguna.py`, which imports nothing of the program):
+prefill in chunks and decode through a real `PagedLLMEngine`, pool and
+rings.  Tiny widths, seeded weights, float32 compute where the claim is
+that the engine computes the same function (errors of 1e-6), bfloat16
+where it is that the benchmark's comparison tells a fault from rounding."""
+import dataclasses
+import hashlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from bench.harness import reference, spec  # noqa: E402
+from ray_tpu.models import configs, decoding, init_params  # noqa: E402
+from ray_tpu.models.transformer import forward  # noqa: E402
+from ray_tpu.ops.moe import MoEConfig  # noqa: E402
+from ray_tpu.ops.rotary import apply_rope  # noqa: E402
+from ray_tpu.serve.llm import LLMDeployment, PagedLLMEngine  # noqa: E402
+
+TINY = os.path.join(ROOT, "bench", "tests", "data", "lagunafamily",
+                    "configs", "tinylaguna-serve.json")
+SEED = 5
+EXACT = 2e-5          # float32 engine against float32 reference
+
+
+def _config(**over):
+    with open(TINY) as f:
+        return dict(json.load(f), **over)
+
+
+def _engine(c, cfg=None, params=None, **over):
+    fam = spec.family(c)
+    true = fam.program_config(c)
+    eng = dict(c["engine"], **over)
+    return PagedLLMEngine(
+        cfg or true,
+        init_params(jax.random.key(SEED), true) if params is None else params,
+        num_slots=eng["num_slots"], max_len=eng["max_len"],
+        block_size=eng["block_size"], prefill_chunk=eng["prefill_chunk"],
+        max_burst=eng["max_burst"])
+
+
+def _errors(e, c, seqs, n_prompt):
+    """Every compared position's error against the reference, which is
+    handed the program's routing and refuses it (NaN) outside the slack."""
+    fam = spec.family(c)
+    got, taken = e.score(seqs, n_prompt, routing=True)
+    out = []
+    for lane in range(len(seqs)):
+        want, _ = fam.forward(e.params, jnp.asarray(seqs[lane], jnp.int32),
+                              c, jit=jax.jit, routing=taken[lane])
+        out.append(np.asarray(reference.position_errors(
+            jnp.stack(got[lane]), want[n_prompt - 1:])))
+    return np.concatenate(out)
+
+
+def _seqs(lanes, total, seed=0):
+    return np.random.default_rng(seed).integers(1, 512, (lanes, total))
+
+
+@pytest.fixture(scope="module")
+def served():
+    c = _config()
+    e = _engine(c)
+    yield e, c
+    e.shutdown()
+
+
+# -- the configuration ---------------------------------------------------------
+def test_the_tiny_configuration_is_the_registry_s():
+    c = _config()
+    cfg = spec.family(c).program_config(c)
+    assert cfg == dataclasses.replace(
+        configs.get("tiny-gated-moe"), name=c["name"],
+        param_dtype=jnp.dtype("float32"), compute_dtype=jnp.dtype("float32"))
+    assert cfg.kinds == ("full",) + ("window", "window", "window",
+                                     "full") * 2
+    assert cfg.n_of("window") == 6 and cfg.n_of("full") == 3
+    assert cfg.n_expert_layers == 8 and cfg.n_periods == 2
+    assert (cfg.heads("full"), cfg.heads("window")) == (6, 8)
+    assert cfg.rope("full") == {"theta": 50000.0, "yarn": cfg.yarn,
+                                "rotary_dim": 8}
+    assert cfg.rope("window") == {"theta": 10000.0, "yarn": None,
+                                  "rotary_dim": 16}
+    assert cfg.moe == MoEConfig(num_experts=8, top_k=3, held=(0, 4),
+                                scoring="sigmoid", route_scale=2.5)
+    assert cfg.state_by_slot and decoding.counts_routed(cfg)
+
+
+def test_published_keys_give_the_published_parameter_count():
+    """33.44 B ("33.4B") at one gate value a head, 3.0 B of them active a
+    token ("A3B"); at one gate value an element of a head it would be
+    34.07 B: the count settles the gate's width."""
+    cfg = configs.get("laguna-xs.2")
+    assert cfg.n_layers == 40 and cfg.n_periods == 9
+    assert cfg.tail_pattern == ("window",) * 3 and cfg.n_of("full") == 10
+    assert abs(cfg.num_params / 33.44e9 - 1) < 1e-3
+    idle = 39 * (256 - 8) * 3 * 2048 * 512
+    assert abs((cfg.num_params - idle) / 3.0e9 - 1) < 0.02
+    by_element = cfg.num_params + 2048 * 127 * (10 * 48 + 30 * 64)
+    assert abs(by_element / 34.07e9 - 1) < 1e-3
+    tiny = configs.get("tiny-gated-moe")
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), tiny))
+    assert sum(x.size for x in jax.tree.leaves(shapes)) == tiny.num_params
+    assert [sorted(b) for b in shapes["lead"]] == [sorted(
+        ["attn_norm", "mlp_norm", "wq", "wk", "wv", "wo", "head_gate",
+         "w_gate", "w_up", "w_down"])]
+    assert shapes["kinds"]["window"]["wq"].shape == (6, 48, 8 * 16)
+    assert shapes["kinds"]["full"]["head_gate"].shape == (2, 48, 6)
+    assert shapes["blocks"]["w_gate"].shape == (8, 4, 48, 24)
+    assert shapes["blocks"]["router"].shape == (8, 48, 8)
+    assert not {"wq", "wo", "head_gate", "router_bias"} & set(
+        shapes["blocks"])
+
+
+def test_the_old_configurations_are_the_objects_they_were():
+    for name in ("tiny", "tiny-moe", "tiny-window-moe", "mellum2-12b"):
+        cfg = configs.get(name)
+        assert cfg.kinds == cfg.period * (cfg.n_layers // len(cfg.period))
+        assert not cfg.lead_pattern and not cfg.tail_pattern
+        assert cfg.heads("window") == cfg.heads("full") == cfg.n_heads
+        assert not cfg.heads_by_kind and not decoding.counts_routed(cfg)
+        assert cfg.n_expert_layers == (cfg.n_layers if cfg.n_experts else 0)
+        assert "rotary_dim" not in cfg.rope("full") \
+            and "rotary_dim" not in cfg.rope("window")
+        if cfg.n_experts:
+            assert cfg.moe == MoEConfig(
+                num_experts=cfg.n_experts, top_k=cfg.expert_top_k,
+                capacity_factor=cfg.capacity_factor)
+        shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+        assert not {"lead", "kinds"} & set(shapes)
+        assert sum(x.size for x in jax.tree.leaves(shapes)) == cfg.num_params
+
+
+def test_bad_settings_are_refused():
+    tiny = configs.get("tiny-gated-moe")
+    for over in ({"lead_pattern": ("full",) * 9}, {"lead_pattern": ("mlp",)},
+                 {"experts_held": (6, 4)}, {"expert_scoring": "tanh"},
+                 {"n_experts": 0}):
+        with pytest.raises(ValueError):
+            dataclasses.replace(tiny, **over)
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), tiny))
+    with pytest.raises(ValueError, match="served model"):
+        forward(params, jnp.zeros((1, 8), jnp.int32), tiny)
+
+
+# -- (i) pool and rings, heads by kind, two ropes, the gate, the share -----------
+@pytest.mark.parametrize("n_prompt", [100, 70, 33])
+def test_prefill_in_chunks_then_decode_equals_the_reference(served, n_prompt):
+    """Prompts against a window of 12 and a ring of 12 + 32 rows: at 100
+    every window layer's ring wraps twice and the last chunk (4 tokens of
+    32) is padded, and the compared positions lie beyond the window, a
+    turn of the ring and YaRN's original 32.  Logits, not tokens."""
+    e, c = served
+    assert e.cache.wk.shape == (6, 5, 44, 2, 16)
+    assert e.cache.k.shape[0] == 3
+    seqs = _seqs(3, n_prompt + 10, seed=n_prompt)
+    errs = _errors(e, c, seqs, n_prompt)
+    assert errs.shape == (33,) and errs.max() < EXACT, errs
+
+
+def test_the_routing_handed_out_is_of_the_expert_layers(served):
+    e, c = served
+    fam = spec.family(c)
+    seqs = _seqs(2, 40, seed=3)
+    got, taken = e.score(seqs, 36, routing=True)
+    plain = e.score(seqs, 36)
+    for lane in range(2):
+        assert taken[lane].shape == (40, 8, 3)         # no leading layer
+        assert taken[lane].max() > 3                   # the router is whole
+        np.testing.assert_array_equal(np.stack(got[lane]),
+                                      np.stack(plain[lane]))
+        own, margin = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
+                                  jit=jax.jit, routing=None)
+        handed, decided = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
+                                      jit=jax.jit, routing=taken[lane])
+        assert float(decided.min()) >= 1.0 - 1e-3
+        np.testing.assert_allclose(handed, own, atol=2e-5)
+    short, _ = fam.forward(e.params, jnp.asarray(seqs[0]), c, jit=jax.jit,
+                           routing=taken[0][:, :7])
+    assert not np.isfinite(short).any()
+
+
+def _gate_left_out(cfg):
+    return dataclasses.replace(cfg, attn_gate=False)
+
+
+def _whole_head_roped(cfg):
+    return dataclasses.replace(cfg, rotary_dim=0)
+
+
+def _half_a_window_head_roped(cfg):
+    return dataclasses.replace(cfg, rotary_dim_window=8)
+
+
+def _one_theta(cfg):
+    return dataclasses.replace(cfg, rope_theta_window=0.0)
+
+
+def _yarn_left_off(cfg):
+    return dataclasses.replace(cfg, yarn=None)
+
+
+def _shared_expert_dropped(cfg):
+    return dataclasses.replace(cfg, d_shared=0)
+
+
+def _route_scale_dropped(cfg):
+    return dataclasses.replace(cfg, route_scale=1.0)
+
+
+def _softmax_for_the_sigmoid(cfg):
+    return dataclasses.replace(cfg, expert_scoring="softmax")
+
+
+FAULTS = [_gate_left_out, _whole_head_roped, _half_a_window_head_roped,
+          _one_theta, _yarn_left_off, _shared_expert_dropped,
+          _route_scale_dropped, _softmax_for_the_sigmoid]
+
+
+@pytest.mark.parametrize("fault", FAULTS,
+                         ids=lambda f: f.__name__.strip("_"))
+def test_what_is_left_out_is_seen(fault):
+    """Float32 on both sides, the seeded weights as they are (the gate's
+    logits are N(0, 1): its values lie across (0, 1), not at 1/2): a
+    program that leaves one mechanism out is thousands of times further
+    from the reference than one that does not, or routes outside the
+    slack."""
+    c = _config()
+    cfg = spec.family(c).program_config(c)
+    e = _engine(c, fault(cfg))
+    try:
+        errs = _errors(e, c, _seqs(2, 50 + 4, seed=9), 50)
+    finally:
+        e.shutdown()
+    assert not np.isfinite(errs).all() or errs.min() > 100 * EXACT, errs
+
+
+# -- (ii) the rope over a part of a head -----------------------------------------
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_the_partial_rope_is_the_reference_s(kind):
+    """`apply_rope(rotary_dim=...)` against the family's `_rope` from the
+    published keys: the first half of a head of 16 under YaRN (its ramp
+    reckoned on 8), the whole head unscaled; the dimensions past
+    `rotary_dim` pass through to the bit."""
+    c = _config()
+    fam = spec.family(c)
+    cfg = fam.program_config(c)
+    x = jax.random.normal(jax.random.key(1), (1, 90, 5, 16), jnp.float32)
+    got = apply_rope(x, jnp.arange(90), **cfg.rope(fam._KINDS[kind]))
+    np.testing.assert_allclose(got[0], fam._rope(x[0], c, kind), atol=2e-6)
+    r = cfg.rope(fam._KINDS[kind])["rotary_dim"]
+    assert r == (8 if kind == "full_attention" else 16)
+    np.testing.assert_array_equal(got[..., r:], x[..., r:])
+    if r < 16:
+        whole = apply_rope(x, jnp.arange(90), theta=50000.0, yarn=cfg.yarn)
+        assert float(jnp.abs(whole - got).max()) > 0.1
+
+
+# -- (iii) the share tied to the model -------------------------------------------
+def test_the_two_halves_add_up_to_the_uncut_layer():
+    """The program's expert layer run as either of two ranks (experts
+    0-3, experts 4-7; the router 8 wide on both), each with the shared
+    expert: the two parts, the shared expert counted once, are the uncut
+    reference's layer, and each part is the reference's given that share."""
+    whole = _config(num_experts=8)
+    fam = spec.family(whole)
+    cfg8 = fam.program_config(whole)
+    assert cfg8.experts_held is None
+    layer = next(p for i, p in enumerate(fam.layer_weights(
+        init_params(jax.random.key(SEED), cfg8), whole)) if i == 3)
+    u = jax.random.normal(jax.random.key(2), (1, 40, 48), jnp.float32)
+    stacks = ("w_gate", "w_up", "w_down")
+    parts, counts = [], []
+    for first in (0, 4):
+        half = _config(first_local_expert=first)
+        cfg = fam.program_config(half)
+        assert cfg.experts_held == (first, 4)
+        bp = {k: (v[first:first + 4] if k in stacks else v)
+              for k, v in layer.items()}
+        bp["mlp_norm"] = jnp.ones((48,))
+        out, visited, taken, routed = decoding._mlp(
+            bp, u, cfg, {k: bp[k][None] for k in stacks}, 0,
+            jnp.ones((1, 40), bool), True)
+        want, _, bad = fam.experts(fam._rms_norm(u[0], bp["mlp_norm"], 1e-6),
+                                   bp, taken[0], half)
+        assert not bool(bad.any()) and int(visited) == 4
+        shared = fam.swiglu(fam._rms_norm(u[0], bp["mlp_norm"], 1e-6), bp,
+                            "shared_")
+        np.testing.assert_allclose(out[0], want + shared, atol=2e-5)
+        parts.append(out[0])
+        counts.append(int(routed))
+    assert sum(counts) == 40 * 3 and min(counts) > 30
+    h = fam._rms_norm(u[0], jnp.ones((48,)), 1e-6)
+    uncut, _, _ = fam.experts(h, layer, None, whole)
+    np.testing.assert_allclose(parts[0] + parts[1] - shared, uncut + shared,
+                               atol=4e-5)
+    assert float(jnp.abs(parts[0] - parts[1]).max()) > 0.01
+
+
+# -- (iv) through the tick: streams, counts ---------------------------------------
+def test_streams_are_greedy_and_the_tick_log_counts_the_share(served):
+    """Two prompts generated together through the scheduler (chunks, then
+    bursts of 8 steps through rings and pool): each token is the arg-max
+    of the scoring entry's logits for the same sequence, and the ticks'
+    `routed_here` sum to the top-k choices of the rows they ran that fell
+    on the held experts (the prompts' rows and each burst's, the steps a
+    finished request no longer needed included)."""
+    e, c = served
+    before = len(e.engine_stats()["tick_log"])
+    prompts = [list(map(int, _seqs(1, n, seed=n)[0])) for n in (45, 23)]
+    import threading
+
+    outs = [None, None]
+
+    def run(i):
+        outs[i] = e.generate(prompts[i], max_tokens=17)
+
+    threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    stats = e.engine_stats()
+    ticks = [dict(zip(stats["tick_fields"], t))
+             for t in stats["tick_log"][before:]]
+    for prompt, out in zip(prompts, outs):
+        assert len(out) == 17
+        seq = np.asarray(prompt + out)
+        got, taken = e.score(seq[None], len(prompt), routing=True)
+        greedy = [int(jnp.argmax(g)) for g in got[0]]
+        assert greedy[:-1] == out
+    rows = sum(t["prefill_tokens"] + t["lanes"] * 8 for t in ticks)
+    routed = sum(t["routed_here"] for t in ticks)
+    assert sum(t["prefill_tokens"] for t in ticks) == 45 + 23
+    # 8 expert layers x top-3 a row, half of the experts held here
+    assert 0.35 < routed / (rows * 8 * 3) < 0.65, (routed, rows)
+    assert all(0 < t["experts_read"] <= 4 for t in ticks if t["lanes"])
+    assert stats["state"]["kv_window"] == e.cache.wk.size * 4 * 2
+    assert stats["state"]["state_resets"] == 0
+
+
+def test_deployment_takes_the_configuration_by_name():
+    dep = LLMDeployment("tiny-gated-moe", engine="paged", num_slots=2,
+                        max_len=128, block_size=8, prefill_chunk=32)
+    try:
+        out = dep({"tokens": list(range(1, 50)), "max_tokens": 4})
+        assert len(out["tokens"]) == 4
+        assert dep.engine.cfg.lead_pattern == ("full",)
+        with pytest.raises(ValueError, match="by slot"):
+            LLMDeployment("tiny-gated-moe", engine="paged",
+                          tensor_parallel=2)
+    finally:
+        dep.engine.shutdown()
+
+
+# -- (v) the benchmark's comparison has teeth -------------------------------------
+def _as_float8(a):
+    return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+
+
+def _cache_in_8_bits(e, fam, monkeypatch):
+    e.score(np.ones((1, 9), np.int64), 8, routing=True)   # builds them
+    for name in ("_score_chunk", "_score_step"):
+        inner = getattr(e, name)
+
+        def program(*a, _inner=inner, **kw):
+            cache, *rest = _inner(*a, **kw)
+            return (jax.tree.map(_as_float8, cache), *rest)
+
+        setattr(e, name, program)
+
+
+def _program_with(e, fam, monkeypatch, params):
+    """The program runs on `params`, the reference on the stated ones."""
+    stated, plain = e.params, fam.forward
+    e.params = params
+    monkeypatch.setattr(fam, "forward",
+                        lambda p, *a, **kw: plain(stated, *a, **kw))
+
+
+def _weights_rounded_once_more(e, fam, monkeypatch):
+    _program_with(e, fam, monkeypatch, jax.tree.map(
+        lambda a: _as_float8(a) if a.ndim >= 2 else a, e.params))
+
+
+def _one_held_expert_dropped(e, fam, monkeypatch):
+    blocks = e.params["blocks"]
+    _program_with(e, fam, monkeypatch, dict(e.params, blocks=dict(
+        blocks, w_down=blocks["w_down"].at[:, 1].set(0))))
+
+
+def _the_dense_layer_dropped(e, fam, monkeypatch):
+    lead = e.params["lead"][0]
+    _program_with(e, fam, monkeypatch, dict(e.params, lead=[dict(
+        lead, w_down=jnp.zeros_like(lead["w_down"]))]))
+
+
+# Readings at this size (CPU, seeds 5-8): as it is, a position's error has
+# medians 0.026-0.030 and a largest of 0.046-0.077 (0.046 at seed 8, which
+# the test takes) and strays by at most 0.12; the cache in 8-bit floats,
+# medians 0.10, largest 0.25, strays to 0.48-1.10.  The family's own two
+# limits (0.06, 0.1) are the published widths'; here they are 0.08 and 0.2.
+TINY_BOUND, TINY_SLACK, TEETH_SEED = 0.08, 0.2, 8
+
+
+@pytest.mark.parametrize("fault", [
+    None, _cache_in_8_bits, _weights_rounded_once_more,
+    _one_held_expert_dropped, _the_dense_layer_dropped],
+    ids=lambda f: f.__name__.strip("_") if f else "as_it_is")
+def test_logits_check_has_teeth(fault, monkeypatch):
+    """`deployment.logits_check` (3 lanes x (the last of 100 prompt
+    positions + 8 decode steps), bfloat16 parameters, compute and cache as
+    the benchmark's configuration has them, the routing handed over, held
+    to the family's own slack and to an error bound between this size's
+    two readings) passes the program as it is with every position decided
+    and fails a program that computes below bfloat16: its cache kept in
+    8-bit floats, its weights rounded once more (model-configs guide,
+    section 3.3), and one that drops a held expert or the dense layer.
+    The family's LOGITS_REL_EXPERTS was measured at the published widths;
+    at a width of 48 bfloat16 rounds coarser, so the bound here lies
+    between this size's readings (above `TINY_BOUND`)."""
+    from bench.harness.deployment import logits_check
+
+    c = _config(param_dtype="bfloat16", compute_dtype="bfloat16",
+                cache_dtype="bfloat16")
+    fam = spec.family(c)
+    monkeypatch.setitem(fam.TOLERANCES, "LOGITS_REL_EXPERTS", TINY_BOUND)
+    monkeypatch.setitem(fam.TOLERANCES, "ROUTER_SLACK", TINY_SLACK)
+    e = _engine(c, params=init_params(jax.random.key(TEETH_SEED),
+                                      fam.program_config(c)))
+    try:
+        if fault:
+            fault(e, fam, monkeypatch)
+        v = logits_check(e, c, TEETH_SEED)
+    finally:
+        e.shutdown()
+    assert v["positions"] == 27 and v["bound"] == TINY_BOUND
+    if fault is None:
+        assert v["ok"] and v["decided"] == 27, v
+        assert v["worst"] < 0.6 * v["bound"], v
+    else:
+        assert not v["ok"], v
+        assert not v["finite"] or v["worst_decided"] > v["bound"], v
+
+
+# -- (vi) the other models lower to the programs they lowered to ------------------
+# sha256 of the StableHLO text (`lowered.as_text()`: no locations) of the
+# served programs and of the scoring step, taken on PR 43's tree (commit
+# 1020620, this PR's parent) with the shapes below: the heads, ropes and
+# gate by kind, the leading layers and the tail around the scan, the
+# shared expert, the share and the scoring in `TransformerConfig.moe`, the
+# routed count through `_paged_forward` and the bias made optional in
+# `moe_mlp_dropless` leave the Mistral-, Mixtral- and Mellum-shaped
+# presets' programs as they were, to the letter: their compiled programs
+# come from the cache as before.  (tests/test_window_moe_serving.py,
+# test_mamba2_moe_serving.py and test_mla_moe_serving.py hold the same
+# presets and the other stacks at other shapes, and still do.)
+_LOWERED_AT_PR_43 = {
+    ("tiny", "chunk"): "26d36df597e7b123",
+    ("tiny", "burst"): "78168c857a908d81",
+    ("tiny", "score_step"): "c29bb156911b3f22",
+    ("tiny-moe", "chunk"): "aad04f48fab2b38e",
+    ("tiny-moe", "burst"): "303a001c72da4788",
+    ("tiny-moe", "score_step"): "f1e61955b16b9e4a",
+    ("tiny-window-moe", "chunk"): "712251b4d56b0178",
+    ("tiny-window-moe", "burst"): "f1575631141e1dd7",
+    ("tiny-window-moe", "score_step"): "c164919fcb733404",
+}
+
+
+@pytest.mark.parametrize("name,program", list(_LOWERED_AT_PR_43),
+                         ids=lambda v: str(v))
+def test_the_other_presets_lower_as_at_the_parent(name, program):
+    cfg = configs.get(name)
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: decoding.init_sequence_state(
+        cfg, 33, 16, num_slots=8, prefill_chunk=64))
+    chunk, burst, _ = decoding.make_paged_engine_fns(cfg)
+    by_slot = cfg.state_by_slot
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    lanes = (arr(8), arr(8, 16), arr(8), arr(8, dtype=jnp.bool_))
+    if program == "chunk":
+        lowered = chunk.lower(params, cache, arr(64), arr(16), arr(), arr(),
+                              **({"slot": arr()} if by_slot else {}))
+    elif program == "burst":
+        lowered = burst.lower(
+            params, cache, *lanes, arr(8, dtype=jnp.float32),
+            jax.eval_shape(lambda: jax.random.key(0)), n_steps=8,
+            **({"slots": arr(8)} if by_slot else {}))
+    else:
+        step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, cfg),
+                       static_argnames=("routing",))
+        lowered = step.lower(
+            params, cache, *lanes, **({"slots": arr(8)} if by_slot else {}),
+            **({"routing": True} if cfg.n_experts else {}))
+    digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+    assert digest == _LOWERED_AT_PR_43[(name, program)]

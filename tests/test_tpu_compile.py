@@ -678,3 +678,66 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
         and fam.expert_operand(config).search(
             body.lstrip().split("\n", 1)[0])]
     assert len(products) >= 3, len(products)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_gated_moe_served_programs_fit_one_chip(topo, program):
+    """Laguna-XS.2 at the benchmark's cut (layer 0 and two periods: three
+    full layers of 48 query heads, six window layers of 64, over 8 KV
+    heads; 128 of 256 experts; half the vocabulary) and serving shape (8
+    slots x 16384, block 16: three layers' pool 1.61 GB, six rings of
+    512 + `prefill_chunk` rows a slot beside 7.64 GB of weights): the
+    width-8 burst and the chunk compile for one v5e chip, groups of 6 and
+    of 8 query heads a KV head in one program, and fit its 15.75 GB
+    usable.  Pool and rings are updated in place (their bytes are
+    aliased) and the temporaries stay under one side of the pool (805
+    MB): nothing of pool, rings or a layer's expert stack is copied
+    whole.  The expert products read the held stacks in place, as
+    Mellum's and GLM's."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "laguna-xs.2-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state, params = resident["sequence_state"], resident["params"]
+    rows = 512 + config["engine"]["prefill_chunk"]
+    assert state.k.shape == (3, 8193, 16, 8, 128)
+    assert state.wk.shape == (6, 9, rows, 8, 128)
+    assert params["kinds"]["full"]["wq"].shape == (2, 2048, 48 * 128)
+    assert params["kinds"]["window"]["wq"].shape == (6, 2048, 64 * 128)
+    assert params["lead"][0]["w_gate"].shape == (2048, 8192)
+    assert params["blocks"]["w_gate"].shape == (8, 128, 2048, 512)
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
+    assert abs(resident_bytes - 9.40e9) < 0.1e9, resident_bytes
+    assert resident_bytes > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < state.k.size * 2, mem.temp_size_in_bytes
+    products = [
+        body for body in text.split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and fam.expert_operand(config).search(
+            body.lstrip().split("\n", 1)[0])]
+    assert len(products) >= 3, len(products)
+    ring_ops = re.findall(rf"= bf16\[[0-9,]*{rows},8,128\]", text)
+    assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
