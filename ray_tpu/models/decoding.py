@@ -465,7 +465,7 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
 
     blocks, experts = _layer_xs(params["blocks"], cfg)
     carry = (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0),
-             jnp.int32(0) if counted else None)
+             _routed_zero(tokens.size, cfg) if counted else None)
     for j, kind in enumerate(lead):
         carry, _ = one(carry, params["lead"][j], kind, lead[:j].count(kind))
     carry, taken = jax.lax.scan(
@@ -542,7 +542,7 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
 
     (cache, _, _, rng, visited, routed), toks = jax.lax.scan(
         tick, (cache, tokens, lengths, rng, jnp.int32(0),
-               jnp.int32(0) if counted else None), None,
+               _routed_zero(tokens.size, cfg) if counted else None), None,
         length=n_steps)
     if counted:
         return cache, toks, rng, visited, routed
@@ -718,3 +718,11 @@ def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):
                         donate_argnums=(1,) if donate else ())
     copy_jit = jax.jit(copy_block, donate_argnums=(0,) if donate else ())
     return chunk_jit, burst_jit, copy_jit
+
+
+def _routed_zero(n_rows: int, cfg):
+    """Where the sum of what `counts_routed` counts starts, for a launch
+    of `n_rows` rows a layer (`ops.moe.routed_zero`)."""
+    from ray_tpu.ops.moe import routed_zero
+
+    return routed_zero(n_rows, cfg.moe)

@@ -80,7 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import paged_attention
-from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless
+from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless, routed_zero
 from ray_tpu.ops.norms import rms_norm
 
 F32 = jnp.float32
@@ -480,9 +480,9 @@ def _served_step(params, state: Mamba2MoEState, tokens, block_tables,
             taken.append(got)
         return carry, (jnp.concatenate(taken) if routing else None)
 
-    zero = jnp.int32(0)
+    zero, none = jnp.int32(0), routed_zero(tokens.size, cfg.moe)
     (x, k_pool, v_pool, conv, h, visited, routed), taken = jax.lax.scan(
-        period, (x, state.k, state.v, state.conv, state.h, zero, zero),
+        period, (x, state.k, state.v, state.conv, state.h, zero, none),
         jnp.arange(cfg.periods))
     if routing:                 # (periods, p, S, K, k) -> (L, S, K, k)
         taken = taken.reshape(cfg.n_layers, *taken.shape[2:])

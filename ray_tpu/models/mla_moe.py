@@ -83,7 +83,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import paged_latent_attention
-from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless
+from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless, routed_zero
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
@@ -415,7 +415,7 @@ def _served_step(params, state: LatentState, tokens, block_tables,
                                        cfg, routing)
         return (x + out, pool, visited + n, routed + r), taken
 
-    zero = jnp.int32(0)
+    zero, none = jnp.int32(0), routed_zero(tokens.size, cfg.moe)
     (x, pool, visited, routed), taken = jax.lax.scan(
-        layer, (x, pool, zero, zero), jnp.arange(cfg.n_expert_layers))
+        layer, (x, pool, zero, none), jnp.arange(cfg.n_expert_layers))
     return LatentState(kv=pool), x, visited, taken, routed

@@ -22,10 +22,13 @@ model (`models/mamba2_moe.py`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.parallel.sharding import LogicalRules, DEFAULT_RULES, with_logical_constraint
 
@@ -118,8 +121,279 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
     return dispatch, combine, probs
 
 
-# Why `moe_mlp_dropless` is a loop over the experts that were hit, and one
-# form for every caller.  Measured on a v5e at Mixtral-8x7B's widths (8
+# A launch groups its rows by expert (`_grouped`) from this many rows on,
+# if the visit would send them through this many (rows x held experts)
+# products' rows or more; any other launch visits (the table above
+# `moe_mlp_dropless`).
+_GROUPED_FROM_ROWS = 64
+_GROUPED_FROM_PRODUCTS = 2048
+
+
+def grouped_tile_rows(n_rows: int, cfg: MoEConfig) -> int:
+    """Rows a trip of the grouped form multiplies in a launch of `n_rows`
+    rows, 0 where the launch takes the visit: a choice of static shapes
+    alone.  Half as many again as the rows an expert sees if the router
+    spreads them evenly (n_rows x top_k / num_experts, the same for a
+    share of them), rounded up to a power of two from 16 (a bf16 tile's
+    sublanes) on, so that nearly every group is one trip: a second tile
+    of an expert reads its weights again."""
+    held = cfg.held[1] if cfg.held else cfg.num_experts
+    if n_rows < _GROUPED_FROM_ROWS or n_rows * held < _GROUPED_FROM_PRODUCTS:
+        return 0
+    mean = -(-n_rows * cfg.top_k // cfg.num_experts)
+    return max(16, 1 << (mean + mean // 2 - 1).bit_length())
+
+
+def routed_zero(n_rows: int, cfg: MoEConfig):
+    """What a caller that sums `return_routed` over layers starts from:
+    a launch of `n_rows` rows counts (choices routed here, tiles) in the
+    grouped form and the choices alone in the visit."""
+    return jnp.zeros((2,), jnp.int32) if grouped_tile_rows(n_rows, cfg) \
+        else jnp.int32(0)
+
+
+# What the tile kernel may hold of an expert's three matrices at a time,
+# both of its buffers counted, and the most it may ask of a v5e's 128 MiB
+# of VMEM.
+_KERNEL_WEIGHT_BYTES = 40 * 2**20
+_VMEM_LIMIT = 100 * 2**20
+_LANES = 128
+
+
+def _f_block(d: int, f: int, itemsize: int) -> int:
+    """Columns of an expert's inner width a step of the tile kernel copies
+    in: all `f` where the three matrices fit twice, else the most whole
+    lane tiles that divide `f` and do."""
+    fits = [fb for fb in range(_LANES, f + 1, _LANES)
+            if f % fb == 0 and 6 * d * fb * itemsize <= _KERNEL_WEIGHT_BYTES]
+    return f if 6 * d * f * itemsize <= _KERNEL_WEIGHT_BYTES or not fits \
+        else fits[-1]
+
+
+def _kernel_vmem(n: int, d: int, f: int, tile: int, itemsize: int) -> int:
+    """Bytes of VMEM `_fused_ffn_kernel` asks for: the weights' blocks
+    twice, the launch's rows and their float32 sum twice and the sum once
+    more while a tile is added to it, a tile's scratches and values, a
+    margin.  The compiler counts what is asked for among a program's
+    temporaries, so no more than that."""
+    return 2**24 + d * (6 * _f_block(d, f, itemsize) * itemsize
+                        + 16 * n + 12 * tile)
+
+
+def _fused_ffn_body(ex_ref, tiles_ref, layer_ref, col_ref, row_ref, gate_ref,
+                    rows_ref, wg_ref, wu_ref, wd_ref, o_ref, x_ref, acc_ref):
+    """Step (i, j) of `_fused_ffn_kernel`'s grid.  At a tile's first step
+    its rows are picked out of the launch's rows (a one-hot product: row
+    r of the tile is row `col_ref[r]` of the launch, none where that is
+    -1), at every step they meet columns j of the expert's inner width,
+    and at its last the tile's results, rounded as the loop's product
+    comes out, are added to their rows of the launch's sum, each times its
+    gate: one-hot again, the float32 gate in three bfloat16 pieces so that
+    the products stay exact."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    n, tile = rows_ref.shape[0], x_ref.shape[0]
+    dtype = x_ref.dtype
+
+    @pl.when((i == 0) & (j == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < tiles_ref[0])
+    def _multiply():
+        @pl.when(j == 0)
+        def _open():
+            pick = jax.lax.broadcasted_iota(jnp.int32, (tile, n), 1) \
+                == col_ref[...]
+            x_ref[...] = jnp.dot(pick.astype(jnp.float32).astype(dtype),
+                                 rows_ref[...],
+                                 preferred_element_type=jnp.float32
+                                 ).astype(dtype)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]
+
+        def product(w_ref):     # rounded as the loop's product comes out
+            return jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32
+                           ).astype(dtype).astype(jnp.float32)
+
+        hidden = jax.nn.silu(product(wg_ref)) * product(wu_ref)
+        acc_ref[...] += jnp.dot(hidden.astype(dtype), wd_ref[...],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _close():
+            y = acc_ref[...].astype(dtype)
+            back = jax.lax.broadcasted_iota(jnp.int32, (n, tile), 0) \
+                == row_ref[...]
+            left = gate_ref[...]                               # (1, tile)
+            total = jnp.zeros(o_ref.shape, jnp.float32)
+            for _ in range(3):
+                piece = left.astype(dtype).astype(jnp.float32)
+                total += jnp.dot(jnp.where(back, piece, 0.0).astype(dtype),
+                                 y, preferred_element_type=jnp.float32)
+                left = left - piece
+            o_ref[...] += total
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _fused_ffn_kernel(rows, w_gate, w_up, w_down, layer, ex, tiles, src,
+                      gates, *, tile):
+    """The grouped form's products and its sum as one kernel: tile i is
+    the rows `src[i]` (n_tiles, tile; -1: none) of `rows` (N, d), through
+    expert `ex[i]` of layer `layer` of the stacks (L, E, d, f) / (L, E, f,
+    d), which stay where they lie, a block of an expert's matrices copied
+    to VMEM a step while the step before it is multiplied; each result is
+    added to its row of the output (N, d) float32 times its gate
+    (`gates`, as `src`).  The rows and the sum stay in VMEM for the whole
+    call.  Tiles from `tiles` on are not multiplied and copy nothing new
+    (their block indices stand still)."""
+    n, d = rows.shape
+    n_tiles = src.shape[0]
+    f = w_gate.shape[-1]
+    fb = _f_block(d, f, w_gate.dtype.itemsize)
+    nj = f // fb
+
+    def live(i, tiles):
+        return jnp.minimum(i, jnp.maximum(tiles[0] - 1, 0))
+
+    def col(i, j, tiles):
+        return jnp.where(i < tiles[0], j, nj - 1)
+
+    def slots(shape):
+        return pl.BlockSpec((None, *shape),
+                            lambda i, j, ex, t, li: (live(i, t), 0, 0))
+
+    def matrix(shape, at):
+        return pl.BlockSpec((None, None, *shape), lambda i, j, ex, t, li: (
+            li[0], ex[live(i, t)], *at(col(i, j, t))))
+
+    return pl.pallas_call(
+        _fused_ffn_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles, nj),
+            in_specs=[
+                slots((tile, 1)), slots((1, tile)), slots((1, tile)),
+                pl.BlockSpec((n, d), lambda i, j, *_: (0, 0)),
+                matrix((d, fb), lambda c: (0, c)),
+                matrix((d, fb), lambda c: (0, c)),
+                matrix((fb, d), lambda c: (c, 0))],
+            out_specs=pl.BlockSpec((n, d), lambda i, j, *_: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((tile, d), rows.dtype),
+                            pltpu.VMEM((tile, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_kernel_vmem(n, d, f, tile,
+                                          w_gate.dtype.itemsize)),
+        name="grouped_expert_ffn",
+    )(ex.astype(jnp.int32), jnp.asarray(tiles, jnp.int32).reshape(1),
+      jnp.asarray(layer, jnp.int32).reshape(1), src[:, :, None],
+      src[:, None, :], gates.astype(jnp.float32)[:, None, :], rows, w_gate,
+      w_up, w_down)
+
+
+def _kernel_takes(rows, w_gate, tile: int) -> bool:
+    """Whether `_fused_ffn_kernel` can run these shapes on a TPU: bfloat16
+    rows and weights in whole tiles (16 sublanes, 128 lanes), and what it
+    keeps in VMEM within the limit."""
+    n, d = rows.shape
+    f = w_gate.shape[-1]
+    return (rows.dtype == w_gate.dtype == jnp.bfloat16 and n % 16 == 0
+            and d % _LANES == 0 and f % _LANES == 0
+            and _kernel_vmem(n, d, f, tile, 2) <= _VMEM_LIMIT)
+
+
+def _expert_ffn(h, stacks, layer, ex):
+    """Expert `ex`'s FFN over h (rows, d): its three matrices out of
+    `stacks` (gate, up, down: (E, ..), or (L, E, ..) with `layer`)."""
+    def expert(stack):
+        # One dynamic slice (cast after it, never the stack), so that it
+        # fuses into the product as an operand and is not hoisted out of
+        # the loop as a layer's copy.
+        at = (ex,) if layer is None else (layer, ex)
+        return jax.lax.dynamic_slice(
+            stack, (*at, 0, 0), (1,) * len(at) + stack.shape[-2:]
+        ).reshape(stack.shape[-2:]).astype(h.dtype)
+
+    gate = h @ expert(stacks[0])
+    up = h @ expert(stacks[1])
+    return (jax.nn.silu(gate) * up) @ expert(stacks[2])
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _grouped(rows, chosen, gate_vals, stacks, layer, *, tile: int):
+    """The routed sum of `rows` (N, d) with each row's choices grouped by
+    expert: `chosen` (B, T, k, E) one-hot over the experts held here (all
+    zero for a choice that fell elsewhere or an idle row), `gate_vals`
+    (B, T, k), `stacks` the three weight stacks and `layer` their layer
+    (None: they are one layer's).  Returns (out (N, d) float32, tiles
+    multiplied: int32).  Jitted, so that a program's call sites (the
+    layers of a period, the tail) trace it once a shape: traced at each,
+    a chunk tier's lowering took half as long again.
+
+    The N x k choices are sorted by expert (those routed nowhere last), so
+    a group is a run of the sorted order, and laid out in tiles of `tile`
+    slots, every group from a tile's first slot on: a group's last tile is
+    ragged, and its spare slots hold no row.  A trip takes a tile's rows
+    through the tile's one expert and adds each result, times its gate, to
+    its row's float32 sum.  Lowered for a TPU the trips are the grid of one
+    kernel (`_fused_ffn_kernel`) where its tiles allow; elsewhere a loop
+    of the visit's own three products, on rows gathered into the layout
+    beforehand and summed by a scatter afterwards."""
+    n, d = rows.shape
+    k, e = chosen.shape[-2:]
+    m = n * k
+    flat = chosen.reshape(m, e)
+    routed = jnp.any(flat > 0, axis=1)                         # (M,)
+    key = jnp.where(routed, jnp.argmax(flat, axis=1), e)
+    order = jnp.argsort(key, stable=True)           # sorted place -> choice
+    count = jnp.sum(flat, axis=0).astype(jnp.int32)            # (E,)
+    first = jnp.cumsum(count) - count               # a group's sorted start
+    trips = -(-count // tile)
+    trips_to = jnp.cumsum(trips)                    # tiles up to and with e
+    tiles = trips_to[-1]
+    # At most M / tile whole tiles and a ragged one a group.
+    n_tiles = m // tile + e
+    ex = jnp.minimum(jnp.searchsorted(
+        trips_to, jnp.arange(n_tiles, dtype=jnp.int32), side="right"), e - 1)
+    # Tile i is the j-th of `ex[i]`: slots j * tile .. of its group.
+    in_group = ((jnp.arange(n_tiles, dtype=jnp.int32)
+                 - (trips_to - trips)[ex]) * tile)[:, None] \
+        + jnp.arange(tile, dtype=jnp.int32)                 # (tiles, tile)
+    there = in_group < count[ex][:, None]
+    choice = order[jnp.where(there, first[ex][:, None] + in_group, 0)]
+    src = jnp.where(there, choice // k, -1)         # a slot's row; -1: none
+    gates = jnp.where(there, gate_vals.reshape(m)[choice], 0.0)
+
+    def loop(rows, ex, tiles, src, gates):
+        tiled = rows[jnp.maximum(src, 0).reshape(-1)]   # (n_tiles * tile, d)
+
+        def trip(i, ys):
+            h = jax.lax.dynamic_slice_in_dim(tiled, i * tile, tile)
+            return jax.lax.dynamic_update_slice_in_dim(
+                ys, _expert_ffn(h, stacks, layer, ex[i]), i * tile, 0)
+
+        ys = jax.lax.fori_loop(0, tiles, trip, jnp.zeros_like(tiled))
+        return jnp.zeros((n, d), jnp.float32).at[
+            jnp.where(src < 0, n, src).reshape(-1)].add(
+                gates.reshape(-1, 1) * ys.astype(jnp.float32), mode="drop")
+
+    def kernel(rows, ex, tiles, src, gates):
+        whole = [w if layer is not None else w[None] for w in stacks]
+        return _fused_ffn_kernel(
+            rows, *whole, 0 if layer is None else layer, ex, tiles, src,
+            gates, tile=tile)
+
+    args = (rows, ex, tiles, src, gates)
+    if not _kernel_takes(rows, stacks[0], tile):
+        return loop(*args), tiles
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=loop), tiles
+
+
+# Why `moe_mlp_dropless` is a loop over the experts that were hit where a
+# launch is narrow, and groups its rows by expert where it is wide (the
+# last table below, PR 46).  Measured on a v5e at Mixtral-8x7B's widths (8
 # experts of three 4096 x 14336 bf16 matrices, top-2; depth 3; PR 31, the
 # bare served programs, ms a decode step / a chunk; "dense" is the product
 # over all experts with zero combine weights that stood here before):
@@ -134,10 +408,13 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 #   12.94 -> 11.59 at 1,920; of 32 tokens 12.42 -> 9.67
 #
 # One more expert a layer costs 0.47 ms = 352 MB at 757 GB/s (92% of the
-# chip's 819).  The visit is never slower, also where every expert is hit,
-# so there is no crossover and no second path; nothing here needed Pallas:
-# each product is one fusion whose operand is the stack with the slice
-# inside (AOT for a v5e; tests/test_tpu_compile.py holds it).
+# chip's 819).  The visit is never slower than the dense product, also
+# where every expert is hit, and needed no Pallas: each product is one
+# fusion whose operand is the stack with the slice inside (AOT for a v5e;
+# tests/test_tpu_compile.py holds it).  At these widths it stays the form
+# of every launch under 256 rows (PR 46's table): eight trips a layer at
+# 92% of the bandwidth leave nothing to win until the products of all
+# rows by every expert outlast the weights' copy (~240 rows).
 #
 # The same loop at 64 small experts (Mellum2-12B-A2.5B's widths: three
 # 2304 x 896 bf16 matrices an expert, 12.4 MB, top-8; depth 8, six window
@@ -164,9 +441,9 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # trip, 20 at 32 tokens): every one of the 64 experts multiplies all 128
 # rows though 16 are routed to it, 8 x the products the model needs, and
 # still waits for memory more than for the MXU.  Tokens grouped by expert
-# (a sort and one product a group) would win the 8 x and most of the 7 us;
-# that is a form of its own and a `perf_opt` PR's (ROADMAP R1), not a
-# repair of this one: at Mixtral's widths this loop is at the roofline.
+# win the 8 x and the 7 us: since PR 46 a chunk of 64 rows or more here
+# takes that form (128 rows: 16.8 -> 12.2 ms at position 2,048), the
+# bursts keep this loop.
 #
 # The same loop over one rank's share, 36 held of 72 experts, top-10
 # (granite-4.0-h-small's widths: three 4096 x 768 bf16 matrices an expert,
@@ -194,9 +471,9 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # (42 us a trip; `moe_chunk_roofline` 55% in the served cell's trace): at
 # 256 rows a trip also reads and writes the float32 accumulator (256 x 4096
 # x 4 B each way, 8 MB beside the 18.9 MB of weights) and multiplies all
-# 256 rows where 36 are routed to the expert.  Rows grouped by expert
-# would win both; still ROADMAP S11's, with the numbers above as its
-# baseline.
+# 256 rows where 36 are routed to the expert.  Rows grouped by expert win
+# both (PR 46: the 256-row chunk 22.0 -> 17.1 ms, its expert ops 15.5 ->
+# ~9.5 where the bytes take 8.3).
 #
 # The same loop over one rank's share, 128 held of 256 experts, top-8,
 # scored by a sigmoid (Laguna-XS.2's widths: three 2048 x 512 bf16 matrices
@@ -225,8 +502,73 @@ def top_k_routing(logits: jnp.ndarray, k: int, capacity: int):
 # 512 x 2048 x 4 B each way is 8 MB beside the 6.3 MB of weights): every one
 # of the 128 held experts multiplies all 512 rows where 16 are routed to it
 # (4 of a row's 8 choices fall here), 32 x the products the model needs.
-# The baseline ROADMAP S11 is judged on at the smallest expert the
-# benchmark holds.
+# The baseline ROADMAP S11's chunk half was judged on, at the smallest
+# expert the benchmark holds (PR 46: the 512-row chunk 39.3 -> 22.8 ms).
+#
+# **Rows grouped by expert** (PR 46; `_grouped`): the bare chunk programs
+# of the five configurations above at position 2,048 on a v5e (`TPU v5
+# lite`, 2026-10-01, two calls; ms a chunk; parent = the visit; "loop" =
+# the visit's three fusions on one tile of the sorted rows a trip, the
+# rows gathered into tiles before and each choice's result gathered back
+# after; "kernel" = one Pallas call a layer, its grid the tiles, on the
+# same gathered tiles; "fused" = that kernel picking a tile's rows and
+# summing its results itself: what stands; tile = `grouped_tile_rows`):
+#
+#   rows                      32     64     128    256    512   512 at 0
+#   Laguna-XS.2   visit      11.7   16.1   20.5    -     39.3    37.8
+#     (6.3 MB)    loop       12.3   15.4   17.9    -     28.9    27.9
+#                 kernel     10.0   12.3   14.1    -     24.6    23.2
+#                 fused       9.9    -     13.7    -     22.8    21.6
+#   granite-4.0-h visit      16.1   18.0   18.1   22.0
+#     (18.9 MB)   loop       16.9   18.6   19.2   23.1
+#                 kernel     14.8   15.9   16.8   20.2
+#                 fused       -     15.0    -     17.1         (17.0 at 0)
+#   GLM-4.7-Flash visit       -     14.0   17.8    -     32.3    26.3
+#     (18.9 MB)   loop        -     14.7   17.6    -     26.2    22.9
+#                 kernel      -     12.3   14.2    -     22.4    18.1
+#                 fused       -      -     13.8    -     20.4    16.5
+#   Mellum2       visit      13.4   14.9   16.8                 (11.1 at 0)
+#     (12.4 MB)   loop       14.0   15.0   15.8
+#                 kernel     11.4   12.2   12.9
+#                 fused      11.3   11.9   12.2                  (9.2 at 0)
+#   Mixtral-8x7B  visit       -     13.3   13.3   14.8   26.6    25.2
+#     (352 MB)    loop        -     13.7   14.4   14.7   17.2    18.2
+#                 kernel      -     13.9   14.6   14.8   16.4    17.4
+#                 fused       -     14.3   14.3   14.1   16.7    17.8
+#
+# Tiles half / twice the rule's (kernel; the loop moves more): Laguna at
+# 512 rows 24.8 / 26.1 against 24.6, granite at 256 rows 20.1 / 21.4
+# against 20.2, Mellum at 128 rows 13.3 / 13.4 against 12.9, Mixtral at
+# 512 rows 21.1 / 27.0 against 16.4 (a second tile of an expert reads its
+# 352 MB again; one tile of all 512 rows is the visit's product).
+# What the table says.  (1) **The loop is no form to keep**: a trip of
+# three fusions on 32-64 rows still costs 11 us at Laguna's 6.3 MB (7.7 of
+# bytes) and 33 us at granite's 18.9 MB (23), and the gathers around it
+# ~0.3 ms a layer, so at granite's widths it is slower than the visit.
+# It is what a platform without the kernel runs (the CPU's tests), and a
+# TPU for rows or weights the kernel cannot take (`_kernel_takes`).  (2)
+# The kernel's expert ops run at 85-90% of the weights' bytes (a 512-row
+# Laguna chunk 8.9 ms where 1,028 tiles x 6.3 MB x 8 layers take 7.9;
+# granite 9.8 for 8.3; GLM 9.2 for 8.1): the next expert's copy runs under
+# this tile's products.  (3) With the rows gathered and the results
+# summed by XLA around it, a layer paid 0.3-0.4 ms more outside the kernel
+# than the visit did (Laguna 12.3 -> 15.2 ms a chunk outside the expert
+# ops, granite 6.8 -> 10.7): inside the kernel both are a one-hot product
+# a tile on an MXU that waits for the copy anyway, and 1.8-3.1 ms a
+# chunk came back.  (4) **The crossover**: from 64 rows on wherever the visit
+# would send rows x held experts >= 2,048 rows through an expert's
+# products.  Under 64 rows lies every burst (Mellum's widest is 32 lanes),
+# and a burst's program stays the parent's to the letter; the kernel
+# would win a 32-row chunk too (-8 to -15%) and gives it up for that.
+# At eight experts (rows x 8 < 2,048: under 256 rows) the visit is 7%
+# faster (64 and 128 rows) and from 256 rows on slower (-5%, then -37%).
+# What is left over the bytes at the small experts is the sort and the
+# tiles' bookkeeping of 4,096-5,120 choices a layer (outside the expert
+# ops a fused chunk still spends 1.1-1.3 ms more than the visit's did,
+# 0.11-0.17 ms a layer, two gathers of 4,864 scalars 32 us each among
+# it; the expert ops themselves 8.7 ms at Laguna's widths, 9.4 at
+# granite's) and the grid's steps that do nothing (`m // tile + e` tiles
+# are laid out, about half hold rows).
 def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
                      live: "jnp.ndarray | None" = None, layer=None,
                      return_routing: bool = False,
@@ -258,13 +600,22 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
 
     A visit of the set, not a product over all experts: at decode the
     cost is the bytes of expert weights read, and 1-4 live tokens are
-    routed to 2-5 of 8 experts.  The experts that some live row chose
-    come first in an order computed from the routing, and a loop of
-    `visited` trips slices one expert's three matrices each into its
-    products over all rows, each row weighted by its gate for that
-    expert (zero where it was not chosen).  An expert no live row chose
-    is never read.  When every expert is hit (a prefill chunk) the loop
-    is the dense form, expert by expert.
+    routed to 2-5 of 8 experts.  **A launch that `grouped_tile_rows`
+    gives no tile (every decode burst, a narrow chunk, a chunk of a few
+    large experts) visits:** the experts that some live row chose come
+    first in an order computed from the routing, and a loop of `visited`
+    trips slices one expert's three matrices each into its products over
+    all rows, each row weighted by its gate for that expert (zero where
+    it was not chosen).  An expert no live row chose is never read.
+    **Any other launch (a prefill chunk) groups its rows by expert**
+    (`_grouped`): every expert would be hit and the visit be the dense
+    form, expert by expert, all rows through each; instead the rows'
+    choices are sorted by expert and a trip multiplies one tile of
+    `grouped_tile_rows` rows by the one expert they chose.  Both compute
+    one function (products in the input's dtype, gates and the sum over
+    a row's choices float32), from the same stacks, sliced inside the
+    products; which one a launch takes follows from its static shapes
+    alone.
 
     **One rank's share** (`cfg.held = (first, count)`): the weight
     stacks hold experts first .. first + count - 1 of `num_experts` and
@@ -276,8 +627,11 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     sum, and nothing stands in for the other ranks or their exchange.
     With `return_routed` one more output, last: the top-k choices of
     live rows that fell on held experts (int32; every live choice where
-    all are held).  `held=None` traces the very operations it traced
-    before there was a share.
+    all are held), from a launch that groups its rows a pair of int32,
+    (those choices, the tiles it multiplied): a caller that sums them
+    over layers starts from `routed_zero`.  `held=None` traces the very
+    operations it traced before there was a share, and a launch that
+    visits the very operations it traced before there was a second form.
 
     **The scoring** is `cfg.scoring`'s (`MoEConfig`): the lines between
     the router's product and `chosen` and nothing after them, so a share,
@@ -323,30 +677,26 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
 
     rows = x.reshape(b * t, d)
 
-    def expert(name, ex):
-        # One dynamic slice (cast after it, never the stack), so that it
-        # fuses into the product as an operand and is not hoisted out of
-        # the loop as a layer's copy.
-        stack = params[name]
-        at = (ex,) if layer is None else (layer, ex)
-        return jax.lax.dynamic_slice(
-            stack, (*at, 0, 0), (1,) * len(at) + stack.shape[-2:]
-        ).reshape(stack.shape[-2:]).astype(dtype)
+    stacks = [params[k] for k in ("w_gate", "w_up", "w_down")]
 
     def visit(i, acc):
         ex = order[i]
-        gate = rows @ expert("w_gate", ex)
-        up = rows @ expert("w_up", ex)
-        out_e = (jax.nn.silu(gate) * up) @ expert("w_down", ex)
+        out_e = _expert_ffn(rows, stacks, layer, ex)
         return acc + jax.lax.dynamic_index_in_dim(w, ex, 1) \
             * out_e.astype(jnp.float32)
 
-    out = jax.lax.fori_loop(0, visited, visit,
-                            jnp.zeros((b * t, d), jnp.float32))
+    tile = grouped_tile_rows(b * t, cfg)
+    if tile:        # (`w` and `order` are the visit's: unused, and dropped)
+        out, tiles = _grouped(rows, chosen, gate_vals, stacks, layer,
+                              tile=tile)
+    else:
+        out = jax.lax.fori_loop(0, visited, visit,
+                                jnp.zeros((b * t, d), jnp.float32))
     out = out.reshape(b, t, d).astype(dtype)
     res = (out, visited, expert_idx) if return_routing else (out, visited)
     if return_routed:
-        res += (jnp.sum(chosen).astype(jnp.int32),)
+        routed = jnp.sum(chosen).astype(jnp.int32)
+        res += (jnp.stack([routed, tiles]) if tile else routed,)
     return res
 
 

@@ -119,12 +119,13 @@ class _Request:
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "routed_here",
                "kv_read_tokens", "reset_s", "experts_read", "ahead",
-               "starved_s")
+               "starved_s", "moe_tiles")
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
 _EXPERTS_READ = TICK_FIELDS.index("experts_read")
 _ROUTED_HERE = TICK_FIELDS.index("routed_here")
+_MOE_TILES = TICK_FIELDS.index("moe_tiles")
 
 
 class _TickAccounts:
@@ -140,8 +141,9 @@ class _TickAccounts:
             self.experts_read = self.starved_s = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
-        # Where the tick's `routed_here` is summed on the device
-        # (`_count_routed`); -1: the tick launched nothing that counts.
+        # Where the tick's `routed_here` and `moe_tiles` are summed on the
+        # device (`_count_routed`); -1: the tick launched nothing that
+        # counts.
         self.routed_at = -1
 
 
@@ -534,15 +536,20 @@ class PagedLLMEngine:
             if getattr(cfg, "n_experts", 0) > 0 else 0)
         # A model that holds one rank's share of its experts: its chunk
         # and burst also hand out the top-k choices that fell on the
-        # share.  They are summed a tick on the device and read with the
-        # records, never inside a tick (`_count_routed`).
+        # share, and a launch that groups its rows by expert the tiles it
+        # multiplied beside them (`ops.moe.moe_mlp_dropless`).  They are
+        # summed a tick on the device and read with the records, never
+        # inside a tick (`_count_routed`).
         self._routed_sums = None
         if counts_routed(cfg):
-            self._routed_sums = jnp.zeros((self.TICKS_KEPT + 8,), jnp.int32)
+            self._routed_sums = jnp.zeros((self.TICKS_KEPT + 8, 2),
+                                          jnp.int32)
             self._routed_next = 0
+            # `n`: the choices alone, or (choices, tiles).
             self._add_routed = jax.jit(
                 lambda sums, at, n, fresh: sums.at[at].set(
-                    jnp.where(fresh, 0, sums[at]) + n))
+                    jnp.where(fresh, 0, sums[at])
+                    + jnp.pad(n.reshape(-1), (0, 2 - n.size))))
         self._prefill_chunk_fn, self._decode, self._copy_block = \
             make_paged_engine_fns(cfg)
         if self._spec_k:
@@ -854,10 +861,11 @@ class PagedLLMEngine:
     def _count_routed(self, routed) -> None:
         """`routed`: what a chunk or a burst of a model that holds a
         share of its experts handed out last (the top-k choices that
-        fell on the share; nothing from any other model).  Added, on the
-        device, to the sum of the tick that launched it: one small
-        launch and no read, so that a chunk stays queued behind the
-        host and a burst ahead of its read.  `_with_routed` reads the
+        fell on the share, from a launch that grouped its rows by expert
+        with the tiles it multiplied; nothing from any other model).
+        Added, on the device, to the sum of the tick that launched it:
+        one small launch and no read, so that a chunk stays queued behind
+        the host and a burst ahead of its read.  `_with_routed` reads the
         sums when the records are asked for."""
         if not routed:
             return
@@ -871,15 +879,22 @@ class PagedLLMEngine:
             fresh)
 
     def _with_routed(self, log: tuple) -> tuple:
-        """The tick log with each record's `routed_here` read from the
-        device's sums (0 where the tick counted none).  A record is
-        among the last TICKS_KEPT, so its sum has not been reused."""
+        """The tick log with each record's `routed_here` and `moe_tiles`
+        read from the device's sums (0 where the tick counted none).  A
+        record is among the last TICKS_KEPT, so its sums have not been
+        reused."""
         if self._routed_sums is None or not log:
             return log
         sums = np.asarray(self._routed_sums)
-        at = _ROUTED_HERE
-        return tuple(t[:at] + (int(sums[t[at]]) if t[at] >= 0 else 0,)
-                     + t[at + 1:] for t in log)
+
+        def read(t):
+            t = list(t)
+            t[_ROUTED_HERE], t[_MOE_TILES] = \
+                map(int, sums[t[_ROUTED_HERE]]) if t[_ROUTED_HERE] >= 0 \
+                else (0, 0)
+            return tuple(t)
+
+        return tuple(read(t) for t in log)
 
     def _reset_slot_state(self, req: "_Request", slot: int) -> None:
         """Zero `slot`'s recurrent state: `req` was admitted to it, or
@@ -1473,6 +1488,12 @@ class PagedLLMEngine:
         steps and the expert layers, that fell on experts held here (a
         model that holds one rank's share of its experts counts them; 0
         from any other; the total is top_k x the rows x the layers).
+        `moe_tiles` (the record's last field), from the same models: the
+        tiles the tick's launches that grouped their rows by expert
+        multiplied, over the expert layers (`ops.moe.grouped_tile_rows`
+        rows each: the chunks; a burst visits and counts none), so
+        `routed_here` of a tick without a burst over `moe_tiles` x the
+        tile's rows is the share of multiplied rows that were routed.
         So tick_s - decode_s -
         prefill_s - sample_s is the tick's time in which the host
         neither waited for the device nor launched: with a burst ahead
@@ -1530,7 +1551,7 @@ class PagedLLMEngine:
                        acct.routed_at if self._routed_sums is not None
                        else 0,
                        acct.kv_read_tokens, acct.reset_s,
-                       acct.experts_read, acct.ahead, acct.starved_s]
+                       acct.experts_read, acct.ahead, acct.starved_s, 0]
                 b = self._inflight
                 if b is not None and b.row is None:
                     b.row = row     # this tick's burst: logged at its read

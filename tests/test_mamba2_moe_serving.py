@@ -387,6 +387,47 @@ def test_a_slot_reused_by_a_second_request_and_the_tick_log(served):
     assert 0.3 * total < here < 0.7 * total
 
 
+@pytest.fixture()
+def grouping_chunk64(monkeypatch):
+    """An engine of 64-row chunks that group their rows by expert: as at
+    many experts (4 held ones would keep the visit up to 512 rows)."""
+    monkeypatch.setattr(moe, "_GROUPED_FROM_PRODUCTS", 0)
+    c = _config()
+    e = _engine(c, prefill_chunk=64)
+    yield e, c
+    e.shutdown()
+
+
+def test_the_tick_log_counts_the_tiles_a_wide_chunk_multiplied(
+        grouping_chunk64):
+    """A 64-row chunk groups its rows by expert and counts the tiles it
+    multiplied (`moe_tiles`, the tick log's last field): at this width a
+    tile holds a whole group, so a tile a held expert that was hit in
+    each of the 8 layers, and the rows routed here fit the tiles.  The
+    32-row chunk and the bursts visit and count none."""
+    e, c = grouping_chunk64
+    tile = moe.grouped_tile_rows(64, e.cfg.moe)
+    assert tile == 64 and not moe.grouped_tile_rows(32, e.cfg.moe)
+    n_logged = len(e.engine_stats()["tick_log"])
+    prompt = list(map(int, _seqs(1, 64 + 20, seed=31)[0]))
+    out = e.generate(prompt, max_tokens=5)
+    assert _is_greedy(e, c, prompt, out)
+    with e._tick_lock:
+        stats = e.engine_stats()
+    assert stats["tick_fields"][-1] == "moe_tiles"
+    ticks = [dict(zip(stats["tick_fields"], t))
+             for t in stats["tick_log"]][n_logged:]
+    wide = [t for t in ticks if t["prefill_tokens"] == 64]
+    assert len(wide) == 1 and not wide[0]["lanes"]
+    assert 8 * 1 <= wide[0]["moe_tiles"] <= 8 * 4
+    assert 0 < wide[0]["routed_here"] <= wide[0]["moe_tiles"] * tile
+    rest = [t for t in ticks if t["prefill_tokens"] != 64]
+    assert any(t["prefill_tokens"] == 20 for t in rest)
+    assert any(t["lanes"] for t in rest)
+    assert all(t["moe_tiles"] == 0 and t["routed_here"] > 0 for t in rest
+               if t["prefill_tokens"] or t["lanes"])
+
+
 def test_a_preempted_stream_equals_the_undisturbed_one():
     """A pool too small for two streams' growth: the younger is
     preempted mid-decode, its state is zeroed with its lengths, and its

@@ -97,12 +97,16 @@ _LIVE = {"all": lambda s: np.ones((s,), bool),
 
 @pytest.mark.parametrize("live_kind", list(_LIVE))
 @pytest.mark.parametrize("lanes,width", [(1, 1), (4, 1), (8, 1), (16, 1),
-                                         (32, 1), (1, 16)],
+                                         (32, 1), (1, 16),
+                                         # rows grouped by expert where
+                                         # the experts are many
+                                         (1, 128), (4, 32), (64, 1)],
                          ids=lambda v: str(v))
 @pytest.mark.parametrize("n_experts,top_k", [(4, 2), (8, 2), (64, 8)])
 def test_served_expert_ffn_visits_the_live_rows_experts(
         n_experts, top_k, lanes, width, live_kind):
-    from ray_tpu.ops.moe import init_moe_params, moe_mlp_dropless
+    from ray_tpu.ops.moe import (
+        grouped_tile_rows, init_moe_params, moe_mlp_dropless)
 
     d, f = 32, 64
     cfg = MoEConfig(num_experts=n_experts, top_k=top_k)
@@ -111,6 +115,8 @@ def test_served_expert_ffn_visits_the_live_rows_experts(
     x = jax.random.normal(jax.random.key(lanes * 100 + width),
                           (lanes, width, d), jnp.bfloat16)
     live = _LIVE[live_kind](lanes)
+    assert bool(grouped_tile_rows(lanes * width, cfg)) == (
+        lanes * width >= 64 and lanes * width * n_experts >= 2048)
     served = jax.jit(lambda x, live: moe_mlp_dropless(
         x, params, cfg, live=live))
     got, visited = served(x, jnp.asarray(live))
@@ -152,3 +158,283 @@ def test_served_expert_ffn_visits_the_live_rows_experts(
                                               jnp.int32(1))
     np.testing.assert_array_equal(np.asarray(stacked, np.float32), got)
     assert int(n) == int(visited)
+
+
+# ---------------------------------------------------------------------------
+# a launch of many rows: the rows' choices grouped by expert
+# ---------------------------------------------------------------------------
+def _plain_routed_sum(x, params, cfg, live=None):
+    """The plain reference with everything `MoEConfig` states: every held
+    expert's FFN over every row, combined with the top-k gates of the
+    configuration's scoring, zero for an expert a row did not choose, for
+    one held elsewhere and for a row that is not live ((B, T) bool).
+    Returns (out (B,T,d), chosen expert ids (B,T,k), the combine weights
+    over the held experts (B,T,held))."""
+    dtype = x.dtype
+    logits = jnp.einsum("btd,de->bte", x, params["router"].astype(dtype))
+    if cfg.scoring == "softmax":
+        scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        picked = scores
+    else:
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        picked = scores + params["router_bias"].astype(jnp.float32)
+    _, expert_idx = jax.lax.top_k(picked, cfg.top_k)
+    gates = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * cfg.route_scale
+    w = jnp.sum(jax.nn.one_hot(expert_idx, cfg.num_experts,
+                               dtype=jnp.float32) * gates[..., None], axis=2)
+    if live is not None:
+        w = w * live[..., None]
+    first, count = cfg.held or (0, cfg.num_experts)
+    w = w[..., first:first + count]
+    gate = jnp.einsum("btd,edf->btef", x, params["w_gate"].astype(dtype))
+    up = jnp.einsum("btd,edf->btef", x, params["w_up"].astype(dtype))
+    out_e = jnp.einsum("btef,efd->bted", jax.nn.silu(gate) * up,
+                       params["w_down"].astype(dtype))
+    out = jnp.einsum("bte,bted->btd", w, out_e.astype(jnp.float32))
+    return out.astype(dtype), expert_idx, w
+
+
+def _as_the_visit(monkeypatch, fn):
+    """`fn()` traced with no launch wide enough to group its rows."""
+    from ray_tpu.ops import moe
+
+    with monkeypatch.context() as m:
+        m.setattr(moe, "_GROUPED_FROM_ROWS", 1 << 30)
+        return fn()
+
+
+_SCORINGS = {"softmax": {},
+             "sigmoid": {"scoring": "sigmoid", "route_scale": 1.8}}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("scoring", list(_SCORINGS))
+@pytest.mark.parametrize("held", [None, (8, 16)], ids=["all", "share"])
+@pytest.mark.parametrize("rows", [32, 128, 512])
+def test_a_wide_launch_groups_its_rows_by_expert(
+        rows, held, scoring, dtype, monkeypatch):
+    """A chunk of `rows` rows, the last 9 a padded tail (`live` by row),
+    top-4 of 32 experts of which all or 16 are held: 32 rows visit, 128
+    and 512 are grouped, and both give the plain reference's sum (float32:
+    to rounding) and each other's, count what the visit counts and take
+    the stacks of all layers with the layer's index."""
+    from ray_tpu.ops.moe import (
+        grouped_tile_rows, init_moe_params, moe_mlp_dropless)
+
+    d, f = 32, 64
+    cfg = MoEConfig(num_experts=32, top_k=4, held=held, **_SCORINGS[scoring])
+    params = init_moe_params(jax.random.key(rows), d, f, cfg, dtype)
+    if scoring == "sigmoid":
+        params["router_bias"] = 0.3 * jax.random.normal(
+            jax.random.key(5), (32,), jnp.float32)
+    if held:
+        params = {k: v if k.startswith("router")
+                  else v[held[0]:held[0] + held[1]]
+                  for k, v in params.items()}
+    x = jax.random.normal(jax.random.key(rows + 1), (1, rows, d), dtype)
+    live = jnp.asarray(np.arange(rows) < rows - 9)[None]
+    tile = grouped_tile_rows(rows, cfg)
+    assert tile == {32: 0, 128: 32, 512: 128}[rows]
+
+    def run(**kw):
+        return jax.jit(lambda x, live: moe_mlp_dropless(
+            x, params, cfg, live=live, **kw))(x, live)
+
+    got, visited, taken, counted = run(return_routing=True,
+                                       return_routed=True)
+    want, chosen, w = _plain_routed_sum(x, params, cfg, live)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got32 = np.asarray(got, np.float32)
+    np.testing.assert_allclose(got32, np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    assert not got32[0, rows - 9:].any()            # the tail: zeros
+    np.testing.assert_array_equal(np.asarray(taken), np.asarray(chosen))
+    assert int(visited) == int((np.asarray(w).sum((0, 1)) > 0).sum())
+    here = int((np.asarray(w) > 0).sum())
+    # the visit: the same sum, the same counts; the grouped form also
+    # the tiles it multiplied, at least one a held expert that was hit
+    v_got, v_visited, v_counted = _as_the_visit(
+        monkeypatch, lambda: run(return_routed=True))
+    np.testing.assert_allclose(got32, np.asarray(v_got, np.float32),
+                               atol=tol, rtol=tol)
+    assert int(v_visited) == int(visited) and int(v_counted) == here
+    if tile:
+        routed, tiles = np.asarray(counted)
+        assert routed == here
+        assert int(visited) <= tiles <= here // tile + int(visited)
+    else:
+        assert int(counted) == here
+    # without the counts and the routing: the same output
+    plain, n = run()
+    np.testing.assert_array_equal(np.asarray(plain, np.float32), got32)
+    assert int(n) == int(visited)
+    # the stacks of all layers and a layer's index: the same launch
+    stacks = {k: v if k.startswith("router")
+              else jnp.stack([v * 0, v, v * 0]) for k, v in params.items()}
+    stacked, n = jax.jit(lambda x, live, li: moe_mlp_dropless(
+        x, stacks, cfg, live=live, layer=li))(x, live, jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(stacked, np.float32), got32)
+    assert int(n) == int(visited)
+
+
+# rows' scores by hand: the router is the identity on the first E features
+_BY_HAND = {
+    # every row takes experts 0 and 1: two groups of 256 rows in tiles of
+    # 128, six experts that no row chose
+    "two experts take every row": dict(
+        e=8, k=2, rows=256, score=lambda r, e: (e >= 2) * -9.0 + e * 0.1,
+        visited=2, tiles=4, tile=128),
+    # top-1 and every row on expert 3
+    "one expert takes every row": dict(
+        e=4, k=1, rows=512, score=lambda r, e: (e == 3) * 9.0,
+        visited=1, tiles=2, tile=256),
+    # rows 0..191 on experts (0, 1), the rest on (6, 7): groups of 192
+    # (two tiles of 128, the second ragged: half its slots hold no row)
+    # and of 64, experts 2..5 unread
+    "a ragged second tile": dict(
+        e=8, k=2, rows=256,
+        score=lambda r, e: np.where(r < 192, (e < 2), (e >= 6)) * 9.0
+        + e * 0.1, visited=4, tiles=6, tile=128),
+}
+
+
+@pytest.mark.parametrize("case", list(_BY_HAND))
+def test_groups_larger_than_a_tile_and_experts_no_row_chose(
+        case, monkeypatch):
+    from ray_tpu.ops.moe import (
+        grouped_tile_rows, init_moe_params, moe_mlp_dropless)
+
+    c = _BY_HAND[case]
+    e, rows, d, f = c["e"], c["rows"], 32, 64
+    cfg = MoEConfig(num_experts=e, top_k=c["k"])
+    assert grouped_tile_rows(rows, cfg) == c["tile"]
+    params = init_moe_params(jax.random.key(3), d, f, cfg, jnp.float32)
+    params["router"] = jnp.eye(d, e, dtype=jnp.float32)
+    x = np.array(jax.random.normal(jax.random.key(4), (1, rows, d)))
+    x[0, :, :e] = c["score"](np.arange(rows)[:, None], np.arange(e)[None])
+    x = jnp.asarray(x)
+
+    def run():
+        return jax.jit(lambda x: moe_mlp_dropless(
+            x, params, cfg, return_routed=True))(x)
+
+    got, visited, (routed, tiles) = run()
+    want, _, _ = _plain_routed_sum(x, params, cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    assert (int(visited), int(tiles)) == (c["visited"], c["tiles"])
+    assert int(routed) == rows * c["k"]
+    v_got, v_visited, v_routed = _as_the_visit(monkeypatch, run)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(v_got),
+                               atol=2e-5, rtol=2e-5)
+    assert int(v_visited) == c["visited"] and int(v_routed) == int(routed)
+
+
+def test_a_chunk_and_a_decode_step_compute_one_function():
+    """The rows of a 256-row chunk, grouped by expert, four by four
+    through the visit (a decode step's launch): the same rows come out."""
+    from ray_tpu.ops.moe import (
+        grouped_tile_rows, init_moe_params, moe_mlp_dropless)
+
+    cfg = MoEConfig(num_experts=16, top_k=4, held=(4, 8),
+                    scoring="sigmoid", route_scale=2.5)
+    params = init_moe_params(jax.random.key(0), 32, 64, cfg, jnp.float32)
+    params = {k: v if k == "router" else v[4:12] for k, v in params.items()}
+    x = jax.random.normal(jax.random.key(1), (1, 256, 32), jnp.float32)
+    assert grouped_tile_rows(256, cfg) and not grouped_tile_rows(4, cfg)
+    chunk, _ = jax.jit(lambda x: moe_mlp_dropless(x, params, cfg))(x)
+    step = jax.jit(lambda x: moe_mlp_dropless(x, params, cfg)[0])
+    steps = jnp.concatenate([step(x[0, i:i + 4, None])[:, 0]
+                             for i in range(0, 256, 4)])
+    np.testing.assert_allclose(np.asarray(chunk[0]), np.asarray(steps),
+                               atol=2e-5, rtol=2e-5)
+
+
+# what a TPU runs in place of the loop of tiles: this repo's kernel
+_KERNEL_CASES = {
+    "every expert held": dict(e=32, k=4, rows=128, held=None, tail=0),
+    "a share, sigmoid scores, a padded tail": dict(
+        e=16, k=4, rows=256, held=(4, 8), tail=9, scoring="sigmoid",
+        route_scale=2.5),
+    "wide tiles": dict(e=4, k=2, rows=512, held=None, tail=3),
+    "one expert takes every row": dict(e=4, k=1, rows=512, held=None,
+                                       tail=0, one=3),
+}
+
+
+@pytest.mark.parametrize("layered", [False, True], ids=["a layer", "stacks"])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_the_tile_kernel_agrees_with_the_loop_of_tiles(
+        case, layered, monkeypatch):
+    """`_fused_ffn_kernel` (the grid over tiles, a tile's rows picked and
+    its results summed inside it) against the loop of tiles that every
+    other platform runs, on the CPU in Pallas's TPU interpret mode, in
+    bfloat16 as it is served: the same sum (to the rounding of a row's
+    float32 sum in another order), the same counts, zeros for a row that
+    is not live, and the plain reference's sum."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.moe import init_moe_params, moe_mlp_dropless
+
+    c = _KERNEL_CASES[case]
+    e, rows, held, d, f = c["e"], c["rows"], c["held"], 128, 256
+    cfg = MoEConfig(num_experts=e, top_k=c["k"], held=held,
+                    scoring=c.get("scoring", "softmax"),
+                    route_scale=c.get("route_scale", 1.0))
+    params = init_moe_params(jax.random.key(2), d, f, cfg, jnp.bfloat16)
+    if cfg.scoring == "sigmoid":
+        params["router_bias"] = 0.3 * jax.random.normal(
+            jax.random.key(5), (e,), jnp.float32)
+    x = jax.random.normal(jax.random.key(6), (1, rows, d), jnp.bfloat16)
+    if "one" in c:
+        params["router"] = jnp.zeros((d, e), jnp.bfloat16).at[
+            :, c["one"]].set(1.0)
+        x = jnp.abs(x)
+    if held:
+        params = {k: v if k.startswith("router")
+                  else v[held[0]:held[0] + held[1]]
+                  for k, v in params.items()}
+    live = jnp.asarray(np.arange(rows) < rows - c["tail"])[None]
+    want, _, _ = _plain_routed_sum(x, params, cfg, live)
+    layer = None
+    if layered:
+        params = {k: v if k.startswith("router")
+                  else jnp.stack([v * 0, v, v * 0])
+                  for k, v in params.items()}
+        layer = jnp.int32(1)
+    assert moe._kernel_takes(x[0], params["w_gate"],
+                             moe.grouped_tile_rows(rows, cfg))
+
+    def run():
+        return jax.jit(lambda x, live: moe_mlp_dropless(
+            x, params, cfg, live=live, layer=layer, return_routed=True))(
+                x, live)
+
+    loop, visited, counted = run()
+    calls = []
+
+    def as_for_a_tpu(*args, tpu, default):
+        calls.append(tpu)
+        return tpu(*args)
+
+    moe._grouped.clear_cache()      # jitted: else the loop's trace again
+    try:
+        with monkeypatch.context() as m, pltpu.force_tpu_interpret_mode():
+            m.setattr(jax.lax, "platform_dependent", as_for_a_tpu)
+            kernel, k_visited, k_counted = run()
+    finally:
+        moe._grouped.clear_cache()
+    assert len(calls) == 1
+    assert kernel.dtype == loop.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(k_counted), np.asarray(counted))
+    assert int(k_visited) == int(visited)
+    loop, kernel = (np.asarray(a, np.float32) for a in (loop, kernel))
+    assert not kernel[0, rows - c["tail"]:].any() or not c["tail"]
+    np.testing.assert_allclose(kernel, loop, atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(kernel, np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
+    if "one" in c:
+        assert int(visited) == 1 and int(counted[1]) == 2

@@ -380,12 +380,48 @@ def test_tensor_parallel_served_step_compiles_for_four_chips(topo, program):
         4 * rows * max(cfg.d_model, cfg.vocab_size))
 
 
-@pytest.mark.parametrize("program", ["paged_decode_burst",
-                                     "paged_prefill_chunk"])
+def _expert_readers(text, operand):
+    """The ops of a compiled program that read a layer's experts where
+    they lie (`operand`: the family's `expert_operand`, what the
+    benchmark's rooflines look for in an op's text): the fused products
+    whose parameter is the stack, sliced inside (a launch that visits),
+    and the calls of the tile kernel (`ops.moe._fused_ffn_kernel`, a
+    launch that groups its rows by expert), each with the stacks it is
+    handed whole.  Returns (products, stacks a kernel call)."""
+    products = [
+        body for body in text.split("\n\n")
+        if body.lstrip().startswith("%fused_computation")
+        and " convolution(" in body
+        and operand.search(body.lstrip().split("\n", 1)[0])]
+    kernels = [
+        len(operand.findall(line.split("operand_layout_constraints=")[1]
+                            .split("metadata=")[0]))
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and "grouped_expert_ffn" in line]
+    return products, kernels
+
+
+def _assert_experts_read_in_place(text, operand, program):
+    """A burst visits (gate, up and down of a trip as fused products over
+    the stack); a chunk of 64 rows or more groups its rows by expert (a
+    call of the tile kernel a call site, the three stacks whole)."""
+    products, kernels = _expert_readers(text, operand)
+    if program.startswith("paged_prefill_chunk"):
+        assert kernels and set(kernels) == {3} and not products, (
+            kernels, len(products))
+    else:
+        assert len(products) >= 3 and not kernels, (len(products), kernels)
+
+
+
+@pytest.mark.parametrize("program", [
+    "paged_decode_burst", "paged_prefill_chunk",
+    "paged_prefill_chunk granite-4.0-h-small"])
 def test_expert_ffn_reads_experts_in_place(topo, program):
     """Mixtral-8x7B at its published widths, depth 2, at the benchmark's
     serving shape: the width-4 burst (the tier `mixtral-chat` decodes at)
-    and the 128-token chunk fit one v5e chip, and their temporaries stay
+    and the chunk fit one v5e chip, and their temporaries stay
     under ONE expert matrix (4096 x 14336 bf16 = 117 MB): no expert's
     weights are copied out before their product and nothing of the
     weights is laid out again.  (A layer's (8, ..) slice taken by the
@@ -394,17 +430,36 @@ def test_expert_ffn_reads_experts_in_place(topo, program):
     read the stack itself, sliced inside the fusion: at least one fused
     product has an operand shaped like a layer's experts, one leading
     dimension allowed, which is what the benchmark's `moe_ffn_roofline`
-    looks for in an op's text."""
+    looks for in an op's text.
+
+    The chunk is lowered at the widest tier the engine launches, 512
+    rows (at 128 rows eight experts keep the visit: `ops.moe.
+    grouped_tile_rows`), and groups its rows by expert (since PR 46): its
+    products are one call of the tile kernel a call site, handed the
+    three stacks of all layers whole (the same operand shape, which is
+    what `moe_chunk_roofline` looks for), tiles of 256 rows where the
+    visit took all 512 through every expert; no fused product reads an
+    expert any more, and the temporaries are still under one expert
+    matrix.  The third case holds the same at a small expert (granite-
+    4.0-h-small's file at depth 2: 36 held experts of three 4096 x 768
+    matrices, a 256-row chunk in tiles of 64): nothing a quarter as large
+    as one layer's stack of an expert matrix (226 MB) is made."""
     import json
     import re
 
+    from bench.harness import spec
     from bench.harness.spec import BENCH_DIR, transformer_config
 
+    program, _, small = program.partition(" ")
+    if small:
+        return _small_expert_chunk_groups_its_rows(topo, small)
     with open(os.path.join(BENCH_DIR, "configs",
                            "mixtral-8x7b-serve-1chip.json")) as f:
         config = json.load(f)
     config["num_hidden_layers"] = 2
     eng = config["engine"]
+    if program == "paged_prefill_chunk":
+        eng = dict(eng, prefill_chunk=512)
     cfg, params, cache, _, arr = _serve_shapes(
         topo, transformer_config(config), eng["num_slots"], eng["max_len"],
         eng["block_size"])
@@ -418,6 +473,18 @@ def test_expert_ffn_reads_experts_in_place(topo, program):
 
     expert_operand = re.compile(
         r"\[(?:\d+,)?8,(?:4096,14336|14336,4096)\]")
+    if program == "paged_prefill_chunk":
+        from ray_tpu.ops.moe import grouped_tile_rows
+
+        assert grouped_tile_rows(512, cfg.moe) == 256
+        assert not grouped_tile_rows(128, cfg.moe)
+        products, kernels = _expert_readers(compiled.as_text(),
+                                            expert_operand)
+        assert spec.family(config).expert_operand(config).pattern \
+            == expert_operand.pattern
+        # gate, up and down of a tile, in one call; nothing else reads one
+        assert kernels == [3] and not products, (kernels, len(products))
+        return
     products = [
         body for body in compiled.as_text().split("\n\n")
         if body.lstrip().startswith("%fused_computation")
@@ -425,6 +492,37 @@ def test_expert_ffn_reads_experts_in_place(topo, program):
         and expert_operand.search(body.lstrip().split("\n", 1)[0])]
     # gate, up and down of a visit
     assert len(products) >= 3, len(products)
+
+
+def _small_expert_chunk_groups_its_rows(topo, name):
+    import json
+
+    from bench.harness import spec
+    from ray_tpu.ops.moe import grouped_tile_rows
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           f"{name}-serve-1chip.json")) as f:
+        config = json.load(f)
+    config["num_hidden_layers"] = 2
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    _, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs
+                  if name.startswith("paged_prefill_chunk")]
+    cfg = fam.program_config(config)
+    assert cfg.moe.held == (0, 36) and grouped_tile_rows(256, cfg.moe) == 64
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    products, kernels = _expert_readers(text, fam.expert_operand(config))
+    assert kernels and set(kernels) == {3} and not products, kernels
+    expert_matrix_stack = 36 * 4096 * 768 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < expert_matrix_stack / 4
 
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
@@ -515,13 +613,8 @@ def test_window_moe_served_programs_fit_one_chip(topo, program):
     assert _device_bytes(compiled) < 15.75e9
     ring_array = state.wk.size * 2
     assert mem.temp_size_in_bytes < ring_array, mem.temp_size_in_bytes
-    products = [
-        body for body in compiled.as_text().split("\n\n")
-        if body.lstrip().startswith("%fused_computation")
-        and " convolution(" in body
-        and fam.expert_operand(config).search(
-            body.lstrip().split("\n", 1)[0])]
-    assert len(products) >= 3, len(products)
+    _assert_experts_read_in_place(compiled.as_text(),
+                                  fam.expert_operand(config), program)
     ring_ops = re.findall(r"= bf16\[[0-9,]*1152,4,128\]", compiled.as_text())
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
 
@@ -579,13 +672,7 @@ def test_mamba2_moe_served_programs_fit_one_chip(topo, program):
     expert_stack = 36 * 4096 * 768 * 2
     assert mem.temp_size_in_bytes < expert_stack, mem.temp_size_in_bytes
     text = compiled.as_text()
-    products = [
-        body for body in text.split("\n\n")
-        if body.lstrip().startswith("%fused_computation")
-        and " convolution(" in body
-        and fam.expert_operand(config).search(
-            body.lstrip().split("\n", 1)[0])]
-    assert len(products) >= 3, len(products)
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert loops and not [name for name in loops if "/ssd" in name], loops
     if program == "paged_prefill_chunk":
@@ -642,8 +729,11 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
     # the burst reads the pool by the kernel (layer 0's and the scan's),
     # the chunk by the block loop
     calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == text.count("tpu_custom_call") == (
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "grouped_expert_ffn" not in line]
+    # (the chunk's one other kernel is the experts' tiles: PR 46)
+    assert len(calls) == text.count("tpu_custom_call") - (
+        program != "paged_decode_burst") == (
         2 if program == "paged_decode_burst" else 0)
     state_bytes = state.kv.size * 2
     resident_bytes = state_bytes + sum(
@@ -671,13 +761,7 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
                           operands) == [pool], call
     every_page = state.kv.shape[0] * state.kv.shape[1]
     assert not re.search(rf"bf16\[(?:\d+,)*{every_page}[,\]]", text)
-    products = [
-        body for body in text.split("\n\n")
-        if body.lstrip().startswith("%fused_computation")
-        and " convolution(" in body
-        and fam.expert_operand(config).search(
-            body.lstrip().split("\n", 1)[0])]
-    assert len(products) >= 3, len(products)
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
 
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
@@ -732,12 +816,6 @@ def test_gated_moe_served_programs_fit_one_chip(topo, program):
     assert mem.alias_size_in_bytes >= state_bytes
     assert _device_bytes(compiled) < 15.75e9
     assert mem.temp_size_in_bytes < state.k.size * 2, mem.temp_size_in_bytes
-    products = [
-        body for body in text.split("\n\n")
-        if body.lstrip().startswith("%fused_computation")
-        and " convolution(" in body
-        and fam.expert_operand(config).search(
-            body.lstrip().split("\n", 1)[0])]
-    assert len(products) >= 3, len(products)
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
     ring_ops = re.findall(rf"= bf16\[[0-9,]*{rows},8,128\]", text)
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
