@@ -564,8 +564,10 @@ _PAGED_GROUP_BLOCKS = 16
 # step) and 0.76 M at 64 (2,340), and the benchmark's harness waits 120 s
 # for a 3 s profile to stop: at 16 (and 128-row chunks) the traced run of
 # `lagunaxs2-agent` failed there, at 64 it stops in ~90 s (PERF.md section
-# 8, PR 40 (1) and PR 45).  A kernel a layer in place of the loop, as the
-# latent pool has, would give the 4% back and most of the events.
+# 8, PR 40 (1) and PR 45).  Since PR 47 a decode step does not come here
+# (`_paged_decode_kernel`: one device op a layer and lane, no trip); what
+# still loops over such a table is a chunk and a verify step, whose 8% at
+# 32 are theirs to take back.
 _PAGED_LONG_TABLE = 512
 _PAGED_GROUP_BLOCKS_LONG = 64
 
@@ -640,30 +642,64 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
     has already written.  `kv_len` (S,) is how many positions of each lane
     are live (0: the lane is idle and its output is garbage nobody reads).
 
-    Only live blocks are read: a loop over groups of `_PAGED_GROUP_BLOCKS`
-    table entries with a running soft-max, whose trip count follows the
-    longest live lane of this call (`_paged_running_softmax`).  The rep =
-    H // Hkv query heads of a KV
-    head are grouped on their own axis against the stored head, so K and V
-    are neither repeated nor kept in another dtype; scores, soft-max, the
-    probabilities and both products' accumulation are float32 (the compiler
-    folds a group's widening into the second product: on a v5e the step
-    takes the same time with the probabilities rounded to the cache dtype).
+    Only live blocks are read, under a running soft-max, in one of two
+    tilings.  **A decode step (one query row a lane) lowered for a TPU is
+    one Pallas kernel a layer, this file's own** (`_paged_decode_kernel`:
+    a live page of K and of V copied to VMEM once, a DMA each, where the
+    loop gathered a group into a temporary that the products read again;
+    operands in the pool's dtype, everything else float32).  A chunk, a
+    verify step (K > 1), every other platform, a pool split over a mesh
+    and a page that is not whole tiles (it says so) take **the loop**
+    over groups of `_PAGED_GROUP_BLOCKS` table entries, whose trip count
+    follows the longest live lane of this call (`_paged_running_softmax`):
+    the rep = H // Hkv query heads of a KV head are grouped on their own
+    axis against the stored head, so K and V are neither repeated nor
+    kept in another dtype; scores, soft-max, the probabilities and both
+    products' accumulation are float32 (the compiler folds a group's
+    widening into the second product: on a v5e the step takes the same
+    time with the probabilities rounded to the cache dtype).  The
+    platform is the one the program is lowered for
+    (`jax.lax.platform_dependent`), as `paged_latent_attention`'s.
     Returns (S, K, H, D) float32.
     """
     s, k_w, h, d = q.shape
     hkv = k_pool.shape[3]
-    qg = q.reshape(s, k_w, hkv, h // hkv, d)
     if scale is None:
         scale = d ** -0.5
-    out = _paged_running_softmax(
-        k_pool, v_pool, layer, block_tables, positions, kv_len,
-        lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,
-                              preferred_element_type=jnp.float32) * scale,
-        lambda p, vb: jnp.einsum("sqhrt,sthd->sqhrd", p, vb,
-                                 preferred_element_type=jnp.float32),
-        (hkv, h // hkv), d)
-    return out.reshape(s, k_w, h, d)
+
+    def loop(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
+        qg = q.reshape(s, k_w, hkv, h // hkv, d)
+        out = _paged_running_softmax(
+            k_pool, v_pool, layer, block_tables, positions, kv_len,
+            lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,
+                                  preferred_element_type=jnp.float32) * scale,
+            lambda p, vb: jnp.einsum("sqhrt,sthd->sqhrd", p, vb,
+                                     preferred_element_type=jnp.float32),
+            (hkv, h // hkv), d)
+        return out.reshape(s, k_w, h, d)
+
+    def kernel(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
+        return _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables,
+                                    kv_len, scale=scale)
+
+    args = (q, k_pool, v_pool, layer, block_tables, positions, kv_len)
+    if k_w != 1 or jax.typeof(k_pool).sharding.mesh.size > 1:
+        # A pool split over a mesh (tensor-parallel serving) is a program
+        # the compiler partitions, and it cannot partition a kernel.
+        return loop(*args)
+    sublanes = 32 // k_pool.dtype.itemsize
+    if d % _LANES or (k_pool.shape[2] * hkv) % sublanes:
+        # Decided at trace time, as `paged_latent_attention`'s.
+        warnings.warn(
+            f"paged_attention: a page of {k_pool.shape[2:]} {k_pool.dtype} "
+            f"is not whole tiles of {sublanes} x {_LANES} as rows of "
+            f"(position, KV head), so a decode step over pool{k_pool.shape} "
+            f"takes the block loop on a TPU too, not the Pallas kernel (a "
+            f"step of 8 lanes takes over a quarter as long again at "
+            f"Laguna-XS.2's widths: 8.4 ms against 6.5)",
+            stacklevel=2)
+        return loop(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
 
 
 # Pool blocks a lane reads per trip over a latent pool.  A latent row is a
@@ -769,20 +805,26 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
 _LATENT_KERNEL_PAGES = 64
 
 
-def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
-                        o_ref, buf, sems, slot_ref, *, pages, d_v, scale):
-    """One lane of `_latent_decode_kernel`'s grid: a loop over the lane's
-    steps of `pages` pool blocks under a running soft-max.  A step's live
-    blocks `[layer, table[lane, j]]` are copied, a DMA a block, into one
-    of the two halves of `buf` (2, pages * block_size, W) while the other
-    half is multiplied; the copy of a lane's first step is started by the
+def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
+                       slot_ref, pools, bufs, sems, *, pages, bs, scale,
+                       d_out, seen, values, prepare=None):
+    """One lane of a decode kernel's grid (`_latent_decode_kernel`,
+    `_paged_decode_kernel`): a loop over the lane's steps of `pages` pool
+    blocks of `bs` positions under a running soft-max.  A step's live
+    blocks `[layer, table[lane, j]]` of every pool of `pools` are copied,
+    a DMA a block and pool, into one of the two halves of that pool's
+    buffer of `bufs` (2, pages * rows of a page, W) while the other half
+    is multiplied; the copy of a lane's first step is started by the
     live lane before it (by lane 0 for the first), so the pipeline runs
     through the lanes of the call.  `slot_ref` carries the half in turn
-    from lane to lane."""
+    from lane to lane.  The first buffer's rows are the keys;
+    `values(keys, slot)` gives the rows the probabilities are applied to
+    (`d_out` columns) and `seen(i, length, shape)` which scores of step
+    `i` stand; `prepare()` runs once a call, before the first copy."""
     lane, n_lanes = pl.program_id(0), pl.num_programs(0)
     n_entries = tables_ref.shape[0] // n_lanes
-    t = buf.shape[1]                       # positions a step
-    bs = t // pages
+    rows = bufs[0].shape[1] // pages       # of a page in a buffer
+    t = pages * bs                         # positions a step
     length = len_ref[lane]
 
     def next_live(start):
@@ -800,18 +842,22 @@ def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
         first = step * pages
         live = jnp.minimum(pages, pl.cdiv(len_ref[of_lane], bs) - first)
         entry = of_lane * n_entries + first
-        layer, half, sem = pool_ref.at[layer_ref[0]], buf.at[slot], sems.at[slot]
+        layer = layer_ref[0]
+        ways = [(pool.at[layer], buf.at[slot], sem.at[slot])
+                for pool, buf, sem in zip(pools, bufs, sems)]
 
         def one(j, _=None):
-            at = j * bs
+            at = j * rows
             if not isinstance(j, int):
-                at = pl.multiple_of(at, bs)
-            dma = pltpu.make_async_copy(
-                layer.at[tables_ref[entry + j]], half.at[pl.ds(at, bs)], sem)
-            if go:
-                dma.start()
-            else:
-                dma.wait()
+                at = pl.multiple_of(at, rows)
+            block = tables_ref[entry + j]
+            for page, half, sem in ways:
+                dma = pltpu.make_async_copy(
+                    page.at[block], half.at[pl.ds(at, rows)], sem)
+                if go:
+                    dma.start()
+                else:
+                    dma.wait()
 
         def from_a_loop():
             jax.lax.fori_loop(0, live, one, None)
@@ -831,7 +877,10 @@ def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
         # meet a probability of 0 in the value product: they have to be
         # numbers.  What a half holds there from then on is an earlier
         # step's rows.
-        buf[...] = jnp.zeros_like(buf)
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        if prepare is not None:
+            prepare()
         slot_ref[0] = 0
         first = next_live(0)
 
@@ -860,12 +909,10 @@ def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
                        jnp.where(more, i + 1, 0), 1 - slot, True)
 
             copies(lane, i, slot, False)
-            rows = buf[slot]                               # (t, W)
+            keys = bufs[0][slot]                           # (rows a step, W)
             s = jax.lax.dot_general(
-                q, rows, _NT, preferred_element_type=jnp.float32) * scale
-            seen = i * t + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1) < length
-            s = jnp.where(seen, s, _NEG_INF)
+                q, keys, _NT, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen(i, length, s.shape), s, _NEG_INF)
             # Position 0 is in step 0 and seen, so `m_new` is a real
             # score from the first step on and masked entries vanish.
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -873,7 +920,7 @@ def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
             p = jnp.exp(s - m_new)
             l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
             acc = alpha * acc + jax.lax.dot_general(
-                p.astype(rows.dtype), rows[:, :d_v], _NN,
+                p.astype(keys.dtype), values(keys, slot), _NN,
                 preferred_element_type=jnp.float32)
             return m_new, l, acc, 1 - slot
 
@@ -882,9 +929,25 @@ def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
             0, n_steps, step,
             (jnp.full((h, 1), _NEG_INF, jnp.float32),
              jnp.zeros((h, 1), jnp.float32),
-             jnp.zeros((h, d_v), jnp.float32), slot_ref[0]))
+             jnp.zeros((h, d_out), jnp.float32), slot_ref[0]))
         slot_ref[0] = slot
         o_ref[0] = acc / l
+
+
+def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
+                        o_ref, buf, sems, slot_ref, *, pages, d_v, scale):
+    """`_paged_decode_body` over one pool of flat rows that are key and,
+    in their first `d_v` columns, value: one buffer, both products'
+    operand."""
+    t = buf.shape[1]                       # positions a step
+
+    def seen(i, length, shape):
+        return i * t + jax.lax.broadcasted_iota(jnp.int32, shape, 1) < length
+
+    _paged_decode_body(
+        layer_ref, tables_ref, len_ref, q_ref, o_ref, slot_ref, (pool_ref,),
+        (buf,), (sems,), pages=pages, bs=t // pages, scale=scale, d_out=d_v,
+        seen=seen, values=lambda rows, slot: rows[:, :d_v])
 
 
 @functools.partial(jax.jit, static_argnames=("d_v", "scale"))
@@ -932,6 +995,158 @@ def _latent_decode_kernel(q, pool, layer, block_tables, kv_len, *, d_v,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       block_tables.astype(jnp.int32).reshape(-1), kv_len.astype(jnp.int32),
       q[:, 0].astype(pool.dtype), pool)
+    return out[:, None]
+
+
+# Pool blocks a step of `_paged_decode_kernel` copies in and multiplies,
+# and the rows of (position, KV head) a step may hold at most: 16 blocks
+# of 16 positions are 2,048 rows at 8 KV heads, 1,024 at Mellum2's 4; at
+# an MHA model's 32 KV heads the cap leaves 8 blocks.  The cap is VMEM's,
+# reckoned first: a page of (16, 8, 128) bfloat16 is 32 KB of K and 32 KB
+# of V and lies in VMEM as it does in HBM, 128 rows of 128 in whole
+# (16, 128) tiles, nothing padded, so the two halves of the two buffers
+# are 1 MB for every 1,024 rows of a step; beside them the mask table
+# (H x rows int32) and a step's scores and probabilities (H x rows
+# float32 twice, once more in the rows' dtype): ~7 MB at 4,096 rows and
+# Laguna-XS.2's 48 heads, inside the 16 MB Mosaic takes by default (asked
+# for 64 MiB, the compiler counts 67 MB more among a Mistral burst's
+# temporaries).  A step of 64 blocks overran it at 16 KV heads (bench-1b4:
+# 17 MB of 16).
+# Measured on a v5e (`TPU v5 lite`, 2026-10-01, PR 47, calls A and B;
+# bfloat16 pools, block 16, D 128, the loop at the group it has: 64 under
+# Laguna's table of 1,024 entries, 16 elsewhere), 16 / 32 / 64 / 128 blocks
+# a step (at Mellum2's 4 KV heads half the rows).  The kernel alone, ms a
+# call (one layer) at Laguna-XS.2's widths (48 query heads over 8):
+#   8 lanes at 5,200-12,600 live positions (71,200 x 4 KB = 292 MB =
+#   0.356 ms at 819 GB/s)      loop 1.066 | 0.421  0.423  0.432  0.477
+#   4 of the 8 lanes (0.178)   loop 1.074 | 0.231  0.229  0.230  0.251
+# (85 / 84 / 82 / 75% and 77 / 78 / 77 / 71% of their bytes; a call
+# under ~0.19 ms is bound by its launch from the host in that timing, loop
+# and kernel alike: 8 lanes at 300 and Mistral's 4 lanes read 0.19-0.20.)
+# The whole decode step of the bare served burst, ms:
+#   Laguna-XS.2 (3 full layers of 9; 8 lanes at 5,400-12,600)
+#                              loop  8.36 |  6.53   6.54   6.55   6.67
+#     4 lanes of the 8               7.05 |  4.64   4.63   4.65   4.69
+#     8 lanes at 240-360             5.53 |  5.39   5.37   5.40   5.45
+#   Mistral-7B (8 layers, 32 heads over 8; 4 of 16 lanes at 200-700)
+#                                    6.29 |  5.67   5.68   5.70   5.74
+#     4 lanes at 3,000-4,000         9.84 |  6.19   6.21   6.22   6.26
+#     16 lanes at 1,750-3,250        9.06 |  7.37   7.36   7.39   7.54
+#   Mellum2 (2 full layers of 8, 32 heads over 4; 2 of 8 lanes at
+#   1,000-3,000)                     5.64 |  5.10   5.09   5.10
+#     8 of 32 lanes at 1,500-4,500  13.23 | 10.19  10.17  10.15
+#   Mixtral-8x7B (3 layers; 2 of 16 lanes at 200-700)
+#                                    6.55 |  6.35   6.33   6.35
+#     4 lanes at 3,000-4,000        10.64 |  9.26   9.27   9.27
+#   granite-4.0-h-small (1 attention layer of 10; 4 of 16 lanes at
+#   2,100-3,900)                    14.60 | 14.14  14.14  14.15
+# 16, 32 and 64 blocks are level (within 0.3% of a step everywhere, 128
+# behind by 1-2%): the copy bounds the kernel, not the step's size.  **16
+# are taken for what a block costs a replica's start**: a whole step's
+# copies are written out, and a written-out block is ~6 ms of tracing and
+# lowering a program (0.054 s a call site with the loop, 0.19 / 0.26 /
+# 0.37 / 0.65 at 4 / 16 / 32 / 64 blocks; a replica warms five burst
+# tiers).  At 32 the warm `setup_s` of `mistral7b-chat` read 24.6 -> 26.6
+# s, `warmup` 3.6 -> 4.85 (call C2b), half the room its bound of 10%
+# leaves (unrolled at lowering instead, `fori_loop(unroll=True)`, a block
+# costs as much: 0.32 at 32).  The kernel is never slower than the loop,
+# short lanes included (Mistral at 200-700: -10%), so the rule reads no
+# length.  Against the loop its output differs by 0.4-0.8% of the loop's
+# rms where the two rescale at different positions and by 1e-6 where a
+# step is the loop's group (both round the probabilities to bfloat16 for
+# the second product).
+# The form.  A stored page has one view that is its bytes: rows of
+# (position, KV head) x D (under the pool's `T(8,128)(2,1)` tiles the
+# reshape (L, N, 16, Hkv, 128) -> (L, N, 16 Hkv, 128) compiles to a
+# bitcast; rows of Hkv x D, each query head spread over its KV head's
+# columns, are another order of the bytes: a copy of the pool).  On it one
+# product of all H query rows with a step's rows computes every query head
+# against every KV head, Hkv times the multiply-adds and Hkv times the
+# scores masked and exponentiated, behind a copy that still bounds the
+# call at 82-85% of its bytes.  A product a KV head on a strided view of
+# those rows is refused by Mosaic (`Strided load with non 32-bit data`: a
+# bfloat16 row is half a sublane); two heads at a time through a 32-bit
+# view compiles and was not run (PERF.md section 8).
+_PAGED_KERNEL_PAGES = 16
+_PAGED_KERNEL_ROWS = 4096
+_MASKED = 1 << 30     # no position reaches it
+
+
+def _kv_decode_body(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
+                    o_ref, k_buf, v_buf, k_sems, v_sems, slot_ref, at_ref,
+                    *, pages, bs, scale):
+    """`_paged_decode_body` over a K and a V pool of grouped-query heads.
+    A page is taken as it is stored, (block_size x Hkv, D): row t * Hkv + g
+    is position t of KV head g, so one product of all H query rows with a
+    step's rows holds every head's scores, each query head's own in the
+    columns of its KV head and the other heads' beside them.  `at_ref`
+    (H, rows a step) holds, once a call, the position a column stands at
+    where its KV head is the row's own and `_MASKED` elsewhere: one
+    comparison a step leaves the scores that count."""
+    h, d = q_ref.shape[1:]
+    hkv = k_buf.shape[1] // (pages * bs)
+    t = pages * bs
+
+    def prepare():
+        row = jax.lax.broadcasted_iota(jnp.int32, at_ref.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, at_ref.shape, 1)
+        own = jax.lax.rem(col, hkv) == jax.lax.div(row, h // hkv)
+        at_ref[...] = jnp.where(own, jax.lax.div(col, hkv), _MASKED)
+
+    _paged_decode_body(
+        layer_ref, tables_ref, len_ref, q_ref, o_ref, slot_ref,
+        (k_ref, v_ref), (k_buf, v_buf), (k_sems, v_sems), pages=pages, bs=bs,
+        scale=scale, d_out=d, prepare=prepare,
+        seen=lambda i, length, shape: at_ref[...] < length - i * t,
+        values=lambda rows, slot: v_buf[slot])
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables, kv_len, *,
+                         scale):
+    """A decode step's attention over a K / V pool as one kernel a layer,
+    of this file's own: `q` (S, 1, H, D) against the pools where they lie,
+    (L, N, block_size, Hkv, D) in HBM, with `layer`, the tables and the
+    lengths as scalars the kernel reads.  A live page of K and the same
+    page of V are one DMA each into their halves of two VMEM buffers;
+    operands in the pool's dtype, accumulation, statistics and rescaling
+    float32, the scale applied to the float32 scores
+    (`_latent_decode_kernel`'s convention and pipeline:
+    `_paged_decode_body`).  Only a lane's live pages are read; an idle
+    lane (`kv_len` 0) reads none and gets 0.  Jitted, as the latent
+    kernel and for its reason.  Returns (S, 1, H, D) float32."""
+    s, _, h, d = q.shape
+    n_layers, n_blocks, bs, hkv, _ = k_pool.shape
+    pages = max(1, min(_PAGED_KERNEL_PAGES, block_tables.shape[1],
+                       _PAGED_KERNEL_ROWS // (bs * hkv)))
+    rows = pages * bs * hkv
+    # Rows of (position, KV head): the bytes of a page as they are stored.
+    stored = (n_layers, n_blocks, bs * hkv, d)
+    out = pl.pallas_call(
+        functools.partial(_kv_decode_body, pages=pages, bs=bs, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s,),
+            in_specs=[
+                pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, d), k_pool.dtype),
+                pltpu.VMEM((2, rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, rows), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_tables.astype(jnp.int32).reshape(-1), kv_len.astype(jnp.int32),
+      q[:, 0].astype(k_pool.dtype), k_pool.reshape(stored),
+      v_pool.reshape(stored))
     return out[:, None]
 
 

@@ -209,6 +209,123 @@ def test_body_all_lanes_idle_is_finite(group_blocks):
 
 
 # ---------------------------------------------------------------------------
+# a decode step lowered for a TPU: the kernel against the block loop
+# ---------------------------------------------------------------------------
+# (pages a step, table entries, Hkv, rep, lane lengths, dtype, scale, the
+# lanes' blocks in table order: None = shuffled, an int = that one block
+# for every entry).  Lengths count the step's own token; 0: an idle lane.
+KERNEL_CASES = {
+    "whole_steps_and_a_part": (2, 7, 8, 4, [32, 0, 64, 100, 1], "bf16",
+                               None, None),
+    "table_narrower_than_a_step": (8, 3, 8, 4, [48, 17, 0], "bf16", None,
+                                   None),
+    "one_whole_step": (4, 4, 8, 4, [64], "bf16", None, None),
+    "a_block_repeated": (2, 6, 8, 4, [96, 40], "bf16", None, 5),
+    "idle_lanes_first_and_last": (2, 5, 4, 8, [0, 0, 70, 16, 0], "bf16",
+                                  None, None),
+    "every_lane_idle": (2, 4, 8, 4, [0, 0], "bf16", None, None),
+    "rep6_over_8": (2, 5, 8, 6, [80, 33, 15], "bf16", None, None),
+    "rep8_over_4": (4, 9, 4, 8, [144, 65], "bf16", None, None),
+    "a_handed_over_scale": (2, 5, 8, 4, [79, 2], "bf16", 1.0 / 128, None),
+    "float32_rows": (2, 5, 8, 4, [80, 31], "f32", None, None),
+    "the_row_cap_sets_the_step": (2, 7, 8, 4, [100, 33], "bf16", None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
+    """`_paged_decode_kernel` in Pallas's TPU interpret mode against
+    `paged_attention` as the CPU lowers it (the block loop) on the same
+    pool: within 1e-2 of the loop's rms (the kernel rounds the
+    probabilities to the rows' dtype, 2**-9), idle lanes exactly 0,
+    float32 out, (S, 1, H, D), the pools untouched."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pages, entries, hkv, rep, lengths, dtype, scale, block = \
+        KERNEL_CASES[case]
+    dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    if case == "the_row_cap_sets_the_step":
+        monkeypatch.setattr(attention, "_PAGED_KERNEL_ROWS",
+                            pages * BS * hkv)
+    else:
+        monkeypatch.setattr(attention, "_PAGED_KERNEL_PAGES", pages)
+    d, s = 128, len(lengths)
+    tables, positions, kv_len = _lanes(
+        [n - 1 if n else None for n in lengths], 1,
+        np.random.default_rng(len(case)), entries)
+    if block:
+        tables = np.where(tables > 0, block, 0)
+    kq, kk, kv = jax.random.split(jax.random.key(len(case)), 3)
+    shape = (3, 1 + s * entries, BS, hkv, d)
+    q = jax.random.normal(kq, (s, 1, hkv * rep, d), dtype)
+    k_pool = jax.random.normal(kk, shape, dtype)
+    v_pool = jax.random.normal(kv, shape, dtype)
+    before = np.asarray(k_pool, np.float32), np.asarray(v_pool, np.float32)
+    assert list(kv_len) == lengths
+    layer, tables = jnp.int32(2), jnp.asarray(tables)
+    kv_len = jnp.asarray(kv_len)
+    with pltpu.force_tpu_interpret_mode():
+        got = attention._paged_decode_kernel(
+            q, k_pool, v_pool, layer, tables, kv_len,
+            scale=d ** -0.5 if scale is None else scale)
+    assert got.shape == (s, 1, hkv * rep, d) and got.dtype == jnp.float32
+    live = np.asarray(kv_len) > 0
+    assert not np.asarray(got[~live]).any()
+    np.testing.assert_array_equal(np.asarray(k_pool, np.float32), before[0])
+    np.testing.assert_array_equal(np.asarray(v_pool, np.float32), before[1])
+    if not live.any():
+        return
+    want = attention.paged_attention(
+        q, k_pool, v_pool, layer, tables, jnp.asarray(positions), kv_len,
+        scale=scale)
+    assert want.shape == got.shape
+    rms = float(jnp.sqrt(jnp.mean(want[live] ** 2)))
+    assert float(jnp.abs(got - want)[live].max()) < 1e-2 * rms
+
+
+def _no_kernel(monkeypatch):
+    def refuse(*a, **kw):
+        raise AssertionError("the decode kernel was reached")
+    monkeypatch.setattr(attention, "_paged_decode_kernel", refuse)
+
+
+def test_a_head_that_is_not_whole_tiles_warns_and_takes_the_loop(
+        monkeypatch):
+    """A decode step over heads the kernel cannot take says so, once a
+    call site, and is the block loop op for op: the kernel is not even
+    traced."""
+    _no_kernel(monkeypatch)
+    with pytest.warns(UserWarning, match="not whole tiles"):
+        got, want, live = _body_case([40, 3, None], 1, 4, jnp.bfloat16)
+    np.testing.assert_allclose(got[live], want[live], atol=ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("k_w", [4, 32], ids=["verify", "chunk"])
+def test_a_chunk_never_reaches_the_kernel(monkeypatch, k_w):
+    """More than one query row a lane (a verify step, a prefill chunk)
+    keeps the loop whatever the head size, and says nothing."""
+    import warnings
+
+    _no_kernel(monkeypatch)
+    lengths = [40, 3]
+    tables, positions, kv_len = _lanes(lengths, k_w, np.random.default_rng(0))
+    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
+    shape = (2, 1 + len(lengths) * B_MAX, BS, 2, 128)
+    q = jax.random.normal(kq, (len(lengths), k_w, 8, 128), jnp.bfloat16)
+    k_pool = jax.random.normal(kk, shape, jnp.bfloat16)
+    v_pool = jax.random.normal(kv, shape, jnp.bfloat16)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        got = attention.paged_attention(
+            q, k_pool, v_pool, jnp.int32(1), jnp.asarray(tables),
+            jnp.asarray(positions), jnp.asarray(kv_len))
+    want = ref_attention(q, k_pool[1], v_pool[1], jnp.asarray(tables),
+                         jnp.asarray(positions))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL,
+                               rtol=0)
+
+
+# ---------------------------------------------------------------------------
 # the three programs against the reference forward
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module", params=["tiny-mha", "tiny-gqa4", "tiny-moe"])

@@ -263,6 +263,28 @@ def test_paged_prefill_chunk_fits_one_chip(topo):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def _assert_pool_read_by_the_kernel(text, pool_shape, calls: int):
+    """`calls` sites of `text` read a K / V pool of `pool_shape` by the
+    decode kernel (`ops.attention._paged_decode_kernel`), each handed K
+    and V once as they are stored: rows of (position, KV head) x D, a
+    bitcast of the pool and no copy of it."""
+    import re
+
+    n_layers, n_blocks, bs, hkv, d = pool_shape
+    stored = f"bf16[{n_layers},{n_blocks},{bs * hkv},{d}]"
+    reads = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "paged_decode_attention" in line]
+    assert len(reads) == calls, (len(reads), calls)
+    for call in reads:
+        operands = call.split("operand_layout_constraints=")[1].split(
+            "metadata=")[0]
+        assert operands.count(stored) == 2, call
+    makers = set(re.findall(r"= " + re.escape(stored) + r"\S* ([a-z-]+)\(",
+                            text))
+    assert makers <= {"bitcast"}, makers
+
+
 @pytest.mark.parametrize("program", ["paged_decode_burst",
                                      "paged_prefill_chunk"])
 def test_served_step_does_not_copy_the_pool(topo, program):
@@ -303,6 +325,10 @@ def test_served_step_does_not_copy_the_pool(topo, program):
     assert "scatter" in makers
     assert makers <= {"parameter", "get-tuple-element", "scatter", "fusion",
                       "bitcast"}, makers
+    # A decode step reads the pool by the kernel (one site: the scan's
+    # body), a chunk by the block loop.
+    _assert_pool_read_by_the_kernel(
+        hlo, cache.k.shape, 1 if program == "paged_decode_burst" else 0)
     window = eng["max_len"] * cfg.n_kv_heads * cfg.head_dim
     largest = max(
         (math.prod(map(int, dims.split(","))), dims)
@@ -615,6 +641,11 @@ def test_window_moe_served_programs_fit_one_chip(topo, program):
     assert mem.temp_size_in_bytes < ring_array, mem.temp_size_in_bytes
     _assert_experts_read_in_place(compiled.as_text(),
                                   fam.expert_operand(config), program)
+    # the burst's full layer reads the pool by the kernel (one site in
+    # the scan's body: 32 query heads over 4), the chunk by the block loop
+    _assert_pool_read_by_the_kernel(
+        compiled.as_text(), state.k.shape,
+        1 if program == "paged_decode_burst" else 0)
     ring_ops = re.findall(r"= bf16\[[0-9,]*1152,4,128\]", compiled.as_text())
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
 
@@ -673,6 +704,10 @@ def test_mamba2_moe_served_programs_fit_one_chip(topo, program):
     assert mem.temp_size_in_bytes < expert_stack, mem.temp_size_in_bytes
     text = compiled.as_text()
     _assert_experts_read_in_place(text, fam.expert_operand(config), program)
+    # the burst's one attention layer reads the pool by the kernel (its
+    # own scale handed over), the chunk by the block loop
+    _assert_pool_read_by_the_kernel(
+        text, state.k.shape, 1 if program == "paged_decode_burst" else 0)
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert loops and not [name for name in loops if "/ssd" in name], loops
     if program == "paged_prefill_chunk":
@@ -816,6 +851,10 @@ def test_gated_moe_served_programs_fit_one_chip(topo, program):
     assert mem.alias_size_in_bytes >= state_bytes
     assert _device_bytes(compiled) < 15.75e9
     assert mem.temp_size_in_bytes < state.k.size * 2, mem.temp_size_in_bytes
+    # the burst reads the pool by the kernel (layer 0's site and the
+    # scan's, 48 query heads over 8), the chunk by the block loop
+    _assert_pool_read_by_the_kernel(
+        text, state.k.shape, 2 if program == "paged_decode_burst" else 0)
     _assert_experts_read_in_place(text, fam.expert_operand(config), program)
     ring_ops = re.findall(rf"= bf16\[[0-9,]*{rows},8,128\]", text)
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
