@@ -122,15 +122,8 @@ class Config:
     transfer_broadcast_fanout: int = 2
     # Chunk RPCs a push/relay keeps in flight toward one peer.
     transfer_push_pipeline: int = 4
-    # Kill switch: serve chunk payloads as raw frames (zero-copy);
-    # 0 falls back to the legacy bytes-through-pickle path.
-    transfer_raw_frames: bool = True
 
     # ---- streaming data plane (data/streaming; RAY_TPU_DATA_STREAM_*) ----
-    # Default Dataset execution path: streaming operator graph with a
-    # bytes-windowed backpressure budget. 0 falls back to the legacy
-    # block-materializing executor in data/execution.py.
-    data_stream_enabled: bool = True
     # Total bytes of operator output the whole pipeline may hold
     # un-consumed before upstream submission stalls (the global window).
     data_stream_window_bytes: int = 128 * 1024 * 1024
@@ -156,9 +149,7 @@ class Config:
     # Pre-leased task lanes: after `task_lane_min_calls` submissions of
     # the same (function, resources, runtime-env) signature the lease is
     # kept warm and pinned, and subsequent calls ride compact raw-frame
-    # deltas straight into the pinned worker's executor queue
-    # (RAY_TPU_TASK_LANE_ENABLED=0 restores per-call leasing).
-    task_lane_enabled: bool = True
+    # deltas straight into the pinned worker's executor queue.
     task_lane_min_calls: int = 3
     # Calls in flight on one pinned lane before new submissions spill
     # back to the normal lease/scheduler path (backpressure bound).
@@ -197,9 +188,9 @@ class Config:
     put_direct_min_bytes: int = 1024 * 1024
 
     # ---- ownership / lineage ----
-    # Keep lineage for reconstruction while refs exist
-    # (ref: ray_config_def.h:145 lineage_pinning_enabled, 1 GiB cap :158).
-    lineage_pinning_enabled: bool = True
+    # Lineage of a retriable task is kept for reconstruction while refs
+    # exist, up to this many bytes (ref: ray_config_def.h:145, 1 GiB
+    # cap :158).
     max_lineage_bytes: int = 1024 * 1024 * 1024
     task_max_retries: int = 3
     actor_max_restarts: int = 0
@@ -212,11 +203,6 @@ class Config:
     # exposition merging every node's syncer-shipped metric snapshot,
     # node-labelled (RAY_TPU_METRICS_GCS_EXPORT_PORT).
     metrics_gcs_export_port: int = 0
-    # Per-service/method RPC instrumentation (queue-wait + handler
-    # latency histograms, inflight gauges, bytes counters) on RpcServer
-    # and both clients. RAY_TPU_METRICS_RPC_ENABLED=0 is the bench
-    # kill switch the observability-overhead probe flips.
-    metrics_rpc_enabled: bool = True
     # EventLoopThread lag probe cadence (0 disables): a sleep(interval)
     # measures its own overshoot — the Python analogue of the
     # reference's instrumented asio event loops.
@@ -246,8 +232,8 @@ class Config:
     task_events_finished_job_ttl_s: float = 300.0
     # Per-task resource attribution: the executor wraps each attempt
     # with thread CPU-time + RSS delta/peak probes and ships them on the
-    # attempt's task-event record (RAY_TPU_TASK_EVENTS_RESOURCES=0 is
-    # the bench kill switch the attribution_overhead probe flips).
+    # attempt's task-event record (RAY_TPU_TASK_EVENTS_RESOURCES=0
+    # turns the probes off).
     task_events_resources: bool = True
     # Opt-in JAX device-memory attribution per attempt (reads
     # device.memory_stats() around the task body — a device runtime
@@ -287,8 +273,7 @@ class Config:
     # client) and the GCS accumulates per-service x per-component
     # request/bytes/handler-time shares (`ray-tpu gcs top`). The shares
     # are the measure-then-shard evidence for the GCS sharding arc.
-    # RAY_TPU_GCS_ATTRIBUTION_ENABLED=0 is the bench kill switch the
-    # gcs_attribution_overhead probe flips.
+    # RAY_TPU_GCS_ATTRIBUTION_ENABLED=0 turns the accounting off.
     gcs_attribution_enabled: bool = True
     # Wall budget for a single GCS handler: any handler exceeding it is
     # logged (method + caller + args digest) and journaled so slow-path
@@ -316,10 +301,6 @@ class Config:
     # this long and its resources return to the pool — the timeout-
     # bounded rollback that keeps a half-placed gang from leaking.
     pg_prepare_ttl_s: float = 30.0
-    # On bundle COMMIT the daemon pre-warms one pool worker per bundle
-    # so gang start rides ~3ms zygote forks instead of cold spawns
-    # (RAY_TPU_PG_PREWARM_ENABLED=0 disables).
-    pg_prewarm_enabled: bool = True
 
     # ---- elastic training plane (train/elastic.py) ----
     # How long the elastic supervisor waits for a replacement bundle

@@ -2039,7 +2039,7 @@ class DistributedCoreWorker:
 
             spec["trace_ctx"] = tracing.inject()
         self._stamp_submit(spec)
-        if options.max_retries > 0 and get_config().lineage_pinning_enabled:
+        if options.max_retries > 0:
             with self._lock:
                 entry = {"spec": spec, "demand": demand, "sched": sched,
                          "deps": deps, "attempts": 0, "fut": None,
@@ -2282,8 +2282,8 @@ class DistributedCoreWorker:
         (signature still cold, lane ineligible, or backlog spill)."""
         cfg = get_config()
         opts = spec["options"]
-        if (not cfg.task_lane_enabled or opts.get("max_calls")
-                or opts.get("streaming") or sched["placement"]):
+        if (opts.get("max_calls") or opts.get("streaming")
+                or sched["placement"]):
             return False
         from ray_tpu.runtime_env import env_hash
 

@@ -1926,7 +1926,7 @@ class NodeDaemon:
         the warm-pool cap): gang start pops these instead of forking
         inside the critical path."""
         cfg = get_config()
-        if not (cfg.pg_prewarm_enabled and cfg.worker_prestart_enabled):
+        if not cfg.worker_prestart_enabled:
             return
         idle = len(self._idle)
         starting = sum(1 for h in self._workers.values()
@@ -2371,7 +2371,6 @@ class NodeDaemon:
             async with self._push_sem:
                 cfg = get_config()
                 total = buf.size
-                raw = cfg.transfer_raw_frames
                 client = self._peer_client(target_address)
                 pending: set = set()
                 depth = max(1, cfg.transfer_push_pipeline)
@@ -2389,7 +2388,7 @@ class NodeDaemon:
                         "NodeDaemon", "receive_object_chunk",
                         object_id=object_id, offset=off,
                         total_size=total,
-                        data=Raw(view) if raw else bytes(view),
+                        data=Raw(view),
                         last=off + ln >= total, timeout=120)))
                     self._m_xfer_out.inc(ln)
                 if pending:
@@ -2432,13 +2431,12 @@ class NodeDaemon:
                                length: int, wait: bool = False,
                                raw: bool = True) -> dict:
         """Serve one chunk as a raw frame — a memoryview straight off
-        the shm mapping, zero copies on this side (the legacy bytes()
-        path survives only for raw=False / kill-switch callers). Serves
-        from an in-flight partial too when the range has landed
-        (`wait=True` long-polls for it): broadcast children stream an
-        object out of this daemon while it is still arriving."""
+        the shm mapping, zero copies on this side (raw=False callers get
+        a bytes() copy through the pickle codec). Serves from an
+        in-flight partial too when the range has landed (`wait=True`
+        long-polls for it): broadcast children stream an object out of
+        this daemon while it is still arriving."""
         oid = ObjectID(object_id)
-        use_raw = raw and get_config().transfer_raw_frames
         buf = self.store.get_buffer(oid)
         if buf is None:
             sink = self._recv_partials.get(object_id)
@@ -2455,7 +2453,7 @@ class NodeDaemon:
                     view = sink.read(offset, end)
                     self._m_xfer_out.inc(end - offset)
                     return {"total_size": sink.size,
-                            "data": Raw(view) if use_raw
+                            "data": Raw(view) if raw
                             else bytes(view)}
             if buf is None:
                 return {"missing": True}
@@ -2467,7 +2465,7 @@ class NodeDaemon:
         buf.release()
         self._m_xfer_out.inc(len(view))
         return {"total_size": total,
-                "data": Raw(view) if use_raw else bytes(view)}
+                "data": Raw(view) if raw else bytes(view)}
 
     async def object_info(self, object_id: bytes) -> dict:
         """Size/seal state of a local (possibly still-arriving) object.
@@ -2492,7 +2490,6 @@ class NodeDaemon:
         striped pulls use get_object_chunk; raw=True upgrades the
         payloads to raw frames."""
         oid = ObjectID(object_id)
-        use_raw = raw and get_config().transfer_raw_frames
         buf = self.store.get_buffer(oid)
         if buf is None:
             yield {"missing": True}
@@ -2506,7 +2503,7 @@ class NodeDaemon:
                 yield {
                     "offset": off,
                     "total_size": total,
-                    "data": Raw(view) if use_raw else bytes(view),
+                    "data": Raw(view) if raw else bytes(view),
                 }
             if total == 0:
                 yield {"offset": 0, "total_size": 0, "data": b""}

@@ -80,8 +80,7 @@ CANCEL = 6
 # context.h). Per-service/method histograms for queue-wait and handler
 # latency, inflight gauges, and bytes counters on the server and both
 # clients — the framing IS the scheduler latency floor, so this is where
-# control-plane regressions become visible. RAY_TPU_METRICS_RPC_ENABLED=0
-# is the kill switch (the bench overhead probe flips it).
+# control-plane regressions become visible.
 # ---------------------------------------------------------------------------
 
 _rpc_metrics_singleton: Optional[dict] = None
@@ -120,12 +119,6 @@ def rpc_metrics() -> dict:
                 tag_keys=("loop",)),
         }
     return _rpc_metrics_singleton
-
-
-def _instrumentation_enabled() -> bool:
-    from ray_tpu.core.config import get_config
-
-    return get_config().metrics_rpc_enabled
 
 
 # ---------------------------------------------------------------------------
@@ -354,8 +347,7 @@ class RpcServer:
         self._server: Optional[asyncio.AbstractServer] = None
         self._conn_tasks: set = set()
         self._writers: set = set()
-        self._metrics = rpc_metrics() if _instrumentation_enabled() \
-            else None
+        self._metrics = rpc_metrics()
         # GCS load attribution: when installed (GcsServer only), called
         # as sink((service, method, caller, in_nbytes), wall_s, kwargs,
         # stream=...) after every handler — caller is the popped
@@ -419,9 +411,8 @@ class RpcServer:
             d = _sched_fuzz_delay()
             if d:
                 await asyncio.sleep(d)
-            if metrics is not None:
-                metrics["bytes"].inc_key(
-                    _K_SRV_OUT, _payload_nbytes(payload))
+            metrics["bytes"].inc_key(
+                _K_SRV_OUT, _payload_nbytes(payload))
             async with wlock:
                 if isinstance(payload, list):
                     # Raw frame: hand each segment to the transport
@@ -434,15 +425,12 @@ class RpcServer:
                 await writer.drain()
 
         async def run_unary(req_id: int, fn, kwargs: dict, codec: int,
-                            mkey: Optional[tuple] = None,
-                            t_recv: float = 0.0,
-                            attr: Optional[tuple] = None) -> None:
-            if metrics is not None or attr is not None:
-                now = _time.perf_counter()
-            if metrics is not None:
-                metrics["queue_wait"].observe_key(
-                    mkey, max(0.0, now - t_recv))
-                metrics["inflight"].inc_key(_K_SRV)
+                            mkey: tuple, t_recv: float,
+                            attr: Optional[tuple]) -> None:
+            now = _time.perf_counter()
+            metrics["queue_wait"].observe_key(
+                mkey, max(0.0, now - t_recv))
+            metrics["inflight"].inc_key(_K_SRV)
             try:
                 result = fn(**kwargs)
                 if inspect.isawaitable(result):
@@ -457,10 +445,9 @@ class RpcServer:
                          "traceback": traceback.format_exc()}
             finally:
                 inflight.pop(req_id, None)
-                if metrics is not None:
-                    metrics["inflight"].inc_key(_K_SRV, -1)
-                    metrics["handler"].observe_key(
-                        mkey, _time.perf_counter() - now)
+                metrics["inflight"].inc_key(_K_SRV, -1)
+                metrics["handler"].observe_key(
+                    mkey, _time.perf_counter() - now)
                 if attr is not None:
                     sink = self.attribution_sink
                     if sink is not None:
@@ -474,15 +461,12 @@ class RpcServer:
                 pass  # client hung up mid-reply; nothing to tell it
 
         async def run_stream(req_id: int, fn, kwargs: dict, codec: int,
-                             mkey: Optional[tuple] = None,
-                             t_recv: float = 0.0,
-                             attr: Optional[tuple] = None) -> None:
-            if metrics is not None or attr is not None:
-                now = _time.perf_counter()
-            if metrics is not None:
-                metrics["queue_wait"].observe_key(
-                    mkey, max(0.0, now - t_recv))
-                metrics["inflight"].inc_key(_K_SRV)
+                             mkey: tuple, t_recv: float,
+                             attr: Optional[tuple]) -> None:
+            now = _time.perf_counter()
+            metrics["queue_wait"].observe_key(
+                mkey, max(0.0, now - t_recv))
+            metrics["inflight"].inc_key(_K_SRV)
             try:
                 async for item in fn(**kwargs):
                     await send(STREAM_ITEM, req_id, item, codec)
@@ -497,10 +481,9 @@ class RpcServer:
                 end = {"ok": False, "error": e}
             finally:
                 inflight.pop(req_id, None)
-                if metrics is not None:
-                    metrics["inflight"].inc_key(_K_SRV, -1)
-                    metrics["handler"].observe_key(
-                        mkey, _time.perf_counter() - now)
+                metrics["inflight"].inc_key(_K_SRV, -1)
+                metrics["handler"].observe_key(
+                    mkey, _time.perf_counter() - now)
                 if attr is not None:
                     sink = self.attribution_sink
                     if sink is not None:
@@ -543,12 +526,9 @@ class RpcServer:
                     if task is not None:
                         task.cancel()
                     continue
-                if metrics is not None:
-                    t_recv = _time.perf_counter()
-                    metrics["bytes"].inc_key(
-                        _K_SRV_IN, len(payload) + _HEADER.size)
-                else:
-                    t_recv = 0.0
+                t_recv = _time.perf_counter()
+                metrics["bytes"].inc_key(
+                    _K_SRV_IN, len(payload) + _HEADER.size)
                 try:
                     (service, method, kwargs), codec = _de_codec(payload)
                 except Exception:  # noqa: BLE001
@@ -567,8 +547,7 @@ class RpcServer:
                         "error": RpcError(
                             f"no such RPC {service}.{method}")}, codec)
                     continue
-                mkey = (_key_for(service, method)
-                        if metrics is not None else None)
+                mkey = _key_for(service, method)
                 attr = ((service, method, caller,
                          len(payload) + _HEADER.size)
                         if self.attribution_sink is not None else None)
@@ -599,8 +578,7 @@ class AsyncRpcClient:
     def __init__(self, address: str, codec: int = CODEC_PICKLE):
         self.address = address
         self.codec = codec
-        self._metrics = rpc_metrics() if _instrumentation_enabled() \
-            else None
+        self._metrics = rpc_metrics()
         self._reader: Optional[asyncio.StreamReader] = None
         self._writer: Optional[asyncio.StreamWriter] = None
         self._wlock: Optional[asyncio.Lock] = None
@@ -641,9 +619,8 @@ class AsyncRpcClient:
         try:
             while True:
                 ftype, req_id, payload = await _read_frame(reader)
-                if metrics is not None:
-                    metrics["bytes"].inc_key(
-                        _K_CLI_IN, len(payload) + _HEADER.size)
+                metrics["bytes"].inc_key(
+                    _K_CLI_IN, len(payload) + _HEADER.size)
                 if ftype == RES:
                     fut = self._pending.pop(req_id, None)
                     if fut is not None and not fut.done():
@@ -685,9 +662,8 @@ class AsyncRpcClient:
         if d:
             await asyncio.sleep(d)
         payload = _ser(obj, self.codec)
-        if self._metrics is not None:
-            self._metrics["bytes"].inc_key(
-                _K_CLI_OUT, _payload_nbytes(payload))
+        self._metrics["bytes"].inc_key(
+            _K_CLI_OUT, _payload_nbytes(payload))
         async with self._wlock:
             if isinstance(payload, list):
                 for part in _frame_parts(ftype, req_id, payload):
@@ -698,8 +674,6 @@ class AsyncRpcClient:
 
     async def call(self, service: str, method: str,
                    timeout: Optional[float] = None, **kwargs) -> Any:
-        if self._metrics is None:
-            return await self._call(service, method, timeout, **kwargs)
         t0 = _time.perf_counter()
         self._metrics["inflight"].inc_key(_K_CLI)
         try:
@@ -859,12 +833,12 @@ class EventLoopThread:
         """Event-loop lag probe (ref: instrumented_io_context.h): a
         periodic sleep measures its own scheduling overshoot — the
         direct signal that a handler is hogging the loop (the exact
-        failure mode the reference's asio stats catch). Off when RPC
-        instrumentation is off or RAY_TPU_METRICS_LOOP_PROBE_MS=0."""
+        failure mode the reference's asio stats catch). Off when
+        RAY_TPU_METRICS_LOOP_PROBE_MS=0."""
         from ray_tpu.core.config import get_config
 
         probe_ms = get_config().metrics_loop_probe_ms
-        if not probe_ms or not _instrumentation_enabled():
+        if not probe_ms:
             return
 
         async def probe() -> None:
@@ -1026,8 +1000,7 @@ class SyncRpcClient:
         self.address = address
         self.codec = codec
         self._loop = loop_thread        # kept for API compatibility
-        self._metrics = rpc_metrics() if _instrumentation_enabled() \
-            else None
+        self._metrics = rpc_metrics()
         self._pool: list = []
         self._lock = threading.Lock()
         self._req_id = 0
@@ -1036,9 +1009,6 @@ class SyncRpcClient:
     def call(self, service: str, method: str,
              timeout: Optional[float] = None, idempotent: bool = False,
              **kwargs) -> Any:
-        if self._metrics is None:
-            return self._call(service, method, timeout, idempotent,
-                              **kwargs)
         t0 = _time.perf_counter()
         self._metrics["inflight"].inc_key(_K_CLI)
         try:
@@ -1135,11 +1105,10 @@ class SyncRpcClient:
                         conn.close()
                         conn = None
                         raise rpc_error(e2, "send") from e2
-            if self._metrics is not None:
-                self._metrics["bytes"].inc_key(
-                    _K_CLI_OUT, _payload_nbytes(payload))
-                self._metrics["bytes"].inc_key(
-                    _K_CLI_IN, conn.last_recv_nbytes)
+            self._metrics["bytes"].inc_key(
+                _K_CLI_OUT, _payload_nbytes(payload))
+            self._metrics["bytes"].inc_key(
+                _K_CLI_IN, conn.last_recv_nbytes)
             with self._lock:
                 if conn is not None and len(self._pool) < self.MAX_POOL:
                     self._pool.append(conn)

@@ -32,7 +32,8 @@ Two halves:
 
 Every knob is a `RAY_TPU_SYNCER_*` env var (config.py); both halves export
 Prometheus counters for deltas sent/suppressed/bytes so the delta-vs-full
-ratio is assertable (bench_scale many_nodes does exactly that).
+ratio is assertable (tests/test_syncer.py
+test_virtual_cluster_delta_dominant_ratio does exactly that).
 """
 from __future__ import annotations
 

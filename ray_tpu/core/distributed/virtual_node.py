@@ -11,8 +11,8 @@ object store, and no worker processes. Many virtual nodes multiplex over a
 few shared AsyncRpcClients, so 1000 nodes cost 1000 asyncio tasks + a
 handful of sockets, not 1000 processes.
 
-Used by bench_scale.py's `many_nodes` probe and the slow-marked pytest
-probe in tests/test_scale_smoke.py.
+Used by tests/test_syncer.py and the scale envelopes in
+tests/test_scale_smoke.py.
 """
 from __future__ import annotations
 
@@ -152,8 +152,7 @@ class InProcDaemonCluster:
     object stores, the real transfer plane (raw frames, create-then-
     fill, striped pulls, broadcast relays), but no worker processes
     (zygote/prestart forced off for the process). Used by the
-    object_transfer / broadcast probes in bench_scale.py and the
-    transfer tests.
+    transfer, channel and diagnosis tests.
     """
 
     def __init__(self, n_nodes: int, *, store_capacity: int = 512 << 20,

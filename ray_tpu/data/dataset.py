@@ -17,7 +17,6 @@ import pyarrow as pa
 
 import ray_tpu
 from ray_tpu.data import block as B
-from ray_tpu.data.execution import execute
 from ray_tpu.data.plan import AllToAllStage, MapStage, ReadTask
 
 BatchUDF = Callable[..., Any]
@@ -206,51 +205,14 @@ class Dataset:
         num_blocks partitions; reducers concat+permute
         (ref: data/_internal shuffle — push-based variant not needed yet).
 
-        On the streaming path every mapper packs its partitions into ONE
-        offset-addressed bundle that rides the broadcast/relay trees
-        (prestaged node-local on multi-node clusters) instead of N²
-        point-to-point pickled gets — see data/streaming/shuffle.py."""
-        def streaming_ref_fn(refs):
+        Every mapper packs its partitions into ONE offset-addressed
+        bundle that rides the broadcast/relay trees (prestaged
+        node-local on multi-node clusters) instead of N² point-to-point
+        pickled gets — see data/streaming/shuffle.py."""
+        def ref_fn(refs):
             from ray_tpu.data.streaming.shuffle import streaming_shuffle_refs
 
             return streaming_shuffle_refs(refs, seed, self._name())
-
-        def ref_fn(refs):
-            from ray_tpu.data.streaming import streaming_enabled
-
-            if streaming_enabled():
-                return streaming_ref_fn(refs)
-            refs = list(refs)
-            if not refs:
-                return refs
-            n_out = len(refs)
-
-            @ray_tpu.remote
-            def scatter(block, n, s):
-                rng = np.random.default_rng(s)
-                idx = rng.permutation(block.num_rows)
-                parts = np.array_split(idx, n)
-                out = tuple(block.take(pa.array(p)) for p in parts)
-                return out[0] if n == 1 else out
-
-            @ray_tpu.remote
-            def combine(s, *parts):
-                t = B.concat(list(parts))
-                rng = np.random.default_rng(s)
-                return t.take(pa.array(rng.permutation(t.num_rows)))
-
-            ss = np.random.SeedSequence(seed)
-            seeds = ss.generate_state(2 * len(refs) + n_out)
-            scattered = [
-                scatter.options(num_returns=n_out).remote(r, n_out,
-                                                          int(seeds[i]))
-                for i, r in enumerate(refs)]
-            if n_out == 1:
-                scattered = [[s] for s in scattered]
-            return [combine.remote(int(seeds[len(refs) + j]),
-                                   *[scattered[i][j]
-                                     for i in range(len(refs))])
-                    for j in range(n_out)]
 
         return self._with(AllToAllStage("RandomShuffle", ref_fn))
 
@@ -394,19 +356,13 @@ class Dataset:
         never stall the pipeline; `equal=True` instead enforces
         round-robin handout (consumers advance in lockstep).
 
-        On the streaming path the coordinator is the ack-based
-        StreamSplitCoordinator (data/streaming/split.py): it tracks one
-        outstanding block per consumer and supports live resplit() on
-        elastic world-size change — no epoch restart, no lost or
-        duplicated samples."""
-        from ray_tpu.data.streaming import streaming_enabled
+        The coordinator is the ack-based StreamSplitCoordinator
+        (data/streaming/split.py): it tracks one outstanding block per
+        consumer and supports live resplit() on elastic world-size
+        change — no epoch restart, no lost or duplicated samples."""
+        from ray_tpu.data.streaming.split import StreamSplitCoordinator
 
-        if streaming_enabled():
-            from ray_tpu.data.streaming.split import StreamSplitCoordinator
-
-            coord = StreamSplitCoordinator.remote(self, n, equal)
-        else:
-            coord = _SplitCoordinator.remote(self, n, equal)
+        coord = StreamSplitCoordinator.remote(self, n, equal)
         return [StreamingSplitIterator(coord, i) for i in range(n)]
 
     def split_at_indices(self, indices: List[int]) -> List["Dataset"]:
@@ -426,23 +382,16 @@ class Dataset:
     # ---------------- execution / consumption ----------------
     def to_block_refs(self) -> Iterator[Any]:
         from ray_tpu.data.stats import DatasetStats
-        from ray_tpu.data.streaming import streaming_enabled, streaming_execute
+        from ray_tpu.data.streaming import streaming_execute
 
         self._last_stats = DatasetStats()
-        if streaming_enabled():
-            # Default path: byte-budgeted streaming operator graph over
-            # the transfer plane (RAY_TPU_DATA_STREAM_ENABLED=0 falls
-            # back to the legacy block-materializing executor).
-            try:
-                yield from streaming_execute(self._read_tasks, self._stages,
-                                             stats=self._last_stats)
-            finally:
-                from ray_tpu.data.streaming import metrics as _dm
+        try:
+            yield from streaming_execute(self._read_tasks, self._stages,
+                                         stats=self._last_stats)
+        finally:
+            from ray_tpu.data.streaming import metrics as _dm
 
-                _dm.on_execution(self._name(), self._last_stats)
-            return
-        yield from execute(self._read_tasks, self._stages,
-                           stats=self._last_stats)
+            _dm.on_execution(self._name(), self._last_stats)
 
     def _name(self) -> str:
         return getattr(self, "_label", "ds")
@@ -705,42 +654,6 @@ class Dataset:
         names = [getattr(s, "name", "?") for s in self._stages]
         return (f"Dataset(blocks~{len(self._read_tasks)}, "
                 f"stages={names})")
-
-
-@ray_tpu.remote(num_cpus=0)
-class _SplitCoordinator:
-    """Hands one streaming execution's block refs out to N consumers
-    (ref: output_splitter.py OutputSplitter). Lives in an actor so every
-    consumer — typically a Train worker on another node — pulls from the
-    SAME execution instead of re-executing the dataset per shard."""
-
-    def __init__(self, dataset, n: int, equal: bool):
-        self._n = n
-        self._equal = equal
-        self._it = iter(dataset.to_block_refs())
-        self._queues: List[list] = [[] for _ in range(n)]
-        self._next_rr = 0
-        self._done = False
-
-    def _pull(self):
-        try:
-            return next(self._it)
-        except StopIteration:
-            self._done = True
-            return None
-
-    def next_block(self, consumer_idx: int):
-        """Next block ref for this consumer, or None when exhausted."""
-        if not self._equal:
-            return None if self._done else self._pull()
-        q = self._queues[consumer_idx]
-        while not q and not self._done:
-            ref = self._pull()
-            if ref is None:
-                break
-            self._queues[self._next_rr].append(ref)
-            self._next_rr = (self._next_rr + 1) % self._n
-        return q.pop(0) if q else None
 
 
 class StreamingSplitIterator:

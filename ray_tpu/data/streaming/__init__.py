@@ -1,9 +1,7 @@
 """Streaming data plane: backpressured Dataset execution over the
 zero-copy transfer plane.
 
-The package replaces the block-materializing default path in
-``data/execution.py`` (kept as the ``RAY_TPU_DATA_STREAM_ENABLED=0``
-fallback) with a byte-budgeted operator graph:
+Every Dataset runs through this package:
 
 - ``executor``  — operator graph whose submissions are gated by a
   bytes-windowed backpressure budget (per-operator in-flight byte caps,
@@ -19,13 +17,12 @@ fallback) with a byte-budgeted operator graph:
 - ``metrics``   — per-operator data-plane gauges federated over the
   report-gauges → syncer → GCS path.
 """
-from ray_tpu.data.streaming.executor import streaming_enabled, streaming_execute
+from ray_tpu.data.streaming.executor import streaming_execute
 from ray_tpu.data.streaming.prefetch import DevicePrefetcher
 from ray_tpu.data.streaming.split import StreamSplitCoordinator
 
 __all__ = [
     "DevicePrefetcher",
     "StreamSplitCoordinator",
-    "streaming_enabled",
     "streaming_execute",
 ]

@@ -1,18 +1,33 @@
-"""Byte-budgeted streaming operator graph — the default Dataset path.
+"""Byte-budgeted streaming operator graph: the Dataset executor.
 
-Extends the operator-graph executor (data/execution.py, kept as the
-``RAY_TPU_DATA_STREAM_ENABLED=0`` fallback) with the reference's
-byte-based backpressure model (ref: python/ray/data/_internal/execution/
-backpressure_policy/streaming_output_backpressure_policy.py): operator
-tasks return ``(block, meta)`` with ``num_returns=2`` so the tiny meta
-object (rows/bytes) is fetched at harvest without materializing the
-block, and every operator is charged for the bytes it has produced that
-no downstream consumer has picked up yet.
+The reference runs each dataset as a graph of concurrent operators with
+per-operator resource budgets, a scheduling step that picks which
+operator to advance, and pluggable backpressure
+(ref: python/ray/data/_internal/execution/streaming_executor.py:55,
+streaming_executor_state.py:494 `select_operator_to_run`,
+backpressure_policy/streaming_output_backpressure_policy.py). This
+module is the equivalent:
 
-Backpressure composes four ways here:
+- Each map segment becomes a linear graph of operators (a read source,
+  fused task-map operators, actor-pool operators). Every operator owns a
+  BOUNDED input queue, an in-flight task budget, and a bounded output
+  queue. All-to-all stages are barriers between segments, as in the
+  reference's plan segmentation.
+- A scheduling step harvests completions, propagates blocks between
+  queues, then advances the RUNNABLE operator with the most headroom
+  (free budget fraction; ties drain downstream-most first) — one task
+  per step, so all operators genuinely overlap instead of running as
+  chained sliding windows. Blocks stay ordered: completions are
+  harvested in submission order per operator.
+- Operator tasks return ``(block, meta)`` with ``num_returns=2`` so the
+  tiny meta object (rows/bytes) is fetched at harvest without
+  materializing the block, and every operator is charged for the bytes
+  it has produced that no downstream consumer has picked up yet.
 
-- task budget + bounded queues, inherited from the legacy executor
-  (shrunk under object-store pressure via ``_effective_window``);
+Backpressure composes four ways:
+
+- the task budget and the bounded queues (the budget shrinks under
+  object-store pressure via ``_effective_window``);
 - a per-operator in-flight byte cap (``data_stream_op_inflight_bytes``)
   — an operator over its cap stops submitting, and the seconds it sits
   byte-blocked are accounted per stage in ``Dataset.stats()``;
@@ -36,27 +51,28 @@ from __future__ import annotations
 
 import logging
 import time
+from collections import deque
 from typing import Any, Iterator, List, Optional
 
 import ray_tpu
 from ray_tpu.core.config import get_config
 from ray_tpu.data.block import concat
-from ray_tpu.data.execution import (
-    _default_window,
-    _effective_window,
-    _group_name,
-    _Operator,
-    _split_actor_stages,
-)
 from ray_tpu.data.plan import AllToAllStage, MapStage, ReadTask, fuse_map_chain
-from ray_tpu.data.stats import DatasetStats
+from ray_tpu.data.stats import DatasetStats, StageStats
 from ray_tpu.exceptions import BackpressureTimeout
 
 logger = logging.getLogger(__name__)
 
 
-def streaming_enabled() -> bool:
-    return get_config().data_stream_enabled
+def _default_window() -> int:
+    """Resource-aware per-operator budget (ref: backpressure_policy/
+    concurrency_cap_backpressure_policy.py): enough in-flight tasks to
+    cover the cluster's CPUs twice, bounded."""
+    try:
+        cpus = int(ray_tpu.cluster_resources().get("CPU", 4))
+    except Exception:  # noqa: BLE001
+        cpus = 4
+    return max(4, min(2 * cpus, 64))
 
 
 def _store_fraction() -> float:
@@ -72,6 +88,15 @@ def _store_fraction() -> float:
     except Exception:  # noqa: BLE001
         pass
     return 0.0
+
+
+def _effective_window(base: int) -> int:
+    """Shrink a budget under object-store pressure (ref:
+    backpressure_policy/streaming_output_backpressure_policy.py — the
+    executor must not outrun consumers into an overflowing store)."""
+    if _store_fraction() > 0.85:
+        return max(2, base // 4)
+    return base
 
 
 def _meta(blk) -> dict:
@@ -128,13 +153,31 @@ def _consume(item):
     return item.consume() if isinstance(item, _StreamItem) else item
 
 
-class _StreamOp(_Operator):
-    """Operator with produced-but-unconsumed byte accounting."""
+class _StreamOp:
+    """One node of the operator graph: bounded inqueue -> budgeted
+    in-flight remote tasks -> bounded outqueue, with an account of the
+    bytes it has produced that nobody has consumed yet (ref: execution/
+    interfaces/physical_operator.py — an operator owns its task pool
+    and exposes readiness to the scheduling loop)."""
 
-    def __init__(self, name, budget, stats, depth, bytebudget: _ByteBudget):
-        super().__init__(name, budget, stats, depth)
+    def __init__(self, name: str, budget: int, stats: StageStats,
+                 depth: int, bytebudget: _ByteBudget):
+        self.name = name
+        self.budget = budget
+        self.max_queue = 2 * budget   # inter-op queue bound
+        self.stats = stats
+        self.depth = depth
+        self.inqueue: deque = deque()
+        self.in_flight: deque = deque()   # (refs, extra) submission order
+        self.outqueue: deque = deque()
+        self.upstream_done = False
         self.bytebudget = bytebudget
         self.unconsumed = 0
+
+    # -- source feeding -------------------------------------------------
+    def feed(self, item: Any) -> None:
+        self.inqueue.append(item)
+        self.stats.on_queue(len(self.inqueue))
 
     # -- byte ledger ----------------------------------------------------
     def charge(self, nbytes: int) -> None:
@@ -152,7 +195,10 @@ class _StreamOp(_Operator):
                 or self.bytebudget.total >= self.bytebudget.window)
 
     def task_runnable(self) -> bool:
-        return super().runnable()
+        return (bool(self.inqueue)
+                and len(self.in_flight) < _effective_window(self.budget)
+                and len(self.in_flight) + len(self.outqueue)
+                < self.max_queue)
 
     def runnable(self) -> bool:
         return self.task_runnable() and not self.byte_blocked()
@@ -162,7 +208,31 @@ class _StreamOp(_Operator):
         the condition whose duration lands in ``stats.stall_s``."""
         return self.task_runnable() and self.byte_blocked()
 
-    # -- completion harvest ---------------------------------------------
+    def headroom(self) -> float:
+        return 1.0 - len(self.in_flight) / max(1, self.budget)
+
+    def submit_one(self) -> None:
+        item = self.inqueue.popleft()
+        refs, extra = self._launch(item)
+        self.in_flight.append((refs, extra))
+        self.stats.on_submit()
+        self.stats.on_active(len(self.in_flight))
+
+    def _launch(self, item):
+        raise NotImplementedError
+
+    def _on_done(self, extra) -> None:
+        pass
+
+    @property
+    def finished(self) -> bool:
+        return (self.upstream_done and not self.inqueue
+                and not self.in_flight and not self.outqueue)
+
+    def shutdown(self) -> None:
+        pass
+
+    # -- completion harvest (in submission order) -----------------------
     def harvest(self) -> bool:
         progressed = False
         while self.in_flight:
@@ -200,8 +270,9 @@ class _StreamTaskMapOp(_StreamOp):
 
 
 class _StreamActorPool:
-    """Least-loaded actor pool whose UDF actors also report block meta
-    (mirror of execution._ActorPool with ``num_returns=2`` methods)."""
+    """Small pool of UDF-holding actors with least-loaded dispatch,
+    whose ``apply`` returns ``(block, meta)``
+    (ref: execution/operators/actor_pool_map_operator.py)."""
 
     def __init__(self, fn_maker, size: int):
         @ray_tpu.remote
@@ -259,11 +330,37 @@ class _StreamActorMapOp(_StreamOp):
             self._pool = None
 
 
+def _split_actor_stages(stages: List[MapStage]):
+    """Group consecutive task-fusable stages; actor stages break fusion."""
+    groups: List[Any] = []
+    cur: List[MapStage] = []
+    for st in stages:
+        if st.actor_fn_maker is not None:
+            if cur:
+                groups.append(cur)
+                cur = []
+            groups.append(st)
+        else:
+            cur.append(st)
+    if cur:
+        groups.append(cur)
+    return groups
+
+
+def _group_name(group) -> str:
+    if isinstance(group, list):
+        return "+".join(s.name for s in group) or "Map"
+    return group.name
+
+
 def _build_stream_graph(map_stages, max_in_flight, stats: DatasetStats,
                         bytebudget: _ByteBudget,
                         with_source: bool = False) -> List[_StreamOp]:
-    """Linear streaming-operator graph for one barrier-free segment
-    (same segmentation/fusion rules as execution._build_graph)."""
+    """Linear operator graph for one barrier-free segment. With
+    `with_source`, the head operator executes ReadTasks (fed lazily by
+    _run_stream_graph through the same bounded inqueue as every other
+    op, so its queue stats reflect real backpressure, not the
+    parallelism)."""
     ops: List[_StreamOp] = []
     groups = _split_actor_stages(map_stages)
 
@@ -298,10 +395,15 @@ def _build_stream_graph(map_stages, max_in_flight, stats: DatasetStats,
 
 def _run_stream_graph(ops: List[_StreamOp],
                       feed: Optional[Iterator[Any]] = None) -> Iterator[Any]:
-    """Scheduling loop: harvest -> propagate -> yield sink -> submit the
-    runnable op with the most headroom (ties downstream-most), exactly
-    as execution._run_graph — plus stall accounting on byte-blocked
-    operators, the spill fallback, and the stall deadline."""
+    """The scheduling loop (ref: streaming_executor_state.py:494).
+
+    Repeats: harvest completions -> propagate between bounded queues ->
+    yield sink output -> advance the runnable operator with the most
+    headroom (ties go downstream-most so the pipeline drains), with
+    stall accounting on byte-blocked operators, the spill fallback and
+    the stall deadline. Blocks on the head in-flight refs only when no
+    step can make progress. `feed` lazily supplies the first operator's
+    input (read tasks, or refs from an upstream barrier)."""
     if not ops:
         if feed is not None:
             yield from (_consume(x) for x in feed)
@@ -402,7 +504,7 @@ def streaming_execute(read_tasks: List[ReadTask], stages: List[Any], *,
                       max_in_flight: Optional[int] = None,
                       stats: Optional[DatasetStats] = None) -> Iterator[Any]:
     """Yield block refs for the fully-applied plan through the
-    byte-budgeted streaming graph (drop-in for execution.execute)."""
+    byte-budgeted streaming graph."""
     cfg = get_config()
     if max_in_flight is None:
         max_in_flight = _default_window()

@@ -1,8 +1,8 @@
 """Pipeline-resident device prefetch for ``iter_jax_batches``.
 
-The legacy feed issued ``jax.device_put`` inline on the consumer
-thread: batch formation, host→HBM transfer, and compute all serialize.
-Here a background thread owns the whole host side — it pulls numpy
+A ``jax.device_put`` issued inline on the consumer thread serializes
+batch formation, host→HBM transfer, and compute.  Here a background
+thread owns the whole host side — it pulls numpy
 batches from the (already streaming) block iterator, applies the
 dtype/sharding transform, and parks up to ``depth`` device-resident
 batches in a bounded queue.  With ``depth=2`` (the default knob) the
@@ -13,8 +13,8 @@ layer describes).
 Hit/miss accounting feeds the data-plane gauges: a *hit* means the
 consumer found a batch already resident when it asked (the pipeline is
 ahead of the accelerator); a run of misses means ingestion is the
-bottleneck and shows up directly in ``bench_data.py``'s train-busy
-probe.
+bottleneck and shows up as the train cell's ``input_wait_ms``
+(``bench/harness/train_cell.py``).
 """
 from __future__ import annotations
 

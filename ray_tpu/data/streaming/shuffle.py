@@ -1,12 +1,11 @@
 """All-to-all shuffle over the zero-copy transfer plane.
 
-The legacy shuffle (dataset.random_shuffle) moves every mapper→reducer
-partition as its own pickled object through point-to-point gets — N²
-small transfers per round, each paying the pickle codec and its own RPC
-slow-start. The streaming shuffle instead has every mapper emit ONE
-sealed *bundle* — all of its reducer partitions packed back-to-back
-behind a fixed-size offset header — and moves bundles over the
-transfer plane:
+A shuffle that moves every mapper→reducer partition as its own pickled
+object through point-to-point gets makes N² small transfers per round,
+each paying the pickle codec and its own RPC slow-start. Here every
+mapper of ``Dataset.random_shuffle`` emits ONE sealed *bundle* — all of
+its reducer partitions packed back-to-back behind a fixed-size offset
+header — and bundles move over the transfer plane:
 
 - **relay-tree pre-staging** (multi-node): each bundle is broadcast to
   every node over the daemon relay tree (`plan_broadcast_tree` /

@@ -1,14 +1,14 @@
 """Elastic streaming split: ack-based block handout that survives
 world-size changes mid-epoch.
 
-The legacy ``_SplitCoordinator`` (data/dataset.py) hands refs out
-fire-and-forget: a consumer that dies between delivery and processing
-silently loses its block, and a resize has no way to redistribute
-queued work. This coordinator tracks one *outstanding* (delivered but
-not yet acknowledged) block per consumer — requesting block k+1
-acknowledges block k, matching the iterator's consume-then-request
-discipline — so on ``resplit(new_n)`` or ``mark_dead(idx)`` the
-unacknowledged blocks are requeued for the surviving consumers:
+A hand-out that forgets a ref once delivered loses the block of a
+consumer that dies between delivery and processing, and gives a resize
+no way to redistribute queued work. This coordinator tracks one
+*outstanding* (delivered but not yet acknowledged) block per consumer —
+requesting block k+1 acknowledges block k, matching the iterator's
+consume-then-request discipline — so on ``resplit(new_n)`` or
+``mark_dead(idx)`` the unacknowledged blocks are requeued for the
+surviving consumers:
 
 - no epoch restart — the single streaming execution keeps going
   (``epoch_id`` never changes across a resize);

@@ -1,5 +1,5 @@
-"""Named model configs (BASELINE.json config list: GPT-2 124M, Llama-3-8B,
-Llama-2-7B-class, plus test/bench sizes)."""
+"""Named model configs (GPT-2 124M, Llama-3-8B, Llama-2-7B-class, Mixtral,
+plus test/bench sizes)."""
 from __future__ import annotations
 
 import dataclasses

@@ -147,7 +147,7 @@ def test_dataset_stats(data_cluster):
 
 
 def test_backpressure_window_shrinks_under_store_pressure(monkeypatch):
-    from ray_tpu.data import execution
+    from ray_tpu.data.streaming.executor import _effective_window
 
     class FakeStore:
         capacity = 100
@@ -159,9 +159,9 @@ def test_backpressure_window_shrinks_under_store_pressure(monkeypatch):
     import ray_tpu.api as api
 
     monkeypatch.setattr(api, "_worker", FakeWorker())
-    assert execution._effective_window(32) == 8
+    assert _effective_window(32) == 8
     FakeStore.used = 10
-    assert execution._effective_window(32) == 32
+    assert _effective_window(32) == 32
 
 
 def test_aggregate_depth_std_quantile_unique():

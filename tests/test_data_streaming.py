@@ -23,16 +23,15 @@ def ray_cluster():
 def _restore_stream_knobs():
     cfg = get_config()
     keep = {k: getattr(cfg, k) for k in (
-        "data_stream_enabled", "data_stream_window_bytes",
-        "data_stream_op_inflight_bytes", "data_stream_spill_threshold",
-        "data_stream_stall_timeout_s", "data_stream_prefetch_depth")}
+        "data_stream_window_bytes", "data_stream_op_inflight_bytes",
+        "data_stream_spill_threshold", "data_stream_stall_timeout_s",
+        "data_stream_prefetch_depth")}
     yield
     for k, v in keep.items():
         setattr(cfg, k, v)
 
 
 def test_streaming_is_default_and_correct():
-    assert get_config().data_stream_enabled
     ds = (rd.range(64, parallelism=4)
           .map_batches(lambda b: {"x": b["id"] * 2}, batch_format="numpy"))
     out = ds.to_numpy()["x"]
@@ -51,15 +50,6 @@ def test_per_operator_byte_stats_populated():
     # The human summary surfaces the new breakdowns.
     s = ds.stats()
     assert "MB out" in s and "stalled" in s
-
-
-def test_legacy_fallback_knob():
-    cfg = get_config()
-    cfg.data_stream_enabled = False
-    ds = rd.range(50, parallelism=3).map(lambda r: r["id"] + 1)
-    assert sorted(ds.take_all()) == list(range(1, 51))
-    # Legacy executor does no byte accounting.
-    assert all(st.bytes_out == 0 for st in ds._last_stats.stages)
 
 
 def test_tiny_op_cap_backpressures_but_completes():

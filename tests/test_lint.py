@@ -5,7 +5,10 @@ parsing + hygiene), each rule against its seeded bad/good fixture tree
 under tests/lint_fixtures/, and — the acceptance contract — a
 zero-violations run over the live repository with all six rules enabled.
 """
+import dataclasses
+import functools
 import json
+import re
 import shutil
 from pathlib import Path
 
@@ -255,3 +258,27 @@ def test_knob_table_covers_registry():
     readme = (REPO_ROOT / "README.md").read_text()
     for k in knobs:
         assert k.env in readme, f"{k.env} missing from README"
+
+
+@functools.lru_cache(maxsize=None)
+def _package_files():
+    return LintContext(REPO_ROOT).package_files()
+
+
+@pytest.mark.parametrize("name", [
+    "data_stream_enabled",
+    "task_lane_enabled",
+    "metrics_rpc_enabled",
+    "transfer_raw_frames",
+    "pg_prewarm_enabled",
+    "lineage_pinning_enabled",
+])
+def test_one_valued_switch_stays_removed(name):
+    """Each of these selected between a path and the one that replaced
+    it, and had one value in use; the other path went with it.  A field
+    of that name coming back means a second path came back."""
+    from ray_tpu.core.config import Config
+
+    assert name not in {f.name for f in dataclasses.fields(Config)}
+    word = re.compile(rf"\b{name}\b")
+    assert [f.rel for f in _package_files() if word.search(f.source)] == []

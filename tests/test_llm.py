@@ -336,8 +336,8 @@ def test_moe_engine_decode_matches_reprefill():
     cached greedy decode == re-prefilling the growing sequence from
     scratch each step. Inference uses DROPLESS exact routing
     (moe_mlp_dropless), so the function is batch-size independent —
-    capacity-based train routing would make these disagree (ref:
-    BASELINE 'Mixtral 8x7B EP' config; TINY_MOE is the CPU stand-in)."""
+    capacity-based train routing would make these disagree (TINY_MOE
+    is the CPU stand-in for the Mixtral-8x7B expert-parallel config)."""
     mcfg = configs.TINY_MOE
     mparams = init_params(jax.random.key(3), mcfg)
 

@@ -1,7 +1,6 @@
-"""CI-sized scale smoke: the bench_scale.py probes at pytest scale
-(ref: release/benchmarks/distributed/test_many_tasks.py,
-test_many_actors.py scaled to a shared-CPU test box; full harness:
-bench_scale.py at the repo root)."""
+"""CI-sized scale smoke (ref: release/benchmarks/distributed/
+test_many_tasks.py, test_many_actors.py scaled to a shared-CPU test
+box; the full-size envelopes below are marked slow)."""
 import time
 
 import pytest
@@ -71,11 +70,10 @@ def test_actor_wave_create_ping_kill(cluster_ray):
 
 @pytest.mark.slow
 def test_many_actors_1000(cluster_ray):
-    """Full-size many_actors probe (bench_scale.py's shape): 1,000
-    actors through the zygote fork path. The asserted floor is far
-    below the recorded ~20+/s so a loaded CI box doesn't flake, but far
-    above the ~0.36/s cold-spawn era — a regression to cold spawning
-    fails this."""
+    """Full-size many_actors envelope: 1,000 actors through the zygote
+    fork path. The asserted floor is low enough that a loaded CI box
+    doesn't flake, but far above what cold spawning reaches — a
+    regression to cold spawning fails this."""
     rate = _actor_churn(cluster_ray, total=1000, wave=50)
     assert rate >= 5.0, f"actor churn regressed to {rate:.2f}/s"
 
@@ -126,10 +124,10 @@ def test_virtual_nodes_100_sync_deltas():
 
 @pytest.mark.slow
 def test_many_virtual_nodes_1000():
-    """Full-size scale envelope (bench_scale.py's many_nodes shape):
-    1000 virtual daemons sustained on one GCS, with the sync path
-    provably delta-dominant — a regression to full-state reporting
-    (or nodes flapping dead under load) fails this."""
+    """Full-size scale envelope: 1000 virtual daemons sustained on one
+    GCS, with the sync path provably delta-dominant — a regression to
+    full-state reporting (or nodes flapping dead under load) fails
+    this."""
     alive, stats, agg, sub_view = _virtual_node_envelope(
         1000, churn_rounds=8, report_interval_s=0.5)
     assert alive >= 1000, f"only {alive}/1000 virtual daemons alive"

@@ -330,8 +330,8 @@ def test_end_to_end_delta_sync_and_fanout():
 def test_virtual_cluster_delta_dominant_ratio():
     """A 30-node virtual cluster under churn keeps the sync path
     delta-dominant: full snapshots happen once per connect, steady state
-    is deltas + suppressed ticks (the bench_scale many_nodes assertion,
-    tier-1 sized)."""
+    is deltas + suppressed ticks (the 1000-node envelope of
+    tests/test_scale_smoke.py, tier-1 sized)."""
     from ray_tpu.core.distributed.gcs_server import GcsServer
     from ray_tpu.core.distributed.virtual_node import VirtualCluster
 
