@@ -131,6 +131,34 @@ TINY_MLA_MOE = MLAMoEConfig(
 # part of the stack.
 GLM_4_7_FLASH = MLAMoEConfig(name="glm-4.7-flash")
 
+# `MLAMoEConfig`'s two kinds of layer at test size: a dense full layer,
+# one period of (full, window, window, window) and two layers more; a full
+# layer's 4 heads attend to the 16 positions that 4 index heads of 16 score
+# highest, a window layer's 2 heads over its own, larger latent to the 12
+# positions up to their own; a gate a head, the latents rescaled.
+TINY_DSA_MOE = dataclasses.replace(
+    TINY_MLA_MOE, name="tiny-dsa-moe", n_layers=7,
+    lead_pattern=("full",),
+    layer_pattern=("full", "window", "window", "window"), window=12,
+    n_heads_window=2, q_rank_window=32, kv_rank_window=40, d_nope_window=20,
+    d_v_window=16, rope_theta_window=500.0, attn_gate=True, latent_rescale=True,
+    index_heads=4, index_dim=16, index_top_k=16, route_scale=1.0)
+
+# dots3-note-prev's published sizes, the language model (279.6 B
+# parameters of the published 288 B: the towers and the multi-token
+# prediction module are not part of the stack), every expert held: a dense
+# full layer, a full layer, then three window layers to one full layer.
+DOTS3_NOTE_PREV = MLAMoEConfig(
+    name="dots3-note-prev", vocab_size=152064, d_model=5120, n_layers=46,
+    n_dense_layers=1, n_heads=128, q_rank=1024, kv_rank=512, d_nope=128,
+    d_rope=64, d_v=128, d_ff=13824, n_experts=256, expert_top_k=8,
+    d_expert=1536, d_shared=1536, route_scale=1.0, rope_theta=8e7,
+    max_seq_len=524288, lead_pattern=("full",),
+    layer_pattern=("full", "window", "window", "window"), window=513,
+    n_heads_window=64, kv_rank_window=1024, d_nope_window=192,
+    rope_theta_window=5e4, attn_gate=True, latent_rescale=True,
+    index_heads=64, index_dim=128, index_top_k=2048)
+
 # What a layer can have of its own in the homogeneous stack, at test
 # size: a dense first layer before two periods of three window layers to
 # one full layer, 8 query heads in a window layer and 6 in a full one over
@@ -172,6 +200,7 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_WINDOW_MOE, MELLUM2_12B,
                                 TINY_MAMBA2_MOE, GRANITE4_H_SMALL,
                                 TINY_MLA_MOE, GLM_4_7_FLASH,
+                                TINY_DSA_MOE, DOTS3_NOTE_PREV,
                                 TINY_GATED_MOE, LAGUNA_XS_2]}
 
 

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -503,7 +504,9 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     cache, logits, _, taken, _ = _paged_decode_logits(
         params, cache, tokens, block_tables, lengths, active, cfg, slots,
         routing)
-    return (cache, logits, taken[:, :, 0]) if routing else (cache, logits)
+    if routing:    # what the rows took: an array, or a model's tree of them
+        return cache, logits, jax.tree.map(lambda a: a[:, :, 0], taken)
+    return cache, logits
 
 
 def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
@@ -580,7 +583,7 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
             x, jnp.maximum(n_valid - 1, 0), 1, axis=1)
         out = (cache, cfg.final_logits(params, last)[0, 0])
     if routing:
-        out += (taken[:, 0],)
+        out += (jax.tree.map(lambda a: a[:, 0], taken),)
     # From a model that counts them, last: the chunk's top-k choices
     # that fell on experts held here (`_served_forward`).
     return (*out, routed) if counts_routed(cfg) else out
@@ -662,34 +665,53 @@ def copy_block(cache, dst: jax.Array, src: jax.Array):
     return _with_pooled(cache, lambda a, _: a.at[:, dst].set(a[:, src]))
 
 
+def _pooled(cache) -> list:
+    return [getattr(cache, name) for name in pooled_leaves(cache)]
+
+
+def _alike(leaves) -> bool:
+    """Whether the pooled leaves stack: one shape (the `k` / `v` pair, a
+    single leaf).  Leaves of unlike rows (a latent pool and its indexer's
+    keys) share layers, blocks and block size and go side by side."""
+    return len({a.shape for a in leaves}) == 1
+
+
 def gather_blocks(cache, block_ids) -> "jnp.ndarray":
     """Extract pool blocks as one host-transferable KV frame: the pooled
     leaves stacked, (n_leaves, L, n, block_size, *row): (2, L, n,
     block_size, Hkv, D) with k over v for the `k` / `v` pair, (1, L, n,
-    block_size, W) for a latent pool.  The frame is
+    block_size, W) for a latent pool; leaves of unlike rows side by side
+    in one leaf's place, their rows flattened and joined, (1, L, n,
+    block_size, sum of the rows): latent rows and index keys.  The frame is
     the disaggregated-serving wire unit — a prefill actor gathers its
     finished blocks, `jax.device_get` turns them into a plain ndarray,
     and the bytes ride the zero-copy transfer plane like any sealed shm
     object (serve/disagg.py ships them; import is `scatter_blocks`).
     Exact roundtrip: no dtype change, so a migrated stream's decode is
-    bit-identical to never having moved.  Pooled leaves of unlike shapes
-    do not stack: such a state would need a frame a leaf."""
+    bit-identical to never having moved."""
     import numpy as np
 
     ids = jnp.asarray(np.asarray(block_ids, np.int32))
-    return jnp.stack([getattr(cache, name)[:, ids]
-                      for name in pooled_leaves(cache)])
+    leaves = [a[:, ids] for a in _pooled(cache)]
+    if _alike(leaves):
+        return jnp.stack(leaves)
+    return jnp.concatenate([a.reshape(*a.shape[:3], -1) for a in leaves],
+                           axis=-1)[None]
 
 
 def frame_fits(cache, frame_shape) -> bool:
     """Whether a `gather_blocks` frame of `frame_shape` is of `cache`'s
     geometry: its leaves, layers, block size and row."""
-    leaves = [getattr(cache, name) for name in pooled_leaves(cache)]
+    leaves = _pooled(cache)
+    if _alike(leaves):
+        n, row = len(leaves), tuple(leaves[0].shape[3:])
+    else:
+        n, row = 1, (sum(math.prod(a.shape[3:]) for a in leaves),)
     want = leaves[0].shape
-    return (len(frame_shape) == len(want) + 1
-            and frame_shape[0] == len(leaves)
-            and frame_shape[1] == want[0]
-            and tuple(frame_shape[3:]) == tuple(want[2:]))
+    return (len(frame_shape) == 4 + len(row)
+            and frame_shape[0] == n and frame_shape[1] == want[0]
+            and frame_shape[3] == want[2]
+            and tuple(frame_shape[4:]) == row)
 
 
 def scatter_blocks(cache, block_ids, frame):
@@ -701,9 +723,14 @@ def scatter_blocks(cache, block_ids, frame):
     import numpy as np
 
     ids = jnp.asarray(np.asarray(block_ids, np.int32))
-    first = getattr(cache, pooled_leaves(cache)[0])
-    frame = jnp.asarray(frame, first.dtype)
-    return _with_pooled(cache, lambda a, i: a.at[:, ids].set(frame[i]))
+    leaves = _pooled(cache)
+    frame = jnp.asarray(frame, leaves[0].dtype)
+    if _alike(leaves):
+        return _with_pooled(cache, lambda a, i: a.at[:, ids].set(frame[i]))
+    ends = np.cumsum([math.prod(a.shape[3:]) for a in leaves])
+    return _with_pooled(cache, lambda a, i: a.at[:, ids].set(
+        frame[0][..., ends[i] - math.prod(a.shape[3:]):ends[i]].reshape(
+            *frame.shape[1:4], *a.shape[3:])))
 
 
 def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):

@@ -2,12 +2,23 @@
 served path: a decoder whose KV cache is one low-rank row a position a
 layer, read in absorbed form; whose leading layers have a dense FFN and
 the rest one rank's share of an expert-parallel FFN chosen by biased
-sigmoid scores, beside a shared expert (the layout of the `glm4_moe_lite`
-and `deepseek_v3` config families).  `models.hybrid` and
-`models.mamba2_moe` are its siblings under the same protocol; unlike
-theirs, its sequences are pool blocks alone (`state_by_slot` false), so
-the engine shares prefixes, copies on write, speculates and ships frames
-as it does for a `TransformerConfig`.
+sigmoid scores, beside a shared expert (the layout of the `glm4_moe_lite`,
+`deepseek_v3` and `dots3_note` config families).  `models.hybrid` and
+`models.mamba2_moe` are its siblings under the same protocol.  **A layer
+is of one of two kinds** (`layer_pattern` / `lead_pattern`, the names
+`TransformerConfig` uses): a *full* layer keeps every position's row in
+pool blocks and attends to all of them or, with `index_top_k`, to the
+positions a learned indexer scores highest; a *window* layer keeps the
+`window` positions up to its own in a ring of latent rows by the engine's
+slot, at sizes of its own.  With full layers alone (what every field
+defaults to: GLM-4.7-Flash) its sequences are pool blocks alone
+(`state_by_slot` false), so the engine shares prefixes, copies on write,
+speculates and ships frames as it does for a `TransformerConfig`; **with
+a window layer the configuration says `state_by_slot`, and the engine
+refuses what rings refuse anywhere** (`serve.llm._refuse_if_by_slot`:
+`import_prefix`, `export_streams`; speculation and a mesh at
+construction), turns prefix sharing off and launches one chunk of
+`prefill_chunk` rows a tick.
 
 The stack, pre-norm residual, RMSNorm throughout:
 
@@ -65,31 +76,94 @@ renormalised times `route_scale`; `experts_held` as in
 share).  The shared expert is a dense SwiGLU every token takes, added
 once whatever the share.
 
-What a sequence keeps (`LatentState`): `kv` (n_layers, N_blocks,
-block_size, row_width), paged as ever, block 0 the null block.
-Parameters: `attn.*` stacked over all layers, `dense.*` over the leading
-dense layers, `ffn.*` over the expert layers.  The up-projections are
-stored by head, `w_uk` (H, d_nope, kv_rank) and `w_uv` (H, kv_rank,
-d_v), so that neither form slices a matrix inside a step.  Multi-token
-prediction modules are not here: the published forward pass for
-next-token logits is the layers above.  Training is not here.
+**The two kinds, and what a full layer selects.**  A kind has its own
+head count, latent ranks, head widths and rope base (`cfg.kind(name)`; a
+window layer's where the `*_window` fields give them, else a full
+layer's); both may gate the attention's output by head (`attn_gate`:
+sigmoid(u W_g), one value a head) and rescale their normed latents by
+sqrt(d_model / rank) (`latent_rescale`).  With `index_top_k` a full layer
+carries the lightning indexer published with DeepSeek-V3.2:
+
+    q^I = c_q W^I_qb        index_heads x index_dim, the first d_rope
+                            dims of each roped
+    k^I = LayerNorm(u W^I_k)  (index_dim), the first d_rope roped: one
+                            row a position, cached
+    w   = u W^I_w * index_heads^-1/2 * index_dim^-1/2
+    I[t, s] = sum_j w[t, j] relu(q^I[t, j] . k^I[s]),  s <= t
+    S_t = the index_top_k positions of largest I[t, s]
+
+and the absorbed read's soft-max runs over S_t alone, chunk and decode
+step alike: `ops.attention.paged_index_scores` (the lane's index keys
+through its block table, float32 accumulation), `select_positions` (an
+exact top-k over positions, never an approximate one) and
+`paged_latent_attention(selected=)` (the selected rows fetched through
+the table into a dense buffer that is both products' operand).  A window
+layer reads its slot's ring through `slot_ring_reader` with
+`latent_window_attention`, on `ring_rows` / `ring_seen` as every ring.
+Scopes in a profile: `mla_attn` around a layer's attention, inside it
+`dsa_index`, `dsa_select`, `dsa_attend`, `latent_swa`, `attn_gate`;
+`moe`, `shared_mlp`, `dense_mlp`.
+
+What a sequence keeps (`LatentState`), three leaves: `kv` (full layers,
+N_blocks, block_size, row_width), paged as ever, block 0 the null block;
+`idx` (full layers, N_blocks, block_size, index_dim), the indexer's keys,
+**pooled by the same table** (`pooled` names both, so copy-on-write, a
+frame and `resident_bytes()["kv_paged"]` carry both; None without an
+indexer); `ring` (window layers, slots + 1, ring rows, the window kind's
+row_width), the null slot last (None without window layers; rows: window
++ prefill_chunk in whole sublane tiles, 513 + 512 -> 1,040).
+Parameters: `attn.*` stacked over the full layers (the indexer's and the
+gate's beside the rest), `attn_window.*` over the window layers, `dense.*`
+over the leading dense layers, `ffn.*` over the expert layers.  The
+up-projections are stored by head, `w_uk` (H, d_nope, kv_rank) and `w_uv`
+(H, kv_rank, d_v), so that neither form slices a matrix inside a step.
+The layer loop scans the periods of the pattern, its body a period's
+layers; the leading dense layers run before it and the layers behind the
+last whole period after it.  Multi-token prediction modules are not here:
+the published forward pass for next-token logits is the layers above.
+Training is not here.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, ClassVar, Optional, Tuple
+import functools
+from typing import Any, ClassVar, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import paged_latent_attention
+from ray_tpu.ops.attention import (
+    latent_window_attention,
+    paged_index_scores,
+    paged_latent_attention,
+    ring_rows,
+    select_positions,
+    select_rows,
+    slot_ring_reader,
+)
 from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless, routed_zero
-from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
 F32 = jnp.float32
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 _LANE_TILE = 128
+_RING_TILE = 16           # a ring's rows, in whole bfloat16 sublane tiles
+_KINDS = ("full", "window")
+
+
+class _Kind(NamedTuple):
+    """The attention sizes of one kind of layer."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    d_nope: int
+    d_v: int
+    theta: float
+    row_width: int
+    scale: float
+    rescale_q: float      # on the normed query latent (1.0: none)
+    rescale_kv: float     # on the normed key / value latent
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,15 +192,52 @@ class MLAMoEConfig:
     param_dtype: Any = jnp.bfloat16
     compute_dtype: Any = jnp.bfloat16
     name: str = "mla-moe"
+    # The kinds of layer ("full" | "window"), as `TransformerConfig` names
+    # them: `lead_pattern` the n_dense_layers leading layers' (empty: all
+    # full), `layer_pattern` the period the expert layers repeat, its
+    # last one cut short where they are not whole periods.  A window layer
+    # sees the `window` positions up to its own and keeps a ring of
+    # latent rows a slot; its sizes are its own where given (0: a full
+    # layer's).
+    layer_pattern: Tuple[str, ...] = ("full",)
+    lead_pattern: Tuple[str, ...] = ()
+    window: int = 0
+    n_heads_window: int = 0
+    q_rank_window: int = 0
+    kv_rank_window: int = 0
+    d_nope_window: int = 0
+    d_v_window: int = 0
+    rope_theta_window: float = 0.0
+    # sigmoid(u W_g), one value a head, on the attention's output.
+    attn_gate: bool = False
+    # sqrt(d_model / rank) on each normed latent.
+    latent_rescale: bool = False
+    # A full layer attends to the `index_top_k` positions that
+    # `index_heads` heads of `index_dim` score highest (0: to all): the
+    # first d_rope dimensions of an index head are roped, its keys one
+    # row a position in a pooled leaf of their own.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_top_k: int = 0
+    index_norm_eps: float = 1e-6
 
-    # The blocks are the sequence: nothing is kept by the engine's slot.
-    state_by_slot: ClassVar[bool] = False
     recurrent: ClassVar[bool] = False
 
     def __post_init__(self):
         if not 0 <= self.n_dense_layers < self.n_layers:
             raise ValueError("n_dense_layers leading layers, then at "
                              "least one expert layer")
+        if self.lead_pattern and len(self.lead_pattern) != self.n_dense_layers:
+            raise ValueError("lead_pattern names the n_dense_layers leading "
+                             "layers' kinds")
+        if not self.layer_pattern or set(self.kinds) - set(_KINDS):
+            raise ValueError(f"a layer is one of {_KINDS}")
+        if "window" in self.kinds and self.window < 1:
+            raise ValueError("window layers need a window")
+        if self.index_top_k and not (self.index_heads
+                                     and self.index_dim >= self.d_rope):
+            raise ValueError("a selection needs index_heads heads of "
+                             "index_dim >= d_rope")
         self.moe                        # MoEConfig checks the held range
 
     @property
@@ -134,13 +245,47 @@ class MLAMoEConfig:
         return self.n_layers - self.n_dense_layers
 
     @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind, in order."""
+        lead = self.lead_pattern or ("full",) * self.n_dense_layers
+        p = self.layer_pattern
+        return tuple(lead) + tuple(p[i % len(p)]
+                                   for i in range(self.n_expert_layers))
+
+    def n_of(self, kind: str) -> int:
+        return self.kinds.count(kind)
+
+    @property
+    def state_by_slot(self) -> bool:
+        """Pool blocks alone are the sequence unless a window layer keeps
+        a ring by the engine's slot."""
+        return "window" in self.kinds
+
+    def kind(self, kind: str) -> _Kind:
+        own = kind == "window"
+        heads = own and self.n_heads_window or self.n_heads
+        q_rank = own and self.q_rank_window or self.q_rank
+        kv_rank = own and self.kv_rank_window or self.kv_rank
+        d_nope = own and self.d_nope_window or self.d_nope
+        rescale = self.latent_rescale
+        return _Kind(
+            heads, q_rank, kv_rank, d_nope,
+            own and self.d_v_window or self.d_v,
+            own and self.rope_theta_window or self.rope_theta,
+            -(-(kv_rank + self.d_rope) // _LANE_TILE) * _LANE_TILE,
+            (d_nope + self.d_rope) ** -0.5,
+            (self.d_model / q_rank) ** 0.5 if rescale else 1.0,
+            (self.d_model / kv_rank) ** 0.5 if rescale else 1.0)
+
+    @property
     def row_width(self) -> int:
-        """A stored row: (latent | roped key) padded to whole lane tiles."""
-        return -(-(self.kv_rank + self.d_rope) // _LANE_TILE) * _LANE_TILE
+        """A full layer's stored row: (latent | roped key) padded to whole
+        lane tiles."""
+        return self.kind("full").row_width
 
     @property
     def attention_scale(self) -> float:
-        return (self.d_nope + self.d_rope) ** -0.5
+        return self.kind("full").scale
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -154,26 +299,82 @@ class MLAMoEConfig:
 
     @property
     def num_params(self) -> int:
-        d, h = self.d_model, self.n_heads
-        attn = d * self.q_rank + self.q_rank * h * (self.d_nope + self.d_rope) \
-            + d * (self.kv_rank + self.d_rope) \
-            + self.kv_rank * h * (self.d_nope + self.d_v) + h * self.d_v * d
+        d = self.d_model
+
+        def attn(kind):
+            k = self.kind(kind)
+            n = d * k.q_rank + k.q_rank * k.heads * (k.d_nope + self.d_rope) \
+                + d * (k.kv_rank + self.d_rope) \
+                + k.kv_rank * k.heads * (k.d_nope + k.d_v) \
+                + k.heads * k.d_v * d + self.attn_gate * d * k.heads
+            if kind == "full" and self.index_top_k:
+                n += k.q_rank * self.index_heads * self.index_dim \
+                    + d * (self.index_dim + self.index_heads)
+            return n
+
         ffn = d * self.n_experts + 3 * d * self.d_shared \
             + self.held[1] * 3 * d * self.d_expert
-        return (2 * self.vocab_size * d + self.n_layers * attn
+        return (2 * self.vocab_size * d
+                + sum(self.n_of(kind) * attn(kind) for kind in _KINDS)
                 + self.n_dense_layers * 3 * d * self.d_ff
                 + self.n_expert_layers * ffn)
 
     # -- the sequence state ---------------------------------------------
+    def ring_rows(self, prefill_chunk: int) -> int:
+        """Rows of a window layer's ring: a window and a chunk (a chunk
+        is written before it is read, and its first query still sees a
+        whole window), in whole sublane tiles."""
+        return -(-(self.window + prefill_chunk) // _RING_TILE) * _RING_TILE
+
     def init_state(self, num_blocks: int, block_size: int, num_slots: int,
                    prefill_chunk: int) -> "LatentState":
-        return LatentState(kv=jnp.zeros(
-            (self.n_layers, num_blocks, block_size, self.row_width),
-            self.compute_dtype))
+        cd = self.compute_dtype
+        n_full = self.n_of("full")
+        idx = ring = None
+        if self.index_top_k:
+            idx = jnp.zeros((n_full, num_blocks, block_size, self.index_dim),
+                            cd)
+        if self.state_by_slot:
+            if not (num_slots and prefill_chunk):
+                raise ValueError(f"{self.name!r} keeps a ring a slot for its "
+                                 f"window layers: num_slots and "
+                                 f"prefill_chunk size them")
+            ring = jnp.zeros((self.n_of("window"), num_slots + 1,
+                              self.ring_rows(prefill_chunk),
+                              self.kind("window").row_width), cd)
+        return LatentState(
+            kv=jnp.zeros((n_full, num_blocks, block_size, self.row_width),
+                         cd), idx=idx, ring=ring)
+
+    def _seen(self, kind: str, n: int) -> int:
+        """Positions a row that sees `n` of them attends in a layer of
+        `kind`."""
+        if kind == "window":
+            return min(n, self.window)
+        return min(n, self.index_top_k) if self.index_top_k else n
 
     def kv_read_tokens(self, lengths) -> int:
-        """Latent rows one decode step sees, over lanes of `lengths`."""
-        return int(self.n_layers * sum(lengths))
+        """Latent rows one decode step attends, over lanes of `lengths`:
+        in a full layer the selected positions (all of them without a
+        selection), in a window layer the window.  The indexer's scan of
+        its own keys is counted apart (`selection_counts`)."""
+        return int(sum(self.n_of(kind) * self._seen(kind, n)
+                       for kind in _KINDS for n in lengths))
+
+    def selection_counts(self, start: int, rows: int) -> Tuple[int, int]:
+        """(positions the indexer scores, positions the full layers then
+        attend) for `rows` rows of one lane at positions `start` ..: row
+        p scores p + 1 and attends min(p + 1, index_top_k), in every full
+        layer.  (0, 0) without a selection."""
+        if not self.index_top_k:
+            return 0, 0
+        k, end = self.index_top_k, start + rows
+        scored = (end * (end + 1) - start * (start + 1)) // 2
+        low = min(max(k - start, 0), rows)        # rows that see under k
+        under = ((start + low) * (start + low + 1)
+                 - start * (start + 1)) // 2
+        n_full = self.n_of("full")
+        return n_full * scored, n_full * (under + (rows - low) * k)
 
     def init_params(self, rng: jax.Array):
         return init_params(rng, self)
@@ -188,22 +389,34 @@ class MLAMoEConfig:
                     block_tables, positions, kv_len, slots=None,
                     routing: bool = False):
         return _served_step(params, state, tokens, block_tables, positions,
-                            kv_len, self, routing)
+                            kv_len, self, slots, routing)
 
 
 @dataclasses.dataclass
 class LatentState:
-    kv: jax.Array         # (n_layers, N_blocks, block_size, row_width)
+    kv: jax.Array         # (full layers, N_blocks, block_size, row_width)
+    # The indexer's keys, pooled by the same table; None without one.
+    idx: Optional[jax.Array] = None   # (full layers, N_blocks, bs, index_dim)
+    # The window layers' rings of latent rows, by slot (the null slot
+    # last); None for a model whose every layer is full.
+    ring: Optional[jax.Array] = None  # (window layers, S + 1, R, row_width)
 
-    # The leaves a block table indexes (`models.decoding.pooled_leaves`).
-    pooled: ClassVar[Tuple[str, ...]] = ("kv",)
+    @property
+    def pooled(self) -> Tuple[str, ...]:
+        """The leaves a block table indexes
+        (`models.decoding.pooled_leaves`)."""
+        return ("kv",) if self.idx is None else ("kv", "idx")
 
     def resident_bytes(self) -> dict:
-        return {"kv_paged": int(self.kv.size * self.kv.dtype.itemsize),
-                "kv_window": 0, "recurrent": 0}
+        def nbytes(*arrays):
+            return int(sum(a.size * a.dtype.itemsize for a in arrays
+                           if a is not None))
+
+        return {"kv_paged": nbytes(self.kv, self.idx),
+                "kv_window": nbytes(self.ring), "recurrent": 0}
 
 
-jax.tree_util.register_dataclass(LatentState, ["kv"], [])
+jax.tree_util.register_dataclass(LatentState, ["kv", "idx", "ring"], [])
 
 
 # The seeded router bias's standard deviation, held by two properties a
@@ -229,15 +442,15 @@ ROUTER_BIAS_STD = 0.05
 # parameters
 # ---------------------------------------------------------------------------
 def init_params(rng: jax.Array, cfg: MLAMoEConfig):
-    """Seeded parameters, the layers of a kind stacked on a leading axis.
-    Norm gains and the router's bias are drawn away from their neutral
-    values, so that a comparison notices when one is left out (with a
-    zero bias, selecting on s + b and gating with s could not be told
-    from selecting and gating on either).  The bias is N(0,
+    """Seeded parameters, the layers of a kind stacked on a leading axis
+    (`attn` the full layers', `attn_window` the window layers' where
+    there are any).  Norm gains and the router's bias are drawn away from
+    their neutral values, so that a comparison notices when one is left
+    out (with a zero bias, selecting on s + b and gating with s could not
+    be told from selecting and gating on either).  The bias is N(0,
     `ROUTER_BIAS_STD`); `router_bias` is float32 whatever `param_dtype`
     is."""
-    d, h, r, qr = cfg.d_model, cfg.n_heads, cfg.kv_rank, cfg.q_rank
-    dn, dr, dv = cfg.d_nope, cfg.d_rope, cfg.d_v
+    d, dr = cfg.d_model, cfg.d_rope
     fe, fs, held = cfg.d_expert, cfg.d_shared, cfg.held[1]
     dt = cfg.param_dtype
     count = iter(range(1 << 20))
@@ -247,16 +460,36 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig):
         return (shift + scale * jax.random.normal(key, shape, F32)) \
             .astype(dtype)
 
-    def attn(n):
-        return {"norm": draw((n, d), 0.1, shift=1.0),
-                "wq_a": draw((n, d, qr), d ** -0.5),
-                "q_norm": draw((n, qr), 0.1, shift=1.0),
-                "wq_b": draw((n, qr, h * (dn + dr)), qr ** -0.5),
-                "wkv_a": draw((n, d, r + dr), d ** -0.5),
-                "kv_norm": draw((n, r), 0.1, shift=1.0),
-                "w_uk": draw((n, h, dn, r), r ** -0.5),
-                "w_uv": draw((n, h, r, dv), r ** -0.5),
-                "wo": draw((n, h * dv, d), (h * dv) ** -0.5)}
+    def attn(n, kind):
+        k = cfg.kind(kind)
+        h, r, qr, dn, dv = k.heads, k.kv_rank, k.q_rank, k.d_nope, k.d_v
+        # What reads a rescaled latent is drawn that much smaller, so
+        # that queries, keys and values have the size they have without
+        # the rescale (a constant on a normed latent is a factor on these
+        # matrices): at N(0, 1 / fan_in) the scores of the rescaled model
+        # had a deviation of 7 where the other models' have 1, a soft-max
+        # so peaked that bfloat16's rounding of a score moved a position's
+        # logits by 0.15 of their size (my chip run, PR 49, call C).
+        in_q, in_kv = qr ** -0.5 / k.rescale_q, r ** -0.5 / k.rescale_kv
+        p = {"norm": draw((n, d), 0.1, shift=1.0),
+             "wq_a": draw((n, d, qr), d ** -0.5),
+             "q_norm": draw((n, qr), 0.1, shift=1.0),
+             "wq_b": draw((n, qr, h * (dn + dr)), in_q),
+             "wkv_a": draw((n, d, r + dr), d ** -0.5),
+             "kv_norm": draw((n, r), 0.1, shift=1.0),
+             "w_uk": draw((n, h, dn, r), in_kv),
+             "w_uv": draw((n, h, r, dv), in_kv),
+             "wo": draw((n, h * dv, d), (h * dv) ** -0.5)}
+        if cfg.attn_gate:
+            p["head_gate"] = draw((n, d, h), d ** -0.5)
+        if kind == "full" and cfg.index_top_k:
+            hi, di = cfg.index_heads, cfg.index_dim
+            p.update(wq_idx=draw((n, qr, hi * di), in_q),
+                     wk_idx=draw((n, d, di), d ** -0.5),
+                     k_idx_norm=draw((n, di), 0.1, shift=1.0),
+                     k_idx_bias=draw((n, di), 0.1),
+                     w_idx=draw((n, d, hi), d ** -0.5))
+        return p
 
     def dense(n):
         return {"norm": draw((n, d), 0.1, shift=1.0),
@@ -275,63 +508,138 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig):
                 "w_up": draw((n, held, d, fe), d ** -0.5),
                 "w_down": draw((n, held, fe, d), fe ** -0.5)}
 
-    return {"embed": draw((cfg.vocab_size, d), d ** -0.5),
-            "attn": attn(cfg.n_layers),
-            "dense": dense(cfg.n_dense_layers),
-            "ffn": ffn(cfg.n_expert_layers),
-            "final_norm": draw((d,), 0.1, shift=1.0),
-            "lm_head": draw((d, cfg.vocab_size), d ** -0.5)}
+    params = {"embed": draw((cfg.vocab_size, d), d ** -0.5),
+              "attn": attn(cfg.n_of("full"), "full"),
+              "dense": dense(cfg.n_dense_layers),
+              "ffn": ffn(cfg.n_expert_layers),
+              "final_norm": draw((d,), 0.1, shift=1.0),
+              "lm_head": draw((d, cfg.vocab_size), d ** -0.5)}
+    if cfg.state_by_slot:
+        params["attn_window"] = attn(cfg.n_of("window"), "window")
+    return params
 
 
 # ---------------------------------------------------------------------------
 # layers
 # ---------------------------------------------------------------------------
-def _to_row_width(x, cfg):
-    """Zeros behind x's last axis up to the stored row's width."""
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
-                   + [(0, cfg.row_width - x.shape[-1])])
+def _to_width(x, width):
+    """Zeros behind x's last axis up to a stored row's width."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
-def _latent_row(ap, u, positions, cfg):
+def _latent_row(ap, u, positions, cfg, k: _Kind):
     """What a position stores, (S, K, row_width): its normalised latent,
     its roped key, zeros up to the tile."""
-    r = cfg.kv_rank
+    r = k.kv_rank
     ckr = jnp.einsum("skd,de->ske", u, ap["wkv_a"].astype(cfg.compute_dtype))
     c = rms_norm(ckr[..., :r], ap["kv_norm"], eps=cfg.norm_eps)
+    if cfg.latent_rescale:
+        c = c * k.rescale_kv
     k_r = apply_rope(ckr[..., None, r:], positions,
-                     theta=cfg.rope_theta)[..., 0, :]
-    return _to_row_width(jnp.concatenate([c, k_r], axis=-1), cfg)
+                     theta=k.theta)[..., 0, :]
+    return _to_width(jnp.concatenate([c, k_r], axis=-1), k.row_width)
 
 
-def _queries(ap, u, positions, cfg):
-    """Every head's (q_n (S, K, H, d_nope), roped q_r (S, K, H, d_rope))."""
+def _queries(ap, u, positions, cfg, k: _Kind):
+    """Every head's (q_n (S, K, H, d_nope), roped q_r (S, K, H, d_rope)),
+    and the normed query latent they are made from."""
     cd = cfg.compute_dtype
     cq = rms_norm(jnp.einsum("skd,dr->skr", u, ap["wq_a"].astype(cd)),
                   ap["q_norm"], eps=cfg.norm_eps)
+    if cfg.latent_rescale:
+        cq = cq * k.rescale_q
     q = jnp.einsum("skr,re->ske", cq, ap["wq_b"].astype(cd)).reshape(
-        *u.shape[:2], cfg.n_heads, cfg.d_nope + cfg.d_rope)
-    return q[..., :cfg.d_nope], apply_rope(q[..., cfg.d_nope:], positions,
-                                           theta=cfg.rope_theta)
+        *u.shape[:2], k.heads, k.d_nope + cfg.d_rope)
+    return q[..., :k.d_nope], apply_rope(q[..., k.d_nope:], positions,
+                                         theta=k.theta), cq
 
 
-def _mla(ap, x, pool, li, wb, off, block_tables, positions, kv_len, cfg):
-    """Layer `li`'s attention over x (S, K, d) in absorbed form: the
-    positions' rows written to the pool at [li, wb, off], then read with
-    the rest of the lanes' blocks.  Returns (out (S, K, d), pool)."""
+def _index(ap, u, cq, idx, at, lanes, cfg, routing):
+    """The indexer of full layer `at`: its key of every new position
+    written to the pooled leaf `idx`, then every query row's score of
+    every position of its lane, and the `index_top_k` best.  Returns
+    ((rows (S, K, k) of the pool laid flat, seen (S, K, k)): what the read
+    fetches, the positions (S, K, k) if `routing` asks else None, idx)."""
+    cd, hi, di = cfg.compute_dtype, cfg.index_heads, cfg.index_dim
+    theta, positions = cfg.rope_theta, lanes.positions
+    bs, tables = idx.shape[2], lanes.block_tables
+    with jax.named_scope("dsa_index"):
+        key = layer_norm(
+            jnp.einsum("skd,de->ske", u, ap["wk_idx"].astype(cd)),
+            ap["k_idx_norm"], ap["k_idx_bias"], eps=cfg.index_norm_eps)
+        key = apply_rope(key[..., None, :], positions, theta=theta,
+                         rotary_dim=cfg.d_rope)[..., 0, :]
+        idx = idx.at[at, lanes.wb, lanes.off].set(key.astype(idx.dtype))
+        q = jnp.einsum("skr,re->ske", cq, ap["wq_idx"].astype(cd)).reshape(
+            *u.shape[:2], hi, di)
+        q = apply_rope(q, positions, theta=theta, rotary_dim=cfg.d_rope)
+        w = jnp.einsum("skd,dh->skh", u, ap["w_idx"].astype(cd)) \
+            .astype(F32) * (hi ** -0.5 * di ** -0.5)
+        scores = paged_index_scores(
+            q.astype(idx.dtype), w, idx, at, tables, positions, lanes.kv_len)
+    with jax.named_scope("dsa_select"):
+        live, k = jnp.max(lanes.kv_len), cfg.index_top_k
+        # each position's row of a layer's pool laid flat, (S, T)
+        rows = jnp.repeat(tables, bs, axis=1) * bs \
+            + jnp.arange(tables.shape[1] * bs) % bs
+        return (select_rows(scores, k, live, rows),
+                select_positions(scores, k, live) if routing else None, idx)
+
+
+class _Lanes(NamedTuple):
+    """What a call's lanes are, for every layer alike."""
+    block_tables: jax.Array
+    positions: jax.Array
+    kv_len: jax.Array
+    wb: jax.Array             # (S, K): the pool block each row is written to
+    off: jax.Array            # and its row there
+    slot: Any = None          # (S, 1): the lanes' engine slots
+    ring_row: Any = None      # (S, K): the ring row each row is written to
+    read_ring: Any = None     # `slot_ring_reader`'s
+
+
+def _attention(ap, x, state, kind, at, lanes: _Lanes, cfg, routing=False):
+    """The attention of the `at`-th layer of `kind` over x (S, K, d) in
+    absorbed form: the positions' rows written to layer `at` of the pool
+    (full) or of the rings (window), then read with the rest of what the
+    lanes keep there.  Returns (out (S, K, d), state, the positions a
+    full layer selected if `routing` asks and it selects, else None)."""
     cd = cfg.compute_dtype
+    k = cfg.kind(kind)
+    kv, idx, ring = state
+    selected = None
     u = rms_norm(x, ap["norm"], eps=cfg.norm_eps)
-    pool = pool.at[li, wb, off].set(
-        _latent_row(ap, u, positions, cfg).astype(pool.dtype))
-    q_n, q_r = _queries(ap, u, positions, cfg)
+    row = _latent_row(ap, u, lanes.positions, cfg, k)
+    if kind == "window":
+        ring = ring.at[at, lanes.slot, lanes.ring_row].set(
+            row.astype(ring.dtype), mode="drop")
+    else:
+        kv = kv.at[at, lanes.wb, lanes.off].set(row.astype(kv.dtype))
+    q_n, q_r, cq = _queries(ap, u, lanes.positions, cfg, k)
     q_lat = jnp.einsum("skhn,hnc->skhc", q_n, ap["w_uk"].astype(cd))
-    q = _to_row_width(jnp.concatenate([q_lat, q_r], axis=-1), cfg)
-    o_lat = paged_latent_attention(
-        q, pool, li, block_tables, positions, kv_len, d_v=cfg.kv_rank,
-        scale=cfg.attention_scale)
+    q = _to_width(jnp.concatenate([q_lat, q_r], axis=-1), k.row_width)
+    if kind == "window":
+        with jax.named_scope("latent_swa"):
+            o_lat = lanes.read_ring(q, ring, ring, at)
+    elif cfg.index_top_k:
+        fetch, selected, idx = _index(ap, u, cq, idx, at, lanes, cfg, routing)
+        with jax.named_scope("dsa_attend"):
+            o_lat = paged_latent_attention(
+                q, kv, at, lanes.block_tables, lanes.positions,
+                lanes.kv_len, d_v=k.kv_rank, scale=k.scale, selected=fetch)
+    else:
+        o_lat = paged_latent_attention(
+            q, kv, at, lanes.block_tables, lanes.positions, lanes.kv_len,
+            d_v=k.kv_rank, scale=k.scale)
     o = jnp.einsum("skhc,hcv->skhv", o_lat.astype(cd),
                    ap["w_uv"].astype(cd))
-    return jnp.einsum("skf,fd->skd", o.reshape(*x.shape[:2], -1),
-                      ap["wo"].astype(cd)), pool
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            o = o * jax.nn.sigmoid(jnp.einsum(
+                "skd,dh->skh", u, ap["head_gate"].astype(cd)))[..., None]
+    out = jnp.einsum("skf,fd->skd", o.reshape(*x.shape[:2], -1),
+                     ap["wo"].astype(cd))
+    return out, (kv, idx, ring), selected
 
 
 def _swiglu(gate_up, down, cd):
@@ -371,51 +679,119 @@ def _take(tree, i):
         lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), tree)
 
 
+def _nth(i, per: int, rank: int):
+    """Index of the `rank`-th of `per` layers a period in period `i`."""
+    return i if per == 1 else i * per + rank
+
+
 def _served_step(params, state: LatentState, tokens, block_tables,
-                 positions, kv_len, cfg: MLAMoEConfig,
+                 positions, kv_len, cfg: MLAMoEConfig, slots=None,
                  routing: bool = False):
     """`tokens` (S, K) at absolute `positions` (S, K) through every
     layer; `kv_len` (S,) is each lane's length once its valid tokens are
-    in (0: an idle lane, which writes the null block and is routed to no
-    expert).  Returns (state, hidden (S, K, d), experts visited summed
-    over the expert layers, the experts every row took (expert layers,
-    S, K, top_k) with `routing` else None, the top-k choices of live
-    rows that fell on held experts, summed likewise).  Write-then-read,
-    as the paged step: the pool is the layer loop's carry.  The leading
-    dense layers run before the scan over the expert layers, which
-    indexes the weight stacks (`ops.moe` says why)."""
+    in (0: an idle lane, which writes the null block and the null slot's
+    ring and is routed to no expert); `slots` (S,) the lanes' engine
+    slots, where window layers keep rings.  Returns (state, hidden (S, K,
+    d), experts visited summed over the expert layers, with `routing`
+    what every row took else None, the top-k choices of live rows that
+    fell on held experts, summed likewise).  What a row took: the experts
+    of every expert layer (expert layers, S, K, top_k); from a model that
+    selects positions a dict of that under "experts" and, under
+    "selected", the positions every full layer attended (full layers, S,
+    K, index_top_k).  Write-then-read, as the paged step: pool, index
+    keys and rings are the layer loop's carry.  The leading dense layers
+    run before the scan, which runs over the periods of the layer
+    pattern, its body a period's layers, and indexes the weight stacks
+    (`ops.moe` says why); the layers behind the last whole period run
+    after it."""
     cd = cfg.compute_dtype
+    if cfg.state_by_slot and slots is None:
+        raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
+                         f"needs the lanes' slots")
     bs = state.kv.shape[2]
     valid = positions < kv_len[:, None]                    # (S, K)
     live = (kv_len > 0)[:, None]
     wb = jnp.where(live, jnp.take_along_axis(
         block_tables, positions // bs, axis=1), 0)
     off = jnp.where(live, positions % bs, 0)
+    lanes = _Lanes(block_tables, positions, kv_len, wb, off)
+    if cfg.state_by_slot:
+        k = cfg.kind("window")
+        lanes = lanes._replace(
+            slot=slots[:, None],
+            ring_row=ring_rows(positions, kv_len, state.ring.shape[2]),
+            read_ring=slot_ring_reader(
+                functools.partial(latent_window_attention, d_v=k.kv_rank,
+                                  scale=k.scale),
+                slots, positions, kv_len, cfg.window, state.ring.shape[1]))
     x = params["embed"].astype(cd)[tokens]
     ffn = {k: v for k, v in params["ffn"].items()
            if k not in _EXPERT_WEIGHTS}
     experts = {k: params["ffn"][k] for k in _EXPERT_WEIGHTS}
-    nd = cfg.n_dense_layers
+    nd, kinds = cfg.n_dense_layers, cfg.kinds
+    period = cfg.layer_pattern
+    per = {kind: period.count(kind) for kind in set(period)}
+    lead = {kind: kinds[:nd].count(kind) for kind in _KINDS}
+    stacks = {"full": params["attn"], "window": params.get("attn_window")}
 
-    def attend(x, pool, li):
+    def attend(x, seq, kind, at):
         with jax.named_scope("mla_attn"):
-            out, pool = _mla(_take(params["attn"], li), x, pool, li, wb,
-                             off, block_tables, positions, kv_len, cfg)
-        return x + out, pool
+            out, seq, selected = _attention(
+                _take(stacks[kind], at), x, seq, kind, at, lanes, cfg,
+                routing)
+        return x + out, seq, selected
 
-    pool = state.kv
-    for j in range(nd):
-        x, pool = attend(x, pool, j)
-        x = x + _dense_ffn(_take(params["dense"], j), x, cfg)
-
-    def layer(carry, i):
-        x, pool, visited, routed = carry
-        x, pool = attend(x, pool, nd + i)
-        out, n, r, taken = _expert_ffn(_take(ffn, i), experts, i, x, valid,
+    def expert_layer(carry, i, j):
+        """Layer `j` of period `i` behind the leading layers."""
+        x, seq, visited, routed = carry
+        kind = period[j]
+        x, seq, selected = attend(
+            x, seq, kind,
+            lead[kind] + _nth(i, per[kind], period[:j].count(kind)))
+        li = _nth(i, len(period), j)
+        out, n, r, taken = _expert_ffn(_take(ffn, li), experts, li, x, valid,
                                        cfg, routing)
-        return (x + out, pool, visited + n, routed + r), taken
+        return (x + out, seq, visited + n, routed + r), taken, selected
 
+    def run(carry, i, n):
+        """The first `n` layers of period `i`."""
+        taken, selected = [], []
+        for j in range(n):
+            carry, t, sel = expert_layer(carry, i, j)
+            taken.append(t)
+            selected.append(sel)
+        return carry, (taken, [sel for sel in selected if sel is not None])
+
+    seq = (state.kv, state.idx, state.ring)
+    chosen = []                     # the full layers' selections, in order
+    for j in range(nd):
+        x, seq, selected = attend(x, seq, kinds[j],
+                                  kinds[:j].count(kinds[j]))
+        chosen += [] if selected is None else [selected[None]]
+        x = x + _dense_ffn(_take(params["dense"], j), x, cfg)
+    n_periods, n_tail = divmod(cfg.n_expert_layers, len(period))
     zero, none = jnp.int32(0), routed_zero(tokens.size, cfg.moe)
-    (x, pool, visited, routed), taken = jax.lax.scan(
-        layer, (x, pool, zero, none), jnp.arange(cfg.n_expert_layers))
-    return LatentState(kv=pool), x, visited, taken, routed
+    carry, (taken, selected) = jax.lax.scan(
+        lambda carry, i: run(carry, i, len(period)), (x, seq, zero, none),
+        jnp.arange(n_periods))
+
+    def in_order(by_rank):
+        """(periods, ..) a layer of the period -> (layers, ..)."""
+        if len(by_rank) == 1:
+            return by_rank[0]
+        both = jnp.stack(by_rank, axis=1)
+        return both.reshape(-1, *both.shape[2:])
+
+    if routing:
+        taken = in_order(taken)
+        chosen += [in_order(selected)] if selected else []
+    if n_tail:
+        carry, (more, selected) = run(carry, n_periods, n_tail)
+        if routing:
+            taken = jnp.concatenate([taken, jnp.stack(more)])
+            chosen += [sel[None] for sel in selected]
+    x, (kv, idx, ring), visited, routed = carry
+    if routing and cfg.index_top_k:
+        taken = {"experts": taken, "selected": jnp.concatenate(chosen)}
+    return (LatentState(kv=kv, idx=idx, ring=ring), x, visited,
+            taken if routing else None, routed)

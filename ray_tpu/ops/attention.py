@@ -713,7 +713,7 @@ _LATENT_GROUP_BLOCKS = 32
 
 
 def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
-                           *, d_v: int, scale: float):
+                           *, d_v: int, scale: float, selected=None):
     """`paged_attention`'s sibling for a latent (MLA, arXiv:2405.04434)
     pool in absorbed form: multi-query attention of `q` (S, K, H, W) over
     one layer of a pool of flat rows (L, N, block_size, W), each row one
@@ -735,7 +735,18 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
     not W's).  W is whole lane tiles: the caller pads a row of 576 to 640,
     or the compiler lays the pool out its own way and copies it whole
     around every step (`models.mla_moe` has the numbers), and the kernel
-    is not to be had."""
+    is not to be had.
+
+    `selected` = (rows (S, K, k) int32, seen (S, K, k) bool), a model that
+    attends to a learned selection of its positions (`select_rows`): each
+    query row's soft-max runs over exactly the rows of the pool it
+    selected and sees (an early row's set is filled up with rows it does
+    not), fetched into a dense buffer (`_selected_latent_attention`),
+    chunk and decode step alike."""
+    if selected is not None:
+        return _selected_latent_attention(q, pool, layer, *selected,
+                                          d_v=d_v, scale=scale)
+
     def loop(q, pool, layer, block_tables, positions, kv_len):
         return _paged_running_softmax(
             pool, pool, layer, block_tables, positions, kv_len,
@@ -764,6 +775,178 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
             stacklevel=2)
         return loop(*args)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
+
+
+# Table entries a trip of `paged_index_scores` reads: its score tile is
+# (rows, index heads, positions) float32 before the heads are summed, 134
+# MB for a 512-row chunk of 64 heads at 64 blocks of 16.
+_INDEX_GROUP_BLOCKS = 64
+# Query rows whose selected rows `_selected_latent_attention` fetches at
+# once: at 2,048 selected rows of 640 values a query row, 128 query rows are
+# a buffer of 0.34 GB and a score tile (128 heads) of 0.13 GB; a chunk's
+# 512 at once would be 1.3 GB a layer.
+_SELECT_QUERY_ROWS = 128
+
+
+def paged_index_scores(q, w, pool, layer, block_tables, positions, kv_len):
+    """A learned index's score of every cached position for every query
+    row (the lightning indexer of DeepSeek-V3.2's report): I[t, s] =
+    sum_j w[t, j] relu(q[t, j] . k[s]) over the index heads j, against
+    one layer of a pool of index keys (L, N, block_size, D), one row a
+    position, read through the lanes' tables.  `q` (S, K, Hi, D) in the
+    pool's dtype, `w` (S, K, Hi) float32 (the heads' weights, the
+    model's constant factors folded in); products accumulate in float32
+    and so do relu, weights and the sum.  Returns (S, K, B x block_size)
+    float32: `_NEG_INF` at the positions a row does not see (s > its
+    own) and at those past the last trip.  Groups of
+    `_INDEX_GROUP_BLOCKS` table entries a trip, the trip count following
+    the longest live lane of the call, as `_paged_running_softmax`."""
+    s, k_w = positions.shape
+    bs = pool.shape[2]
+    g = min(_INDEX_GROUP_BLOCKS, block_tables.shape[1])
+    t = g * bs
+    tables = jnp.pad(block_tables, ((0, 0), (0, -block_tables.shape[1] % g)))
+    width = tables.shape[1] * bs
+
+    def group(i, out):
+        ids = jax.lax.dynamic_slice_in_dim(tables, i * g, g, axis=1)
+        kb = pool[layer, ids].reshape(s, t, pool.shape[3])
+        sc = jnp.einsum("sqhd,std->sqht", q, kb,
+                        preferred_element_type=jnp.float32)
+        part = jnp.sum(jax.nn.relu(sc) * w[..., None], axis=2)    # (S,K,t)
+        return jax.lax.dynamic_update_slice_in_dim(out, part, i * t, axis=2)
+
+    out = jax.lax.fori_loop(
+        0, (jnp.max(kv_len) + t - 1) // t, group,
+        jnp.full((s, k_w, width), _NEG_INF, jnp.float32))
+    seen = jnp.arange(width) <= positions[:, :, None]
+    return jnp.where(seen, out, _NEG_INF)[..., :block_tables.shape[1] * bs]
+
+
+# What an exact top-k costs on a v5e.  `jax.lax.top_k` is there a stable
+# sort of (score, position) pairs over all the candidates; measured (`TPU v5
+# lite`, 2026-10-01, PR 49, calls A to E; 512 rows, k = 2,048, float32
+# scores), ms by candidates a row: 4,096 0.98 | 8,192 2.12 | 12,288 4.26 |
+# 16,384 4.79 | 20,480 12.28 | 24,576 14.18 | 32,768 17.63 (`argsort`:
+# 18.85): past 16,384 it leaves a fast path.  So (i) a row's candidates are
+# cut to the tier, k x 2^j, that holds the call's longest lane; (ii) a tier
+# past `_SELECT_SPAN` is sorted span by span and the spans' bests by one
+# more sort of 2 k pairs a span; (iii) what rides with a score is what the
+# fetch needs, the position's row of the pool, so that nothing looks table
+# entries up afterwards.  Each of these was first built otherwise and
+# measured: the rows looked up after a top-k of positions, a gather of
+# 2,048 scalars a query row, 7.5 ms a 512-row launch and layer beside a
+# sort of 17.6; the rows as a second value of a *stable* sort, to which the
+# compiler adds the positions as a third operand, 25.0 ms at 32,768 (call
+# E); the spans' bests merged by `take_along_axis`, that gather again, 25.8
+# (call C); the spans as one batched top-k over a reshaped (rows, spans,
+# span), a change of layout, 24-54 (call B).  Hence one *unstable* sort of
+# (score, payload) pairs: which of two equal scores comes first is the
+# sorting network's to say, the same for every payload (it compares scores
+# alone), so the rows fetched and the positions handed to a check are one
+# selection.  The k-th score alone, by 33 rounds of bisection on the
+# scores' bit patterns, reads 0.71 ms at 32,768 (call B), and nothing cheap
+# was found to turn `score >= it` into the list the fetch needs: PERF.md
+# section 8, PR 49.
+_SELECT_SPAN = 16384
+
+
+def _best_pairs(neg, payload, k: int):
+    """The k smallest of `neg` (.., T) with their `payload`, in order."""
+    neg, payload = jax.lax.sort((neg, payload), dimension=-1, num_keys=1,
+                                is_stable=False)
+    return neg[..., :k], payload[..., :k]
+
+
+def _select(scores, payload, k: int, live):
+    """(-score, payload) (S, K, k) of each row's k largest `scores` (S, K,
+    T), largest first; `payload` (S, T) int32 rides with its position's
+    score.  An exact top-k (no approximate one: it would be another
+    model); among equal scores the sort's own order.  `live` (a traced
+    scalar, or None): positions at or past it hold `_NEG_INF` in every
+    row (the call's longest lane), so only the tier of candidates that
+    holds it, k x 2^j, is sorted, in spans of `_SELECT_SPAN`."""
+    width = scores.shape[-1]
+    k = min(k, width)
+
+    def search(sc, payload):
+        upto = sc.shape[-1]
+        payload = jnp.broadcast_to(payload[:, None, :], sc.shape)
+        spans = [_best_pairs(-sc[..., lo:lo + _SELECT_SPAN],
+                             payload[..., lo:lo + _SELECT_SPAN], k)
+                 for lo in range(0, upto, _SELECT_SPAN)]
+        if len(spans) == 1:
+            return spans[0]
+        return _best_pairs(*(jnp.concatenate(x, axis=-1)
+                             for x in zip(*spans)), k)
+
+    tiers = [k]
+    while tiers[-1] < width:
+        tiers.append(min(2 * tiers[-1], width))
+    if live is None or len(tiers) == 1:
+        return search(scores, payload)
+    tier = sum((live > t).astype(jnp.int32) for t in tiers[:-1])
+    return jax.lax.switch(
+        tier, [lambda sc, pl, t=t: search(sc[..., :t], pl[..., :t])
+               for t in tiers], scores, payload)
+
+
+def select_positions(scores, k: int, live=None):
+    """The `k` positions of largest `scores` (S, K, T) a row, (S, K,
+    min(k, T)) int32 (`_select`).  A row that sees fewer than k positions
+    gets all of them and, behind them, positions it does not see (their
+    score is `_NEG_INF`)."""
+    at = jnp.broadcast_to(jnp.arange(scores.shape[-1], dtype=jnp.int32),
+                          (scores.shape[0], scores.shape[-1]))
+    return _select(scores, at, k, live)[1]
+
+
+def select_rows(scores, k: int, live, rows):
+    """`select_positions` for the fetch: the same selection in the same
+    order, but of `rows` (S, T) int32, each position's row in a layer's
+    pool laid flat (block x block_size + offset, through the lane's
+    table), with (S, K, k) bool which of them the row sees (a score above
+    `_NEG_INF`)."""
+    neg, got = _select(scores, rows, k, live)
+    return got, neg < -0.5 * _NEG_INF
+
+
+def _selected_latent_attention(q, pool, layer, rows, seen, *, d_v: int,
+                               scale: float):
+    """`paged_latent_attention` over a selection: `rows` (S, K, k) int32,
+    each query row's selected rows of `[layer]` of the pool laid flat
+    (`select_rows`), are fetched into a dense (query rows, k, W) buffer,
+    which is both products' operand; one soft-max over the k, masked to
+    those the row sees (`seen` (S, K, k)).  A chunk's query rows go
+    `_SELECT_QUERY_ROWS` at a time."""
+    s, k_w, h, w = q.shape
+    n_layers, n_blocks, bs, _ = pool.shape
+    flat = pool.reshape(n_layers, n_blocks * bs, w)
+
+    def attend(q, rows, seen):                         # (S, r, ...)
+        # Every selected row lies in the pool: no clamp.
+        got = flat.at[layer, rows].get(mode="promise_in_bounds")  # (S,r,k,W)
+        sc = jnp.einsum("sqhe,sqje->sqhj", q, got,
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(seen[:, :, None, :], sc, _NEG_INF)
+        # The probabilities in the rows' dtype, as the decode kernels'; the
+        # product over the whole row and the value's columns taken of the
+        # result: a slice of the fetched buffer would be a copy of it.
+        out = jnp.einsum("sqhj,sqje->sqhe",
+                         jax.nn.softmax(sc, axis=-1).astype(got.dtype), got,
+                         preferred_element_type=jnp.float32)
+        return out[..., :d_v]
+
+    r = _SELECT_QUERY_ROWS
+    if k_w <= r or k_w % r:
+        return attend(q, rows, seen)
+
+    def split(x):                          # (S, K, ..) -> (K / r, S, r, ..)
+        return jnp.moveaxis(x.reshape(s, k_w // r, r, *x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(lambda xs: attend(*xs),
+                      (split(q), split(rows), split(seen)))
+    return jnp.moveaxis(out, 0, 1).reshape(s, k_w, h, d_v)
 
 
 # Pool blocks a step of the decode kernel copies in and multiplies.
@@ -1297,3 +1480,21 @@ def window_diff_attention(q6, k_ring, v_ring, positions, kv_len, window):
     score, mix, own_columns = _diff_products(q6)
     sc = jnp.where(seen[:, :, None, :], score(k_ring), _NEG_INF)
     return own_columns(mix(jax.nn.softmax(sc, axis=-1), v_ring))
+
+
+def latent_window_attention(q, ring, _, positions, kv_len, window, *,
+                            d_v: int, scale: float):
+    """`window_attention`'s sibling for rings of latent rows (S, R, W),
+    in absorbed form: multi-query attention of `q` (S, K, H, W) over a
+    ring a lane whose rows are (normalised latent | roped key | padding),
+    the first `d_v` columns also the value, as a row of
+    `paged_latent_attention`'s pool; rows seen as `ring_seen` reads them,
+    the whole ring in one soft-max.  `slot_ring_reader` hands it the ring
+    as keys and again as values; it reads the one.  Returns (S, K, H,
+    d_v) float32."""
+    seen = ring_seen(positions, kv_len, ring.shape[1], window)
+    sc = jnp.einsum("sqhe,ste->sqht", q, ring,
+                    preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(seen[:, :, None, :], sc, _NEG_INF)
+    return jnp.einsum("sqht,ste->sqhe", jax.nn.softmax(sc, axis=-1),
+                      ring[..., :d_v], preferred_element_type=jnp.float32)

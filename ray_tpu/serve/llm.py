@@ -119,7 +119,8 @@ class _Request:
 TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "routed_here",
                "kv_read_tokens", "reset_s", "experts_read", "ahead",
-               "starved_s", "moe_tiles")
+               "starved_s", "moe_tiles", "index_scored_tokens",
+               "kv_selected_tokens")
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -134,13 +135,15 @@ class _TickAccounts:
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
-                 "routed_at", "experts_read", "ahead", "starved_s")
+                 "routed_at", "experts_read", "ahead", "starved_s",
+                 "index_scored_tokens", "kv_selected_tokens")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
             self.experts_read = self.starved_s = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
+        self.index_scored_tokens = self.kv_selected_tokens = 0
         # Where the tick's `routed_here` and `moe_tiles` are summed on the
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
@@ -529,6 +532,11 @@ class PagedLLMEngine:
         # the model's own count, or every layer over every position.
         self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
             lambda lengths: cfg.n_layers * sum(lengths))
+        # A model whose full layers attend to a learned selection of their
+        # positions says what a launch's rows score and then attend
+        # (`selection_counts(start, rows)`, one lane); None from any other.
+        self._selection_counts = (
+            cfg.selection_counts if getattr(cfg, "index_top_k", 0) else None)
         # Layers whose FFN is a set of experts: the burst's count of
         # experts visited is per layer and step over these.
         self._expert_layers = (
@@ -601,6 +609,7 @@ class PagedLLMEngine:
                       "migrated_blocks": 0, "migrate_fallbacks": 0,
                       "disagg_prefills": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
+                      "index_scored_tokens": 0, "kv_selected_tokens": 0,
                       "state_resets": 0, "state_rebuilds": 0}
         self._request_phases: deque = deque(
             maxlen=self.REQUEST_PHASES_KEPT)
@@ -896,6 +905,20 @@ class PagedLLMEngine:
 
         return tuple(read(t) for t in log)
 
+    def _count_selection(self, lanes) -> None:
+        """`lanes`: (first position, rows) of each lane of a launch.  What
+        a model that selects positions scores and then attends there goes
+        to the tick's `index_scored_tokens` / `kv_selected_tokens` and to
+        the cumulative counters; nothing from any other model."""
+        if self._selection_counts is None:
+            return
+        for start, rows in lanes:
+            scored, selected = self._selection_counts(start, rows)
+            self._acct.index_scored_tokens += scored
+            self._acct.kv_selected_tokens += selected
+            self.stats["index_scored_tokens"] += scored
+            self.stats["kv_selected_tokens"] += selected
+
     def _reset_slot_state(self, req: "_Request", slot: int) -> None:
         """Zero `slot`'s recurrent state: `req` was admitted to it, or
         was preempted and will re-prefill.  A launch, counted in the
@@ -1097,6 +1120,7 @@ class PagedLLMEngine:
                     jnp.int32(req.pos), jnp.int32(nv),
                     **self._slot_kw(slot))
                 self._count_routed(routed)
+                self._count_selection([(req.pos, nv)])
                 req.pos += nv
                 budget -= nv
                 progressed = True
@@ -1219,7 +1243,11 @@ class PagedLLMEngine:
                 temps[j] = req.temperature
             if self._spec_k and self._spec_tick(idx, tables, lengths,
                                                 active, temps):
+                self._count_selection(
+                    (int(n), self._spec_k) for n in lengths[:len(idx)])
                 return True
+            self._count_selection(
+                (int(n), burst) for n in lengths[:len(idx)])
             t0 = time.time()
             tok_mat, visited = self._launch_burst(
                 idx, w, host_tok, tables, lengths, active, temps)
@@ -1519,6 +1547,14 @@ class PagedLLMEngine:
         next burst or chunk, or to the tick's end.  The lower bound of
         the device's idle time that the host causes: what the device
         idled before such a read returned only a trace can show.
+        `index_scored_tokens`, `kv_selected_tokens` (behind `moe_tiles`),
+        from a model whose full layers attend to a learned selection
+        (`cfg.selection_counts`; 0 from any other): the positions the
+        indexer scored for the tick's prefill rows and its burst's lanes
+        and steps (a row at position p scores p + 1, in every full
+        layer), and the positions those rows then attended (at most
+        `index_top_k` each): the host's count from the lengths, as
+        `kv_read_tokens`.
 
         The tick runs on the phase clock (`_PhaseClock`): it starts in
         `admit`, its parts switch the leaf as they go, and it ends in
@@ -1551,7 +1587,8 @@ class PagedLLMEngine:
                        acct.routed_at if self._routed_sums is not None
                        else 0,
                        acct.kv_read_tokens, acct.reset_s,
-                       acct.experts_read, acct.ahead, acct.starved_s, 0]
+                       acct.experts_read, acct.ahead, acct.starved_s, 0,
+                       acct.index_scored_tokens, acct.kv_selected_tokens]
                 b = self._inflight
                 if b is not None and b.row is None:
                     b.row = row     # this tick's burst: logged at its read
@@ -1586,8 +1623,11 @@ class PagedLLMEngine:
         last: 1 + steps arrays of (V,).  With `routing` (a model with
         experts) returns (those, per lane the experts the program took
         at every position, prompt positions too, in every layer:
-        (n_prompt + steps, L, top_k) int32), through the same two
-        programs compiled to hand them out.  The engine must be idle;
+        (n_prompt + steps, L, top_k) int32; from a model that also selects
+        positions a dict of that under "experts" and, under "selected",
+        the positions its full layers attended, (n_prompt + steps, full
+        layers, index_top_k)), through the same two programs compiled to
+        hand them out.  The engine must be idle;
         its state is left as after requests that finished."""
         import jax
         import jax.numpy as jnp
@@ -1645,8 +1685,9 @@ class PagedLLMEngine:
                             jnp.int32(nv), **self._slot_kw(lane),
                             **route_kw)
                         if routing:            # (L, C, k) -> (nv, L, k)
-                            taken[lane].append(
-                                np.asarray(route[0]).swapaxes(0, 1)[:nv])
+                            taken[lane].append(jax.tree.map(
+                                lambda a: np.asarray(a).swapaxes(0, 1)[:nv],
+                                route[0]))
                     got[lane].append(last)         # position n_prompt - 1
                 active = np.arange(w) < lanes
                 on_device = (jnp.asarray(tables), jnp.asarray(active))
@@ -1660,15 +1701,18 @@ class PagedLLMEngine:
                         jnp.asarray(np.where(active, i, 0).astype(np.int32)),
                         on_device[1], **lanes_kw, **route_kw)
                     if routing:                # (L, w, k) -> (w, L, k)
-                        route = np.asarray(route[0]).swapaxes(0, 1)
+                        route = jax.tree.map(
+                            lambda a: np.asarray(a).swapaxes(0, 1), route[0])
                     for lane in range(lanes):
                         got[lane].append(logits[lane])     # position i
                         if routing:
-                            taken[lane].append(route[lane][None])
+                            taken[lane].append(jax.tree.map(
+                                lambda a: a[lane][None], route))
             finally:
                 self.allocator.free(blocks)
         if routing:
-            return got, [np.concatenate(t) for t in taken]
+            return got, [jax.tree.map(lambda *a: np.concatenate(a), *t)
+                         for t in taken]
         return got
 
     # -- disaggregated serving / live migration -------------------------

@@ -377,8 +377,10 @@ def test_starved_seconds_count_an_empty_queue_with_work_in_hand():
 
 # -- (e) the shape of the records --------------------------------------------
 def test_the_tick_fields_end_with_ahead_and_starved_seconds():
-    # and, behind them since PR 46, the tiles a tick's chunks multiplied
-    assert TICK_FIELDS[-3:] == ("ahead", "starved_s", "moe_tiles")
+    # and, behind them since PR 46, the tiles a tick's chunks multiplied,
+    # since PR 49 what a learned selection scored and attended
+    assert TICK_FIELDS[-5:] == ("ahead", "starved_s", "moe_tiles",
+                                "index_scored_tokens", "kv_selected_tokens")
     assert PHASES == ("wait", "admit", "burst_launch", "burst_read", "emit",
                       "chunk_launch", "first_read", "book")
     eng = _engine()

@@ -211,9 +211,11 @@ def test_the_absorbed_and_the_plain_form_agree():
         outs = []
         for start in range(0, t, size):
             pos = jnp.arange(start, start + size, dtype=jnp.int32)[None]
-            out, pool = mla_moe._mla(
-                ap, x[None, start:start + size], pool, li, table[0, pos // 8],
-                pos % 8, table, pos, jnp.asarray([start + size]), cfg)
+            lanes = mla_moe._Lanes(table, pos, jnp.asarray([start + size]),
+                                   table[0, pos // 8], pos % 8)
+            out, (pool, _, _), _ = mla_moe._attention(
+                ap, x[None, start:start + size], (pool, None, None), "full",
+                li, lanes, cfg)
             outs.append(out[0])
         np.testing.assert_allclose(np.asarray(jnp.concatenate(outs)),
                                    np.asarray(want), atol=1e-5)
@@ -805,8 +807,8 @@ def _roped_score_dropped(monkeypatch):
     inner = mla_moe._queries
 
     def no_rope_part(*a):
-        q_n, q_r = inner(*a)
-        return q_n, jnp.zeros_like(q_r)
+        q_n, q_r, cq = inner(*a)
+        return q_n, jnp.zeros_like(q_r), cq
 
     monkeypatch.setattr(mla_moe, "_queries", no_rope_part)
 

@@ -858,3 +858,63 @@ def test_gated_moe_served_programs_fit_one_chip(topo, program):
     _assert_experts_read_in_place(text, fam.expert_operand(config), program)
     ring_ops = re.findall(rf"= bf16\[[0-9,]*{rows},8,128\]", text)
     assert ring_ops and fam.ring_operand(config).search(ring_ops[0])
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_dsa_moe_served_programs_fit_one_chip(topo, program):
+    """dots3-note-prev at the benchmark's cut (layer 0 and one period:
+    two full layers of 128 heads with the indexer, three window layers of
+    64; 32 of 256 experts; an eighth of the vocabulary) and serving shape
+    (8 slots x 32,768, block 16: the full layers' latent rows 0.67 GB and
+    their index keys 0.13 GB pooled by one table of 2,048 entries a lane,
+    three rings of 1,040 rows of 1,152 a slot, beside 8.17 GB of
+    weights): the width-8 burst and the 512-row chunk compile for one
+    v5e chip and fit its 15.75 GB usable.  The three leaves are updated in
+    place (their bytes are aliased), the temporaries stay under 1 GB (the
+    chunk fetches its selected rows 128 query rows at a time: 0.34 GB a
+    buffer, where all 512 at once would be 1.3 GB a layer), and the ops
+    that read index keys, selected rows and rings show the shapes the
+    family's `index_operand`, `attn_operand`, `ring_operand` and
+    `select_operand` say, so that the traced run's readers find them."""
+    import json
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "dots3-note-prev-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state, params = resident["sequence_state"], resident["params"]
+    assert state.kv.shape == (2, 16385, 16, 640)
+    assert state.idx.shape == (2, 16385, 16, 128)
+    assert state.ring.shape == (3, 9, 1040, 1152)
+    assert params["attn"]["wq_b"].shape == (2, 1024, 128 * 192)
+    assert params["attn"]["wq_idx"].shape == (2, 1024, 64 * 128)
+    assert params["attn_window"]["wq_b"].shape == (3, 1024, 64 * 256)
+    assert params["attn_window"]["w_uk"].shape == (3, 64, 192, 1024)
+    assert params["ffn"]["w_gate"].shape == (4, 32, 5120, 1536)
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
+    assert abs(resident_bytes - 9.04e9) < 0.05e9, resident_bytes
+    assert resident_bytes > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
+    for operand in (fam.index_operand, fam.attn_operand, fam.ring_operand,
+                    fam.select_operand):
+        assert operand(config).search(text), operand.__name__
