@@ -96,8 +96,8 @@ and the absorbed read's soft-max runs over S_t alone, chunk and decode
 step alike: `ops.attention.paged_index_scores` (the lane's index keys
 through its block table, float32 accumulation), `select_positions` (an
 exact top-k over positions, never an approximate one) and
-`paged_latent_attention(selected=)` (the selected rows fetched through
-the table into a dense buffer that is both products' operand).  A window
+`paged_latent_attention(selected=)` (a chunk on a TPU reads live pages
+whole, the selection a mask; the rest fetch the selected rows).  A window
 layer reads its slot's ring through `slot_ring_reader` with
 `latent_window_attention`, on `ring_rows` / `ring_seen` as every ring.
 Scopes in a profile: `mla_attn` around a layer's attention, inside it
@@ -582,7 +582,7 @@ def _index(ap, u, cq, idx, at, lanes, cfg, routing):
         # each position's row of a layer's pool laid flat, (S, T)
         rows = jnp.repeat(tables, bs, axis=1) * bs \
             + jnp.arange(tables.shape[1] * bs) % bs
-        return (select_rows(scores, k, live, rows),
+        return (select_rows(scores, k, live, rows) + (scores,),
                 select_positions(scores, k, live) if routing else None, idx)
 
 

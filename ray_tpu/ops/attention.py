@@ -737,15 +737,15 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
     around every step (`models.mla_moe` has the numbers), and the kernel
     is not to be had.
 
-    `selected` = (rows (S, K, k) int32, seen (S, K, k) bool), a model that
-    attends to a learned selection of its positions (`select_rows`): each
-    query row's soft-max runs over exactly the rows of the pool it
-    selected and sees (an early row's set is filled up with rows it does
-    not), fetched into a dense buffer (`_selected_latent_attention`),
-    chunk and decode step alike."""
+    `selected` = `select_rows`' (rows, seen) and where the caller has
+    them (least, the index scores), a model that attends to a learned
+    selection: each query row's soft-max runs over exactly the rows it
+    selected and sees.  A chunk lowered for a TPU reads them as a mask
+    in a Pallas kernel (`_masked_latent_kernel`), a decode step and every
+    other platform fetch them (`_attend_selected` has the rule)."""
     if selected is not None:
-        return _selected_latent_attention(q, pool, layer, *selected,
-                                          d_v=d_v, scale=scale)
+        return _attend_selected(q, pool, layer, block_tables, kv_len,
+                                *selected, d_v=d_v, scale=scale)
 
     def loop(q, pool, layer, block_tables, positions, kv_len):
         return _paged_running_softmax(
@@ -781,10 +781,10 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
 # (rows, index heads, positions) float32 before the heads are summed, 134
 # MB for a 512-row chunk of 64 heads at 64 blocks of 16.
 _INDEX_GROUP_BLOCKS = 64
-# Query rows whose selected rows `_selected_latent_attention` fetches at
-# once: at 2,048 selected rows of 640 values a query row, 128 query rows are
-# a buffer of 0.34 GB and a score tile (128 heads) of 0.13 GB; a chunk's
-# 512 at once would be 1.3 GB a layer.
+# Query rows `_selected_latent_attention` fetches for at once (since PR 50
+# a chunk's fallback off the kernel's reach, `_attend_masked`; a decode step
+# is under it): 2,048 rows of 640 a query row, 128 query rows are a buffer
+# of 0.34 GB and a score tile of 0.13 GB; 512 at once 1.3 GB a layer.
 _SELECT_QUERY_ROWS = 128
 
 
@@ -902,23 +902,23 @@ def select_positions(scores, k: int, live=None):
 
 
 def select_rows(scores, k: int, live, rows):
-    """`select_positions` for the fetch: the same selection in the same
-    order, but of `rows` (S, T) int32, each position's row in a layer's
-    pool laid flat (block x block_size + offset, through the lane's
-    table), with (S, K, k) bool which of them the row sees (a score above
-    `_NEG_INF`)."""
+    """`select_positions` for the read: the same selection in order, but
+    of `rows` (S, T) int32, each position's row in a layer's pool laid
+    flat, with (S, K, k) bool which the row sees (a score above `_NEG_INF`)
+    and (S, K) the set's least score (halfway to it if it sees under k)."""
     neg, got = _select(scores, rows, k, live)
-    return got, neg < -0.5 * _NEG_INF
+    return got, neg < -0.5 * _NEG_INF, jnp.maximum(-neg[..., -1],
+                                                   0.5 * _NEG_INF)
 
 
 def _selected_latent_attention(q, pool, layer, rows, seen, *, d_v: int,
                                scale: float):
-    """`paged_latent_attention` over a selection: `rows` (S, K, k) int32,
-    each query row's selected rows of `[layer]` of the pool laid flat
-    (`select_rows`), are fetched into a dense (query rows, k, W) buffer,
-    which is both products' operand; one soft-max over the k, masked to
-    those the row sees (`seen` (S, K, k)).  A chunk's query rows go
-    `_SELECT_QUERY_ROWS` at a time."""
+    """A selection read by fetching it, on every platform (a chunk on a
+    TPU comes here off `_masked_latent_kernel`'s reach): `rows` (S, K, k)
+    int32, each query row's rows of `[layer]` of the pool laid flat, go
+    into a dense (query rows, k, W) buffer, both products' operand; one
+    soft-max over the k, masked to those the row sees (`seen`).  A
+    chunk's query rows go `_SELECT_QUERY_ROWS` at a time."""
     s, k_w, h, w = q.shape
     n_layers, n_blocks, bs, _ = pool.shape
     flat = pool.reshape(n_layers, n_blocks * bs, w)
@@ -1331,6 +1331,303 @@ def _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables, kv_len, *,
       q[:, 0].astype(k_pool.dtype), k_pool.reshape(stored),
       v_pool.reshape(stored))
     return out[:, None]
+
+
+# How a chunk reads a selection on a TPU, and why not by its rows (PR 50;
+# `TPU v5 lite`, 2026-10-01, calls 1 to 3; dots3-note-prev's widths: one
+# lane of 512 query rows x 128 heads over rows of 640 bfloat16, 512 of them
+# the value, k = 2,048, a pool of 2 x 16,385 blocks of 16).
+# **A DMA a selected row cannot be written.**  ISSUE 50 asked for a kernel
+# that copies each query row's 2,048 rows from the pool laid flat, a DMA a
+# row (a 32-bit word row a pair, the half taken in VMEM), and multiplies
+# them there.  Mosaic refuses every slice of a tiled dimension that is not
+# whole tiles, in HBM as in VMEM and at any width of element, compiled for
+# a described v5e: `pool.at[layer, pl.ds(row, 1)]` on float32 "Slice shape
+# along dimension 1 must be aligned to tiling (8), but is 1"; two bfloat16
+# rows, or one row of the pool's 32-bit view (`ref.bitcast`, tiling (4,
+# 128)): "(4), but is 1"; a whole tile at a row that is no multiple of 8:
+# "Failed to prove that a tile index in dimension 1 is divisible by the
+# tiling".  (Pallas's interpreter ran the float32 form and has no bitcast
+# of a DMA's source.)  What can be copied is a tile: 8 stored rows, 10 KB,
+# eight times a row's bytes, 21 MB a query row = 26 us at 819 GB/s = 13 ms
+# a layer and launch before a product, against the fetch's 17.7; with the
+# 16,384 columns a query row then has to mask, not built.
+# **Built: the lanes' live pages read whole, the selection a mask.**  A
+# launch's 512 sets together are nearly every live position, so each page
+# is copied once a group of query rows (a step's pages one DMA each, the
+# next step's under this step's products) and multiplied with all the
+# group's rows x heads at once; what the selection saves is then no
+# product, only which scores count.  ms a call (one layer), the fetch
+# (`_selected_latent_attention`: XLA's gather of 128 x 2,048 rows, 4.4 ms,
+# and the read of the 0.34 GB it wrote, 1.9, four times) beside the kernel
+# at (query rows a group, pages a step):
+#   context     fetch |  (8, 32)  (16, 32)  (16, 64)  (16, 128)  (8, 16)
+#        0      25.51 |    1.18     1.16      1.59      2.75      1.24
+#    8,192      25.65 |    9.87     9.94      9.48     11.2      11.21
+#   16,384      25.68 |   18.61    18.68     17.43     19.65     21.21
+#   24,064      25.70 |   26.78    26.9      24.38     26.07     30.51
+#   32,256      25.66 |   37.51    35.66     33.32       -       40.5
+# ((24, 64) and (32, 32) overrun VMEM's 64 MiB; (16, 32), (8, 16) and the
+# last row are call 1's, before the mask was taken a query row and not a
+# score row, which gave 3-5%.)  (16, 64): 2 x 512 x 128 x 1,152 FLOPs a
+# live position in 1.0 us, 70% of the MXU's 197 TFLOP/s, level from 8k to
+# 32k.  The fetch costs the same at every context and the kernel a
+# millisecond a thousand live positions: they cross at ~25,500, and
+# `_MASKED_LIVE_MAX` is the longest lane measured under it.  With the
+# counts and the branch around it (`_attend_masked`) the kernel reads 1.68 /
+# 9.57 / 17.55 / 24.48.  The bare 512-row launch of the benchmark's five
+# layers (two of them full), parent | this: 73.6 | 26.8 at context 0, 75.0
+# | 31.7 at 2k, 83.4 | 51.9 at 8k, 99.3 | 83.4 at 16k, 100.2 | 98.5 at
+# 24,064, 100.8 | 101.4 at 31,744 (the fetch, past the kernel's reach); a
+# decode step of 8 lanes at 24k 7.69 | 7.66 (the fetch, both).  Against
+# the fetch the kernel differs by 1% of its rms (the running soft-max
+# rescales and rounds exp(s - m), the fetch the normalised probabilities,
+# both to bfloat16).
+# **Equal scores.**  The mask is `score >= the set's least`, and the sort
+# settles ties at a set's edge in an order of its own.  With float32
+# scores a row's 2,049th best equals its 2,048th once in ~2,000 rows: 0 / 1
+# / 0 / 3 rows of a launch's 512 in the four calls above (normal scores of
+# 23 bits), every one a set that holds one of the tied positions, which
+# the kernel finds by its row of the pool (`last`).  A row that keeps two
+# or more and leaves one out sends its launch to the fetch; none was seen.
+_MASKED_QUERY_ROWS = 16
+_MASKED_KERNEL_PAGES = 64
+_MASKED_LIVE_MAX = 24576
+
+
+def _masked_latent_body(layer_ref, tables_ref, len_ref, pool_ref, q_ref,
+                        sc_ref, least_ref, last_ref, at_ref, o_ref, buf,
+                        sems, *, pages, bs, d_v, scale):
+    """One group of a lane's query rows of `_masked_latent_kernel`'s grid:
+    a loop over the lane's steps of `pages` pool blocks under a running
+    soft-max.  A step's live blocks `[layer, table[lane, j]]` of the pool
+    laid flat are copied, a DMA a block, into one half of `buf` (2, pages
+    x bs, W) while the other half is multiplied with every head of every
+    query row of the group at once.  A position counts for a query row
+    where its index score `sc_ref` (G, T) is above the row's `least_ref`
+    (G, 1), or equals it and either the row keeps every such position
+    (`last_ref` (G, 1) negative) or the position's row of the pool,
+    `at_ref` (1, T), is `last_ref`'s; nowhere else: `_NEG_INF` in the
+    scores, 0 among the probabilities."""
+    lane = pl.program_id(0)
+    g, h, w = q_ref.shape[1:]
+    t = pages * bs                         # positions a step
+    layer, length = layer_ref[0], len_ref[lane]
+    entry = lane * (tables_ref.shape[0] // pl.num_programs(0))
+    n_steps = pl.cdiv(length, t)
+
+    def copies(step, slot, go):
+        """Start (`go`) or await the copies of step `step`: its blocks
+        that hold a live position, no others."""
+        first = step * pages
+        live = jnp.minimum(pages, pl.cdiv(length, bs) - first)
+
+        def one(j, _):
+            at = pl.multiple_of(tables_ref[entry + first + j] * bs, bs)
+            dma = pltpu.make_async_copy(
+                pool_ref.at[layer, pl.ds(at, bs)],
+                buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
+                sems.at[slot])
+            if go:
+                dma.start()
+            else:
+                dma.wait()
+
+        jax.lax.fori_loop(0, live, one, None)
+
+    @pl.when((lane == 0) & (pl.program_id(1) == 0))
+    def _open():
+        # Rows past a lane's length are masked out and meet a probability
+        # of 0 in the value product: they have to be numbers.
+        buf[...] = jnp.zeros_like(buf)
+
+    pl.when(n_steps > 0)(lambda: copies(0, 0, True))
+    q = q_ref[0].reshape(g * h, w)
+    least, last = least_ref[0], last_ref[0]                # (G, 1)
+
+    def step(i, carry):
+        m, l, acc = carry
+        slot = i % 2
+        pl.when(i + 1 < n_steps)(lambda: copies(i + 1, 1 - slot, True))
+        copies(i, slot, False)
+        keys = buf[slot]                                   # (t, W)
+        s = jax.lax.dot_general(
+            q, keys, _NT, preferred_element_type=jnp.float32) * scale
+        here = pl.ds(pl.multiple_of(i * t, t), t)
+        sc = sc_ref[0, :, here]                            # (G, t)
+        keep = (sc > least) | ((sc == least) & (
+            (last < 0) | (at_ref[0, :, here] == last)))
+        # a query row's mask for each of its heads: (G, t) -> (G x H, t)
+        keep = jnp.broadcast_to(
+            jnp.where(keep, 1.0, 0.0)[:, None, :], (g, h, t)
+        ).reshape(g * h, t) > 0.5
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jax.lax.dot_general(
+            p.astype(keys.dtype), keys[:, :d_v], _NN,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    # A row may select nothing of a step, its first included: `m` starts
+    # above the mask's `_NEG_INF` and under every score, so that such a
+    # step's exp(s - m_new) are 0 and not 1.
+    _, l, acc = jax.lax.fori_loop(
+        0, n_steps, step,
+        (jnp.full((g * h, 1), 0.5 * _NEG_INF, jnp.float32),
+         jnp.zeros((g * h, 1), jnp.float32),
+         jnp.zeros((g * h, d_v), jnp.float32)))
+    # l is 0 where a row selects nothing: an idle lane, a padded row.
+    o_ref[0] = (acc / jnp.where(l > 0, l, 1.0)).reshape(g, h, d_v)
+
+
+@functools.partial(jax.jit, static_argnames=("d_v", "scale"))
+def _masked_latent_kernel(q, pool, layer, block_tables, kv_len, scores,
+                          least, last, *, d_v, scale):
+    """A chunk's attention over a selection as one kernel a layer, of
+    this file's own, **reading the lanes' live pages whole and the
+    selection as a mask**: `q` (S, K, H, W) against the pool where it
+    lies, laid flat (L, N x block_size, W) in HBM (a bitcast of it), with
+    `layer`, the tables and the lengths as scalars the kernel reads.
+    Query row (s, i) attends the positions p of its lane whose index
+    score `scores[s, i, p]` (S, K, T) float32 is above `least[s, i]` (S,
+    K), and of those that equal it all (`last[s, i]` (S, K) int32
+    negative) or the one whose row of the pool is `last[s, i]`
+    (`_attend_selected` says when that is the set `select_rows` chose).
+    The grid is (lanes, groups of `_MASKED_QUERY_ROWS` query rows); a
+    group's rows times the heads are the rows of one score tile against
+    a step of `_MASKED_KERNEL_PAGES` pages, copied to VMEM once for both
+    products (`_masked_latent_body`).  Operands in the pool's dtype,
+    accumulation, statistics and rescaling float32, the scale applied to
+    the float32 scores (`_latent_decode_kernel`'s conventions).  Jitted,
+    as the decode kernels and for their reason.  Returns (S, K, H, d_v)
+    float32."""
+    s, k_w, h, w = q.shape
+    n_layers, n_blocks, bs, _ = pool.shape
+    g = _MASKED_QUERY_ROWS
+    pages = min(_MASKED_KERNEL_PAGES, block_tables.shape[1])
+    t = pages * bs
+    # Whole groups of query rows and whole steps of positions: a padded
+    # row selects nothing, a padded position is selected by nobody.
+    rows = -k_w % g
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, -block_tables.shape[1] % pages)))
+    width = tables.shape[1] * bs
+    q = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, rows), (0, 0), (0, 0)))
+    scores = jnp.pad(
+        scores, ((0, 0), (0, rows), (0, width - scores.shape[-1])),
+        constant_values=_NEG_INF)
+    least = jnp.pad(least.astype(jnp.float32), ((0, 0), (0, rows)),
+                    constant_values=-_NEG_INF)
+    last = jnp.pad(last.astype(jnp.int32), ((0, 0), (0, rows)))
+    # each position's row of a layer's pool laid flat
+    at = jnp.repeat(tables, bs, axis=1) * bs + jnp.arange(width) % bs
+    out = pl.pallas_call(
+        functools.partial(_masked_latent_body, pages=pages, bs=bs, d_v=d_v,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s, (k_w + rows) // g),
+            in_specs=[
+                # the pool first: a profile keeps the start of an op's text
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, g, h, w), lambda i, j, *_: (i, j, 0, 0)),
+                pl.BlockSpec((1, g, width), lambda i, j, *_: (i, j, 0)),
+                pl.BlockSpec((1, g, 1), lambda i, j, *_: (i, j, 0)),
+                pl.BlockSpec((1, g, 1), lambda i, j, *_: (i, j, 0)),
+                pl.BlockSpec((1, 1, width), lambda i, j, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, g, h, d_v),
+                                   lambda i, j, *_: (i, j, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, t, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((s, k_w + rows, h, d_v), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="masked_latent_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.reshape(-1),
+      kv_len.astype(jnp.int32), pool.reshape(n_layers, n_blocks * bs, w),
+      q, scores, least[..., None], last[..., None], at[:, None, :])
+    return out[:, :k_w]
+
+
+def _masked_takes(q_shape, pool_shape, dtype, d_v: int):
+    """None where `_masked_latent_kernel` can run these shapes on a TPU,
+    else what stands in its way: rows, values and pages in whole tiles,
+    the heads whole sublane tiles of a query row's score rows."""
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize not in (2, 4):
+        return f"a pool of {dtype.name} has no tile of its own"
+    sublanes = 32 // dtype.itemsize
+    if pool_shape[-1] % _LANES or d_v % _LANES:
+        return (f"a row of {pool_shape[-1]} with a value of {d_v} is not "
+                f"whole tiles of {_LANES} lanes")
+    if q_shape[2] % sublanes or pool_shape[2] % sublanes:
+        return (f"{q_shape[2]} heads or a page of {pool_shape[2]} rows are "
+                f"not whole tiles of {sublanes} sublanes")
+    return None
+
+
+def _attend_masked(q, pool, layer, block_tables, kv_len, rows, seen, least,
+                   scores, *, d_v: int, scale: float):
+    """`_masked_latent_kernel` where its mask is the selection to the
+    position, else the fetch.  Every position above a row's `least` is in
+    its set; of those that equal it the sort kept `kept`, in an order of
+    its own.  So the mask is the set where a row's set holds every such
+    position, or one alone (the set's last row, which the kernel then
+    looks for among them); a launch in which some row keeps two or more
+    and leaves one out takes the fetch, as does one whose longest lane
+    passes `_MASKED_LIVE_MAX`."""
+    above = jnp.sum(scores > least[..., None], axis=-1)
+    kept = jnp.sum(seen, axis=-1) - above
+    every = jnp.sum(scores == least[..., None], axis=-1) == kept
+    return jax.lax.cond(
+        (jnp.max(kv_len) <= _MASKED_LIVE_MAX) & jnp.all(every | (kept == 1)),
+        lambda: _masked_latent_kernel(
+            q, pool, layer, block_tables, kv_len, scores, least,
+            jnp.where(every, -1, rows[..., -1]), d_v=d_v, scale=scale),
+        lambda: _selected_latent_attention(q, pool, layer, rows, seen,
+                                           d_v=d_v, scale=scale))
+
+
+def _attend_selected(q, pool, layer, block_tables, kv_len, rows, seen,
+                     least=None, scores=None, *, d_v: int, scale: float):
+    """`paged_latent_attention` over a selection: `select_rows`' rows and
+    seen, and where the caller has them its least and the index `scores`
+    (S, K, T) it chose by, in one of two forms that attend the same set.
+    **A chunk (K > 1) lowered for a TPU reads its lanes' live pages
+    whole, once a group of query rows, in one Pallas kernel a layer, the
+    selection a mask on its score tile** (`_masked_latent_kernel`, where
+    `_attend_masked` finds the mask to be the set).  A decode step, a
+    selection handed over without its scores, every other platform, a
+    pool split over a mesh and shapes that are not whole tiles (it says
+    so) **fetch** the selected rows into a dense buffer
+    (`_selected_latent_attention`).  The platform is the one the program
+    is lowered for (`jax.lax.platform_dependent`), as
+    `paged_latent_attention`'s."""
+    def fetch(q, pool, layer, block_tables, kv_len, rows, seen, *_):
+        return _selected_latent_attention(q, pool, layer, rows, seen,
+                                          d_v=d_v, scale=scale)
+
+    args = (q, pool, layer, block_tables, kv_len, rows, seen, least, scores)
+    if least is None or q.shape[1] == 1 \
+            or jax.typeof(pool).sharding.mesh.size > 1:
+        return fetch(*args)
+    why = _masked_takes(q.shape, pool.shape, pool.dtype, d_v)
+    if why is not None:
+        # Decided at trace time, as `paged_latent_attention`'s.
+        warnings.warn(
+            f"paged_latent_attention: {why}, so a chunk over pool"
+            f"{pool.shape} fetches its selected rows on a TPU too, not the "
+            f"Pallas kernel (a 512-row launch at dots3-note-prev's widths "
+            f"takes 25 ms a layer whatever its context)", stacklevel=3)
+        return fetch(*args)
+    return jax.lax.platform_dependent(
+        *args, tpu=functools.partial(_attend_masked, d_v=d_v, scale=scale),
+        default=fetch)
 
 
 # Differential attention (arXiv:2410.05258): softmax(q1 k1^T / sqrt(D)) v

@@ -406,9 +406,13 @@ def test_tiers_and_spans_give_the_one_top_k(live, span, monkeypatch):
     assert all(len(set(row.tolist())) == 8 for row in got.reshape(-1, 8))
     rows_of = jnp.asarray(rng.permutation(1000)[:128].reshape(2, 64),
                           jnp.int32)
-    rows, seen = jax.jit(lambda s, n, r: attention.select_rows(s, 8, n, r))(
+    rows, seen, least = jax.jit(
+        lambda s, n, r: attention.select_rows(s, 8, n, r))(
         jnp.asarray(scores), jnp.int32(live), rows_of)
     assert (np.asarray(seen) == (want > -1e29)).all()
+    # the least score of the set: its last, or for a row that sees fewer
+    # than 8 a number between every real score and the mask's
+    assert (np.asarray(least) == np.maximum(want[..., -1], -5e29)).all()
     assert (np.asarray(rows) == np.take_along_axis(
         np.asarray(rows_of)[:, None, :].repeat(3, 1), got, -1)).all()
 
@@ -448,6 +452,190 @@ def test_the_selected_read_is_a_soft_max_over_exactly_the_set():
         finally:
             attention._SELECT_QUERY_ROWS = old
         np.testing.assert_allclose(got[0], want, rtol=2e-5, atol=2e-5)
+
+
+def _a_chunk_that_selects(dtype, lengths, k_w, scores_of, *, heads=4, w=128,
+                          d_v=24, k=16, bs=8, entries=6, seed=0):
+    """`lengths` lanes of `k_w` query rows at `tiny-dsa-moe`'s widths (4
+    heads over rows of 128, 24 of them the value; 16 selected): a pool
+    whose null block and last block both stand in a table, index scores
+    `scores_of(shape, key)` masked to the positions a row sees, and
+    `select_rows`' selection of them."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    lanes, width = len(lengths), entries * bs
+    n_blocks = lanes * entries
+    pool = jax.random.normal(keys[0], (2, n_blocks, bs, w),
+                             jnp.float32).astype(dtype)
+    q = jax.random.normal(keys[1], (lanes, k_w, heads, w),
+                          jnp.float32).astype(dtype)
+    # every block once, the pool's first row and its last among them
+    tables = jax.random.permutation(keys[2], n_blocks).reshape(
+        lanes, entries).astype(jnp.int32)
+    kv_len = jnp.asarray(lengths, jnp.int32)
+    positions = jnp.maximum(
+        kv_len[:, None] - k_w + jnp.arange(k_w)[None, :], 0)
+    scores = jnp.where(
+        (jnp.arange(width) <= positions[:, :, None])
+        & (kv_len > 0)[:, None, None],
+        scores_of((lanes, k_w, width), keys[3]), attention._NEG_INF)
+    at = jnp.repeat(tables, bs, axis=1) * bs + jnp.arange(width) % bs
+    selection = attention.select_rows(scores, k, jnp.max(kv_len), at)
+    return (q, pool, 1, tables, kv_len), selection, scores
+
+
+def _ties(selection, scores):
+    """(the rows whose set holds every position that ties with its last,
+    those whose set holds one of them) as `_attend_masked` counts them."""
+    _, seen, least = selection
+    kept = seen.sum(-1) - (scores > least[..., None]).sum(-1)
+    return (scores == least[..., None]).sum(-1) == kept, kept == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths,k_w", [
+    ((40,), 8),        # every row sees more than the 16 it selects
+    ((11,), 11),       # rows that see 1, 2, .. 11: all of them selected
+    ((18,), 4),        # the count's edge: rows that see 15, 16, 17, 18
+    ((48, 0, 5), 1),   # a row a lane, one of them idle (nothing selected)
+    ((17, 30), 5),     # query rows that are no multiple of the group
+    ((33, 48), 8),     # and a multiple, a lane that fills its table
+])
+def test_the_masked_kernel_agrees_with_the_fetch(dtype, lengths, k_w,
+                                                 monkeypatch):
+    """`_masked_latent_kernel` in Pallas's interpret mode (Mosaic needs a
+    TPU; `tests/test_tpu_compile.py` compiles it for a described one)
+    against `_selected_latent_attention` over `select_rows`' selection:
+    the same set read two ways, the kernel's in steps of two pages under
+    a running soft-max and groups of four query rows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_QUERY_ROWS", 4)
+    monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
+    lanes, (rows, seen, least), scores = _a_chunk_that_selects(
+        dtype, lengths, k_w, lambda shape, key: jax.random.normal(key, shape))
+    want = attention._selected_latent_attention(
+        *lanes[:3], rows, seen, d_v=24, scale=0.2)
+    with pltpu.force_tpu_interpret_mode():
+        got = attention._masked_latent_kernel(
+            *lanes, scores, least, jnp.full(least.shape, -1), d_v=24,
+            scale=0.2)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    live = np.asarray(lanes[4]) > 0
+    assert not np.asarray(got[~live]).any()
+    # float32: the two differ by the order of their sums; bfloat16: the
+    # kernel rounds exp(s - m) to the rows' dtype, the fetch the
+    # normalised probabilities (2**-9 each)
+    bound = 2e-6 if dtype == jnp.float32 else 1.5e-2
+    rms = float(jnp.sqrt(jnp.mean(want[live] ** 2)))
+    assert float(jnp.abs(got - want)[live].max()) < bound * rms
+
+
+@pytest.mark.parametrize("decimals,seed,every_row_settled", [
+    (1, 0, True), (1, 3, False), (0, 4, False)])
+def test_equal_scores_at_a_set_s_edge_are_the_sort_s_to_settle(
+        decimals, seed, every_row_settled, monkeypatch):
+    """Index scores rounded until positions tie with a set's last: the
+    mask is the sort's set where the set holds every such position or one
+    alone (the kernel finds it by its row of the pool), and a launch in
+    which a row keeps several and leaves one out takes the fetch, to the
+    bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_QUERY_ROWS", 4)
+    monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
+    lanes, selection, scores = _a_chunk_that_selects(
+        jnp.float32, (48, 40), 6, seed=seed,
+        scores_of=lambda shape, key: jnp.round(
+            jax.random.normal(key, shape), decimals))
+    rows, seen, least = selection
+    every, one = _ties(selection, scores)
+    assert not bool(every.all()) and bool((~every & one).any())
+    assert bool((every | one).all()) == every_row_settled
+    want = attention._selected_latent_attention(
+        *lanes[:3], rows, seen, d_v=24, scale=0.2)
+    with pltpu.force_tpu_interpret_mode():
+        by_rule = attention._attend_masked(
+            *lanes, *selection, scores, d_v=24, scale=0.2)
+        kernel = attention._masked_latent_kernel(
+            *lanes, scores, least, jnp.where(every, -1, rows[..., -1]),
+            d_v=24, scale=0.2)
+    settled = np.asarray(every | one)
+    np.testing.assert_allclose(np.asarray(kernel)[settled],
+                               np.asarray(want)[settled], atol=2e-6)
+    if every_row_settled:
+        np.testing.assert_allclose(by_rule, want, atol=2e-6)
+    else:
+        np.testing.assert_array_equal(by_rule, want)
+        assert float(jnp.abs(kernel - want).max()) > 1e-3
+
+
+def test_a_lane_past_the_kernel_s_reach_takes_the_fetch(monkeypatch):
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
+    lanes, selection, scores = _a_chunk_that_selects(
+        jnp.float32, (40,), 8, lambda shape, key: jax.random.normal(key, shape))
+    want = attention._selected_latent_attention(
+        *lanes[:3], *selection[:2], d_v=24, scale=0.2)
+    for reach, same in ((39, True), (40, False)):
+        monkeypatch.setattr(attention, "_MASKED_LIVE_MAX", reach)
+        with pltpu.force_tpu_interpret_mode():
+            got = attention._attend_masked(*lanes, *selection, scores,
+                                           d_v=24, scale=0.2)
+        assert bool((got == want).all()) == same
+        np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("q_shape,pool_shape,dtype,d_v,taken", [
+    ((1, 512, 128, 640), (2, 16385, 16, 640), jnp.bfloat16, 512, True),
+    ((4, 64, 16, 128), (2, 9, 16, 128), jnp.bfloat16, 128, True),
+    ((1, 512, 128, 640), (2, 16385, 8, 640), jnp.float32, 512, True),
+    ((1, 512, 128, 576), (2, 16385, 16, 576), jnp.bfloat16, 512, False),
+    ((1, 512, 128, 640), (2, 16385, 16, 640), jnp.bfloat16, 448, False),
+    ((1, 512, 8, 640), (2, 16385, 16, 640), jnp.bfloat16, 512, False),
+    ((1, 512, 128, 640), (2, 16385, 8, 640), jnp.bfloat16, 512, False),
+    ((1, 512, 128, 640), (2, 16385, 16, 640), jnp.float8_e4m3fn, 512, False),
+    ((1, 32, 4, 128), (2, 17, 8, 128), jnp.bfloat16, 24, False),
+], ids=["dots3-note-prev", "whole-tiles", "float32", "row-of-576",
+        "value-of-448", "8-heads", "page-of-8", "8-bit", "tiny-dsa-moe"])
+def test_the_rule_that_picks_the_selected_read(q_shape, pool_shape, dtype,
+                                               d_v, taken, recwarn):
+    """Shapes alone decide (`_masked_takes`): rows, values, heads and
+    pages in whole tiles of the pool's dtype.  What is refused says so
+    and fetches; a decode step and a selection handed over without its
+    scores fetch without a word."""
+    assert (attention._masked_takes(q_shape, pool_shape, dtype, d_v)
+            is None) == taken
+    if max(q_shape + pool_shape) > 1024:
+        return
+    k = 8
+    q = jnp.zeros(q_shape, dtype)
+    pool = jnp.zeros(pool_shape, dtype)
+    lanes, k_w = q_shape[:2]
+    tables = jnp.zeros((lanes, 2), jnp.int32)
+    rows = jnp.zeros((lanes, k_w, k), jnp.int32)
+    seen = jnp.ones((lanes, k_w, k), bool)
+    scores = jnp.zeros((lanes, k_w, 2 * pool_shape[2]))
+
+    def lower(q, *selected):
+        return jax.jit(lambda q, pool: attention.paged_latent_attention(
+            q, pool, 0, tables, jnp.zeros((lanes, q.shape[1]), jnp.int32),
+            jnp.ones((lanes,), jnp.int32), d_v=d_v, scale=1.0,
+            selected=selected)).lower(q, pool)
+
+    chunk = lower(q, rows, seen, jnp.zeros((lanes, k_w)), scores)
+    said = [str(w.message) for w in recwarn.list
+            if "fetches its selected rows" in str(w.message)]
+    assert bool(said) == (not taken)
+    # this host lowers for its CPU: the fetch, the kernel's branch nowhere
+    assert "masked_latent_attention" not in chunk.as_text()
+    recwarn.clear()
+    lower(q, rows, seen)
+    lower(q[:, :1], rows[:, :1], seen[:, :1], jnp.zeros((lanes, 1)),
+          scores[:, :1])
+    assert not [w for w in recwarn.list
+                if "fetches its selected rows" in str(w.message)]
 
 
 def test_the_latent_ring_reader_sees_the_window_and_no_more():
@@ -550,9 +738,10 @@ def _selecting(pick):
     fetches and what it hands over."""
     def rows_of(scores, k, live, rows):
         at = pick(scores, k, live)
+        picked = jnp.take_along_axis(scores, at, axis=-1)
         return (jnp.take_along_axis(
             jnp.broadcast_to(rows[:, None, :], scores.shape), at, axis=-1),
-            jnp.take_along_axis(scores, at, axis=-1) > -1e29)
+            picked > -1e29, jnp.maximum(picked.min(axis=-1), -5e29))
 
     def fault(monkeypatch, cfg):
         monkeypatch.setattr(mla_moe, "select_positions", pick)

@@ -872,11 +872,19 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     weights): the width-8 burst and the 512-row chunk compile for one
     v5e chip and fit its 15.75 GB usable.  The three leaves are updated in
     place (their bytes are aliased), the temporaries stay under 1 GB (the
-    chunk fetches its selected rows 128 query rows at a time: 0.34 GB a
-    buffer, where all 512 at once would be 1.3 GB a layer), and the ops
-    that read index keys, selected rows and rings show the shapes the
-    family's `index_operand`, `attn_operand`, `ring_operand` and
-    `select_operand` say, so that the traced run's readers find them."""
+    chunk's fetch, kept for what the kernel does not reach, takes its
+    selected rows 128 query rows at a time: 0.34 GB a buffer, where all
+    512 at once would be 1.3 GB a layer; 0.98 GB in all with the index
+    scores kept for the kernel's mask, 0.91 before it; the burst 0.41), and the ops that read
+    index keys, selected rows and rings show the shapes the family's
+    `index_operand`, `attn_operand`, `ring_operand` and `select_operand`
+    say, so that the traced run's readers find them.  **The chunk's
+    selected read is this repo's Pallas kernel** (since PR 50: a
+    `tpu_custom_call` for layer 0 and one in the scan's body, each handed
+    the pool laid flat, which is what `attn_operand` and `select_operand`
+    look for, within the 600 characters of an op's text that a profile
+    keeps), under a branch whose other side is the fetch; the burst's is
+    the fetch alone."""
     import json
 
     from bench.harness import spec
@@ -918,3 +926,17 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     for operand in (fam.index_operand, fam.attn_operand, fam.ring_operand,
                     fam.select_operand):
         assert operand(config).search(text), operand.__name__
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "masked_latent_attention" in line]
+    assert len(calls) == (2 if program == "paged_prefill_chunk" else 0)
+    for call in calls:
+        seen_by_a_profile = call.strip()[:600]
+        assert "bf16[2,262160,640]" in seen_by_a_profile, call
+        assert fam.attn_operand(config).search(seen_by_a_profile)
+        assert fam.select_operand(config).search(seen_by_a_profile)
+    # the fetch stands beside the kernel (a longer lane, a tie the mask
+    # cannot settle): the gathered buffer is still a shape of the chunk
+    if calls:
+        assert "bf16[128,2048,640]" in text
+        assert mem.temp_size_in_bytes > 0.3e9, mem.temp_size_in_bytes
