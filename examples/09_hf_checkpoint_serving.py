@@ -19,6 +19,9 @@ from ray_tpu import serve
 from ray_tpu.models import from_hf
 from ray_tpu.serve.llm import LLMDeployment
 
+# seeded: a draw whose greedy tokens hit the config's EOS (id 2) stops
+# transformers early and not the engine, once in ~30 runs
+torch.manual_seed(0)
 hf_model = transformers.LlamaForCausalLM(transformers.LlamaConfig(
     vocab_size=256, hidden_size=64, intermediate_size=128,
     num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,

@@ -94,15 +94,17 @@ carries the lightning indexer published with DeepSeek-V3.2:
 
 and the absorbed read's soft-max runs over S_t alone, chunk and decode
 step alike: `ops.attention.paged_index_scores` (the lane's index keys
-through its block table, float32 accumulation), `select_positions` (an
-exact top-k over positions, never an approximate one) and
-`paged_latent_attention(selected=)` (a chunk on a TPU reads live pages
-whole, the selection a mask; the rest fetch the selected rows).  A window
-layer reads its slot's ring through `slot_ring_reader` with
-`latent_window_attention`, on `ring_rows` / `ring_seen` as every ring.
+through its block table, float32 accumulation) and
+`paged_latent_attention(selected=)`, which chooses and reads in one (an
+exact top-k over positions, never an approximate one: a chunk on a TPU
+finds S_t's least score by a search that sorts nothing and reads live
+pages whole, that threshold its mask; the rest sort, and fetch the
+selected rows).  A window layer reads its slot's ring through
+`slot_ring_reader` with `latent_window_attention`, on `ring_rows` /
+`ring_seen` as every ring.
 Scopes in a profile: `mla_attn` around a layer's attention, inside it
-`dsa_index`, `dsa_select`, `dsa_attend`, `latent_swa`, `attn_gate`;
-`moe`, `shared_mlp`, `dense_mlp`.
+`dsa_index`, `dsa_attend` (inside it `dsa_select`), `latent_swa`,
+`attn_gate`; `moe`, `shared_mlp`, `dense_mlp`.
 
 What a sequence keeps (`LatentState`), three leaves: `kv` (full layers,
 N_blocks, block_size, row_width), paged as ever, block 0 the null block;
@@ -137,8 +139,6 @@ from ray_tpu.ops.attention import (
     paged_index_scores,
     paged_latent_attention,
     ring_rows,
-    select_positions,
-    select_rows,
     slot_ring_reader,
 )
 from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless, routed_zero
@@ -554,12 +554,13 @@ def _queries(ap, u, positions, cfg, k: _Kind):
                                          theta=k.theta), cq
 
 
-def _index(ap, u, cq, idx, at, lanes, cfg, routing):
+def _index(ap, u, cq, idx, at, lanes, cfg):
     """The indexer of full layer `at`: its key of every new position
     written to the pooled leaf `idx`, then every query row's score of
-    every position of its lane, and the `index_top_k` best.  Returns
-    ((rows (S, K, k) of the pool laid flat, seen (S, K, k)): what the read
-    fetches, the positions (S, K, k) if `routing` asks else None, idx)."""
+    every position of its lane.  Returns ((scores (S, K, T) float32, each
+    position's row of a layer's pool laid flat (S, T), index_top_k): what
+    `paged_latent_attention(selected=)` chooses the best of, idx); nothing
+    is sorted here (the read sorts where it fetches, and only there)."""
     cd, hi, di = cfg.compute_dtype, cfg.index_heads, cfg.index_dim
     theta, positions = cfg.rope_theta, lanes.positions
     bs, tables = idx.shape[2], lanes.block_tables
@@ -577,13 +578,12 @@ def _index(ap, u, cq, idx, at, lanes, cfg, routing):
             .astype(F32) * (hi ** -0.5 * di ** -0.5)
         scores = paged_index_scores(
             q.astype(idx.dtype), w, idx, at, tables, positions, lanes.kv_len)
-    with jax.named_scope("dsa_select"):
-        live, k = jnp.max(lanes.kv_len), cfg.index_top_k
-        # each position's row of a layer's pool laid flat, (S, T)
+        # What the read takes the positions' rows by: the sort carries a
+        # position's row with its score, the kernel tells a tied position
+        # by it.
         rows = jnp.repeat(tables, bs, axis=1) * bs \
             + jnp.arange(tables.shape[1] * bs) % bs
-        return (select_rows(scores, k, live, rows) + (scores,),
-                select_positions(scores, k, live) if routing else None, idx)
+    return (scores, rows, cfg.index_top_k), idx
 
 
 class _Lanes(NamedTuple):
@@ -622,11 +622,11 @@ def _attention(ap, x, state, kind, at, lanes: _Lanes, cfg, routing=False):
         with jax.named_scope("latent_swa"):
             o_lat = lanes.read_ring(q, ring, ring, at)
     elif cfg.index_top_k:
-        fetch, selected, idx = _index(ap, u, cq, idx, at, lanes, cfg, routing)
+        best, idx = _index(ap, u, cq, idx, at, lanes, cfg)
         with jax.named_scope("dsa_attend"):
-            o_lat = paged_latent_attention(
-                q, kv, at, lanes.block_tables, lanes.positions,
-                lanes.kv_len, d_v=k.kv_rank, scale=k.scale, selected=fetch)
+            o_lat, selected = paged_latent_attention(
+                q, kv, at, lanes.block_tables, lanes.positions, lanes.kv_len,
+                d_v=k.kv_rank, scale=k.scale, selected=best + (routing,))
     else:
         o_lat = paged_latent_attention(
             q, kv, at, lanes.block_tables, lanes.positions, lanes.kv_len,

@@ -737,12 +737,12 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
     around every step (`models.mla_moe` has the numbers), and the kernel
     is not to be had.
 
-    `selected` = `select_rows`' (rows, seen) and where the caller has
-    them (least, the index scores), a model that attends to a learned
-    selection: each query row's soft-max runs over exactly the rows it
-    selected and sees.  A chunk lowered for a TPU reads them as a mask
-    in a Pallas kernel (`_masked_latent_kernel`), a decode step and every
-    other platform fetch them (`_attend_selected` has the rule)."""
+    `selected` = (index scores, each position's row of the pool, k, and
+    whether the positions are wanted too), a model that attends to a
+    learned selection: each query row's soft-max runs over exactly its k
+    best-scored rows, and (out, positions or None) comes back.  A chunk
+    lowered for a TPU reads them as a mask in a Pallas kernel, a decode
+    step and every other platform fetch them (`_attend_selected`)."""
     if selected is not None:
         return _attend_selected(q, pool, layer, block_tables, kv_len,
                                 *selected, d_v=d_v, scale=scale)
@@ -844,10 +844,10 @@ def paged_index_scores(q, w, pool, layer, block_tables, positions, kv_len):
 # (score, payload) pairs: which of two equal scores comes first is the
 # sorting network's to say, the same for every payload (it compares scores
 # alone), so the rows fetched and the positions handed to a check are one
-# selection.  The k-th score alone, by 33 rounds of bisection on the
-# scores' bit patterns, reads 0.71 ms at 32,768 (call B), and nothing cheap
-# was found to turn `score >= it` into the list the fetch needs: PERF.md
-# section 8, PR 49.
+# selection.  The k-th score alone, by 32 rounds of bisection on the
+# scores' bit patterns, sorts nothing (0.30 ms at 16,384), and since PR 51
+# a chunk's kernel reads `score >= it` as its mask and needs no list: the
+# sort is the fetch's alone (`_attend_masked`; the search's table above it).
 _SELECT_SPAN = 16384
 
 
@@ -902,10 +902,10 @@ def select_positions(scores, k: int, live=None):
 
 
 def select_rows(scores, k: int, live, rows):
-    """`select_positions` for the read: the same selection in order, but
-    of `rows` (S, T) int32, each position's row in a layer's pool laid
-    flat, with (S, K, k) bool which the row sees (a score above `_NEG_INF`)
-    and (S, K) the set's least score (halfway to it if it sees under k)."""
+    """`select_positions` for the fetch (a decode step, a chunk off the
+    mask's reach: nobody else sorts): the same selection in order, but of
+    `rows` (S, T) int32, each position's row in a layer's pool laid flat,
+    (S, K, k) bool which the row sees, (S, K) the set's least score."""
     neg, got = _select(scores, rows, k, live)
     return got, neg < -0.5 * _NEG_INF, jnp.maximum(-neg[..., -1],
                                                    0.5 * _NEG_INF)
@@ -1383,13 +1383,45 @@ def _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables, kv_len, *,
 # the fetch the kernel differs by 1% of its rms (the running soft-max
 # rescales and rounds exp(s - m), the fetch the normalised probabilities,
 # both to bfloat16).
-# **Equal scores.**  The mask is `score >= the set's least`, and the sort
-# settles ties at a set's edge in an order of its own.  With float32
+# **The selection is a threshold, and nothing is sorted for it** (PR 51;
+# `TPU v5 lite`, 2026-10-01, call 1; bare, 512 rows, k = 2,048, float32
+# normal scores, a row seeing up to its own position).  The mask needs the
+# set's least score and no list, and PR 50 still sorted in front of the
+# kernel.  The k-th largest score is the largest float32 that k scores
+# reach, found a bit a round from the top of its place in the order of
+# all float32 (`_score_at`): 32 counts of `score >= candidate` over the
+# row, exact whatever the scores are.  ms by candidates a row, the sort
+# (`select_rows`) | the search | the search with the three counts at the
+# edge (`_edge_of_best`, what a launch pays):
+#    4,096   0.62 | 0.25 | 0.47        16,384   4.59 | 0.30 | 0.52
+#    8,192   1.32 | 0.21 | 0.50        24,576   6.39 | 0.42 | 0.60
+#   32,768  12.08 | 2.96 | 3.12   (a call under ~0.2 ms is bound by its
+# launch from the host).  32 rounds over 32 MB in 0.30 ms are 3.4 TB/s:
+# up to 24,576 candidates (48 MB) the compiler keeps the scores on the
+# chip between the rounds, at 32,768 it reads HBM every round.  Hence the
+# search reads no candidate past `_MASKED_LIVE_MAX`: the kernel reaches no
+# further.  In a launch's profile (call 3, context 24,064) the 32 rounds
+# are 0.29 ms a layer.  It has two tiers, 4 k and all: with the sort's
+# five, traced at both call sites of each of a replica's five chunk
+# tiers, a warm replica of dots3-note-prev read `setup_s` +2.2% / +7.8% /
+# +9.2% in three pairs (calls A, B; bound 10%; its warm-up 22.5-23.2 s for
+# 17.8-19.5); with two, jitted, +2.5% on the medians of five and three
+# runs (call F: 19.3-22.4 s), and a bare launch the same to 0.2 ms at
+# every context.  Tried beside it, call 1: two bits a round
+# (three counts in 16 rounds) 0.21 / 0.24 / 0.34 / 0.48, 1.53 at 32,768; four
+# bits (15 counts in 8 rounds) 0.26 / 0.44 / 0.80 / 1.18 / 1.51; a Pallas
+# kernel with a group's (16, T) scores in VMEM for all 32 rounds 0.24 /
+# 0.26 / 0.39 / 0.51 / 0.53 (32 rows a group: 0.20 / 0.26 / 0.34 / 0.48 /
+# 0.51; 8: 0.32-0.96): level with the plain loop where the kernel reaches,
+# so the plain loop stands.
+# **Equal scores.**  The mask is `score >= the set's least`, and of the
+# positions that tie with it the set may hold some only.  With float32
 # scores a row's 2,049th best equals its 2,048th once in ~2,000 rows: 0 / 1
-# / 0 / 3 rows of a launch's 512 in the four calls above (normal scores of
-# 23 bits), every one a set that holds one of the tied positions, which
-# the kernel finds by its row of the pool (`last`).  A row that keeps two
-# or more and leaves one out sends its launch to the fetch; none was seen.
+# / 0 / 3 rows of a launch's 512 in PR 50's four calls (normal scores of 23
+# bits), every one a set that holds one of the tied positions: the kernel
+# keeps the lowest of them, the reference's own rule, found by its row of
+# the pool (`last`).  A row that keeps two or more and leaves one out sends
+# its launch to the fetch, where the sort settles it; none was seen.
 _MASKED_QUERY_ROWS = 16
 _MASKED_KERNEL_PAGES = 64
 _MASKED_LIVE_MAX = 24576
@@ -1495,7 +1527,7 @@ def _masked_latent_kernel(q, pool, layer, block_tables, kv_len, scores,
     score `scores[s, i, p]` (S, K, T) float32 is above `least[s, i]` (S,
     K), and of those that equal it all (`last[s, i]` (S, K) int32
     negative) or the one whose row of the pool is `last[s, i]`
-    (`_attend_selected` says when that is the set `select_rows` chose).
+    (`_attend_masked` finds both, and whether that is an exact top-k).
     The grid is (lanes, groups of `_MASKED_QUERY_ROWS` query rows); a
     group's rows times the heads are the rows of one score tile against
     a step of `_MASKED_KERNEL_PAGES` pages, copied to VMEM once for both
@@ -1571,50 +1603,137 @@ def _masked_takes(q_shape, pool_shape, dtype, d_v: int):
     return None
 
 
-def _attend_masked(q, pool, layer, block_tables, kv_len, rows, seen, least,
-                   scores, *, d_v: int, scale: float):
-    """`_masked_latent_kernel` where its mask is the selection to the
-    position, else the fetch.  Every position above a row's `least` is in
-    its set; of those that equal it the sort kept `kept`, in an order of
-    its own.  So the mask is the set where a row's set holds every such
-    position, or one alone (the set's last row, which the kernel then
-    looks for among them); a launch in which some row keeps two or more
-    and leaves one out takes the fetch, as does one whose longest lane
-    passes `_MASKED_LIVE_MAX`."""
-    above = jnp.sum(scores > least[..., None], axis=-1)
-    kept = jnp.sum(seen, axis=-1) - above
-    every = jnp.sum(scores == least[..., None], axis=-1) == kept
+def _score_at(key):
+    """The float32 that stands at place `key` (uint32) in the order of all
+    float32: the negatives' bit patterns turned over, the others above
+    them."""
+    top = jnp.uint32(1 << 31)
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key >= top, key ^ top, ~key), jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "reach"))
+def _edge_of_best(scores, k: int, live, reach: int = 1 << 30):
+    """Where each row's k largest `scores` (S, K, T) end, **by a search
+    that sorts nothing**: (least, above, equal, first), each (S, K).
+    `least` is the k-th largest score to the bit (`select_rows`' own:
+    halfway to `_NEG_INF` for a row that sees under k), the largest
+    float32 that k of the row's scores reach, found a bit a round from the
+    top of its place in the order (`_score_at`); `above` and `equal` count
+    the scores over it and at it, and `first` is the lowest position at
+    it.  `live` as `_select`'s: only the candidates that can hold the
+    call's longest lane are read, the first 4 k or all up to `reach` (two
+    tiers, not the sort's five, because a tier costs a replica's start
+    more than it saves a launch; what comes back for a lane longer than
+    `reach` nobody reads).  Jitted, as the kernels: a program's call sites
+    trace it once."""
+    k = min(k, scores.shape[-1])
+    top = max(k, min(scores.shape[-1], reach))
+
+    def edge(sc):
+        def round_(i, key):
+            higher = key | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+            count = jnp.sum(sc >= _score_at(higher)[..., None], axis=-1,
+                            dtype=jnp.int32)
+            return jnp.where(count >= k, higher, key)
+
+        key = jax.lax.fori_loop(0, 32, round_,
+                                jnp.zeros(sc.shape[:-1], jnp.uint32))
+        least = jnp.maximum(_score_at(key), 0.5 * _NEG_INF)[..., None]
+        tie = sc == least
+        return (least[..., 0], jnp.sum(sc > least, axis=-1, dtype=jnp.int32),
+                jnp.sum(tie, axis=-1, dtype=jnp.int32),
+                jnp.argmax(tie, axis=-1).astype(jnp.int32))
+
+    if 4 * k >= top:
+        return edge(scores[..., :top])
+    return jax.lax.cond(live > 4 * k, lambda: edge(scores[..., :top]),
+                        lambda: edge(scores[..., :4 * k]))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "handed", "d_v", "scale"))
+def _fetch_best(q, pool, layer, block_tables, kv_len, scores, at, *, k: int,
+                handed: bool, d_v: int, scale: float):
+    """The k best by the sort (`select_rows`: here and nowhere else), their
+    rows fetched (`_selected_latent_attention`); with `handed` also the
+    positions, in the sort's order.  Jitted: a chunk holds it on both
+    sides of the platform's branch and at two call sites, one trace of
+    its five tiers of sorts for all of them."""
+    with jax.named_scope("dsa_select"):
+        live = jnp.max(kv_len)
+        rows, seen, _ = select_rows(scores, k, live, at)
+        positions = select_positions(scores, k, live) if handed else None
+    return _selected_latent_attention(q, pool, layer, rows, seen, d_v=d_v,
+                                      scale=scale), positions
+
+
+def _attend_masked(q, pool, layer, block_tables, kv_len, scores, at, *,
+                   k: int, handed: bool, d_v: int, scale: float):
+    """`_masked_latent_kernel` under the selection as a threshold, where
+    that is an exact top-k, else the fetch.  **A launch that reads the
+    mask executes no sort**: `_edge_of_best` finds each row's k-th best
+    score, `least`; every position above it is in the set, and of the
+    `equal` that tie with it the set keeps `kept`, enough to make k.  So
+    the mask is the set where a row keeps every such position, or one
+    alone: the lowest, which the kernel finds among them by its row of
+    the pool.  A launch in which some row keeps two or more and leaves one
+    out takes the fetch, sort and all inside that branch (`_fetch_best`),
+    as does one whose longest lane passes `_MASKED_LIVE_MAX` and one whose
+    counts do not bear the search out (a score that is no number).  With
+    `handed` the positions come back too: those the mask kept, or the
+    fetch's."""
+    live, k = jnp.max(kv_len), min(k, scores.shape[-1])
+    with jax.named_scope("dsa_select"):
+        least, above, equal, first = _edge_of_best(
+            scores, k, live, reach=_MASKED_LIVE_MAX)
+        # a row that sees under k keeps all it sees: none at its `least`
+        kept = jnp.where(least > 0.5 * _NEG_INF, k - above, 0)
+        every = equal == kept
+        last = jnp.where(every, -1, jnp.take_along_axis(at, first, axis=1))
+        exact = jnp.all(every | ((kept == 1) & (equal > 0)))
+
+    def mask():
+        out = _masked_latent_kernel(q, pool, layer, block_tables, kv_len,
+                                    scores, least, last, d_v=d_v, scale=scale)
+        if not handed:
+            return out, None
+        bar = least[..., None]
+        keep = (scores > bar) | ((scores == bar) & (
+            (last[..., None] < 0) | (at[:, None, :] == last[..., None])))
+        # the kept positions (a set: the order is the sort's), behind them
+        # for a row that sees under k positions it does not see
+        return out, select_positions(jnp.where(keep, 0.0, _NEG_INF), k, live)
+
     return jax.lax.cond(
-        (jnp.max(kv_len) <= _MASKED_LIVE_MAX) & jnp.all(every | (kept == 1)),
-        lambda: _masked_latent_kernel(
-            q, pool, layer, block_tables, kv_len, scores, least,
-            jnp.where(every, -1, rows[..., -1]), d_v=d_v, scale=scale),
-        lambda: _selected_latent_attention(q, pool, layer, rows, seen,
-                                           d_v=d_v, scale=scale))
+        (live <= _MASKED_LIVE_MAX) & exact, mask,
+        lambda: _fetch_best(q, pool, layer, block_tables, kv_len, scores, at,
+                            k=k, handed=handed, d_v=d_v, scale=scale))
 
 
-def _attend_selected(q, pool, layer, block_tables, kv_len, rows, seen,
-                     least=None, scores=None, *, d_v: int, scale: float):
-    """`paged_latent_attention` over a selection: `select_rows`' rows and
-    seen, and where the caller has them its least and the index `scores`
-    (S, K, T) it chose by, in one of two forms that attend the same set.
-    **A chunk (K > 1) lowered for a TPU reads its lanes' live pages
-    whole, once a group of query rows, in one Pallas kernel a layer, the
-    selection a mask on its score tile** (`_masked_latent_kernel`, where
-    `_attend_masked` finds the mask to be the set).  A decode step, a
-    selection handed over without its scores, every other platform, a
-    pool split over a mesh and shapes that are not whole tiles (it says
-    so) **fetch** the selected rows into a dense buffer
-    (`_selected_latent_attention`).  The platform is the one the program
-    is lowered for (`jax.lax.platform_dependent`), as
-    `paged_latent_attention`'s."""
-    def fetch(q, pool, layer, block_tables, kv_len, rows, seen, *_):
-        return _selected_latent_attention(q, pool, layer, rows, seen,
-                                          d_v=d_v, scale=scale)
-
-    args = (q, pool, layer, block_tables, kv_len, rows, seen, least, scores)
-    if least is None or q.shape[1] == 1 \
-            or jax.typeof(pool).sharding.mesh.size > 1:
+def _attend_selected(q, pool, layer, block_tables, kv_len, scores, at,
+                     k: int, handed: bool = False, *, d_v: int,
+                     scale: float):
+    """`paged_latent_attention` over a learned selection: each query row
+    attends the `k` positions of its lane with the largest index `scores`
+    (S, K, T) float32 (an exact top-k: `_select` says why no other), `at`
+    (S, T) int32 each position's row in a layer's pool laid flat.  One
+    of two forms that attend the same function.  **A chunk (K > 1)
+    lowered for a TPU reads its lanes' live pages whole, once a group of
+    query rows, in one Pallas kernel a layer, the selection a threshold
+    on its score tile and nothing sorted** (`_attend_masked`, which falls
+    back where the threshold is not the set).  A decode step, every other
+    platform, a pool split over a mesh and shapes that are not whole
+    tiles (it says so) **sort, and fetch** the selected rows into a dense
+    buffer (`_fetch_best`).  The platform is the one the program is
+    lowered for (`jax.lax.platform_dependent`), as
+    `paged_latent_attention`'s.  Returns (out (S, K, H, d_v) float32, the
+    positions attended (S, K, k) int32 if `handed` else None: a set, in
+    the order of the form that read it)."""
+    how = dict(k=k, handed=handed, d_v=d_v, scale=scale)
+    fetch = functools.partial(_fetch_best, **how)
+    args = (q, pool, layer, block_tables, kv_len, scores, at)
+    if q.shape[1] == 1 or jax.typeof(pool).sharding.mesh.size > 1:
         return fetch(*args)
     why = _masked_takes(q.shape, pool.shape, pool.dtype, d_v)
     if why is not None:
@@ -1626,8 +1745,7 @@ def _attend_selected(q, pool, layer, block_tables, kv_len, rows, seen,
             f"takes 25 ms a layer whatever its context)", stacklevel=3)
         return fetch(*args)
     return jax.lax.platform_dependent(
-        *args, tpu=functools.partial(_attend_masked, d_v=d_v, scale=scale),
-        default=fetch)
+        *args, tpu=functools.partial(_attend_masked, **how), default=fetch)
 
 
 # Differential attention (arXiv:2410.05258): softmax(q1 k1^T / sqrt(D)) v

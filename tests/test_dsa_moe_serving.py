@@ -417,11 +417,49 @@ def test_tiers_and_spans_give_the_one_top_k(live, span, monkeypatch):
         np.asarray(rows_of)[:, None, :].repeat(3, 1), got, -1)).all()
 
 
+_SCORES = {
+    "rounded": lambda rng, shape: np.round(rng.normal(size=shape), 1),
+    "negative": lambda rng, shape: -1 - 1e3 * np.abs(rng.normal(size=shape)),
+    "all_equal": lambda rng, shape: np.full(shape, 0.25),
+    "zeros_of_both_signs": lambda rng, shape: rng.choice(
+        [-0.0, 0.0, -2.0, 3.0], size=shape),
+}
+
+
+@pytest.mark.parametrize("live", [5, 8, 9, 16, 17, 40, 64])
+@pytest.mark.parametrize("kind", list(_SCORES))
+def test_the_search_ends_on_the_sort_s_least_score(live, kind):
+    """`_edge_of_best`, the search that sorts nothing, over the tiers of
+    `test_tiers_and_spans_give_the_one_top_k` and rows that see 0 to
+    `live` positions: its least score is the sort's to the bit (halfway to
+    the mask's for a row that sees under 8), it stands in the row, the
+    counts around it say so (above < k <= above + equal), and `first` is
+    the lowest position that holds it."""
+    k = 8
+    scores = _SCORES[kind](np.random.default_rng(live), (2, 3, 64)) \
+        .astype(np.float32)
+    sees = np.maximum(live - 3 * np.arange(3), 0)[None, :, None]
+    scores[np.broadcast_to(np.arange(64) >= sees, scores.shape)] = -1e30
+    least, above, equal, first = map(np.asarray, attention._edge_of_best(
+        jnp.asarray(scores), k, jnp.int32(live)))
+    by_the_sort = attention.select_rows(
+        jnp.asarray(scores), k, None, jnp.zeros((2, 64), jnp.int32))[2]
+    assert (least == np.asarray(by_the_sort)).all()
+    assert (above == (scores > least[..., None]).sum(-1)).all()
+    assert (equal == (scores == least[..., None]).sum(-1)).all()
+    full = np.broadcast_to(sees[..., 0] >= k, least.shape)
+    assert ((above < k) & (k <= above + equal))[full].all()
+    assert (first == (scores == least[..., None]).argmax(-1))[full].all()
+    # a row that sees under k: everything it sees is above, nothing at it
+    assert (least[~full] == -5e29).all() and not equal[~full].any()
+    assert (above == np.minimum(sees[..., 0], 64))[~full].all()
+
+
 def test_the_selected_read_is_a_soft_max_over_exactly_the_set():
-    """`paged_latent_attention(selected=)` against a masked dense soft-max
-    over the same positions, with a set that holds positions the row does
-    not see, handed over as rows of the pool laid flat; a chunk wider
-    than `_SELECT_QUERY_ROWS` goes in groups."""
+    """The fetch (`_selected_latent_attention`) against a masked dense
+    soft-max over the same positions, with a set that holds positions the
+    row does not see, handed over as rows of the pool laid flat; a chunk
+    wider than `_SELECT_QUERY_ROWS` goes in groups."""
     rng = np.random.default_rng(2)
     bs, w, d_v, h, n_blocks, k = 8, 32, 24, 2, 20, 6
     pool = jnp.asarray(rng.normal(size=(2, n_blocks, bs, w)), jnp.float32)
@@ -446,9 +484,8 @@ def test_the_selected_read_is_a_soft_max_over_exactly_the_set():
         old, attention._SELECT_QUERY_ROWS = \
             attention._SELECT_QUERY_ROWS, group
         try:
-            got = attention.paged_latent_attention(
-                q, pool, 0, tables, positions, jnp.array([56]), d_v=d_v,
-                scale=0.3, selected=(rows, seen))
+            got = attention._selected_latent_attention(
+                q, pool, 0, rows, seen, d_v=d_v, scale=0.3)
         finally:
             attention._SELECT_QUERY_ROWS = old
         np.testing.assert_allclose(got[0], want, rtol=2e-5, atol=2e-5)
@@ -459,8 +496,8 @@ def _a_chunk_that_selects(dtype, lengths, k_w, scores_of, *, heads=4, w=128,
     """`lengths` lanes of `k_w` query rows at `tiny-dsa-moe`'s widths (4
     heads over rows of 128, 24 of them the value; 16 selected): a pool
     whose null block and last block both stand in a table, index scores
-    `scores_of(shape, key)` masked to the positions a row sees, and
-    `select_rows`' selection of them."""
+    `scores_of(shape, key)` masked to the positions a row sees, each
+    position's row of the pool, and `select_rows`' selection of them."""
     keys = jax.random.split(jax.random.key(seed), 4)
     lanes, width = len(lengths), entries * bs
     n_blocks = lanes * entries
@@ -480,7 +517,7 @@ def _a_chunk_that_selects(dtype, lengths, k_w, scores_of, *, heads=4, w=128,
         scores_of((lanes, k_w, width), keys[3]), attention._NEG_INF)
     at = jnp.repeat(tables, bs, axis=1) * bs + jnp.arange(width) % bs
     selection = attention.select_rows(scores, k, jnp.max(kv_len), at)
-    return (q, pool, 1, tables, kv_len), selection, scores
+    return (q, pool, 1, tables, kv_len), selection, (scores, at, k)
 
 
 def _ties(selection, scores):
@@ -489,6 +526,18 @@ def _ties(selection, scores):
     _, seen, least = selection
     kept = seen.sum(-1) - (scores > least[..., None]).sum(-1)
     return (scores == least[..., None]).sum(-1) == kept, kept == 1
+
+
+def _the_rule_s_set(scores, at, k):
+    """What the threshold path attends, written out: the k best scores of
+    a row (all it sees, if fewer), of equal scores the lower position.
+    Returns (rows (S, K, k) of the pool, seen, positions)."""
+    sc = np.asarray(scores)
+    order = np.argsort(-sc, axis=-1, kind="stable")[..., :k]
+    picked = np.take_along_axis(sc, order, -1)
+    rows = np.take_along_axis(
+        np.broadcast_to(np.asarray(at)[:, None, :], sc.shape), order, -1)
+    return jnp.asarray(rows), jnp.asarray(picked > -1e29), order
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -512,7 +561,7 @@ def test_the_masked_kernel_agrees_with_the_fetch(dtype, lengths, k_w,
 
     monkeypatch.setattr(attention, "_MASKED_QUERY_ROWS", 4)
     monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
-    lanes, (rows, seen, least), scores = _a_chunk_that_selects(
+    lanes, (rows, seen, least), (scores, _, _) = _a_chunk_that_selects(
         dtype, lengths, k_w, lambda shape, key: jax.random.normal(key, shape))
     want = attention._selected_latent_attention(
         *lanes[:3], rows, seen, d_v=24, scale=0.2)
@@ -535,54 +584,57 @@ def test_the_masked_kernel_agrees_with_the_fetch(dtype, lengths, k_w,
     (1, 0, True), (1, 3, False), (0, 4, False)])
 def test_equal_scores_at_a_set_s_edge_are_the_sort_s_to_settle(
         decimals, seed, every_row_settled, monkeypatch):
-    """Index scores rounded until positions tie with a set's last: the
-    mask is the sort's set where the set holds every such position or one
-    alone (the kernel finds it by its row of the pool), and a launch in
-    which a row keeps several and leaves one out takes the fetch, to the
-    bit."""
+    """Index scores rounded until positions tie with a set's last: where
+    every row keeps all of them or one alone the launch reads the mask,
+    and that is a fetch of the rule's set (of equal scores the lowest
+    position, found by its row of the pool); a launch in which a row
+    keeps several and leaves one out takes the fetch of the sort's set,
+    to the bit.  The positions handed over are the set that was read."""
     from jax.experimental.pallas import tpu as pltpu
 
     monkeypatch.setattr(attention, "_MASKED_QUERY_ROWS", 4)
     monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
-    lanes, selection, scores = _a_chunk_that_selects(
+    lanes, selection, best = _a_chunk_that_selects(
         jnp.float32, (48, 40), 6, seed=seed,
         scores_of=lambda shape, key: jnp.round(
             jax.random.normal(key, shape), decimals))
-    rows, seen, least = selection
+    scores, at, k = best
     every, one = _ties(selection, scores)
     assert not bool(every.all()) and bool((~every & one).any())
     assert bool((every | one).all()) == every_row_settled
-    want = attention._selected_latent_attention(
+    rows, seen, positions = _the_rule_s_set(*best)
+    by_the_rule = attention._selected_latent_attention(
         *lanes[:3], rows, seen, d_v=24, scale=0.2)
+    by_the_sort = attention._selected_latent_attention(
+        *lanes[:3], *selection[:2], d_v=24, scale=0.2)
     with pltpu.force_tpu_interpret_mode():
-        by_rule = attention._attend_masked(
-            *lanes, *selection, scores, d_v=24, scale=0.2)
-        kernel = attention._masked_latent_kernel(
-            *lanes, scores, least, jnp.where(every, -1, rows[..., -1]),
-            d_v=24, scale=0.2)
-    settled = np.asarray(every | one)
-    np.testing.assert_allclose(np.asarray(kernel)[settled],
-                               np.asarray(want)[settled], atol=2e-6)
+        got, handed = attention._attend_masked(
+            *lanes, scores, at, k=k, handed=True, d_v=24, scale=0.2)
     if every_row_settled:
-        np.testing.assert_allclose(by_rule, want, atol=2e-6)
+        np.testing.assert_allclose(got, by_the_rule, atol=2e-6)
+        # the sort took another of the tied positions in some row
+        assert float(jnp.abs(by_the_sort - by_the_rule).max()) > 1e-3
+        assert (np.sort(positions, -1) == np.sort(handed, -1)).all()
     else:
-        np.testing.assert_array_equal(by_rule, want)
-        assert float(jnp.abs(kernel - want).max()) > 1e-3
+        np.testing.assert_array_equal(got, by_the_sort)
+        assert (np.asarray(handed) == np.asarray(
+            attention.select_positions(scores, k, jnp.max(lanes[4])))).all()
 
 
 def test_a_lane_past_the_kernel_s_reach_takes_the_fetch(monkeypatch):
     from jax.experimental.pallas import tpu as pltpu
 
     monkeypatch.setattr(attention, "_MASKED_KERNEL_PAGES", 2)
-    lanes, selection, scores = _a_chunk_that_selects(
+    lanes, selection, best = _a_chunk_that_selects(
         jnp.float32, (40,), 8, lambda shape, key: jax.random.normal(key, shape))
     want = attention._selected_latent_attention(
         *lanes[:3], *selection[:2], d_v=24, scale=0.2)
     for reach, same in ((39, True), (40, False)):
         monkeypatch.setattr(attention, "_MASKED_LIVE_MAX", reach)
         with pltpu.force_tpu_interpret_mode():
-            got = attention._attend_masked(*lanes, *selection, scores,
-                                           d_v=24, scale=0.2)
+            got, handed = attention._attend_masked(
+                *lanes, *best[:2], k=best[2], handed=False, d_v=24, scale=0.2)
+        assert handed is None
         assert bool((got == want).all()) == same
         np.testing.assert_allclose(got, want, atol=2e-6)
 
@@ -603,37 +655,32 @@ def test_the_rule_that_picks_the_selected_read(q_shape, pool_shape, dtype,
                                                d_v, taken, recwarn):
     """Shapes alone decide (`_masked_takes`): rows, values, heads and
     pages in whole tiles of the pool's dtype.  What is refused says so
-    and fetches; a decode step and a selection handed over without its
-    scores fetch without a word."""
+    and fetches; a decode step fetches without a word."""
     assert (attention._masked_takes(q_shape, pool_shape, dtype, d_v)
             is None) == taken
     if max(q_shape + pool_shape) > 1024:
         return
-    k = 8
     q = jnp.zeros(q_shape, dtype)
     pool = jnp.zeros(pool_shape, dtype)
     lanes, k_w = q_shape[:2]
     tables = jnp.zeros((lanes, 2), jnp.int32)
-    rows = jnp.zeros((lanes, k_w, k), jnp.int32)
-    seen = jnp.ones((lanes, k_w, k), bool)
-    scores = jnp.zeros((lanes, k_w, 2 * pool_shape[2]))
+    at = jnp.zeros((lanes, 2 * pool_shape[2]), jnp.int32)
 
-    def lower(q, *selected):
+    def lower(q):
+        scores = jnp.zeros((lanes, q.shape[1], 2 * pool_shape[2]))
         return jax.jit(lambda q, pool: attention.paged_latent_attention(
             q, pool, 0, tables, jnp.zeros((lanes, q.shape[1]), jnp.int32),
             jnp.ones((lanes,), jnp.int32), d_v=d_v, scale=1.0,
-            selected=selected)).lower(q, pool)
+            selected=(scores, at, 8, False))[0]).lower(q, pool)
 
-    chunk = lower(q, rows, seen, jnp.zeros((lanes, k_w)), scores)
+    chunk = lower(q)
     said = [str(w.message) for w in recwarn.list
             if "fetches its selected rows" in str(w.message)]
     assert bool(said) == (not taken)
     # this host lowers for its CPU: the fetch, the kernel's branch nowhere
     assert "masked_latent_attention" not in chunk.as_text()
     recwarn.clear()
-    lower(q, rows, seen)
-    lower(q[:, :1], rows[:, :1], seen[:, :1], jnp.zeros((lanes, 1)),
-          scores[:, :1])
+    lower(q[:, :1])
     assert not [w for w in recwarn.list
                 if "fetches its selected rows" in str(w.message)]
 
@@ -744,8 +791,12 @@ def _selecting(pick):
             picked > -1e29, jnp.maximum(picked.min(axis=-1), -5e29))
 
     def fault(monkeypatch, cfg):
-        monkeypatch.setattr(mla_moe, "select_positions", pick)
-        monkeypatch.setattr(mla_moe, "select_rows", rows_of)
+        monkeypatch.setattr(attention, "select_positions", pick)
+        monkeypatch.setattr(attention, "select_rows", rows_of)
+        # the fetch as written, not the jitted one: an earlier test's
+        # trace of it holds the sound selection
+        monkeypatch.setattr(attention, "_fetch_best",
+                            attention._fetch_best.__wrapped__)
         return cfg
     return fault
 

@@ -874,8 +874,11 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     place (their bytes are aliased), the temporaries stay under 1 GB (the
     chunk's fetch, kept for what the kernel does not reach, takes its
     selected rows 128 query rows at a time: 0.34 GB a buffer, where all
-    512 at once would be 1.3 GB a layer; 0.98 GB in all with the index
-    scores kept for the kernel's mask, 0.91 before it; the burst 0.41), and the ops that read
+    512 at once would be 1.3 GB a layer; 0.986 GB in all with the index
+    scores kept for the kernel's mask, 0.91 before it; the search for a
+    set's least score, since PR 51, compares the scores where they lie and
+    keeps no second array of them: 0.980 -> 0.986; the burst 0.41), and
+    the ops that read
     index keys, selected rows and rings show the shapes the family's
     `index_operand`, `attn_operand`, `ring_operand` and `select_operand`
     say, so that the traced run's readers find them.  **The chunk's
@@ -883,9 +886,11 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     `tpu_custom_call` for layer 0 and one in the scan's body, each handed
     the pool laid flat, which is what `attn_operand` and `select_operand`
     look for, within the 600 characters of an op's text that a profile
-    keeps), under a branch whose other side is the fetch; the burst's is
-    the fetch alone."""
+    keeps), under a branch whose other side is the fetch **and, since
+    PR 51, the only sorts of index scores the chunk has**; the burst's is
+    the sort and the fetch alone."""
     import json
+    import re
 
     from bench.harness import spec
 
@@ -940,3 +945,12 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     if calls:
         assert "bf16[128,2048,640]" in text
         assert mem.temp_size_in_bytes > 0.3e9, mem.temp_size_in_bytes
+        # and every sort of index scores stands on that side of the branch
+        # (the platform's branch, then `_attend_masked`'s): a launch that
+        # reads the mask executes none
+        sorts = [line for line in text.splitlines()
+                 if re.search(r"\bsort\(", line) and "dsa_select" in line]
+        assert sorts and all(
+            re.search(r"dsa_attend/(cond/branch_\d_fun/){2}jit\(_fetch_best\)/"
+                      r"dsa_select", line)
+            for line in sorts), sorts[:2]
