@@ -193,6 +193,30 @@ LAGUNA_XS_2 = dataclasses.replace(
                      beta_slow=1.0, attention_factor=1.4158883083359672),
     max_seq_len=262144, param_dtype=jnp.bfloat16)
 
+# Generation by diffusion over blocks in the homogeneous stack, at test
+# size: blocks of 4 positions filled in 2 denoising passes and committed,
+# QK-norm, a head size the width does not give, 8 small experts top-4, no
+# shared expert; float32 so that a test holds it to the reference's tokens.
+TINY_BLOCK_DIFFUSION_MOE = TransformerConfig(
+    name="tiny-block-diffusion-moe", vocab_size=512, d_model=48, n_layers=3,
+    n_heads=4, n_kv_heads=2, d_head=16, d_ff=160, d_expert=24, n_experts=8,
+    expert_top_k=4, qk_norm=True, rope_theta=10000.0, norm_eps=1e-6,
+    max_seq_len=512, remat=False, param_dtype=jnp.float32,
+    compute_dtype=jnp.float32, diffusion_block=4, denoise_steps=2,
+    mask_token_id=500,
+)
+
+# SDAR-30B-A3B-Chat's published sizes (30.53 B parameters, ~3 B active a
+# token): 128 experts of width 768, top-8; `d_ff` (the config's
+# intermediate_size) is used by no layer.  Block length, passes and the
+# mask token are the family's released defaults, not keys of its config.
+SDAR_30B_A3B = dataclasses.replace(
+    TINY_BLOCK_DIFFUSION_MOE, name="sdar-30b-a3b", vocab_size=151936,
+    d_model=2048, n_layers=48, n_heads=32, n_kv_heads=4, d_head=128,
+    d_ff=6144, d_expert=768, n_experts=128, expert_top_k=8,
+    rope_theta=1000000.0, max_seq_len=32768, param_dtype=jnp.bfloat16,
+    compute_dtype=jnp.bfloat16, mask_token_id=151669)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
@@ -201,7 +225,8 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_MAMBA2_MOE, GRANITE4_H_SMALL,
                                 TINY_MLA_MOE, GLM_4_7_FLASH,
                                 TINY_DSA_MOE, DOTS3_NOTE_PREV,
-                                TINY_GATED_MOE, LAGUNA_XS_2]}
+                                TINY_GATED_MOE, LAGUNA_XS_2,
+                                TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B]}
 
 
 def get(name: str):

@@ -28,6 +28,15 @@ engine's slot instead of pool blocks (`ops.attention`, above
 `ring_rows`): the pool then holds the full layers alone, and a served
 call also takes the lanes' `slots`.
 
+A model that generates by diffusion over blocks
+(`TransformerConfig.diffusion_block` = B) runs the same body under
+another mask: a row sees every position up to the end of its own block of
+B (`sees`, below), a prompt is prefilled in whole blocks, and a decode
+call is `paged_denoise_burst`: for each block its lanes fill, passes of B
+rows a lane that rewrite the block's K / V in place, the last of them
+over the finished block.  (A pool block, or page, is `block_size`
+positions; a block of the model is B of them, and B divides a page.)
+
 Convention: pool block 0 is the NULL block.  The allocator never hands it
 out; unallocated table entries and inactive slots point at it, so every
 gather/scatter is in-bounds without conditionals.  Writes routed to block
@@ -372,7 +381,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     Write-then-read, in place: a full layer scatters the tokens' KV into
     the pool at [layer, table[pos // bs], pos % bs] first, so the attention
     that follows finds them there and its mask is simply kv_pos <= pos,
-    for the context and the in-call causal prefix alike; a window layer
+    for the context and the in-call causal prefix alike (for a model with
+    a `diffusion_block` B, kv_pos <= `sees` = pos // B * B + B - 1, the
+    last position of the row's block: the call carries whole blocks, whose
+    rows see each other both ways, and every earlier block); a window layer
     writes its slot's ring at [layer, slot, pos % R] and reads the ring.
     Pool and rings are the layer loop's carry, never its xs/ys: no slice
     of them is taken out or stacked back.  The loop runs over periods of
@@ -398,6 +410,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     # Whom an expert layer routes: a model that counts its choices, the
     # real rows (a chunk's padded tail is none); else the live lanes.
     routed_rows = positions < kv_len[:, None] if counted else live_lane
+    sees = None
+    if cfg.diffusion_block:
+        sees = positions // cfg.diffusion_block * cfg.diffusion_block \
+            + cfg.diffusion_block - 1
     if cfg.state_by_slot:
         ring_row = ring_rows(positions, kv_len, cache.wk.shape[2])
         lane = slots[:, None]
@@ -417,7 +433,8 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
                 v_pool = v_pool.at[at, wb, off].set(
                     v.astype(v_pool.dtype))
                 attn = paged_attention(q, k_pool, v_pool, at,
-                                       block_tables, positions, kv_len)
+                                       block_tables, positions, kv_len,
+                                       sees=sees)
         else:
             with jax.named_scope("swa"):
                 wk = wk.at[at, lane, ring_row].set(
@@ -549,6 +566,132 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
         length=n_steps)
     if counted:
         return cache, toks, rng, visited, routed
+    return cache, toks, rng, visited
+
+
+def _fills(cfg: TransformerConfig) -> list:
+    """Rows a block's denoising passes fill, pass by pass
+    (`low_confidence_static`): B // T each, the first B % T one more."""
+    b, t = cfg.diffusion_block, cfg.denoise_steps
+    return [b // t + (i < b % t) for i in range(t)]
+
+
+def denoise_select(logits: jax.Array, open_rows: jax.Array, n_fill,
+                   temps: jax.Array, rng: jax.Array):
+    """What one denoising pass fills.  logits (S, B, vocab) of a block's
+    rows (row i predicts the token at its own position), `open_rows`
+    (S, B) bool the rows that still hold the mask token, `n_fill` how
+    many of them a lane fills in this pass, `temps` (S,) as
+    `sample_per_slot` takes them.  Each row's candidate is its argmax (a
+    sample at its lane's temperature above 0) and its confidence the
+    candidate's probability under the row's soft-max (of the logits over
+    the temperature, where there is one); a lane fills its `n_fill` open
+    rows of highest confidence, of equal ones the lower position first,
+    all that are open where fewer are, never a row that is not open.
+    Returns (candidates (S, B) int32, the rows filled (S, B) bool)."""
+    s, b, v = logits.shape
+    x0 = sample_per_slot(logits.reshape(s * b, v), rng,
+                         jnp.repeat(temps, b)).reshape(s, b)
+    scaled = logits.astype(jnp.float32) \
+        / jnp.where(temps > 0.0, temps, 1.0)[:, None, None]
+    conf = jnp.exp(
+        jnp.take_along_axis(scaled, x0[..., None], axis=-1)[..., 0]
+        - jax.scipy.special.logsumexp(scaled, axis=-1))
+    conf = jnp.where(open_rows, conf, -1.0)
+    row = jnp.arange(b)
+    # before[s, i, j]: row j is filled before row i.
+    before = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (row[None, None, :] < row[None, :, None]))
+    return x0, open_rows & (before.sum(axis=-1) < n_fill)
+
+
+def _block_pass(params, cache, tokens, block_tables, lengths, active, cfg,
+                routing=False):
+    """One forward pass over the block each lane is filling: tokens (S, B)
+    at positions lengths .. lengths + B - 1 (`lengths` (S,): the lanes'
+    committed positions, whole blocks), over the lanes' earlier blocks and
+    the block's own rows.  It writes the block's K / V over whatever an
+    earlier pass left there.  Returns (cache, hidden (S, B, d), experts
+    visited, the routing if asked)."""
+    b = cfg.diffusion_block
+    positions = lengths[:, None] + jnp.arange(b, dtype=jnp.int32)
+    cache, x, visited, taken, _ = _paged_forward(
+        params, cache, tokens, block_tables, positions,
+        jnp.where(active, lengths + b, 0), cfg, None, routing)
+    return cache, x, visited, taken
+
+
+def paged_block_pass(params, cache: PagedKVCache, tokens, block_tables,
+                     lengths, active, cfg: TransformerConfig,
+                     routing: bool = False):
+    """The pass `paged_denoise_burst` scans, with its logits handed out (a
+    scoring entry's: the burst returns tokens, never logits): tokens
+    (S, B), rows that stand open holding `cfg.mask_token_id`, or a
+    finished block's own tokens (the commit).  Returns (cache, logits
+    (S, B, vocab)) and with `routing` the experts each row took,
+    (L, S, B, top_k)."""
+    cache, x, _, taken = _block_pass(params, cache, tokens, block_tables,
+                                     lengths, active, cfg, routing)
+    out = (cache, _final_logits(params, x, cfg))
+    return (*out, taken) if routing else out
+
+
+def paged_denoise_burst(params, cache: PagedKVCache, tokens, open_rows,
+                        block_tables, lengths, active, temps, rng,
+                        cfg: TransformerConfig, n_blocks: int):
+    """`n_blocks` blocks of `cfg.diffusion_block` = B positions for every
+    lane in one device call: what a decode burst is for a model that
+    generates by diffusion over blocks.  `lengths` (S,) are the lanes'
+    committed positions (whole blocks), `tokens` / `open_rows` (S, B) the
+    first block as it stands: a prompt's last len % B tokens as given rows
+    (not open) and `cfg.mask_token_id` in the open ones; every later block
+    starts all open.  The tables must cover lengths + n_blocks x B.
+
+    A block takes T = `cfg.denoise_steps` denoising passes and one commit
+    pass, every lane in lock step: a denoising pass runs the block's B
+    rows against the kept K / V of every earlier block and the block's own
+    rows (`_block_pass`), takes the head's logits and fills the most
+    confident open rows (`denoise_select`: `_fills` says how many); after
+    T passes no row is open, and the commit pass runs the finished block
+    and leaves its K / V in the lanes' pages (what the passes that saw
+    mask tokens wrote there is overwritten: write-then-read in place, as a
+    verify step's rejected tail is).  A block with fewer open rows is
+    finished earlier and its remaining passes repeat the commit, same
+    values.  The pool is the carry of both scans.  Returns (cache, tokens
+    (n_blocks, S, B), rng, experts visited: int32, summed over the passes
+    and the layers)."""
+    b = cfg.diffusion_block
+    fills = jnp.asarray(_fills(cfg), jnp.int32)
+
+    def block(carry, first):
+        cache, rng, lengths, visited = carry
+
+        def denoise(carry, n_fill):
+            cache, toks, still, rng, visited = carry
+            with jax.named_scope("denoise_pass"):
+                cache, x, n, _ = _block_pass(params, cache, toks,
+                                             block_tables, lengths, active,
+                                             cfg)
+            with jax.named_scope("denoise_select"):
+                rng, sub = jax.random.split(rng)
+                x0, fill = denoise_select(_final_logits(params, x, cfg),
+                                          still, n_fill, temps, sub)
+            return (cache, jnp.where(fill, x0, toks), still & ~fill, rng,
+                    visited + n), None
+
+        toks = jnp.where(first, tokens, cfg.mask_token_id)
+        (cache, toks, _, rng, visited), _ = jax.lax.scan(
+            denoise, (cache, toks, open_rows | ~first, rng, visited), fills)
+        with jax.named_scope("block_commit"):
+            cache, _, n, _ = _block_pass(params, cache, toks, block_tables,
+                                         lengths, active, cfg)
+        return (cache, rng, jnp.where(active, lengths + b, lengths),
+                visited + n), toks
+
+    (cache, rng, _, visited), toks = jax.lax.scan(
+        block, (cache, rng, lengths, jnp.int32(0)),
+        jnp.arange(n_blocks) == 0)
     return cache, toks, rng, visited
 
 
@@ -737,12 +880,19 @@ def make_paged_engine_fns(cfg: TransformerConfig, donate: bool = True):
     """Jitted (prefill_chunk, decode_burst, copy_block) with cache
     donation.  Chunk width C and table depth B_max ride in the argument
     shapes (one compile per distinct pair, same discipline as prefill
-    buckets); the burst takes a static n_steps."""
+    buckets); the burst takes a static n_steps.  The burst is of the
+    model's kind: `paged_denoise_burst`, with a static n_blocks, for one
+    that generates by diffusion over blocks."""
     chunk_jit = jax.jit(_bind_cfg(paged_prefill_chunk, cfg),
                         donate_argnums=(1,) if donate else ())
-    burst_jit = jax.jit(_bind_cfg(paged_decode_burst, cfg),
-                        static_argnames=("n_steps",),
-                        donate_argnums=(1,) if donate else ())
+    if getattr(cfg, "diffusion_block", 0):
+        burst_jit = jax.jit(_bind_cfg(paged_denoise_burst, cfg),
+                            static_argnames=("n_blocks",),
+                            donate_argnums=(1,) if donate else ())
+    else:
+        burst_jit = jax.jit(_bind_cfg(paged_decode_burst, cfg),
+                            static_argnames=("n_steps",),
+                            donate_argnums=(1,) if donate else ())
     copy_jit = jax.jit(copy_block, donate_argnums=(0,) if donate else ())
     return chunk_jit, burst_jit, copy_jit
 

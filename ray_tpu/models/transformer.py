@@ -124,6 +124,17 @@ class TransformerConfig:
     experts_held: Optional[Tuple[int, int]] = None
     expert_scoring: str = "softmax"
     route_scale: float = 1.0
+    # Generation by diffusion over blocks, served only (`models.decoding.
+    # paged_denoise_burst`); 0: a next-token model, what every other is.
+    # A position sees every position up to the end of its own block of
+    # `diffusion_block` (causal between blocks, both ways inside one), row
+    # i of the logits predicts the token at position i itself, and a
+    # sequence grows a block at a time: `denoise_steps` passes over a block
+    # whose open rows hold `mask_token_id`, each filling the most confident
+    # of them, then one pass that commits the finished block's K / V.
+    diffusion_block: int = 0
+    denoise_steps: int = 0
+    mask_token_id: int = 0
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)
@@ -145,6 +156,23 @@ class TransformerConfig:
         if self.n_experts <= 0 and (self.d_shared or self.experts_held):
             raise ValueError("a shared expert and a held share stand "
                              "beside routed experts: n_experts is 0")
+        block = self.diffusion_block
+        if block:
+            if not (block >= 2 and 1 <= self.denoise_steps <= block
+                    and 0 <= self.mask_token_id < self.vocab_size):
+                raise ValueError(
+                    f"diffusion_block {block}: a block is 2 rows or more "
+                    f"(`paged_attention` masks a launch of one row a lane "
+                    f"by its own position), denoise_steps "
+                    f"{self.denoise_steps} fills 1 to {block} rows a pass, "
+                    f"and mask_token_id {self.mask_token_id} is a row of "
+                    f"the {self.vocab_size}-row embedding")
+            if "window" in pattern + lead:
+                raise ValueError("a block's rows see each other both ways: "
+                                 "a window layer's ring has no such mask")
+        elif self.denoise_steps or self.mask_token_id:
+            raise ValueError("denoise_steps and mask_token_id come with a "
+                             "diffusion_block")
         self.moe                        # MoEConfig checks share and scoring
 
     @property
@@ -234,7 +262,8 @@ class TransformerConfig:
         return MoEConfig(num_experts=self.n_experts, top_k=self.expert_top_k,
                          capacity_factor=self.capacity_factor,
                          held=self.experts_held, scoring=self.expert_scoring,
-                         route_scale=self.route_scale)
+                         route_scale=self.route_scale,
+                         grouped_from_rows=16 if self.diffusion_block else 0)
 
     @property
     def num_params(self) -> int:
@@ -474,7 +503,8 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         ("n_heads_window", cfg.heads_by_kind), ("attn_gate", cfg.attn_gate),
         ("d_shared", cfg.d_shared), ("experts_held", cfg.experts_held),
         ("expert_scoring", cfg.expert_scoring != "softmax"),
-        ("route_scale", cfg.route_scale != 1.0)) if differs]
+        ("route_scale", cfg.route_scale != 1.0),
+        ("diffusion_block", cfg.diffusion_block)) if differs]
     if served_only:
         raise ValueError(
             f"{cfg.name!r} is a served model (`models.decoding`): the train "

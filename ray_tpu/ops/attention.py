@@ -627,20 +627,19 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
 
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
-                    scale=None):
+                    scale=None, sees=None):
     """Attention of `q` (S, K, H, D) over one layer of a paged KV pool.
-    `scale` multiplies the scores (None: D ** -0.5; a model that
-    publishes another, such as 1 / D, hands it over: folded into a
-    bfloat16 `q` it would round every query once more unless it is a
-    power of two).
+    `scale` multiplies the scores (None: D ** -0.5; a model that publishes
+    another, such as 1 / D, hands it over: folded into a bfloat16 `q` it
+    would round every query once more unless it is a power of two).
 
     `k_pool` / `v_pool` are the whole pool (L, N, block_size, Hkv, D), read
     at `[layer, block]` as stored: no slice of it is taken out and no copy in
     another dtype is made.  Lane s owns the blocks `block_tables[s]` (S, B)
-    in sequence order; its query i stands at absolute position
-    `positions[s, i]` and sees the kv positions <= that, which the caller
-    has already written.  `kv_len` (S,) is how many positions of each lane
-    are live (0: the lane is idle and its output is garbage nobody reads).
+    in sequence order; its query i stands at `positions[s, i]` and sees the
+    kv positions <= that, or <= `sees[s, i]` where given (the end of the
+    query's block, K >= 2: `models.decoding`), all written by the caller.
+    `kv_len` (S,): a lane's live positions (0: idle, its output garbage).
 
     Only live blocks are read, under a running soft-max, in one of two
     tilings.  **A decode step (one query row a lane) lowered for a TPU is
@@ -682,6 +681,7 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
         return _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables,
                                     kv_len, scale=scale)
 
+    positions = positions if sees is None else sees    # the loop's mask
     args = (q, k_pool, v_pool, layer, block_tables, positions, kv_len)
     if k_w != 1 or jax.typeof(k_pool).sharding.mesh.size > 1:
         # A pool split over a mesh (tensor-parallel serving) is a program

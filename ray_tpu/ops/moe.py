@@ -45,13 +45,13 @@ class MoEConfig:
     # experts alone.  None: all of them (`moe_mlp_dropless`).
     held: Optional[Tuple[int, int]] = None
     # How `moe_mlp_dropless` scores the experts.  "softmax": the top_k
-    # largest probabilities, renormalised.  "sigmoid": s = sigmoid(logit)
-    # a router output; the top_k largest of s + `router_bias` (a learned
-    # (E,) vector beside the router in the parameters, which selects and
-    # does not gate; of s alone where the parameters have none) are taken,
-    # gated by their own s renormalised and multiplied by `route_scale`.
+    # largest probabilities, renormalised.  "sigmoid": s = sigmoid(logit) a
+    # router output; the top_k largest of s + `router_bias` (a learned (E,)
+    # vector beside the router, which selects and does not gate; of s alone
+    # without one) are taken, gated by their own s x `route_scale`.
     scoring: str = "softmax"
     route_scale: float = 1.0
+    grouped_from_rows: int = 0   # `grouped_tile_rows`; 0: _GROUPED_FROM_ROWS
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
@@ -131,14 +131,14 @@ _GROUPED_FROM_PRODUCTS = 2048
 
 def grouped_tile_rows(n_rows: int, cfg: MoEConfig) -> int:
     """Rows a trip of the grouped form multiplies in a launch of `n_rows`
-    rows, 0 where the launch takes the visit: a choice of static shapes
-    alone.  Half as many again as the rows an expert sees if the router
-    spreads them evenly (n_rows x top_k / num_experts, the same for a
-    share of them), rounded up to a power of two from 16 (a bf16 tile's
-    sublanes) on, so that nearly every group is one trip: a second tile
-    of an expert reads its weights again."""
+    rows, 0 where the launch takes the visit: a choice of static shapes (and
+    of `cfg.grouped_from_rows`: below, above `moe_mlp_dropless`).  Half as
+    many again as the rows an expert sees if the router spreads them evenly
+    (n_rows x top_k / num_experts), rounded up to a power of two from 16 on,
+    so that nearly every group is one trip (a second tile reads again)."""
     held = cfg.held[1] if cfg.held else cfg.num_experts
-    if n_rows < _GROUPED_FROM_ROWS or n_rows * held < _GROUPED_FROM_PRODUCTS:
+    if n_rows < (cfg.grouped_from_rows or _GROUPED_FROM_ROWS) \
+            or n_rows * held < _GROUPED_FROM_PRODUCTS:
         return 0
     mean = -(-n_rows * cfg.top_k // cfg.num_experts)
     return max(16, 1 << (mean + mean // 2 - 1).bit_length())
@@ -569,6 +569,17 @@ def _grouped(rows, chosen, gate_vals, stacks, layer, *, tile: int):
 # it; the expert ops themselves 8.7 ms at Laguna's widths, 9.4 at
 # granite's) and the grid's steps that do nothing (`m // tile + e` tiles
 # are laid out, about half hold rows).
+#
+# **A model that fills blocks groups from 16 rows on**
+# (`MoEConfig.grouped_from_rows`, which `TransformerConfig.moe` sets for a
+# `diffusion_block` alone; PR 52): its decode launch is a pass of B
+# rows a lane, 32 rows at 8 lanes, which no program before it ran, so there
+# is no burst's text to keep; the table above has the kernel 8-15% ahead of
+# the visit at 32 rows; and the visit's trip is 12 device ops, of which a
+# pass over 128 experts ran 112 a layer: 57,000 device events a burst of
+# six passes, 2.7 M in the benchmark's 3 s profile, which then did not stop
+# inside the harness's 120 s (my chip run, PR 52, call 1).  Grouped, a
+# layer's experts are one kernel call.
 def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
                      live: "jnp.ndarray | None" = None, layer=None,
                      return_routing: bool = False,

@@ -41,7 +41,7 @@ class _Request:
                  "chunk_s", "chunk_tokens", "token_q", "dropped", "blocks",
                  "pos", "prefilling", "no_register", "trace",
                  "last_emit_wall", "ahead", "record", "decode_from",
-                 "decode_span", "lanes_sum", "bursts")
+                 "decode_span", "lanes_sum", "bursts", "given")
 
     def __init__(self, prompt, max_tokens, temperature, stream=False):
         from ray_tpu.core.config import get_config
@@ -96,6 +96,10 @@ class _Request:
         self.decode_from: Optional[List[float]] = None
         self.decode_span: Optional[tracing.Span] = None
         self.lanes_sum = self.bursts = 0
+        # A model that generates by diffusion over blocks: the tokens of
+        # the context behind its last whole block, which stand as given
+        # rows of the next block the request fills (`_begin_decode`).
+        self.given: List[int] = []
         # Resumed contexts embed generated tokens in `prompt` — never
         # publish them as a reusable prompt prefix.
         self.no_register = False
@@ -120,7 +124,7 @@ TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "routed_here",
                "kv_read_tokens", "reset_s", "experts_read", "ahead",
                "starved_s", "moe_tiles", "index_scored_tokens",
-               "kv_selected_tokens")
+               "kv_selected_tokens", "blocks", "passes", "block_tokens")
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -136,7 +140,8 @@ class _TickAccounts:
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
                  "routed_at", "experts_read", "ahead", "starved_s",
-                 "index_scored_tokens", "kv_selected_tokens")
+                 "index_scored_tokens", "kv_selected_tokens", "blocks",
+                 "passes", "block_tokens")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
@@ -144,6 +149,7 @@ class _TickAccounts:
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
         self.index_scored_tokens = self.kv_selected_tokens = 0
+        self.blocks = self.passes = self.block_tokens = 0
         # Where the tick's `routed_here` and `moe_tiles` are summed on the
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
@@ -154,7 +160,9 @@ class _Burst:
     """A decode burst that is launched and not yet read, and what its
     read needs: the token matrix and the count of experts visited (both
     still on the device), lane by lane the request, the tokens the host
-    counted for it at the launch and whether they are its last, the
+    counted for it at the launch, whether they are its last and how many
+    of the lane's tokens come before them (the given rows of a block: 0
+    for a next-token model), the
     launch's time and its number among the engine's launches, and the
     record of the tick that launched it, which enters the tick log at
     the read, when `experts_read` is known."""
@@ -372,7 +380,27 @@ class PagedLLMEngine:
     are what each would need.  `recurrent`: state is zeroed at admission
     and preemption as above.  `speculation_k >= 2` is refused for
     either: a rejected draft has advanced a recurrence, and has written
-    ring rows that no test yet shows are never seen.  A third, of a
+    ring rows that no test yet shows are never seen.
+
+    `diffusion_block` (B > 0: a model that generates by diffusion over
+    blocks, `models.decoding.paged_denoise_burst`): a step no longer
+    yields one token a sequence.  A prompt is prefilled in whole blocks
+    of B and nothing is sampled from it; its last len % B tokens stand
+    as given rows of the first block the request fills
+    (`_begin_decode`).  A decode tick launches, for every decoding lane,
+    `max_burst // B` blocks of `denoise_steps` + 1 forward passes each
+    and counts up to `max_burst` tokens a lane from them (fewer where
+    given rows or `max_tokens` cut a block), lengths are whole blocks
+    throughout, and **the first token comes from the first burst's
+    read**, where `ttft_s` ends.  The burst's inputs are the host's own
+    (given rows, else the mask token), so bursts run ahead as any
+    other's.  A registered prefix is the prompt's whole pages (a page
+    is whole blocks: `block_size % B == 0`) and carries no logits.
+    `speculation_k >= 2` (a draft is verified against next-token
+    logits, which this model has none of), a mesh, `export_streams` /
+    `import_prefix` and prefill offload are refused with the reason.
+
+    A third, of a
     model with experts: does it hold one rank's share of them
     (`models.decoding.counts_routed`)?  Its chunk and burst then hand
     out the top-k choices that fell on the share, and a tick's record
@@ -424,6 +452,28 @@ class PagedLLMEngine:
         self.eos_id = eos_id
         self.max_burst = max(1, max_burst if eos_id is None else
                              min(max_burst, 4))
+        # A model that generates by diffusion over blocks of `_block`
+        # positions (0: a next-token model): a burst is whole blocks, a
+        # launch's rows and a page whole blocks too, and what a forward
+        # pass is to a burst's count of experts visited differs
+        # (`_burst_passes`: a step; a block's passes).
+        self._block = int(getattr(cfg, "diffusion_block", 0))
+        self._burst_passes = self.max_burst
+        if self._block:
+            b = self._block
+            self.max_burst = max(b, self.max_burst // b * b)
+            self._burst_passes = (self.max_burst // b
+                                  * (cfg.denoise_steps + 1))
+            misfit = [n for n in [self.block_size, *self._chunk_tiers]
+                      if n % b]
+            if misfit:
+                raise ValueError(
+                    f"{cfg.name!r} fills blocks of {b} positions: a page "
+                    f"(block_size {self.block_size}) and every prefill "
+                    f"launch ({self._chunk_tiers}) must be whole blocks, "
+                    f"{misfit} are not; a registered prefix ends on a "
+                    f"page and a chunk may not split a block's rows, which "
+                    f"see each other")
         # A model whose sequences keep state by slot (window rings,
         # recurrent state), and whether some of it is recurrent.
         self._by_slot = bool(getattr(cfg, "state_by_slot", False))
@@ -451,6 +501,12 @@ class PagedLLMEngine:
         if speculation_ngram is None:
             speculation_ngram = knobs.serve_speculation_ngram
         self._spec_k = speculation_k if speculation_k >= 2 else 0
+        if self._block and self._spec_k:
+            raise ValueError(
+                f"speculation_k={speculation_k} with {cfg.name!r}: a draft "
+                f"is verified against the logits of the next token, and "
+                f"this model's row predicts the token at its own position; "
+                f"its blocks are filled by denoising passes instead")
         if self._by_slot and self._spec_k:
             raise ValueError(
                 f"speculation_k={speculation_k} with {cfg.name!r}: a "
@@ -495,6 +551,13 @@ class PagedLLMEngine:
                 raise ValueError(
                     f"{cfg.name!r} keeps {_slot_state(cfg)} by slot: it is "
                     f"served by the paged engine on one device (no mesh)")
+            if self._block:
+                raise ValueError(
+                    f"{cfg.name!r} generates by diffusion over blocks: its "
+                    f"burst, a scan of passes that rewrite a block's K / V "
+                    f"in place, has not been shown to agree under a `tp` "
+                    f"split of the pool; it is served on one device (no "
+                    f"mesh)")
             if getattr(cfg, "init_state", None) is not None:
                 raise ValueError(
                     f"{cfg.name!r} brings its own sequence state: the "
@@ -610,6 +673,7 @@ class PagedLLMEngine:
                       "disagg_prefills": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
                       "index_scored_tokens": 0, "kv_selected_tokens": 0,
+                      "blocks": 0, "passes": 0, "block_tokens": 0,
                       "state_resets": 0, "state_rebuilds": 0}
         self._request_phases: deque = deque(
             maxlen=self.REQUEST_PHASES_KEPT)
@@ -765,7 +829,8 @@ class PagedLLMEngine:
         for w in self._width_tiers:
             z = np.zeros((w,), np.int32)
             self._launch_burst(
-                [], w, z, np.zeros((w, self._b_max), np.int32), z,
+                [], w, self._burst_input([], w),
+                np.zeros((w, self._b_max), np.int32), z,
                 np.zeros((w,), bool), np.zeros((w,), np.float32))
             if self._spec_k:
                 self.cache, _, _, self._rng = self._verify(
@@ -838,13 +903,49 @@ class PagedLLMEngine:
             return {}
         return {"slots": self._jnp.asarray(self._lane_slots(idx, width))}
 
-    def _launch_burst(self, idx: List[int], width: int, host_tok, tables,
+    def _burst_input(self, idx: List[int], width: int):
+        """What a burst over the slots `idx` starts from, by lane, as the
+        host knows it.  A next-token model: `host_tok` (width,), a lane's
+        token where the host holds it (a first token, or every burst of
+        the request is read), else -1 (the device's vector has it).  A
+        model that fills blocks: (tokens (width, B), open rows (width, B)
+        bool) of each lane's first block, its request's given rows first
+        and the mask token in the open ones."""
+        if not self._block:
+            host_tok = np.full((width,), -1, np.int32)
+            for j, i in enumerate(idx):
+                if not self._slots[i].ahead:
+                    host_tok[j] = self._last_tokens[i]
+            return host_tok
+        toks = np.full((width, self._block), self.cfg.mask_token_id,
+                       np.int32)
+        still = np.ones((width, self._block), bool)
+        for j, i in enumerate(idx):
+            given = self._slots[i].given
+            toks[j, :len(given)] = given
+            still[j, :len(given)] = False
+        return toks, still
+
+    def _launch_burst(self, idx: List[int], width: int, first, tables,
                       lengths, active, temps):
-        """Three launches and no read: the gather of the lanes' input
-        tokens (`host_tok[j]` where it is >= 0, else the last token slot
-        idx[j] sampled), the burst, the scatter of its last row back by
-        slot.  Returns (token matrix, experts visited), on the device."""
+        """The burst of the model's kind over `first` (`_burst_input`),
+        and no read.  A next-token model, three launches: the gather of
+        the lanes' input tokens (`first[j]` where it is >= 0, else the
+        last token slot idx[j] sampled), the burst, the scatter of its
+        last row back by slot.  A model that fills blocks, one: every
+        input is the host's.  Returns (token matrix, experts visited), on
+        the device."""
         jnp = self._jnp
+        if self._block:
+            self._mark_launch()
+            self.cache, tok_mat, self._rng, visited = self._decode(
+                self.params, self.cache, jnp.asarray(first[0]),
+                jnp.asarray(first[1]), jnp.asarray(tables),
+                jnp.asarray(lengths), jnp.asarray(active),
+                jnp.asarray(temps), self._rng,
+                n_blocks=self.max_burst // self._block)
+            return tok_mat, visited
+        host_tok = first
         slots = jnp.asarray(self._lane_slots(idx, width))
         self._mark_launch()
         self.cache, tok_mat, self._rng, visited, *routed = self._decode(
@@ -945,8 +1046,16 @@ class PagedLLMEngine:
             return False
         bs = self.block_size
         n = len(req.prompt)
-        shared, covered, meta = self.allocator.lookup_prefix(req.prompt)
-        if covered == n and meta is None and shared:
+        if self._block:
+            # What is prefilled is the prompt's whole blocks, and what is
+            # shared its whole pages: a hit never carries logits.
+            fill = n // self._block * self._block
+            shared, covered, meta = self.allocator.lookup_prefix(
+                req.prompt[:n // bs * bs])
+        else:
+            fill = n
+            shared, covered, meta = self.allocator.lookup_prefix(req.prompt)
+        if not self._block and covered == n and meta is None and shared:
             # Whole-prompt chain without stored logits (evicted): fall
             # back to re-prefilling the tail chunk.
             self.allocator.free(shared[-1:])
@@ -979,6 +1088,13 @@ class PagedLLMEngine:
         self._reset_slot_state(req, slot)
         if covered > 0:
             self.stats["prefix_hits"] += 1
+        if self._block and covered == fill:
+            # Nothing to prefill: the prompt's whole blocks are shared
+            # pages, or it has none.  Its tail opens the first block.
+            if covered == 0:
+                self.stats["prefix_misses"] += 1
+            self._begin_decode(req, None)
+            return True
         if covered == n:
             # Whole-prompt hit: sample the first token from the stored
             # last-logits under THIS request's temperature — no prompt
@@ -1019,10 +1135,22 @@ class PagedLLMEngine:
             req.blocks[-1] = new
             self._table_row(req.slot, req.blocks)
 
-    def _begin_decode(self, req: "_Request", first_tok: int) -> None:
+    def _begin_decode(self, req: "_Request", first_tok: Optional[int]
+                      ) -> None:
         # KV written so far = the prefilled context (a preempted request
         # re-enters here with out_tokens already emitted).
         n_ctx = len(req.prompt) + len(req.out_tokens)
+        if self._block:
+            # A model that fills blocks: the context's whole blocks are
+            # in, its tail stands as given rows of the block the request
+            # fills first, and nothing has been sampled: the first token
+            # comes from the first burst's read (`_harvest`).
+            whole = n_ctx // self._block * self._block
+            req.given = (req.prompt + req.out_tokens)[whole:]
+            req.prefilling = False
+            self._lengths[req.slot] = whole
+            self._maybe_finish(req.slot)
+            return
         if req.first_token_at is None:
             self._obs_first_token(req)
         req.prefilling = False
@@ -1105,6 +1233,10 @@ class PagedLLMEngine:
                         break        # wait for completions to free blocks
                     req.blocks = alloc
                     self._table_row(slot, req.blocks)
+                if self._block:
+                    # The pages cover the whole context; what is prefilled
+                    # is its whole blocks (the tail: `_begin_decode`).
+                    n = n // self._block * self._block
                 nv = min(budget, n - req.pos)
                 c = self._tier_for(self._chunk_tiers, nv)
                 nv = min(nv, c)
@@ -1134,6 +1266,16 @@ class PagedLLMEngine:
                     self._prefillq.popleft()
                     if self._recurrent and req.out_tokens:
                         self.stats["state_rebuilds"] += 1
+                    if self._block:
+                        # The prompt's whole pages, without logits: a
+                        # block's passes write only behind them.
+                        if not req.out_tokens and not req.no_register:
+                            bs = self.block_size
+                            self.allocator.register_prefix(
+                                req.prompt[:len(req.prompt) // bs * bs],
+                                req.blocks)
+                        self._begin_decode(req, None)
+                        continue
                     if not req.out_tokens and not req.no_register:
                         # Publish the prompt's blocks for prefix reuse
                         # BEFORE our own appends diverge the tail (COW
@@ -1178,7 +1320,13 @@ class PagedLLMEngine:
 
     def _decode_tick(self) -> bool:
         """Launch the next burst over the decoding slots, then read the
-        one launched a tick ago (see the class docstring)."""
+        one launched a tick ago (see the class docstring).  For a model
+        that generates by diffusion over blocks the burst is
+        `max_burst // B` blocks a lane, each `denoise_steps` + 1 forward
+        passes of B rows: a lane's length moves by `max_burst` positions
+        as any other's, and it is counted `max_burst` tokens less the
+        given rows of its first block (the prompt's tail) and whatever
+        `max_tokens` cuts off its last."""
         self._clock.enter(_BURST_LAUNCH)
         burst = self.max_burst
         # One tick advances either a burst (burst tokens of KV) or a
@@ -1225,18 +1373,13 @@ class PagedLLMEngine:
             self._acct.lanes, self._acct.width = len(idx), w
             self._acct.kv_read_tokens = self._kv_read_tokens(
                 [int(self._lengths[i]) for i in idx])
-            # A lane's token from the host where the host holds it (a
-            # first token, or every burst of the request is read), else
-            # (-1) from the device's vector.
-            host_tok = np.full((w,), -1, np.int32)
+            first = self._burst_input(idx, w)
             tables = np.zeros((w, self._b_max), np.int32)
             lengths = np.zeros((w,), np.int32)
             active = np.zeros((w,), bool)
             temps = np.zeros((w,), np.float32)
             for j, i in enumerate(idx):
                 req = self._slots[i]
-                if not req.ahead:
-                    host_tok[j] = self._last_tokens[i]
                 tables[j] = self._tables[i]
                 lengths[j] = self._lengths[i]
                 active[j] = True
@@ -1250,7 +1393,7 @@ class PagedLLMEngine:
                 (int(n), burst) for n in lengths[:len(idx)])
             t0 = time.time()
             tok_mat, visited = self._launch_burst(
-                idx, w, host_tok, tables, lengths, active, temps)
+                idx, w, first, tables, lengths, active, temps)
             self._acct.decode_s = time.time() - t0
             # The host's books move at the launch.  A request whose
             # last token is in this burst leaves its slot now: the next
@@ -1260,14 +1403,24 @@ class PagedLLMEngine:
             for i in idx:
                 req = self._slots[i]
                 self._lengths[i] += burst   # KV written for every step
-                n = min(burst, req.max_tokens - len(req.out_tokens)
+                # Given rows come first among a lane's tokens and are not
+                # the request's to emit.
+                skip, req.given = len(req.given), []
+                n = min(burst - skip, req.max_tokens - len(req.out_tokens)
                         - req.ahead)
                 req.ahead += n
                 last = self._ends_at(req, len(req.out_tokens) + req.ahead)
                 if last:
                     self._slots[i] = None
                     self._tables[i, :] = 0
-                lanes.append((req, n, last))
+                lanes.append((req, n, last, skip))
+            if self._block:
+                acct = self._acct
+                acct.blocks = len(idx) * burst // self._block
+                acct.passes = self._burst_passes
+                acct.block_tokens = sum(lane[1] for lane in lanes)
+                for k in ("blocks", "passes", "block_tokens"):
+                    self.stats[k] += getattr(acct, k)
             self._inflight = _Burst(
                 tok_mat, visited if self._expert_layers else None, lanes,
                 t0, self._launches)
@@ -1288,9 +1441,14 @@ class PagedLLMEngine:
             # Tokens and count, both copies started before either is
             # waited for: a second read after the first costs 0.6 ms.
             tok_mat, visited = self._jax.device_get((b.tok_mat, b.visited))
-            experts = int(visited) / (self.max_burst * self._expert_layers)
+            experts = int(visited) / (self._burst_passes
+                                      * self._expert_layers)
         else:
             tok_mat, experts = np.asarray(b.tok_mat), 0.0   # (burst, w)
+        # By lane, in the order of the positions: (w, burst), from a
+        # matrix by step, or by block and row (blocks, w, B).
+        tok_mat = tok_mat.T if tok_mat.ndim == 2 else \
+            tok_mat.transpose(1, 0, 2).reshape(tok_mat.shape[1], -1)
         t1 = self._clock.enter(_EMIT)
         if b.seq == self._launches and self._clock.ticking:
             self._idle_from = t1    # nothing was launched behind it
@@ -1303,15 +1461,19 @@ class PagedLLMEngine:
         # The burst's turn on the device began when the one before it
         # was read, if that was after its launch.
         began, self._read_at = max(b.t0, self._read_at), t1
-        for j, (req, n, last) in enumerate(b.lanes):
+        for j, (req, n, last, skip) in enumerate(b.lanes):
             if req.done.is_set():
                 continue    # ended at an earlier read: EOS, a dropped
                 #             stream; these are the burst ahead's tokens
+            if req.first_token_at is None:
+                # A model that fills blocks samples nothing at the end of
+                # its prefill: its first token is this read's.
+                self._obs_first_token(req)
             req.ahead -= n
             req.lanes_sum += len(b.lanes)
             req.bursts += 1
             n0, eos = len(req.out_tokens), False
-            for tok in tok_mat[:n, j].tolist():
+            for tok in tok_mat[j, skip:skip + n].tolist():
                 req.emit(tok)
                 self.stats["tokens_generated"] += 1
                 if self.eos_id is not None and tok == self.eos_id:
@@ -1555,6 +1717,14 @@ class PagedLLMEngine:
         layer), and the positions those rows then attended (at most
         `index_top_k` each): the host's count from the lengths, as
         `kv_read_tokens`.
+        `blocks`, `passes`, `block_tokens` (the record's last), from a
+        model that generates by diffusion over blocks (0 from any other):
+        the blocks the burst's lanes filled (lanes x `max_burst` // B),
+        the forward passes the burst ran, each over every lane (blocks a
+        lane x (`denoise_steps` + 1)), and the tokens counted for its
+        lanes at the launch: under blocks x B where a prompt's tail or
+        `max_tokens` cuts a block.  For such a model `experts_read` is per
+        layer and pass.
 
         The tick runs on the phase clock (`_PhaseClock`): it starts in
         `admit`, its parts switch the leaf as they go, and it ends in
@@ -1588,7 +1758,8 @@ class PagedLLMEngine:
                        else 0,
                        acct.kv_read_tokens, acct.reset_s,
                        acct.experts_read, acct.ahead, acct.starved_s, 0,
-                       acct.index_scored_tokens, acct.kv_selected_tokens]
+                       acct.index_scored_tokens, acct.kv_selected_tokens,
+                       acct.blocks, acct.passes, acct.block_tokens]
                 b = self._inflight
                 if b is not None and b.row is None:
                     b.row = row     # this tick's burst: logged at its read
@@ -1608,7 +1779,39 @@ class PagedLLMEngine:
                 self._work.clear()
 
     # -- scoring -----------------------------------------------------------
-    def score(self, seqs, n_prompt: int, routing: bool = False):
+    def _score_blocks(self, seqs, n_prompt: int, open_rows, on_device,
+                      route_kw, got, taken, noised) -> None:
+        """`score`'s half for a model that fills blocks: every block of
+        `seqs` behind `n_prompt` twice through `_score_step`
+        (`paged_block_pass`), all lanes a pass: with its open rows holding
+        the mask token (its logits to `got`, its experts to `noised`), then
+        committed with its own tokens (its experts to `taken`).
+        `on_device`: the lanes' tables and which of them are live."""
+        jnp, b = self._jnp, self._block
+        lanes, total = seqs.shape
+        tables, active = on_device
+        w = tables.shape[0]
+        for i in range(n_prompt, total, b):
+            still = np.ones((lanes, b), bool) if open_rows is None \
+                else np.asarray(open_rows)[:, (i - n_prompt) // b]
+            block = np.zeros((2, w, b), np.int32)
+            block[:, :lanes] = seqs[:, i:i + b]
+            block[0, :lanes][still] = self.cfg.mask_token_id
+            at = jnp.where(active, i, 0).astype(jnp.int32)
+            for toks, kept in zip(block, (noised, taken)):
+                self.cache, logits, *route = self._score_step(
+                    self.params, self.cache, jnp.asarray(toks), tables, at,
+                    active, **route_kw)
+                if kept is noised:
+                    for lane in range(lanes):
+                        got[lane].extend(logits[lane])
+                if route:                  # (L, w, B, k) -> (w, B, L, k)
+                    route = np.asarray(route[0]).transpose(1, 2, 0, 3)
+                    for lane in range(lanes):
+                        kept[lane].append(route[lane])
+
+    def score(self, seqs, n_prompt: int, routing: bool = False,
+              open_rows=None):
         """Logits by the engine's own programs, for a comparison with a
         reference.  Each row of `seqs` (lanes, n_prompt + steps) gets a
         slot and blocks of its own: its first `n_prompt` tokens are
@@ -1628,12 +1831,27 @@ class PagedLLMEngine:
         the positions its full layers attended, (n_prompt + steps, full
         layers, index_top_k)), through the same two programs compiled to
         hand them out.  The engine must be idle;
-        its state is left as after requests that finished."""
+        its state is left as after requests that finished.
+
+        A model that generates by diffusion over blocks of B: `n_prompt`
+        and the rest are whole blocks; the first `n_prompt` tokens are
+        prefilled as above, and every later block goes twice through the
+        pass the burst scans (`paged_block_pass`), all lanes a pass: once
+        with the rows `open_rows[lane, block]` ((lanes, blocks, B) bool;
+        None: all) holding the mask token, whose logits are what is
+        returned, then with its own tokens, the commit that leaves its
+        K / V.  Returns per lane the logits of positions n_prompt .. the
+        last (row i predicts position i itself), B arrays of (V,) a block,
+        and with `routing` per lane a dict: "clean", the experts taken at
+        every position by the prefill and the commits, (total, L, top_k),
+        and "noised", those the passes over open rows took, (total -
+        n_prompt, L, top_k)."""
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.models.decoding import (
-            _bind_cfg, paged_decode_step, paged_prefill_chunk)
+            _bind_cfg, paged_block_pass, paged_decode_step,
+            paged_prefill_chunk)
 
         seqs = np.asarray(seqs)
         lanes, total = seqs.shape
@@ -1641,9 +1859,15 @@ class PagedLLMEngine:
             raise ValueError(f"score(): {lanes} lanes of {total} tokens do "
                              f"not fit {self.num_slots} slots of "
                              f"{self.max_len}")
+        b = self._block
+        if b and (n_prompt % b or total % b):
+            raise ValueError(f"score(): {self.cfg.name!r} fills blocks of "
+                             f"{b}: a prompt of {n_prompt} and {total} "
+                             f"tokens in all are not whole blocks")
         if self._score_step is None:
             self._score_step = jax.jit(
-                _bind_cfg(paged_decode_step, self.cfg), donate_argnums=(1,),
+                _bind_cfg(paged_block_pass if b else paged_decode_step,
+                          self.cfg), donate_argnums=(1,),
                 static_argnames=("routing",))
         chunk_fn, route_kw = self._prefill_chunk_fn, {}
         if routing:
@@ -1688,28 +1912,39 @@ class PagedLLMEngine:
                             taken[lane].append(jax.tree.map(
                                 lambda a: np.asarray(a).swapaxes(0, 1)[:nv],
                                 route[0]))
-                    got[lane].append(last)         # position n_prompt - 1
+                    if not b:
+                        got[lane].append(last)     # position n_prompt - 1
                 active = np.arange(w) < lanes
                 on_device = (jnp.asarray(tables), jnp.asarray(active))
                 lanes_kw = self._lanes_kw(list(range(lanes)), w)
-                for i in range(n_prompt, total):
-                    tok = np.zeros((w,), np.int32)
-                    tok[:lanes] = seqs[:, i]
-                    self.cache, logits, *route = self._score_step(
-                        self.params, self.cache, jnp.asarray(tok),
-                        on_device[0],
-                        jnp.asarray(np.where(active, i, 0).astype(np.int32)),
-                        on_device[1], **lanes_kw, **route_kw)
-                    if routing:                # (L, w, k) -> (w, L, k)
-                        route = jax.tree.map(
-                            lambda a: np.asarray(a).swapaxes(0, 1), route[0])
-                    for lane in range(lanes):
-                        got[lane].append(logits[lane])     # position i
-                        if routing:
-                            taken[lane].append(jax.tree.map(
-                                lambda a: a[lane][None], route))
+                noised: List[List[Any]] = [[] for _ in range(lanes)]
+                if b:
+                    self._score_blocks(seqs, n_prompt, open_rows, on_device,
+                                       route_kw, got, taken, noised)
+                else:
+                    for i in range(n_prompt, total):
+                        tok = np.zeros((w,), np.int32)
+                        tok[:lanes] = seqs[:, i]
+                        at = np.where(active, i, 0).astype(np.int32)
+                        self.cache, logits, *route = self._score_step(
+                            self.params, self.cache, jnp.asarray(tok),
+                            on_device[0], jnp.asarray(at), on_device[1],
+                            **lanes_kw, **route_kw)
+                        if routing:                # (L, w, k) -> (w, L, k)
+                            route = jax.tree.map(
+                                lambda a: np.asarray(a).swapaxes(0, 1),
+                                route[0])
+                        for lane in range(lanes):
+                            got[lane].append(logits[lane])     # position i
+                            if routing:
+                                taken[lane].append(jax.tree.map(
+                                    lambda a: a[lane][None], route))
             finally:
                 self.allocator.free(blocks)
+        if routing and b:
+            return got, [{"clean": np.concatenate(t),
+                          "noised": np.concatenate(n)}
+                         for t, n in zip(taken, noised)]
         if routing:
             return got, [jax.tree.map(lambda *a: np.concatenate(a), *t)
                          for t in taken]
@@ -1717,6 +1952,13 @@ class PagedLLMEngine:
 
     # -- disaggregated serving / live migration -------------------------
     def _refuse_if_by_slot(self, what: str) -> None:
+        if self._block:
+            raise ValueError(
+                f"{what} with {self.cfg.name!r}: it generates by diffusion "
+                f"over blocks; a frame ends with a partial page and the "
+                f"last token's logits, of which this model samples "
+                f"nothing, and no test yet holds a shipped stream of it "
+                f"to a local one")
         if self._by_slot:
             raise ValueError(
                 f"{what} with {self.cfg.name!r}: its sequences keep "
@@ -2022,6 +2264,15 @@ class LLMDeployment:
         cfg = (configs.get(cfg_name) if isinstance(cfg_name, str)
                else cfg_name)
         by_slot = bool(getattr(cfg, "state_by_slot", False))
+        by_block = bool(getattr(cfg, "diffusion_block", 0))
+        if by_block and (tensor_parallel > 1 or disagg):
+            raise ValueError(
+                f"{cfg.name!r} generates by diffusion over blocks: it is "
+                f"served by the paged engine on one device, without "
+                f"disaggregated prefill (a prefill actor's frame carries "
+                f"a causal prompt's tail page and last logits; this "
+                f"model's prompt is prefilled in whole blocks and sampled "
+                f"from nowhere)")
         if by_slot and (tensor_parallel > 1 or disagg):
             raise ValueError(
                 f"{cfg.name!r} keeps {_slot_state(cfg)} by slot: it is "
@@ -2070,7 +2321,8 @@ class LLMDeployment:
         from ray_tpu.core.config import get_config
 
         if disagg is None:
-            disagg = get_config().serve_disagg_enabled and not by_slot
+            disagg = get_config().serve_disagg_enabled \
+                and not (by_slot or by_block)
         self._disagg = None
         self.disagg_role = "unified"
         # Prefill actors re-derive weights from (cfg, seed); a custom

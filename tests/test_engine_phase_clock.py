@@ -378,9 +378,11 @@ def test_starved_seconds_count_an_empty_queue_with_work_in_hand():
 # -- (e) the shape of the records --------------------------------------------
 def test_the_tick_fields_end_with_ahead_and_starved_seconds():
     # and, behind them since PR 46, the tiles a tick's chunks multiplied,
-    # since PR 49 what a learned selection scored and attended
-    assert TICK_FIELDS[-5:] == ("ahead", "starved_s", "moe_tiles",
-                                "index_scored_tokens", "kv_selected_tokens")
+    # since PR 49 what a learned selection scored and attended, since
+    # PR 52 what a burst of denoising passes filled
+    assert TICK_FIELDS[-8:] == ("ahead", "starved_s", "moe_tiles",
+                                "index_scored_tokens", "kv_selected_tokens",
+                                "blocks", "passes", "block_tokens")
     assert PHASES == ("wait", "admit", "burst_launch", "burst_read", "emit",
                       "chunk_launch", "first_read", "book")
     eng = _engine()

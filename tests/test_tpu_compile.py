@@ -954,3 +954,62 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
             re.search(r"dsa_attend/(cond/branch_\d_fun/){2}jit\(_fetch_best\)/"
                       r"dsa_select", line)
             for line in sorts), sorts[:2]
+
+
+@pytest.mark.parametrize("program", ["paged_denoise_burst",
+                                     "paged_prefill_chunk"])
+def test_block_diffusion_served_programs_fit_one_chip(topo, program):
+    """SDAR-30B-A3B-Chat at the benchmark's cut (6 of 48 layers, all 128
+    experts top-8, the whole 151,936-row vocabulary) and serving shape (8
+    slots x 4,096, block 16: a pool of 0.40 GB beside 8.72 GB of weights):
+    the width-8 burst of two blocks (a scan of two denoising passes and a
+    commit, a scan of those over the blocks) and the 512-row chunk under
+    the block mask compile for one v5e chip and fit its 15.75 GB usable.
+    The pool is updated in place through both scans (its bytes are
+    aliased), the temporaries stay under the pool's own size, **a pass of
+    32 rows groups its rows by expert as a chunk does**
+    (`MoEConfig.grouped_from_rows` = 16: a call of the tile kernel at each
+    of the burst's two call sites, the denoising scan's and the commit's,
+    the three stacks whole; no visit), a pass's attention is the block
+    loop (four rows a lane: no decode kernel), and the ops of the
+    selection show the vocabulary as the family's `select_operand` says."""
+    import json
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "sdar-30b-a3b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state, params = resident["sequence_state"], resident["params"]
+    assert state.k.shape == (6, 2049, 16, 4, 128)
+    assert params["blocks"]["w_gate"].shape == (6, 128, 2048, 768)
+    assert params["lm_head"].shape == (2048, 151936)
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
+    assert abs(resident_bytes - 9.125e9) < 0.01e9, resident_bytes
+    assert resident_bytes > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < state_bytes, mem.temp_size_in_bytes
+    # both programs read their experts as a chunk does
+    _assert_experts_read_in_place(text, fam.expert_operand(config),
+                                  "paged_prefill_chunk")
+    _assert_pool_read_by_the_kernel(text, state.k.shape, 0)
+    if program == "paged_denoise_burst":
+        assert len(_expert_readers(text, fam.expert_operand(config))[1]) == 2
+        assert fam.select_operand(config).search(text)
+        assert text.count("denoise_select") and text.count("block_commit")
