@@ -15,6 +15,8 @@ each own an engine; the pow-2 router spreads requests.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 import queue
 import threading
@@ -25,7 +27,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from ray_tpu.util import tracing
+from ray_tpu.util import compile_cache, tracing
 
 
 # Canonical home is the typed error tree (the wire-typed-errors lint
@@ -302,6 +304,45 @@ _CHUNK_TOP_ROWS = 512   # (i) past it the device's time a token stops falling
 _ROWS_A_STEP = 64       # (ii) prompt rows that cost no more than a decode step
 
 
+class _SetupSpans:
+    """A replica's start as span records (`tracing.Span.finish()`'s
+    shape, one `trace_id`), kept by its engine and read through
+    `engine_stats()["setup"]`: `serve.setup` (root: `LLMDeployment`'s
+    constructor) over `.device_init`, `.params`, `.engine_build`,
+    `.warmup` and, one a launch of `warmup()`, `.warmup.tier`.  An
+    engine built on its own has the last three and no root.  Where
+    serve tracing is on the records go to the tracing buffer as every
+    span does (`ray-tpu timeline` then shows the start beside the
+    requests); the kill switch silences that sink alone.  All on
+    `time.time()`, the compile log's clock (`util/compile_cache.py`)."""
+    SPANS_KEPT = 256        # a start is 5 spans and one a tier
+
+    def __init__(self):
+        self.trace_id = "setup-" + uuid.uuid4().hex[:12]
+        self.root: Optional[tracing.Span] = None
+        self.records: deque = deque(maxlen=self.SPANS_KEPT)
+
+    def open(self, name: str, parent: Optional[tracing.Span] = None,
+             **attrs) -> tracing.Span:
+        """A span that starts now, under `parent` or else the root."""
+        parent = parent or self.root
+        return tracing.Span(
+            name, self.trace_id,
+            parent.span_id if parent is not None else None, attrs)
+
+    def close(self, span: tracing.Span, **attrs) -> None:
+        span.attrs.update(attrs)
+        self.records.append(span.finish(buffered=tracing.serve_enabled()))
+
+    @contextlib.contextmanager
+    def span(self, name: str, parent: Optional[tracing.Span] = None,
+             **attrs):
+        """`open` and `close` around a block that completes."""
+        s = self.open(name, parent, **attrs)
+        yield s
+        self.close(s)
+
+
 class PagedLLMEngine:
     """Paged/block KV-cache engine: the one served engine.
 
@@ -416,7 +457,12 @@ class PagedLLMEngine:
                  max_burst: int = 8, prefix_sharing: Optional[bool] = None,
                  speculation_k: Optional[int] = None,
                  speculation_ngram: Optional[int] = None,
-                 store=None, mesh=None):
+                 store=None, mesh=None,
+                 setup: Optional[_SetupSpans] = None):
+        """`setup`: the spans of the start this engine is part of
+        (`LLMDeployment` hands its own over); None: the engine's own."""
+        self._setup = setup or _SetupSpans()
+        build_span = self._setup.open("serve.setup.engine_build")
         import jax
         import jax.numpy as jnp
 
@@ -694,6 +740,8 @@ class PagedLLMEngine:
         self._idle_from = 0.0
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
+        self._setup.close(build_span, num_blocks=self.num_blocks,
+                          kv_bytes=sum(self._state_bytes.values()))
 
     # -- the request-facing surface --------------------------------------
     @staticmethod
@@ -815,36 +863,64 @@ class PagedLLMEngine:
         if records:
             s["tick_log"] = self._with_routed(_snapshot(self._tick_log))
             s["tick_fields"] = TICK_FIELDS
+            # The replica's start on one clock: its spans, and every
+            # program the process has traced, lowered and loaded or
+            # compiled (a tier compiled inside serving is there too).
+            s["setup"] = self.setup_records()
         s["queue_depth"] = len(self._pending)
         s["active"] = sum(1 for r in self._slots if r is not None)
         return s
 
+    def setup_records(self) -> Dict[str, list]:
+        """`engine_stats()["setup"]`: the `serve.setup*` spans of this
+        replica's start and the process's compile log."""
+        return {"spans": list(_snapshot(self._setup.records)),
+                "compile_log": compile_cache.log()}
+
     def warmup(self) -> None:
         """Compile every width/chunk tier up front (benchmarks; serving
         just compiles tiers lazily as load ramps).  Inactive-lane calls
-        scatter into the null block — garbage no request reads."""
+        scatter into the null block — garbage no request reads.
+
+        Recorded (`engine_stats()["setup"]["spans"]`): `serve.setup.warmup`
+        from entry to return and, under it, one `serve.setup.warmup.tier`
+        a launch, which is the call into the jitted program: its trace,
+        lowering, load or compile (by name in the compile log beside
+        the spans) and enqueue.  No launch is waited for, so a tier's
+        first execution overlaps the next tier's load and has no time of
+        its own here, and the last ones still run at the return."""
         import jax.numpy as jnp
 
-        self._drain()
-        for w in self._width_tiers:
-            z = np.zeros((w,), np.int32)
-            self._launch_burst(
-                [], w, self._burst_input([], w),
-                np.zeros((w, self._b_max), np.int32), z,
-                np.zeros((w,), bool), np.zeros((w,), np.float32))
-            if self._spec_k:
-                self.cache, _, _, self._rng = self._verify(
-                    self.params, self.cache,
-                    jnp.zeros((w, self._spec_k), jnp.int32),
-                    jnp.zeros((w, self._b_max), jnp.int32),
-                    jnp.asarray(z), jnp.zeros((w,), bool),
-                    jnp.zeros((w,), jnp.float32), self._rng)
-        for c in self._chunk_tiers:
-            self.cache, _, *routed = self._prefill_chunk_fn(
-                self.params, self.cache, jnp.zeros((c,), jnp.int32),
-                jnp.zeros((self._b_max,), jnp.int32), jnp.int32(0),
-                jnp.int32(0), **self._slot_kw(self.num_slots))
-            self._count_routed(routed)
+        tier = functools.partial(self._setup.span, "serve.setup.warmup.tier")
+        with self._setup.span("serve.setup.warmup",
+                              width_tiers=list(self._width_tiers),
+                              chunk_tiers=list(self._chunk_tiers)) as whole:
+            self._drain()
+            for w in self._width_tiers:
+                z = np.zeros((w,), np.int32)
+                with tier(whole, program=self._decode.__name__,
+                          kind="burst", width=w):
+                    self._launch_burst(
+                        [], w, self._burst_input([], w),
+                        np.zeros((w, self._b_max), np.int32), z,
+                        np.zeros((w,), bool), np.zeros((w,), np.float32))
+                if self._spec_k:
+                    with tier(whole, program=self._verify.__name__,
+                              kind="verify", width=w):
+                        self.cache, _, _, self._rng = self._verify(
+                            self.params, self.cache,
+                            jnp.zeros((w, self._spec_k), jnp.int32),
+                            jnp.zeros((w, self._b_max), jnp.int32),
+                            jnp.asarray(z), jnp.zeros((w,), bool),
+                            jnp.zeros((w,), jnp.float32), self._rng)
+            for c in self._chunk_tiers:
+                with tier(whole, program=self._prefill_chunk_fn.__name__,
+                          kind="chunk", rows=c):
+                    self.cache, _, *routed = self._prefill_chunk_fn(
+                        self.params, self.cache, jnp.zeros((c,), jnp.int32),
+                        jnp.zeros((self._b_max,), jnp.int32), jnp.int32(0),
+                        jnp.int32(0), **self._slot_kw(self.num_slots))
+                    self._count_routed(routed)
 
     def gauges(self) -> Dict[str, float]:
         """Cheap autoscaling signals (riding the syncer push)."""
@@ -2248,21 +2324,28 @@ class LLMDeployment:
         brings its own sequence state such as models.hybrid.HybridConfig)
         — e.g. the config half of
         `ray_tpu.models.from_hf(...)`, with `params_loader` returning
-        the converted weights (serve real HF checkpoints)."""
+        the converted weights (serve real HF checkpoints).
+
+        The constructor's parts are recorded as the spans of
+        `_SetupSpans` and every program it compiles in the compile log:
+        `runtime_report()` hands out both."""
+        setup = _SetupSpans()
+        setup.root = setup.open("serve.setup", num_slots=num_slots,
+                                max_len=max_len)
         import jax
 
         from ray_tpu.models import configs, init_params
-        from ray_tpu.util import compile_cache
 
         if engine != "paged":
             raise ValueError(
                 f"engine={engine!r}: every deployment is served by the "
                 f"paged engine (engine='paged', PagedLLMEngine)")
-        # Start counting before the first compile, so runtime_report()
-        # can say what this replica's start cost in compiles.
+        # Start the compile log before the first compile, so
+        # runtime_report() can say what this replica's start cost.
         compile_cache.counts()
         cfg = (configs.get(cfg_name) if isinstance(cfg_name, str)
                else cfg_name)
+        setup.root.attrs["cfg"] = cfg.name
         by_slot = bool(getattr(cfg, "state_by_slot", False))
         by_block = bool(getattr(cfg, "diffusion_block", 0))
         if by_block and (tensor_parallel > 1 or disagg):
@@ -2279,12 +2362,23 @@ class LLMDeployment:
                 f"served by the paged engine on one device, without "
                 f"disaggregated prefill (a shipped KV frame is not its "
                 f"sequence)")
-        if params_loader:
-            params = params_loader()
-        else:       # a model that brings its own stack brings its own
-            own_init = getattr(cfg, "init_params", None)
-            params = (own_init(jax.random.key(seed)) if own_init
-                      else init_params(jax.random.key(seed), cfg))
+        # The process's first touch of the backend, which whatever ran
+        # first below would pay unnamed.
+        with setup.span("serve.setup.device_init") as span:
+            devices = jax.devices()
+            span.attrs.update(platform=devices[0].platform,
+                              count=len(devices))
+        with setup.span("serve.setup.params",
+                        loader=bool(params_loader)) as span:
+            if params_loader:
+                params = params_loader()
+            else:   # a model that brings its own stack brings its own
+                own_init = getattr(cfg, "init_params", None)
+                params = (own_init(jax.random.key(seed)) if own_init
+                          else init_params(jax.random.key(seed), cfg))
+            jax.block_until_ready(params)
+            span.attrs["bytes"] = sum(
+                int(x.nbytes) for x in jax.tree_util.tree_leaves(params))
         mesh = None
         if tensor_parallel > 1:
             # Claim N local chips as a tp mesh for this replica (the
@@ -2314,7 +2408,8 @@ class LLMDeployment:
             block_size=block_size, num_blocks=num_blocks,
             prefill_chunk=prefill_chunk, seed=seed,
             prefix_sharing=prefix_sharing,
-            speculation_k=speculation_k, store=store, mesh=mesh)
+            speculation_k=speculation_k, store=store, mesh=mesh,
+            setup=setup)
         # Disaggregated serving: this replica decodes; chunked prefill
         # of long prompts offloads to dedicated prefill actors whose
         # finished KV blocks ship back as frames (serve/disagg.py).
@@ -2336,6 +2431,7 @@ class LLMDeployment:
                 block_size=self.engine.block_size,
                 max_len=max_len)
             self.disagg_role = "decode"
+        setup.close(setup.root)
 
     def set_serve_context(self, app: str, replica_id: str) -> None:
         """Replica-actor hook: lets the disagg client tag its prefill
@@ -2401,14 +2497,24 @@ class LLMDeployment:
         return self.engine.engine_stats()
 
     def runtime_report(self, _request: Optional[dict] = None) -> dict:
-        """What this replica's process computes on and what it has taken
-        from / added to the compile cache — asked of the process that
-        owns the chip, because a caller that asked JAX would take it."""
-        from ray_tpu.util import compile_cache
+        """What this replica's process computes on and what its start
+        cost — asked of the process that owns the chip, because a
+        caller that asked JAX would take it.  `compile_cache`: the
+        cache's traffic and the compile log's running totals
+        (`compile_cache.counts()`); `setup`, as in `engine_stats()`:
+        `compile_log`, one entry per program traced, lowered and loaded
+        or compiled, with its seconds by phase and `cache` "hit" /
+        "miss" / "off" (`compile_cache.log()`), and `spans`, the
+        `serve.setup*` spans of the constructor and of `warmup()`, on
+        the log's clock.  `cache: "miss"` after the first start of a
+        checkout, or an entry that starts after the `serve.setup` span
+        has ended (a tier compiled inside serving), is a stall worth a
+        look."""
         from ray_tpu.util.tpu import device_report
 
         return {"device": device_report(),
-                "compile_cache": compile_cache.counts()}
+                "compile_cache": compile_cache.counts(),
+                "setup": self.engine.setup_records()}
 
     def serve_state(self) -> dict:
         """Replica gauge-loop hook: disagg role + the digests of this

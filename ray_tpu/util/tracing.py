@@ -65,7 +65,11 @@ class Span:
         self.start = time.time()
         self.end: Optional[float] = None
 
-    def finish(self, end_ts: Optional[float] = None) -> dict:
+    def finish(self, end_ts: Optional[float] = None,
+               buffered: bool = True) -> dict:
+        """The span's record, which also goes to the buffer the worker's
+        flusher drains unless `buffered` is false (a record its owner
+        keeps whether the sink is on or not: a replica's start)."""
         import os
 
         self.end = time.time() if end_ts is None else end_ts
@@ -81,6 +85,8 @@ class Span:
             "pid": os.getpid(),
             "attrs": self.attrs,
         }
+        if not buffered:
+            return record
         with _buffer_lock:
             _buffer.append(record)
             if len(_buffer) > MAX_BUFFER:

@@ -483,6 +483,8 @@ def test_no_decode_span_is_minted_with_the_kill_switch_off():
         finally:
             eng.shutdown()
     cfg_mod.reset_config()
-    assert minted == []
+    # The engine's start keeps its own records whatever the switch says
+    # (it silences their second sink: tests/test_setup_timeline.py).
+    assert [n for n in minted if not n.startswith("serve.setup")] == []
     _closed(rec)            # the record fills all the same
     assert rec["id"] is None and rec["n_out"] == 10

@@ -424,7 +424,7 @@ def test_the_counters_carry_the_phase_seconds_and_neither_log(
     # the gauge loop's view: the counters, without the logs
     counters = six_requests["counters"]
     assert counters["completed"] == len(_PROMPT_LENS)
-    logs = {"request_phases", "tick_log", "tick_fields"}
+    logs = {"request_phases", "tick_log", "tick_fields", "setup"}
     assert not logs & set(counters)
     assert set(counters) | logs == set(stats)
     seconds = counters["phase_seconds"]
