@@ -26,18 +26,30 @@ scalar a head; state `S` (H, P, N) float32:
                                                      norm over all HP)
 
 A decode step is that recurrence for one position on the lanes' state.
-A prefill chunk of Q positions from the slot's incoming state S_0 is the
-same recurrence as three matrix products a head and no loop over
-positions (`_ssd_chunk`): with s_t = sum_{r<=t} dt_r A and L[t, r] =
-exp(s_t - s_r) for r <= t, else 0,
+A chunk of Q positions from the slot's incoming state S_0 is the same
+recurrence as three matrix products a head and no loop over positions
+(`_ssd_chunk`): with s_t = sum_{r<=t} dt_r A and L[t, r] = exp(s_t - s_r)
+for r <= t, else 0,
 
     Y   = (L o (C B^T)) (dt o X) + exp(s) o (C S_0^T)
     S_Q = exp(s_Q) S_0 + sum_r exp(s_Q - s_r) dt_r x_r B_r^T
 
 decay terms and the state in float32, the products in the compute dtype
-with float32 accumulation.  A position that is not valid (the padded
-tail of a chunk, an idle lane) takes dt = 0: it decays nothing, adds
-nothing, and the state passes it unchanged to the bit, as in
+with float32 accumulation.  A prefill launch of up to Q = the engine's
+`prefill_chunk` rows is one such chunk; a wider one, of m x Q rows, is m
+of them, the float32 state handed from one to the next inside the
+program (`_mamba2`: one `lax.scan`, so `_ssd_chunk` is traced once, and
+unrolled where it is lowered: as a loop the carried state took a layout
+of its own and the slots' whole state array was copied into it, 0.69 GB
+of temporaries a 512-row launch where the unrolled program holds 0.05;
+AOT for a v5e, PR 56), while everything that keeps no
+state between positions (projections, convolution, attention, experts)
+runs over all the rows at once: a launch streams the weights once
+whatever its rows.  Nothing a sequence keeps is laid out by a launch's
+rows, which the class states (`launch_spans_chunks`) and the engine
+asks.  A position that is not valid (the padded tail of a launch,
+wherever it begins, or an idle lane) takes dt = 0: it decays nothing,
+adds nothing, and the state passes it unchanged to the bit, as in
 `models.hybrid._mamba`.
 
 Attention: grouped-query heads over the engine's paged pool
@@ -58,7 +70,9 @@ What a sequence keeps (`Mamba2MoEState`):
     conv   (n_mamba, S+1, d_conv-1, HP + 2N)   the last conv inputs
     h      (n_mamba, S+1, P, H, N) float32     the state, by engine slot
 
-Row S is the null slot.  A slot's state is stored channels first, (P, H,
+Row S is the null slot; the state also names, as a static field, the
+positions of one chunk (`chunk`: the engine's `prefill_chunk`), which
+sizes none of the arrays.  A slot's state is stored channels first, (P, H,
 N): with P = 64, half a lane tile, as the minor dimension of x the
 compiler lays a chunk's x out as (P, H) and the state it updates with
 it; stored as (H, P, N) every slot's state was copied into that layout
@@ -123,6 +137,10 @@ class Mamba2MoEConfig:
     # zeroes a slot's state when it changes hands.
     state_by_slot: ClassVar[bool] = True
     recurrent: ClassVar[bool] = True
+    # Nothing of that state is laid out by a launch's rows (no ring of
+    # window + prefill_chunk positions): a launch of m x prefill_chunk
+    # rows is m chunks, the state carried between them in the program.
+    launch_spans_chunks: ClassVar[bool] = True
 
     def __post_init__(self):
         if set(self.layer_pattern) - {"mamba", "attention"} \
@@ -180,7 +198,8 @@ class Mamba2MoEConfig:
             k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
             conv=jnp.zeros(rows + (self.d_conv - 1, self.d_xbc), dtype),
             h=jnp.zeros(rows + (self.ssm_head_dim, self.ssm_heads,
-                                self.d_state), self.state_dtype))
+                                self.d_state), self.state_dtype),
+            chunk=prefill_chunk)
 
     @staticmethod
     def reset_slot(state: "Mamba2MoEState", slot) -> "Mamba2MoEState":
@@ -218,6 +237,7 @@ class Mamba2MoEState:
     v: jax.Array
     conv: jax.Array       # (n_mamba, S+1, d_conv-1, HP + 2N)
     h: jax.Array          # (n_mamba, S+1, P, H, N) float32
+    chunk: int            # positions of one SSD chunk (static)
 
     def resident_bytes(self) -> dict:
         def nbytes(*arrays):
@@ -228,7 +248,7 @@ class Mamba2MoEState:
 
 
 jax.tree_util.register_dataclass(
-    Mamba2MoEState, ["k", "v", "conv", "h"], [])
+    Mamba2MoEState, ["k", "v", "conv", "h"], ["chunk"])
 
 
 # ---------------------------------------------------------------------------
@@ -325,13 +345,18 @@ def _ssd_chunk(xh, b_in, c_out, dt, a_neg, h0, cd):
     return y, h
 
 
-def _mamba2(bp, x, conv_s, h_s, valid, cfg):
+def _mamba2(bp, x, conv_s, h_s, valid, cfg, chunk):
     """The Mamba-2 mixer over the K positions of each lane, from the
     lane's state: conv_s (S, d_conv-1, HP + 2N), h_s (S, P, H, N)
     float32.  Returns (out (S, K, d), conv_s', h_s').  K = 1 is the
-    one-position recurrence; K > 1 is one chunk of `_ssd_chunk`.  A
-    position that is not `valid` (S, K; the valid ones are a prefix)
-    takes dt = 0 and the conv rows kept are the last valid ones'."""
+    one-position recurrence; 1 < K <= `chunk` is one chunk of
+    `_ssd_chunk`; K = m x `chunk` is m of them in an unrolled
+    scan that carries the state: `_ssd_chunk` is traced once whatever m
+    is, and no loop is lowered (the module's header says why).
+    The projections, the convolution, the gate and the norm run over
+    all K rows.  A position that is not `valid` (S, K; the valid ones
+    are a prefix, which may end in any of the m chunks) takes dt = 0 and
+    the conv rows kept are the last valid ones'."""
     cd = cfg.compute_dtype
     di, n, hs, p = cfg.d_inner, cfg.d_state, cfg.ssm_heads, cfg.ssm_head_dim
     s_w, k_w = x.shape[:2]
@@ -361,8 +386,24 @@ def _mamba2(bp, x, conv_s, h_s, valid, cfg):
                 * b_in[:, 0].astype(F32)[:, None, None, :]
             y = jnp.einsum("sphn,sn->shp", h_s,
                            c_out[:, 0].astype(F32))[:, None]
-        else:
+        elif k_w <= chunk:
             y, h_s = _ssd_chunk(xh, b_in, c_out, dt, a_neg, h_s, cd)
+        elif k_w % chunk:
+            raise ValueError(f"a launch of {k_w} rows is not whole chunks "
+                             f"of {chunk}")
+        else:
+            def parts(a):           # (S, m Q, ..) -> (m, S, Q, ..)
+                return jnp.swapaxes(
+                    a.reshape(s_w, k_w // chunk, chunk, *a.shape[2:]), 0, 1)
+
+            def one(h0, part):
+                y, h_q = _ssd_chunk(*part, a_neg, h0, cd)
+                return h_q, y
+
+            h_s, y = jax.lax.scan(
+                one, h_s, tuple(map(parts, (xh, b_in, c_out, dt))),
+                unroll=True)
+            y = jnp.swapaxes(y, 0, 1).reshape(s_w, k_w, hs, p)
     y = y + bp["D"].astype(F32)[:, None] * xh.astype(F32)  # (S, K, H, P)
     gated = (y.reshape(s_w, k_w, di) * jax.nn.silu(z.astype(F32))).astype(cd)
     out = jnp.einsum("ske,ed->skd",
@@ -441,7 +482,7 @@ def _served_step(params, state: Mamba2MoEState, tokens, block_tables,
             with jax.named_scope("mamba"):
                 out, conv_s, h_s = _mamba2(
                     _take(params["mamba"], at), x, conv[at, slots],
-                    h[at, slots], valid, cfg)
+                    h[at, slots], valid, cfg, state.chunk)
                 conv = conv.at[at, slots].set(conv_s)
                 h = h.at[at, slots].set(h_s.astype(h.dtype))
         else:
@@ -486,5 +527,5 @@ def _served_step(params, state: Mamba2MoEState, tokens, block_tables,
         jnp.arange(cfg.periods))
     if routing:                 # (periods, p, S, K, k) -> (L, S, K, k)
         taken = taken.reshape(cfg.n_layers, *taken.shape[2:])
-    return (Mamba2MoEState(k=k_pool, v=v_pool, conv=conv, h=h), x, visited,
-            taken, routed)
+    return (dataclasses.replace(state, k=k_pool, v=v_pool, conv=conv, h=h),
+            x, visited, taken, routed)

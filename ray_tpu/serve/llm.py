@@ -356,12 +356,17 @@ class PagedLLMEngine:
     latency of active streams.
 
     `prefill_chunk` is the unit and the floor of that budget, and the
-    widest launch of a model that keeps state by slot (its rings hold
-    window + prefill_chunk rows, its recurrence takes one chunk of that
-    many positions).  A model whose sequences are pool blocks alone is
-    given wider tiers above it, in powers of two, up to the rows that
+    widest launch of a model whose slots keep rings (they hold window +
+    prefill_chunk rows).  A model whose sequences are pool blocks alone
+    is given wider tiers above it, in powers of two, up to the rows that
     make a launch compute-bound: at `prefill_chunk` = 128 a launch
-    streams every weight to multiply 128 rows by it.
+    streams every weight to multiply 128 rows by it.  So is a model
+    with state by slot that says a launch's rows lay none of it out
+    (`launch_spans_chunks`: a state-space model whose program takes
+    m x `prefill_chunk` rows as m chunks and hands the state from one
+    to the next itself); for it `prefill_chunk` is the positions of one
+    chunk of its recurrence, and no tier under it is built (`__init__`
+    says why).
 
     The host runs one burst ahead of its own reads, never more: the
     next burst needs nothing the host has to read first.  Lengths,
@@ -524,15 +529,31 @@ class PagedLLMEngine:
         # recurrent state), and whether some of it is recurrent.
         self._by_slot = bool(getattr(cfg, "state_by_slot", False))
         self._recurrent = bool(getattr(cfg, "recurrent", False))
-        if not self._by_slot:
-            # Pool blocks alone: a launch is generic in its rows, so the
-            # tiers go on above `prefill_chunk` (no prompt reaches
-            # max_len rows).  State by slot was laid out for
-            # `prefill_chunk` rows a launch and stops there.
+        # State by slot of which the model says that a launch's rows lay
+        # none of it out: its program takes m x `prefill_chunk` rows as
+        # m chunks, the state carried from one to the next.
+        self._spans_chunks = self._by_slot and bool(
+            getattr(cfg, "launch_spans_chunks", False))
+        if not self._by_slot or self._spans_chunks:
+            # Pool blocks alone, or such state: a launch is generic in
+            # its rows, so the tiers go on above `prefill_chunk` (no
+            # prompt reaches max_len rows).  Rings were laid out for
+            # `prefill_chunk` rows a launch and stop there.
             wide = 2 * self.prefill_chunk
             while wide <= min(_CHUNK_TOP_ROWS, max_len):
                 self._chunk_tiers.append(wide)
                 wide *= 2
+        if self._spans_chunks:
+            # Under `prefill_chunk` such a launch is one chunk of fewer
+            # positions, and a narrower program saves the device little
+            # or nothing: granite-4.0-h-small's bare chunk program takes
+            # 15.9 / 14.5 / 14.8 / 16.4 ms at 32 / 64 / 128 / 256 rows and
+            # 15.5 at 256 with 20 of them valid (a launch is its 9.5 GB
+            # of weights; a v5e, PERF.md section 7, PR 56), while each
+            # tier is 0.9-1.4 s of every start.  So none is built, and a
+            # prompt's last launch takes `prefill_chunk` rows.
+            self._chunk_tiers = [t for t in self._chunk_tiers
+                                 if t >= self.prefill_chunk]
         # The widest launch beside a decode burst: see _prefill_budget.
         beside = max(self.prefill_chunk, _ROWS_A_STEP * self.max_burst)
         self._chunk_beside_burst = max(
@@ -1256,7 +1277,7 @@ class PagedLLMEngine:
         """Prompt tokens this tick's prefill launches may carry.  (i)
         With nobody decoding: the widest chunk tier, the rows past which
         the device's time a token stops falling (`_CHUNK_TOP_ROWS`; a
-        model with state by slot has no tier above `prefill_chunk`).
+        model whose slots keep rings has no tier above `prefill_chunk`).
         (ii) Beside a burst (this tick launched one): no more rows than
         take the device as long as the burst does, `_ROWS_A_STEP` a
         step of it -- the ITL bound, in the one form the host can
@@ -1893,7 +1914,9 @@ class PagedLLMEngine:
         slot and blocks of its own: its first `n_prompt` tokens are
         prefilled through the engine's jitted chunk program, in the
         launches an idle engine's tick would use (its widest chunk tier,
-        then the last launch padded to its tier as a served one is),
+        then the last launch padded to its tier as a served one is; with
+        `routing`, to the widest tier for a model whose launch spans
+        chunks, so that one routed program is built),
         then the rest is teacher-forced, all lanes a
         step, through `paged_decode_step` (the function the burst scans;
         the burst itself returns sampled tokens, never logits) at the
@@ -1954,6 +1977,11 @@ class PagedLLMEngine:
             chunk_fn, route_kw = self._score_chunk, {"routing": True}
         per_lane = math.ceil(total / self.block_size)
         top = self._chunk_tiers[-1]     # an idle engine's budget
+        # The routed program is score()'s own, built a shape.  For a
+        # model whose launch spans chunks a last launch of fewer tokens
+        # is the widest one with a shorter valid prefix: one routed
+        # chunk program is built, of the rows a window's launches have.
+        one_shape = routing and self._spans_chunks
         got: List[List[Any]] = [[] for _ in range(lanes)]
         taken: List[List[Any]] = [[] for _ in range(lanes)]
         with self._tick_lock:
@@ -1976,7 +2004,8 @@ class PagedLLMEngine:
                     for start in range(0, n_prompt, top):
                         nv = min(top, n_prompt - start)
                         toks = np.zeros(
-                            (self._tier_for(self._chunk_tiers, nv),),
+                            (top if one_shape else
+                             self._tier_for(self._chunk_tiers, nv),),
                             np.int32)
                         toks[:nv] = seqs[lane, start:start + nv]
                         self.cache, last, *route = chunk_fn(

@@ -1,11 +1,14 @@
 """The prompt tokens one prefill launch carries (serve/llm.py
 `_prefill_budget`): wide launches for a model whose sequences are pool
-blocks alone, `prefill_chunk` rows at most for one that keeps state by
-slot, the cap beside a decode burst, what `warmup()` compiles, and the
-counter that says which tiers ran."""
+blocks alone and for one whose state by slot spans chunks,
+`prefill_chunk` rows at most for one whose slots keep rings, the cap
+beside a decode burst, what `warmup()` compiles, and the counter that
+says which tiers ran."""
+import dataclasses
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -81,12 +84,11 @@ def test_wide_launches_equal_narrow_ones(wide_and_narrow, n_prompt):
     assert set(narrow.stats["prefill_launch_tokens"]) == {16}
 
 
-@pytest.mark.parametrize(
-    "name", ["tiny-hybrid", "tiny-window-moe", "tiny-mamba2-moe"])
+@pytest.mark.parametrize("name", ["tiny-hybrid", "tiny-window-moe"])
 def test_state_by_slot_never_launches_past_prefill_chunk(name):
-    """Rings hold window + prefill_chunk rows and a recurrence takes one
-    chunk of that many positions: such a model's tiers are the ones it
-    always had, and no wider program is launched, so none is compiled."""
+    """Rings hold window + prefill_chunk rows: such a model's tiers are
+    the ones it always had, and no wider program is launched, so none is
+    compiled."""
     cfg = configs.get(name)
     e = _engine(cfg, _params(cfg))
     rows = []
@@ -111,6 +113,95 @@ def test_state_by_slot_never_launches_past_prefill_chunk(name):
         assert e.stats["prefill_launch_tokens"] == {CHUNK: n}
     finally:
         e.shutdown()
+
+
+@pytest.fixture(scope="module")
+def spans_chunks():
+    """`tiny-mamba2-moe` (state by slot that no launch's rows lay out)
+    at `prefill_chunk` 64, warmed, with the rows of every launch of its
+    chunk program recorded."""
+    cfg = dataclasses.replace(configs.get("tiny-mamba2-moe"),
+                              compute_dtype=jnp.float32)
+    e = _engine(cfg, _params(cfg), max_len=1024, prefill_chunk=64)
+    rows, chunk_fn = [], e._prefill_chunk_fn
+
+    def recorded(params, cache, toks, *a, **kw):
+        rows.append(toks.shape[0])
+        return chunk_fn(params, cache, toks, *a, **kw)
+
+    e._prefill_chunk_fn = recorded
+    with e._tick_lock:
+        e.warmup()
+    yield e, rows, chunk_fn
+    e.shutdown()
+
+
+def test_state_that_spans_chunks_has_the_ladder_above_prefill_chunk(
+        spans_chunks):
+    """Tiers go on above `prefill_chunk` to `_CHUNK_TOP_ROWS` as a
+    pool-only model's do, and none under it is built: `warmup()`
+    launches one program a tier, four and not five."""
+    e, rows, chunk_fn = spans_chunks
+    assert e._by_slot and e._recurrent and e._spans_chunks
+    assert e.prefill_chunk == 64 and e.cache.chunk == 64
+    assert e._chunk_tiers == [64, 128, 256, 512]
+    assert rows[:4] == e._chunk_tiers and chunk_fn._cache_size() == 4
+    assert e._prefill_budget() == 512
+    assert e._chunk_beside_burst == min(512, llm._ROWS_A_STEP * e.max_burst)
+    # prefill_chunk 256, as granite's: a short last launch is one of 256
+    wide = _engine(e.cfg, e.params, max_len=1024, prefill_chunk=256)
+    try:
+        assert wide._chunk_tiers == [256, 512]
+        assert wide._tier_for(wide._chunk_tiers, 20) == 256
+    finally:
+        wide.shutdown()
+
+
+def test_state_that_spans_chunks_compiles_nothing_after_warmup(spans_chunks):
+    """A long prompt after `warmup()` goes in launches of the top tier
+    and a last one of its own, compiles nothing, and is counted by the
+    rows of its launches; what it generates is what launches of
+    `prefill_chunk` rows give."""
+    e, rows, chunk_fn = spans_chunks
+    fns = (chunk_fn, e._decode, e._take_last, e._put_last)
+    before = [f._cache_size() for f in fns]
+    del rows[:]
+    counted = dict(e.stats["prefill_launch_tokens"])
+    prompt = _prompt(700)
+    got = e.generate(prompt, max_tokens=6)
+    assert rows == [512, 256]
+    assert [f._cache_size() for f in fns] == before
+    by_rows = e.stats["prefill_launch_tokens"]
+    assert list(by_rows) == e._chunk_tiers
+    assert {t: n - counted[t] for t, n in by_rows.items() if n != counted[t]} \
+        == {512: 512, 256: 188}
+    top = llm._CHUNK_TOP_ROWS
+    try:
+        llm._CHUNK_TOP_ROWS = 0
+        narrow = _engine(e.cfg, e.params, max_len=1024, prefill_chunk=64)
+    finally:
+        llm._CHUNK_TOP_ROWS = top
+    try:
+        assert narrow._chunk_tiers == [64]
+        assert narrow.generate(prompt, max_tokens=6) == got
+    finally:
+        narrow.shutdown()
+
+
+def test_score_with_routing_builds_one_chunk_program(spans_chunks):
+    """`score(routing=True)` compiles a chunk program of its own: for
+    this kind of model the last launch takes the top tier's rows too,
+    so one is built, and its logits are those of the served launches."""
+    e, rows, _ = spans_chunks
+    seqs = np.asarray([_prompt(700, seed=9)])
+    del rows[:]
+    want = e.score(seqs, 699)[0][0]
+    assert rows == [512, 256]
+    got, taken = e.score(seqs, 699, routing=True)
+    assert e._score_chunk._cache_size() == 1
+    assert taken[0].shape == (700, e.cfg.n_layers, e.cfg.expert_top_k)
+    np.testing.assert_allclose(np.asarray(got[0][0]), np.asarray(want),
+                               atol=2e-5)
 
 
 def test_after_warmup_a_long_prompt_compiles_nothing():

@@ -717,6 +717,56 @@ def test_mamba2_moe_served_programs_fit_one_chip(topo, program):
         assert fam.state_operand(config).search(text)
 
 
+def test_mamba2_moe_launch_of_two_chunks_copies_no_state(topo):
+    """The same configuration's widest prefill launch, 512 rows = two
+    chunks of 256 (`models/mamba2_moe.py:_mamba2`): it compiles for one
+    v5e chip, its decay matrix is a chunk's ([H, 256, 256], not the
+    launch's), nothing in the `ssd` scope is a loop, and the slots'
+    state is still updated in place: the temporaries stay under a tenth
+    of the state array (0.64 GB), where the sub-chunks as a lowered loop
+    held 0.69 GB, a copy of all of it in the carry's layout."""
+    import json
+    import re
+
+    from bench.harness import spec
+    from ray_tpu.models.decoding import make_paged_engine_fns
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "granite-4.0-h-small-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    def arr(shape, dtype):
+        return place(jax.ShapeDtypeStruct(shape, dtype))
+
+    fam, eng = spec.family(config), config["engine"]
+    resident, _ = fam.serve_programs(config, place)
+    params, state = resident["params"], resident["sequence_state"]
+    assert state.chunk == eng["prefill_chunk"] == config["mamba_chunk_size"]
+    chunk_fn, _, _ = make_paged_engine_fns(fam.program_config(config))
+    compiled = chunk_fn.lower(
+        params, state, arr((2 * state.chunk,), jnp.int32),
+        arr((eng["max_len"] // eng["block_size"],), jnp.int32),
+        arr((), jnp.int32), arr((), jnp.int32),
+        slot=arr((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < 0.064e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
+    assert loops and not [name for name in loops if "/ssd" in name], loops
+    assert re.search(r"\[128,256,256\]", text)
+    assert not re.search(r"\[128,512,512\]", text)
+    assert fam.scan_operand(config).search(text)
+
+
 @pytest.mark.parametrize("program", ["paged_decode_burst",
                                      "paged_prefill_chunk c=512"])
 def test_mla_moe_served_programs_fit_one_chip(topo, program):
