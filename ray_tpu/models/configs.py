@@ -159,6 +159,31 @@ DOTS3_NOTE_PREV = MLAMoEConfig(
     rope_theta_window=5e4, attn_gate=True, latent_rescale=True,
     index_heads=64, index_dim=128, index_top_k=2048)
 
+# `MLAMoEConfig` with a residual of four streams at test size: two dense
+# layers and three expert layers (the scan runs three periods), every
+# stream-to-stream matrix projected by 20 Sinkhorn-Knopp rounds, the rope
+# under YaRN in DeepSeek's convention (cos and sin x 1, the soft-max scale
+# x (0.1 ln 8 + 1)^2), all 8 experts held, top-3, a shared expert.
+TINY_MHC_MLA_MOE = dataclasses.replace(
+    TINY_MLA_MOE, name="tiny-mhc-mla-moe", n_layers=5, n_dense_layers=2,
+    experts_held=None, route_scale=2.0, hc_mult=4,
+    yarn=YarnScaling(factor=8.0, original_max_len=64, attention_factor=1.0),
+    yarn_mscale_all_dim=1.0)
+
+# Xing4.0-29B-A4B's published sizes (29.5 B parameters, ~4 B active a
+# token), every expert held: two dense layers, 38 expert layers, four
+# residual streams a position; its multi-token-prediction module is not
+# part of the stack.
+XING4_0_29B_A4B = MLAMoEConfig(
+    name="xing4.0-29b-a4b", vocab_size=131072, d_model=3584, n_layers=40,
+    n_dense_layers=2, n_heads=32, q_rank=768, kv_rank=512, d_nope=128,
+    d_rope=64, d_v=128, d_ff=9216, n_experts=64, expert_top_k=4,
+    d_expert=1024, d_shared=1024, route_scale=2.0, rope_theta=10000.0,
+    norm_eps=1e-6, max_seq_len=262144, hc_mult=4,
+    yarn=YarnScaling(factor=64.0, original_max_len=4096, beta_fast=32.0,
+                     beta_slow=1.0, attention_factor=1.0),
+    yarn_mscale_all_dim=1.0)
+
 # What a layer can have of its own in the homogeneous stack, at test
 # size: a dense first layer before two periods of three window layers to
 # one full layer, 8 query heads in a window layer and 6 in a full one over
@@ -225,6 +250,7 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_MAMBA2_MOE, GRANITE4_H_SMALL,
                                 TINY_MLA_MOE, GLM_4_7_FLASH,
                                 TINY_DSA_MOE, DOTS3_NOTE_PREV,
+                                TINY_MHC_MLA_MOE, XING4_0_29B_A4B,
                                 TINY_GATED_MOE, LAGUNA_XS_2,
                                 TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B]}
 

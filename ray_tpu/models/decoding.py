@@ -322,20 +322,24 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
     (S,) where the sequence keeps state by slot (None for a model whose
     state is the pool alone).  Returns (cache, hidden, experts visited,
     the routing if asked, the top-k choices that fell on experts held
-    here or None from a model that does not count them: `_paged_forward`).
-    A model's own step that has experts (`n_experts`) takes `routing`
-    and returns all five itself; one without returns (cache, hidden)."""
+    here or None from a model that does not count them: `_paged_forward`,
+    the largest defect of the call's projected stream mixes or None from
+    a model with one residual stream: `counts_defect`).  A model's own
+    step that has experts (`n_experts`) takes `routing` and returns the
+    first five itself, or all six; one without returns (cache, hidden)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
-        return _paged_forward(params, cache, tokens, block_tables,
-                              positions, kv_len, cfg, slots, routing)
-    if getattr(cfg, "n_experts", 0) > 0:
-        return own(params, cache, tokens, block_tables, positions, kv_len,
-                   slots, routing=routing)
-    if routing:
+        out = _paged_forward(params, cache, tokens, block_tables,
+                             positions, kv_len, cfg, slots, routing)
+    elif getattr(cfg, "n_experts", 0) > 0:
+        out = own(params, cache, tokens, block_tables, positions, kv_len,
+                  slots, routing=routing)
+    elif routing:
         raise ValueError(f"{cfg.name!r} has no experts: no routing to give")
-    return (*own(params, cache, tokens, block_tables, positions, kv_len,
-                 slots), jnp.int32(0), None, None)
+    else:
+        out = (*own(params, cache, tokens, block_tables, positions, kv_len,
+                    slots), jnp.int32(0), None, None)
+    return out if len(out) == 6 else (*out, None)
 
 
 def counts_routed(cfg) -> bool:
@@ -343,6 +347,15 @@ def counts_routed(cfg) -> bool:
     the top-k choices that fell on experts held here: a model that holds
     one rank's share of its experts (`experts_held`) does."""
     return getattr(cfg, "experts_held", None) is not None
+
+
+def counts_defect(cfg) -> bool:
+    """Whether the served programs of `cfg` hand out, last of all, how
+    far the stream mixes of the launch stopped from the doubly stochastic
+    matrices (`ops.hyper_connections.res_defect`, the largest over the
+    launch's rows and mixes): a model whose residual is several streams
+    (`hc_mult`) does."""
+    return getattr(cfg, "hc_mult", 0) > 0
 
 
 def _served_logits(params, x, cfg):
@@ -518,7 +531,7 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     `routing` (a scoring entry's): also the experts each lane took in
     every layer, (L, S, top_k).
     """
-    cache, logits, _, taken, _ = _paged_decode_logits(
+    cache, logits, _, taken, _, _ = _paged_decode_logits(
         params, cache, tokens, block_tables, lengths, active, cfg, slots,
         routing)
     if routing:    # what the rows took: an array, or a model's tree of them
@@ -529,12 +542,13 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
 def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
                          active, cfg, slots, routing=False):
     """`paged_decode_step` with the step's count of experts visited and,
-    from a model that counts them, of top-k choices routed here."""
-    cache, x, visited, taken, routed = _served_forward(
+    from a model that counts them, of top-k choices routed here and the
+    defect of its stream mixes."""
+    cache, x, visited, taken, routed, defect = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
         jnp.where(active, lengths + 1, 0), cfg, slots, routing)
     return (cache, _served_logits(params, x, cfg)[:, 0], visited, taken,
-            routed)
+            routed, defect)
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
@@ -547,26 +561,29 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
     Returns (cache, token_matrix (n_steps, S), rng, experts visited:
     int32, summed over the steps and the layers) and, from a model that
     holds a share of its experts (`counts_routed`), the top-k choices
-    that fell on it as a fifth, summed likewise."""
-    counted = counts_routed(cfg)
+    that fell on it as a fifth, summed likewise; from a model of several
+    residual streams (`counts_defect`), last, the largest defect of the
+    steps' mixes."""
+    counted, mixed = counts_routed(cfg), counts_defect(cfg)
 
     def tick(carry, _):
-        cache, toks, lengths, rng, visited, routed = carry
-        cache, logits, n, _, r = _paged_decode_logits(
+        cache, toks, lengths, rng, visited, routed, defect = carry
+        cache, logits, n, _, r, short = _paged_decode_logits(
             params, cache, toks, block_tables, lengths, active, cfg, slots)
         rng, sub = jax.random.split(rng)
         nxt = sample_per_slot(logits, sub, temps)
         lengths = jnp.where(active, lengths + 1, lengths)
         return (cache, nxt, lengths, rng, visited + n,
-                routed + r if counted else None), nxt
+                routed + r if counted else None,
+                jnp.maximum(defect, short) if mixed else None), nxt
 
-    (cache, _, _, rng, visited, routed), toks = jax.lax.scan(
+    (cache, _, _, rng, visited, routed, defect), toks = jax.lax.scan(
         tick, (cache, tokens, lengths, rng, jnp.int32(0),
-               _routed_zero(tokens.size, cfg) if counted else None), None,
+               _routed_zero(tokens.size, cfg) if counted else None,
+               jnp.float32(0.0) if mixed else None), None,
         length=n_steps)
-    if counted:
-        return cache, toks, rng, visited, routed
-    return cache, toks, rng, visited
+    return (cache, toks, rng, visited, *([routed] if counted else []),
+            *([defect] if mixed else []))
 
 
 def _fills(cfg: TransformerConfig) -> list:
@@ -714,7 +731,7 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     each position took in every layer, (L, C, top_k).
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x, _, taken, routed = _served_forward(
+    cache, x, _, taken, routed, defect = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
         (start + n_valid)[None], cfg,
         None if slot is None else jnp.asarray(slot, jnp.int32)[None],
@@ -728,8 +745,10 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     if routing:
         out += (jax.tree.map(lambda a: a[:, 0], taken),)
     # From a model that counts them, last: the chunk's top-k choices
-    # that fell on experts held here (`_served_forward`).
-    return (*out, routed) if counts_routed(cfg) else out
+    # that fell on experts held here, then the defect of its stream
+    # mixes (`_served_forward`).
+    return (*out, *([routed] if counts_routed(cfg) else []),
+            *([defect] if counts_defect(cfg) else []))
 
 
 def paged_verify_step(params, cache: PagedKVCache, cand_tokens: jax.Array,

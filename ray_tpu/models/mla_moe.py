@@ -29,6 +29,28 @@ The stack, pre-norm residual, RMSNorm throughout:
                                        shared_l
     logits = RMSNorm_f(x) W_head       (untied)
 
+**With `hc_mult` = n > 0 the residual is n streams a position**, X in
+R^{n x d}, mixed around every sub-block by manifold-constrained
+hyper-connections (`ops.hyper_connections` has the equations: arXiv:
+2512.24880 on arXiv:2409.19606; the layout of the `xing4_0` config
+family); each sub-block F (MLA_l or FFN_l, with its own pre-norm) has a
+mixing of its own (`hc_attn` / `hc_ffn`: Phi, three alphas, b; float32):
+
+    X = (E[token], .., E[token])                    expansion: n copies
+    h_pre, h_post, H_res = mixing_F(X)              H_res projected by
+                                                    `hc_sinkhorn_iters`
+                                                    Sinkhorn-Knopp rounds
+    X = H_res X + h_post^T F(RMSNorm(h_pre X))      two a layer
+    logits = RMSNorm_f(sum of the streams) W_head   contraction
+
+The streams are laid flat between sub-blocks, (S, K, n d), in
+`compute_dtype`; coefficients and both mixes are float32.  `served_step`
+still hands (S, K, d) to `final_logits`, and hands out one value more:
+the largest |row sum - 1| / |column sum - 1| of the call's H_res
+(`hc_res_defect` of the engine's tick log).  With `yarn` the rope of both
+kinds is scaled in DeepSeek's convention and the soft-max scale carries
+YaRN's factor (`kind()` builds it, `yarn_score_factor`).
+
 MLA, H heads, for a position's normed input u:
 
     c_q = RMSNorm(u W_qa)                       (q_rank)
@@ -104,7 +126,8 @@ selected rows).  A window layer reads its slot's ring through
 `ring_seen` as every ring.
 Scopes in a profile: `mla_attn` around a layer's attention, inside it
 `dsa_index`, `dsa_attend` (inside it `dsa_select`), `latent_swa`,
-`attn_gate`; `moe`, `shared_mlp`, `dense_mlp`.
+`attn_gate`; `moe`, `shared_mlp`, `dense_mlp`; `hc_pre`, `hc_sinkhorn`,
+`hc_post` around each (a model of several streams).
 
 What a sequence keeps (`LatentState`), three leaves: `kv` (full layers,
 N_blocks, block_size, row_width), paged as ever, block 0 the null block;
@@ -116,7 +139,8 @@ row_width), the null slot last (None without window layers; rows: window
 + prefill_chunk in whole sublane tiles, 513 + 512 -> 1,040).
 Parameters: `attn.*` stacked over the full layers (the indexer's and the
 gate's beside the rest), `attn_window.*` over the window layers, `dense.*`
-over the leading dense layers, `ffn.*` over the expert layers.  The
+over the leading dense layers, `ffn.*` over the expert layers, `hc_attn.*`
+/ `hc_ffn.*` over all layers (a model of several streams).  The
 up-projections are stored by head, `w_uk` (H, d_nope, kv_rank) and `w_uv`
 (H, kv_rank, d_v), so that neither form slices a matrix inside a step.
 The layer loop scans the periods of the pattern, its body a period's
@@ -129,6 +153,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, ClassVar, NamedTuple, Optional, Tuple
 
 import jax
@@ -141,9 +166,14 @@ from ray_tpu.ops.attention import (
     ring_rows,
     slot_ring_reader,
 )
+from ray_tpu.ops.hyper_connections import (
+    hc_coefficients,
+    hc_mix_up,
+    n_coefficients,
+)
 from ray_tpu.ops.moe import MoEConfig, moe_mlp_dropless, routed_zero
 from ray_tpu.ops.norms import layer_norm, rms_norm
-from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.ops.rotary import YarnScaling, apply_rope
 
 F32 = jnp.float32
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -220,6 +250,21 @@ class MLAMoEConfig:
     index_dim: int = 0
     index_top_k: int = 0
     index_norm_eps: float = 1e-6
+    # The rope under YaRN, in DeepSeek's convention: `yarn` says how the
+    # frequencies are blended and what multiplies cos and sin (its
+    # `attention_factor`: mscale over mscale_all_dim's), and the soft-max
+    # scale carries (0.1 `yarn_mscale_all_dim` ln(factor) + 1)^2 (0: 1).
+    yarn: Optional[YarnScaling] = None
+    yarn_mscale_all_dim: float = 0.0
+    # Residual streams a position (0: one, `x += out`), mixed around
+    # every sub-block by manifold-constrained hyper-connections
+    # (`ops.hyper_connections`): the Sinkhorn-Knopp rounds of the
+    # stream-to-stream matrix, the eps of its divisions and of the
+    # flattened streams' norm, and the clip of its logits.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     recurrent: ClassVar[bool] = False
 
@@ -238,6 +283,9 @@ class MLAMoEConfig:
                                      and self.index_dim >= self.d_rope):
             raise ValueError("a selection needs index_heads heads of "
                              "index_dim >= d_rope")
+        if self.hc_mult == 1 or self.hc_mult < 0:
+            raise ValueError("hc_mult: 0 (one residual stream) or the "
+                             "streams mixed, at least two")
         self.moe                        # MoEConfig checks the held range
 
     @property
@@ -268,14 +316,26 @@ class MLAMoEConfig:
         kv_rank = own and self.kv_rank_window or self.kv_rank
         d_nope = own and self.d_nope_window or self.d_nope
         rescale = self.latent_rescale
+        # The soft-max scale, built here alone: the published head size's,
+        # times YaRN's factor where the rope is scaled.
+        scale = (d_nope + self.d_rope) ** -0.5 * self.yarn_score_factor
         return _Kind(
             heads, q_rank, kv_rank, d_nope,
             own and self.d_v_window or self.d_v,
             own and self.rope_theta_window or self.rope_theta,
             -(-(kv_rank + self.d_rope) // _LANE_TILE) * _LANE_TILE,
-            (d_nope + self.d_rope) ** -0.5,
+            scale,
             (self.d_model / q_rank) ** 0.5 if rescale else 1.0,
             (self.d_model / kv_rank) ** 0.5 if rescale else 1.0)
+
+    @property
+    def yarn_score_factor(self) -> float:
+        """What YaRN puts on the soft-max scale: (0.1 mscale_all_dim
+        ln(factor) + 1)^2, 1 without it."""
+        if self.yarn is None or not self.yarn_mscale_all_dim:
+            return 1.0
+        return (0.1 * self.yarn_mscale_all_dim
+                * math.log(self.yarn.factor) + 1.0) ** 2
 
     @property
     def row_width(self) -> int:
@@ -314,7 +374,11 @@ class MLAMoEConfig:
 
         ffn = d * self.n_experts + 3 * d * self.d_shared \
             + self.held[1] * 3 * d * self.d_expert
-        return (2 * self.vocab_size * d
+        # Two mixes a layer: Phi, three alphas and b (`ops.hyper_connections`).
+        c = n_coefficients(self.hc_mult)
+        mixing = 2 * self.n_layers * (self.hc_mult * d * c + 3 + c) \
+            if self.hc_mult else 0
+        return (2 * self.vocab_size * d + mixing
                 + sum(self.n_of(kind) * attn(kind) for kind in _KINDS)
                 + self.n_dense_layers * 3 * d * self.d_ff
                 + self.n_expert_layers * ffn)
@@ -470,7 +534,13 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig):
         # had a deviation of 7 where the other models' have 1, a soft-max
         # so peaked that bfloat16's rounding of a score moved a position's
         # logits by 0.15 of their size (my chip run, PR 49, call C).
-        in_q, in_kv = qr ** -0.5 / k.rescale_q, r ** -0.5 / k.rescale_kv
+        # YaRN's factor on the soft-max scale is a factor on the scores
+        # likewise (2.0 at factor 64: a deviation of 2, and a position's
+        # logits 0.041-0.056 of their size off the reference's where the
+        # other latent models read 0.02-0.03; my chip run, PR 57, call A):
+        # the query's up-projection is drawn that much smaller.
+        in_q = qr ** -0.5 / k.rescale_q / cfg.yarn_score_factor
+        in_kv = r ** -0.5 / k.rescale_kv
         p = {"norm": draw((n, d), 0.1, shift=1.0),
              "wq_a": draw((n, d, qr), d ** -0.5),
              "q_norm": draw((n, qr), 0.1, shift=1.0),
@@ -516,6 +586,24 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig):
               "lm_head": draw((d, cfg.vocab_size), d ** -0.5)}
     if cfg.state_by_slot:
         params["attn_window"] = attn(cfg.n_of("window"), "window")
+    if cfg.hc_mult:
+        # The mixing's parameters, every layer's attention's and every
+        # layer's FFN's, float32 whatever `param_dtype` is, drawn behind
+        # everything else so that a model without streams keeps its
+        # parameters.  No config gives a seeding: alpha 1, Phi N(0, 1 /
+        # (n d)) and b N(0, 1), not the paper's near-identity start, so
+        # that the dynamic part moves every coefficient by far more than
+        # a tolerance and a program that skips it, the projection or the
+        # clip is another function.
+        n, c = cfg.hc_mult, n_coefficients(cfg.hc_mult)
+
+        def mixing():
+            return {"phi": draw((cfg.n_layers, c, n * d), (n * d) ** -0.5,
+                                F32),
+                    "alpha": jnp.ones((cfg.n_layers, 3), F32),
+                    "b": draw((cfg.n_layers, c), 1.0, F32)}
+
+        params["hc_attn"], params["hc_ffn"] = mixing(), mixing()
     return params
 
 
@@ -535,8 +623,8 @@ def _latent_row(ap, u, positions, cfg, k: _Kind):
     c = rms_norm(ckr[..., :r], ap["kv_norm"], eps=cfg.norm_eps)
     if cfg.latent_rescale:
         c = c * k.rescale_kv
-    k_r = apply_rope(ckr[..., None, r:], positions,
-                     theta=k.theta)[..., 0, :]
+    k_r = apply_rope(ckr[..., None, r:], positions, theta=k.theta,
+                     yarn=cfg.yarn)[..., 0, :]
     return _to_width(jnp.concatenate([c, k_r], axis=-1), k.row_width)
 
 
@@ -551,7 +639,7 @@ def _queries(ap, u, positions, cfg, k: _Kind):
     q = jnp.einsum("skr,re->ske", cq, ap["wq_b"].astype(cd)).reshape(
         *u.shape[:2], k.heads, k.d_nope + cfg.d_rope)
     return q[..., :k.d_nope], apply_rope(q[..., k.d_nope:], positions,
-                                         theta=k.theta), cq
+                                         theta=k.theta, yarn=cfg.yarn), cq
 
 
 def _index(ap, u, cq, idx, at, lanes, cfg):
@@ -569,11 +657,12 @@ def _index(ap, u, cq, idx, at, lanes, cfg):
             jnp.einsum("skd,de->ske", u, ap["wk_idx"].astype(cd)),
             ap["k_idx_norm"], ap["k_idx_bias"], eps=cfg.index_norm_eps)
         key = apply_rope(key[..., None, :], positions, theta=theta,
-                         rotary_dim=cfg.d_rope)[..., 0, :]
+                         yarn=cfg.yarn, rotary_dim=cfg.d_rope)[..., 0, :]
         idx = idx.at[at, lanes.wb, lanes.off].set(key.astype(idx.dtype))
         q = jnp.einsum("skr,re->ske", cq, ap["wq_idx"].astype(cd)).reshape(
             *u.shape[:2], hi, di)
-        q = apply_rope(q, positions, theta=theta, rotary_dim=cfg.d_rope)
+        q = apply_rope(q, positions, theta=theta, yarn=cfg.yarn,
+                       rotary_dim=cfg.d_rope)
         w = jnp.einsum("skd,dh->skh", u, ap["w_idx"].astype(cd)) \
             .astype(F32) * (hi ** -0.5 * di ** -0.5)
         scores = paged_index_scores(
@@ -696,14 +785,24 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     what every row took else None, the top-k choices of live rows that
     fell on held experts, summed likewise).  What a row took: the experts
     of every expert layer (expert layers, S, K, top_k); from a model that
-    selects positions a dict of that under "experts" and, under
-    "selected", the positions every full layer attended (full layers, S,
-    K, index_top_k).  Write-then-read, as the paged step: pool, index
+    selects positions or mixes streams a dict of that under "experts"
+    and, under "selected", the positions every full layer attended (full
+    layers, S, K, index_top_k), under "hc_defect" the largest defect of
+    the row's own mixes (1, S, K).  Write-then-read, as the paged step: pool, index
     keys and rings are the layer loop's carry.  The leading dense layers
     run before the scan, which runs over the periods of the layer
     pattern, its body a period's layers, and indexes the weight stacks
     (`ops.moe` says why); the layers behind the last whole period run
-    after it."""
+    after it.
+
+    With `hc_mult` the hidden state between sub-blocks is the position's
+    streams laid flat, (S, K, hc_mult d): the embedding copied into each
+    behind the lookup, every sub-block read through a mix-down and added
+    through a mix-up (`ops.hyper_connections`), the streams summed before
+    the hidden state is handed back, (S, K, d) as ever.  A sixth value
+    then: the largest defect of the projected stream-to-stream matrices
+    over the call's valid rows and its mixes (None from a model with one
+    stream)."""
     cd = cfg.compute_dtype
     if cfg.state_by_slot and slots is None:
         raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
@@ -725,6 +824,9 @@ def _served_step(params, state: LatentState, tokens, block_tables,
                                   scale=k.scale),
                 slots, positions, kv_len, cfg.window, state.ring.shape[1]))
     x = params["embed"].astype(cd)[tokens]
+    hc = cfg.hc_mult
+    if hc:
+        x = jnp.tile(x, (1, 1, hc))     # every stream starts as the embedding
     ffn = {k: v for k, v in params["ffn"].items()
            if k not in _EXPERT_WEIGHTS}
     experts = {k: params["ffn"][k] for k in _EXPERT_WEIGHTS}
@@ -734,24 +836,48 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     lead = {kind: kinds[:nd].count(kind) for kind in _KINDS}
     stacks = {"full": params["attn"], "window": params.get("attn_window")}
 
-    def attend(x, seq, kind, at):
+    def read(x, defect, mixing, layer):
+        """What the sub-block of layer `layer()` reads of x, how its
+        output goes back (None: added), and the defect so far.  (The
+        layer's index is made here, so that a model without streams
+        lowers to the text it had.)"""
+        if not hc:
+            return x, None, defect
+        hp = _take(params[mixing], layer())
+        u, h_post, h_res, short = hc_coefficients(
+            x, hp["phi"], hp["alpha"], hp["b"], n=hc,
+            iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            clamp=cfg.hc_res_clamp)
+        return u, (h_res, h_post), jnp.maximum(defect, short)
+
+    def add(x, out, mix):
+        return x + out if mix is None else hc_mix_up(x, out, *mix, n=hc)
+
+    def attend(x, defect, seq, kind, at, layer):
+        u, mix, defect = read(x, defect, "hc_attn", layer)
         with jax.named_scope("mla_attn"):
             out, seq, selected = _attention(
-                _take(stacks[kind], at), x, seq, kind, at, lanes, cfg,
+                _take(stacks[kind], at), u, seq, kind, at, lanes, cfg,
                 routing)
-        return x + out, seq, selected
+        return add(x, out, mix), defect, seq, selected
 
     def expert_layer(carry, i, j):
         """Layer `j` of period `i` behind the leading layers."""
-        x, seq, visited, routed = carry
+        x, seq, visited, routed, defect = carry
         kind = period[j]
-        x, seq, selected = attend(
-            x, seq, kind,
-            lead[kind] + _nth(i, per[kind], period[:j].count(kind)))
+
+        def layer():
+            return nd + _nth(i, len(period), j)
+
+        x, defect, seq, selected = attend(
+            x, defect, seq, kind,
+            lead[kind] + _nth(i, per[kind], period[:j].count(kind)), layer)
         li = _nth(i, len(period), j)
-        out, n, r, taken = _expert_ffn(_take(ffn, li), experts, li, x, valid,
+        u, mix, defect = read(x, defect, "hc_ffn", layer)
+        out, n, r, taken = _expert_ffn(_take(ffn, li), experts, li, u, valid,
                                        cfg, routing)
-        return (x + out, seq, visited + n, routed + r), taken, selected
+        return (add(x, out, mix), seq, visited + n, routed + r,
+                defect), taken, selected
 
     def run(carry, i, n):
         """The first `n` layers of period `i`."""
@@ -764,16 +890,19 @@ def _served_step(params, state: LatentState, tokens, block_tables,
 
     seq = (state.kv, state.idx, state.ring)
     chosen = []                     # the full layers' selections, in order
+    defect = jnp.zeros(tokens.shape, F32) if hc else None   # a row's largest
     for j in range(nd):
-        x, seq, selected = attend(x, seq, kinds[j],
-                                  kinds[:j].count(kinds[j]))
+        layer = functools.partial(int, j)
+        x, defect, seq, selected = attend(x, defect, seq, kinds[j],
+                                          kinds[:j].count(kinds[j]), layer)
         chosen += [] if selected is None else [selected[None]]
-        x = x + _dense_ffn(_take(params["dense"], j), x, cfg)
+        u, mix, defect = read(x, defect, "hc_ffn", layer)
+        x = add(x, _dense_ffn(_take(params["dense"], j), u, cfg), mix)
     n_periods, n_tail = divmod(cfg.n_expert_layers, len(period))
     zero, none = jnp.int32(0), routed_zero(tokens.size, cfg.moe)
     carry, (taken, selected) = jax.lax.scan(
-        lambda carry, i: run(carry, i, len(period)), (x, seq, zero, none),
-        jnp.arange(n_periods))
+        lambda carry, i: run(carry, i, len(period)),
+        (x, seq, zero, none, defect), jnp.arange(n_periods))
 
     def in_order(by_rank):
         """(periods, ..) a layer of the period -> (layers, ..)."""
@@ -790,8 +919,17 @@ def _served_step(params, state: LatentState, tokens, block_tables,
         if routing:
             taken = jnp.concatenate([taken, jnp.stack(more)])
             chosen += [sel[None] for sel in selected]
-    x, (kv, idx, ring), visited, routed = carry
-    if routing and cfg.index_top_k:
-        taken = {"experts": taken, "selected": jnp.concatenate(chosen)}
+    x, (kv, idx, ring), visited, routed, defect = carry
+    if hc:      # the final norm and the head read the sum of the streams
+        x = sum(x[..., i * cfg.d_model:(i + 1) * cfg.d_model].astype(F32)
+                for i in range(hc)).astype(cd)
+    if routing and (cfg.index_top_k or hc):
+        taken = {"experts": taken}
+        if cfg.index_top_k:
+            taken["selected"] = jnp.concatenate(chosen)
+        if hc:
+            taken["hc_defect"] = defect[None]
+    if hc:
+        defect = jnp.max(jnp.where(valid, defect, 0.0))
     return (LatentState(kv=kv, idx=idx, ring=ring), x, visited,
-            taken if routing else None, routed)
+            taken if routing else None, routed, defect)

@@ -127,6 +127,9 @@ TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "kv_read_tokens", "reset_s", "experts_read", "ahead",
                "starved_s", "moe_tiles", "index_scored_tokens",
                "kv_selected_tokens", "blocks", "passes", "block_tokens")
+# Behind them in the records of a model whose residual is several streams,
+# and of no other (`engine_stats()["tick_fields"]` says which a log has).
+_HC_RES_DEFECT = "hc_res_defect"
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -141,7 +144,8 @@ class _TickAccounts:
     folded into one tick-log record by PagedLLMEngine._tick."""
     __slots__ = ("decode_s", "prefill_s", "sample_s", "lanes", "width",
                  "prefill_tokens", "kv_read_tokens", "reset_s",
-                 "routed_at", "experts_read", "ahead", "starved_s",
+                 "routed_at", "defect_at", "experts_read", "ahead",
+                 "starved_s",
                  "index_scored_tokens", "kv_selected_tokens", "blocks",
                  "passes", "block_tokens")
 
@@ -156,6 +160,9 @@ class _TickAccounts:
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
         self.routed_at = -1
+        # Likewise where the largest defect of the tick's stream mixes is
+        # kept (`hc_res_defect`); -1: the tick launched nothing that mixes.
+        self.defect_at = -1
 
 
 class _Burst:
@@ -450,7 +457,11 @@ class PagedLLMEngine:
     model with experts: does it hold one rank's share of them
     (`models.decoding.counts_routed`)?  Its chunk and burst then hand
     out the top-k choices that fell on the share, and a tick's record
-    carries their sum (`routed_here`, `_count_routed`).
+    carries their sum (`routed_here`, `_count_routed`).  A fourth: is
+    its residual several streams (`models.decoding.counts_defect`)?
+    Its chunk and burst then hand out, last, how far their stream mixes
+    stopped from the doubly stochastic matrices, and a tick's record
+    carries the largest (`hc_res_defect`).
     """
     TICKS_KEPT = 4096
 
@@ -473,6 +484,7 @@ class PagedLLMEngine:
 
         from ray_tpu.core.config import get_config
         from ray_tpu.models.decoding import (
+            counts_defect,
             counts_routed,
             init_sequence_state,
             make_paged_engine_fns,
@@ -688,6 +700,17 @@ class PagedLLMEngine:
                 lambda sums, at, n, fresh: sums.at[at].set(
                     jnp.where(fresh, 0, sums[at])
                     + jnp.pad(n.reshape(-1), (0, 2 - n.size))))
+        # A model of several residual streams: the largest defect of a
+        # tick's mixes, kept on the device as the sums above are.
+        self._defects = None
+        self.tick_fields = TICK_FIELDS
+        if counts_defect(cfg):
+            self.tick_fields += (_HC_RES_DEFECT,)
+            self._defects = jnp.zeros((self.TICKS_KEPT + 8,), jnp.float32)
+            self._defect_next = 0
+            self._max_defect = jax.jit(
+                lambda kept, at, d, fresh: kept.at[at].set(
+                    jnp.maximum(jnp.where(fresh, 0.0, kept[at]), d)))
         self._prefill_chunk_fn, self._decode, self._copy_block = \
             make_paged_engine_fns(cfg)
         if self._spec_k:
@@ -883,7 +906,7 @@ class PagedLLMEngine:
                       "state_rebuilds": s.pop("state_rebuilds")}
         if records:
             s["tick_log"] = self._with_routed(_snapshot(self._tick_log))
-            s["tick_fields"] = TICK_FIELDS
+            s["tick_fields"] = self.tick_fields
             # The replica's start on one clock: its spans, and every
             # program the process has traced, lowered and loaded or
             # compiled (a tier compiled inside serving is there too).
@@ -1065,40 +1088,57 @@ class PagedLLMEngine:
             self._acct.starved_s += time.time() - self._idle_from
             self._idle_from = 0.0
 
-    def _count_routed(self, routed) -> None:
-        """`routed`: what a chunk or a burst of a model that holds a
-        share of its experts handed out last (the top-k choices that
-        fell on the share, from a launch that grouped its rows by expert
-        with the tiles it multiplied; nothing from any other model).
-        Added, on the device, to the sum of the tick that launched it:
-        one small launch and no read, so that a chunk stays queued behind
-        the host and a burst ahead of its read.  `_with_routed` reads the
-        sums when the records are asked for."""
-        if not routed:
-            return
+    def _count_routed(self, handed) -> None:
+        """`handed`: what a chunk or a burst handed out behind its own
+        results.  From a model that holds a share of its experts the
+        top-k choices that fell on the share (from a launch that grouped
+        its rows by expert with the tiles it multiplied); then, from a
+        model of several residual streams, the largest defect of the
+        launch's mixes; nothing from any other model.  Each is added (the
+        defect: its largest kept), on the device, to what the tick that
+        launched it holds: one small launch and no read, so that a chunk
+        stays queued behind the host and a burst ahead of its read.
+        `_with_routed` reads both when the records are asked for."""
+        handed = list(handed)
         acct = self._acct
-        fresh = acct.routed_at < 0
-        if fresh:
-            acct.routed_at = self._routed_next % self._routed_sums.shape[0]
-            self._routed_next += 1
-        self._routed_sums = self._add_routed(
-            self._routed_sums, self._jnp.int32(acct.routed_at), routed[0],
-            fresh)
+        if self._routed_sums is not None and handed:
+            fresh = acct.routed_at < 0
+            if fresh:
+                acct.routed_at = \
+                    self._routed_next % self._routed_sums.shape[0]
+                self._routed_next += 1
+            self._routed_sums = self._add_routed(
+                self._routed_sums, self._jnp.int32(acct.routed_at),
+                handed.pop(0), fresh)
+        if self._defects is not None and handed:
+            fresh = acct.defect_at < 0
+            if fresh:
+                acct.defect_at = self._defect_next % self._defects.shape[0]
+                self._defect_next += 1
+            self._defects = self._max_defect(
+                self._defects, self._jnp.int32(acct.defect_at),
+                handed.pop(0), fresh)
 
     def _with_routed(self, log: tuple) -> tuple:
         """The tick log with each record's `routed_here` and `moe_tiles`
-        read from the device's sums (0 where the tick counted none).  A
-        record is among the last TICKS_KEPT, so its sums have not been
-        reused."""
-        if self._routed_sums is None or not log:
+        read from the device's sums and its `hc_res_defect` from the
+        device's largest (0 where the tick counted none).  A record is
+        among the last TICKS_KEPT, so its sums have not been reused."""
+        if (self._routed_sums is None and self._defects is None) or not log:
             return log
-        sums = np.asarray(self._routed_sums)
+        sums = None if self._routed_sums is None \
+            else np.asarray(self._routed_sums)
+        defects = None if self._defects is None \
+            else np.asarray(self._defects)
 
         def read(t):
             t = list(t)
-            t[_ROUTED_HERE], t[_MOE_TILES] = \
-                map(int, sums[t[_ROUTED_HERE]]) if t[_ROUTED_HERE] >= 0 \
-                else (0, 0)
+            if sums is not None:
+                t[_ROUTED_HERE], t[_MOE_TILES] = \
+                    map(int, sums[t[_ROUTED_HERE]]) \
+                    if t[_ROUTED_HERE] >= 0 else (0, 0)
+            if defects is not None:     # the record's last field
+                t[-1] = float(defects[t[-1]]) if t[-1] >= 0 else 0.0
             return tuple(t)
 
         return tuple(read(t) for t in log)
@@ -1822,6 +1862,12 @@ class PagedLLMEngine:
         lanes at the launch: under blocks x B where a prompt's tail or
         `max_tokens` cuts a block.  For such a model `experts_read` is per
         layer and pass.
+        `hc_res_defect` (behind them, in the records of a model whose
+        residual is several streams and of no other: `tick_fields` of
+        the stats names a log's fields): the largest |row sum - 1|
+        and |column sum - 1| of the projected stream-to-stream matrices
+        over the valid rows and the mixes of the tick's chunks and of its
+        burst's steps, reduced on the device and read with the records.
 
         The tick runs on the phase clock (`_PhaseClock`): it starts in
         `admit`, its parts switch the leaf as they go, and it ends in
@@ -1857,6 +1903,8 @@ class PagedLLMEngine:
                        acct.experts_read, acct.ahead, acct.starved_s, 0,
                        acct.index_scored_tokens, acct.kv_selected_tokens,
                        acct.blocks, acct.passes, acct.block_tokens]
+                if self._defects is not None:
+                    row.append(acct.defect_at)
                 b = self._inflight
                 if b is not None and b.row is None:
                     b.row = row     # this tick's burst: logged at its read

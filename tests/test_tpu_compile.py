@@ -850,6 +850,64 @@ def test_mla_moe_served_programs_fit_one_chip(topo, program):
 
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk c=512"])
+def test_mhc_mla_served_programs_fit_one_chip(topo, program):
+    """Xing4.0-29B-A4B at the benchmark's cut (7 of 40 layers: one dense
+    layer and six expert layers, all 64 experts, the whole vocabulary) and
+    serving shape (8 slots x 8,192, block 16: a latent pool of 4,097
+    blocks x 16 rows of 640 = 0.59 GB beside 11.08 GB of weights): the
+    width-8 burst and the widest chunk tier (512 rows) compile for one
+    v5e chip and fit its 15.75 GB usable.  **The four streams' mixing is
+    this repo's three Pallas kernels** (`hc_pre`, `hc_sinkhorn`,
+    `hc_post`: a `tpu_custom_call` of each for the leading layer's two
+    mixes and for the two of the scan's body), which Mosaic compiles
+    here at the published width (a row's 4 x 3,584 values laid flat), and
+    every op that touches a row's streams shows them as the family's
+    `hc_operand` says; no mix leaves a loop of rounds in the program."""
+    import json
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "xing4.0-29b-a4b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state = resident["sequence_state"]
+    assert state.kv.shape == (7, 4097, 16, 640)
+    state_bytes = state.kv.size * 2
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize
+        for s in jax.tree.leaves(resident["params"]))
+    assert abs(resident_bytes - 11.67e9) < 0.01e9, resident_bytes
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in ("hc_pre", "hc_sinkhorn", "hc_post"):
+        mine = [c for c in calls if f"%{kernel}." in c.split("=")[0]]
+        assert len(mine) == 4, (kernel, len(mine))
+    streams = fam.hc_operand(config)
+    assert all(streams.search(c) for c in calls
+               if "%hc_pre." in c.split("=")[0]
+               or "%hc_post." in c.split("=")[0])
+    # no loop runs the 20 Sinkhorn rounds: they are the kernel's
+    assert '"known_trip_count":{"n":"20"}' not in text
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
                                      "paged_prefill_chunk"])
 def test_gated_moe_served_programs_fit_one_chip(topo, program):
     """Laguna-XS.2 at the benchmark's cut (layer 0 and two periods: three
