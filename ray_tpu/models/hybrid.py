@@ -423,7 +423,8 @@ def _served_step(params, state: HybridState, tokens, block_tables, positions,
     lane = slots[:, None]
     x = params["embed"].astype(cd)[tokens]
     n_half = cfg.n_layers // 2
-    # A window layer reads its rings where they lie (ops.attention).
+    # A window layer reads its lanes' rings, or every slot's where they lie
+    # (ops.attention.slot_ring_reader).
     ring_attention = slot_ring_reader(
         window_diff_attention, slots, positions, kv_len, cfg.window,
         state.wk.shape[1])

@@ -1837,24 +1837,78 @@ def ring_seen(positions, kv_len, ring: int, window: int):
     return (held <= pos) & (held > pos - window) & (held >= 0)
 
 
+# Which rings a burst's window layer reads, by the bare burst's time (ms a
+# burst of 8 steps, every lane live at 2,048 positions, median of 12; my
+# chip runs, PR 58, call A, one TPU v5 lite).  "in place": every slot's
+# rings where they lie, the lanes' queries put at their slots; "joined":
+# the lanes' rings alone, a `dynamic_slice` a lane; "a lane at a time":
+# those slices, `attend` called once a lane.
+#
+#   lanes of slots      in place   joined   a lane at a time
+#   Mellum2   4 of 33     32.59     25.66     25.75
+#             8 of 33     32.95     27.38     27.75
+#            16 of 33     34.69     31.26     31.18
+#            32 of 33     37.50     39.09       -
+#   phi4flash 4 of 33    111.46    102.15    102.49
+#             8 of 33    118.10    111.32    111.42
+#            16 of 33    132.32    129.47    130.26
+#            32 of 33    167.30    171.76       -
+#   Laguna    4 of 9      25.76     23.61     23.12
+#             8 of 9      26.59     26.42     26.69
+#   dots3     4 of 9      36.94     35.47     36.07
+#             8 of 9      41.87     40.78     41.58
+#
+# (Rings a slot: Mellum2 6 of 1,152 x 4 x 128, phi4flash 8 of 640 x 1,280
+# for differential heads, Laguna 6 of 1,024 x 8 x 128, dots3 3 of 1,040 x
+# 1,152 latent rows.)  Joined wins wherever the lanes are at most half the
+# slots and loses where they are all of them but one on 33 (it copies what
+# the other reads where it lies); at 8 of 9 it is a draw on Laguna and
+# 2.6% on dots3, whose traffic never fills that tier, so the rule stays
+# the one line below.  A lane at a time is no faster than the join and is
+# not kept.
+def _lanes_rings(lanes: int, n_slots: int) -> bool:
+    """Whether a call of `lanes` lanes reads its lanes' rings alone, of
+    `n_slots` (the null slot counted): the table above."""
+    return 2 * lanes <= n_slots
+
+
+def ring_slots_read(lanes: int, n_slots: int) -> int:
+    """The slots whose rings one window layer of a call of `lanes` lanes
+    reads through `slot_ring_reader`."""
+    return lanes if _lanes_rings(lanes, n_slots) else n_slots
+
+
 def slot_ring_reader(attend, slots, positions, kv_len, window: int,
                      n_slots: int):
     """`read(q, k_rings, v_rings, layer)`: `attend(q, k_ring, v_ring,
     positions, kv_len, window)` of the lanes' queries over their slots'
-    rings of `[layer]`, read where they lie.  One lane (a prefill chunk):
-    its slot's ring, a slice.  Several (a burst): every slot's ring, in
+    rings of `[layer]`.  A call narrow against the slots (`_lanes_rings`;
+    one lane: a prefill chunk): each lane's ring where it lies, a
+    `dynamic_slice` a lane, joined along the lane axis; an idle lane
+    carries the null slot and length 0, reads that ring and sees nothing.
+    A call as wide as most of the slots: every slot's ring in place, in
     slot order, with the lanes' queries put at their `slots` (S,) and the
-    answers taken back; a gather of the lanes' rings would be a copy of
-    them (and the compiler makes it one of the whole array).  Slots that
-    are no lane of the call have length 0 and see nothing.  `n_slots`
-    counts the null slot."""
-    if positions.shape[0] == 1:
+    answers taken back; slots that are no lane of the call have length 0
+    and see nothing.  What the compiler makes of each (the compiled text
+    for a described v5e, Mellum2's burst at width 4 of 33): of
+    `rings[layer, slots]`, a gather, slices of the whole ring array
+    `(6,33,384,4,128)` and more bytes than in place (XLA's count 2.28 ->
+    7.49 GB), as when PR 30 first tried it; of the per-lane slices,
+    `(1,1,1152,4,128)` slices and `(4,1152,4,128)` arrays, no array of 33
+    but the rings' write (2.28 -> 1.58 GB).  `n_slots` counts the null
+    slot."""
+    lanes = positions.shape[0]
+    if _lanes_rings(lanes, n_slots):
         def read(q, k_rings, v_rings, layer):
-            at = (layer, slots[0]) + (0,) * (k_rings.ndim - 2)
+            ats = [(layer, slots[j]) + (0,) * (k_rings.ndim - 2)
+                   for j in range(lanes)]
             size = (1, 1) + k_rings.shape[2:]
-            return attend(q, jax.lax.dynamic_slice(k_rings, at, size)[0],
-                          jax.lax.dynamic_slice(v_rings, at, size)[0],
-                          positions, kv_len, window)
+
+            def joined(rings):
+                return jnp.concatenate([jax.lax.dynamic_slice(
+                    rings, at, size) for at in ats], axis=1)[0]
+            return attend(q, joined(k_rings), joined(v_rings), positions,
+                          kv_len, window)
         return read
     pos_all = jnp.zeros((n_slots,) + positions.shape[1:],
                         positions.dtype).at[slots].set(positions)

@@ -126,7 +126,8 @@ TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
                "lanes", "width", "prefill_tokens", "routed_here",
                "kv_read_tokens", "reset_s", "experts_read", "ahead",
                "starved_s", "moe_tiles", "index_scored_tokens",
-               "kv_selected_tokens", "blocks", "passes", "block_tokens")
+               "kv_selected_tokens", "blocks", "passes", "block_tokens",
+               "ring_slots")
 # Behind them in the records of a model whose residual is several streams,
 # and of no other (`engine_stats()["tick_fields"]` says which a log has).
 _HC_RES_DEFECT = "hc_res_defect"
@@ -147,7 +148,7 @@ class _TickAccounts:
                  "routed_at", "defect_at", "experts_read", "ahead",
                  "starved_s",
                  "index_scored_tokens", "kv_selected_tokens", "blocks",
-                 "passes", "block_tokens")
+                 "passes", "block_tokens", "ring_slots")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
@@ -155,7 +156,7 @@ class _TickAccounts:
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
         self.index_scored_tokens = self.kv_selected_tokens = 0
-        self.blocks = self.passes = self.block_tokens = 0
+        self.blocks = self.passes = self.block_tokens = self.ring_slots = 0
         # Where the tick's `routed_here` and `moe_tiles` are summed on the
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
@@ -494,6 +495,7 @@ class PagedLLMEngine:
             sample_one,
             take_last,
         )
+        from ray_tpu.ops.attention import ring_slots_read
         from ray_tpu.serve.kv_cache import KVBlockAllocator
 
         knobs = get_config()
@@ -667,6 +669,12 @@ class PagedLLMEngine:
             cfg, self.num_blocks, self.block_size, num_slots=num_slots,
             prefill_chunk=self.prefill_chunk, shardings=cache_sh)
         self._state_bytes = self.cache.resident_bytes()
+        # The slots whose rings a burst of each width reads in a window
+        # layer (the tick's `ring_slots`).
+        self._ring_slots = {
+            w: ring_slots_read(w, num_slots + 1)
+            if self._state_bytes["kv_window"] else 0
+            for w in self._width_tiers}
         self._reset_state = (jax.jit(cfg.reset_slot, donate_argnums=(0,))
                              if self._recurrent else None)
         self._score_step = self._score_chunk = None
@@ -1510,6 +1518,7 @@ class PagedLLMEngine:
             self._acct.lanes, self._acct.width = len(idx), w
             self._acct.kv_read_tokens = self._kv_read_tokens(
                 [int(self._lengths[i]) for i in idx])
+            self._acct.ring_slots = self._ring_slots[w]
             first = self._burst_input(idx, w)
             tables = np.zeros((w, self._b_max), np.int32)
             lengths = np.zeros((w,), np.int32)
@@ -1854,7 +1863,7 @@ class PagedLLMEngine:
         layer), and the positions those rows then attended (at most
         `index_top_k` each): the host's count from the lengths, as
         `kv_read_tokens`.
-        `blocks`, `passes`, `block_tokens` (the record's last), from a
+        `blocks`, `passes`, `block_tokens` (behind them), from a
         model that generates by diffusion over blocks (0 from any other):
         the blocks the burst's lanes filled (lanes x `max_burst` // B),
         the forward passes the burst ran, each over every lane (blocks a
@@ -1862,6 +1871,11 @@ class PagedLLMEngine:
         lanes at the launch: under blocks x B where a prompt's tail or
         `max_tokens` cuts a block.  For such a model `experts_read` is per
         layer and pass.
+        `ring_slots` (the record's last): the slots whose rings one step
+        of the tick's burst reads in a window layer
+        (`ops.attention.ring_slots_read`: the burst's width where it is
+        narrow against the slots, else every slot and the null slot; 0
+        for a model without rings or a tick without a burst).
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -1902,7 +1916,8 @@ class PagedLLMEngine:
                        acct.kv_read_tokens, acct.reset_s,
                        acct.experts_read, acct.ahead, acct.starved_s, 0,
                        acct.index_scored_tokens, acct.kv_selected_tokens,
-                       acct.blocks, acct.passes, acct.block_tokens]
+                       acct.blocks, acct.passes, acct.block_tokens,
+                       acct.ring_slots]
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -258,8 +258,8 @@ def test_with_speculation_every_burst_is_read_in_its_own_tick(eng):
 
 # -- (f) the tick log says which bursts ran ahead ----------------------------
 def test_tick_log_marks_every_burst_but_a_busy_periods_first(eng):
-    assert TICK_FIELDS[-8:-6] == ("ahead", "starved_s")
-    assert eng.engine_stats()["tick_fields"][-9:-3] == (
+    assert TICK_FIELDS[-9:-7] == ("ahead", "starved_s")
+    assert eng.engine_stats()["tick_fields"][-10:-4] == (
         "experts_read", "ahead", "starved_s", "moe_tiles",
         "index_scored_tokens", "kv_selected_tokens")
     for period in range(2):

@@ -200,7 +200,7 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
                      routing=True)
     assert [int(jnp.argmax(g)) for g in got[0]][:-1] == out
     stats = e.engine_stats()
-    assert stats["tick_fields"][-5:-3] == ("index_scored_tokens",
+    assert stats["tick_fields"][-6:-4] == ("index_scored_tokens",
                                            "kv_selected_tokens")
     ticks = [dict(zip(stats["tick_fields"], t))
              for t in stats["tick_log"][before:]]

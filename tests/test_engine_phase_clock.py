@@ -379,10 +379,12 @@ def test_starved_seconds_count_an_empty_queue_with_work_in_hand():
 def test_the_tick_fields_end_with_ahead_and_starved_seconds():
     # and, behind them since PR 46, the tiles a tick's chunks multiplied,
     # since PR 49 what a learned selection scored and attended, since
-    # PR 52 what a burst of denoising passes filled
-    assert TICK_FIELDS[-8:] == ("ahead", "starved_s", "moe_tiles",
+    # PR 52 what a burst of denoising passes filled, since PR 58 the
+    # slots whose rings a burst's window layer reads
+    assert TICK_FIELDS[-9:] == ("ahead", "starved_s", "moe_tiles",
                                 "index_scored_tokens", "kv_selected_tokens",
-                                "blocks", "passes", "block_tokens")
+                                "blocks", "passes", "block_tokens",
+                                "ring_slots")
     assert PHASES == ("wait", "admit", "burst_launch", "burst_read", "emit",
                       "chunk_launch", "first_read", "book")
     eng = _engine()

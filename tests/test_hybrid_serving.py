@@ -224,7 +224,7 @@ def test_a_slot_reused_by_a_second_request(served):
     assert stats["state"]["state_resets"] == resets + 2
     assert stats["prefix_hits"] == 0
     fields = stats["tick_fields"]
-    assert fields[-11:-9] == ("kv_read_tokens", "reset_s")
+    assert fields[-12:-10] == ("kv_read_tokens", "reset_s")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     assert any(t["reset_s"] > 0 for t in ticks)
     one = [t for t in ticks if t["lanes"] == 1][-1]

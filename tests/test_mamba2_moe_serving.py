@@ -445,7 +445,7 @@ def test_the_tick_log_counts_the_tiles_a_wide_chunk_multiplied(
     assert _is_greedy(e, c, prompt, out)
     with e._tick_lock:
         stats = e.engine_stats()
-    assert stats["tick_fields"][-6] == "moe_tiles"
+    assert stats["tick_fields"][-7] == "moe_tiles"
     ticks = [dict(zip(stats["tick_fields"], t))
              for t in stats["tick_log"]][n_logged:]
     wide = [t for t in ticks if t["prefill_tokens"] == 64]

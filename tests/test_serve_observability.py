@@ -489,7 +489,7 @@ def test_tick_log_says_how_many_experts_a_burst_read(model):
         eng.shutdown()
     assert all(len(o) == 12 for o in outs)
     fields = stats["tick_fields"]
-    assert fields[-9:-5] == ("experts_read", "ahead", "starved_s",
+    assert fields[-10:-6] == ("experts_read", "ahead", "starved_s",
                              "moe_tiles")
     ticks = [dict(zip(fields, t)) for t in stats["tick_log"]]
     decoded = [t for t in ticks if t["lanes"] > 0]
