@@ -184,6 +184,38 @@ XING4_0_29B_A4B = MLAMoEConfig(
                      beta_slow=1.0, attention_factor=1.0),
     yarn_mscale_all_dim=1.0)
 
+# Latent attention that selects in every layer with experts chosen group
+# by group, at test size: a dense layer and four expert layers, all full;
+# 4 heads attend to the 16 positions that 4 index heads of 16 score
+# highest, under YaRN (cos and sin x 1, the soft-max scale x (0.1 ln 8 +
+# 1)^2); 16 experts in 4 groups of 4 of which 2 groups are kept, top-3,
+# gates x 2.5, this rank holding half of group 0 (experts 0-1); a shared
+# expert.  Pool blocks alone are the sequence: prefixes are shared,
+# blocks copied on write, frames shipped.
+TINY_GROUP_MOE = dataclasses.replace(
+    TINY_MLA_MOE, name="tiny-group-moe", n_layers=5, n_experts=16,
+    experts_held=(0, 2), expert_groups=4, expert_groups_kept=2,
+    route_scale=2.5, norm_eps=1e-6, index_heads=4, index_dim=16,
+    index_top_k=16,
+    yarn=YarnScaling(factor=8.0, original_max_len=64, attention_factor=1.0),
+    yarn_mscale_all_dim=1.0)
+
+# DeepSeek-V3.2-Exp's published sizes (671.9 B parameters for the 61
+# layers, embedding and head; ~37 B active a token), every expert held:
+# three dense layers, 58 expert layers of 256 experts in 8 groups of which
+# 4 are kept, every layer selecting its 2,048 positions; its
+# multi-token-prediction module is not part of the stack.
+DEEPSEEK_V3_2_EXP = MLAMoEConfig(
+    name="deepseek-v3.2-exp", vocab_size=129280, d_model=7168, n_layers=61,
+    n_dense_layers=3, n_heads=128, q_rank=1536, kv_rank=512, d_nope=128,
+    d_rope=64, d_v=128, d_ff=18432, n_experts=256, expert_top_k=8,
+    d_expert=2048, d_shared=2048, route_scale=2.5, expert_groups=8,
+    expert_groups_kept=4, rope_theta=10000.0, norm_eps=1e-6,
+    max_seq_len=163840, index_heads=64, index_dim=128, index_top_k=2048,
+    yarn=YarnScaling(factor=40.0, original_max_len=4096, beta_fast=32.0,
+                     beta_slow=1.0, attention_factor=1.0),
+    yarn_mscale_all_dim=1.0)
+
 # What a layer can have of its own in the homogeneous stack, at test
 # size: a dense first layer before two periods of three window layers to
 # one full layer, 8 query heads in a window layer and 6 in a full one over
@@ -251,6 +283,7 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_MLA_MOE, GLM_4_7_FLASH,
                                 TINY_DSA_MOE, DOTS3_NOTE_PREV,
                                 TINY_MHC_MLA_MOE, XING4_0_29B_A4B,
+                                TINY_GROUP_MOE, DEEPSEEK_V3_2_EXP,
                                 TINY_GATED_MOE, LAGUNA_XS_2,
                                 TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B]}
 

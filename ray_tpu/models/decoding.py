@@ -349,6 +349,14 @@ def counts_routed(cfg) -> bool:
     return getattr(cfg, "experts_held", None) is not None
 
 
+def counts_groups(cfg) -> bool:
+    """Whether what `counts_routed` counts is three numbers, the last
+    the live rows whose kept groups of experts hold an expert held here
+    (`ops.moe.routed_zero`): a model that holds a share of experts that
+    are chosen group by group."""
+    return counts_routed(cfg) and getattr(cfg, "expert_groups", 1) > 1
+
+
 def counts_defect(cfg) -> bool:
     """Whether the served programs of `cfg` hand out, last of all, how
     far the stream mixes of the launch stopped from the doubly stochastic

@@ -95,8 +95,12 @@ Experts: `ops.moe.moe_mlp_dropless` with `MoEConfig.scoring =
 largest of s + `router_bias` are taken, gated by their own s
 renormalised times `route_scale`; `experts_held` as in
 `models.mamba2_moe` (the router at its published width, the stacks the
-share).  The shared expert is a dense SwiGLU every token takes, added
-once whatever the share.
+share).  With `expert_groups` > 1 the selection is limited to groups
+(the layout of the `deepseek_v3` / `deepseek_v32` families: the
+`expert_groups_kept` groups of consecutive experts whose two best s +
+bias sum highest, the top-k inside them alone; the gates as ever).  The
+shared expert is a dense SwiGLU every token takes, added once whatever
+the share.
 
 **The two kinds, and what a full layer selects.**  A kind has its own
 head count, latent ranks, head widths and rope base (`cfg.kind(name)`; a
@@ -126,8 +130,9 @@ selected rows).  A window layer reads its slot's ring through
 `ring_seen` as every ring.
 Scopes in a profile: `mla_attn` around a layer's attention, inside it
 `dsa_index`, `dsa_attend` (inside it `dsa_select`), `latent_swa`,
-`attn_gate`; `moe`, `shared_mlp`, `dense_mlp`; `hc_pre`, `hc_sinkhorn`,
-`hc_post` around each (a model of several streams).
+`attn_gate`; `moe` (inside it `moe_groups`), `shared_mlp`, `dense_mlp`;
+`hc_pre`, `hc_sinkhorn`, `hc_post` around each (a model of several
+streams).
 
 What a sequence keeps (`LatentState`), three leaves: `kv` (full layers,
 N_blocks, block_size, row_width), paged as ever, block 0 the null block;
@@ -216,6 +221,11 @@ class MLAMoEConfig:
     route_scale: float = 1.8
     # (first, count) of the n_experts held here; None: all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    # Group-limited routing (`ops.moe.MoEConfig.n_groups`): a row's
+    # experts come from the `expert_groups_kept` of `expert_groups` groups
+    # of consecutive experts whose two best selection scores sum highest.
+    expert_groups: int = 1
+    expert_groups_kept: int = 1
     rope_theta: float = 1000000.0
     norm_eps: float = 1e-5
     max_seq_len: int = 202752
@@ -355,7 +365,9 @@ class MLAMoEConfig:
     def moe(self) -> MoEConfig:
         return MoEConfig(num_experts=self.n_experts, top_k=self.expert_top_k,
                          held=self.experts_held, scoring="sigmoid",
-                         route_scale=self.route_scale)
+                         route_scale=self.route_scale,
+                         n_groups=self.expert_groups,
+                         groups_kept=self.expert_groups_kept)
 
     @property
     def num_params(self) -> int:
@@ -783,13 +795,16 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     slots, where window layers keep rings.  Returns (state, hidden (S, K,
     d), experts visited summed over the expert layers, with `routing`
     what every row took else None, the top-k choices of live rows that
-    fell on held experts, summed likewise).  What a row took: the experts
+    fell on held experts, summed likewise: under groups of experts three
+    counts, `ops.moe.routed_zero`).  What a row took: the experts
     of every expert layer (expert layers, S, K, top_k); from a model that
     selects positions or mixes streams a dict of that under "experts"
     and, under "selected", the positions every full layer attended (full
     layers, S, K, index_top_k), under "hc_defect" the largest defect of
-    the row's own mixes (1, S, K).  Write-then-read, as the paged step: pool, index
-    keys and rings are the layer loop's carry.  The leading dense layers
+    the row's own mixes (1, S, K), under "groups" the groups of experts
+    the row kept (expert layers, S, K, expert_groups_kept).
+    Write-then-read, as the paged step: pool, index keys and rings are
+    the layer loop's carry.  The leading dense layers
     run before the scan, which runs over the periods of the layer
     pattern, its body a period's layers, and indexes the weight stacks
     (`ops.moe` says why); the layers behind the last whole period run
@@ -923,8 +938,11 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     if hc:      # the final norm and the head read the sum of the streams
         x = sum(x[..., i * cfg.d_model:(i + 1) * cfg.d_model].astype(F32)
                 for i in range(hc)).astype(cd)
-    if routing and (cfg.index_top_k or hc):
-        taken = {"experts": taken}
+    groups = cfg.expert_groups > 1
+    if routing and (cfg.index_top_k or hc or groups):
+        k = cfg.expert_top_k    # the kept groups ride behind (`ops.moe`)
+        taken = {"experts": taken[..., :k], "groups": taken[..., k:]} \
+            if groups else {"experts": taken}
         if cfg.index_top_k:
             taken["selected"] = jnp.concatenate(chosen)
         if hc:

@@ -52,11 +52,30 @@ class MoEConfig:
     scoring: str = "softmax"
     route_scale: float = 1.0
     grouped_from_rows: int = 0   # `grouped_tile_rows`; 0: _GROUPED_FROM_ROWS
+    # Group-limited routing (under "sigmoid"): the experts stand in
+    # `n_groups` groups of consecutive ones, a group's score is the sum of
+    # its two largest selection scores, and a row's top_k come from the
+    # `groups_kept` groups of largest score alone.  1 / 1: one group, no
+    # expert left out.
+    n_groups: int = 1
+    groups_kept: int = 1
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring={self.scoring!r}: 'softmax' or "
                              f"'sigmoid'")
+        if (self.n_groups, self.groups_kept) != (1, 1):
+            if self.scoring != "sigmoid":
+                raise ValueError("groups of experts are scored by biased "
+                                 "sigmoid scores: scoring='sigmoid'")
+            if self.n_groups < 1 or self.num_experts % self.n_groups:
+                raise ValueError(f"n_groups={self.n_groups} does not divide "
+                                 f"{self.num_experts} experts into groups")
+            if not 1 <= self.groups_kept <= self.n_groups or self.top_k > \
+                    self.groups_kept * (self.num_experts // self.n_groups):
+                raise ValueError(
+                    f"groups_kept={self.groups_kept} of {self.n_groups} "
+                    f"groups must hold top_k={self.top_k} experts")
         if self.held is not None:
             first, count = self.held
             if first < 0 or count < 1 or first + count > self.num_experts:
@@ -147,9 +166,39 @@ def grouped_tile_rows(n_rows: int, cfg: MoEConfig) -> int:
 def routed_zero(n_rows: int, cfg: MoEConfig):
     """What a caller that sums `return_routed` over layers starts from:
     a launch of `n_rows` rows counts (choices routed here, tiles) in the
-    grouped form and the choices alone in the visit."""
+    grouped form and the choices alone in the visit; under groups of
+    experts (choices, tiles, rows whose kept groups hold a held expert)
+    in either."""
+    if cfg.n_groups > 1:
+        return jnp.zeros((3,), jnp.int32)
     return jnp.zeros((2,), jnp.int32) if grouped_tile_rows(n_rows, cfg) \
         else jnp.int32(0)
+
+
+def _group_scores(by_group):
+    """(.., G, size) selection scores -> (.., G): a group's score is the
+    sum of its two largest (of its largest where it has one expert)."""
+    return jnp.sum(jax.lax.top_k(by_group, min(2, by_group.shape[-1]))[0],
+                   axis=-1)
+
+
+def _kept_groups(pick, cfg: MoEConfig):
+    """Group-limited routing's first step.  `pick` (B, T, E) float32
+    selection scores -> (`pick` with every expert outside the row's
+    `groups_kept` groups of largest `_group_scores` at -inf, those groups
+    (B, T, groups_kept) int32, open (B, T) bool: one of them holds an
+    expert held here).  Among equal groups the lower index is kept, as
+    among equal experts."""
+    b, t, e = pick.shape
+    size = e // cfg.n_groups
+    by_group = pick.reshape(b, t, cfg.n_groups, size)
+    _, kept = jax.lax.top_k(_group_scores(by_group), cfg.groups_kept)
+    keep = jnp.any(jax.nn.one_hot(kept, cfg.n_groups, dtype=jnp.bool_),
+                   axis=2)                                      # (B,T,G)
+    first, count = cfg.held or (0, e)
+    here = keep[..., first // size:(first + count - 1) // size + 1]
+    return jnp.where(keep[..., None], by_group, -jnp.inf).reshape(b, t, e), \
+        kept, jnp.any(here, axis=-1)
 
 
 # What the tile kernel may hold of an expert's three matrices at a time,
@@ -601,7 +650,8 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     `return_routing` also the experts each row took, (B, T, top_k) int32
     (a scoring entry hands them to a reference, which then computes the
     same function where two router logits lie closer than the program's
-    rounding).  With `layer`
+    rounding; under `cfg.n_groups` > 1 the groups the row kept ride
+    behind them, (B, T, top_k + groups_kept)).  With `layer`
     (a traced index) the three expert weights are the stacks of all
     layers (L, E, ..) and the visit slices [layer, expert]: a caller
     inside a scan over layers hands the stacks whole, because a layer's
@@ -649,7 +699,15 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
     `live`, `return_routing` and `return_routed` mean the same under
     either.  "sigmoid" reads `params["router_bias"]` (E,) where the
     parameters have one.  Soft-max scoring with `route_scale` 1 traces
-    what it traced before there was a choice.
+    what it traced before there was a choice.  **With `cfg.n_groups` > 1
+    the selection is limited to groups** (`_kept_groups`, in the scope
+    `moe_groups`): the selection scores outside a row's `groups_kept`
+    best groups are masked before the top-k, and nothing else changes
+    (the gates are renormalised over all top_k taken, held or not);
+    `return_routed` then hands out three int32 from either form,
+    (choices routed here, tiles multiplied or 0, the live rows whose kept
+    groups hold an expert held here: the rows that *can* route here).
+    One group traces what it traced before there were groups.
     """
     b, t, d = x.shape
     dtype = x.dtype
@@ -663,9 +721,11 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
         # The bias moves the selection; the gates are the unbiased scores.
         probs = jax.nn.sigmoid(logits.astype(jnp.float32))
         bias = params.get("router_bias")
-        _, expert_idx = jax.lax.top_k(
-            probs if bias is None else probs + bias.astype(jnp.float32),
-            cfg.top_k)
+        pick = probs if bias is None else probs + bias.astype(jnp.float32)
+        if cfg.n_groups > 1:
+            with jax.named_scope("moe_groups"):
+                pick, kept, open_here = _kept_groups(pick, cfg)
+        _, expert_idx = jax.lax.top_k(pick, cfg.top_k)
         gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
     gate_vals = gate_vals / jnp.maximum(
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
@@ -704,10 +764,19 @@ def moe_mlp_dropless(x: jnp.ndarray, params: dict, cfg: MoEConfig, *,
         out = jax.lax.fori_loop(0, visited, visit,
                                 jnp.zeros((b * t, d), jnp.float32))
     out = out.reshape(b, t, d).astype(dtype)
-    res = (out, visited, expert_idx) if return_routing else (out, visited)
+    res = (out, visited)
+    if return_routing:      # under groups the row's kept groups ride behind
+        res += (expert_idx if cfg.n_groups == 1 else jnp.concatenate(
+            [expert_idx, kept.astype(expert_idx.dtype)], axis=-1),)
     if return_routed:
         routed = jnp.sum(chosen).astype(jnp.int32)
-        res += (jnp.stack([routed, tiles]) if tile else routed,)
+        if cfg.n_groups > 1:
+            if live is not None:
+                open_here &= (live[:, None] if live.ndim == 1 else live)
+            res += (jnp.stack([routed, tiles if tile else jnp.int32(0),
+                               jnp.sum(open_here, dtype=jnp.int32)]),)
+        else:
+            res += (jnp.stack([routed, tiles]) if tile else routed,)
     return res
 
 

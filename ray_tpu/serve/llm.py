@@ -131,6 +131,9 @@ TICK_FIELDS = ("start", "tick_s", "decode_s", "prefill_s", "sample_s",
 # Behind them in the records of a model whose residual is several streams,
 # and of no other (`engine_stats()["tick_fields"]` says which a log has).
 _HC_RES_DEFECT = "hc_res_defect"
+# Before it in the records of a model that holds a share of experts chosen
+# group by group, and of no other.
+_GROUP_OPEN_ROWS = "group_open_rows"
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -486,6 +489,7 @@ class PagedLLMEngine:
         from ray_tpu.core.config import get_config
         from ray_tpu.models.decoding import (
             counts_defect,
+            counts_groups,
             counts_routed,
             init_sequence_state,
             make_paged_engine_fns,
@@ -699,19 +703,25 @@ class PagedLLMEngine:
         # summed a tick on the device and read with the records, never
         # inside a tick (`_count_routed`).
         self._routed_sums = None
+        self.tick_fields = TICK_FIELDS
         if counts_routed(cfg):
-            self._routed_sums = jnp.zeros((self.TICKS_KEPT + 8, 2),
+            # Experts chosen group by group: a third count, the rows whose
+            # kept groups hold an expert held here (`group_open_rows`).
+            width = 2
+            if counts_groups(cfg):
+                width = 3
+                self.tick_fields += (_GROUP_OPEN_ROWS,)
+            self._routed_sums = jnp.zeros((self.TICKS_KEPT + 8, width),
                                           jnp.int32)
             self._routed_next = 0
-            # `n`: the choices alone, or (choices, tiles).
+            # `n`: the choices alone, (choices, tiles), or all three.
             self._add_routed = jax.jit(
                 lambda sums, at, n, fresh: sums.at[at].set(
                     jnp.where(fresh, 0, sums[at])
-                    + jnp.pad(n.reshape(-1), (0, 2 - n.size))))
+                    + jnp.pad(n.reshape(-1), (0, width - n.size))))
         # A model of several residual streams: the largest defect of a
         # tick's mixes, kept on the device as the sums above are.
         self._defects = None
-        self.tick_fields = TICK_FIELDS
         if counts_defect(cfg):
             self.tick_fields += (_HC_RES_DEFECT,)
             self._defects = jnp.zeros((self.TICKS_KEPT + 8,), jnp.float32)
@@ -1129,7 +1139,8 @@ class PagedLLMEngine:
 
     def _with_routed(self, log: tuple) -> tuple:
         """The tick log with each record's `routed_here` and `moe_tiles`
-        read from the device's sums and its `hc_res_defect` from the
+        (and `group_open_rows`, where the log has it) read from the
+        device's sums and its `hc_res_defect` from the
         device's largest (0 where the tick counted none).  A record is
         among the last TICKS_KEPT, so its sums have not been reused."""
         if (self._routed_sums is None and self._defects is None) or not log:
@@ -1142,9 +1153,11 @@ class PagedLLMEngine:
         def read(t):
             t = list(t)
             if sums is not None:
-                t[_ROUTED_HERE], t[_MOE_TILES] = \
-                    map(int, sums[t[_ROUTED_HERE]]) \
-                    if t[_ROUTED_HERE] >= 0 else (0, 0)
+                counted = map(int, sums[t[_ROUTED_HERE]]) \
+                    if t[_ROUTED_HERE] >= 0 else (0,) * sums.shape[1]
+                t[_ROUTED_HERE], t[_MOE_TILES], *more = counted
+                if more:                # behind the fields every log has
+                    t[len(TICK_FIELDS)] = more[0]
             if defects is not None:     # the record's last field
                 t[-1] = float(defects[t[-1]]) if t[-1] >= 0 else 0.0
             return tuple(t)
@@ -1876,6 +1889,12 @@ class PagedLLMEngine:
         (`ops.attention.ring_slots_read`: the burst's width where it is
         narrow against the slots, else every slot and the null slot; 0
         for a model without rings or a tick without a burst).
+        `group_open_rows` (behind them, in the records of a model that
+        holds a share of experts chosen group by group and of no other):
+        of the rows `routed_here` counts over, summed over the expert
+        layers likewise, those whose kept groups hold an expert held
+        here, the rows that *can* route here (the program's count,
+        `ops.moe._kept_groups`).
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -1918,6 +1937,8 @@ class PagedLLMEngine:
                        acct.index_scored_tokens, acct.kv_selected_tokens,
                        acct.blocks, acct.passes, acct.block_tokens,
                        acct.ring_slots]
+                if _GROUP_OPEN_ROWS in self.tick_fields:
+                    row.append(0)       # read with `routed_here`
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -934,21 +934,34 @@ def test_logits_check_has_teeth(fault, monkeypatch):
         assert not v["finite"] or v["worst_decided"] > v["bound"], v
 
 
-# -- (vi) the old latent model lowers to the programs it lowered to ---------------
+# -- (vi) the old latent models lower to the programs they lowered to -------------
 # sha256 of the StableHLO text (`lowered.as_text()`: no locations) of the
-# served programs of `tiny-mla-moe`, taken on PR 48's tree (commit e33cf29,
-# this PR's parent) with the shapes below: the kinds of layer, the leaves
-# of `LatentState`, the period's scan and the trees of what the rows took
-# leave GLM-4.7-Flash's programs as they were, to the letter, so that its
-# compiled programs come from the cache as before.
-_LOWERED_AT_PR_48 = {"chunk": "d7d54907d46b5ad4", "burst": "99cd8866034ddb13",
+# served programs with the shapes below.  `tiny-mla-moe`: taken on PR 48's
+# tree (commit e33cf29): the kinds of layer, the leaves of `LatentState`,
+# the period's scan and the trees of what the rows took leave
+# GLM-4.7-Flash's programs as they were, to the letter, so that its
+# compiled programs come from the cache as before.  `tiny-dsa-moe` (dots3's
+# layout) and `tiny-mhc-mla-moe` (Xing4.0's): taken on PR 58's tree (commit
+# 3c96652, PR 59's parent): groups of experts in the selection
+# (`MoEConfig.n_groups`) and their count in the tick log leave the
+# programs of a configuration without groups as they were.
+_LOWERED_AT_THE_PARENT = {
+    "tiny-mla-moe": {"chunk": "d7d54907d46b5ad4", "burst": "99cd8866034ddb13",
                      "copy_block": "de83fbd14fd07de6",
-                     "verify": "c783a012baeae859"}
+                     "verify": "c783a012baeae859"},
+    "tiny-dsa-moe": {"chunk": "a6e75b7f8777bca9", "burst": "3b03be3c982c46a9",
+                     "copy_block": "0ccb71cf37b52b1b"},
+    "tiny-mhc-mla-moe": {"chunk": "ae4eefdbdedeed4b",
+                         "burst": "5967f98f16941ca8",
+                         "copy_block": "4e367a9e3bd6f856",
+                         "verify": "ce4fab5ba1546dcf"}}
 
 
-@pytest.mark.parametrize("program", list(_LOWERED_AT_PR_48))
-def test_the_old_latent_model_lowers_as_at_the_parent(program):
-    cfg = configs.get("tiny-mla-moe")
+@pytest.mark.parametrize("name,program", [
+    (name, program) for name, programs in _LOWERED_AT_THE_PARENT.items()
+    for program in programs])
+def test_the_old_latent_model_lowers_as_at_the_parent(name, program):
+    cfg = configs.get(name)
     params = jax.eval_shape(lambda: cfg.init_params(jax.random.key(0)))
     cache = jax.eval_shape(lambda: decoding.init_sequence_state(
         cfg, 17, 8, num_slots=4, prefill_chunk=32))
@@ -960,14 +973,17 @@ def test_the_old_latent_model_lowers_as_at_the_parent(program):
 
     lanes = (arr(4, 8), arr(4), arr(4, dtype=jnp.bool_),
              arr(4, dtype=jnp.float32), key)
+    by_slot = cfg.state_by_slot         # rings: the lanes' slots ride along
     if program == "chunk":
-        lowered = chunk.lower(params, cache, arr(32), arr(8), arr(), arr())
+        lowered = chunk.lower(params, cache, arr(32), arr(8), arr(), arr(),
+                              **({"slot": arr()} if by_slot else {}))
     elif program == "burst":
-        lowered = burst.lower(params, cache, arr(4), *lanes, n_steps=4)
+        lowered = burst.lower(params, cache, arr(4), *lanes, n_steps=4,
+                              **({"slots": arr(4)} if by_slot else {}))
     elif program == "copy_block":
         lowered = jax.jit(decoding.copy_block).lower(cache, arr(), arr())
     else:
         lowered = decoding.make_paged_spec_fns(cfg).lower(
             params, cache, arr(4, 3), *lanes)
     digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
-    assert digest == _LOWERED_AT_PR_48[program]
+    assert digest == _LOWERED_AT_THE_PARENT[name][program]

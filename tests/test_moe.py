@@ -438,3 +438,134 @@ def test_the_tile_kernel_agrees_with_the_loop_of_tiles(
                                atol=3e-2, rtol=3e-2)
     if "one" in c:
         assert int(visited) == 1 and int(counted[1]) == 2
+
+
+# -- group-limited routing -------------------------------------------------------
+# sha256 of the StableHLO text of a sigmoid-scored layer that holds a share,
+# taken on the parent's tree (commit 3c96652): a launch of 4 rows (the
+# visit) and one of 256 (rows grouped by expert).
+_ONE_GROUP_AT_THE_PARENT = {4: "04b99dc734b3be50", 256: "74f268b0c76ad236"}
+
+
+def _grouped_oracle(logits, bias, n_groups, kept, k, scale):
+    """NumPy: (kept groups, taken experts, gates) a row, ties by the lower
+    index: a group's score the sum of its two largest z, the `kept` groups
+    of largest score, the k largest z inside them, gates the unbiased
+    scores renormalised over the k taken x scale."""
+    s = 1.0 / (1.0 + np.exp(-logits.astype(np.float64)))
+    z = (s + bias).astype(np.float32)
+    size = z.shape[-1] // n_groups
+    groups, taken, gates = [], [], []
+    for row_s, row_z in zip(s, z):
+        by_group = row_z.reshape(n_groups, size)
+        score = np.sort(by_group, axis=-1)[:, -2:].sum(-1, dtype=np.float32)
+        keep = np.argsort(-score, kind="stable")[:kept]
+        inside = np.where(np.isin(np.arange(z.shape[-1]) // size, keep),
+                          row_z, -np.inf)
+        took = np.argsort(-inside, kind="stable")[:k]
+        groups.append(np.sort(keep))
+        taken.append(took)
+        gates.append(scale * row_s[took] / row_s[took].sum())
+    return np.array(groups), np.array(taken), np.array(gates)
+
+
+@pytest.mark.parametrize("rows", [8, 512], ids=["visit", "grouped"])
+@pytest.mark.parametrize("held", [None, (0, 4), (12, 8)],
+                         ids=["all", "half a group", "across two groups"])
+def test_group_limited_routing_is_the_oracle_s(held, rows):
+    """32 experts in 4 groups of 8, 2 kept, top-4 x 2.5; the router's
+    logits and its bias lie on a grid of quarters, so that scores tie
+    (the logits ride in a row's last 32 columns, which the router copies
+    out): the kept groups, the experts taken, the routed sum with the
+    oracle's gates, the choices that fell here and the rows whose kept
+    groups hold a held expert."""
+    from ray_tpu.ops.moe import (
+        grouped_tile_rows, init_moe_params, moe_mlp_dropless)
+
+    e, d, f = 32, 48, 24
+    cfg = MoEConfig(num_experts=e, top_k=4, held=held, scoring="sigmoid",
+                    route_scale=2.5, n_groups=4, groups_kept=2)
+    assert bool(grouped_tile_rows(rows, cfg)) == (rows == 512)
+    first, count = held or (0, e)
+    params = init_moe_params(jax.random.key(0), d, f, cfg, jnp.float32)
+    params = {k: v[first:first + count] for k, v in params.items()
+              if k != "router"}
+    rng = np.random.default_rng(1)
+    logits = np.round(rng.normal(size=(rows, e)) * 4) / 4
+    bias = (np.round(rng.normal(size=(e,)) * 8) / 16).astype(np.float32)
+    x = np.concatenate([rng.normal(size=(rows, d - e)) * 0.5, logits],
+                       -1).astype(np.float32)
+    params["router"] = jnp.concatenate([jnp.zeros((d - e, e)), jnp.eye(e)])
+    params["router_bias"] = jnp.asarray(bias)
+    live = np.arange(rows) < rows - 3
+    out, visited, taken, routed = jax.jit(lambda x: moe_mlp_dropless(
+        x, params, cfg, live=jnp.asarray(live)[None], return_routing=True,
+        return_routed=True))(jnp.asarray(x)[None])
+    groups, want, gates = _grouped_oracle(logits, bias, 4, 2, 4, 2.5)
+    taken = np.asarray(taken[0])
+    np.testing.assert_array_equal(taken[:, :4], want)
+    np.testing.assert_array_equal(np.sort(taken[:, 4:], axis=-1), groups)
+    ref = np.zeros((rows, d), np.float32)
+    for r in np.flatnonzero(live):
+        for ex, g in zip(want[r], gates[r]):
+            if first <= ex < first + count:
+                wg, wu, wd = (np.asarray(params[k][ex - first])
+                              for k in ("w_gate", "w_up", "w_down"))
+                h = x[r] @ wg
+                ref[r] += g * ((h / (1 + np.exp(-h)) * (x[r] @ wu)) @ wd)
+    np.testing.assert_allclose(np.asarray(out[0]), ref, atol=2e-5, rtol=2e-5)
+    here = (want >= first) & (want < first + count) & live[:, None]
+    size = e // 4
+    open_rows = live & np.array([
+        any(first // size <= g <= (first + count - 1) // size for g in row)
+        for row in groups])
+    routed = np.asarray(routed)
+    assert routed.shape == (3,)
+    assert routed[0] == here.sum() and routed[2] == open_rows.sum()
+    assert (routed[1] > 0) == (rows == 512)
+    assert int(visited) == len(set(want[here].tolist()))
+    if held is None:
+        assert routed[2] == live.sum()
+    elif rows == 512:
+        assert 0 < routed[2] < live.sum()
+
+
+def test_one_group_lowers_to_the_text_before_there_were_groups():
+    """`n_groups` = `groups_kept` = 1 is today's routing: the program a
+    sigmoid-scored layer lowered to on the parent's tree (commit 3c96652,
+    digests taken there), a launch that visits and one that groups."""
+    import hashlib
+
+    from ray_tpu.ops.moe import init_moe_params, moe_mlp_dropless
+
+    cfg = MoEConfig(num_experts=16, top_k=4, held=(4, 8), scoring="sigmoid",
+                    route_scale=2.5, n_groups=1, groups_kept=1)
+    assert cfg == MoEConfig(num_experts=16, top_k=4, held=(4, 8),
+                            scoring="sigmoid", route_scale=2.5)
+    params = jax.eval_shape(lambda: init_moe_params(
+        jax.random.key(0), 32, 64, cfg, jnp.float32))
+    params = {k: v if k == "router" else jax.ShapeDtypeStruct(
+        (8,) + v.shape[1:], v.dtype) for k, v in params.items()}
+    params["router_bias"] = jax.ShapeDtypeStruct((16,), jnp.float32)
+    got = {}
+    for rows in (4, 256):
+        lowered = jax.jit(lambda x, p: moe_mlp_dropless(
+            x, p, cfg, return_routing=True, return_routed=True)).lower(
+            jax.ShapeDtypeStruct((1, rows, 32), jnp.float32), params)
+        got[rows] = hashlib.sha256(
+            lowered.as_text().encode()).hexdigest()[:16]
+    assert got == _ONE_GROUP_AT_THE_PARENT
+
+
+def test_groups_that_cannot_be_are_refused():
+    with pytest.raises(ValueError, match="sigmoid"):
+        MoEConfig(num_experts=16, top_k=4, n_groups=4, groups_kept=2)
+    with pytest.raises(ValueError, match="does not divide"):
+        MoEConfig(num_experts=16, top_k=4, scoring="sigmoid", n_groups=3,
+                  groups_kept=2)
+    with pytest.raises(ValueError, match="must hold"):
+        MoEConfig(num_experts=16, top_k=4, scoring="sigmoid", n_groups=8,
+                  groups_kept=1)
+    with pytest.raises(ValueError, match="must hold"):
+        MoEConfig(num_experts=16, top_k=4, scoring="sigmoid", n_groups=4,
+                  groups_kept=5)
