@@ -324,9 +324,11 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
     the routing if asked, the top-k choices that fell on experts held
     here or None from a model that does not count them: `_paged_forward`,
     the largest defect of the call's projected stream mixes or None from
-    a model with one residual stream: `counts_defect`).  A model's own
-    step that has experts (`n_experts`) takes `routing` and returns the
-    first five itself, or all six; one without returns (cache, hidden)."""
+    a model with one residual stream: `counts_defect`, how many of the
+    call's layers read a learned selection as a mask or None from a model
+    that selects nothing: `counts_masked`).  A model's own step that has
+    experts (`n_experts`) takes `routing` and returns the first five
+    itself, or more of them; one without returns (cache, hidden)."""
     own = getattr(cfg, "served_step", None)
     if own is None:
         out = _paged_forward(params, cache, tokens, block_tables,
@@ -339,7 +341,7 @@ def _served_forward(params, cache, tokens, block_tables, positions, kv_len,
     else:
         out = (*own(params, cache, tokens, block_tables, positions, kv_len,
                     slots), jnp.int32(0), None, None)
-    return out if len(out) == 6 else (*out, None)
+    return (*out, *(None,) * (7 - len(out)))
 
 
 def counts_routed(cfg) -> bool:
@@ -364,6 +366,14 @@ def counts_defect(cfg) -> bool:
     launch's rows and mixes): a model whose residual is several streams
     (`hc_mult`) does."""
     return getattr(cfg, "hc_mult", 0) > 0
+
+
+def counts_masked(cfg) -> bool:
+    """Whether the burst of `cfg` hands out, last of all, how many reads
+    of a learned selection took the mask (`ops.attention._attend_masked`'s
+    own predicate, summed over the steps and the layers that select): a
+    model that attends to an indexer's selection (`index_top_k`) does."""
+    return getattr(cfg, "index_top_k", 0) > 0
 
 
 def _served_logits(params, x, cfg):
@@ -539,7 +549,7 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
     `routing` (a scoring entry's): also the experts each lane took in
     every layer, (L, S, top_k).
     """
-    cache, logits, _, taken, _, _ = _paged_decode_logits(
+    cache, logits, _, taken, *_ = _paged_decode_logits(
         params, cache, tokens, block_tables, lengths, active, cfg, slots,
         routing)
     if routing:    # what the rows took: an array, or a model's tree of them
@@ -550,13 +560,13 @@ def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,
 def _paged_decode_logits(params, cache, tokens, block_tables, lengths,
                          active, cfg, slots, routing=False):
     """`paged_decode_step` with the step's count of experts visited and,
-    from a model that counts them, of top-k choices routed here and the
-    defect of its stream mixes."""
-    cache, x, visited, taken, routed, defect = _served_forward(
+    from a model that counts them, of top-k choices routed here, the
+    defect of its stream mixes and its selections read as a mask."""
+    cache, x, visited, taken, routed, defect, masked = _served_forward(
         params, cache, tokens[:, None], block_tables, lengths[:, None],
         jnp.where(active, lengths + 1, 0), cfg, slots, routing)
     return (cache, _served_logits(params, x, cfg)[:, 0], visited, taken,
-            routed, defect)
+            routed, defect, masked)
 
 
 def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
@@ -570,28 +580,33 @@ def paged_decode_burst(params, cache: PagedKVCache, tokens, block_tables,
     int32, summed over the steps and the layers) and, from a model that
     holds a share of its experts (`counts_routed`), the top-k choices
     that fell on it as a fifth, summed likewise; from a model of several
-    residual streams (`counts_defect`), last, the largest defect of the
-    steps' mixes."""
+    residual streams (`counts_defect`), then, the largest defect of the
+    steps' mixes; from a model that attends to a learned selection
+    (`counts_masked`), last, how many of the steps' selecting layers
+    read it as a mask: int32."""
     counted, mixed = counts_routed(cfg), counts_defect(cfg)
+    selects = counts_masked(cfg)
 
     def tick(carry, _):
-        cache, toks, lengths, rng, visited, routed, defect = carry
-        cache, logits, n, _, r, short = _paged_decode_logits(
+        cache, toks, lengths, rng, visited, routed, defect, masked = carry
+        cache, logits, n, _, r, short, took = _paged_decode_logits(
             params, cache, toks, block_tables, lengths, active, cfg, slots)
         rng, sub = jax.random.split(rng)
         nxt = sample_per_slot(logits, sub, temps)
         lengths = jnp.where(active, lengths + 1, lengths)
         return (cache, nxt, lengths, rng, visited + n,
                 routed + r if counted else None,
-                jnp.maximum(defect, short) if mixed else None), nxt
+                jnp.maximum(defect, short) if mixed else None,
+                masked + took if selects else None), nxt
 
-    (cache, _, _, rng, visited, routed, defect), toks = jax.lax.scan(
+    (cache, _, _, rng, visited, routed, defect, masked), toks = jax.lax.scan(
         tick, (cache, tokens, lengths, rng, jnp.int32(0),
                _routed_zero(tokens.size, cfg) if counted else None,
-               jnp.float32(0.0) if mixed else None), None,
+               jnp.float32(0.0) if mixed else None,
+               jnp.int32(0) if selects else None), None,
         length=n_steps)
     return (cache, toks, rng, visited, *([routed] if counted else []),
-            *([defect] if mixed else []))
+            *([defect] if mixed else []), *([masked] if selects else []))
 
 
 def _fills(cfg: TransformerConfig) -> list:
@@ -739,7 +754,7 @@ def paged_prefill_chunk(params, cache: PagedKVCache, tokens: jax.Array,
     each position took in every layer, (L, C, top_k).
     """
     positions = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    cache, x, _, taken, routed, defect = _served_forward(
+    cache, x, _, taken, routed, defect, _ = _served_forward(
         params, cache, tokens[None], block_tables[None], positions[None],
         (start + n_valid)[None], cfg,
         None if slot is None else jnp.asarray(slot, jnp.int32)[None],

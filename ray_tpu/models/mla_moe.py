@@ -122,12 +122,13 @@ and the absorbed read's soft-max runs over S_t alone, chunk and decode
 step alike: `ops.attention.paged_index_scores` (the lane's index keys
 through its block table, float32 accumulation) and
 `paged_latent_attention(selected=)`, which chooses and reads in one (an
-exact top-k over positions, never an approximate one: a chunk on a TPU
-finds S_t's least score by a search that sorts nothing and reads live
-pages whole, that threshold its mask; the rest sort, and fetch the
-selected rows).  A window layer reads its slot's ring through
-`slot_ring_reader` with `latent_window_attention`, on `ring_rows` /
-`ring_seen` as every ring.
+exact top-k over positions, never an approximate one: on a TPU a chunk
+and a decode step find S_t's least score by a search that sorts nothing
+and read live pages whole, that threshold their mask; the rest sort, and
+fetch the selected rows; a served call also hands out how many of its
+reads took the mask, the burst's `select_masked` of the tick log).  A
+window layer reads its slot's ring through `slot_ring_reader` with
+`latent_window_attention`, on `ring_rows` / `ring_seen` as every ring.
 Scopes in a profile: `mla_attn` around a layer's attention, inside it
 `dsa_index`, `dsa_attend` (inside it `dsa_select`), `latent_swa`,
 `attn_gate`; `moe` (inside it `moe_groups`), `shared_mlp`, `dense_mlp`;
@@ -704,11 +705,13 @@ def _attention(ap, x, state, kind, at, lanes: _Lanes, cfg, routing=False):
     absorbed form: the positions' rows written to layer `at` of the pool
     (full) or of the rings (window), then read with the rest of what the
     lanes keep there.  Returns (out (S, K, d), state, the positions a
-    full layer selected if `routing` asks and it selects, else None)."""
+    full layer selected if `routing` asks and it selects, else None,
+    from a layer that selects 1 where it read its selection as a mask and
+    0 where it fetched, else None)."""
     cd = cfg.compute_dtype
     k = cfg.kind(kind)
     kv, idx, ring = state
-    selected = None
+    selected = masked = None
     u = rms_norm(x, ap["norm"], eps=cfg.norm_eps)
     row = _latent_row(ap, u, lanes.positions, cfg, k)
     if kind == "window":
@@ -725,7 +728,7 @@ def _attention(ap, x, state, kind, at, lanes: _Lanes, cfg, routing=False):
     elif cfg.index_top_k:
         best, idx = _index(ap, u, cq, idx, at, lanes, cfg)
         with jax.named_scope("dsa_attend"):
-            o_lat, selected = paged_latent_attention(
+            o_lat, selected, masked = paged_latent_attention(
                 q, kv, at, lanes.block_tables, lanes.positions, lanes.kv_len,
                 d_v=k.kv_rank, scale=k.scale, selected=best + (routing,))
     else:
@@ -740,7 +743,7 @@ def _attention(ap, x, state, kind, at, lanes: _Lanes, cfg, routing=False):
                 "skd,dh->skh", u, ap["head_gate"].astype(cd)))[..., None]
     out = jnp.einsum("skf,fd->skd", o.reshape(*x.shape[:2], -1),
                      ap["wo"].astype(cd))
-    return out, (kv, idx, ring), selected
+    return out, (kv, idx, ring), selected, masked
 
 
 def _swiglu(gate_up, down, cd):
@@ -817,7 +820,9 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     the hidden state is handed back, (S, K, d) as ever.  A sixth value
     then: the largest defect of the projected stream-to-stream matrices
     over the call's valid rows and its mixes (None from a model with one
-    stream)."""
+    stream).  A seventh from a model that selects positions: how many of
+    its layers read their selection as a mask (`ops.attention.
+    _attend_masked`'s own predicate, summed; None from any other)."""
     cd = cfg.compute_dtype
     if cfg.state_by_slot and slots is None:
         raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
@@ -868,31 +873,33 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     def add(x, out, mix):
         return x + out if mix is None else hc_mix_up(x, out, *mix, n=hc)
 
-    def attend(x, defect, seq, kind, at, layer):
+    def attend(x, defect, seq, masked, kind, at, layer):
         u, mix, defect = read(x, defect, "hc_attn", layer)
         with jax.named_scope("mla_attn"):
-            out, seq, selected = _attention(
+            out, seq, selected, took = _attention(
                 _take(stacks[kind], at), u, seq, kind, at, lanes, cfg,
                 routing)
-        return add(x, out, mix), defect, seq, selected
+        if took is not None:
+            masked = masked + took
+        return add(x, out, mix), defect, seq, masked, selected
 
     def expert_layer(carry, i, j):
         """Layer `j` of period `i` behind the leading layers."""
-        x, seq, visited, routed, defect = carry
+        x, seq, visited, routed, defect, masked = carry
         kind = period[j]
 
         def layer():
             return nd + _nth(i, len(period), j)
 
-        x, defect, seq, selected = attend(
-            x, defect, seq, kind,
+        x, defect, seq, masked, selected = attend(
+            x, defect, seq, masked, kind,
             lead[kind] + _nth(i, per[kind], period[:j].count(kind)), layer)
         li = _nth(i, len(period), j)
         u, mix, defect = read(x, defect, "hc_ffn", layer)
         out, n, r, taken = _expert_ffn(_take(ffn, li), experts, li, u, valid,
                                        cfg, routing)
         return (add(x, out, mix), seq, visited + n, routed + r,
-                defect), taken, selected
+                defect, masked), taken, selected
 
     def run(carry, i, n):
         """The first `n` layers of period `i`."""
@@ -906,10 +913,12 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     seq = (state.kv, state.idx, state.ring)
     chosen = []                     # the full layers' selections, in order
     defect = jnp.zeros(tokens.shape, F32) if hc else None   # a row's largest
+    masked = jnp.int32(0) if cfg.index_top_k else None  # reads by the mask
     for j in range(nd):
         layer = functools.partial(int, j)
-        x, defect, seq, selected = attend(x, defect, seq, kinds[j],
-                                          kinds[:j].count(kinds[j]), layer)
+        x, defect, seq, masked, selected = attend(
+            x, defect, seq, masked, kinds[j], kinds[:j].count(kinds[j]),
+            layer)
         chosen += [] if selected is None else [selected[None]]
         u, mix, defect = read(x, defect, "hc_ffn", layer)
         x = add(x, _dense_ffn(_take(params["dense"], j), u, cfg), mix)
@@ -917,7 +926,7 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     zero, none = jnp.int32(0), routed_zero(tokens.size, cfg.moe)
     carry, (taken, selected) = jax.lax.scan(
         lambda carry, i: run(carry, i, len(period)),
-        (x, seq, zero, none, defect), jnp.arange(n_periods))
+        (x, seq, zero, none, defect, masked), jnp.arange(n_periods))
 
     def in_order(by_rank):
         """(periods, ..) a layer of the period -> (layers, ..)."""
@@ -934,7 +943,7 @@ def _served_step(params, state: LatentState, tokens, block_tables,
         if routing:
             taken = jnp.concatenate([taken, jnp.stack(more)])
             chosen += [sel[None] for sel in selected]
-    x, (kv, idx, ring), visited, routed, defect = carry
+    x, (kv, idx, ring), visited, routed, defect, masked = carry
     if hc:      # the final norm and the head read the sum of the streams
         x = sum(x[..., i * cfg.d_model:(i + 1) * cfg.d_model].astype(F32)
                 for i in range(hc)).astype(cd)
@@ -950,4 +959,4 @@ def _served_step(params, state: LatentState, tokens, block_tables,
     if hc:
         defect = jnp.max(jnp.where(valid, defect, 0.0))
     return (LatentState(kv=kv, idx=idx, ring=ring), x, visited,
-            taken if routing else None, routed, defect)
+            taken if routing else None, routed, defect, masked)

@@ -740,9 +740,11 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
     `selected` = (index scores, each position's row of the pool, k, and
     whether the positions are wanted too), a model that attends to a
     learned selection: each query row's soft-max runs over exactly its k
-    best-scored rows, and (out, positions or None) comes back.  A chunk
-    lowered for a TPU reads them as a mask in a Pallas kernel, a decode
-    step and every other platform fetch them (`_attend_selected`)."""
+    best-scored rows, and (out, positions or None, 1 where the call read
+    the selection as a mask else 0) comes back.  A chunk and a decode
+    step lowered for a TPU read them as a mask over the lanes' live pages
+    in a Pallas kernel and sort nothing; every other platform sorts, and
+    fetches them (`_attend_selected`)."""
     if selected is not None:
         return _attend_selected(q, pool, layer, block_tables, kv_len,
                                 *selected, d_v=d_v, scale=scale)
@@ -782,9 +784,10 @@ def paged_latent_attention(q, pool, layer, block_tables, positions, kv_len,
 # MB for a 512-row chunk of 64 heads at 64 blocks of 16.
 _INDEX_GROUP_BLOCKS = 64
 # Query rows `_selected_latent_attention` fetches for at once (since PR 50
-# a chunk's fallback off the kernel's reach, `_attend_masked`; a decode step
-# is under it): 2,048 rows of 640 a query row, 128 query rows are a buffer
-# of 0.34 GB and a score tile of 0.13 GB; 512 at once 1.3 GB a layer.
+# a chunk's fallback off the kernel's reach, since PR 60 a decode step's
+# too, `_attend_masked`; a decode step's lanes are under it): 2,048 rows of
+# 640 a query row, 128 query rows are a buffer of 0.34 GB and a score tile
+# of 0.13 GB; 512 at once 1.3 GB a layer.
 _SELECT_QUERY_ROWS = 128
 
 
@@ -846,8 +849,10 @@ def paged_index_scores(q, w, pool, layer, block_tables, positions, kv_len):
 # alone), so the rows fetched and the positions handed to a check are one
 # selection.  The k-th score alone, by 32 rounds of bisection on the
 # scores' bit patterns, sorts nothing (0.30 ms at 16,384), and since PR 51
-# a chunk's kernel reads `score >= it` as its mask and needs no list: the
-# sort is the fetch's alone (`_attend_masked`; the search's table above it).
+# a chunk's kernel, since PR 60 a decode step's, reads `score >= it` as its
+# mask and needs no list: the sort is the fetch's alone, and the fetch is
+# every other platform's and, on a TPU, what a call falls back to inside
+# the program (`_attend_masked`; the search's table above it).
 _SELECT_SPAN = 16384
 
 
@@ -902,8 +907,8 @@ def select_positions(scores, k: int, live=None):
 
 
 def select_rows(scores, k: int, live, rows):
-    """`select_positions` for the fetch (a decode step, a chunk off the
-    mask's reach: nobody else sorts): the same selection in order, but of
+    """`select_positions` for the fetch (a call off the mask's reach or
+    platform: nobody else sorts): the same selection in order, but of
     `rows` (S, T) int32, each position's row in a layer's pool laid flat,
     (S, K, k) bool which the row sees, (S, K) the set's least score."""
     neg, got = _select(scores, rows, k, live)
@@ -913,8 +918,8 @@ def select_rows(scores, k: int, live, rows):
 
 def _selected_latent_attention(q, pool, layer, rows, seen, *, d_v: int,
                                scale: float):
-    """A selection read by fetching it, on every platform (a chunk on a
-    TPU comes here off `_masked_latent_kernel`'s reach): `rows` (S, K, k)
+    """A selection read by fetching it, on every platform (a call on a
+    TPU comes here off the masked kernels' reach): `rows` (S, K, k)
     int32, each query row's rows of `[layer]` of the pool laid flat, go
     into a dense (query rows, k, W) buffer, both products' operand; one
     soft-max over the k, masked to those the row sees (`seen`).  A
@@ -990,11 +995,13 @@ _LATENT_KERNEL_PAGES = 64
 
 def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
                        slot_ref, pools, bufs, sems, *, pages, bs, scale,
-                       d_out, seen, values, prepare=None):
+                       d_out, seen, values, prepare=None, flat=False,
+                       sparse=False):
     """One lane of a decode kernel's grid (`_latent_decode_kernel`,
-    `_paged_decode_kernel`): a loop over the lane's steps of `pages` pool
-    blocks of `bs` positions under a running soft-max.  A step's live
-    blocks `[layer, table[lane, j]]` of every pool of `pools` are copied,
+    `_paged_decode_kernel`, `_masked_decode_kernel`): a loop over the
+    lane's steps of `pages` pool blocks of `bs` positions under a running
+    soft-max.  A step's live blocks `[layer, table[lane, j]]` of every
+    pool of `pools` are copied,
     a DMA a block and pool, into one of the two halves of that pool's
     buffer of `bufs` (2, pages * rows of a page, W) while the other half
     is multiplied; the copy of a lane's first step is started by the
@@ -1003,7 +1010,12 @@ def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
     from lane to lane.  The first buffer's rows are the keys;
     `values(keys, slot)` gives the rows the probabilities are applied to
     (`d_out` columns) and `seen(i, length, shape)` which scores of step
-    `i` stand; `prepare()` runs once a call, before the first copy."""
+    `i` stand; `prepare()` runs once a call, before the first copy.
+    `flat`: a layer of a pool is its blocks' rows laid flat, (N x rows of
+    a page, W), and a page the rows from `block x rows` on.  `sparse`:
+    `seen` may leave a step, a lane's first included, without a score
+    that stands (a selection).  Both are read at trace time: a kernel
+    that gives neither traces as it did before they were."""
     lane, n_lanes = pl.program_id(0), pl.num_programs(0)
     n_entries = tables_ref.shape[0] // n_lanes
     rows = bufs[0].shape[1] // pages       # of a page in a buffer
@@ -1034,6 +1046,8 @@ def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
             if not isinstance(j, int):
                 at = pl.multiple_of(at, rows)
             block = tables_ref[entry + j]
+            if flat:
+                block = pl.ds(pl.multiple_of(block * rows, rows), rows)
             for page, half, sem in ways:
                 dma = pltpu.make_async_copy(
                     page.at[block], half.at[pl.ds(at, rows)], sem)
@@ -1097,7 +1111,9 @@ def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
                 q, keys, _NT, preferred_element_type=jnp.float32) * scale
             s = jnp.where(seen(i, length, s.shape), s, _NEG_INF)
             # Position 0 is in step 0 and seen, so `m_new` is a real
-            # score from the first step on and masked entries vanish.
+            # score from the first step on and masked entries vanish
+            # (`sparse`: `m` starts above the mask and under every score,
+            # so a step without a score leaves exp(s - m_new) 0, not 1).
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -1110,11 +1126,13 @@ def _paged_decode_body(layer_ref, tables_ref, len_ref, q_ref, o_ref,
         h = q.shape[0]
         _, l, acc, slot = jax.lax.fori_loop(
             0, n_steps, step,
-            (jnp.full((h, 1), _NEG_INF, jnp.float32),
+            (jnp.full((h, 1), 0.5 * _NEG_INF if sparse else _NEG_INF,
+                      jnp.float32),
              jnp.zeros((h, 1), jnp.float32),
              jnp.zeros((h, d_out), jnp.float32), slot_ref[0]))
         slot_ref[0] = slot
-        o_ref[0] = acc / l
+        # `sparse`: l is 0 where a live lane's row selects nothing.
+        o_ref[0] = acc / (jnp.where(l > 0, l, 1.0) if sparse else l)
 
 
 def _latent_decode_body(layer_ref, tables_ref, len_ref, q_ref, pool_ref,
@@ -1427,6 +1445,15 @@ _MASKED_KERNEL_PAGES = 64
 _MASKED_LIVE_MAX = 24576
 
 
+def _kept(scores, least, last, at):
+    """Which positions a threshold keeps: those whose score is above the
+    row's `least`, and of those that equal it all (`last` negative) or
+    the one whose row of the pool, `at`, is `last`.  One rule for both
+    masked kernels and for the positions handed to a check."""
+    return (scores > least) | ((scores == least) & (
+        (last < 0) | (at == last)))
+
+
 def _masked_latent_body(layer_ref, tables_ref, len_ref, pool_ref, q_ref,
                         sc_ref, least_ref, last_ref, at_ref, o_ref, buf,
                         sems, *, pages, bs, d_v, scale):
@@ -1487,8 +1514,7 @@ def _masked_latent_body(layer_ref, tables_ref, len_ref, pool_ref, q_ref,
             q, keys, _NT, preferred_element_type=jnp.float32) * scale
         here = pl.ds(pl.multiple_of(i * t, t), t)
         sc = sc_ref[0, :, here]                            # (G, t)
-        keep = (sc > least) | ((sc == least) & (
-            (last < 0) | (at_ref[0, :, here] == last)))
+        keep = _kept(sc, least, last, at_ref[0, :, here])
         # a query row's mask for each of its heads: (G, t) -> (G x H, t)
         keep = jnp.broadcast_to(
             jnp.where(keep, 1.0, 0.0)[:, None, :], (g, h, t)
@@ -1586,6 +1612,145 @@ def _masked_latent_kernel(q, pool, layer, block_tables, kv_len, scores,
     return out[:, :k_w]
 
 
+# A decode step reads its selection the same way (PR 60; `TPU v5 lite`,
+# 2026-10-03, call A2): one query row a lane, its H = 128 heads the rows of
+# the score tile (the chunk's kernel at K = 1 would pad the row to a group
+# of 16 and multiply sixteen times as much), on `_paged_decode_body`'s
+# pipeline through the lanes.  Bare, one layer, k = 2,048 of float32 normal
+# scores, rows of 640 bfloat16 of which 512 are the value, every lane at
+# the same length; ms a call, each timed inside one program (a scan over
+# 16 queries: no launch from the host in it).  The fetch (`_fetch_best`:
+# the sort of the tier that holds the longest lane, the gather of 2,048
+# rows a lane, both products) | this kernel behind the search and the
+# branch (`_attend_masked`) | of that the search (`_threshold`):
+#   live a lane   8 lanes                     4 lanes
+#     4,096     0.437 | 0.212 | 0.075      0.258 | 0.143 | 0.064
+#     8,192     0.555 | 0.293 | 0.073      0.306 | 0.193 | 0.063
+#    13,312     0.830 | 0.449 | 0.085      0.442 | 0.275 | 0.069
+#    16,384     0.829 | 0.513 | 0.086      0.445 | 0.311 | 0.068
+#    24,576     1.435 | 0.691 | 0.130      0.752 | 0.385 | 0.095
+#    32,768     1.430 | 0.916 | 0.132      0.754 | 0.493 | 0.089
+# (to 16,384 under DeepSeek-V3.2-Exp's pool and table of 1,024 entries,
+# past it under dots3-note-prev's of 2,048, which reads the same to 0.02 ms
+# where both were measured; 32,768: the kernel and the search alone, the
+# branch still stood at 24,576.)  The kernel wins at every length a cell
+# can hold, by 1.6-2.1 times, and its lead grows with the tier the sort is
+# on; it takes 3.2 us a step of 64 pages (1,024 positions: 1.3 MB, 1.6 us
+# of copy at 819 GB/s, 0.30 GFLOP, 1.5 us at 197 TFLOP/s): 128 query rows
+# stream past each 128 x 128 tile of stored rows once, so the MXU loads a
+# tile for every 128 rows it multiplies, and the products with the
+# soft-max's vector work over a (128, 1,024) tile bound the step, not the
+# copy (410 GB/s).  Past 32,768 nobody has measured (the sort would go to
+# four spans, the kernel on by 0.028 ms a thousand positions at 8 lanes):
+# `_MASKED_DECODE_LIVE_MAX` is the longest lane measured.  Against the same
+# set attended in float32 the kernel's output differs by 0.07% of its rms
+# (at most 1.1% of the rms in one value), the fetch's by 0.15% (3.1%): a
+# running soft-max rounds exp(s - m) to bfloat16, the fetch the normalised
+# probabilities.
+# **Pages a step: the table's are 64, 32 are taken for what a written-out
+# page costs a replica's start** (`_PAGED_KERNEL_PAGES` has the account: a
+# step's copies are written out, and the kernel is traced once a burst
+# tier and lowered once a program).  128 pages read the same as 64 to
+# 0.01 ms; 32 cost 0.02-0.03 ms a call (call A: 0.204 / 0.292 / 0.428 for
+# 0.181 / 0.271 / 0.402 at 8 lanes of 4k / 8k / 13k).  In the cell
+# (`dsv32-agent`, five layers a step; calls B and C, one seed a row,
+# `tpot_p50_ms`): the parent 15.94 / 16.01 | 64 pages 13.47 / 13.59 | 32
+# pages 13.55 / 13.70 | 16 pages 13.76 / 13.80 | 64 issued from a loop
+# 13.86 / 14.00; the cell's warm `setup_s` read 62.2 -> 65.5 and 61.3 ->
+# 66.4 at 64 pages (+5.4%, +8.2% under a bound of 10%; tracing and
+# lowering the kernel for a described v5e, this sandbox's CPU: 0.81 s a
+# tier of 8 lanes at 64 pages, 0.38 at 32, 0.38 at 16).
+_MASKED_DECODE_PAGES = 32
+_MASKED_DECODE_LIVE_MAX = 32768
+
+
+def _masked_decode_body(layer_ref, tables_ref, len_ref, pool_ref, q_ref,
+                        sc_ref, least_ref, last_ref, at_ref, o_ref, buf,
+                        sems, slot_ref, *, pages, d_v, scale):
+    """`_paged_decode_body` over one pool of latent rows, as
+    `_latent_decode_body` but laid flat, **under a selection as a mask**:
+    a position counts where it is under the lane's length and `_kept`
+    keeps it by its index score `sc_ref` (1, T) against the lane's
+    `least_ref` (1, 1) and, at a tie, by its row of the pool `at_ref`
+    (1, T) against `last_ref` (1, 1): `_masked_latent_body`'s rule."""
+    t = buf.shape[1]                       # positions a step
+
+    def seen(i, length, shape):
+        here = pl.ds(pl.multiple_of(i * t, t), t)
+        keep = _kept(sc_ref[0, :, here], least_ref[0], last_ref[0],
+                     at_ref[0, :, here])
+        # One comparison over the score tile: a kept position's column
+        # stands under what is left of the lane's length, any other
+        # under 0.
+        return jax.lax.broadcasted_iota(jnp.int32, shape, 1) < jnp.where(
+            keep, length - i * t, 0)
+
+    _paged_decode_body(
+        layer_ref, tables_ref, len_ref, q_ref, o_ref, slot_ref, (pool_ref,),
+        (buf,), (sems,), pages=pages, bs=t // pages, scale=scale, d_out=d_v,
+        seen=seen, values=lambda rows, slot: rows[:, :d_v], flat=True,
+        sparse=True)
+
+
+@functools.partial(jax.jit, static_argnames=("d_v", "scale"))
+def _masked_decode_kernel(q, pool, layer, block_tables, kv_len, scores,
+                          least, last, *, d_v, scale):
+    """A decode step's attention over a selection as one kernel a layer,
+    of this file's own: `_masked_latent_kernel` for one query row a lane,
+    `q` (S, 1, H, W), on `_latent_decode_kernel`'s grid (the lanes) and
+    pipeline (`_paged_decode_body`: the next live lane's first step
+    fetched ahead), its conventions and its operands, the pool laid flat
+    (L, N x block_size, W) first.  Lane s attends the positions p under
+    `kv_len[s]` whose index score `scores[s, 0, p]` (S, 1, T) float32 is
+    above `least[s, 0]`, and of those that equal it all (`last[s, 0]`
+    negative) or the one whose row of the pool is `last[s, 0]`
+    (`_threshold`).  A lane's H heads are the rows of the score tile
+    against a step of `_MASKED_DECODE_PAGES` pages: the chunk's kernel at
+    K = 1 would pad the one query row to a group of
+    `_MASKED_QUERY_ROWS`.  Only a lane's live pages are read; an idle
+    lane reads none and gets 0.  Returns (S, 1, H, d_v) float32."""
+    s, _, h, w = q.shape
+    n_layers, n_blocks, bs, _ = pool.shape
+    pages = min(_MASKED_DECODE_PAGES, block_tables.shape[1])
+    # Whole steps of positions: a padded position is selected by nobody.
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, -block_tables.shape[1] % pages)))
+    width = tables.shape[1] * bs
+    scores = jnp.pad(scores, ((0, 0), (0, 0), (0, width - scores.shape[-1])),
+                     constant_values=_NEG_INF)
+    # each position's row of a layer's pool laid flat
+    at = jnp.repeat(tables, bs, axis=1) * bs + jnp.arange(width) % bs
+    out = pl.pallas_call(
+        functools.partial(_masked_decode_body, pages=pages, d_v=d_v,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s,),
+            in_specs=[
+                # the pool first: a profile keeps the start of an op's text
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, h, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, 1, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, 1, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, h, d_v), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, h, d_v), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="masked_decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.reshape(-1),
+      kv_len.astype(jnp.int32), pool.reshape(n_layers, n_blocks * bs, w),
+      q[:, 0].astype(pool.dtype), scores, least.astype(jnp.float32)[..., None],
+      last.astype(jnp.int32)[..., None], at[:, None, :])
+    return out[:, None]
+
+
 def _masked_takes(q_shape, pool_shape, dtype, d_v: int):
     """None where `_masked_latent_kernel` can run these shapes on a TPU,
     else what stands in its way: rows, values and pages in whole tiles,
@@ -1668,47 +1833,65 @@ def _fetch_best(q, pool, layer, block_tables, kv_len, scores, at, *, k: int,
                                       scale=scale), positions
 
 
-def _attend_masked(q, pool, layer, block_tables, kv_len, scores, at, *,
-                   k: int, handed: bool, d_v: int, scale: float):
-    """`_masked_latent_kernel` under the selection as a threshold, where
-    that is an exact top-k, else the fetch.  **A launch that reads the
-    mask executes no sort**: `_edge_of_best` finds each row's k-th best
-    score, `least`; every position above it is in the set, and of the
-    `equal` that tie with it the set keeps `kept`, enough to make k.  So
-    the mask is the set where a row keeps every such position, or one
-    alone: the lowest, which the kernel finds among them by its row of
-    the pool.  A launch in which some row keeps two or more and leaves one
-    out takes the fetch, sort and all inside that branch (`_fetch_best`),
-    as does one whose longest lane passes `_MASKED_LIVE_MAX` and one whose
-    counts do not bear the search out (a score that is no number).  With
-    `handed` the positions come back too: those the mask kept, or the
-    fetch's."""
-    live, k = jnp.max(kv_len), min(k, scores.shape[-1])
+def _threshold(scores, at, k: int, live, reach: int):
+    """The selection as a threshold (the choosing, a chunk's and a decode
+    step's alike, and **no sort**): `_edge_of_best` finds each row's k-th
+    best score, `least`; every position above it is in the set, and of
+    the `equal` that tie with it the set keeps `kept`, enough to make k.
+    So the mask `score >= least` is the set where a row keeps every such
+    position, or one alone: the lowest, which a kernel finds among them
+    by its row of the pool, `last` (negative: every one).  Returns (least
+    (S, K) float32, last (S, K) int32, whether that is an exact top-k in
+    every row: false where some row keeps two or more and leaves one
+    out, and where the counts do not bear the search out, a score that
+    is no number)."""
     with jax.named_scope("dsa_select"):
-        least, above, equal, first = _edge_of_best(
-            scores, k, live, reach=_MASKED_LIVE_MAX)
+        least, above, equal, first = _edge_of_best(scores, k, live,
+                                                   reach=reach)
         # a row that sees under k keeps all it sees: none at its `least`
         kept = jnp.where(least > 0.5 * _NEG_INF, k - above, 0)
         every = equal == kept
         last = jnp.where(every, -1, jnp.take_along_axis(at, first, axis=1))
         exact = jnp.all(every | ((kept == 1) & (equal > 0)))
+    return least, last, exact
+
+
+def _attend_masked(q, pool, layer, block_tables, kv_len, scores, at, *,
+                   k: int, handed: bool, d_v: int, scale: float):
+    """The selection read as a mask over the lanes' live pages where the
+    threshold is an exact top-k (`_threshold`) and the call's longest
+    lane is within the kernel's reach, else the fetch: a chunk by
+    `_masked_latent_kernel` up to `_MASKED_LIVE_MAX`, a decode step (one
+    query row a lane) by `_masked_decode_kernel` up to
+    `_MASKED_DECODE_LIVE_MAX`.  **A call that reads the mask executes no
+    sort**; one whose ties the mask cannot express, or whose longest lane
+    is past the reach, takes the fetch, sort and all inside that branch
+    (`_fetch_best`).  Returns (out, with `handed` the positions: those
+    the mask kept, or the fetch's; else None, whether the call read the
+    mask: the branch's own predicate, int32)."""
+    decode = q.shape[1] == 1
+    kernel = _masked_decode_kernel if decode else _masked_latent_kernel
+    reach = _MASKED_DECODE_LIVE_MAX if decode else _MASKED_LIVE_MAX
+    live, k = jnp.max(kv_len), min(k, scores.shape[-1])
+    least, last, exact = _threshold(scores, at, k, live, reach)
 
     def mask():
-        out = _masked_latent_kernel(q, pool, layer, block_tables, kv_len,
-                                    scores, least, last, d_v=d_v, scale=scale)
+        out = kernel(q, pool, layer, block_tables, kv_len, scores, least,
+                     last, d_v=d_v, scale=scale)
         if not handed:
             return out, None
-        bar = least[..., None]
-        keep = (scores > bar) | ((scores == bar) & (
-            (last[..., None] < 0) | (at[:, None, :] == last[..., None])))
+        keep = _kept(scores, least[..., None], last[..., None],
+                     at[:, None, :])
         # the kept positions (a set: the order is the sort's), behind them
         # for a row that sees under k positions it does not see
         return out, select_positions(jnp.where(keep, 0.0, _NEG_INF), k, live)
 
-    return jax.lax.cond(
-        (live <= _MASKED_LIVE_MAX) & exact, mask,
+    masked = (live <= reach) & exact
+    return (*jax.lax.cond(
+        masked, mask,
         lambda: _fetch_best(q, pool, layer, block_tables, kv_len, scores, at,
-                            k=k, handed=handed, d_v=d_v, scale=scale))
+                            k=k, handed=handed, d_v=d_v, scale=scale)),
+        masked.astype(jnp.int32))
 
 
 def _attend_selected(q, pool, layer, block_tables, kv_len, scores, at,
@@ -1718,31 +1901,40 @@ def _attend_selected(q, pool, layer, block_tables, kv_len, scores, at,
     attends the `k` positions of its lane with the largest index `scores`
     (S, K, T) float32 (an exact top-k: `_select` says why no other), `at`
     (S, T) int32 each position's row in a layer's pool laid flat.  One
-    of two forms that attend the same function.  **A chunk (K > 1)
-    lowered for a TPU reads its lanes' live pages whole, once a group of
-    query rows, in one Pallas kernel a layer, the selection a threshold
-    on its score tile and nothing sorted** (`_attend_masked`, which falls
-    back where the threshold is not the set).  A decode step, every other
-    platform, a pool split over a mesh and shapes that are not whole
-    tiles (it says so) **sort, and fetch** the selected rows into a dense
-    buffer (`_fetch_best`).  The platform is the one the program is
-    lowered for (`jax.lax.platform_dependent`), as
-    `paged_latent_attention`'s.  Returns (out (S, K, H, d_v) float32, the
-    positions attended (S, K, k) int32 if `handed` else None: a set, in
-    the order of the form that read it)."""
+    of two forms that attend the same function.  **Lowered for a TPU, a
+    chunk and a decode step alike read their lanes' live pages whole in
+    one Pallas kernel a layer, the selection a threshold on the score
+    tile and nothing sorted** (`_attend_masked`: a chunk once a group of
+    query rows, a decode step a lane's heads at once; it falls back
+    inside the program where the threshold is not the set or a lane is
+    past the kernel's reach).  Every other platform, a pool split over a
+    mesh and shapes that are not whole tiles (a chunk says so) **sort,
+    and fetch** the selected rows into a dense buffer (`_fetch_best`).
+    The platform is the one the program is lowered for
+    (`jax.lax.platform_dependent`), as `paged_latent_attention`'s.
+    Returns (out (S, K, H, d_v) float32, the positions attended (S, K, k)
+    int32 if `handed` else None: a set, in the order of the form that
+    read it, 1 where the call read the mask else 0: int32, the program's
+    to sum)."""
     how = dict(k=k, handed=handed, d_v=d_v, scale=scale)
-    fetch = functools.partial(_fetch_best, **how)
     args = (q, pool, layer, block_tables, kv_len, scores, at)
-    if q.shape[1] == 1 or jax.typeof(pool).sharding.mesh.size > 1:
+
+    def fetch(*args):
+        return (*_fetch_best(*args, **how), jnp.int32(0))
+
+    if jax.typeof(pool).sharding.mesh.size > 1:
         return fetch(*args)
     why = _masked_takes(q.shape, pool.shape, pool.dtype, d_v)
     if why is not None:
-        # Decided at trace time, as `paged_latent_attention`'s.
-        warnings.warn(
-            f"paged_latent_attention: {why}, so a chunk over pool"
-            f"{pool.shape} fetches its selected rows on a TPU too, not the "
-            f"Pallas kernel (a 512-row launch at dots3-note-prev's widths "
-            f"takes 25 ms a layer whatever its context)", stacklevel=3)
+        # Decided at trace time, as `paged_latent_attention`'s; a decode
+        # step says nothing more than its model's chunk has.
+        if q.shape[1] != 1:
+            warnings.warn(
+                f"paged_latent_attention: {why}, so a chunk over pool"
+                f"{pool.shape} fetches its selected rows on a TPU too, not "
+                f"the Pallas kernel (a 512-row launch at dots3-note-prev's "
+                f"widths takes 25 ms a layer whatever its context)",
+                stacklevel=3)
         return fetch(*args)
     return jax.lax.platform_dependent(
         *args, tpu=functools.partial(_attend_masked, **how), default=fetch)

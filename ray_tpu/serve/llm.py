@@ -134,6 +134,10 @@ _HC_RES_DEFECT = "hc_res_defect"
 # Before it in the records of a model that holds a share of experts chosen
 # group by group, and of no other.
 _GROUP_OPEN_ROWS = "group_open_rows"
+# Behind them in the records of a model whose layers attend to a learned
+# selection, and of no other: 100 x the reads of its burst that took the
+# selection as a mask.
+_SELECT_MASKED = "select_masked"
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -151,11 +155,11 @@ class _TickAccounts:
                  "routed_at", "defect_at", "experts_read", "ahead",
                  "starved_s",
                  "index_scored_tokens", "kv_selected_tokens", "blocks",
-                 "passes", "block_tokens", "ring_slots")
+                 "passes", "block_tokens", "ring_slots", "select_masked")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
-            self.experts_read = self.starved_s = 0.0
+            self.experts_read = self.starved_s = self.select_masked = 0.0
         self.lanes = self.width = self.prefill_tokens = 0
         self.kv_read_tokens = self.ahead = 0
         self.index_scored_tokens = self.kv_selected_tokens = 0
@@ -171,18 +175,19 @@ class _TickAccounts:
 
 class _Burst:
     """A decode burst that is launched and not yet read, and what its
-    read needs: the token matrix and the count of experts visited (both
+    read needs: the token matrix, the count of experts visited and, from
+    a model that selects positions, of selections read as a mask (all
     still on the device), lane by lane the request, the tokens the host
     counted for it at the launch, whether they are its last and how many
     of the lane's tokens come before them (the given rows of a block: 0
     for a next-token model), the
     launch's time and its number among the engine's launches, and the
     record of the tick that launched it, which enters the tick log at
-    the read, when `experts_read` is known."""
-    __slots__ = ("tok_mat", "visited", "lanes", "t0", "seq", "row")
+    the read, when `experts_read` and `select_masked` are known."""
+    __slots__ = ("tok_mat", "visited", "masked", "lanes", "t0", "seq", "row")
 
-    def __init__(self, tok_mat, visited, lanes, t0, seq):
-        self.tok_mat, self.visited = tok_mat, visited
+    def __init__(self, tok_mat, visited, masked, lanes, t0, seq):
+        self.tok_mat, self.visited, self.masked = tok_mat, visited, masked
         self.lanes, self.t0, self.seq = lanes, t0, seq
         self.row: Optional[list] = None
 
@@ -490,6 +495,7 @@ class PagedLLMEngine:
         from ray_tpu.models.decoding import (
             counts_defect,
             counts_groups,
+            counts_masked,
             counts_routed,
             init_sequence_state,
             make_paged_engine_fns,
@@ -719,6 +725,16 @@ class PagedLLMEngine:
                 lambda sums, at, n, fresh: sums.at[at].set(
                     jnp.where(fresh, 0, sums[at])
                     + jnp.pad(n.reshape(-1), (0, width - n.size))))
+        # A model that attends to a learned selection: its burst hands
+        # out how many reads took the selection as a mask, read with the
+        # burst's tokens and logged as a share of its steps x the layers
+        # that select (a row at position 0 scores one position in each).
+        self._masked_at = 0
+        if counts_masked(cfg):
+            self._masked_at = len(self.tick_fields)
+            self.tick_fields += (_SELECT_MASKED,)
+            self._select_reads = \
+                self._burst_passes * cfg.selection_counts(0, 1)[0]
         # A model of several residual streams: the largest defect of a
         # tick's mixes, kept on the device as the sums above are.
         self._defects = None
@@ -1071,8 +1087,8 @@ class PagedLLMEngine:
         the lanes' input tokens (`first[j]` where it is >= 0, else the
         last token slot idx[j] sampled), the burst, the scatter of its
         last row back by slot.  A model that fills blocks, one: every
-        input is the host's.  Returns (token matrix, experts visited), on
-        the device."""
+        input is the host's.  Returns (token matrix, experts visited, a
+        selecting model's reads by the mask or None), on the device."""
         jnp = self._jnp
         if self._block:
             self._mark_launch()
@@ -1082,7 +1098,7 @@ class PagedLLMEngine:
                 jnp.asarray(lengths), jnp.asarray(active),
                 jnp.asarray(temps), self._rng,
                 n_blocks=self.max_burst // self._block)
-            return tok_mat, visited
+            return tok_mat, visited, None
         host_tok = first
         slots = jnp.asarray(self._lane_slots(idx, width))
         self._mark_launch()
@@ -1093,8 +1109,9 @@ class PagedLLMEngine:
             jnp.asarray(temps), self._rng, n_steps=self.max_burst,
             **({"slots": slots} if self._by_slot else {}))
         self._last_dev = self._put_last(self._last_dev, slots, tok_mat)
+        masked = routed.pop() if self._masked_at else None
         self._count_routed(routed)
-        return tok_mat, visited
+        return tok_mat, visited, masked
 
     def _mark_launch(self) -> None:
         """A burst, a verify window or a prefill chunk is about to be
@@ -1551,7 +1568,7 @@ class PagedLLMEngine:
             self._count_selection(
                 (int(n), burst) for n in lengths[:len(idx)])
             t0 = time.time()
-            tok_mat, visited = self._launch_burst(
+            tok_mat, visited, masked = self._launch_burst(
                 idx, w, first, tables, lengths, active, temps)
             self._acct.decode_s = time.time() - t0
             # The host's books move at the launch.  A request whose
@@ -1581,8 +1598,8 @@ class PagedLLMEngine:
                 for k in ("blocks", "passes", "block_tokens"):
                     self.stats[k] += getattr(acct, k)
             self._inflight = _Burst(
-                tok_mat, visited if self._expert_layers else None, lanes,
-                t0, self._launches)
+                tok_mat, visited if self._expert_layers else None, masked,
+                lanes, t0, self._launches)
             if prev is not None:
                 self._acct.ahead = 1
                 self._harvest(prev)
@@ -1596,14 +1613,14 @@ class PagedLLMEngine:
         """Read a launched burst's tokens and emit them, each lane's to
         the request that was in it at the launch."""
         t0 = self._clock.enter(_BURST_READ)
-        if b.visited is not None:
-            # Tokens and count, both copies started before either is
-            # waited for: a second read after the first costs 0.6 ms.
-            tok_mat, visited = self._jax.device_get((b.tok_mat, b.visited))
-            experts = int(visited) / (self._burst_passes
-                                      * self._expert_layers)
-        else:
-            tok_mat, experts = np.asarray(b.tok_mat), 0.0   # (burst, w)
+        # Tokens and counts, every copy started before any is waited
+        # for: a second read after the first costs 0.6 ms.
+        tok_mat, visited, masked = self._jax.device_get(
+            (b.tok_mat, b.visited, b.masked))         # (burst, w), counts
+        experts = 0.0 if visited is None else \
+            int(visited) / (self._burst_passes * self._expert_layers)
+        masked = 0.0 if masked is None else \
+            100.0 * int(masked) / self._select_reads
         # By lane, in the order of the positions: (w, burst), from a
         # matrix by step, or by block and row (blocks, w, B).
         tok_mat = tok_mat.T if tok_mat.ndim == 2 else \
@@ -1614,8 +1631,11 @@ class PagedLLMEngine:
         self._acct.decode_s += t1 - t0
         if b.row is None:           # read in the tick that launched it
             self._acct.experts_read = experts
+            self._acct.select_masked = masked
         else:
             b.row[_EXPERTS_READ] = experts
+            if self._masked_at:
+                b.row[self._masked_at] = masked
             self._tick_log.append(tuple(b.row))
         # The burst's turn on the device began when the one before it
         # was read, if that was after its launch.
@@ -1895,6 +1915,13 @@ class PagedLLMEngine:
         layers likewise, those whose kept groups hold an expert held
         here, the rows that *can* route here (the program's count,
         `ops.moe._kept_groups`).
+        `select_masked` (behind them, in the records of a model whose
+        layers attend to a learned selection and of no other): 100 x the
+        reads of the tick's burst that took the selection as a mask over
+        the lanes' live pages, of its steps x the layers that select (the
+        program's count, `ops.attention._attend_masked`'s own predicate,
+        read with the burst's tokens; 0 for a tick without a burst and on
+        a platform that fetches).
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -1939,6 +1966,8 @@ class PagedLLMEngine:
                        acct.ring_slots]
                 if _GROUP_OPEN_ROWS in self.tick_fields:
                     row.append(0)       # read with `routed_here`
+                if self._masked_at:
+                    row.append(acct.select_masked)
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -188,7 +188,8 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
     """Streams through the scheduler (chunks, then bursts of 8 steps): each
     token is the arg-max of the scoring entry's logits for the same
     sequence, and the ticks' `index_scored_tokens` / `kv_selected_tokens`
-    are the model's count for the rows they ran."""
+    are the model's count for the rows they ran; a burst on this host
+    fetches its selections (`select_masked` 0)."""
     e, c = served
     cfg = e.cfg
     before = len(e.engine_stats()["tick_log"])
@@ -200,14 +201,16 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
                      routing=True)
     assert [int(jnp.argmax(g)) for g in got[0]][:-1] == out
     stats = e.engine_stats()
-    assert stats["tick_fields"][-6:-4] == ("index_scored_tokens",
+    assert stats["tick_fields"][-7:-5] == ("index_scored_tokens",
                                            "kv_selected_tokens")
+    assert stats["tick_fields"][-1] == "select_masked"
     ticks = [dict(zip(stats["tick_fields"], t))
              for t in stats["tick_log"][before:]]
     assert sum(t["prefill_tokens"] for t in ticks) == 45
     scored, selected = cfg.selection_counts(0, 45)
     bursts = [t for t in ticks if t["lanes"]]
     assert len(bursts) == 2                   # 16 of the 17 tokens
+    assert {t["select_masked"] for t in ticks} == {0.0}
     for j in range(2):
         a, b = cfg.selection_counts(45 + 8 * j, 8)
         scored, selected = scored + a, selected + b
@@ -608,8 +611,9 @@ def test_equal_scores_at_a_set_s_edge_are_the_sort_s_to_settle(
     by_the_sort = attention._selected_latent_attention(
         *lanes[:3], *selection[:2], d_v=24, scale=0.2)
     with pltpu.force_tpu_interpret_mode():
-        got, handed = attention._attend_masked(
+        got, handed, masked = attention._attend_masked(
             *lanes, scores, at, k=k, handed=True, d_v=24, scale=0.2)
+    assert int(masked) == every_row_settled
     if every_row_settled:
         np.testing.assert_allclose(got, by_the_rule, atol=2e-6)
         # the sort took another of the tied positions in some row
@@ -632,9 +636,113 @@ def test_a_lane_past_the_kernel_s_reach_takes_the_fetch(monkeypatch):
     for reach, same in ((39, True), (40, False)):
         monkeypatch.setattr(attention, "_MASKED_LIVE_MAX", reach)
         with pltpu.force_tpu_interpret_mode():
-            got, handed = attention._attend_masked(
+            got, handed, masked = attention._attend_masked(
                 *lanes, *best[:2], k=best[2], handed=False, d_v=24, scale=0.2)
-        assert handed is None
+        assert handed is None and int(masked) == (not same)
+        assert bool((got == want).all()) == same
+        np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def _late(key, shape):
+    """Scores whose best stand behind a lane's first 16 positions."""
+    return jax.random.normal(key, shape) + 10.0 * (jnp.arange(shape[-1]) >= 16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths,scores_of", [
+    ((40, 33), jax.random.normal),   # every lane sees more than its 16
+    ((48, 0, 5), jax.random.normal),  # an idle lane, a lane that sees 5
+    ((0, 16, 17, 0), jax.random.normal),  # the count's edge, idle ends
+    ((48, 40), _late),        # a first kernel step that holds none of them
+    ((48, 48, 48), jax.random.normal),    # lanes that fill their tables
+], ids=["longer", "idle-and-fewer", "edge", "late", "full"])
+def test_the_masked_decode_kernel_agrees_with_the_fetch(dtype, lengths,
+                                                        scores_of,
+                                                        monkeypatch):
+    """`_masked_decode_kernel` (one query row a lane, the lane's heads the
+    score tile's rows, on `_paged_decode_body`'s pipeline through the
+    lanes) in Pallas's interpret mode against `_selected_latent_attention`
+    over `select_rows`' selection, in steps of two pages (16 positions,
+    as many as are selected)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_DECODE_PAGES", 2)
+    lanes, (rows, seen, least), (scores, _, _) = _a_chunk_that_selects(
+        dtype, lengths, 1, lambda shape, key: scores_of(key, shape))
+    if scores_of is _late:      # nothing of a long lane's set in step 0
+        assert int((rows[0, 0] // 8 == lanes[3][0, :2, None]).sum()) == 0
+    want = attention._selected_latent_attention(
+        *lanes[:3], rows, seen, d_v=24, scale=0.2)
+    with pltpu.force_tpu_interpret_mode():
+        got = attention._masked_decode_kernel(
+            *lanes, scores, least, jnp.full(least.shape, -1), d_v=24,
+            scale=0.2)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    live = np.asarray(lanes[4]) > 0
+    assert not np.asarray(got[~live]).any()
+    bound = 2e-6 if dtype == jnp.float32 else 1.5e-2    # as the chunk's
+    rms = float(jnp.sqrt(jnp.mean(want[live] ** 2)))
+    assert float(jnp.abs(got - want)[live].max()) < bound * rms
+
+
+@pytest.mark.parametrize("decimals,seed,ties", [
+    (1, 5, "every"), (1, 0, "one"), (0, 4, "several")])
+def test_equal_scores_at_a_decode_step_s_edge(decimals, seed, ties,
+                                              monkeypatch):
+    """`test_equal_scores_at_a_set_s_edge_are_the_sort_s_to_settle` for
+    one query row a lane: lanes that keep every position tied with their
+    set's last, or one of them alone (the lowest, by its row of the
+    pool), read the mask; a burst in which a lane keeps several and
+    leaves one out takes the fetch of the sort's set, to the bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_DECODE_PAGES", 2)
+    lanes, selection, best = _a_chunk_that_selects(
+        jnp.float32, (48, 40, 44), 1, seed=seed,
+        scores_of=lambda shape, key: jnp.round(
+            jax.random.normal(key, shape), decimals))
+    scores, at, k = best
+    every, one = _ties(selection, scores)
+    assert {"every": bool(every.all()),
+            "one": bool((every | one).all() and not every.all()),
+            "several": not bool((every | one).all())}[ties]
+    assert bool(((scores == selection[2][..., None]).sum(-1) > 1).any())
+    rows, seen, positions = _the_rule_s_set(*best)
+    by_the_rule = attention._selected_latent_attention(
+        *lanes[:3], rows, seen, d_v=24, scale=0.2)
+    by_the_sort = attention._selected_latent_attention(
+        *lanes[:3], *selection[:2], d_v=24, scale=0.2)
+    with pltpu.force_tpu_interpret_mode():
+        got, handed, masked = attention._attend_masked(
+            *lanes, scores, at, k=k, handed=True, d_v=24, scale=0.2)
+    assert int(masked) == (ties != "several")
+    if ties == "several":
+        np.testing.assert_array_equal(got, by_the_sort)
+        assert (np.asarray(handed) == np.asarray(
+            attention.select_positions(scores, k, jnp.max(lanes[4])))).all()
+    else:
+        np.testing.assert_allclose(got, by_the_rule, atol=2e-6)
+        assert (np.sort(positions, -1) == np.sort(handed, -1)).all()
+
+
+def test_a_lane_past_the_decode_reach_takes_the_fetch(monkeypatch):
+    """`_MASKED_DECODE_LIVE_MAX` against the burst's longest lane, inside
+    the program: within it the mask, past it the fetch to the bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(attention, "_MASKED_DECODE_PAGES", 2)
+    lanes, selection, best = _a_chunk_that_selects(
+        jnp.float32, (40, 22), 1,
+        lambda shape, key: jax.random.normal(key, shape))
+    want = attention._selected_latent_attention(
+        *lanes[:3], *selection[:2], d_v=24, scale=0.2)
+    for reach, same in ((39, True), (40, False)):
+        monkeypatch.setattr(attention, "_MASKED_DECODE_LIVE_MAX", reach)
+        with pltpu.force_tpu_interpret_mode():
+            got, handed, masked = attention._attend_masked(
+                *lanes, *best[:2], k=best[2], handed=False, d_v=24, scale=0.2)
+        assert handed is None and int(masked) == (not same)
         assert bool((got == want).all()) == same
         np.testing.assert_allclose(got, want, atol=2e-6)
 
@@ -655,7 +763,8 @@ def test_the_rule_that_picks_the_selected_read(q_shape, pool_shape, dtype,
                                                d_v, taken, recwarn):
     """Shapes alone decide (`_masked_takes`): rows, values, heads and
     pages in whole tiles of the pool's dtype.  What is refused says so
-    and fetches; a decode step fetches without a word."""
+    and fetches; a decode step goes by the same rule and, refused, says
+    nothing more than its model's chunk has."""
     assert (attention._masked_takes(q_shape, pool_shape, dtype, d_v)
             is None) == taken
     if max(q_shape + pool_shape) > 1024:
@@ -680,9 +789,11 @@ def test_the_rule_that_picks_the_selected_read(q_shape, pool_shape, dtype,
     # this host lowers for its CPU: the fetch, the kernel's branch nowhere
     assert "masked_latent_attention" not in chunk.as_text()
     recwarn.clear()
-    lower(q[:, :1])
-    assert not [w for w in recwarn.list
-                if "fetches its selected rows" in str(w.message)]
+    # a decode step on this host: the fetch (its sort and its gather), no
+    # kernel of either form, and not a word
+    step = lower(q[:, :1]).as_text()
+    assert "stablehlo.sort" in step and "masked_" not in step
+    assert not recwarn.list
 
 
 def test_the_latent_ring_reader_sees_the_window_and_no_more():
@@ -944,12 +1055,15 @@ def test_logits_check_has_teeth(fault, monkeypatch):
 # layout) and `tiny-mhc-mla-moe` (Xing4.0's): taken on PR 58's tree (commit
 # 3c96652, PR 59's parent): groups of experts in the selection
 # (`MoEConfig.n_groups`) and their count in the tick log leave the
-# programs of a configuration without groups as they were.
+# programs of a configuration without groups as they were.  `tiny-dsa-moe`'s
+# burst: taken on PR 60's tree, whose burst of a configuration that selects
+# hands out one count more (its reads by the mask); its chunk, and every
+# program of a configuration that selects nothing, are the older trees'.
 _LOWERED_AT_THE_PARENT = {
     "tiny-mla-moe": {"chunk": "d7d54907d46b5ad4", "burst": "99cd8866034ddb13",
                      "copy_block": "de83fbd14fd07de6",
                      "verify": "c783a012baeae859"},
-    "tiny-dsa-moe": {"chunk": "a6e75b7f8777bca9", "burst": "3b03be3c982c46a9",
+    "tiny-dsa-moe": {"chunk": "a6e75b7f8777bca9", "burst": "75cee36e4489b129",
                      "copy_block": "0ccb71cf37b52b1b"},
     "tiny-mhc-mla-moe": {"chunk": "ae4eefdbdedeed4b",
                          "burst": "5967f98f16941ca8",

@@ -23,7 +23,11 @@ sys.path.insert(0, ROOT)
 
 from bench.harness import reference, spec  # noqa: E402
 from ray_tpu.models import configs, decoding, mla_moe  # noqa: E402
-from ray_tpu.serve.llm import LLMDeployment, PagedLLMEngine  # noqa: E402
+from ray_tpu.serve.llm import (  # noqa: E402
+    TICK_FIELDS,
+    LLMDeployment,
+    PagedLLMEngine,
+)
 
 TINY = os.path.join(ROOT, "bench", "tests", "data", "deepseekv32family",
                     "configs", "tinydsv32-serve.json")
@@ -139,7 +143,8 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
     """A stream through the scheduler (chunks, then bursts of 8 steps):
     each token is the arg-max of the scoring entry's logits for the same
     sequence, and the ticks' `group_open_rows` are the rows, a layer,
-    whose handed-out groups hold group 0 (where experts 0-1 stand)."""
+    whose handed-out groups hold group 0 (where experts 0-1 stand); a
+    burst on this host fetches its selections (`select_masked` 0)."""
     e, c = served
     before = len(e.engine_stats()["tick_log"])
     prompt = list(map(int, _seqs(1, 45, seed=45)[0]))
@@ -148,11 +153,13 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
                          routing=True)
     assert [int(jnp.argmax(g)) for g in got[0]][:-1] == out
     stats = e.engine_stats()
-    assert stats["tick_fields"][-1] == "group_open_rows"
+    assert stats["tick_fields"] == TICK_FIELDS + ("group_open_rows",
+                                                  "select_masked")
     ticks = [dict(zip(stats["tick_fields"], t))
              for t in stats["tick_log"][before:]]
     assert sum(t["prefill_tokens"] for t in ticks) == 45
     assert sum(t["lanes"] for t in ticks) == 2          # two bursts of 8
+    assert {t["select_masked"] for t in ticks} == {0.0}
     groups = taken[0]["groups"][:45 + 16]               # (rows, 4, 2)
     experts = taken[0]["experts"][:45 + 16]
     assert sum(t["group_open_rows"] for t in ticks) == int(
@@ -162,6 +169,123 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
     assert (np.sort(groups, -1)[..., :, None] == experts[..., None, :] // 4
             ).any(-2).all()
     assert 0.2 < (groups == 0).any(-1).mean() < 0.8
+
+
+def test_a_burst_counts_the_reads_that_took_the_mask(monkeypatch):
+    """The burst's program at widths the masked kernel takes (a value of
+    128 columns, 8 heads, float32), lowered twice: as on this host (the
+    fetch: the count 0) and with the read sent where a TPU's goes
+    (`_attend_masked`, its kernel in Pallas's interpret mode: the count
+    its steps x the five layers, by the branch's own predicate).  Both
+    sample the same tokens: a burst equals its steps either way."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops import attention
+
+    cfg = dataclasses.replace(
+        configs.get("tiny-group-moe"), kv_rank=128, n_heads=8,
+        compute_dtype=jnp.float32, param_dtype=jnp.float32)
+    params = cfg.init_params(jax.random.key(SEED))
+    lanes, steps, bs = 3, 4, 8
+
+    def burst(cache):
+        # lanes of 37 and 20 positions and an idle one, blocks of their own
+        tables = jnp.arange(1, 1 + lanes * 8, dtype=jnp.int32).reshape(
+            lanes, 8)
+        return decoding.paged_decode_burst(
+            params, cache, jnp.array([5, 7, 0], jnp.int32), tables,
+            jnp.array([37, 20, 0], jnp.int32), jnp.array([True, True, False]),
+            jnp.zeros((lanes,), jnp.float32), jax.random.key(1), cfg, steps)
+
+    def fresh():
+        state = decoding.init_sequence_state(cfg, 1 + lanes * 8, bs,
+                                             num_slots=lanes, prefill_chunk=32)
+        # seeded rows in every block (a seed whose steps have no tie at a
+        # set's edge: one there sends a read to the fetch, and is counted)
+        return jax.tree.map(
+            lambda a: jax.random.normal(jax.random.key(6), a.shape, a.dtype),
+            state)
+
+    assert attention._masked_takes(
+        (lanes, 1, 8, cfg.row_width), fresh().kv.shape, jnp.float32, 128) \
+        is None
+    *_, fetched = out = jax.jit(burst)(fresh())
+    assert len(out) == 6 and int(fetched) == 0      # .., routed, masked
+
+    def as_on_a_tpu(q, pool, layer, tables, kv_len, scores, at, k,
+                    handed=False, *, d_v, scale):
+        return attention._attend_masked(q, pool, layer, tables, kv_len,
+                                        scores, at, k=k, handed=handed,
+                                        d_v=d_v, scale=scale)
+
+    monkeypatch.setattr(attention, "_attend_selected", as_on_a_tpu)
+    monkeypatch.setattr(attention, "_MASKED_DECODE_PAGES", 2)
+    with pltpu.force_tpu_interpret_mode():
+        *_, masked = got = jax.jit(burst)(fresh())
+    assert int(masked) == steps * cfg.n_layers
+    # the live lanes' tokens and rows (an idle lane samples from garbage
+    # and writes the null block)
+    assert (np.asarray(got[1])[:, :2] == np.asarray(out[1])[:, :2]).all()
+    np.testing.assert_allclose(got[0].kv[:, 1:], out[0].kv[:, 1:], atol=1e-5)
+
+
+def test_the_tick_log_carries_the_burst_s_share_of_masked_reads(monkeypatch):
+    """`select_masked`: 100 x the burst's count over its steps x the
+    layers that select, read with the burst's tokens; 0 in a tick without
+    a burst.  (The count is made to say 1 a read: this host fetches.)"""
+    from ray_tpu.ops import attention
+
+    fetch = attention._attend_selected
+
+    def counted(*a, **kw):
+        out, positions, _ = fetch(*a, **kw)
+        return out, positions, jnp.int32(a[0].shape[1] == 1)
+
+    monkeypatch.setattr(attention, "_attend_selected", counted)
+    c = _config()
+    e = _engine(c)
+    try:
+        prompt = list(map(int, _seqs(1, 45, seed=46)[0]))
+        assert len(e.generate(prompt, max_tokens=17)) == 17
+        stats = e.engine_stats()
+    finally:
+        e.shutdown()
+    ticks = [dict(zip(stats["tick_fields"], t)) for t in stats["tick_log"]]
+    assert [t["select_masked"] for t in ticks if t["lanes"]] == [100.0] * 2
+    assert {t["select_masked"] for t in ticks if not t["lanes"]} == {0.0}
+
+
+def test_the_benchmark_reads_the_mask_s_share_weighted_by_lanes():
+    """`dsa_mask_share.decode` (bench/metrics): `select_masked` of the
+    window's ticks weighted by their `lanes` (a tick without a burst
+    weighs nothing), None from a log without the field (a parent
+    commit's)."""
+    import types
+
+    from bench.harness import report
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == "dsa_mask_share.decode"]
+    assert entry["workloads"] == ["dsv32-agent"]
+    with open(spec.metric_file(spec.BENCH_DIR, entry["name"], ".json")) as f:
+        metric = dict(json.load(f), **entry)
+    fields = ("start", "tick_s", "lanes", "select_masked")
+    ticks = ((9.0, 0.1, 8, 0.0),                # before the window
+             (10.0, 0.1, 6, 100.0), (10.1, 0.1, 0, 0.0), (10.2, 0.1, 2, 50.0))
+
+    def ctx(fields, ticks):
+        return {"run": {"outcomes": [types.SimpleNamespace(
+                    cause=None, first=1.0, request_id="r")]},
+                "replica": {"stats": {
+                    "request_phases": [{"id": "r", "submitted": 9.95,
+                                        "ttft_s": 0.4}],
+                    "tick_fields": fields, "tick_log": ticks}}}
+
+    read = report._reader(metric)
+    assert read(ctx(fields, ticks), **metric["args"]) == pytest.approx(87.5)
+    assert read(ctx(fields[:3], [t[:3] for t in ticks]),
+                **metric["args"]) is None
 
 
 def test_deployment_takes_the_configuration_by_name():

@@ -213,7 +213,7 @@ def test_the_absorbed_and_the_plain_form_agree():
             pos = jnp.arange(start, start + size, dtype=jnp.int32)[None]
             lanes = mla_moe._Lanes(table, pos, jnp.asarray([start + size]),
                                    table[0, pos // 8], pos % 8)
-            out, (pool, _, _), _ = mla_moe._attention(
+            out, (pool, _, _), *_ = mla_moe._attention(
                 ap, x[None, start:start + size], (pool, None, None), "full",
                 li, lanes, cfg)
             outs.append(out[0])

@@ -11,6 +11,7 @@ the run on the chip.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 
@@ -995,8 +996,11 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     the pool laid flat, which is what `attn_operand` and `select_operand`
     look for, within the 600 characters of an op's text that a profile
     keeps), under a branch whose other side is the fetch **and, since
-    PR 51, the only sorts of index scores the chunk has**; the burst's is
-    the sort and the fetch alone."""
+    PR 51, the only sorts of index scores the chunk has**; **the burst's
+    is, since PR 60, its own form of that kernel** (`masked_decode_
+    attention`: one query row a lane, at the same two call sites, handed
+    the pool laid flat the same way), its sorts all on the other side of
+    its branch too."""
     import json
     import re
 
@@ -1039,10 +1043,12 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
     for operand in (fam.index_operand, fam.attn_operand, fam.ring_operand,
                     fam.select_operand):
         assert operand(config).search(text), operand.__name__
+    kernel = "masked_latent_attention" \
+        if program == "paged_prefill_chunk" else "masked_decode_attention"
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
-             and "masked_latent_attention" in line]
-    assert len(calls) == (2 if program == "paged_prefill_chunk" else 0)
+             and "masked_" in line]
+    assert len(calls) == 2 and all(kernel in call for call in calls)
     for call in calls:
         seen_by_a_profile = call.strip()[:600]
         assert "bf16[2,262160,640]" in seen_by_a_profile, call
@@ -1050,18 +1056,51 @@ def test_dsa_moe_served_programs_fit_one_chip(topo, program):
         assert fam.select_operand(config).search(seen_by_a_profile)
     # the fetch stands beside the kernel (a longer lane, a tie the mask
     # cannot settle): the gathered buffer is still a shape of the chunk
-    if calls:
+    if program == "paged_prefill_chunk":
         assert "bf16[128,2048,640]" in text
         assert mem.temp_size_in_bytes > 0.3e9, mem.temp_size_in_bytes
-        # and every sort of index scores stands on that side of the branch
-        # (the platform's branch, then `_attend_masked`'s): a launch that
-        # reads the mask executes none
-        sorts = [line for line in text.splitlines()
-                 if re.search(r"\bsort\(", line) and "dsa_select" in line]
-        assert sorts and all(
-            re.search(r"dsa_attend/(cond/branch_\d_fun/){2}jit\(_fetch_best\)/"
-                      r"dsa_select", line)
-            for line in sorts), sorts[:2]
+    else:
+        assert "bf16[8,2048,640]" in text          # the fetch beside it
+    # and every sort of index scores stands on that side of the branch
+    # (the platform's branch, then `_attend_masked`'s): a launch or a
+    # burst that reads the mask executes none
+    sorts = [line for line in text.splitlines()
+             if re.search(r"\bsort\(", line) and "dsa_select" in line]
+    assert sorts and all(
+        re.search(r"dsa_attend/(cond/branch_\d_fun/){2}jit\(_fetch_best\)/"
+                  r"dsa_select", line)
+        for line in sorts), sorts[:2]
+
+
+@pytest.mark.parametrize("lanes", [4, 8])
+@pytest.mark.parametrize("layers,entries", [(5, 1024), (2, 2048)],
+                         ids=["dsv32", "dots3"])
+def test_the_masked_decode_kernel_compiles(topo, lanes, layers, entries):
+    """`_masked_decode_kernel` alone at DeepSeek-V3.2-Exp's and
+    dots3-note-prev's widths (128 heads over rows of 640 bfloat16, 512 of
+    them the value; the benchmark's pools: 5 layers under tables of 1,024
+    entries, 2 under 2,048) for a burst of 4 and of 8 lanes: Mosaic takes
+    the lanes' grid, the flat pool's page copies and the blocked scores,
+    and the call shows the pool laid flat within what a profile keeps of
+    an op's text."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    blocks = 8 * entries + 1
+    compiled = jax.jit(functools.partial(
+        attention._masked_decode_kernel, d_v=512, scale=0.1)).lower(
+        arr((lanes, 1, 128, 640), jnp.bfloat16),
+        arr((layers, blocks, 16, 640), jnp.bfloat16), arr((), jnp.int32),
+        arr((lanes, entries), jnp.int32), arr((lanes,), jnp.int32),
+        arr((lanes, 1, entries * 16), jnp.float32),
+        arr((lanes, 1), jnp.float32), arr((lanes, 1), jnp.int32)).compile()
+    (call,) = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert "masked_decode_attention" in call
+    assert f"bf16[{layers},{blocks * 16},640]" in call.strip()[:600]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 @pytest.mark.parametrize("program", ["paged_denoise_burst",
