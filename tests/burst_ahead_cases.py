@@ -45,18 +45,23 @@ def run_until_done(e, reqs, limit=400):
     raise AssertionError("requests never finished")
 
 
-def step_reference(e, prompt, n_tokens):
+def _jitted(fn, cfg):
+    return jax.jit(decoding._bind_cfg(fn, cfg))
+
+
+def step_reference(e, prompt, n_tokens, bound=_jitted):
     """The greedy continuation of `prompt` one step at a time: the
     prompt through `paged_prefill_chunk`, then `paged_decode_step` on one
     lane, on a sequence state of its own.  Nothing of the engine's tick,
-    its lane map or its burst."""
+    its lane map or its burst.  `bound(fn, cfg)`: the jitted `fn` of
+    `cfg` (a fresh one; `served_contract.bound` remembers)."""
     cfg = e.cfg
     state = decoding.init_sequence_state(
         cfg, e._b_max + 1, e.block_size, num_slots=1,
         prefill_chunk=e.prefill_chunk)
     table = jnp.arange(1, e._b_max + 1, dtype=jnp.int32)
-    chunk = jax.jit(decoding._bind_cfg(decoding.paged_prefill_chunk, cfg))
-    step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, cfg))
+    chunk = bound(decoding.paged_prefill_chunk, cfg)
+    step = bound(decoding.paged_decode_step, cfg)
     by_slot = getattr(cfg, "state_by_slot", False)
     prompt = list(map(int, prompt))
     for start in range(0, len(prompt), e.prefill_chunk):
@@ -107,7 +112,7 @@ def run_join_and_leave(e, vocab=500):
     return first + late
 
 
-def join_and_leave(e, vocab=500):
+def join_and_leave(e, vocab=500, bound=_jitted):
     """`run_join_and_leave`, each stream held to the step-by-step
     reference, and the tick log to the scenario: the tiers go 4, 8, 4,
     and every burst but the first is launched while the one before it is
@@ -115,7 +120,8 @@ def join_and_leave(e, vocab=500):
     n_logged = len(ticks_of(e))
     for r in run_join_and_leave(e, vocab):
         assert r.error is None and len(r.out_tokens) == r.max_tokens
-        assert r.out_tokens == step_reference(e, r.prompt, r.max_tokens)
+        assert r.out_tokens == step_reference(e, r.prompt, r.max_tokens,
+                                              bound)
     assert e.allocator.snapshot()["blocks_active"] == 0
     launched = [t for t in ticks_of(e)[n_logged:] if t["lanes"]]
     # a busy period's first burst has none before it; every other has

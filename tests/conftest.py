@@ -12,9 +12,49 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 # Subprocesses (GCS server, node daemons, workers) inherit both variables.
 
+# The run's own compile cache: a directory made for this run and handed to
+# JAX's persistent cache before jax is imported, with its floors at zero,
+# so that a program compiled once (by any of xdist's workers, or by a
+# process a test starts) is loaded wherever it is built again: the suite
+# builds the same engines' chunk and burst programs hundreds of times.
+# Nobody sets it from outside (a directory that outlives the run would be
+# a second result), and whoever made it removes it at the run's end.
+_RUN_CACHE = None
+if not os.environ.get("PYTEST_XDIST_WORKER"):     # xdist's workers inherit
+    import tempfile
+
+    _RUN_CACHE = tempfile.mkdtemp(prefix="ray_tpu_tests_jax_cache_")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _RUN_CACHE
+    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
+
+
+# `--dist loadfile` hands files to its workers in collection order, and a
+# file of minutes that starts last is the tail of the run's wall: the files
+# over ~120 CPU-seconds (junit of the driver's command, PR 62) start first,
+# longest first; every other file keeps its place.
+_LONG_FIRST = (
+    "test_tpu_compile", "test_moe", "test_dsa_moe_serving",
+    "test_mamba2_moe_serving", "test_mla_moe_serving", "test_tune_breadth",
+    "test_paged_attention", "test_hybrid_serving", "test_window_moe_serving",
+    "test_mhc_mla_serving", "test_gated_moe_serving",
+    "test_group_moe_serving", "test_examples", "test_pipeline")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: at for at, name in enumerate(_LONG_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.stem, len(rank)))
+
+
+def pytest_unconfigure(config):
+    if _RUN_CACHE is not None:
+        import shutil
+
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
 
 
 @pytest.fixture

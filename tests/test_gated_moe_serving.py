@@ -6,88 +6,47 @@ in rope (a full layer's under YaRN over half a head, a window layer's
 unscaled over the whole), a gate a head on the attention's output, and
 one rank's share of 8 experts chosen by sigmoid scores beside a shared
 expert), held to the laguna family's plain float32 reference
-(`bench/families/laguna.py`, which imports nothing of the program):
-prefill in chunks and decode through a real `PagedLLMEngine`, pool and
-rings.  Tiny widths, seeded weights, float32 compute where the claim is
-that the engine computes the same function (errors of 1e-6), bfloat16
-where it is that the benchmark's comparison tells a fault from rounding."""
+(`bench/families/laguna.py`, which imports nothing of the program).  The
+served contract's cases are `tests/served_contract.py`'s."""
 import dataclasses
-import hashlib
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import served_contract as contract
+from ray_tpu.models import configs, decoding, init_params
+from ray_tpu.models.transformer import forward
+from ray_tpu.ops.moe import MoEConfig
+from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.serve.llm import LLMDeployment
+from served_contract import Family, Teeth, on_the_engine, seqs
 
-from bench.harness import reference, spec  # noqa: E402
-from ray_tpu.models import configs, decoding, init_params  # noqa: E402
-from ray_tpu.models.transformer import forward  # noqa: E402
-from ray_tpu.ops.moe import MoEConfig  # noqa: E402
-from ray_tpu.ops.rotary import apply_rope  # noqa: E402
-from ray_tpu.serve.llm import LLMDeployment, PagedLLMEngine  # noqa: E402
+# Readings at this size (CPU, seeds 5-8): as it is, a position's error has
+# medians 0.026-0.030 and a largest of 0.046-0.077 (0.046 at seed 8, which
+# the test takes) and strays by at most 0.12; the cache in 8-bit floats,
+# medians 0.10, largest 0.25, strays to 0.48-1.10.  The family's own two
+# limits (0.06, 0.1) are the published widths'; here they are 0.08 and 0.2.
+TINY_BOUND, TINY_SLACK, TEETH_SEED = 0.08, 0.2, 8
 
-TINY = os.path.join(ROOT, "bench", "tests", "data", "lagunafamily",
-                    "configs", "tinylaguna-serve.json")
-SEED = 5
-EXACT = 2e-5          # float32 engine against float32 reference
-
-
-def _config(**over):
-    with open(TINY) as f:
-        return dict(json.load(f), **over)
-
-
-def _engine(c, cfg=None, params=None, **over):
-    fam = spec.family(c)
-    true = fam.program_config(c)
-    eng = dict(c["engine"], **over)
-    return PagedLLMEngine(
-        cfg or true,
-        init_params(jax.random.key(SEED), true) if params is None else params,
-        num_slots=eng["num_slots"], max_len=eng["max_len"],
-        block_size=eng["block_size"], prefill_chunk=eng["prefill_chunk"],
-        max_burst=eng["max_burst"])
-
-
-def _errors(e, c, seqs, n_prompt):
-    """Every compared position's error against the reference, which is
-    handed the program's routing and refuses it (NaN) outside the slack."""
-    fam = spec.family(c)
-    got, taken = e.score(seqs, n_prompt, routing=True)
-    out = []
-    for lane in range(len(seqs)):
-        want, _ = fam.forward(e.params, jnp.asarray(seqs[lane], jnp.int32),
-                              c, jit=jax.jit, routing=taken[lane])
-        out.append(np.asarray(reference.position_errors(
-            jnp.stack(got[lane]), want[n_prompt - 1:])))
-    return np.concatenate(out)
-
-
-def _seqs(lanes, total, seed=0):
-    return np.random.default_rng(seed).integers(1, 512, (lanes, total))
-
-
-@pytest.fixture(scope="module")
-def served():
-    c = _config()
-    e = _engine(c)
-    yield e, c
-    e.shutdown()
+FAM = Family(
+    tiny="lagunafamily/configs/tinylaguna-serve.json",
+    registry="tiny-gated-moe",
+    as_registry=dict(param_dtype=contract.FLOAT32,
+                     compute_dtype=contract.FLOAT32),
+    own_init=False, handed=lambda taken: {"routing": taken},
+    deployment=dict(engine="paged", num_slots=2, max_len=128, block_size=8,
+                    prefill_chunk=32), request=(50, 4),
+    teeth=Teeth(tolerances={"LOGITS_REL_EXPERTS": TINY_BOUND,
+                            "ROUTER_SLACK": TINY_SLACK},
+                seed=TEETH_SEED, sound_margin=0.6))
+engines, served = contract.fixtures(FAM)
 
 
 # -- the configuration ---------------------------------------------------------
 def test_the_tiny_configuration_is_the_registry_s():
-    c = _config()
-    cfg = spec.family(c).program_config(c)
-    assert cfg == dataclasses.replace(
-        configs.get("tiny-gated-moe"), name=c["name"],
-        param_dtype=jnp.dtype("float32"), compute_dtype=jnp.dtype("float32"))
+    _, cfg = contract.tiny_configuration_is_the_registry_s(FAM)
     assert cfg.kinds == ("full",) + ("window", "window", "window",
                                      "full") * 2
     assert cfg.n_of("window") == 6 and cfg.n_of("full") == 3
@@ -165,90 +124,52 @@ def test_prefill_in_chunks_then_decode_equals_the_reference(served, n_prompt):
     """Prompts against a window of 12 and a ring of 12 + 32 rows: at 100
     every window layer's ring wraps twice and the last chunk (4 tokens of
     32) is padded, and the compared positions lie beyond the window, a
-    turn of the ring and YaRN's original 32.  Logits, not tokens."""
+    turn of the ring and YaRN's original 32."""
     e, c = served
     assert e.cache.wk.shape == (6, 5, 44, 2, 16)
     assert e.cache.k.shape[0] == 3
-    seqs = _seqs(3, n_prompt + 10, seed=n_prompt)
-    errs = _errors(e, c, seqs, n_prompt)
-    assert errs.shape == (33,) and errs.max() < EXACT, errs
+    contract.prefill_then_decode_equals_the_reference(
+        FAM, e, c, 3, n_prompt, 10, seed=n_prompt)
 
 
 def test_the_routing_handed_out_is_of_the_expert_layers(served):
     e, c = served
-    fam = spec.family(c)
-    seqs = _seqs(2, 40, seed=3)
-    got, taken = e.score(seqs, 36, routing=True)
-    plain = e.score(seqs, 36)
+    fam = FAM.reference(c)
+    rows = seqs(2, 40, seed=3)
+    got, taken = e.score(rows, 36, routing=True)
+    plain = e.score(rows, 36)
     for lane in range(2):
         assert taken[lane].shape == (40, 8, 3)         # no leading layer
         assert taken[lane].max() > 3                   # the router is whole
         np.testing.assert_array_equal(np.stack(got[lane]),
                                       np.stack(plain[lane]))
-        own, margin = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
-                                  jit=jax.jit, routing=None)
-        handed, decided = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
-                                      jit=jax.jit, routing=taken[lane])
+        own, margin = fam.forward(e.params, jnp.asarray(rows[lane]), c,
+                                  jit=contract.jit, routing=None)
+        handed, decided = fam.forward(e.params, jnp.asarray(rows[lane]), c,
+                                      jit=contract.jit, routing=taken[lane])
         assert float(decided.min()) >= 1.0 - 1e-3
         np.testing.assert_allclose(handed, own, atol=2e-5)
-    short, _ = fam.forward(e.params, jnp.asarray(seqs[0]), c, jit=jax.jit,
+    short, _ = fam.forward(e.params, jnp.asarray(rows[0]), c, jit=contract.jit,
                            routing=taken[0][:, :7])
     assert not np.isfinite(short).any()
 
 
-def _gate_left_out(cfg):
-    return dataclasses.replace(cfg, attn_gate=False)
+FAULTS = {"gate_left_out": dict(attn_gate=False),
+          "whole_head_roped": dict(rotary_dim=0),
+          "half_a_window_head_roped": dict(rotary_dim_window=8),
+          "one_theta": dict(rope_theta_window=0.0),
+          "yarn_left_off": dict(yarn=None),
+          "shared_expert_dropped": dict(d_shared=0),
+          "route_scale_dropped": dict(route_scale=1.0),
+          "softmax_for_the_sigmoid": dict(expert_scoring="softmax")}
 
 
-def _whole_head_roped(cfg):
-    return dataclasses.replace(cfg, rotary_dim=0)
-
-
-def _half_a_window_head_roped(cfg):
-    return dataclasses.replace(cfg, rotary_dim_window=8)
-
-
-def _one_theta(cfg):
-    return dataclasses.replace(cfg, rope_theta_window=0.0)
-
-
-def _yarn_left_off(cfg):
-    return dataclasses.replace(cfg, yarn=None)
-
-
-def _shared_expert_dropped(cfg):
-    return dataclasses.replace(cfg, d_shared=0)
-
-
-def _route_scale_dropped(cfg):
-    return dataclasses.replace(cfg, route_scale=1.0)
-
-
-def _softmax_for_the_sigmoid(cfg):
-    return dataclasses.replace(cfg, expert_scoring="softmax")
-
-
-FAULTS = [_gate_left_out, _whole_head_roped, _half_a_window_head_roped,
-          _one_theta, _yarn_left_off, _shared_expert_dropped,
-          _route_scale_dropped, _softmax_for_the_sigmoid]
-
-
-@pytest.mark.parametrize("fault", FAULTS,
-                         ids=lambda f: f.__name__.strip("_"))
-def test_what_is_left_out_is_seen(fault):
-    """Float32 on both sides, the seeded weights as they are (the gate's
-    logits are N(0, 1): its values lie across (0, 1), not at 1/2): a
-    program that leaves one mechanism out is thousands of times further
-    from the reference than one that does not, or routes outside the
-    slack."""
-    c = _config()
-    cfg = spec.family(c).program_config(c)
-    e = _engine(c, fault(cfg))
-    try:
-        errs = _errors(e, c, _seqs(2, 50 + 4, seed=9), 50)
-    finally:
-        e.shutdown()
-    assert not np.isfinite(errs).all() or errs.min() > 100 * EXACT, errs
+@pytest.mark.parametrize("change", FAULTS.values(), ids=list(FAULTS))
+def test_what_is_left_out_is_seen(engines, change):
+    """The seeded weights as they are (the gate's logits are N(0, 1): its
+    values lie across (0, 1), not at 1/2)."""
+    contract.a_fault_is_seen(FAM, engines, dataclasses.replace(
+        FAM.program_config(FAM.config()), **change))
 
 
 # -- (ii) the rope over a part of a head -----------------------------------------
@@ -258,8 +179,8 @@ def test_the_partial_rope_is_the_reference_s(kind):
     published keys: the first half of a head of 16 under YaRN (its ramp
     reckoned on 8), the whole head unscaled; the dimensions past
     `rotary_dim` pass through to the bit."""
-    c = _config()
-    fam = spec.family(c)
+    c = FAM.config()
+    fam = FAM.reference(c)
     cfg = fam.program_config(c)
     x = jax.random.normal(jax.random.key(1), (1, 90, 5, 16), jnp.float32)
     got = apply_rope(x, jnp.arange(90), **cfg.rope(fam._KINDS[kind]))
@@ -278,17 +199,17 @@ def test_the_two_halves_add_up_to_the_uncut_layer():
     0-3, experts 4-7; the router 8 wide on both), each with the shared
     expert: the two parts, the shared expert counted once, are the uncut
     reference's layer, and each part is the reference's given that share."""
-    whole = _config(num_experts=8)
-    fam = spec.family(whole)
+    whole = FAM.config(num_experts=8)
+    fam = FAM.reference(whole)
     cfg8 = fam.program_config(whole)
     assert cfg8.experts_held is None
     layer = next(p for i, p in enumerate(fam.layer_weights(
-        init_params(jax.random.key(SEED), cfg8), whole)) if i == 3)
+        FAM.params(cfg8), whole)) if i == 3)
     u = jax.random.normal(jax.random.key(2), (1, 40, 48), jnp.float32)
     stacks = ("w_gate", "w_up", "w_down")
     parts, counts = [], []
     for first in (0, 4):
-        half = _config(first_local_expert=first)
+        half = FAM.config(first_local_expert=first)
         cfg = fam.program_config(half)
         assert cfg.experts_held == (first, 4)
         bp = {k: (v[first:first + 4] if k in stacks else v)
@@ -323,7 +244,7 @@ def test_streams_are_greedy_and_the_tick_log_counts_the_share(served):
     finished request no longer needed included)."""
     e, c = served
     before = len(e.engine_stats()["tick_log"])
-    prompts = [list(map(int, _seqs(1, n, seed=n)[0])) for n in (45, 23)]
+    prompts = [contract.prompt(n, n) for n in (45, 23)]
     import threading
 
     outs = [None, None]
@@ -356,107 +277,52 @@ def test_streams_are_greedy_and_the_tick_log_counts_the_share(served):
 
 
 def test_deployment_takes_the_configuration_by_name():
-    dep = LLMDeployment("tiny-gated-moe", engine="paged", num_slots=2,
-                        max_len=128, block_size=8, prefill_chunk=32)
-    try:
-        out = dep({"tokens": list(range(1, 50)), "max_tokens": 4})
-        assert len(out["tokens"]) == 4
+    with contract.deployed(FAM) as dep:
         assert dep.engine.cfg.lead_pattern == ("full",)
         with pytest.raises(ValueError, match="by slot"):
             LLMDeployment("tiny-gated-moe", engine="paged",
                           tensor_parallel=2)
-    finally:
-        dep.engine.shutdown()
 
 
 # -- (v) the benchmark's comparison has teeth -------------------------------------
-def _as_float8(a):
-    return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
-
-
+@on_the_engine
 def _cache_in_8_bits(e, fam, monkeypatch):
-    e.score(np.ones((1, 9), np.int64), 8, routing=True)   # builds them
-    for name in ("_score_chunk", "_score_step"):
-        inner = getattr(e, name)
-
-        def program(*a, _inner=inner, **kw):
-            cache, *rest = _inner(*a, **kw)
-            return (jax.tree.map(_as_float8, cache), *rest)
-
-        setattr(e, name, program)
+    contract.score_keeps(e, monkeypatch, lambda cache: jax.tree.map(
+        contract.as_float8, cache))
 
 
-def _program_with(e, fam, monkeypatch, params):
-    """The program runs on `params`, the reference on the stated ones."""
-    stated, plain = e.params, fam.forward
-    e.params = params
-    monkeypatch.setattr(fam, "forward",
-                        lambda p, *a, **kw: plain(stated, *a, **kw))
-
-
+@on_the_engine
 def _weights_rounded_once_more(e, fam, monkeypatch):
-    _program_with(e, fam, monkeypatch, jax.tree.map(
-        lambda a: _as_float8(a) if a.ndim >= 2 else a, e.params))
+    contract.program_with(e, fam, monkeypatch, jax.tree.map(
+        lambda a: contract.as_float8(a) if a.ndim >= 2 else a, e.params))
 
 
+@on_the_engine
 def _one_held_expert_dropped(e, fam, monkeypatch):
     blocks = e.params["blocks"]
-    _program_with(e, fam, monkeypatch, dict(e.params, blocks=dict(
+    contract.program_with(e, fam, monkeypatch, dict(e.params, blocks=dict(
         blocks, w_down=blocks["w_down"].at[:, 1].set(0))))
 
 
+@on_the_engine
 def _the_dense_layer_dropped(e, fam, monkeypatch):
     lead = e.params["lead"][0]
-    _program_with(e, fam, monkeypatch, dict(e.params, lead=[dict(
+    contract.program_with(e, fam, monkeypatch, dict(e.params, lead=[dict(
         lead, w_down=jnp.zeros_like(lead["w_down"]))]))
-
-
-# Readings at this size (CPU, seeds 5-8): as it is, a position's error has
-# medians 0.026-0.030 and a largest of 0.046-0.077 (0.046 at seed 8, which
-# the test takes) and strays by at most 0.12; the cache in 8-bit floats,
-# medians 0.10, largest 0.25, strays to 0.48-1.10.  The family's own two
-# limits (0.06, 0.1) are the published widths'; here they are 0.08 and 0.2.
-TINY_BOUND, TINY_SLACK, TEETH_SEED = 0.08, 0.2, 8
 
 
 @pytest.mark.parametrize("fault", [
     None, _cache_in_8_bits, _weights_rounded_once_more,
     _one_held_expert_dropped, _the_dense_layer_dropped],
     ids=lambda f: f.__name__.strip("_") if f else "as_it_is")
-def test_logits_check_has_teeth(fault, monkeypatch):
-    """`deployment.logits_check` (3 lanes x (the last of 100 prompt
-    positions + 8 decode steps), bfloat16 parameters, compute and cache as
-    the benchmark's configuration has them, the routing handed over, held
-    to the family's own slack and to an error bound between this size's
-    two readings) passes the program as it is with every position decided
-    and fails a program that computes below bfloat16: its cache kept in
+def test_logits_check_has_teeth(engines, fault, monkeypatch):
+    """Fails a program that computes below bfloat16: its cache kept in
     8-bit floats, its weights rounded once more (model-configs guide,
     section 3.3), and one that drops a held expert or the dense layer.
     The family's LOGITS_REL_EXPERTS was measured at the published widths;
     at a width of 48 bfloat16 rounds coarser, so the bound here lies
     between this size's readings (above `TINY_BOUND`)."""
-    from bench.harness.deployment import logits_check
-
-    c = _config(param_dtype="bfloat16", compute_dtype="bfloat16",
-                cache_dtype="bfloat16")
-    fam = spec.family(c)
-    monkeypatch.setitem(fam.TOLERANCES, "LOGITS_REL_EXPERTS", TINY_BOUND)
-    monkeypatch.setitem(fam.TOLERANCES, "ROUTER_SLACK", TINY_SLACK)
-    e = _engine(c, params=init_params(jax.random.key(TEETH_SEED),
-                                      fam.program_config(c)))
-    try:
-        if fault:
-            fault(e, fam, monkeypatch)
-        v = logits_check(e, c, TEETH_SEED)
-    finally:
-        e.shutdown()
-    assert v["positions"] == 27 and v["bound"] == TINY_BOUND
-    if fault is None:
-        assert v["ok"] and v["decided"] == 27, v
-        assert v["worst"] < 0.6 * v["bound"], v
-    else:
-        assert not v["ok"], v
-        assert not v["finite"] or v["worst_decided"] > v["bound"], v
+    contract.logits_check_has_teeth(FAM, engines, fault, monkeypatch)
 
 
 # -- (vi) the other models lower to the programs they lowered to ------------------
@@ -487,30 +353,5 @@ _LOWERED_AT_PR_43 = {
 @pytest.mark.parametrize("name,program", list(_LOWERED_AT_PR_43),
                          ids=lambda v: str(v))
 def test_the_other_presets_lower_as_at_the_parent(name, program):
-    cfg = configs.get(name)
-    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    cache = jax.eval_shape(lambda: decoding.init_sequence_state(
-        cfg, 33, 16, num_slots=8, prefill_chunk=64))
-    chunk, burst, _ = decoding.make_paged_engine_fns(cfg)
-    by_slot = cfg.state_by_slot
-
-    def arr(*shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    lanes = (arr(8), arr(8, 16), arr(8), arr(8, dtype=jnp.bool_))
-    if program == "chunk":
-        lowered = chunk.lower(params, cache, arr(64), arr(16), arr(), arr(),
-                              **({"slot": arr()} if by_slot else {}))
-    elif program == "burst":
-        lowered = burst.lower(
-            params, cache, *lanes, arr(8, dtype=jnp.float32),
-            jax.eval_shape(lambda: jax.random.key(0)), n_steps=8,
-            **({"slots": arr(8)} if by_slot else {}))
-    else:
-        step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, cfg),
-                       static_argnames=("routing",))
-        lowered = step.lower(
-            params, cache, *lanes, **({"slots": arr(8)} if by_slot else {}),
-            **({"routing": True} if cfg.n_experts else {}))
-    digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
-    assert digest == _LOWERED_AT_PR_43[(name, program)]
+    assert contract.lowered_digest(name, program, **contract.WIDE_SHAPES) \
+        == _LOWERED_AT_PR_43[(name, program)]

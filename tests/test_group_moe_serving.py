@@ -2,74 +2,41 @@
 by group on the served path (`tiny-group-moe`: DeepSeek-V3.2-Exp's layout
 at test size), held to the deepseek_v32 family's plain float32 reference
 (`bench/families/deepseek_v32.py`, which imports nothing of the program,
-attends in the plain form, scores its own groups and takes its own top-k).
-Tiny widths, seeded weights, float32 compute: the engine computes the
-reference's function, the shares of a layer add up to the uncut layer, the
-blocks alone are the sequence (prefixes shared, blocks copied on write,
-frames shipped, with a selection on), and every fault of the family's
-`control` is seen by the comparison that decides `correct`."""
+attends in the plain form, scores its own groups and takes its own top-k):
+the shares of a layer add up to the uncut layer, the blocks alone are the
+sequence (prefixes shared, blocks copied on write, frames shipped, with a
+selection on), and every fault of the family's `control` is seen by the
+comparison that decides `correct`.  The served contract's cases are
+`tests/served_contract.py`'s."""
 import dataclasses
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import served_contract as contract
+from bench.harness import reference, spec
+from ray_tpu.models import configs, decoding
+from ray_tpu.serve.llm import TICK_FIELDS
+from served_contract import ROOT, Family, seqs
 
-from bench.harness import reference, spec  # noqa: E402
-from ray_tpu.models import configs, decoding, mla_moe  # noqa: E402
-from ray_tpu.serve.llm import (  # noqa: E402
-    TICK_FIELDS,
-    LLMDeployment,
-    PagedLLMEngine,
-)
-
-TINY = os.path.join(ROOT, "bench", "tests", "data", "deepseekv32family",
-                    "configs", "tinydsv32-serve.json")
-SEED = 5
-EXACT = 2e-5          # float32 engine against float32 reference
-
-
-def _config(**over):
-    with open(TINY) as f:
-        return dict(json.load(f), **over)
-
-
-def _engine(c, cfg=None, **over):
-    true = spec.family(c).program_config(c)
-    eng = dict(c["engine"], **over)
-    return PagedLLMEngine(
-        cfg or true, true.init_params(jax.random.key(SEED)),
-        num_slots=eng["num_slots"], max_len=eng["max_len"],
-        block_size=eng["block_size"], prefill_chunk=eng["prefill_chunk"],
-        max_burst=eng["max_burst"], speculation_k=eng["speculation_k"],
-        prefix_sharing=eng.get("prefix_sharing", True))
-
-
-def _seqs(lanes, total, seed=0):
-    return np.random.default_rng(seed).integers(1, 512, (lanes, total))
-
-
-@pytest.fixture(scope="module")
-def served():
-    c = _config()
-    e = _engine(c)
-    yield e, c
-    e.shutdown()
+FAM = Family(
+    tiny="deepseekv32family/configs/tinydsv32-serve.json",
+    registry="tiny-group-moe",
+    as_registry=dict(compute_dtype=contract.FLOAT32),
+    published=("deepseek-v3.2-exp", 1e8, 6719),      # the published 671B
+    deployment=dict(engine="paged", num_slots=2, max_len=128, block_size=8,
+                    prefill_chunk=32), request=(50, 4))
+SEED, EXACT = FAM.seed, FAM.exact
+engines, served = contract.fixtures(FAM)
 
 
 # -- the configuration ---------------------------------------------------------
 def test_the_tiny_configuration_is_the_registry_s():
-    c = _config()
-    cfg = spec.family(c).program_config(c)
-    assert cfg == dataclasses.replace(
-        configs.get("tiny-group-moe"), name=c["name"],
-        compute_dtype=jnp.dtype("float32"))
+    _, cfg = contract.tiny_configuration_is_the_registry_s(FAM)
     assert cfg.kinds == ("full",) * 5 and not cfg.state_by_slot
     moe = cfg.moe
     assert (moe.n_groups, moe.groups_kept, moe.held) == (4, 2, (0, 2))
@@ -88,8 +55,7 @@ def test_the_tiny_configuration_is_the_registry_s():
 
 
 def test_published_sizes_give_the_published_parameter_count():
-    cfg = configs.get("deepseek-v3.2-exp")
-    assert round(cfg.num_params / 1e8) == 6719      # the published 671B
+    cfg, _ = contract.published_parameter_count(FAM)
     assert cfg.n_of("full") == 61 and cfg.n_dense_layers == 3
     assert (cfg.moe.n_groups, cfg.moe.groups_kept) == (8, 4)
     assert abs(cfg.attention_scale - 192 ** -0.5 * 1.8739) < 1e-5
@@ -118,22 +84,22 @@ def test_prefill_in_chunks_then_decode_equals_the_reference(served, n_prompt):
     strays), and, for the routing, against the reference's own groups and
     top-k with no routing handed over."""
     e, c = served
-    fam = spec.family(c)
-    seqs = _seqs(2, n_prompt + 8, seed=n_prompt)
-    got = fam.score(e, c, seqs, n_prompt)
+    fam = FAM.reference(c)
+    rows = seqs(2, n_prompt + 8, seed=n_prompt)
+    got = fam.score(e, c, rows, n_prompt)
     for lane in range(2):
-        tokens = jnp.asarray(seqs[lane], jnp.int32)
-        left = fam._HANDED[fam._key(seqs[lane])]
+        tokens = jnp.asarray(rows[lane], jnp.int32)
+        left = fam._HANDED[fam._key(rows[lane])]
         assert left["groups"].shape == (n_prompt + 8, 4, 2)
-        want, margin = fam.forward(e.params, tokens, c, jit=jax.jit)
+        want, margin = fam.forward(e.params, tokens, c, jit=contract.jit)
         errs = np.asarray(reference.position_errors(
             jnp.stack(got[lane]), want[n_prompt - 1:]))
         assert errs.shape == (9,) and errs.max() < EXACT, errs
         assert float(margin.min()) == 1.0 and fam.LAST["route_stray"] == 0.0
         if lane:
             continue
-        own, _ = fam.forward(e.params, tokens, c, jit=jax.jit, routing=None,
-                             selection=(0, left["selected"]))
+        own, _ = fam.forward(e.params, tokens, c, jit=contract.jit,
+                             routing=None, selection=(0, left["selected"]))
         errs = np.asarray(reference.position_errors(
             jnp.stack(got[lane]), own[n_prompt - 1:]))
         assert errs.max() < EXACT, errs
@@ -147,7 +113,7 @@ def test_a_burst_equals_its_steps_and_the_tick_log_counts(served):
     burst on this host fetches its selections (`select_masked` 0)."""
     e, c = served
     before = len(e.engine_stats()["tick_log"])
-    prompt = list(map(int, _seqs(1, 45, seed=45)[0]))
+    prompt = contract.prompt(45, 45)
     out = e.generate(prompt, max_tokens=17)
     got, taken = e.score(np.asarray(prompt + out)[None], len(prompt),
                          routing=True)
@@ -229,10 +195,12 @@ def test_a_burst_counts_the_reads_that_took_the_mask(monkeypatch):
     np.testing.assert_allclose(got[0].kv[:, 1:], out[0].kv[:, 1:], atol=1e-5)
 
 
-def test_the_tick_log_carries_the_burst_s_share_of_masked_reads(monkeypatch):
+def test_the_tick_log_carries_the_burst_s_share_of_masked_reads(
+        engines, monkeypatch):
     """`select_masked`: 100 x the burst's count over its steps x the
     layers that select, read with the burst's tokens; 0 in a tick without
-    a burst.  (The count is made to say 1 a read: this host fetches.)"""
+    a burst.  (The count is made to say 1 a read: this host fetches; an
+    engine of its own, traced with the count in place.)"""
     from ray_tpu.ops import attention
 
     fetch = attention._attend_selected
@@ -242,14 +210,10 @@ def test_the_tick_log_carries_the_burst_s_share_of_masked_reads(monkeypatch):
         return out, positions, jnp.int32(a[0].shape[1] == 1)
 
     monkeypatch.setattr(attention, "_attend_selected", counted)
-    c = _config()
-    e = _engine(c)
-    try:
-        prompt = list(map(int, _seqs(1, 45, seed=46)[0]))
+    with engines.private() as (e, _):
+        prompt = contract.prompt(45, 46)
         assert len(e.generate(prompt, max_tokens=17)) == 17
         stats = e.engine_stats()
-    finally:
-        e.shutdown()
     ticks = [dict(zip(stats["tick_fields"], t)) for t in stats["tick_log"]]
     assert [t["select_masked"] for t in ticks if t["lanes"]] == [100.0] * 2
     assert {t["select_masked"] for t in ticks if not t["lanes"]} == {0.0}
@@ -289,126 +253,38 @@ def test_the_benchmark_reads_the_mask_s_share_weighted_by_lanes():
 
 
 def test_deployment_takes_the_configuration_by_name():
-    dep = LLMDeployment("tiny-group-moe", engine="paged", num_slots=2,
-                        max_len=128, block_size=8, prefill_chunk=32)
-    try:
-        out = dep({"tokens": list(range(1, 50)), "max_tokens": 4})
-        assert len(out["tokens"]) == 4
+    with contract.deployed(FAM) as dep:
         state = dep.stats()["state"]
         assert state["kv_paged"] > 0 and state["kv_window"] == 0
-    finally:
-        dep.engine.shutdown()
 
 
 # -- blocks alone are the sequence, with a selection on ----------------------------
-def test_prefixes_are_shared_copied_on_write_and_shipped(served):
-    """A second request hits the first one's prefix (latent rows and index
-    keys), a block copied on write carries both leaves, and a stream's
-    blocks shipped to another engine as a frame of both leaves side by
-    side are adopted there, where the prompt then hits them and streams
-    what it streamed at home."""
-    from burst_ahead_cases import park, run_until_done, submit, tick
-
-    src, c = served
-    cfg = src.cfg
-    state = cfg.init_state(5, 8, 2, 16)
-    state = dataclasses.replace(
-        state, kv=state.kv.at[:, 1].set(1.0), idx=state.idx.at[:, 1].set(2.0))
-    out = decoding.copy_block(state, jnp.int32(3), jnp.int32(1))
-    assert float(out.kv[:, 3].min()) == 1.0 == float(out.kv[:, 1].min())
-    assert float(out.idx[:, 3].min()) == 2.0 and float(out.idx[:, 2].max()) == 0
-
-    prompt = list(map(int, _seqs(1, 70, seed=70)[0]))
-    dst = _engine(c, num_slots=2, max_len=128)
-    try:
-        first = src.generate(prompt, max_tokens=36)
-        hits = src.stats["prefix_hits"]
-        assert src.generate(prompt, max_tokens=36) == first
-        assert src.stats["prefix_hits"] == hits + 1
-        park(src)
-        req = submit(src, prompt, 36, stream=True)
-        req.trace = {"trace_id": "rid-group"}
-        for _ in range(50):
-            tick(src)
-            if len(req.out_tokens) >= 4:
-                break
-        (ticket,) = src.export_streams()
-        n_kv = len(ticket["tokens"])
-        kv = np.asarray(ticket["kv"])
-        assert kv.shape == (1, 5, -(-n_kv // 8), 8, 128 + 16)
-        assert kv[..., 128:].any()                   # the index keys ride
-        assert dst.import_prefix(ticket["tokens"], kv[..., :128], 8) == 0
-        assert dst.import_prefix(ticket["tokens"], kv, 8) == -(-n_kv // 8)
-        hits = dst.stats["prefix_hits"]
-        assert dst.generate(prompt, max_tokens=36) == first
-        assert dst.stats["prefix_hits"] == hits + 1
-        run_until_done(src, [req])
-        assert req.out_tokens == first
-    finally:
-        dst.shutdown()
+def test_prefixes_are_shared_copied_on_write_and_shipped(served, engines):
+    src, _ = served                 # unparked when it is handed out again
+    contract.copy_block_copies_both_pooled_leaves(src.cfg)
+    dst, _ = engines(num_slots=2, max_len=128)
+    contract.prefix_shared_and_both_leaves_shipped(
+        src, dst, contract.prompt(70, 70), 36, "rid-group")
 
 
-def test_speculation_equals_the_plain_stream():
+def test_speculation_equals_the_plain_stream(engines):
     """Speculation stays on for full layers alone: a verify step selects
     as a chunk does."""
-    c = _config()
     prompt = [100, 200] * 12
-    plain, spec_ = _engine(c, max_burst=1), _engine(
-        c, max_burst=1, speculation_k=4)
-    try:
-        assert plain.generate(prompt, max_tokens=12) \
-            == spec_.generate(prompt, max_tokens=12)
-    finally:
-        plain.shutdown()
-        spec_.shutdown()
+    plain, _ = engines(max_burst=1)
+    spec_, _ = engines(max_burst=1, speculation_k=4)
+    assert plain.generate(prompt, max_tokens=12) \
+        == spec_.generate(prompt, max_tokens=12)
 
 
 # -- the share tied to the model ---------------------------------------------------
 @pytest.mark.parametrize("held", [2, 4], ids=["half a group", "a group"])
 def test_the_shares_add_up_to_the_uncut_layer(held):
-    """The program's expert layer run as each rank of the tiny model (16
-    experts in 4 groups, 2 kept, the router 16 wide on every rank): the
-    ranks' routed parts plus the shared expert counted once are the uncut
-    reference's layer under group-limited routing, each rank's part is
-    the reference's given that share, and the ranks' `group_open_rows`
-    are the rows that keep a group of theirs."""
-    whole = _config(n_routed_experts=16)
-    fam = spec.family(whole)
-    cfg_all = fam.program_config(whole)
-    assert cfg_all.experts_held is None
-    params = cfg_all.init_params(jax.random.key(SEED))
-    fp = {k: v[2] for k, v in params["ffn"].items()}
-    x = jax.random.normal(jax.random.key(2), (1, 40, 64), jnp.float32)
-    stacks = ("w_gate", "w_up", "w_down")
-    u = fam._rms_norm(x[0], fp["norm"], 1e-6)
-    shared = fam.shared_expert(u, fp)
-    uncut, margin, _ = fam.experts(u, fp, None, whole)
-    assert float(margin.min()) > 0
-    parts, routed, opened = [], 0, 0
-    for first in range(0, 16, held):
-        share = _config(n_routed_experts=held, first_local_expert=first)
-        cfg = fam.program_config(share)
-        assert cfg.experts_held == (first, held)
-        mine = {k: (v[first:first + held] if k in stacks else v)
-                for k, v in fp.items()}
-        out, visited, counts, taken = mla_moe._expert_ffn(
-            {k: v for k, v in mine.items() if k not in stacks},
-            {k: mine[k][None] for k in stacks}, 0, x,
-            jnp.ones((1, 40), bool), cfg, True)
-        want, _, bad = fam.experts(u, mine, taken[0][:, :3], share,
-                                   groups=taken[0][:, 3:])
-        assert not bool(bad.any())
-        np.testing.assert_allclose(out[0], want + shared, atol=2e-5)
-        parts.append(out[0] - shared)
-        routed += int(counts[0])
-        opened += int(counts[2])
-        assert int(counts[2]) == int(
-            (taken[0][:, 3:] == first // 4).any(-1).sum())
-    assert routed == 40 * 3
-    assert opened == 40 * 2 * (4 // held)     # 2 kept groups a row
-    np.testing.assert_allclose(sum(parts) + shared, uncut + shared,
-                               atol=6e-5)
-    assert float(jnp.abs(parts[0] - parts[1]).max()) > 0.01
+    """16 experts in 4 groups, 2 kept, under group-limited routing: the
+    ranks' `group_open_rows` are the rows that keep a group of theirs."""
+    rows, _, opened = contract.ranks_shares_add_up(FAM, 16, held, 1e-6,
+                                                   groups=True)
+    assert opened == rows * 2 * (4 // held)     # 2 kept groups a row
 
 
 # -- the comparison that decides `correct` sees each fault -------------------------
@@ -417,17 +293,19 @@ FAULTS = ("sound", "one_group", "group_max", "no_scale", "top_half",
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_logits_check_sees_each_control(fault, monkeypatch):
+def test_logits_check_sees_each_control(engines, fault, monkeypatch):
     """`deployment.logits_check` (2 lanes x (the last of 40 prompt
     positions + 6 decode steps), float32 throughout, its limits tightened
     to what float32 leaves: nothing strays) passes the program as it is
     with every position decided and refuses the program under each fault
     of the family's `control`: by a group or an expert outside its slack,
     by a selection of the wrong size or outside its slack (NaN), or by
-    the error of its logits."""
+    the error of its logits.  (An engine a control: it patches what the
+    programs are traced from.)"""
     from bench.harness.deployment import logits_check
 
-    c = _config(check={"lanes": 2, "prompt_len": 40, "decode_steps": 6})
+    check = {"check": {"lanes": 2, "prompt_len": 40, "decode_steps": 6}}
+    c = FAM.config(**check)
     fam = spec.family(c)
     for name, value in (("LOGITS_REL_EXPERTS", 100 * EXACT),
                         ("ROUTER_SLACK", 0.02), ("SELECT_SLACK", 0.02),
@@ -435,11 +313,8 @@ def test_logits_check_sees_each_control(fault, monkeypatch):
         monkeypatch.setitem(fam.TOLERANCES, name, value)
     cfg, undo = fam.control(fault, fam.program_config(c))
     try:
-        e = _engine(c, cfg)
-        try:
+        with engines.private(config=check, cfg=cfg) as (e, c):
             v = logits_check(e, c, SEED)
-        finally:
-            e.shutdown()
     finally:
         undo()
     assert v["positions"] == 14

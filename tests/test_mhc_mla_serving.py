@@ -3,87 +3,41 @@ hyper-connections (`ops.hyper_connections`) in `MLAMoEConfig`'s own stack,
 on the served path, held to the xing4 family's plain float32 reference
 (`bench/families/xing4.py`, which imports nothing of `ray_tpu/models/` or
 `ray_tpu/ops/`, keeps the streams as a dimension of their own and runs the
-Sinkhorn rounds as sums over axes): prefill in chunks of unequal size, each
-reading the lane's earlier blocks of the latent pool, then decode steps,
-through a real `PagedLLMEngine`; two leading dense layers and three expert
-layers (the scan runs three periods), 8 experts top-3 all held; the rope
-under YaRN with its factor on the soft-max scale.  Tiny widths, seeded
-weights, float32 compute: the engine computes the reference's function to
-1e-5, and a program with bfloat16 coefficients, without the clip, or with
-fewer than 20 rounds does not."""
+Sinkhorn rounds as sums over axes); two leading dense layers and three
+expert layers (the scan runs three periods), 8 experts top-3 all held; the
+rope under YaRN with its factor on the soft-max scale.  The engine computes
+the reference's function to 1e-5, and a program with bfloat16 coefficients,
+without the clip, or with fewer than 20 rounds does not.  The served
+contract's cases are `tests/served_contract.py`'s."""
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import served_contract as contract
+from ray_tpu.models import configs, decoding, mla_moe
+from ray_tpu.ops import hyper_connections as hc
+from ray_tpu.serve.llm import TICK_FIELDS, PagedLLMEngine
+from served_contract import Family, seqs
 
-from bench.harness import reference, spec  # noqa: E402
-from ray_tpu.models import configs, decoding, mla_moe  # noqa: E402
-from ray_tpu.ops import hyper_connections as hc  # noqa: E402
-from ray_tpu.serve.llm import TICK_FIELDS, LLMDeployment, PagedLLMEngine  # noqa: E402
-
-TINY = os.path.join(ROOT, "bench", "tests", "data", "xing4family",
-                    "configs", "tinyxing4-serve.json")
-SEED = 7
-EXACT = 2e-5          # float32 engine against float32 reference
-
-
-def _config(**over):
-    with open(TINY) as f:
-        return dict(json.load(f), **over)
-
-
-def _engine(c, cfg=None, **over):
-    fam = spec.family(c)
-    cfg, eng = cfg or fam.program_config(c), dict(c["engine"], **over)
-    return PagedLLMEngine(
-        cfg, cfg.init_params(jax.random.key(SEED)),
-        num_slots=eng["num_slots"], max_len=eng["max_len"],
-        block_size=eng["block_size"], prefill_chunk=eng["prefill_chunk"],
-        max_burst=eng["max_burst"], speculation_k=eng["speculation_k"])
-
-
-def _errors(e, c, seqs, n_prompt):
-    """The engine's logits against the reference's, the reference given the
-    experts the program took and each position's defect."""
-    fam = spec.family(c)
-    got, taken = e.score(seqs, n_prompt, routing=True)
-    errs = []
-    for lane in range(len(seqs)):
-        want, _ = fam.forward(
-            e.params, jnp.asarray(seqs[lane], jnp.int32), c, jit=jax.jit,
-            routing=jax.tree.map(np.asarray, taken[lane]))
-        errs.append(np.asarray(reference.position_errors(
-            jnp.stack(got[lane]), want[n_prompt - 1:])))
-    return np.concatenate(errs)
-
-
-def _seqs(lanes, total, seed=0):
-    return np.random.default_rng(seed).integers(1, 512, (lanes, total))
-
-
-@pytest.fixture(scope="module")
-def served():
-    c = _config()
-    e = _engine(c)
-    yield e, c
-    e.shutdown()
+FAM = Family(
+    tiny="xing4family/configs/tinyxing4-serve.json",
+    registry="tiny-mhc-mla-moe",
+    as_registry=dict(norm_eps=1e-6, compute_dtype=contract.FLOAT32),
+    published=("xing4.0-29b-a4b", 1e7, 2951),           # "29B" published
+    # the norms' gains and the routers' biases on top
+    leaves=("xing4.0-29b-a4b", 1e-4), seed=7,
+    # the experts the program took and each position's defect
+    handed=lambda taken: {"routing": jax.tree.map(np.asarray, taken)},
+    deployment=dict(contract.SMALL, engine="paged"))
+SEED, EXACT = FAM.seed, FAM.exact
+engines, served = contract.fixtures(FAM)
 
 
 def test_the_tiny_configuration_is_the_registry_s():
-    c = _config()
-    fam = spec.family(c)
-    cfg = fam.program_config(c)
-    assert cfg == dataclasses.replace(
-        configs.get("tiny-mhc-mla-moe"), name=c["name"], norm_eps=1e-6,
-        compute_dtype=jnp.dtype("float32"))
+    _, cfg = contract.tiny_configuration_is_the_registry_s(FAM)
     assert (cfg.hc_mult, cfg.hc_sinkhorn_iters, cfg.hc_eps,
             cfg.hc_res_clamp) == (4, 20, 1e-6, (-30.0, 30.0))
     assert cfg.n_dense_layers == 2 and cfg.n_expert_layers == 3
@@ -98,9 +52,9 @@ def test_the_yarn_scale_is_built_in_one_place():
     """(d_nope + d_rope)^-0.5 x (0.1 mscale_all_dim ln factor + 1)^2, in
     `kind()` alone, and `attention_scale` returns it: 0.14468 at the
     published sizes, as the family's own arithmetic; cos and sin x 1."""
-    c = _config()
-    fam = spec.family(c)
-    cfg = fam.program_config(c)
+    c = FAM.config()
+    fam = FAM.reference(c)
+    cfg = FAM.program_config(c)
     assert cfg.attention_scale == cfg.kind("full").scale
     assert cfg.attention_scale == pytest.approx(
         20 ** -0.5 * (0.1 * np.log(8) + 1) ** 2)
@@ -116,37 +70,27 @@ def test_the_yarn_scale_is_built_in_one_place():
 
 
 def test_published_sizes_give_the_published_parameter_count():
-    cfg = configs.get("xing4.0-29b-a4b")
-    assert round(cfg.num_params / 1e7) == 2951            # "29B" published
-    shapes = jax.eval_shape(lambda: cfg.init_params(jax.random.key(0)))
-    total = sum(x.size for x in jax.tree.leaves(shapes))
-    # the norms' gains and the routers' biases on top
-    assert 0 < total - cfg.num_params < 1e-4 * cfg.num_params
+    cfg, shapes = contract.published_parameter_count(FAM)
     assert shapes["hc_attn"]["phi"].shape == (40, 24, 4 * 3584)
     assert shapes["hc_ffn"]["b"].dtype == jnp.float32
     one = dataclasses.replace(cfg, hc_mult=0)
     assert cfg.num_params - one.num_params == 2 * 40 * (14336 * 24 + 27)
 
 
-def test_chunks_of_unequal_size_then_decode(served):
+def test_chunks_of_unequal_size_then_decode(served, engines):
     """100 prompt tokens as one launch of the 128-row tier and, on a narrow
     engine, as chunks of 32, 32, 32 and a tail of 4 padded, each reading
     the lane's earlier blocks; then 10 decode steps; against the plain
     reference's full forward, on logits."""
     e, c = served
-    seqs = _seqs(2, 100 + 10)
-    errs = _errors(e, c, seqs, 100)
-    assert errs.shape == (22,) and errs.max() < EXACT, errs
-    fam = spec.family(c)
+    contract.prefill_then_decode_equals_the_reference(FAM, e, c, 2, 100, 10)
+    fam = FAM.reference(c)
     assert fam.LAST["hc_defect_median_program"] == pytest.approx(
         fam.LAST["hc_defect_median"], rel=0.2)
-    narrow = _engine(c, prefill_chunk=32)
-    try:
+    with engines.private(prefill_chunk=32) as (narrow, _):   # its tiers cut
         narrow._chunk_tiers = [t for t in narrow._chunk_tiers if t <= 32]
-        errs = _errors(narrow, c, seqs[:1, :70], 68)
+        errs = FAM.errors(narrow, c, seqs(2, 100 + 10)[:1, :70], 68)
         assert errs.max() < EXACT, errs
-    finally:
-        narrow.shutdown()
 
 
 def test_idle_lanes_change_nothing(served):
@@ -158,7 +102,7 @@ def test_idle_lanes_change_nothing(served):
     cache = decoding.init_sequence_state(cfg, 17, 8, num_slots=4,
                                          prefill_chunk=32)
     chunk, burst, _ = decoding.make_paged_engine_fns(cfg)
-    toks = jnp.asarray(_seqs(1, 32)[0], jnp.int32)
+    toks = jnp.asarray(seqs(1, 32)[0], jnp.int32)
     table = jnp.arange(1, 9, dtype=jnp.int32)
     cache, _, defect = chunk(e.params, cache, toks, table, jnp.int32(0),
                              jnp.int32(20))
@@ -199,7 +143,7 @@ def test_expansion_and_contraction(monkeypatch):
 
     monkeypatch.setattr(mla_moe, "hc_coefficients", through)
     state = cfg.init_state(9, 8, 0, 0)
-    toks = jnp.asarray(_seqs(1, 24), jnp.int32)
+    toks = jnp.asarray(seqs(1, 24), jnp.int32)
     args = (toks, jnp.arange(1, 9, dtype=jnp.int32)[None],
             jnp.arange(24, dtype=jnp.int32)[None], jnp.array([24], jnp.int32))
     _, x, *_ = mla_moe._served_step(params, state, *args, cfg)
@@ -221,16 +165,16 @@ def test_the_defect_is_in_the_tick_log(served):
     fields = e.engine_stats()["tick_fields"]
     assert fields == TICK_FIELDS + ("hc_res_defect",)
     at = len(fields) - 1
-    prompt = [int(t) for t in _seqs(1, 90, seed=3)[0]]
+    prompt = [int(t) for t in seqs(1, 90, seed=3)[0]]
     before = len(e.engine_stats()["tick_log"])
     out = e.generate(prompt, max_tokens=9)
     log = e.engine_stats()["tick_log"][before:]
     seen = [t[at] for t in log]
     assert all(isinstance(v, float) and 0 <= v < 0.05 for v in seen), seen
     assert max(seen) > 1e-6
-    fam = spec.family(c)
+    fam = FAM.reference(c)
     fam.forward(e.params, jnp.asarray(prompt + out, jnp.int32), c,
-                jit=jax.jit, routing=None)
+                jit=contract.jit, routing=None)
     # the program's rows are the reference's but for the last token's,
     # whose mixes no launch ran
     assert max(seen) == pytest.approx(fam.LAST["hc_res_defect"], rel=0.05)
@@ -249,16 +193,9 @@ def test_the_defect_is_in_the_tick_log(served):
 
 
 def test_served_by_the_deployment():
-    dep = LLMDeployment(configs.get("tiny-mhc-mla-moe"), num_slots=2,
-                        max_len=64, block_size=8, prefill_chunk=16,
-                        engine="paged")
-    try:
-        out = dep({"tokens": list(range(1, 20)), "max_tokens": 3})
-        assert len(out["tokens"]) == 3
+    with contract.deployed(FAM, configs.get(FAM.registry)) as dep:
         assert dep.stats()["state"]["kv_paged"] \
             == dep.engine.cache.kv.size * 2          # bfloat16
-    finally:
-        dep.engine.shutdown()
 
 
 # -- the comparison has teeth -------------------------------------------------
@@ -298,17 +235,14 @@ def _mix_up_without_h_post(monkeypatch, cfg):
 @pytest.mark.parametrize("fault", [
     _bfloat16_coefficients, _without_the_clip, _five_rounds,
     _mix_up_without_h_post], ids=lambda f: f.__name__.strip("_"))
-def test_the_reference_tells_a_fault_of_the_mixing(fault, monkeypatch):
+def test_the_reference_tells_a_fault_of_the_mixing(engines, fault,
+                                                   monkeypatch):
     """(`test_chunks_of_unequal_size_then_decode` holds the program as it
     is to the same 2e-5.)"""
-    c = _config()
-    cfg = fault(monkeypatch, spec.family(c).program_config(c))
+    c = FAM.config()
+    cfg = fault(monkeypatch, FAM.program_config(c))
     if isinstance(cfg, tuple):               # the reference's side changes
         cfg, over = cfg
         c = dict(c, **over)
-    e = _engine(c, cfg)
-    try:
-        errs = _errors(e, c, _seqs(2, 64 + 4, seed=2), 64)
-    finally:
-        e.shutdown()
-    assert not np.all(np.isfinite(errs)) or errs.min() > 5 * EXACT, errs
+    contract.a_fault_is_seen(FAM, engines, cfg, c, n_prompt=64, seed=2,
+                             times=5)

@@ -3,18 +3,10 @@
 with its own YaRN rope, a head size the width does not give, QK-norm,
 eight small experts top-4), held to the mellum family's plain float32
 reference (`bench/families/mellum.py`, which imports nothing of the
-program) and to `transformer.forward`: prefill and decode through a real
-`PagedLLMEngine`, whose slots hold a block table for the full layers and a
-ring of window KV for each window layer.  Tiny widths, seeded weights,
-float32 compute where the claim is that the engine computes the same
-function (errors of 1e-6), bfloat16 where it is that the benchmark's
-comparison tells a fault from rounding."""
+program) and to `transformer.forward`; its slots hold a block table for
+the full layers and a ring of window KV for each window layer.  The served
+contract's cases are `tests/served_contract.py`'s."""
 import dataclasses
-import json
-import os
-import sys
-import threading
-import time
 import warnings
 
 import jax
@@ -22,69 +14,36 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+import served_contract as contract
+from bench.harness import reference
+from ray_tpu.models import configs, decoding, init_params
+from ray_tpu.models.transformer import forward
+from ray_tpu.serve.llm import PagedLLMEngine
+from served_contract import Family, Teeth, on_the_engine, seqs
 
-from bench.harness import reference, spec  # noqa: E402
-from ray_tpu.models import configs, decoding, init_params  # noqa: E402
-from ray_tpu.models.transformer import forward  # noqa: E402
-from ray_tpu.serve.llm import LLMDeployment, PagedLLMEngine  # noqa: E402
-
-TINY = os.path.join(ROOT, "bench", "tests", "data", "mellumfamily",
-                    "configs", "tinymellum-serve.json")
-SEED = 5
-EXACT = 2e-5          # float32 engine against float32 reference
-
-
-def _config(**over):
-    with open(TINY) as f:
-        return dict(json.load(f), **over)
-
-
-def _engine(c, cfg=None, **over):
-    fam = spec.family(c)
-    true = fam.program_config(c)
-    eng = dict(c["engine"], **over)
-    return PagedLLMEngine(
-        cfg or true, init_params(jax.random.key(SEED), true),
-        num_slots=eng["num_slots"], max_len=eng["max_len"],
-        block_size=eng["block_size"], prefill_chunk=eng["prefill_chunk"],
-        max_burst=eng["max_burst"], num_blocks=eng.get("num_blocks"))
-
-
-def _want(e, c, seq, routing=None):
-    logits, _ = spec.family(c).forward(
-        e.params, jnp.asarray(seq, jnp.int32), c, jit=jax.jit,
-        routing=routing)
-    return logits
-
-
-def _errors(e, c, seqs, n_prompt):
-    got = e.score(seqs, n_prompt)
-    return np.concatenate([
-        np.asarray(reference.position_errors(
-            jnp.stack(got[lane]), _want(e, c, seqs[lane])[n_prompt - 1:]))
-        for lane in range(len(seqs))])
-
-
-def _seqs(lanes, total, seed=0):
-    return np.random.default_rng(seed).integers(1, 512, (lanes, total))
-
-
-@pytest.fixture(scope="module")
-def served():
-    c = _config()
-    e = _engine(c)
-    yield e, c
-    e.shutdown()
+FAM = Family(
+    tiny="mellumfamily/configs/tinymellum-serve.json",
+    registry="tiny-window-moe",
+    as_registry=dict(param_dtype=contract.FLOAT32,
+                     compute_dtype=contract.FLOAT32),
+    published=("mellum2-12b", 1e7, 1215),               # "12B"
+    leaves=("tiny-window-moe", None), own_init=False,
+    front=("windowmoe", {}), slot_leaves=("wk", "wv"), written=("wk", "wv"),
+    refusals=dict(speculation_k="rows of the window rings",
+                  export_streams="export_streams.*ring of",
+                  import_prefix="import_prefix.*ring of",
+                  frame=(2, 2, 4, 8, 2, 16),
+                  deployment="ring of window KV by slot"),
+    # The family's LOGITS_REL_EXPERTS was measured at the published widths;
+    # at a width of 48 bfloat16 rounds coarser (as it is: 0.020 at most,
+    # 8-bit cache: 0.09-0.12), so the bound here is 0.04.
+    teeth=Teeth(tolerances={"LOGITS_REL_EXPERTS": 0.04}, sound_margin=0.6))
+EXACT = FAM.exact
+engines, served = contract.fixtures(FAM)
 
 
 def test_the_tiny_configuration_is_the_registry_s():
-    c = _config()
-    cfg = spec.family(c).program_config(c)
-    assert cfg == dataclasses.replace(
-        configs.get("tiny-window-moe"), name=c["name"],
-        param_dtype=jnp.dtype("float32"), compute_dtype=jnp.dtype("float32"))
+    _, cfg = contract.tiny_configuration_is_the_registry_s(FAM)
     assert cfg.period == ("window",) * 3 + ("full",)
     assert cfg.n_of("window") == 6 and cfg.n_of("full") == 2
     assert cfg.head_dim == 16 != cfg.d_model // cfg.n_heads
@@ -104,13 +63,9 @@ def test_an_old_configuration_is_the_object_it_was():
 
 
 def test_published_sizes_give_the_published_parameter_count():
-    cfg = configs.get("mellum2-12b")
-    assert round(cfg.num_params / 1e7) == 1215         # "12B"
+    cfg, _ = contract.published_parameter_count(FAM)
     active = cfg.num_params - cfg.n_layers * (64 - 8) * 3 * 2304 * 896
     assert round(active / 1e7) == 244                  # "A2.5B"
-    tiny = configs.get("tiny-window-moe")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), tiny))
-    assert sum(x.size for x in jax.tree.leaves(shapes)) == tiny.num_params
 
 
 def test_bad_patterns_are_refused():
@@ -131,15 +86,14 @@ def test_prompt_longer_than_window_and_one_turn_of_the_ring(served):
     e, c = served
     assert e.cache.wk.shape == (6, 5, 44, 2, 16)
     assert e.cache.k.shape[0] == 2
-    seqs = _seqs(3, 100 + 10)
-    errs = _errors(e, c, seqs, 100)
-    assert errs.shape == (33,) and errs.max() < EXACT, errs
+    contract.prefill_then_decode_equals_the_reference(FAM, e, c, 3, 100, 10)
+    rows = seqs(3, 100 + 10)
     cfg = dataclasses.replace(e.cfg, capacity_factor=2.0)    # E / top_k
-    got = e.score(seqs, 100)
+    got = e.score(rows, 100)
     with warnings.catch_warnings(record=True) as seen:
         warnings.simplefilter("always")
         with jax.default_matmul_precision("highest"):
-            want = forward(e.params, jnp.asarray(seqs, jnp.int32), cfg)
+            want = forward(e.params, jnp.asarray(rows, jnp.int32), cfg)
     assert any("window attention" in str(w.message) for w in seen)
     for lane in range(3):
         err = reference.position_errors(jnp.stack(got[lane]),
@@ -155,134 +109,43 @@ def test_forward_refuses_a_window_under_sequence_parallelism():
 
 
 # -- (ii) every chunk tier, and a padded last chunk ---------------------------
-@pytest.fixture(scope="module")
-def served_chunk64():
-    c = _config()
-    e = _engine(c, prefill_chunk=64)
-    yield e, c
-    e.shutdown()
-
-
 @pytest.mark.parametrize("n_prompt", [64, 81, 97, 128])
-def test_every_chunk_tier_and_a_padded_tail(served_chunk64, n_prompt):
+def test_every_chunk_tier_and_a_padded_tail(engines, n_prompt):
     """prefill_chunk 64 has the tiers 32 and 64: 64 = one whole chunk,
     81 = 64 + 17 (tier 32, padded), 97 = 64 + 33 (tier 64, padded),
-    128 = two whole.  A padded position writes no ring row."""
-    e, c = served_chunk64
-    assert e._chunk_tiers == [32, 64] and e.cache.wk.shape[2] == 76
-    errs = _errors(e, c, _seqs(2, n_prompt + 4, seed=n_prompt), n_prompt)
-    assert errs.max() < EXACT, errs
+    128 = two whole."""
+    e = contract.every_chunk_tier_and_a_padded_tail(FAM, engines, n_prompt,
+                                                    [32, 64])
+    assert e.cache.wk.shape[2] == 76
 
 
 # -- (iii) unequal lanes and an idle lane between them ------------------------
 def test_unequal_lanes_with_an_idle_lane_between(served):
-    """The step the burst scans, over lanes 0 and 2 of different lengths
-    with lane 1 idle and pointed at its own slot all the same: the live
-    lanes' logits are the reference's, and the idle lane's slot and the
-    null slot keep their rings to the bit."""
-    e, c = served
-    seqs = [_seqs(1, 70, seed=1)[0], _seqs(1, 30, seed=2)[0],
-            _seqs(1, 41, seed=3)[0]]
-    step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, e.cfg))
-    bs = e.block_size
-    with e._tick_lock:
-        tables = np.zeros((4, e._b_max), np.int32)
-        for lane, seq in enumerate(seqs):
-            per = -(-(len(seq) + 1) // bs)
-            tables[lane, :per] = 1 + lane * 16 + np.arange(per)
-            for start in range(0, len(seq) - 1, e.prefill_chunk):
-                toks = np.zeros((e.prefill_chunk,), np.int32)
-                nv = min(e.prefill_chunk, len(seq) - 1 - start)
-                toks[:nv] = seq[start:start + nv]
-                e.cache, _ = e._prefill_chunk_fn(
-                    e.params, e.cache, jnp.asarray(toks),
-                    jnp.asarray(tables[lane]), jnp.int32(start),
-                    jnp.int32(nv), slot=jnp.int32(lane))
-        before = jax.tree.map(np.asarray, e.cache)
-        lengths = np.array([69, 29, 40, 0], np.int32)
-        active = np.array([True, False, True, False])
-        cache, logits = step(
-            e.params, e.cache, jnp.asarray([s[-1] for s in seqs] + [0],
-                                           jnp.int32),
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
-            slots=jnp.asarray([0, 1, 2, e.num_slots], jnp.int32))
-        after = jax.tree.map(np.asarray, cache)
-    for lane in (0, 2):
-        err = reference.position_errors(
-            logits[lane][None], _want(e, c, seqs[lane])[-1:])
-        assert float(err[0]) < EXACT
-    for name in ("wk", "wv"):
-        for slot in (1, e.num_slots):
-            assert np.array_equal(getattr(after, name)[:, slot],
-                                  getattr(before, name)[:, slot]), name
-        assert not np.array_equal(getattr(after, name)[:, 0],
-                                  getattr(before, name)[:, 0])
+    contract.unequal_lanes_with_an_idle_lane_between(FAM, *served)
 
 
 def test_a_burst_equals_its_steps(served):
-    e, c = served
-    cfg = e.cfg
-    state = decoding.init_sequence_state(cfg, 17, 8, num_slots=4,
-                                         prefill_chunk=32)
-    tables = jnp.asarray(np.arange(1, 17, dtype=np.int32).reshape(4, 4))
-    lengths = jnp.asarray([3, 0, 9, 1], jnp.int32)
-    active = jnp.asarray([True, False, True, True])
-    slots = jnp.asarray([2, 4, 0, 3], jnp.int32)
-    toks = jnp.asarray([5, 0, 7, 9], jnp.int32)
-    temps = jnp.zeros((4,), jnp.float32)
-    burst = jax.jit(decoding._bind_cfg(decoding.paged_decode_burst, cfg),
-                    static_argnames=("n_steps",))
-    b_state, b_toks, _, visited = burst(
-        e.params, state, toks, tables, lengths, active, temps,
-        jax.random.key(0), n_steps=3, slots=slots)
-    step = jax.jit(decoding._bind_cfg(decoding.paged_decode_step, cfg))
-    s_toks = []
-    for _ in range(3):
-        state, logits = step(e.params, state, toks, tables, lengths, active,
-                             slots=slots)
-        toks = jnp.argmax(logits, -1).astype(jnp.int32)
-        lengths = jnp.where(active, lengths + 1, lengths)
-        s_toks.append(toks)
-    live = np.asarray(active)
-    assert np.array_equal(np.asarray(b_toks)[:, live],
-                          np.stack(s_toks)[:, live])
-    for a, b in zip(jax.tree.leaves(b_state), jax.tree.leaves(state)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    b_state, state, visited, _, _ = contract.burst_equals_its_steps(served[0])
+    contract.leaves_agree(b_state, state)
     assert not np.asarray(b_state.wk[:, 1]).any()     # a slot no lane had
     # three live lanes x top-4 of 8 experts, 3 steps x 8 layers
-    assert 4 * 24 <= int(visited) <= 8 * 24
+    assert 4 * 24 <= visited <= 8 * 24
 
 
 # -- (iv), (v): the engine's own scheduling ------------------------------------
-def _is_greedy(e, c, prompt, out):
-    """`out` is the reference's greedy continuation of `prompt`: one
-    full forward over both (its own routing: float32 on both sides)."""
-    logits = _want(e, c, list(prompt) + list(out))
-    return out == [int(t) for t in
-                   jnp.argmax(logits[len(prompt) - 1:-1], axis=-1)]
-
-
 def test_a_slot_reused_without_a_reset(served):
     """The second request takes slot 0 with the first's rows still in its
     rings: a stale row lies at a position the new sequence has not
     reached, and is masked.  Nothing is zeroed."""
     e, c = served
-    first = list(map(int, _seqs(1, 90, seed=11)[0]))
-    second = list(map(int, _seqs(1, 35, seed=12)[0]))
-    out1 = e.generate(first, max_tokens=6)
+    _, stats, ticks = contract.a_slot_reused_by_a_second_request(
+        FAM, e, c, (90, 35))
     assert np.asarray(e.cache.wk[:, 0]).any()
-    out2 = e.generate(second, max_tokens=6)      # slot 0 again
-    assert len(out1) == len(out2) == 6
-    assert _is_greedy(e, c, first, out1) and _is_greedy(e, c, second, out2)
-    with e._tick_lock:
-        stats = e.engine_stats()
-    assert stats["state"]["state_resets"] == 0
-    assert stats["prefix_hits"] == 0
     state = stats["state"]
+    assert state["state_resets"] == 0
     assert state["kv_paged"] == 2 * 2 * e.num_blocks * 8 * 2 * 16 * 4
     assert state["kv_window"] == 2 * 6 * 5 * 44 * 2 * 16 * 4
     assert state["recurrent"] == 0
-    ticks = [dict(zip(stats["tick_fields"], t)) for t in stats["tick_log"]]
     assert all(t["reset_s"] == 0 for t in ticks)
     one = [t for t in ticks if t["lanes"] == 1][-1]
     # one lane of length n > 12: two full layers see n, six windows 12
@@ -291,76 +154,27 @@ def test_a_slot_reused_without_a_reset(served):
     assert 4 <= one["experts_read"] <= 4.0           # one lane: its top-4
 
 
-def test_a_preempted_stream_equals_the_undisturbed_one():
-    """A pool too small for two streams' growth: the younger is
-    preempted mid-decode and re-prefills prompt + emitted tokens over the
-    rows its first pass left in the rings."""
-    c = _config()
-    e = _engine(c, num_blocks=12, max_burst=4)
-    try:
-        prompts = [list(map(int, _seqs(1, 30, seed=s)[0])) for s in (21, 22)]
-        outs = [None, None]
-
-        def run(i):
-            outs[i] = e.generate(prompts[i], max_tokens=24)
-
-        threads = [threading.Thread(target=run, args=(i,)) for i in (0, 1)]
-        for t in threads:
-            t.start()
-            time.sleep(0.05)
-        for t in threads:
-            t.join(timeout=120)
-        stats = e.engine_stats()
-        assert stats["preemptions"] >= 1
-        assert stats["state"]["state_resets"] == 0
-        assert all(len(o) == 24 for o in outs)
-        assert all(_is_greedy(e, c, p, o) for p, o in zip(prompts, outs))
-    finally:
-        e.shutdown()
+def test_a_preempted_stream_equals_the_undisturbed_one(engines):
+    """The younger re-prefills over the rows its first pass left in the
+    rings: nothing is zeroed."""
+    stats = contract.preempted_stream_equals_the_undisturbed_one(FAM, engines)
+    assert stats["state"]["state_resets"] == 0
 
 
-def test_streams_equal_the_step_reference_while_lanes_join_and_leave():
-    """The engine launches a burst before it has read the one before
-    (tests/test_burst_ahead.py), here on slots that hold rings beside the
-    pool, with experts whose count is read with the tokens: requests of
-    different lengths join and leave mid-stream, the tiers go 4, 8, 4, a
-    slot changes hands while its last burst is unread, and every stream
-    is the step-by-step reference's."""
-    from burst_ahead_cases import join_and_leave, park
-
-    e = park(_engine(_config(), num_slots=8))
-    try:
-        join_and_leave(e)
-    finally:
-        e.shutdown()
+def test_streams_equal_the_step_reference_while_lanes_join_and_leave(engines):
+    """On slots that hold rings beside the pool, with experts whose count
+    is read with the tokens."""
+    contract.streams_equal_the_step_reference_while_lanes_join_and_leave(
+        FAM, engines)
 
 
 # -- (vi) what a ring forbids is refused, and says why --------------------------
 def test_refusals():
-    c = _config()
-    cfg = spec.family(c).program_config(c)
-    params = init_params(jax.random.key(0), cfg)
-    with pytest.raises(ValueError, match="rows of the window rings"):
-        PagedLLMEngine(cfg, params, num_slots=2, max_len=64, block_size=8,
-                       prefill_chunk=16, speculation_k=4)
+    cfg, params, e = contract.refusals(FAM)
+    assert e._reset_state is None             # and nothing to zero
     with pytest.raises(ValueError, match="ring of window KV by slot"):
         PagedLLMEngine(cfg, params, num_slots=2, max_len=64, block_size=8,
                        prefill_chunk=16, mesh=object())
-    e = PagedLLMEngine(cfg, params, num_slots=2, max_len=64, block_size=8,
-                       prefill_chunk=16, prefix_sharing=True)
-    try:
-        assert not e.allocator.prefix_sharing     # off by itself
-        assert e._reset_state is None             # and nothing to zero
-        prompt = list(range(1, 30))
-        e.generate(prompt, max_tokens=2)
-        e.generate(prompt, max_tokens=2)
-        assert e.stats["prefix_hits"] == 0
-        with pytest.raises(ValueError, match="export_streams.*ring of"):
-            e.export_streams()
-        with pytest.raises(ValueError, match="import_prefix.*ring of"):
-            e.import_prefix(prompt, np.zeros((2, 2, 4, 8, 2, 16)), 8)
-    finally:
-        e.shutdown()
     dense = configs.get("tiny")
     twin = PagedLLMEngine(dense, init_params(jax.random.key(0), dense),
                           num_slots=2, max_len=64, block_size=8,
@@ -370,26 +184,17 @@ def test_refusals():
             twin.score(np.ones((1, 9), np.int64), 8, routing=True)
     finally:
         twin.shutdown()
-    for kw in ({"disagg": True}, {"tensor_parallel": 2}):
-        with pytest.raises(ValueError, match="ring of window KV by slot"):
-            LLMDeployment(cfg, num_slots=2, max_len=64, **kw)
     with pytest.raises(ValueError, match="slots"):
         decoding._paged_forward(params, e.cache, None, None, None, None, cfg)
 
 
 def test_deployment_takes_the_configuration_by_name():
-    dep = LLMDeployment("tiny-window-moe", num_slots=2, max_len=64,
-                        block_size=8, prefill_chunk=16)
-    try:
+    with contract.deployed(FAM) as dep:
         assert dep._disagg is None
-        out = dep({"tokens": list(range(1, 20)), "max_tokens": 3})
-        assert len(out["tokens"]) == 3
         with pytest.raises(ValueError, match="import_prefix"):
             dep.adopt_kv(list(range(8)), np.zeros((2, 2, 1, 8, 2, 16)), 8)
         state = dep.stats()["state"]
         assert state["kv_window"] > 0 and state["recurrent"] == 0
-    finally:
-        dep.engine.shutdown()
 
 
 # -- (vii) the routing is handed over, and held to a slack -----------------------
@@ -399,24 +204,24 @@ def test_the_routing_score_returns_is_the_reference_s_own(served):
     top-4 wherever the reference's margin is clear of rounding, and
     handing them over leaves the reference's logits as they were."""
     e, c = served
-    fam = spec.family(c)
-    seqs = _seqs(2, 70 + 5, seed=31)
-    got, taken = e.score(seqs, 70, routing=True)
-    plain = e.score(seqs, 70)
+    fam = FAM.reference(c)
+    rows = seqs(2, 70 + 5, seed=31)
+    got, taken = e.score(rows, 70, routing=True)
+    plain = e.score(rows, 70)
     for lane in range(2):
         assert taken[lane].shape == (75, 8, 4)
         assert taken[lane].dtype == np.int32
         np.testing.assert_array_equal(np.stack(got[lane]),
                                       np.stack(plain[lane]))
-        own, margin = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
-                                  jit=jax.jit, routing=None)
-        handed, decided = fam.forward(e.params, jnp.asarray(seqs[lane]), c,
-                                      jit=jax.jit, routing=taken[lane])
+        own, margin = fam.forward(e.params, jnp.asarray(rows[lane]), c,
+                                  jit=contract.jit, routing=None)
+        handed, decided = fam.forward(e.params, jnp.asarray(rows[lane]), c,
+                                      jit=contract.jit, routing=taken[lane])
         assert float(margin.min()) < reference.ROUTER_MARGIN   # the trap
         assert float(decided.min()) >= 1.0 - 1e-4
         np.testing.assert_allclose(np.asarray(handed), np.asarray(own),
                                    atol=2e-5)
-        x = e.params["embed"][jnp.asarray(seqs[lane])]
+        x = e.params["embed"][jnp.asarray(rows[lane])]
         first = fam._rms_norm(
             x + fam.attention(fam._rms_norm(
                 x, e.params["blocks"]["attn_norm"][0], 1e-6),
@@ -431,26 +236,26 @@ def test_the_routing_score_returns_is_the_reference_s_own(served):
 
 def test_forced_routing_outside_the_slack_fails(served):
     e, c = served
-    fam = spec.family(c)
-    seq = _seqs(1, 40, seed=32)[0]
+    fam = FAM.reference(c)
+    seq = seqs(1, 40, seed=32)[0]
     _, taken = e.score(seq[None], 36, routing=True)
-    _, own = fam.forward(e.params, jnp.asarray(seq), c, jit=jax.jit,
+    _, own = fam.forward(e.params, jnp.asarray(seq), c, jit=contract.jit,
                          routing=taken[0])
     assert bool(jnp.all(jnp.isfinite(own)))
     forced = taken[0].copy()
     # position 20, layer 3: the expert the reference likes least
     worst = [x for x in range(8) if x not in set(forced[20, 3])]
     forced[20, 3, 0] = worst[-1]
-    probe, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=jax.jit,
+    probe, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=contract.jit,
                            routing=forced)
     bad = ~np.isfinite(np.asarray(probe)).all(axis=-1)
     assert bad.sum() <= 4 and bad[20]     # the gap there is over the slack
     twice = taken[0].copy()
     twice[7, 5, 1] = twice[7, 5, 0]                   # one expert twice
-    again, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=jax.jit,
+    again, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=contract.jit,
                            routing=twice)
     assert not np.isfinite(np.asarray(again[7])).any()
-    short, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=jax.jit,
+    short, _ = fam.forward(e.params, jnp.asarray(seq), c, jit=contract.jit,
                            routing=taken[0][:, :, :3])
     assert not np.isfinite(np.asarray(short)).any()
 
@@ -489,104 +294,25 @@ def _one_expert_dropped(monkeypatch, cfg):
     return cfg
 
 
-def _cache_in_8_bits(monkeypatch, cfg):
-    return cfg              # put into the engine once it is built
-
-
-def _round_the_cache(e):
-    def rounded(a):
-        return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
-
-    e.score(np.ones((1, 9), np.int64), 8, routing=True)   # builds them
-    for name in ("_score_chunk", "_score_step"):
-        inner = getattr(e, name)
-
-        def program(*a, _inner=inner, **kw):
-            cache, *rest = _inner(*a, **kw)
-            return (jax.tree.map(rounded, cache), *rest)
-
-        setattr(e, name, program)
+@on_the_engine
+def _cache_in_8_bits(e, ref, monkeypatch):
+    contract.score_keeps(e, monkeypatch, lambda cache: jax.tree.map(
+        contract.as_float8, cache))
 
 
 @pytest.mark.parametrize("fault", [
     None, _window_mask_dropped, _yarn_left_off, _qk_norm_left_out,
     _one_expert_dropped, _top_k_one_short, _cache_in_8_bits],
     ids=lambda f: f.__name__.strip("_") if f else "as_it_is")
-def test_logits_check_has_teeth(fault, monkeypatch):
-    """`deployment.logits_check` (3 lanes x (the last of 100 prompt
-    positions + 8 decode steps), bfloat16 parameters, compute and cache as
-    the benchmark's configuration has them, the routing handed over, held
-    to the family's own slack and to an error bound between this size's
-    two readings) passes the program as it is with every position
-    decided, and fails each fault.  The family's LOGITS_REL_EXPERTS was
-    measured at the published widths; at a width of 48 bfloat16 rounds
-    coarser (as it is: 0.020 at most, 8-bit cache: 0.09-0.12), so the
-    bound here is 0.04."""
-    from bench.harness.deployment import logits_check
-
-    c = _config(param_dtype="bfloat16", compute_dtype="bfloat16",
-                cache_dtype="bfloat16")
-    fam = spec.family(c)
-    monkeypatch.setitem(fam.TOLERANCES, "LOGITS_REL_EXPERTS", 0.04)
-    cfg = fam.program_config(c)
-    e = _engine(c, fault(monkeypatch, cfg) if fault else cfg)
-    try:
-        if fault is _cache_in_8_bits:
-            _round_the_cache(e)
-        v = logits_check(e, c, SEED)
-    finally:
-        e.shutdown()
-    assert v["positions"] == 27
-    assert v["bound"] == fam.TOLERANCES["LOGITS_REL_EXPERTS"]
-    if fault is None:
-        assert v["ok"] and v["decided"] == 27, v
-        assert v["worst"] < 0.6 * v["bound"], v
-    else:
-        assert not v["ok"], v
-        assert not v["finite"] or v["worst_decided"] > v["bound"], v
+def test_logits_check_has_teeth(engines, fault, monkeypatch):
+    contract.logits_check_has_teeth(FAM, engines, fault, monkeypatch)
 
 
 # -- the served path: serve.run -> proxy -> handle -> replica -> engine ---------
 def test_served_through_the_front_like_any_model():
-    import urllib.request
-
-    import ray_tpu
-    from ray_tpu import serve
-
-    cfg = configs.get("tiny-window-moe")
-    prompt = list(range(3, 40))
-    twin = PagedLLMEngine(cfg, init_params(jax.random.key(0), cfg),
-                          num_slots=2, max_len=128, block_size=8,
-                          prefill_chunk=16)
-    try:
-        want = twin.generate(prompt, max_tokens=10)
-    finally:
-        twin.shutdown()
-    ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
-    try:
-        serve.run(serve.deployment(LLMDeployment).bind(
-            "tiny-window-moe", num_slots=2, max_len=128, block_size=8,
-            prefill_chunk=16), name="windowmoe", _http=True,
-            route_prefix="/windowmoe")
-        handle = serve.get_app_handle("windowmoe")
-        streamed = [it["token"] for it in handle.options(
-            method_name="stream").remote_streaming(
-                {"tokens": prompt, "max_tokens": 10})]
-        assert streamed == want
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{serve.http_port()}/windowmoe",
-            data=json.dumps({"tokens": prompt, "max_tokens": 10}).encode(),
-            headers={"Content-Type": "application/json"})
-        with urllib.request.urlopen(req, timeout=120) as resp:
-            assert json.loads(resp.read())["tokens"] == want
-        stats = handle.options(method_name="stats").remote({}).result(
-            timeout=60)
-        assert stats["state"]["kv_window"] > 0
-        assert stats["state"]["state_resets"] == 0
-        assert stats["prefix_hits"] == 0
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
+    stats = contract.served_through_the_front_like_any_model(FAM)
+    assert stats["state"]["kv_window"] > 0
+    assert stats["state"]["state_resets"] == 0
 
 
 # -- a model without a pattern lowers to the program it lowered to ---------------
@@ -609,25 +335,6 @@ _LOWERED_AT_PR_33 = {
 @pytest.mark.parametrize("name,program", list(_LOWERED_AT_PR_33),
                          ids=lambda v: str(v))
 def test_old_configurations_lower_as_before(name, program):
-    import hashlib
-
-    cfg = configs.get(name)
-    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    cache = jax.eval_shape(lambda: decoding.init_paged_cache(cfg, 17, 8))
-    chunk, burst, _ = decoding.make_paged_engine_fns(cfg)
-
-    def arr(*shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    if program == "chunk":
-        lowered = chunk.lower(params, cache, arr(32), arr(8), arr(), arr())
-    elif program == "burst":
-        lowered = burst.lower(
-            params, cache, arr(4), arr(4, 8), arr(4),
-            arr(4, dtype=jnp.bool_), arr(4, dtype=jnp.float32),
-            jax.eval_shape(lambda: jax.random.key(0)), n_steps=4)
-    else:
-        lowered = jax.jit(lambda p, t: forward(p, t, cfg)).lower(
-            params, arr(2, 64))
-    digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
-    assert digest == _LOWERED_AT_PR_33[(name, program)]
+    assert contract.lowered_digest(
+        name, program, state=False, **contract.SMALL_SHAPES) \
+        == _LOWERED_AT_PR_33[(name, program)]
