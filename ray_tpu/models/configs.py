@@ -274,6 +274,38 @@ SDAR_30B_A3B = dataclasses.replace(
     rope_theta=1000000.0, max_seq_len=32768, param_dtype=jnp.bfloat16,
     compute_dtype=jnp.bfloat16, mask_token_id=151669)
 
+# A third kind of layer in the homogeneous stack, at test size: two
+# periods of three linear layers (Gated DeltaNet: 2 key heads serving 4
+# value heads of 8, a convolution of 4 rows, chunks of 8 positions) to one
+# full layer whose output is gated element by element and whose rope turns
+# a quarter of a head; every norm's gain 1 + w; 8 experts top-3 of which
+# this rank holds the upper four, beside a shared expert under a gate of
+# its own.  float32, so that a test holds it to the reference's logits.
+TINY_GATED_DELTA_MOE = TransformerConfig(
+    name="tiny-gated-delta-moe", vocab_size=512, d_model=48, n_layers=8,
+    n_heads=4, n_kv_heads=2, d_head=16, d_ff=160, d_expert=24, d_shared=24,
+    n_experts=8, expert_top_k=3, experts_held=(4, 4), qk_norm=True,
+    attn_gate=16, rotary_dim=4, shared_gate=True, norm_plus_one=True,
+    layer_pattern=("linear", "linear", "linear", "full"), linear_k_heads=2,
+    linear_v_heads=4, linear_d_k=8, linear_d_v=8, linear_conv=4,
+    linear_chunk=8, rope_theta=10000.0, norm_eps=1e-6, max_seq_len=512,
+    remat=False, param_dtype=jnp.float32, compute_dtype=jnp.float32,
+)
+
+# Qwen3-Next-80B-A3B-Instruct's published sizes (79.7 B parameters, ~3 B
+# active a token), every expert held: twelve periods of three linear
+# layers to one full layer, 512 experts of width 512, top-10; `d_ff` (the
+# config's intermediate_size) is used by no layer; its multi-token-
+# prediction module is not part of the stack.
+QWEN3_NEXT_80B_A3B = dataclasses.replace(
+    TINY_GATED_DELTA_MOE, name="qwen3-next-80b-a3b", vocab_size=151936,
+    d_model=2048, n_layers=48, n_heads=16, n_kv_heads=2, d_head=256,
+    d_ff=5120, d_expert=512, d_shared=512, n_experts=512, expert_top_k=10,
+    experts_held=None, attn_gate=256, rotary_dim=64, linear_k_heads=16,
+    linear_v_heads=32, linear_d_k=128, linear_d_v=128, linear_chunk=64,
+    rope_theta=10000000.0, max_seq_len=262144, param_dtype=jnp.bfloat16,
+    compute_dtype=jnp.bfloat16)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
@@ -285,7 +317,8 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_MHC_MLA_MOE, XING4_0_29B_A4B,
                                 TINY_GROUP_MOE, DEEPSEEK_V3_2_EXP,
                                 TINY_GATED_MOE, LAGUNA_XS_2,
-                                TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B]}
+                                TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B,
+                                TINY_GATED_DELTA_MOE, QWEN3_NEXT_80B_A3B]}
 
 
 def get(name: str):

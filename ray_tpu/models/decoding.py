@@ -28,6 +28,14 @@ engine's slot instead of pool blocks (`ops.attention`, above
 `ring_rows`): the pool then holds the full layers alone, and a served
 call also takes the lanes' `slots`.
 
+A model with linear layers (`layer_pattern` names "linear":
+`ops.gated_delta`) keeps for those no KV at all but, by the engine's slot,
+a float32 state a head and the last rows of a short convolution
+(`PagedKVCache.lstate` / `.lconv`): recurrent state, which the engine
+zeroes when a slot changes hands (`TransformerConfig.reset_slot`), which a
+launch of m x `linear_chunk` rows carries from chunk to chunk inside the
+program, and which a row that is not valid leaves as it was.
+
 A model that generates by diffusion over blocks
 (`TransformerConfig.diffusion_block` = B) runs the same body under
 another mask: a row sees every position up to the end of its own block of
@@ -52,7 +60,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import TransformerConfig, qk_normed
+from ray_tpu.models.transformer import (
+    TransformerConfig, gain_of, qk_normed)
 from ray_tpu.ops.attention import (
     paged_attention, ring_rows, slot_ring_reader, window_attention)
 from ray_tpu.ops.norms import rms_norm
@@ -64,7 +73,7 @@ _NEG_INF = -1e30
 def _qkv(bp, x, cfg, positions, kind="full"):
     """A layer of `kind`'s roped queries, keys and values of x (S, K, d)."""
     cd = cfg.compute_dtype
-    h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
+    h = rms_norm(x, gain_of(bp["attn_norm"], cfg), eps=cfg.norm_eps)
     b, t = x.shape[:2]
     q = jnp.einsum("btd,dh->bth", h, bp["wq"].astype(cd)).reshape(
         b, t, cfg.heads(kind), cfg.head_dim)
@@ -79,15 +88,19 @@ def _qkv(bp, x, cfg, positions, kind="full"):
 
 
 def _head_gate(bp, x, cfg):
-    """The gate on a layer's attention output, (S, K, H, 1) float32: one
-    value a query head, the sigmoid of the layer's normed input (the norm
-    `_qkv` takes too: one computation once compiled) through `head_gate`."""
+    """The gate on a layer's attention output, (S, K, H, `cfg.attn_gate`)
+    float32: one value a query head or one an element, the sigmoid of the
+    layer's normed input (the norm `_qkv` takes too: one computation once
+    compiled) through `head_gate`."""
     cd = cfg.compute_dtype
-    h = rms_norm(x, bp["attn_norm"], eps=cfg.norm_eps)
+    h = rms_norm(x, gain_of(bp["attn_norm"], cfg), eps=cfg.norm_eps)
     with jax.named_scope("attn_gate"):
-        return jax.nn.sigmoid(jnp.einsum(
+        gate = jax.nn.sigmoid(jnp.einsum(
             "btd,dh->bth", h, bp["head_gate"].astype(cd)
-        ).astype(jnp.float32))[..., None]
+        ).astype(jnp.float32))
+        if cfg.attn_gate == 1:
+            return gate[..., None]
+        return gate.reshape(*gate.shape[:2], -1, cfg.attn_gate)
 
 
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -101,6 +114,67 @@ def _layer_xs(blocks, cfg):
         return blocks, None
     return ({k: v for k, v in blocks.items() if k not in _EXPERT_WEIGHTS},
             {k: blocks[k] for k in _EXPERT_WEIGHTS})
+
+
+def _gated_norm(o, z, gain, eps):
+    """A linear layer's output norm: o (S, K, Hv, d_v) float32 normalised
+    over a head under a plain gain, then times silu(z)."""
+    return rms_norm(o, gain, eps=eps) * jax.nn.silu(z.astype(jnp.float32))
+
+
+def _linear_mixer(bp, x, conv_rows, state, valid, cfg):
+    """A linear layer's mixer (Gated DeltaNet, `ops.gated_delta`) over
+    x (S, K, d), from the lanes' conv rows (S, J - 1, c) and state
+    (S, Hv, d_k, d_v).  K = 1 is the rule's step, K > 1 its chunk form over
+    chunks of `cfg.linear_chunk` with the state handed on inside.  A row
+    that is not `valid` (S, K; the valid ones are a prefix) takes beta = 0
+    and g = 0, so the state passes it, and the conv rows kept are the
+    last valid ones'.  Returns (out (S, K, d), conv rows, state
+    float32)."""
+    from ray_tpu.ops.gated_delta import (
+        causal_conv, gated_delta_chunks, gated_delta_step)
+
+    cd, f32 = cfg.compute_dtype, jnp.float32
+    hk, hv = cfg.linear_k_heads, cfg.linear_v_heads
+    dk, dv, n_conv = cfg.linear_d_k, cfg.linear_d_v, cfg.linear_conv_dim
+    s_w, k_w = x.shape[:2]
+    u = rms_norm(x, gain_of(bp["attn_norm"], cfg), eps=cfg.norm_eps)
+    qkvz = jnp.einsum("skd,de->ske", u, bp["in_qkvz"].astype(cd))
+    ba = jnp.einsum("skd,de->ske", u, bp["in_ba"].astype(cd)).astype(f32)
+    with jax.named_scope("gdn_conv"):
+        conv, conv_rows = causal_conv(
+            conv_rows, qkvz[..., :n_conv], bp["conv_w"],
+            jnp.sum(valid, axis=1).astype(jnp.int32))
+        qkv = jax.nn.silu(conv)                            # float32
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        q = unit(qkv[..., :hk * dk].reshape(s_w, k_w, hk, dk)) * dk ** -0.5
+        k = unit(qkv[..., hk * dk:2 * hk * dk].reshape(s_w, k_w, hk, dk))
+        v = qkv[..., 2 * hk * dk:].reshape(s_w, k_w, hv, dv)
+        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        live = valid[..., None]
+        beta = jnp.where(live, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+        g = jnp.where(live, -jnp.exp(bp["A_log"].astype(f32))
+                      * jax.nn.softplus(ba[..., hv:]
+                                        + bp["dt_bias"].astype(f32)), 0.0)
+    if k_w == 1:
+        with jax.named_scope("gdn_step"):
+            o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                        beta[:, 0], state)
+            o = o[:, None]
+    else:
+        with jax.named_scope("gdn_chunk"):
+            o, state = gated_delta_chunks(q, k, v, g, beta, state,
+                                          chunk=cfg.linear_chunk, cd=cd)
+    with jax.named_scope("gdn_gate_norm"):
+        y = _gated_norm(o, qkvz[..., n_conv:].reshape(s_w, k_w, hv, dv),
+                        bp["gate_norm"], cfg.norm_eps)
+    out = jnp.einsum("ske,ed->skd", y.reshape(s_w, k_w, hv * dv).astype(cd),
+                     bp["out_proj"].astype(cd))
+    return out, conv_rows, state
 
 
 def _swiglu(bp, h, cd, prefix="w_"):
@@ -121,7 +195,7 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
     added once; without (a model that has none, or a leading layer of
     one that has), the dense FFN, 0 visited."""
     cd = cfg.compute_dtype
-    h = rms_norm(x, bp["mlp_norm"], eps=cfg.norm_eps)
+    h = rms_norm(x, gain_of(bp["mlp_norm"], cfg), eps=cfg.norm_eps)
     if experts is not None:
         # Dropless exact routing: decode must compute the same function
         # regardless of batch size (capacity routing is train-only) —
@@ -135,7 +209,12 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
                 layer=li, return_routing=routing, return_routed=counted)
         if cfg.d_shared:
             with jax.named_scope("shared_mlp"):
-                out = out + _swiglu(bp, h, cd, "shared_")
+                shared = _swiglu(bp, h, cd, "shared_")
+                if cfg.shared_gate:
+                    shared = (shared * jax.nn.sigmoid(jnp.einsum(
+                        "btd,do->bto", h, bp["shared_scale"].astype(cd)
+                    ).astype(jnp.float32))).astype(cd)
+                out = out + shared
         return (out, visited, more[0] if routing else None,
                 more[-1] if counted else None)
     if routing and cfg.n_experts <= 0:
@@ -145,7 +224,7 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
 
 def _final_logits(params, x, cfg):
     cd = cfg.compute_dtype
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    x = rms_norm(x, gain_of(params["final_norm"], cfg), eps=cfg.norm_eps)
     if cfg.tie_embeddings:
         return jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
     return jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(cd))
@@ -240,6 +319,10 @@ class PagedKVCache:
     # model whose every layer is full, whose state is the pool alone.
     wk: Optional[jax.Array] = None    # (L_window, S + 1, R, Hkv, D)
     wv: Optional[jax.Array] = None
+    # The linear layers' recurrent state, by slot (the null slot last):
+    # the last conv inputs and the state a head; None without such layers.
+    lconv: Optional[jax.Array] = None   # (L_linear, S + 1, J - 1, c)
+    lstate: Optional[jax.Array] = None  # (L_linear, S + 1, Hv, d_k, d_v)
 
     def resident_bytes(self) -> dict:
         """Bytes a replica keeps for its sequences, by kind of state."""
@@ -248,10 +331,12 @@ class PagedKVCache:
                            if a is not None))
 
         return {"kv_paged": nbytes(self.k, self.v),
-                "kv_window": nbytes(self.wk, self.wv), "recurrent": 0}
+                "kv_window": nbytes(self.wk, self.wv),
+                "recurrent": nbytes(self.lconv, self.lstate)}
 
 
-jax.tree_util.register_dataclass(PagedKVCache, ["k", "v", "wk", "wv"], [])
+jax.tree_util.register_dataclass(
+    PagedKVCache, ["k", "v", "wk", "wv", "lconv", "lstate"], [])
 
 
 def paged_cache_shardings(mesh) -> PagedKVCache:
@@ -277,20 +362,35 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     with window layers also gets their rings, `num_slots` + 1 of
     window + `prefill_chunk` rows each (a row is a position's (Hkv, D),
     as in the pool: D is a whole lane tile or the layout is the
-    compiler's and copied every step, `ops.attention` says)."""
+    compiler's and copied every step, `ops.attention` says).  A model
+    with linear layers gets their conv rows (the cache dtype) and state
+    (`cfg.linear_state_dtype`), zero, `num_slots` + 1 of each."""
     dtype = dtype or cfg.compute_dtype
     row = (cfg.n_kv_heads, cfg.head_dim)
     shape = (cfg.n_of("full"), num_blocks, block_size, *row)
     k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
     rings = {}
-    if cfg.state_by_slot:
-        if not (num_slots and prefill_chunk):
-            raise ValueError(f"{cfg.name!r} keeps a ring a slot for its "
-                             f"window layers: num_slots and prefill_chunk "
-                             f"size them")
+    if cfg.state_by_slot and not (num_slots and prefill_chunk):
+        raise ValueError(f"{cfg.name!r} keeps state by slot (a ring for "
+                         f"its window layers, a state for its linear "
+                         f"ones): num_slots and prefill_chunk size it")
+    if cfg.window:
         ring = (cfg.n_of("window"), num_slots + 1,
                 cfg.window + prefill_chunk, *row)
         rings = {"wk": jnp.zeros(ring, dtype), "wv": jnp.zeros(ring, dtype)}
+    if cfg.recurrent:
+        if prefill_chunk % cfg.linear_chunk:
+            raise ValueError(
+                f"{cfg.name!r} carries its linear layers' state over "
+                f"chunks of {cfg.linear_chunk} positions: a launch of "
+                f"prefill_chunk {prefill_chunk} rows is not whole chunks")
+        per_slot = (cfg.n_of("linear"), num_slots + 1)
+        rings = {
+            "lconv": jnp.zeros((*per_slot, cfg.linear_conv - 1,
+                                cfg.linear_conv_dim), dtype),
+            "lstate": jnp.zeros((*per_slot, cfg.linear_v_heads,
+                                 cfg.linear_d_k, cfg.linear_d_v),
+                                cfg.linear_state_dtype)}
     return PagedKVCache(k=jnp.zeros(shape, dtype, device=k_sh),
                         v=jnp.zeros(shape, dtype, device=v_sh), **rings)
 
@@ -425,7 +525,7 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     through the same body (`one`).
     """
     if cfg.state_by_slot and slots is None:
-        raise ValueError(f"{cfg.name!r} keeps rings by slot: a served call "
+        raise ValueError(f"{cfg.name!r} keeps state by slot: a served call "
                          f"needs the lanes' slots")
     cd = cfg.compute_dtype
     bs = cache.k.shape[2]
@@ -441,11 +541,12 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     # Whom an expert layer routes: a model that counts its choices, the
     # real rows (a chunk's padded tail is none); else the live lanes.
     routed_rows = positions < kv_len[:, None] if counted else live_lane
+    valid_rows = positions < kv_len[:, None] if cfg.recurrent else None
     sees = None
     if cfg.diffusion_block:
         sees = positions // cfg.diffusion_block * cfg.diffusion_block \
             + cfg.diffusion_block - 1
-    if cfg.state_by_slot:
+    if cfg.window:
         ring_row = ring_rows(positions, kv_len, cache.wk.shape[2])
         lane = slots[:, None]
         read_ring = slot_ring_reader(window_attention, slots, positions,
@@ -455,7 +556,15 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         """One layer of `kind` with the weights `bp`, the `at`-th of its
         kind (its layer of the pool or of the rings), the `li`-th of the
         expert stacks (None: a leading layer)."""
-        x, k_pool, v_pool, wk, wv, visited, routed = carry
+        x, k_pool, v_pool, wk, wv, visited, routed, lin = carry
+        if kind == "linear":
+            lconv, lstate = lin
+            out, rows, state = _linear_mixer(
+                bp, x, lconv[at, slots], lstate[at, slots], valid_rows, cfg)
+            lin = (lconv.at[at, slots].set(rows),
+                   lstate.at[at, slots].set(state.astype(lstate.dtype)))
+            return ffn((x + out, k_pool, v_pool, wk, wv, visited, routed,
+                        lin), bp, li)
         q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)
         if kind == "full":
             with jax.named_scope("full_attn"):
@@ -478,14 +587,20 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         attn = attn.reshape(*tokens.shape, cfg.heads(kind) * cfg.head_dim)
         x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
                            bp["wo"].astype(cd))
+        return ffn((x, k_pool, v_pool, wk, wv, visited, routed, lin), bp,
+                   li)
+
+    def ffn(carry, bp, li):
+        """The layer's FFN on the carry's x, behind either mixer."""
+        x, *state, visited, routed, lin = carry
         if li is None:
             with jax.named_scope("dense_mlp"):
                 out, n, idx, r = _mlp(bp, x, cfg)
         else:
             out, n, idx, r = _mlp(bp, x, cfg, experts, li, routed_rows,
                                   routing)
-        return (x + out, k_pool, v_pool, wk, wv, visited + n,
-                routed if r is None else routed + r), idx
+        return (x + out, *state, visited + n,
+                routed if r is None else routed + r, lin), idx
 
     def behind(i, j, kind, bps=None):
         """Layer `j` of period `i` behind the leading layers (a traced
@@ -498,7 +613,7 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         # it can be taken (AOT for a v5e, PR 34).
         bp = bps if bps is not None else _take(blocks, li)
         at = _nth(i, per[kind], period[:j].count(kind))
-        if cfg.heads_by_kind:
+        if "kinds" in params:
             bp = {**bp, **_take(params["kinds"][kind], at)}
         if kind in lead:                 # the leading layers' come first
             at = at + lead.count(kind)
@@ -514,7 +629,8 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
 
     blocks, experts = _layer_xs(params["blocks"], cfg)
     carry = (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0),
-             _routed_zero(tokens.size, cfg) if counted else None)
+             _routed_zero(tokens.size, cfg) if counted else None,
+             (cache.lconv, cache.lstate) if cfg.recurrent else None)
     for j, kind in enumerate(lead):
         carry, _ = one(carry, params["lead"][j], kind, lead[:j].count(kind))
     carry, taken = jax.lax.scan(
@@ -526,9 +642,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         carry, idx = one(carry, *behind(cfg.n_periods, j, kind))
         if routing:
             taken = jnp.concatenate([taken, idx[None]])
-    x, k_pool, v_pool, wk, wv, visited, routed = carry
-    return (PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv), x, visited,
-            taken, routed)
+    x, k_pool, v_pool, wk, wv, visited, routed, lin = carry
+    lconv, lstate = lin or (None, None)
+    return (PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv, lconv=lconv,
+                         lstate=lstate), x, visited, taken, routed)
 
 
 def paged_decode_step(params, cache: PagedKVCache, tokens: jax.Array,

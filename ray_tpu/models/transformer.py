@@ -23,6 +23,12 @@ reference: python/ray/train/torch/train_loop_utils.py:158):
   scan over periods whose body is the period's layers, so compile time
   stays O(1) in depth.  Period 1 (every model without a pattern) is the
   scan over layers it always was.
+- **A third kind keeps no KV at all**: a `linear` layer is a Gated
+  DeltaNet mixer (`ops.gated_delta`): a matrix of state a head by the
+  engine's slot, corrected and written a position, beside the rows of
+  a short convolution.  It has no head, rope or `wq` of an attention
+  layer, so in such a model everything a kind's mixer owns is stacked by
+  kind and `blocks` keeps what every layer shares.  Served only.
 - **What differs by layer lives outside the layers' stacks**: leading
   layers whose FFN is dense (`lead_pattern`) are blocks of their own
   before the scan; where the kinds differ in query heads
@@ -35,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import warnings
 from typing import Any, Optional, Tuple
 
@@ -106,9 +113,10 @@ class TransformerConfig:
     # How many of a head's dimensions a full layer's rope turns, the
     # first that many (`ops.rotary.apply_rope`); 0: all.
     rotary_dim: int = 0
-    # A gate on the attention's output, one value a query head:
-    # sigmoid(the layer's normed input x head_gate), arXiv:2505.06708.
-    attn_gate: bool = False
+    # A gate on the attention's output, its width a query head: 1 (or
+    # True) one value a head, `head_dim` one an element; sigmoid(the
+    # layer's normed input x head_gate), arXiv:2505.06708.  0: none.
+    attn_gate: int = 0
     # The kinds of the leading layers, before the periods, whose FFN is
     # dense at width d_ff whatever n_experts says.  They count in
     # n_layers; what follows them is whole periods and then, behind
@@ -135,15 +143,36 @@ class TransformerConfig:
     diffusion_block: int = 0
     denoise_steps: int = 0
     mask_token_id: int = 0
+    # A "linear" layer (`ops.gated_delta`), served only: its key and
+    # value heads (a key head serves linear_v_heads // linear_k_heads
+    # value heads), their sizes, the width of the causal convolution over
+    # [q | k | v], the positions of one chunk of the rule's matrix form,
+    # and the dtype a slot's state is kept in.
+    linear_k_heads: int = 0
+    linear_v_heads: int = 0
+    linear_d_k: int = 0
+    linear_d_v: int = 0
+    linear_conv: int = 4
+    linear_chunk: int = 64
+    linear_state_dtype: Any = jnp.float32
+    # Every RMSNorm of the stack but a linear layer's gated one multiplies
+    # by 1 + w (a gain stored about 0), served only.
+    norm_plus_one: bool = False
+    # The shared expert's output times sigmoid(the FFN's normed input x
+    # `shared_scale`), one value a token; served only.
+    shared_gate: bool = False
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)
         lead = tuple(self.lead_pattern)
         object.__setattr__(self, "layer_pattern", pattern)
         object.__setattr__(self, "lead_pattern", lead)
-        if set(pattern + lead) - {"full", "window"}:
+        object.__setattr__(self, "attn_gate", int(self.attn_gate))
+        if set(pattern) - {"full", "window", "linear"} \
+                or set(lead) - {"full", "window"}:
             raise ValueError(f"layer_pattern {pattern}, lead_pattern {lead}: "
-                             f"a layer is 'full' or 'window'")
+                             f"a layer is 'full', 'window' or, behind the "
+                             f"leading ones, 'linear'")
         if pattern and not lead and self.n_layers % len(pattern):
             raise ValueError(f"n_layers {self.n_layers} is not whole "
                              f"periods of {pattern}")
@@ -156,6 +185,29 @@ class TransformerConfig:
         if self.n_experts <= 0 and (self.d_shared or self.experts_held):
             raise ValueError("a shared expert and a held share stand "
                              "beside routed experts: n_experts is 0")
+        if self.shared_gate and not self.d_shared:
+            raise ValueError("shared_gate gates a shared expert: d_shared "
+                             "is 0")
+        if self.attn_gate not in (0, 1, self.head_dim):
+            raise ValueError(f"attn_gate {self.attn_gate}: a gate is one "
+                             f"value a head (1) or one an element "
+                             f"(head_dim = {self.head_dim})")
+        if "linear" in pattern:
+            hk, hv = self.linear_k_heads, self.linear_v_heads
+            if not (hk > 0 and hv % hk == 0 and hv > 0 and self.linear_d_k
+                    > 0 and self.linear_d_v > 0 and self.linear_conv >= 2
+                    and self.linear_chunk > 0):
+                raise ValueError(
+                    f"a linear layer has linear_k_heads ({hk}) key heads "
+                    f"that divide its linear_v_heads ({hv}) value heads, "
+                    f"their sizes linear_d_k / linear_d_v, a convolution "
+                    f"of 2 rows or more and a chunk")
+            if "window" in pattern + lead or self.diffusion_block:
+                raise ValueError(
+                    "a linear layer's state is carried from chunk to chunk "
+                    "of a launch and its rows are seen once: a window "
+                    "layer's ring is laid out by a launch's rows and a "
+                    "block's passes run its rows again")
         block = self.diffusion_block
         if block:
             if not (block >= 2 and 1 <= self.denoise_steps <= block
@@ -205,7 +257,7 @@ class TransformerConfig:
         return self.kinds.count(kind)
 
     def heads(self, kind: str) -> int:
-        """Query heads of a layer of `kind`."""
+        """Query heads of an attention layer of `kind`."""
         return (self.n_heads_window if kind == "window" else 0) \
             or self.n_heads
 
@@ -214,6 +266,19 @@ class TransformerConfig:
         """Whether the kinds differ in query heads, so that what has a
         kind's width is stacked by kind (`init_params`)."""
         return self.heads("window") != self.n_heads
+
+    @property
+    def mixers_by_kind(self) -> bool:
+        """Whether a kind's whole mixer is stacked by kind, `blocks`
+        keeping the norms and the FFN alone: a model with linear layers,
+        which share no weight of an attention layer's."""
+        return self.recurrent
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of a linear layer's convolution: q, k and v."""
+        return 2 * self.linear_k_heads * self.linear_d_k \
+            + self.linear_v_heads * self.linear_d_v
 
     @property
     def n_experts_held(self) -> int:
@@ -229,8 +294,31 @@ class TransformerConfig:
     @property
     def state_by_slot(self) -> bool:
         """Whether a served sequence keeps more than pool blocks: a ring a
-        slot for each window layer (`models.decoding`)."""
-        return "window" in self.period
+        slot for each window layer, a state and conv rows a slot for each
+        linear layer (`models.decoding`)."""
+        return "window" in self.period or self.recurrent
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some of that is recurrent state, which the engine
+        zeroes when a slot changes hands (`reset_slot`)."""
+        return "linear" in self.period
+
+    @property
+    def launch_spans_chunks(self) -> bool:
+        """Whether a launch's rows lay none of the state by slot out: a
+        launch of m x `linear_chunk` rows is m chunks of the rule, the
+        state handed on inside the program (the engine then builds launch
+        tiers above `prefill_chunk`)."""
+        return self.recurrent
+
+    @staticmethod
+    def reset_slot(cache, slot):
+        """Zero one slot's recurrent state (a request is admitted to it,
+        or a preempted stream will re-prefill)."""
+        return dataclasses.replace(
+            cache, lconv=cache.lconv.at[:, slot].set(0),
+            lstate=cache.lstate.at[:, slot].set(0))
 
     def kv_read_tokens(self, lengths) -> int:
         """KV positions one decode step sees over lanes of `lengths`:
@@ -273,13 +361,19 @@ class TransformerConfig:
         experts = self.n_experts_held * 3 * d * self.expert_width \
             + d * self.n_experts + 3 * d * self.d_shared
         total = v * d * (1 if self.tie_embeddings else 2) + d
+        experts += d if self.shared_gate else 0
+        inner = self.linear_v_heads * self.linear_d_v
+        linear = d * (self.linear_conv_dim + inner + 2 * self.linear_v_heads) \
+            + self.linear_conv * self.linear_conv_dim \
+            + 2 * self.linear_v_heads + self.linear_d_v + inner * d
         for i, kind in enumerate(self.kinds):
             h = self.heads(kind)
             dense = self.n_experts <= 0 or i < len(self.lead_pattern)
-            total += d * h * self.head_dim * 2 + d * kv * 2 + 2 * d \
-                + (d * h if self.attn_gate else 0) \
-                + (2 * self.head_dim if self.qk_norm else 0) \
-                + (3 * d * f if dense else experts)
+            total += 2 * d + (3 * d * f if dense else experts)
+            total += linear if kind == "linear" else (
+                d * h * self.head_dim * 2 + d * kv * 2
+                + d * h * self.attn_gate
+                + (2 * self.head_dim if self.qk_norm else 0))
         return total
 
 
@@ -289,7 +383,12 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     (`cfg.lead_pattern`) is a block of its own, unstacked, in the list
     `lead`.  Where the kinds differ in query heads, `blocks` lacks what
     has a kind's width (`wq`, `wo`, `head_gate`), which `kinds[kind]`
-    stacks over the layers of that kind behind the leading ones."""
+    stacks over the layers of that kind behind the leading ones.  In a
+    model with linear layers (`cfg.mixers_by_kind`) `blocks` keeps the
+    norms and the FFN alone and `kinds[kind]` a kind's whole mixer: an
+    attention layer's `wq`, `wk`, `wv`, `wo`, gate and QK-norm, a linear
+    layer's `in_qkvz` ([q | k | v | z]), `in_ba` ([b | a]), `conv_w`,
+    `A_log`, `dt_bias` (float32 both), `gate_norm` and `out_proj`."""
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     nkv = cfg.n_kv_heads
@@ -299,32 +398,64 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)).astype(dt)
 
+    def gain(key, shape, neutral=True):
+        """A norm's gain: 1 (`neutral`) or drawn about it, so that a
+        comparison notices a gain left out; about 0 in a model whose
+        norms add the 1 themselves, where nothing is neutral."""
+        if neutral and not cfg.norm_plus_one:
+            return jnp.ones(shape, dt)
+        return ((0.0 if cfg.norm_plus_one else 1.0) + 0.1 * jax.random.normal(
+            key, shape, jnp.float32)).astype(dt)
+
     def wide(k, l, nh):
         """What of `l` layers (() for one) is `nh` query heads wide."""
         out = {"wq": dense(k[1], (*l, d, nh * hd), d),
                "wo": dense(k[4], (*l, nh * hd, d), nh * hd)}
         if cfg.attn_gate:
             out["head_gate"] = dense(jax.random.fold_in(k[4], 2),
-                                     (*l, d, nh), d)
+                                     (*l, d, nh * cfg.attn_gate), d)
+        return out
+
+    def norms(k, l):
+        return {"attn_norm": gain(jax.random.fold_in(k[0], 1), (*l, d)),
+                "mlp_norm": gain(jax.random.fold_in(k[0], 2), (*l, d))}
+
+    def narrow(k, l):
+        """`l` attention layers' keys, values and QK-norm: what no kind
+        of attention layer has a width of its own for."""
+        out = {"wk": dense(k[2], (*l, d, nkv * hd), d),
+               "wv": dense(k[3], (*l, d, nkv * hd), d)}
+        if cfg.qk_norm:
+            for i, name in enumerate(("q_norm", "k_norm")):
+                out[name] = gain(jax.random.fold_in(k[1], 1 + i), (*l, hd),
+                                 neutral=False)
         return out
 
     def attention(k, l, nh):
         """`l` layers' attention and norms; `nh` None: without what is
         stacked by kind."""
-        out = {
-            "attn_norm": jnp.ones((*l, d), dt),
-            **({} if nh is None else wide(k, l, nh)),
-            "wk": dense(k[2], (*l, d, nkv * hd), d),
-            "wv": dense(k[3], (*l, d, nkv * hd), d),
-            "mlp_norm": jnp.ones((*l, d), dt),
-        }
-        if cfg.qk_norm:
-            # Drawn away from 1, so that a comparison notices a gain left out.
-            for i, name in enumerate(("q_norm", "k_norm")):
-                out[name] = (1.0 + 0.1 * jax.random.normal(
-                    jax.random.fold_in(k[1], 1 + i), (*l, hd),
-                    jnp.float32)).astype(dt)
-        return out
+        return {**norms(k, l), **({} if nh is None else wide(k, l, nh)),
+                **narrow(k, l)}
+
+    def linear(k, l):
+        """`l` linear layers' mixers.  The decay is drawn so that a step
+        keeps exp(g) mostly in (0.2, 1), the slowest heads remembering
+        over hundreds of positions: a state lost between two launches
+        then still shows many positions later."""
+        hv, dv = cfg.linear_v_heads, cfg.linear_d_v
+        inner, conv = hv * dv, cfg.linear_conv_dim
+        rate = jnp.exp(jax.random.uniform(
+            k[5], (*l, hv), jnp.float32, math.log(1e-3), 0.0))
+        return {"in_qkvz": dense(k[1], (*l, d, conv + inner), d),
+                "in_ba": dense(k[2], (*l, d, 2 * hv), d),
+                "conv_w": dense(k[3], (*l, cfg.linear_conv, conv),
+                                cfg.linear_conv),
+                "A_log": jnp.log(rate),
+                "dt_bias": jax.random.uniform(k[6], (*l, hv), jnp.float32,
+                                              -1.0, 1.0),
+                "gate_norm": (1.0 + 0.1 * jax.random.normal(
+                    k[7], (*l, dv), jnp.float32)).astype(dt),
+                "out_proj": dense(k[4], (*l, inner, d), inner)}
 
     def swiglu(k, l, width, prefix="w_"):
         return {prefix + "gate": dense(k[5], (*l, d, width), d),
@@ -336,7 +467,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
 
     n_lead = len(cfg.lead_pattern)
     l = (cfg.n_layers - n_lead,)
-    blocks = attention(keys, l, None if cfg.heads_by_kind else cfg.n_heads)
+    blocks = norms(keys, l) if cfg.mixers_by_kind else attention(
+        keys, l, None if cfg.heads_by_kind else cfg.n_heads)
     if cfg.n_experts > 0:
         e, f = cfg.n_experts, cfg.expert_width
         held = cfg.n_experts_held
@@ -348,23 +480,31 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
         })
         if cfg.d_shared:
             blocks.update(swiglu(keys_of(98), l, cfg.d_shared, "shared_"))
+        if cfg.shared_gate:
+            blocks["shared_scale"] = dense(keys_of(97)[0], (*l, d, 1), d)
     else:
         blocks.update(swiglu(keys, l, f))
     params = {
         "embed": dense(keys[0], (cfg.vocab_size, d), d ** 0.5 * d),  # ~N(0, 1/sqrt(d))
         "blocks": blocks,
-        "final_norm": jnp.ones((d,), dt),
+        "final_norm": gain(jax.random.fold_in(keys[0], 3), (d,)),
     }
     if n_lead:
         params["lead"] = [
             {**attention(keys_of(100 + i), (), cfg.heads(kind)),
              **swiglu(keys_of(100 + i), (), cfg.d_ff)}
             for i, kind in enumerate(cfg.lead_pattern)]
-    if cfg.heads_by_kind:
+    def of_kind(k, n, kind):
+        """What `kinds[kind]` stacks over the `n` layers of `kind`."""
+        if kind == "linear":
+            return linear(k, n)
+        own = wide(k, n, cfg.heads(kind))
+        return {**own, **narrow(k, n)} if cfg.mixers_by_kind else own
+
+    if cfg.mixers_by_kind or cfg.heads_by_kind:
         behind = cfg.kinds[n_lead:]
         params["kinds"] = {
-            kind: wide(keys_of(200 + i), (behind.count(kind),),
-                       cfg.heads(kind))
+            kind: of_kind(keys_of(200 + i), (behind.count(kind),), kind)
             for i, kind in enumerate(sorted(set(behind)))}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
@@ -379,18 +519,26 @@ def param_logical_axes(cfg: TransformerConfig):
             out["head_gate"] = (*l, "embed", "heads")
         return out
 
-    def attention(l, by_kind):
-        out = {
-            "attn_norm": (*l, "embed"),
-            **({} if by_kind else wide(l)),
-            "wk": (*l, "embed", "kv_heads"),
-            "wv": (*l, "embed", "kv_heads"),
-            "mlp_norm": (*l, "embed"),
-        }
+    def norms(l):
+        return {"attn_norm": (*l, "embed"), "mlp_norm": (*l, "embed")}
+
+    def narrow(l):
+        out = {"wk": (*l, "embed", "kv_heads"),
+               "wv": (*l, "embed", "kv_heads")}
         if cfg.qk_norm:
             out.update({"q_norm": (*l, "head_dim"),
                         "k_norm": (*l, "head_dim")})
         return out
+
+    def attention(l, by_kind):
+        return {**norms(l), **({} if by_kind else wide(l)), **narrow(l)}
+
+    def linear(l):
+        return {"in_qkvz": (*l, "embed", "heads"),
+                "in_ba": (*l, "embed", None), "conv_w": (*l, None, "heads"),
+                "A_log": (*l, None), "dt_bias": (*l, None),
+                "gate_norm": (*l, "head_dim"),
+                "out_proj": (*l, "heads", "embed")}
 
     def swiglu(l, prefix="w_"):
         return {prefix + "gate": (*l, "embed", "mlp"),
@@ -398,7 +546,8 @@ def param_logical_axes(cfg: TransformerConfig):
                 prefix + "down": (*l, "mlp", "embed")}
 
     l = ("layers",)
-    blocks = attention(l, cfg.heads_by_kind)
+    blocks = norms(l) if cfg.mixers_by_kind else attention(
+        l, cfg.heads_by_kind)
     if cfg.n_experts > 0:
         blocks.update({
             "router": ("layers", "embed", "expert"),
@@ -408,6 +557,8 @@ def param_logical_axes(cfg: TransformerConfig):
         })
         if cfg.d_shared:
             blocks.update(swiglu(l, "shared_"))
+        if cfg.shared_gate:
+            blocks["shared_scale"] = ("layers", "embed", None)
     else:
         blocks.update(swiglu(l))
     axes = {
@@ -418,9 +569,12 @@ def param_logical_axes(cfg: TransformerConfig):
     if cfg.lead_pattern:
         axes["lead"] = [{**attention((), False), **swiglu(())}
                         for _ in cfg.lead_pattern]
-    if cfg.heads_by_kind:
-        axes["kinds"] = {kind: wide(l) for kind in sorted(
-            set(cfg.kinds[len(cfg.lead_pattern):]))}
+    behind = sorted(set(cfg.kinds[len(cfg.lead_pattern):]))
+    if cfg.mixers_by_kind:
+        axes["kinds"] = {kind: linear(l) if kind == "linear"
+                         else {**wide(l), **narrow(l)} for kind in behind}
+    elif cfg.heads_by_kind:
+        axes["kinds"] = {kind: wide(l) for kind in behind}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -439,12 +593,18 @@ def _repeated_kv(attn_impl):
     return impl
 
 
+def gain_of(w, cfg: TransformerConfig):
+    """The gain an RMSNorm of the stack multiplies by, from its stored
+    `w`: `w`, or 1 + w (float32) in a model whose norms add the 1."""
+    return 1.0 + w.astype(jnp.float32) if cfg.norm_plus_one else w
+
+
 def qk_normed(bp, q, k, cfg: TransformerConfig):
     """q, k (B, T, heads, D) under the block's QK-norm, where it has one."""
     if not cfg.qk_norm:
         return q, k
-    return (rms_norm(q, bp["q_norm"], eps=cfg.norm_eps),
-            rms_norm(k, bp["k_norm"], eps=cfg.norm_eps))
+    return (rms_norm(q, gain_of(bp["q_norm"], cfg), eps=cfg.norm_eps),
+            rms_norm(k, gain_of(bp["k_norm"], cfg), eps=cfg.norm_eps))
 
 
 def _block(x, bp, cfg: TransformerConfig, rules: LogicalRules, *,
@@ -504,7 +664,10 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         ("d_shared", cfg.d_shared), ("experts_held", cfg.experts_held),
         ("expert_scoring", cfg.expert_scoring != "softmax"),
         ("route_scale", cfg.route_scale != 1.0),
-        ("diffusion_block", cfg.diffusion_block)) if differs]
+        ("diffusion_block", cfg.diffusion_block),
+        ("layer_pattern 'linear'", cfg.recurrent),
+        ("norm_plus_one", cfg.norm_plus_one),
+        ("shared_gate", cfg.shared_gate)) if differs]
     if served_only:
         raise ValueError(
             f"{cfg.name!r} is a served model (`models.decoding`): the train "

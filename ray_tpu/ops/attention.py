@@ -626,6 +626,18 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     return acc / jnp.where(l > 0, l, 1.0)[..., None]
 
 
+# The fewest KV heads whose pool a decode step reads through the kernel.
+# The kernel takes a page as rows of (position, KV head), the pool
+# reshaped (L, N, block_size * Hkv, D); the compiler stores a pool of 2
+# heads in tiles of (2, 128) and those rows in tiles of (8, 128), so the
+# reshape is no bitcast but a copy of the layer's whole pool, K and V, a
+# step: bf16[2,8193,32,256], 0.27 GB each, 1.49 s of a 3 s trace at
+# Qwen3-Next's full layers (2 KV heads of 256; AOT for a v5e and `TPU v5
+# lite`, PR 64).  Such a pool takes the loop, which reads groups of pages
+# where they lie.  Every pool a kernel was measured on has 4 heads or more.
+_PAGED_KERNEL_MIN_KV_HEADS = 4
+
+
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
                     scale=None, sees=None):
     """Attention of `q` (S, K, H, D) over one layer of a paged KV pool.
@@ -698,6 +710,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
             f"step of 8 lanes takes over a quarter as long again at "
             f"Laguna-XS.2's widths: 8.4 ms against 6.5)",
             stacklevel=2)
+        return loop(*args)
+    if hkv < _PAGED_KERNEL_MIN_KV_HEADS:
         return loop(*args)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
 

@@ -138,6 +138,12 @@ _GROUP_OPEN_ROWS = "group_open_rows"
 # selection, and of no other: 100 x the reads of its burst that took the
 # selection as a mask.
 _SELECT_MASKED = "select_masked"
+# Behind them in the records of a model with linear layers, and of no
+# other: the state rows (a lane's state in one linear layer) one step of
+# the tick's burst read, and wrote as many, and the chunks of the rule its
+# prefill launches carried (rows over `linear_chunk`, a launch's padded
+# tail counted: the program runs it).
+_LINEAR_STATE = ("linear_state_rows", "delta_chunks")
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -155,7 +161,8 @@ class _TickAccounts:
                  "routed_at", "defect_at", "experts_read", "ahead",
                  "starved_s",
                  "index_scored_tokens", "kv_selected_tokens", "blocks",
-                 "passes", "block_tokens", "ring_slots", "select_masked")
+                 "passes", "block_tokens", "ring_slots", "select_masked",
+                 "linear_state_rows", "delta_chunks")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
@@ -164,6 +171,7 @@ class _TickAccounts:
         self.kv_read_tokens = self.ahead = 0
         self.index_scored_tokens = self.kv_selected_tokens = 0
         self.blocks = self.passes = self.block_tokens = self.ring_slots = 0
+        self.linear_state_rows = self.delta_chunks = 0
         # Where the tick's `routed_here` and `moe_tiles` are summed on the
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
@@ -735,6 +743,13 @@ class PagedLLMEngine:
             self.tick_fields += (_SELECT_MASKED,)
             self._select_reads = \
                 self._burst_passes * cfg.selection_counts(0, 1)[0]
+        # A model with linear layers: what its bursts and launches carry
+        # of their state is the host's own arithmetic.
+        self._linear_layers = (
+            cfg.n_of("linear") if getattr(cfg, "linear_chunk", 0)
+            and self._recurrent else 0)
+        if self._linear_layers:
+            self.tick_fields += _LINEAR_STATE
         # A model of several residual streams: the largest defect of a
         # tick's mixes, kept on the device as the sums above are.
         self._defects = None
@@ -1436,6 +1451,9 @@ class PagedLLMEngine:
                 acct = self._acct
                 acct.prefill_s += self._obs_prefill(req, t0, nv, c)
                 acct.prefill_tokens += nv
+                if self._linear_layers:
+                    acct.delta_chunks += self._linear_layers * (
+                        c // min(c, self.cfg.linear_chunk))
                 if req.pos >= n:
                     clock.enter(_BOOK)
                     self._prefillq.popleft()
@@ -1549,6 +1567,7 @@ class PagedLLMEngine:
             self._acct.kv_read_tokens = self._kv_read_tokens(
                 [int(self._lengths[i]) for i in idx])
             self._acct.ring_slots = self._ring_slots[w]
+            self._acct.linear_state_rows = self._linear_layers * len(idx)
             first = self._burst_input(idx, w)
             tables = np.zeros((w, self._b_max), np.int32)
             lengths = np.zeros((w,), np.int32)
@@ -1922,6 +1941,12 @@ class PagedLLMEngine:
         program's count, `ops.attention._attend_masked`'s own predicate,
         read with the burst's tokens; 0 for a tick without a burst and on
         a platform that fetches).
+        `linear_state_rows`, `delta_chunks` (behind them, in the records
+        of a model with linear layers and of no other): the state rows (a
+        lane's state in one linear layer) one step of the tick's burst
+        reads, and writes as many: live lanes x linear layers; and the
+        chunks of the delta rule the tick's prefill launches carried, over
+        the linear layers (a launch's rows over `linear_chunk`).
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -1968,6 +1993,8 @@ class PagedLLMEngine:
                     row.append(0)       # read with `routed_here`
                 if self._masked_at:
                     row.append(acct.select_masked)
+                if self._linear_layers:
+                    row += [acct.linear_state_rows, acct.delta_chunks]
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -42,7 +42,8 @@ _LONG_FIRST = (
     "test_mamba2_moe_serving", "test_mla_moe_serving", "test_tune_breadth",
     "test_paged_attention", "test_hybrid_serving", "test_window_moe_serving",
     "test_mhc_mla_serving", "test_gated_moe_serving",
-    "test_group_moe_serving", "test_examples", "test_pipeline")
+    "test_group_moe_serving", "test_gated_delta_serving", "test_examples",
+    "test_pipeline")
 
 
 def pytest_collection_modifyitems(items):

@@ -1189,3 +1189,72 @@ def test_block_diffusion_served_programs_fit_one_chip(topo, program):
         assert len(_expert_readers(text, fam.expert_operand(config))[1]) == 2
         assert fam.select_operand(config).search(text)
         assert text.count("denoise_select") and text.count("block_commit")
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_gated_delta_served_programs_fit_one_chip(topo, program):
+    """Qwen3-Next-80B-A3B at the benchmark's cut (two periods of three
+    linear layers to one full layer; 128 of 512 experts; a quarter of the
+    vocabulary) and serving shape (8 slots x 16384, block 16: two layers'
+    pool 0.54 GB, six linear layers' float32 state and conv rows for 9
+    slots 0.12 GB, beside 7.33 GB of weights): the width-8 burst and the
+    chunk of 512 rows (eight chunks of the delta rule, the state handed on
+    in an unrolled scan) compile for one v5e chip and fit its 15.75 GB
+    usable.  Pool, state and conv rows are updated in place (their bytes
+    are aliased).  **The full layers' pool has 2 KV heads of 256**: the
+    compiler stores it in tiles of (2, 128), the decode kernel's rows of
+    (position, KV head) would be a copy of a layer's whole pool a step
+    (bf16[2,8193,32,256], 0.27 GB for K and again for V: 1.49 s of a 3 s
+    trace on the chip, PR 64), so the burst reads it by the loop
+    (`ops.attention._PAGED_KERNEL_MIN_KV_HEADS`) and nothing of that shape
+    is made.  The expert products read the held stacks in place, as
+    Laguna's (same expert, 6.3 MB)."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "qwen3-next-80b-a3b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state, params = resident["sequence_state"], resident["params"]
+    assert state.k.shape == (2, 8193, 16, 2, 256) and state.wk is None
+    assert state.lstate.shape == (6, 9, 32, 128, 128)
+    assert state.lstate.dtype == jnp.float32
+    assert state.lconv.shape == (6, 9, 3, 8192)
+    assert params["kinds"]["full"]["wq"].shape == (2, 2048, 16 * 256)
+    assert params["kinds"]["full"]["head_gate"].shape == (2, 2048, 16 * 256)
+    assert params["kinds"]["linear"]["in_qkvz"].shape == (6, 2048, 12288)
+    assert params["blocks"]["w_gate"].shape == (8, 128, 2048, 512)
+    assert params["lm_head"].shape == (2048, 37984)
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
+    assert abs(resident_bytes - 7.99e9) < 0.1e9, resident_bytes
+    assert resident_bytes > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < state.k.size * 2, mem.temp_size_in_bytes
+    _assert_pool_read_by_the_kernel(text, state.k.shape, 0)
+    assert not re.search(r"= bf16\[2,8193,32,256\]", text)
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program,
+                                  visit_sites=4)
+    # the lanes' state (the burst) or the slot's (the chunk) is there in
+    # float32, and the rule's triangular system in the chunk alone
+    assert fam.state_operand(config).search(text) \
+        if program == "paged_decode_burst" \
+        else re.search(r"f32\[1,8,32,64,64\]", text)
