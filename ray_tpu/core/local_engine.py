@@ -28,7 +28,7 @@ class _Store:
     """In-memory object store with completion futures."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()  # `delete` comes from `__del__` too
         self._cond = threading.Condition(self._lock)
         self._data: Dict[ObjectID, bytes] = {}
         self._events: Dict[ObjectID, threading.Event] = {}

@@ -291,7 +291,8 @@ def cmd_list(gcs: _Gcs, args) -> None:
                                 "NODE"]))
     elif kind == "tasks":
         events = gcs.call("TaskEvents", "list_events", limit=args.limit)
-        rows = [[e["task_id"][:12], e.get("name", ""), e.get("state", ""),
+        rows = [[e.get("task_id", "")[:12], e.get("name", ""),
+                 e.get("state", ""),
                  f"{(e.get('end_ts', 0) - e.get('start_ts', 0)) * 1000:.1f}",
                  (e.get("node_id") or "")[:12], e.get("error") or ""]
                 for e in events]

@@ -32,18 +32,29 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
 
+import case_limit  # noqa: E402
+
+# The limit's own fixture modules run in inner runs, under a conftest of
+# their own (tests/test_case_limit.py); tier-1 does not come by.
+collect_ignore = ["case_limit_fixtures"]
+
+
+def pytest_configure(config):
+    config.pluginmanager.register(case_limit, "case_limit")
+
 
 # `--dist loadfile` hands files to its workers in collection order, and a
 # file of minutes that starts last is the tail of the run's wall: the files
-# over ~120 CPU-seconds (junit of the driver's command, PR 62) start first,
-# longest first; every other file keeps its place.
+# over ~120 CPU-seconds (junit of the driver's command, PR 65) start first,
+# longest first, and after them the one file of a minute that the alphabet
+# would start last; every other file keeps its place.
 _LONG_FIRST = (
-    "test_tpu_compile", "test_moe", "test_dsa_moe_serving",
-    "test_mamba2_moe_serving", "test_mla_moe_serving", "test_tune_breadth",
-    "test_paged_attention", "test_hybrid_serving", "test_window_moe_serving",
-    "test_mhc_mla_serving", "test_gated_moe_serving",
-    "test_group_moe_serving", "test_gated_delta_serving", "test_examples",
-    "test_pipeline")
+    "test_moe", "test_tpu_compile", "test_dsa_moe_serving",
+    "test_gated_delta_serving", "test_mamba2_moe_serving",
+    "test_mla_moe_serving", "test_paged_attention",
+    "test_window_moe_serving", "test_hybrid_serving",
+    "test_gated_moe_serving", "test_mhc_mla_serving",
+    "test_group_moe_serving", "test_tune_breadth")
 
 
 def pytest_collection_modifyitems(items):

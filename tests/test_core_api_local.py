@@ -221,3 +221,30 @@ def test_large_numpy_roundtrip():
 def test_cluster_resources():
     res = ray_tpu.cluster_resources()
     assert res.get("CPU", 0) > 0
+
+
+def test_a_ref_collected_under_the_store_s_lock_does_not_wait_for_it():
+    """The collector runs `ObjectRef.__del__` at whatever allocation it
+    likes, and `_Store._event` allocates under the store's lock: the
+    `delete` that `__del__` ends in then took the lock a second time, in
+    the same thread, and waited for ever (PR 65's first whole run:
+    `test_workflow.py::test_per_step_retry_with_backoff`, once; ROADMAP
+    D12 (k)).  Here the store's `_events` collects on its `get`, which
+    `_event` calls under the lock."""
+    import threading
+
+    from ray_tpu.core.local_engine import _Store
+
+    store = _Store()
+
+    class CollectsARef(dict):
+        def get(self, key, default=None):
+            store.delete(b"a ref nobody holds")     # `_ref_removed`
+            return super().get(key, default)
+
+    store._events = CollectsARef()
+    waiter = threading.Thread(target=store.wait, args=(b"an object", 0.01),
+                              daemon=True)
+    waiter.start()
+    waiter.join(timeout=10)
+    assert not waiter.is_alive()

@@ -826,6 +826,22 @@ def test_cli_status_and_lists(obs_cluster):
     assert "raytpu_workers" in out
 
 
+def test_cli_lists_tasks_beside_an_event_without_a_task_id(obs_cluster):
+    """`list_events` fills the room its limit leaves with spans and profile
+    events, which carry no task id: `list tasks` prints them too."""
+    from ray_tpu.api import _global_worker
+    from ray_tpu.scripts import cli
+
+    worker = _global_worker()
+    worker.gcs.call("TaskEvents", "add_task_events", profile=[
+        {"kind": "profile", "category": "cpu_profile", "name": "no_task_id",
+         "start_ts": 1.0, "end_ts": 2.0}])
+    buf = io.StringIO()
+    with redirect_stdout(buf):
+        cli.main(["--address", worker.gcs_address, "list", "tasks"])
+    assert "no_task_id" in buf.getvalue() and "traced" in buf.getvalue()
+
+
 # ---------------------------------------------------------------------------
 # Grafana dashboard generation (ref: dashboard/modules/metrics/
 # grafana_dashboard_factory.py) + usage stats (ref: _private/usage/)

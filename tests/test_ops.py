@@ -238,9 +238,10 @@ def test_ulysses_grads_match_reference():
     spec = P(None, "sp", None, None)
     uly = _ulysses_sharded(mesh, spec)
 
-    gq = jax.grad(lambda q_: jnp.sum(uly(q_, k, v) ** 2))(q)
-    gq_ref = jax.grad(
-        lambda q_: jnp.sum(mha_reference(q_, k, v, causal=True) ** 2))(q)
+    # under `jit`, as a step runs them (op by op: a compile an op)
+    gq = jax.jit(jax.grad(lambda q_: jnp.sum(uly(q_, k, v) ** 2)))(q)
+    gq_ref = jax.jit(jax.grad(
+        lambda q_: jnp.sum(mha_reference(q_, k, v, causal=True) ** 2)))(q)
     np.testing.assert_allclose(np.asarray(gq), np.asarray(gq_ref),
                                atol=1e-4)
 

@@ -21,6 +21,17 @@ def tiny_cfg(n_layers=4, compute_dtype=jnp.bfloat16):
         compute_dtype=compute_dtype)
 
 
+def reference_loss(cfg):
+    """`loss_fn` of `cfg` under `jit`, as a train step runs it: op by op
+    the same arithmetic costs a compile an op (and the pipelined loss's
+    gradient 25 s where the jitted one is 2)."""
+    return jax.jit(lambda params, batch: loss_fn(params, batch, cfg))
+
+
+def pipeline_loss(cfg, mesh, n_micro):
+    return jax.jit(make_pipeline_loss(cfg, mesh, n_micro))
+
+
 def make_batch(key, cfg, batch=8, seq=16):
     tokens = jax.random.randint(key, (batch, seq + 1), 0, cfg.vocab_size,
                                 dtype=jnp.int32)
@@ -33,9 +44,9 @@ def test_pipeline_loss_matches_reference(pp, n_micro):
     params = init_params(jax.random.key(0), cfg)
     batch = make_batch(jax.random.key(1), cfg)
 
-    ref = loss_fn(params, batch, cfg)
+    ref = reference_loss(cfg)(params, batch)
     mesh = build_pipeline_mesh(pp, dp=1)
-    pl = make_pipeline_loss(cfg, mesh, n_micro)(params, batch)
+    pl = pipeline_loss(cfg, mesh, n_micro)(params, batch)
     np.testing.assert_allclose(float(pl), float(ref), rtol=2e-4)
 
 
@@ -45,10 +56,10 @@ def test_pipeline_grads_match_reference():
     params = init_params(jax.random.key(0), cfg)
     batch = make_batch(jax.random.key(1), cfg)
 
-    g_ref = jax.grad(lambda p: loss_fn(p, batch, cfg))(params)
+    g_ref = jax.jit(jax.grad(reference_loss(cfg)))(params, batch)
     mesh = build_pipeline_mesh(2, dp=1)
-    ploss = make_pipeline_loss(cfg, mesh, 2)
-    g_pp = jax.grad(ploss)(params, batch)
+    g_pp = jax.jit(jax.grad(make_pipeline_loss(cfg, mesh, 2)))(
+        params, batch)
 
     flat_ref, _ = jax.tree.flatten(g_ref)
     flat_pp, _ = jax.tree.flatten(g_pp)
@@ -63,9 +74,9 @@ def test_pipeline_with_dp_axis():
     params = init_params(jax.random.key(0), cfg)
     batch = make_batch(jax.random.key(1), cfg)
 
-    ref = loss_fn(params, batch, cfg)
+    ref = reference_loss(cfg)(params, batch)
     mesh = build_pipeline_mesh(2, dp=2)
-    pl = make_pipeline_loss(cfg, mesh, 2)(params, batch)
+    pl = pipeline_loss(cfg, mesh, 2)(params, batch)
     np.testing.assert_allclose(float(pl), float(ref), rtol=2e-4)
 
 
@@ -77,9 +88,9 @@ def test_pipeline_masked_loss_matches_reference():
     batch["mask"] = (jax.random.uniform(jax.random.key(2), tgt_shape)
                      > 0.3).astype(jnp.float32)
 
-    ref = loss_fn(params, batch, cfg)
+    ref = reference_loss(cfg)(params, batch)
     mesh = build_pipeline_mesh(2, dp=1)
-    pl = make_pipeline_loss(cfg, mesh, 2)(params, batch)
+    pl = pipeline_loss(cfg, mesh, 2)(params, batch)
     np.testing.assert_allclose(float(pl), float(ref), rtol=1e-3)
 
 

@@ -82,7 +82,9 @@ def test_sac_improves_pendulum():
                          rollout_fragment_length=32)
             # SAC wants ~1 update per env step (ref sac.py defaults);
             # with these settings the swing-up goes from ~-1250 to
-            # better than -400 in ~50 iterations (~20s CPU).
+            # better than -300 in 45 iterations (over seeds 0-9 the gain
+            # asserted below reads 944 to 1,190 at 45 and 627 to 1,117
+            # at 40, against its bound of 400).
             .training(train_batch_size=128,
                       num_updates_per_iteration=128,
                       learning_starts=256,
@@ -91,7 +93,7 @@ def test_sac_improves_pendulum():
             .rl_module(model_hidden=(64, 64))
             .build())
     early, late = [], []
-    for it in range(55):
+    for it in range(45):
         m = algo.train()
         r = m.get("episode_return_mean")
         if r is not None:
@@ -168,11 +170,55 @@ def test_evaluation_workers_separate_and_deterministic(local_ray):
     algo.stop()
 
 
+def test_cql_conservative_term_lowers_q_off_the_data():
+    """What makes CQL conservative, without a training run: on one batch
+    whose recorded action is always +1 (a terminal step, reward 0, so the
+    TD target is 0 everywhere), the penalty pushes Q up on the recorded
+    action and down on an action the data never took.  Same seed, same
+    batch, 40 updates, with the term and without: Q(s, 1) - Q(s, -1.5)
+    reads 0.60 against -0.32 here; over seeds 0-9 the difference is
+    0.69-1.40 and the last penalty 0.54-0.86 lower."""
+    from ray_tpu.rllib.cql import CQLLearner
+    from ray_tpu.rllib.models import apply_twin_q
+    from ray_tpu.rllib.sac import SACHyperparams
+
+    n = 128
+    obs = np.random.default_rng(0).normal(size=(n, 3)).astype(np.float32)
+    batch = {"obs": obs, "actions": np.full((n, 1), 1.0, np.float32),
+             "rewards": np.zeros(n, np.float32), "next_obs": obs,
+             "terminals": np.ones(n, np.float32)}
+
+    def after_40_updates(cql_alpha):
+        learner = CQLLearner(
+            3, 1, SACHyperparams(act_limit=2.0, target_entropy=-1.0),
+            cql_alpha=cql_alpha, seed=0, hidden=(32, 32))
+        for _ in range(40):
+            metrics = learner.update(batch)
+
+        def q(action):
+            return float(np.minimum(*apply_twin_q(
+                learner.critic, obs, np.full((n, 1), action, np.float32))
+            ).mean())
+
+        return q(1.0) - q(-1.5), float(metrics["cql_penalty"])
+
+    plain, plain_penalty = after_40_updates(0.0)
+    conservative, penalty = after_40_updates(5.0)
+    assert conservative > plain + 0.3, (plain, conservative)
+    assert penalty < plain_penalty - 0.25, (plain_penalty, penalty)
+
+
+@pytest.mark.slow
 def test_cql_trains_offline_and_beats_random(tmp_path):
     """CQL (ref: rllib/algorithms/cql) trains PURELY from a recorded
     replay dataset (diverse, D4RL-replay-style) and its deterministic
     policy clearly beats random on Pendulum — measured runs reach ~-100,
-    i.e. better than the behavior policy itself."""
+    i.e. better than the behavior policy itself.
+
+    Slow (56 s under the driver's six workers) and not to be shrunk: at
+    30 SAC and 20 CQL iterations the evaluation read -228 to -900 over
+    seeds 0-9 against this bound of -700.  The case above holds the
+    conservative term in seconds."""
     from ray_tpu.rllib import CQLConfig, SACConfig
     from ray_tpu.rllib.cql import record_replay
 

@@ -31,19 +31,21 @@ def test_tpe_searcher_improves(tune_cluster, tmp_path):
         objective,
         param_space={"x": tune.uniform(-10.0, 10.0)},
         tune_config=tune.TuneConfig(
-            metric="score", mode="max", num_samples=24,
+            metric="score", mode="max", num_samples=14,
             max_concurrent_trials=4,
             search_alg=tune.TPESearcher(n_initial=6), seed=7),
         run_config=RunConfig(storage_path=str(tmp_path), name="tpe"),
     )
     grid = tuner.fit()
     best = grid.get_best_result("score")
-    assert best.metrics["score"] > -4.0   # within 2.0 of the optimum
+    # within 2.0 of the optimum (14 samples, six of them the random
+    # warm-up: over seeds 0-9 the best read -0.146 to -0.0002)
+    assert best.metrics["score"] > -4.0
     # Later (adaptive) samples should average better than the random
     # warmup — the searcher actually learned.
     xs = [r.metrics["config"]["x"] for r in grid._results
           if "config" in r.metrics]
-    assert len(xs) == 24
+    assert len(xs) == 14
 
 
 def test_concurrency_limiter(tune_cluster):
@@ -177,14 +179,15 @@ def test_ask_tell_adapter_drives_tuner(tune_cluster, tmp_path):
         trainable,
         param_space={"x": tune.uniform(-4, 4)},
         tune_config=tune.TuneConfig(
-            metric="score", mode="max", num_samples=16,
+            metric="score", mode="max", num_samples=8,
             max_concurrent_trials=1, search_alg=searcher),
         run_config=ray_tpu.train.RunConfig(name="asktell",
                                            storage_path=str(tmp_path)))
     grid = tuner.fit()
     best = grid.get_best_result("score", "max")
-    # Random search over [-4,4] rarely lands this close in 16 draws;
-    # the hill climber reliably does (seeded).
+    # Random search over [-4,4] rarely lands this close in 8 draws; the
+    # hill climber does (seeded and one trial at a time, so the same
+    # every run: -0.57, -0.57, -0.36, -0.013, ... -0.006 at the eighth).
     assert best.metrics["score"] > -0.5, best.metrics
     with pytest.raises(TypeError, match="ask"):
         tune.AskTellSearcher(object())
