@@ -63,7 +63,8 @@ import jax.numpy as jnp
 from ray_tpu.models.transformer import (
     TransformerConfig, gain_of, qk_normed)
 from ray_tpu.ops.attention import (
-    paged_attention, ring_rows, slot_ring_reader, window_attention)
+    paged_attention, pages_as_rows, ring_rows, slot_ring_reader,
+    window_attention)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
 
@@ -313,7 +314,13 @@ class PagedKVCache:
     operations below ask `pooled_leaves` and `resident_bytes()`
     (`kv_paged`: the pooled leaves' bytes, which the allocator's
     `bytes_per_block` is taken from) and never a leaf by name."""
-    k: jax.Array          # (L_full, N_blocks, block_size, Hkv, D)
+    # (L_full, N_blocks, block_size, Hkv, D), or, where such a page is not
+    # whole tiles as the compiler stores it and is as the rows the decode
+    # kernel reads (`ops.attention.pages_as_rows`; `init_paged_cache`
+    # decides), (L_full, N_blocks, block_size x Hkv, D): row t x Hkv + g
+    # is position t of KV head g.  `_paged_forward`'s write and
+    # `paged_attention` know which; the block operations index [:, block].
+    k: jax.Array
     v: jax.Array
     # The window layers' rings, by slot (the null slot last); None for a
     # model whose every layer is full, whose state is the pool alone.
@@ -358,7 +365,11 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                      ) -> PagedKVCache:
     """Zero pool of the full layers; with `shardings`
     (`paged_cache_shardings`) it is allocated directly sharded: a pool
-    that fits only across chips never exists whole on chip 0.  A model
+    that fits only across chips never exists whole on chip 0.  On one
+    chip (no `shardings`: they split the KV heads' axis) a page is kept
+    as rows of (position, KV head) where `ops.attention.pages_as_rows`
+    says so (fewer than 4 KV heads of whole lanes: `PagedKVCache.k`), a
+    rule of shapes, the same on every platform.  A model
     with window layers also gets their rings, `num_slots` + 1 of
     window + `prefill_chunk` rows each (a row is a position's (Hkv, D),
     as in the pool: D is a whole lane tile or the layout is the
@@ -368,6 +379,8 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     dtype = dtype or cfg.compute_dtype
     row = (cfg.n_kv_heads, cfg.head_dim)
     shape = (cfg.n_of("full"), num_blocks, block_size, *row)
+    if shardings is None and pages_as_rows(*row, block_size, dtype):
+        shape = (*shape[:2], block_size * cfg.n_kv_heads, cfg.head_dim)
     k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
     rings = {}
     if cfg.state_by_slot and not (num_slots and prefill_chunk):
@@ -528,12 +541,18 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         raise ValueError(f"{cfg.name!r} keeps state by slot: a served call "
                          f"needs the lanes' slots")
     cd = cfg.compute_dtype
-    bs = cache.k.shape[2]
+    as_rows = cache.k.ndim == 4            # `ops.attention.pages_as_rows`
+    bs = cache.k.shape[2] // (cfg.n_kv_heads if as_rows else 1)
     live_lane = kv_len > 0
     live = live_lane[:, None]
     wb = jnp.where(live, jnp.take_along_axis(
         block_tables, positions // bs, axis=1), 0)         # (S, K)
     off = jnp.where(live, positions % bs, 0)
+    # Where a layer's tokens' (Hkv, D) go: [block, position], or the
+    # position's Hkv rows of a page kept as rows.
+    written = (wb, off) if not as_rows else (
+        wb[..., None], off[..., None] * cfg.n_kv_heads
+        + jnp.arange(cfg.n_kv_heads))
     x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
     period, lead, tail = cfg.period, cfg.lead_pattern, cfg.tail_pattern
     per = {kind: period.count(kind) for kind in set(period)}
@@ -568,13 +587,13 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)
         if kind == "full":
             with jax.named_scope("full_attn"):
-                k_pool = k_pool.at[at, wb, off].set(
+                k_pool = k_pool.at[(at, *written)].set(
                     k.astype(k_pool.dtype))
-                v_pool = v_pool.at[at, wb, off].set(
+                v_pool = v_pool.at[(at, *written)].set(
                     v.astype(v_pool.dtype))
                 attn = paged_attention(q, k_pool, v_pool, at,
                                        block_tables, positions, kv_len,
-                                       sees=sees)
+                                       sees=sees, kv_heads=cfg.n_kv_heads)
         else:
             with jax.named_scope("swa"):
                 wk = wk.at[at, lane, ring_row].set(
@@ -981,8 +1000,10 @@ def _alike(leaves) -> bool:
 def gather_blocks(cache, block_ids) -> "jnp.ndarray":
     """Extract pool blocks as one host-transferable KV frame: the pooled
     leaves stacked, (n_leaves, L, n, block_size, *row): (2, L, n,
-    block_size, Hkv, D) with k over v for the `k` / `v` pair, (1, L, n,
-    block_size, W) for a latent pool; leaves of unlike rows side by side
+    block_size, Hkv, D) with k over v for the `k` / `v` pair ((2, L, n,
+    block_size x Hkv, D) of pages kept as rows, which an engine whose
+    pool is split over a mesh, and so kept by position, refuses:
+    `frame_fits`), (1, L, n, block_size, W) for a latent pool; leaves of unlike rows side by side
     in one leaf's place, their rows flattened and joined, (1, L, n,
     block_size, sum of the rows): latent rows and index keys.  The frame is
     the disaggregated-serving wire unit — a prefill actor gathers its

@@ -496,7 +496,7 @@ def _served_step(params, state: Mamba2MoEState, tokens, block_tables,
                 v_pool = v_pool.at[at, wb, off].set(v.astype(v_pool.dtype))
                 attn = paged_attention(
                     q, k_pool, v_pool, at, block_tables, positions, kv_len,
-                    scale=cfg.attention_scale)
+                    scale=cfg.attention_scale, kv_heads=cfg.n_kv_heads)
                 out = jnp.einsum(
                     "skh,hd->skd",
                     attn.reshape(*tokens.shape, -1).astype(cd),

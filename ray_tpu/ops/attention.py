@@ -574,7 +574,7 @@ _PAGED_GROUP_BLOCKS_LONG = 64
 
 def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
                            kv_len, score, mix, stat, d_out,
-                           group_blocks=None):
+                           group_blocks=None, block_size=None):
     """The loop both paged bodies share: groups of `group_blocks`
     table entries (None: `_PAGED_GROUP_BLOCKS`, or
     `_PAGED_GROUP_BLOCKS_LONG` under a table wider than
@@ -583,9 +583,12 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     the longest live lane of the call.  `score(kb)` gives a group's
     scaled scores (S, K, *stat, t) float32 from its keys (S, t, ...),
     the pool's trailing dimensions as stored; `mix(p, vb)` applies the
-    probabilities to its values, (S, K, *stat, d_out)."""
+    probabilities to its values, (S, K, *stat, d_out).  `block_size`:
+    the positions of a page where it holds several rows a position
+    (`pages_as_rows`: a group's keys are then (S, t x rows, ...)); None:
+    a page's rows are its positions."""
     s, k_w = positions.shape
-    bs, row = k_pool.shape[2], k_pool.shape[3:]
+    bs, row = block_size or k_pool.shape[2], k_pool.shape[3:]
     if group_blocks is None:
         group_blocks = _PAGED_GROUP_BLOCKS \
             if block_tables.shape[1] <= _PAGED_LONG_TABLE \
@@ -600,11 +603,11 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     def group(i, carry):
         m, l, acc = carry
         ids = jax.lax.dynamic_slice_in_dim(tables, i * g, g, axis=1)
-        kb = k_pool[layer, ids].reshape(s, t, *row)
+        kb = k_pool[layer, ids].reshape(s, -1, *row)
         # One pool given as both: its rows carry key and value together
         # (`paged_latent_attention`) and a group is gathered once.
         vb = kb if v_pool is k_pool \
-            else v_pool[layer, ids].reshape(s, t, *row)
+            else v_pool[layer, ids].reshape(s, -1, *row)
         sc = score(kb)
         seen = (i * t + jnp.arange(t)) <= positions[:, :, None]   # (S,K,t)
         sc = jnp.where(seen[over_stat], sc, _NEG_INF)
@@ -626,32 +629,87 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
     return acc / jnp.where(l > 0, l, 1.0)[..., None]
 
 
-# The fewest KV heads whose pool a decode step reads through the kernel.
-# The kernel takes a page as rows of (position, KV head), the pool
-# reshaped (L, N, block_size * Hkv, D); the compiler stores a pool of 2
-# heads in tiles of (2, 128) and those rows in tiles of (8, 128), so the
-# reshape is no bitcast but a copy of the layer's whole pool, K and V, a
-# step: bf16[2,8193,32,256], 0.27 GB each, 1.49 s of a 3 s trace at
-# Qwen3-Next's full layers (2 KV heads of 256; AOT for a v5e and `TPU v5
-# lite`, PR 64).  Such a pool takes the loop, which reads groups of pages
-# where they lie.  Every pool a kernel was measured on has 4 heads or more.
-_PAGED_KERNEL_MIN_KV_HEADS = 4
+# Which pools are kept as the rows the decode kernel reads.  The kernel
+# takes a page as rows of (position, KV head) x D.  A pool kept by
+# position, (L, N, block_size, Hkv, D), of 4 KV heads or more is stored in
+# tiles that make those rows a bitcast; **of fewer, the compiler stores
+# bf16[2,8193,16,2,256] in tiles `T(2,128)(2,1)` and the rows in
+# `T(8,128)(2,1)`**, so the kernel's view was a copy of a layer's whole
+# pool, K and V, a step (Qwen3-Next's full layers, 2 KV heads of 256:
+# `reshape.2443` / `.2444`, 0.27 GB each, 1.49 s of a 3 s trace, `tpot_p50_ms`
+# 10.48; PR 64, which sent such a pool to the loop: 8.11), and the loop's
+# gather of 8 lanes' groups of 64 such pages ran at a tenth of the chip's
+# bandwidth (`fusion.845` / `.849 bf16[512,16,2,256]`: 2.46 ms of a 5.9 ms
+# step where the live bytes ask 0.3).  Such a pool is allocated as the
+# rows themselves, (L, N, block_size x Hkv, D), row t x Hkv + g position t
+# of KV head g: whole (16, 128) tiles, the kernel's `stored` view to the
+# letter (its body and mask table are PR 47's, untouched), and under
+# `T(8,128)(2,1)` a position's two bfloat16 heads are one 32-bit sublane.
+# What knows: `models.decoding.init_paged_cache` (the shape),
+# `_paged_forward` (the write: rows off x Hkv + arange(Hkv) of the block)
+# and `paged_attention`; the block operations index `[:, block]`.
+# What was tried (PR 66; AOT for a described v5e, then `TPU v5 lite`,
+# 2026-10-04, call A: the bare served programs at Qwen3-Next's cut, 8
+# slots x 16,384, parent | this):
+#   AOT, the burst: one `paged_decode_attention` call site (the scan's),
+#   K and V handed over as `bf16[2,8193,32,256]{T(8,128)(2,1)}`, the
+#   scatter in place, nothing else of the pool's shape, temporaries 52 MB;
+#   the chunk: groups of bf16[64,32,256] gathered in whole tiles, each
+#   regrouped (1 MB, a relayout to `bf16[1024,2,256]{T(2,128)(2,1)}`) for
+#   the products the loop had.  The pool is never regrouped.
+#   a decode step, ms:  8 lanes at 4,096-12,288       6.67 | 4.25
+#                       4 of the 8                     5.63 | 3.38
+#                       8 lanes at 240-360             3.83 | 3.77
+#   a launch of 512 rows, ms, at position 0 / 4,096 / 8,192 / 11,776
+#                       22.50 / 22.78 / 23.40 / 23.85 | 22.78 / 22.90 /
+#                       23.72 / 23.95  (+0.4% to +1.3%: the regroup)
+# In the cell's traces the kernel's two calls a step take 0.24-0.26 ms
+# each for 57k live positions (117 MB = 0.143 ms at 819 GB/s: 55-59% of
+# its bytes; a page is 16 KB, so a step of `_PAGED_KERNEL_PAGES` moves
+# half the bytes of Laguna's, which read 85%).  More pages a step give
+# little: the bare step of 8 lanes reads 4.25 / 4.20 / 4.16 ms at 16 / 32
+# / 64 (call B), so the constant stays PR 47's.  Not built: rows of a
+# position with its heads side by side, (L, N, block_size, Hkv x D), also
+# whole tiles, a kernel that multiplies a KV head's query rows with
+# lane-aligned columns (no mask table, no products against the other
+# head) and a chunk that needs no regroup: the form to take if the
+# launch's 1% is ever wanted back; and a kernel that copies pages of
+# (16, 2, 256) as stored and unpacks the heads in VMEM (what Mosaic
+# refused of that kind is above `_PAGED_KERNEL_PAGES`).
+# The rule reads shapes alone, the same on every platform (a frame
+# shipped between engines has one geometry); a pool split over a mesh
+# keeps the KV heads' axis it is split on, and takes the loop anyway.
+def pages_as_rows(n_kv_heads: int, head_dim: int, block_size: int,
+                  dtype) -> bool:
+    """Whether a K / V pool of such pages is allocated as the decode
+    kernel reads it, (L, N, block_size x Hkv, D): a page that is not whole
+    tiles as (block_size, Hkv, D) and is as rows of (position, KV head).
+    `models.decoding.init_paged_cache` asks, for a pool on one chip."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return (n_kv_heads < 4 and head_dim % _LANES == 0
+            and (block_size * n_kv_heads) % sublanes == 0)
 
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
-                    scale=None, sees=None):
+                    scale=None, sees=None, *, kv_heads: int):
     """Attention of `q` (S, K, H, D) over one layer of a paged KV pool.
     `scale` multiplies the scores (None: D ** -0.5; a model that publishes
     another, such as 1 / D, hands it over: folded into a bfloat16 `q` it
     would round every query once more unless it is a power of two).
 
-    `k_pool` / `v_pool` are the whole pool (L, N, block_size, Hkv, D), read
-    at `[layer, block]` as stored: no slice of it is taken out and no copy in
-    another dtype is made.  Lane s owns the blocks `block_tables[s]` (S, B)
-    in sequence order; its query i stands at `positions[s, i]` and sees the
-    kv positions <= that, or <= `sees[s, i]` where given (the end of the
-    query's block, K >= 2: `models.decoding`), all written by the caller.
-    `kv_len` (S,): a lane's live positions (0: idle, its output garbage).
+    `k_pool` / `v_pool` are the whole pool, read at `[layer, block]` as
+    stored: no slice of it is taken out and no copy in another dtype is
+    made.  A page is kept in one of two layouts, and whoever allocates the
+    pool decides (`models.decoding.init_paged_cache`, by `pages_as_rows`):
+    by position, (L, N, block_size, Hkv, D), or as the rows the decode
+    kernel reads, (L, N, block_size x Hkv, D), row t x Hkv + g position t
+    of KV head g.  `kv_heads` is Hkv, the caller's (its configuration's):
+    the second layout does not show it.  Lane s owns the blocks
+    `block_tables[s]` (S, B) in sequence order; its query i stands at
+    `positions[s, i]` and sees the kv positions <= that, or <=
+    `sees[s, i]` where given (the end of the query's block, K >= 2:
+    `models.decoding`), all written by the caller.  `kv_len` (S,): a
+    lane's live positions (0: idle, its output garbage).
 
     Only live blocks are read, under a running soft-max, in one of two
     tilings.  **A decode step (one query row a lane) lowered for a TPU is
@@ -664,34 +722,41 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
     over groups of `_PAGED_GROUP_BLOCKS` table entries, whose trip count
     follows the longest live lane of this call (`_paged_running_softmax`):
     the rep = H // Hkv query heads of a KV head are grouped on their own
-    axis against the stored head, so K and V are neither repeated nor
-    kept in another dtype; scores, soft-max, the probabilities and both
-    products' accumulation are float32 (the compiler folds a group's
-    widening into the second product: on a v5e the step takes the same
-    time with the probabilities rounded to the cache dtype).  The
-    platform is the one the program is lowered for
+    axis against the stored head (of pages kept as rows, the gathered
+    group is regrouped by head, 1 MB a trip, never the pool), so K and V
+    are neither repeated nor kept in another dtype; scores, soft-max,
+    the probabilities and both products' accumulation are float32 (the
+    compiler folds a group's widening into the second product: on a v5e
+    the step takes the same time with the probabilities rounded to the
+    cache dtype).  The platform is the one the program is lowered for
     (`jax.lax.platform_dependent`), as `paged_latent_attention`'s.
     Returns (S, K, H, D) float32.
     """
     s, k_w, h, d = q.shape
-    hkv = k_pool.shape[3]
+    hkv = kv_heads
+    rows = k_pool.ndim == 4                # `pages_as_rows`
+    bs = k_pool.shape[2] // hkv if rows else k_pool.shape[2]
     if scale is None:
         scale = d ** -0.5
+
+    def heads(group):
+        """A gathered group's keys or values by (position, KV head)."""
+        return group.reshape(s, -1, hkv, d) if rows else group
 
     def loop(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
         qg = q.reshape(s, k_w, hkv, h // hkv, d)
         out = _paged_running_softmax(
             k_pool, v_pool, layer, block_tables, positions, kv_len,
-            lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, kb,
+            lambda kb: jnp.einsum("sqhrd,sthd->sqhrt", qg, heads(kb),
                                   preferred_element_type=jnp.float32) * scale,
-            lambda p, vb: jnp.einsum("sqhrt,sthd->sqhrd", p, vb,
+            lambda p, vb: jnp.einsum("sqhrt,sthd->sqhrd", p, heads(vb),
                                      preferred_element_type=jnp.float32),
-            (hkv, h // hkv), d)
+            (hkv, h // hkv), d, block_size=bs)
         return out.reshape(s, k_w, h, d)
 
     def kernel(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
         return _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables,
-                                    kv_len, scale=scale)
+                                    kv_len, scale=scale, kv_heads=hkv)
 
     positions = positions if sees is None else sees    # the loop's mask
     args = (q, k_pool, v_pool, layer, block_tables, positions, kv_len)
@@ -700,7 +765,7 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
         # the compiler partitions, and it cannot partition a kernel.
         return loop(*args)
     sublanes = 32 // k_pool.dtype.itemsize
-    if d % _LANES or (k_pool.shape[2] * hkv) % sublanes:
+    if d % _LANES or (bs * hkv) % sublanes:
         # Decided at trace time, as `paged_latent_attention`'s.
         warnings.warn(
             f"paged_attention: a page of {k_pool.shape[2:]} {k_pool.dtype} "
@@ -710,8 +775,6 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
             f"step of 8 lanes takes over a quarter as long again at "
             f"Laguna-XS.2's widths: 8.4 ms against 6.5)",
             stacklevel=2)
-        return loop(*args)
-    if hkv < _PAGED_KERNEL_MIN_KV_HEADS:
         return loop(*args)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
 
@@ -1316,27 +1379,29 @@ def _kv_decode_body(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
         values=lambda rows, slot: v_buf[slot])
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
+@functools.partial(jax.jit, static_argnames=("scale", "kv_heads"))
 def _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables, kv_len, *,
-                         scale):
+                         scale, kv_heads):
     """A decode step's attention over a K / V pool as one kernel a layer,
     of this file's own: `q` (S, 1, H, D) against the pools where they lie,
-    (L, N, block_size, Hkv, D) in HBM, with `layer`, the tables and the
-    lengths as scalars the kernel reads.  A live page of K and the same
-    page of V are one DMA each into their halves of two VMEM buffers;
-    operands in the pool's dtype, accumulation, statistics and rescaling
-    float32, the scale applied to the float32 scores
+    (L, N, block_size, Hkv, D) or those pages kept as rows (L, N,
+    block_size x Hkv, D) in HBM (`kv_heads` = Hkv), with `layer`, the
+    tables and the lengths as scalars the kernel reads.  A live page of K
+    and the same page of V are one DMA each into their halves of two VMEM
+    buffers; operands in the pool's dtype, accumulation, statistics and
+    rescaling float32, the scale applied to the float32 scores
     (`_latent_decode_kernel`'s convention and pipeline:
     `_paged_decode_body`).  Only a lane's live pages are read; an idle
     lane (`kv_len` 0) reads none and gets 0.  Jitted, as the latent
     kernel and for its reason.  Returns (S, 1, H, D) float32."""
     s, _, h, d = q.shape
-    n_layers, n_blocks, bs, hkv, _ = k_pool.shape
+    # Rows of (position, KV head): the bytes of a page as they are stored,
+    # and the pool's own shape where `pages_as_rows` allocated it.
+    stored = (*k_pool.shape[:2], math.prod(k_pool.shape[2:-1]), d)
+    bs = stored[2] // kv_heads
     pages = max(1, min(_PAGED_KERNEL_PAGES, block_tables.shape[1],
-                       _PAGED_KERNEL_ROWS // (bs * hkv)))
-    rows = pages * bs * hkv
-    # Rows of (position, KV head): the bytes of a page as they are stored.
-    stored = (n_layers, n_blocks, bs * hkv, d)
+                       _PAGED_KERNEL_ROWS // stored[2]))
+    rows = pages * stored[2]
     out = pl.pallas_call(
         functools.partial(_kv_decode_body, pages=pages, bs=bs, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -314,7 +314,8 @@ def _padded_tail_advances(monkeypatch, cfg):
 def _usual_attention_scale(monkeypatch, cfg):
     inner = mamba2_moe.paged_attention
     monkeypatch.setattr(
-        mamba2_moe, "paged_attention", lambda *a, scale=None: inner(*a))
+        mamba2_moe, "paged_attention",
+        lambda *a, scale=None, **kw: inner(*a, **kw))
 
 
 def _residual_multiplier_dropped(monkeypatch, cfg):

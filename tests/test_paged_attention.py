@@ -61,10 +61,22 @@ def ref_attention(q, k_layer, v_layer, block_tables, positions):
                       vh.astype(jnp.float32))
 
 
+def _by_position(cache, cfg):
+    """`cache`'s pool as (L, N, block_size, Hkv, D), whichever way
+    `init_paged_cache` stores a page: kept as rows, row t x Hkv + g is
+    position t of KV head g."""
+    def view(a):
+        return a.reshape(*a.shape[:2], -1, cfg.n_kv_heads, cfg.head_dim)
+
+    return PagedKVCache(k=view(cache.k), v=view(cache.v))
+
+
 def ref_forward(params, cache, tokens, block_tables, positions, active, cfg):
     """tokens (S,K) -> (cache, logits (S,K,vocab)), the pool slices going
     through the layer scan as xs / ys as they used to."""
     cd = cfg.compute_dtype
+    stored = cache.k.shape
+    cache = _by_position(cache, cfg)
     bs = cache.k.shape[2]
     x = params["embed"].astype(cd)[tokens]
     wb = jnp.take_along_axis(block_tables, positions // bs, axis=1)
@@ -85,7 +97,8 @@ def ref_forward(params, cache, tokens, block_tables, positions, active, cfg):
     blocks, experts = decoding._layer_xs(params["blocks"], cfg)
     x, (k, v) = jax.lax.scan(
         layer, x, (blocks, jnp.arange(cfg.n_layers), cache.k, cache.v))
-    return PagedKVCache(k=k, v=v), decoding._final_logits(params, x, cfg)
+    return (PagedKVCache(k=k.reshape(stored), v=v.reshape(stored)),
+            decoding._final_logits(params, x, cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -122,7 +135,7 @@ def _body_case(lengths, k_w, rep, dtype, seed=0, b_max=B_MAX):
     layer = 1
     # A new function each time: `jax.jit` of the same one would hand back
     # the trace made under another `_PAGED_GROUP_BLOCKS`.
-    got = jax.jit(lambda *a: attention.paged_attention(*a))(
+    got = jax.jit(lambda *a: attention.paged_attention(*a, kv_heads=hkv))(
         q, k_pool, v_pool, jnp.int32(layer), jnp.asarray(tables),
         jnp.asarray(positions), jnp.asarray(kv_len))
     want = ref_attention(q, k_pool[layer], v_pool[layer],
@@ -213,7 +226,10 @@ def test_body_all_lanes_idle_is_finite(group_blocks):
 # ---------------------------------------------------------------------------
 # (pages a step, table entries, Hkv, rep, lane lengths, dtype, scale, the
 # lanes' blocks in table order: None = shuffled, an int = that one block
-# for every entry).  Lengths count the step's own token; 0: an idle lane.
+# for every entry, D: 128 unless given).  Lengths count the step's own
+# token; 0: an idle lane.  Fewer than 4 KV heads: the pool is kept as rows
+# (`attention.pages_as_rows`, `init_paged_cache`'s rule) and handed over
+# as it is.
 KERNEL_CASES = {
     "whole_steps_and_a_part": (2, 7, 8, 4, [32, 0, 64, 100, 1], "bf16",
                                None, None),
@@ -229,7 +245,20 @@ KERNEL_CASES = {
     "a_handed_over_scale": (2, 5, 8, 4, [79, 2], "bf16", 1.0 / 128, None),
     "float32_rows": (2, 5, 8, 4, [80, 31], "f32", None, None),
     "the_row_cap_sets_the_step": (2, 7, 8, 4, [100, 33], "bf16", None, None),
+    # a lane over four steps, an idle one, one shorter than a page
+    "two_heads_of_256_as_rows": (2, 7, 2, 8, [100, 0, 5, 64, 33], "bf16",
+                                 None, None, 256),
+    "two_heads_of_128_as_rows": (4, 6, 2, 8, [96, 7, 0], "bf16", None, None),
+    "one_head_float32_as_rows": (2, 5, 1, 8, [70, 16], "f32", None, None),
 }
+
+
+def _stored(pool, hkv, d):
+    """A pool drawn as (L, N, block_size, Hkv, D), as `init_paged_cache`
+    would keep it."""
+    if attention.pages_as_rows(hkv, d, BS, pool.dtype):
+        return pool.reshape(*pool.shape[:2], BS * hkv, d)
+    return pool
 
 
 @pytest.mark.parametrize("case", list(KERNEL_CASES))
@@ -241,7 +270,7 @@ def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
     float32 out, (S, 1, H, D), the pools untouched."""
     from jax.experimental.pallas import tpu as pltpu
 
-    pages, entries, hkv, rep, lengths, dtype, scale, block = \
+    pages, entries, hkv, rep, lengths, dtype, scale, block, *d = \
         KERNEL_CASES[case]
     dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
     if case == "the_row_cap_sets_the_step":
@@ -249,7 +278,7 @@ def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
                             pages * BS * hkv)
     else:
         monkeypatch.setattr(attention, "_PAGED_KERNEL_PAGES", pages)
-    d, s = 128, len(lengths)
+    (d,), s = d or (128,), len(lengths)
     tables, positions, kv_len = _lanes(
         [n - 1 if n else None for n in lengths], 1,
         np.random.default_rng(len(case)), entries)
@@ -258,8 +287,10 @@ def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
     kq, kk, kv = jax.random.split(jax.random.key(len(case)), 3)
     shape = (3, 1 + s * entries, BS, hkv, d)
     q = jax.random.normal(kq, (s, 1, hkv * rep, d), dtype)
-    k_pool = jax.random.normal(kk, shape, dtype)
-    v_pool = jax.random.normal(kv, shape, dtype)
+    by_position = (jax.random.normal(kk, shape, dtype),
+                   jax.random.normal(kv, shape, dtype))
+    k_pool, v_pool = (_stored(pool, hkv, d) for pool in by_position)
+    assert (k_pool.ndim == 4) == (hkv < 4)
     before = np.asarray(k_pool, np.float32), np.asarray(v_pool, np.float32)
     assert list(kv_len) == lengths
     layer, tables = jnp.int32(2), jnp.asarray(tables)
@@ -267,7 +298,7 @@ def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
     with pltpu.force_tpu_interpret_mode():
         got = attention._paged_decode_kernel(
             q, k_pool, v_pool, layer, tables, kv_len,
-            scale=d ** -0.5 if scale is None else scale)
+            scale=d ** -0.5 if scale is None else scale, kv_heads=hkv)
     assert got.shape == (s, 1, hkv * rep, d) and got.dtype == jnp.float32
     live = np.asarray(kv_len) > 0
     assert not np.asarray(got[~live]).any()
@@ -277,10 +308,15 @@ def test_the_decode_kernel_agrees_with_the_block_loop(monkeypatch, case):
         return
     want = attention.paged_attention(
         q, k_pool, v_pool, layer, tables, jnp.asarray(positions), kv_len,
-        scale=scale)
+        scale=scale, kv_heads=hkv)
     assert want.shape == got.shape
     rms = float(jnp.sqrt(jnp.mean(want[live] ** 2)))
     assert float(jnp.abs(got - want)[live].max()) < 1e-2 * rms
+    if scale is None:         # and the loop, over either layout, the formula
+        plain = ref_attention(q, by_position[0][2], by_position[1][2], tables,
+                              jnp.asarray(positions))
+        np.testing.assert_allclose(np.asarray(want)[live],
+                                   np.asarray(plain)[live], atol=ATOL, rtol=0)
 
 
 def _no_kernel(monkeypatch):
@@ -300,10 +336,13 @@ def test_a_head_that_is_not_whole_tiles_warns_and_takes_the_loop(
     np.testing.assert_allclose(got[live], want[live], atol=ATOL, rtol=0)
 
 
+@pytest.mark.parametrize("as_rows", [False, True],
+                         ids=["by_position", "as_rows"])
 @pytest.mark.parametrize("k_w", [4, 32], ids=["verify", "chunk"])
-def test_a_chunk_never_reaches_the_kernel(monkeypatch, k_w):
+def test_a_chunk_never_reaches_the_kernel(monkeypatch, k_w, as_rows):
     """More than one query row a lane (a verify step, a prefill chunk)
-    keeps the loop whatever the head size, and says nothing."""
+    keeps the loop whatever the head size and however the pages are
+    kept, and says nothing."""
     import warnings
 
     _no_kernel(monkeypatch)
@@ -317,8 +356,10 @@ def test_a_chunk_never_reaches_the_kernel(monkeypatch, k_w):
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         got = attention.paged_attention(
-            q, k_pool, v_pool, jnp.int32(1), jnp.asarray(tables),
-            jnp.asarray(positions), jnp.asarray(kv_len))
+            q, *((_stored(pool, 2, 128) if as_rows else pool)
+                 for pool in (k_pool, v_pool)),
+            jnp.int32(1), jnp.asarray(tables), jnp.asarray(positions),
+            jnp.asarray(kv_len), kv_heads=2)
     want = ref_attention(q, k_pool[1], v_pool[1], jnp.asarray(tables),
                          jnp.asarray(positions))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL,
@@ -328,17 +369,21 @@ def test_a_chunk_never_reaches_the_kernel(monkeypatch, k_w):
 # ---------------------------------------------------------------------------
 # the three programs against the reference forward
 # ---------------------------------------------------------------------------
-@pytest.fixture(scope="module", params=["tiny-mha", "tiny-gqa4", "tiny-moe"])
+@pytest.fixture(scope="module",
+                params=["tiny-mha", "tiny-gqa4", "tiny-moe", "tiny-rows"])
 def model(request):
-    """`tiny` with 4 query heads on 4 / 1 KV heads and `tiny-moe` (4 on 4,
-    4 experts top-2), float32 throughout so that the comparison is of the
-    algorithm, not of roundings."""
+    """`tiny` with 4 query heads on 4 / 1 KV heads, `tiny-moe` (4 on 4,
+    4 experts top-2) and `tiny` with its 2 KV heads of 128, whose pool is
+    kept as rows (`attention.pages_as_rows`); float32 throughout so that
+    the comparison is of the algorithm, not of roundings."""
     name, _, variant = request.param.rpartition("-")
     cfg = configs.get(request.param if variant == "moe" else name)
     if variant != "moe":
-        cfg = dataclasses.replace(
-            cfg, n_kv_heads={"mha": 4, "gqa4": 1}[variant])
+        cfg = dataclasses.replace(cfg, **{
+            "mha": {"n_kv_heads": 4}, "gqa4": {"n_kv_heads": 1},
+            "rows": {"d_head": 128}}[variant])
     cfg = dataclasses.replace(cfg, compute_dtype=jnp.float32)
+    assert (init_paged_cache(cfg, 2, BS).k.ndim == 4) == (variant == "rows")
     return cfg, init_params(jax.random.key(1), cfg)
 
 
@@ -461,8 +506,9 @@ def test_burst_pool_equals_steps_and_spares_other_blocks(model):
                                       np.asarray(before[:, spare]))
 
 
-def _changed(before, after):
+def _changed(before, after, cfg):
     """The pool entries {(layer, block, offset)} at which two caches differ."""
+    before, after = _by_position(before, cfg), _by_position(after, cfg)
     diff = (np.asarray(before.k) != np.asarray(after.k)).any(axis=(3, 4))
     diff |= (np.asarray(before.v) != np.asarray(after.v)).any(axis=(3, 4))
     return {tuple(int(i) for i in idx) for idx in np.argwhere(diff)}
@@ -505,7 +551,7 @@ def test_step_changes_the_entries_it_writes_and_no_other(model, caller):
             params, cache, toks[0], jnp.asarray(tables[0]), lens[0],
             jnp.int32(k_w), cfg=cfg)
     positions = np.asarray(lengths)[:, None] + np.arange(k_w)
-    changed = _changed(cache, after)
+    changed = _changed(cache, after, cfg)
     assert changed == _entries(cfg, tables, positions)
     assert all(block != 0 for _, block, _ in changed)
 
@@ -520,7 +566,7 @@ def test_idle_lane_and_chunk_padding_write_the_null_block_only(model):
     after, _ = paged_decode_step(
         params, cache, jnp.asarray([3, 4], jnp.int32), jnp.asarray(tables),
         jnp.asarray([5, 9], jnp.int32), jnp.asarray([True, False]), cfg=cfg)
-    changed = _changed(cache, after)
+    changed = _changed(cache, after, cfg)
     mine = _entries(cfg, tables, np.array([[5]]))
     assert mine <= changed
     assert all(block == 0 for _, block, _ in changed - mine)
@@ -533,10 +579,41 @@ def test_idle_lane_and_chunk_padding_write_the_null_block_only(model):
     row[0] = tables[0, 0]
     after, _ = paged_prefill_chunk(params, cache, toks, jnp.asarray(row),
                                    jnp.int32(0), jnp.int32(12), cfg=cfg)
-    changed = _changed(cache, after)
+    changed = _changed(cache, after, cfg)
     mine = _entries(cfg, row[None], np.arange(16)[None])
     assert mine <= changed
     assert all(block == 0 for _, block, _ in changed - mine)
+
+
+def test_a_block_copied_and_a_frame_out_and_in_again_read_the_same(model):
+    """The block operations index `[:, block]` and take a page however it
+    is kept: a lane whose last block was copied (`copy_block`) and its
+    table pointed at the copy, and lanes whose blocks went out as a frame
+    (`gather_blocks`) and into an empty pool (`scatter_blocks`), decode
+    to the logits they decoded to, bit for bit."""
+    cfg, params = model
+    cache, toks, tables, lengths, active = _decode_inputs(cfg, 1)
+
+    def step(cache, tables):
+        return np.asarray(paged_decode_step(
+            params, cache, toks[:, 0], jnp.asarray(tables), lengths, active,
+            cfg=cfg)[1])[np.asarray(active)]
+
+    want = step(cache, tables)
+    tables = np.array(tables)
+    lane, entry = 4, int(lengths[4]) // BS          # 70 positions: entry 4
+    spare = int(np.setdiff1d(np.arange(1, cache.k.shape[1]), tables)[0])
+    copied = decoding.copy_block(cache, jnp.int32(spare),
+                                 jnp.int32(tables[lane, entry]))
+    tables[lane, entry] = spare
+    np.testing.assert_array_equal(step(copied, tables), want)
+    owned = np.unique(tables[tables > 0])
+    frame = np.asarray(decoding.gather_blocks(copied, owned))
+    empty = init_paged_cache(cfg, cache.k.shape[1], BS)
+    assert decoding.frame_fits(empty, frame.shape)
+    assert frame.shape[:3] == (2, cfg.n_layers, len(owned))
+    np.testing.assert_array_equal(
+        step(decoding.scatter_blocks(empty, owned, frame), tables), want)
 
 
 # ---------------------------------------------------------------------------

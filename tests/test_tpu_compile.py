@@ -268,11 +268,14 @@ def _assert_pool_read_by_the_kernel(text, pool_shape, calls: int):
     """`calls` sites of `text` read a K / V pool of `pool_shape` by the
     decode kernel (`ops.attention._paged_decode_kernel`), each handed K
     and V once as they are stored: rows of (position, KV head) x D, a
-    bitcast of the pool and no copy of it."""
+    bitcast of the pool and no copy of it.  A pool kept as those rows
+    (`ops.attention.pages_as_rows`: four dimensions) is handed over as it
+    is, and nothing makes an array of its shape but the scatter that
+    writes it in place (a `fusion` of that shape roots one)."""
     import re
 
-    n_layers, n_blocks, bs, hkv, d = pool_shape
-    stored = f"bf16[{n_layers},{n_blocks},{bs * hkv},{d}]"
+    n_layers, n_blocks, *page, d = pool_shape
+    stored = f"bf16[{n_layers},{n_blocks},{math.prod(page)},{d}]"
     reads = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
              and "paged_decode_attention" in line]
@@ -281,9 +284,16 @@ def _assert_pool_read_by_the_kernel(text, pool_shape, calls: int):
         operands = call.split("operand_layout_constraints=")[1].split(
             "metadata=")[0]
         assert operands.count(stored) == 2, call
-    makers = set(re.findall(r"= " + re.escape(stored) + r"\S* ([a-z-]+)\(",
-                            text))
-    assert makers <= {"bitcast"}, makers
+    made = r"= " + re.escape(stored) + r"\S* "
+    makers = set(re.findall(made + r"([a-z-]+)\(", text))
+    if len(page) == 2:
+        assert makers <= {"bitcast"}, makers
+        return
+    assert makers <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                      "bitcast"}, makers
+    for fused in re.findall(made + r"fusion\(.*?calls=%([\w.]+)", text):
+        body = text.split(f"\n%{fused} (")[1].split("\n}")[0]
+        assert re.search(r"ROOT %\S+ " + made + r"scatter\(", body), fused
 
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
@@ -1202,13 +1212,18 @@ def test_gated_delta_served_programs_fit_one_chip(topo, program):
     chunk of 512 rows (eight chunks of the delta rule, the state handed on
     in an unrolled scan) compile for one v5e chip and fit its 15.75 GB
     usable.  Pool, state and conv rows are updated in place (their bytes
-    are aliased).  **The full layers' pool has 2 KV heads of 256**: the
-    compiler stores it in tiles of (2, 128), the decode kernel's rows of
-    (position, KV head) would be a copy of a layer's whole pool a step
-    (bf16[2,8193,32,256], 0.27 GB for K and again for V: 1.49 s of a 3 s
-    trace on the chip, PR 64), so the burst reads it by the loop
-    (`ops.attention._PAGED_KERNEL_MIN_KV_HEADS`) and nothing of that shape
-    is made.  The expert products read the held stacks in place, as
+    are aliased).  **The full layers' pool has 2 KV heads of 256 and is
+    kept as the decode kernel reads it**, rows of (position, KV head),
+    bf16[2,8193,32,256] in whole (16, 128) tiles
+    (`ops.attention.pages_as_rows`; kept by position, (16, 2, 256), the
+    compiler stores it in tiles of (2, 128) and the kernel's view was a
+    copy of a layer's whole pool a step, 0.27 GB for K and again for V:
+    1.49 s of a 3 s trace on the chip, PR 64): the burst reads it by the
+    kernel, one call site in the scan over the periods, K and V handed
+    over as stored; nothing of the pool's shape is made but by the
+    in-place scatter, in the burst and in the chunk, which reads groups
+    of pages by the loop (no kernel call), and the temporaries stay under
+    one pool.  The expert products read the held stacks in place, as
     Laguna's (same expert, 6.3 MB)."""
     import json
     import re
@@ -1231,7 +1246,7 @@ def test_gated_delta_served_programs_fit_one_chip(topo, program):
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     state, params = resident["sequence_state"], resident["params"]
-    assert state.k.shape == (2, 8193, 16, 2, 256) and state.wk is None
+    assert state.k.shape == (2, 8193, 32, 256) and state.wk is None
     assert state.lstate.shape == (6, 9, 32, 128, 128)
     assert state.lstate.dtype == jnp.float32
     assert state.lconv.shape == (6, 9, 3, 8192)
@@ -1249,8 +1264,8 @@ def test_gated_delta_served_programs_fit_one_chip(topo, program):
     assert mem.alias_size_in_bytes >= state_bytes
     assert _device_bytes(compiled) < 15.75e9
     assert mem.temp_size_in_bytes < state.k.size * 2, mem.temp_size_in_bytes
-    _assert_pool_read_by_the_kernel(text, state.k.shape, 0)
-    assert not re.search(r"= bf16\[2,8193,32,256\]", text)
+    _assert_pool_read_by_the_kernel(
+        text, state.k.shape, 1 if program == "paged_decode_burst" else 0)
     _assert_experts_read_in_place(text, fam.expert_operand(config), program,
                                   visit_sites=4)
     # the lanes' state (the burst) or the slot's (the chunk) is there in
