@@ -306,6 +306,35 @@ QWEN3_NEXT_80B_A3B = dataclasses.replace(
     rope_theta=10000000.0, max_seq_len=262144, param_dtype=jnp.bfloat16,
     compute_dtype=jnp.bfloat16)
 
+# A fourth kind of layer, at test size: two leading conv layers (gated
+# short convolutions of 3 rows, `ops.short_conv`) with a dense FFN, two
+# periods of one full layer (QK-norm, heads of 16) to three conv layers,
+# and a tail that is no prefix of a period; 8 experts top-4 by sigmoid
+# scores under a selection bias, all held; the head is the embedding.
+# float32, so that a test holds it to the reference's logits.
+TINY_SHORT_CONV_MOE = TransformerConfig(
+    name="tiny-short-conv-moe", vocab_size=512, d_model=64, n_layers=13,
+    n_heads=4, n_kv_heads=2, d_head=16, d_ff=160, d_expert=24, n_experts=8,
+    expert_top_k=4, expert_scoring="sigmoid", router_bias=True, qk_norm=True,
+    lead_pattern=("conv", "conv"),
+    layer_pattern=("full", "conv", "conv", "conv"),
+    layer_tail=("full", "conv", "full"), conv_kernel=3, rope_theta=10000.0,
+    norm_eps=1e-5, tie_embeddings=True, max_seq_len=512, remat=False,
+    param_dtype=jnp.float32, compute_dtype=jnp.float32,
+)
+
+# LFM2-8B-A1B's published sizes (8.34 B parameters, ~1.5 B active a
+# token): `layer_types` item by item, 18 conv layers and 6 full layers of
+# 32 query / 8 KV heads of 64; the two leading layers' FFN dense at 7168,
+# the other 22 of 32 experts of width 1792, top-4, every one held.
+LFM2_8B_A1B = dataclasses.replace(
+    TINY_SHORT_CONV_MOE, name="lfm2-8b-a1b", vocab_size=65536, d_model=2048,
+    n_layers=24, n_heads=32, n_kv_heads=8, d_head=64, d_ff=7168,
+    d_expert=1792, n_experts=32,
+    layer_tail=("full", "conv", "conv", "full", "conv", "conv"),
+    rope_theta=1000000.0, max_seq_len=128000, param_dtype=jnp.bfloat16,
+    compute_dtype=jnp.bfloat16)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
@@ -318,7 +347,8 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_GROUP_MOE, DEEPSEEK_V3_2_EXP,
                                 TINY_GATED_MOE, LAGUNA_XS_2,
                                 TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B,
-                                TINY_GATED_DELTA_MOE, QWEN3_NEXT_80B_A3B]}
+                                TINY_GATED_DELTA_MOE, QWEN3_NEXT_80B_A3B,
+                                TINY_SHORT_CONV_MOE, LFM2_8B_A1B]}
 
 
 def get(name: str):

@@ -34,7 +34,11 @@ a float32 state a head and the last rows of a short convolution
 (`PagedKVCache.lstate` / `.lconv`): recurrent state, which the engine
 zeroes when a slot changes hands (`TransformerConfig.reset_slot`), which a
 launch of m x `linear_chunk` rows carries from chunk to chunk inside the
-program, and which a row that is not valid leaves as it was.
+program, and which a row that is not valid leaves as it was.  A model with
+conv layers (`ops.short_conv`) keeps for those, leading ones too, the
+convolution's last rows alone, in the same leaf `lconv` (one stack a
+model: the rows `ops.gated_delta.causal_conv` continues from, whichever
+mixer calls it, zeroed and counted as the linear layers' are).
 
 A model that generates by diffusion over blocks
 (`TransformerConfig.diffusion_block` = B) runs the same body under
@@ -63,7 +67,7 @@ import jax.numpy as jnp
 from ray_tpu.models.transformer import (
     TransformerConfig, gain_of, qk_normed)
 from ray_tpu.ops.attention import (
-    paged_attention, pages_as_rows, ring_rows, slot_ring_reader,
+    page_rows, paged_attention, pages_as_rows, ring_rows, slot_ring_reader,
     window_attention)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rotary import apply_rope
@@ -178,6 +182,24 @@ def _linear_mixer(bp, x, conv_rows, state, valid, cfg):
     return out, conv_rows, state
 
 
+def _conv_mixer(bp, x, conv_rows, valid, cfg):
+    """A conv layer's mixer (`ops.short_conv`) over x (S, K, d), from the
+    lanes' conv rows (S, J - 1, d): a decode step and a chunk of any K
+    alike.  The rows kept are the last valid ones' (`valid` (S, K): the
+    valid ones are a prefix).  Returns (out (S, K, d), conv rows)."""
+    from ray_tpu.ops.short_conv import gated_short_conv
+
+    cd = cfg.compute_dtype
+    u = rms_norm(x, gain_of(bp["attn_norm"], cfg), eps=cfg.norm_eps)
+    bcz = jnp.einsum("skd,de->ske", u, bp["in_proj"].astype(cd))
+    with jax.named_scope("short_conv"):
+        y, conv_rows = gated_short_conv(
+            conv_rows, bcz, bp["conv_w"],
+            jnp.sum(valid, axis=1).astype(jnp.int32))
+    return jnp.einsum("ske,ed->skd", y.astype(cd),
+                      bp["out_proj"].astype(cd)), conv_rows
+
+
 def _swiglu(bp, h, cd, prefix="w_"):
     gate = jnp.einsum("btd,df->btf", h, bp[prefix + "gate"].astype(cd))
     up = jnp.einsum("btd,df->btf", h, bp[prefix + "up"].astype(cd))
@@ -205,8 +227,11 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
 
         counted = counts_routed(cfg)
         with jax.named_scope("moe"):
+            bias = {"router_bias": bp["router_bias"]} \
+                if "router_bias" in bp else {}
             out, visited, *more = moe_mlp_dropless(
-                h, {"router": bp["router"], **experts}, cfg.moe, live=live,
+                h, {"router": bp["router"], **bias, **experts}, cfg.moe,
+                live=live,
                 layer=li, return_routing=routing, return_routed=counted)
         if cfg.d_shared:
             with jax.named_scope("shared_mlp"):
@@ -318,7 +343,9 @@ class PagedKVCache:
     # whole tiles as the compiler stores it and is as the rows the decode
     # kernel reads (`ops.attention.pages_as_rows`; `init_paged_cache`
     # decides), (L_full, N_blocks, block_size x Hkv, D): row t x Hkv + g
-    # is position t of KV head g.  `_paged_forward`'s write and
+    # is position t of KV head g; of heads of half a lane tile,
+    # (L_full, N_blocks, block_size x Hkv / 2, 128), two of a position's
+    # heads side by side.  `_paged_forward`'s write and
     # `paged_attention` know which; the block operations index [:, block].
     k: jax.Array
     v: jax.Array
@@ -328,6 +355,8 @@ class PagedKVCache:
     wv: Optional[jax.Array] = None
     # The linear layers' recurrent state, by slot (the null slot last):
     # the last conv inputs and the state a head; None without such layers.
+    # A model with conv layers keeps theirs in `lconv`, (L_conv, S + 1,
+    # conv_kernel - 1, d) with the leading layers' first, and no `lstate`.
     lconv: Optional[jax.Array] = None   # (L_linear, S + 1, J - 1, c)
     lstate: Optional[jax.Array] = None  # (L_linear, S + 1, Hv, d_k, d_v)
 
@@ -367,31 +396,38 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     (`paged_cache_shardings`) it is allocated directly sharded: a pool
     that fits only across chips never exists whole on chip 0.  On one
     chip (no `shardings`: they split the KV heads' axis) a page is kept
-    as rows of (position, KV head) where `ops.attention.pages_as_rows`
-    says so (fewer than 4 KV heads of whole lanes: `PagedKVCache.k`), a
-    rule of shapes, the same on every platform.  A model
+    as rows of whole lanes where `ops.attention.pages_as_rows` says so
+    (fewer than 4 KV heads of whole lanes, or heads of half a lane
+    tile: `PagedKVCache.k`), a rule of shapes, the same on every
+    platform.  A model
     with window layers also gets their rings, `num_slots` + 1 of
     window + `prefill_chunk` rows each (a row is a position's (Hkv, D),
     as in the pool: D is a whole lane tile or the layout is the
     compiler's and copied every step, `ops.attention` says).  A model
     with linear layers gets their conv rows (the cache dtype) and state
-    (`cfg.linear_state_dtype`), zero, `num_slots` + 1 of each."""
+    (`cfg.linear_state_dtype`), zero, `num_slots` + 1 of each; one with
+    conv layers their rows, likewise."""
     dtype = dtype or cfg.compute_dtype
     row = (cfg.n_kv_heads, cfg.head_dim)
     shape = (cfg.n_of("full"), num_blocks, block_size, *row)
     if shardings is None and pages_as_rows(*row, block_size, dtype):
-        shape = (*shape[:2], block_size * cfg.n_kv_heads, cfg.head_dim)
+        shape = (*shape[:2], *page_rows(*row, block_size))
     k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
     rings = {}
     if cfg.state_by_slot and not (num_slots and prefill_chunk):
         raise ValueError(f"{cfg.name!r} keeps state by slot (a ring for "
                          f"its window layers, a state for its linear "
-                         f"ones): num_slots and prefill_chunk size it")
+                         f"ones, rows for its conv ones): num_slots and "
+                         f"prefill_chunk size it")
     if cfg.window:
         ring = (cfg.n_of("window"), num_slots + 1,
                 cfg.window + prefill_chunk, *row)
         rings = {"wk": jnp.zeros(ring, dtype), "wv": jnp.zeros(ring, dtype)}
-    if cfg.recurrent:
+    if cfg.n_of("conv"):
+        rings = {"lconv": jnp.zeros(
+            (cfg.n_of("conv"), num_slots + 1, cfg.conv_kernel - 1,
+             cfg.d_model), dtype)}
+    elif cfg.recurrent:
         if prefill_chunk % cfg.linear_chunk:
             raise ValueError(
                 f"{cfg.name!r} carries its linear layers' state over "
@@ -501,8 +537,9 @@ def _take(tree, i):
 
 
 def _nth(i, per: int, rank: int):
-    """Index of the `rank`-th of `per` layers a period in period `i`."""
-    return i if per == 1 else i * per + rank
+    """Index of the `rank`-th of `per` layers a period in period `i` (a
+    `rank` of `per` or more: a tail that is no prefix of a period)."""
+    return i if per == 1 and not rank else i * per + rank
 
 
 def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
@@ -542,17 +579,20 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
                          f"needs the lanes' slots")
     cd = cfg.compute_dtype
     as_rows = cache.k.ndim == 4            # `ops.attention.pages_as_rows`
-    bs = cache.k.shape[2] // (cfg.n_kv_heads if as_rows else 1)
+    # Rows of a page a position fills: its KV heads, or 128-lane rows of
+    # two half-lane heads side by side.
+    per_pos = cfg.n_kv_heads * cfg.head_dim // cache.k.shape[3] \
+        if as_rows else 1
+    bs = cache.k.shape[2] // per_pos
     live_lane = kv_len > 0
     live = live_lane[:, None]
     wb = jnp.where(live, jnp.take_along_axis(
         block_tables, positions // bs, axis=1), 0)         # (S, K)
     off = jnp.where(live, positions % bs, 0)
     # Where a layer's tokens' (Hkv, D) go: [block, position], or the
-    # position's Hkv rows of a page kept as rows.
+    # position's rows of a page kept as rows.
     written = (wb, off) if not as_rows else (
-        wb[..., None], off[..., None] * cfg.n_kv_heads
-        + jnp.arange(cfg.n_kv_heads))
+        wb[..., None], off[..., None] * per_pos + jnp.arange(per_pos))
     x = params["embed"].astype(cd)[tokens]                 # (S, K, d)
     period, lead, tail = cfg.period, cfg.lead_pattern, cfg.tail_pattern
     per = {kind: period.count(kind) for kind in set(period)}
@@ -576,6 +616,12 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         kind (its layer of the pool or of the rings), the `li`-th of the
         expert stacks (None: a leading layer)."""
         x, k_pool, v_pool, wk, wv, visited, routed, lin = carry
+        if kind == "conv":
+            lconv, _ = lin
+            out, rows = _conv_mixer(bp, x, lconv[at, slots], valid_rows,
+                                    cfg)
+            return ffn((x + out, k_pool, v_pool, wk, wv, visited, routed,
+                        (lconv.at[at, slots].set(rows), None)), bp, li)
         if kind == "linear":
             lconv, lstate = lin
             out, rows, state = _linear_mixer(
@@ -587,6 +633,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)
         if kind == "full":
             with jax.named_scope("full_attn"):
+                if as_rows and k_pool.shape[3] != cfg.head_dim:
+                    # two half-lane heads side by side in a row
+                    k, v = (a.reshape(*a.shape[:2], per_pos, -1)
+                            for a in (k, v))
                 k_pool = k_pool.at[(at, *written)].set(
                     k.astype(k_pool.dtype))
                 v_pool = v_pool.at[(at, *written)].set(
@@ -621,17 +671,18 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         return (x + out, *state, visited + n,
                 routed if r is None else routed + r, lin), idx
 
-    def behind(i, j, kind, bps=None):
+    def behind(i, j, kind, bps=None, among=period):
         """Layer `j` of period `i` behind the leading layers (a traced
-        `i`: inside the scan; `cfg.n_periods`: the tail), as `one` takes
-        it: its weights (`bps` where the scan hands them), its kind, its
-        layer of the pool or of the rings, its index in the stacks."""
+        `i`: inside the scan; `cfg.n_periods`, `among` the tail's kinds:
+        the tail), as `one` takes it: its weights (`bps` where the scan
+        hands them), its kind, its layer of the pool or of the rings, its
+        index in the stacks."""
         li = _nth(i, len(period), j)
         # A period's layers index the stacks themselves: the scan's
         # slice of a period, (p, ..), is copied out before a layer of
         # it can be taken (AOT for a v5e, PR 34).
         bp = bps if bps is not None else _take(blocks, li)
-        at = _nth(i, per[kind], period[:j].count(kind))
+        at = _nth(i, per[kind], among[:j].count(kind))
         if "kinds" in params:
             bp = {**bp, **_take(params["kinds"][kind], at)}
         if kind in lead:                 # the leading layers' come first
@@ -658,7 +709,7 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     if routing:                      # (periods, p, S, K, k) -> (L, S, K, k)
         taken = taken.reshape(cfg.n_periods * len(period), *taken.shape[2:])
     for j, kind in enumerate(tail):
-        carry, idx = one(carry, *behind(cfg.n_periods, j, kind))
+        carry, idx = one(carry, *behind(cfg.n_periods, j, kind, among=tail))
         if routing:
             taken = jnp.concatenate([taken, idx[None]])
     x, k_pool, v_pool, wk, wv, visited, routed, lin = carry

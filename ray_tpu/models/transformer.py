@@ -29,6 +29,11 @@ reference: python/ray/train/torch/train_loop_utils.py:158):
   a short convolution.  It has no head, rope or `wq` of an attention
   layer, so in such a model everything a kind's mixer owns is stacked by
   kind and `blocks` keeps what every layer shares.  Served only.
+- **A fourth kind keeps two rows**: a `conv` layer is a gated short
+  convolution (`ops.short_conv`; LFM2's mixer): `[B | C | z]` of the
+  normed input, a depth-wise causal convolution of `conv_kernel` rows over
+  `B * z`, times `C`.  By the engine's slot it keeps the convolution's
+  last inputs and nothing else; it may lead the stack.  Served only.
 - **What differs by layer lives outside the layers' stacks**: leading
   layers whose FFN is dense (`lead_pattern`) are blocks of their own
   before the scan; where the kinds differ in query heads
@@ -161,6 +166,18 @@ class TransformerConfig:
     # The shared expert's output times sigmoid(the FFN's normed input x
     # `shared_scale`), one value a token; served only.
     shared_gate: bool = False
+    # A "conv" layer (`ops.short_conv`), served only: the rows of its
+    # depth-wise causal convolution, the current one counted (a slot keeps
+    # `conv_kernel` - 1 rows of `d_model` a layer).
+    conv_kernel: int = 3
+    # The kinds of the layers behind the last whole period, where they
+    # are not the first layers of one more: (): `tail_pattern` follows
+    # from `n_layers`.
+    layer_tail: Tuple[str, ...] = ()
+    # A selection bias a routed expert (`blocks["router_bias"]`, float32):
+    # the top-k are taken of the sigmoid scores + it, the gates are the
+    # scores' (`ops.moe.MoEConfig.scoring` "sigmoid"); served only.
+    router_bias: bool = False
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)
@@ -168,15 +185,24 @@ class TransformerConfig:
         object.__setattr__(self, "layer_pattern", pattern)
         object.__setattr__(self, "lead_pattern", lead)
         object.__setattr__(self, "attn_gate", int(self.attn_gate))
-        if set(pattern) - {"full", "window", "linear"} \
-                or set(lead) - {"full", "window"}:
+        tail = tuple(self.layer_tail)
+        object.__setattr__(self, "layer_tail", tail)
+        if set(pattern) - {"full", "window", "linear", "conv"} \
+                or set(lead) - {"full", "window", "conv"}:
             raise ValueError(f"layer_pattern {pattern}, lead_pattern {lead}: "
-                             f"a layer is 'full', 'window' or, behind the "
-                             f"leading ones, 'linear'")
-        if pattern and not lead and self.n_layers % len(pattern):
+                             f"a layer is 'full', 'window', 'conv' or, "
+                             f"behind the leading ones, 'linear'")
+        if pattern and not lead and not tail \
+                and self.n_layers % len(pattern):
             raise ValueError(f"n_layers {self.n_layers} is not whole "
                              f"periods of {pattern}")
-        if len(lead) >= self.n_layers:
+        if tail and (set(tail) - set(pattern) or (
+                self.n_layers - len(lead) - len(tail)) % len(pattern)):
+            raise ValueError(
+                f"layer_tail {tail} stands behind whole periods of "
+                f"{pattern} and is made of their kinds: n_layers "
+                f"{self.n_layers}, lead_pattern {lead}")
+        if len(lead) + len(tail) >= self.n_layers:
             raise ValueError(f"lead_pattern {lead} leaves none of "
                              f"{self.n_layers} layers to the periods")
         if ("window" in pattern + lead) != (self.window > 0):
@@ -208,6 +234,26 @@ class TransformerConfig:
                     "of a launch and its rows are seen once: a window "
                     "layer's ring is laid out by a launch's rows and a "
                     "block's passes run its rows again")
+        if "conv" in pattern + lead:
+            if self.conv_kernel < 2:
+                raise ValueError(f"conv_kernel {self.conv_kernel}: a conv "
+                                 f"layer convolves 2 rows or more")
+            if "linear" in pattern:
+                raise ValueError(
+                    "conv and linear layers in one pattern: a slot keeps "
+                    "one stack of convolution rows (`PagedKVCache.lconv`) "
+                    "and theirs differ in width")
+            if "window" in pattern + lead or self.diffusion_block:
+                raise ValueError(
+                    "a conv layer's rows are carried through a launch and "
+                    "seen once: a window layer's ring is laid out by a "
+                    "launch's rows and a block's passes run its rows again")
+        if self.router_bias and (self.n_experts <= 0
+                                 or self.expert_scoring != "sigmoid"):
+            raise ValueError("router_bias moves the selection among sigmoid "
+                             "scores: expert_scoring is "
+                             f"{self.expert_scoring!r}, n_experts "
+                             f"{self.n_experts}")
         block = self.diffusion_block
         if block:
             if not (block >= 2 and 1 <= self.denoise_steps <= block
@@ -238,13 +284,15 @@ class TransformerConfig:
     @property
     def n_periods(self) -> int:
         """Whole periods after the leading layers: the scan's length."""
-        return (self.n_layers - len(self.lead_pattern)) // len(self.period)
+        return (self.n_layers - len(self.lead_pattern)
+                - len(self.layer_tail)) // len(self.period)
 
     @property
     def tail_pattern(self) -> Tuple[str, ...]:
-        """The layers behind the last whole period: the first of one more."""
-        return self.period[:(self.n_layers - len(self.lead_pattern))
-                           % len(self.period)]
+        """The layers behind the last whole period: `layer_tail`, else the
+        first of one more."""
+        return self.layer_tail or self.period[
+            :(self.n_layers - len(self.lead_pattern)) % len(self.period)]
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -270,8 +318,8 @@ class TransformerConfig:
     @property
     def mixers_by_kind(self) -> bool:
         """Whether a kind's whole mixer is stacked by kind, `blocks`
-        keeping the norms and the FFN alone: a model with linear layers,
-        which share no weight of an attention layer's."""
+        keeping the norms and the FFN alone: a model with linear or conv
+        layers, which share no weight of an attention layer's."""
         return self.recurrent
 
     @property
@@ -295,30 +343,33 @@ class TransformerConfig:
     def state_by_slot(self) -> bool:
         """Whether a served sequence keeps more than pool blocks: a ring a
         slot for each window layer, a state and conv rows a slot for each
-        linear layer (`models.decoding`)."""
+        linear layer, conv rows for each conv layer (`models.decoding`)."""
         return "window" in self.period or self.recurrent
 
     @property
     def recurrent(self) -> bool:
         """Whether some of that is recurrent state, which the engine
         zeroes when a slot changes hands (`reset_slot`)."""
-        return "linear" in self.period
+        return "linear" in self.period \
+            or "conv" in self.period + self.lead_pattern
 
     @property
     def launch_spans_chunks(self) -> bool:
         """Whether a launch's rows lay none of the state by slot out: a
         launch of m x `linear_chunk` rows is m chunks of the rule, the
-        state handed on inside the program (the engine then builds launch
-        tiers above `prefill_chunk`)."""
+        state handed on inside the program, and a conv layer continues
+        its convolution over any number of rows (the engine then builds
+        launch tiers above `prefill_chunk`)."""
         return self.recurrent
 
     @staticmethod
     def reset_slot(cache, slot):
         """Zero one slot's recurrent state (a request is admitted to it,
         or a preempted stream will re-prefill)."""
-        return dataclasses.replace(
-            cache, lconv=cache.lconv.at[:, slot].set(0),
-            lstate=cache.lstate.at[:, slot].set(0))
+        return dataclasses.replace(cache, **{
+            name: getattr(cache, name).at[:, slot].set(0)
+            for name in ("lconv", "lstate")
+            if getattr(cache, name) is not None})
 
     def kv_read_tokens(self, lengths) -> int:
         """KV positions one decode step sees over lanes of `lengths`:
@@ -362,6 +413,8 @@ class TransformerConfig:
             + d * self.n_experts + 3 * d * self.d_shared
         total = v * d * (1 if self.tie_embeddings else 2) + d
         experts += d if self.shared_gate else 0
+        experts += self.n_experts if self.router_bias else 0
+        conv = 4 * d * d + self.conv_kernel * d
         inner = self.linear_v_heads * self.linear_d_v
         linear = d * (self.linear_conv_dim + inner + 2 * self.linear_v_heads) \
             + self.linear_conv * self.linear_conv_dim \
@@ -370,10 +423,10 @@ class TransformerConfig:
             h = self.heads(kind)
             dense = self.n_experts <= 0 or i < len(self.lead_pattern)
             total += 2 * d + (3 * d * f if dense else experts)
-            total += linear if kind == "linear" else (
-                d * h * self.head_dim * 2 + d * kv * 2
-                + d * h * self.attn_gate
-                + (2 * self.head_dim if self.qk_norm else 0))
+            attention = d * h * self.head_dim * 2 + d * kv * 2 \
+                + d * h * self.attn_gate \
+                + (2 * self.head_dim if self.qk_norm else 0)
+            total += {"linear": linear, "conv": conv}.get(kind, attention)
         return total
 
 
@@ -388,7 +441,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     norms and the FFN alone and `kinds[kind]` a kind's whole mixer: an
     attention layer's `wq`, `wk`, `wv`, `wo`, gate and QK-norm, a linear
     layer's `in_qkvz` ([q | k | v | z]), `in_ba` ([b | a]), `conv_w`,
-    `A_log`, `dt_bias` (float32 both), `gate_norm` and `out_proj`."""
+    `A_log`, `dt_bias` (float32 both), `gate_norm` and `out_proj`, a conv
+    layer's `in_proj` ([B | C | z]), `conv_w` and `out_proj`; a leading
+    conv layer's block holds those in place of an attention's."""
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     nkv = cfg.n_kv_heads
@@ -457,6 +512,13 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
                     k[7], (*l, dv), jnp.float32)).astype(dt),
                 "out_proj": dense(k[4], (*l, inner, d), inner)}
 
+    def conv(k, l):
+        """`l` conv layers' mixers."""
+        return {"in_proj": dense(k[1], (*l, d, 3 * d), d),
+                "conv_w": dense(k[3], (*l, cfg.conv_kernel, d),
+                                cfg.conv_kernel),
+                "out_proj": dense(k[4], (*l, d, d), d)}
+
     def swiglu(k, l, width, prefix="w_"):
         return {prefix + "gate": dense(k[5], (*l, d, width), d),
                 prefix + "up": dense(k[6], (*l, d, width), d),
@@ -482,6 +544,12 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
             blocks.update(swiglu(keys_of(98), l, cfg.d_shared, "shared_"))
         if cfg.shared_gate:
             blocks["shared_scale"] = dense(keys_of(97)[0], (*l, d, 1), d)
+        if cfg.router_bias:
+            # Away from 0, so that a comparison notices a selection made
+            # on the scores alone (`models.mla_moe.ROUTER_BIAS_STD` says
+            # why no wider).
+            blocks["router_bias"] = 0.05 * jax.random.normal(
+                keys_of(96)[0], (*l, e), jnp.float32)
     else:
         blocks.update(swiglu(keys, l, f))
     params = {
@@ -491,13 +559,18 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     }
     if n_lead:
         params["lead"] = [
-            {**attention(keys_of(100 + i), (), cfg.heads(kind)),
+            {**({**norms(keys_of(100 + i), ()), **conv(keys_of(100 + i), ())}
+                if kind == "conv"
+                else attention(keys_of(100 + i), (), cfg.heads(kind))),
              **swiglu(keys_of(100 + i), (), cfg.d_ff)}
             for i, kind in enumerate(cfg.lead_pattern)]
+
     def of_kind(k, n, kind):
         """What `kinds[kind]` stacks over the `n` layers of `kind`."""
         if kind == "linear":
             return linear(k, n)
+        if kind == "conv":
+            return conv(k, n)
         own = wide(k, n, cfg.heads(kind))
         return {**own, **narrow(k, n)} if cfg.mixers_by_kind else own
 
@@ -540,6 +613,11 @@ def param_logical_axes(cfg: TransformerConfig):
                 "gate_norm": (*l, "head_dim"),
                 "out_proj": (*l, "heads", "embed")}
 
+    def conv(l):
+        return {"in_proj": (*l, "embed", "heads"),
+                "conv_w": (*l, None, "embed"),
+                "out_proj": (*l, "heads", "embed")}
+
     def swiglu(l, prefix="w_"):
         return {prefix + "gate": (*l, "embed", "mlp"),
                 prefix + "up": (*l, "embed", "mlp"),
@@ -559,6 +637,8 @@ def param_logical_axes(cfg: TransformerConfig):
             blocks.update(swiglu(l, "shared_"))
         if cfg.shared_gate:
             blocks["shared_scale"] = ("layers", "embed", None)
+        if cfg.router_bias:
+            blocks["router_bias"] = ("layers", "expert")
     else:
         blocks.update(swiglu(l))
     axes = {
@@ -567,11 +647,13 @@ def param_logical_axes(cfg: TransformerConfig):
         "final_norm": ("embed",),
     }
     if cfg.lead_pattern:
-        axes["lead"] = [{**attention((), False), **swiglu(())}
-                        for _ in cfg.lead_pattern]
+        axes["lead"] = [{**({**norms(()), **conv(())} if kind == "conv"
+                            else attention((), False)), **swiglu(())}
+                        for kind in cfg.lead_pattern]
     behind = sorted(set(cfg.kinds[len(cfg.lead_pattern):]))
     if cfg.mixers_by_kind:
-        axes["kinds"] = {kind: linear(l) if kind == "linear"
+        own = {"linear": linear, "conv": conv}
+        axes["kinds"] = {kind: own[kind](l) if kind in own
                          else {**wide(l), **narrow(l)} for kind in behind}
     elif cfg.heads_by_kind:
         axes["kinds"] = {kind: wide(l) for kind in behind}
@@ -665,7 +747,9 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         ("expert_scoring", cfg.expert_scoring != "softmax"),
         ("route_scale", cfg.route_scale != 1.0),
         ("diffusion_block", cfg.diffusion_block),
-        ("layer_pattern 'linear'", cfg.recurrent),
+        ("layer_pattern 'linear'", "linear" in cfg.period),
+        ("layer_pattern 'conv'", "conv" in cfg.kinds),
+        ("router_bias", cfg.router_bias),
         ("norm_plus_one", cfg.norm_plus_one),
         ("shared_gate", cfg.shared_gate)) if differs]
     if served_only:

@@ -681,13 +681,43 @@ def _paged_running_softmax(k_pool, v_pool, layer, block_tables, positions,
 # keeps the KV heads' axis it is split on, and takes the loop anyway.
 def pages_as_rows(n_kv_heads: int, head_dim: int, block_size: int,
                   dtype) -> bool:
-    """Whether a K / V pool of such pages is allocated as the decode
-    kernel reads it, (L, N, block_size x Hkv, D): a page that is not whole
-    tiles as (block_size, Hkv, D) and is as rows of (position, KV head).
+    """Whether a K / V pool of such pages is allocated as rows of whole
+    lanes, (L, N, rows, lanes) = `page_rows`: a page that is not whole
+    tiles as (block_size, Hkv, D) and is as such rows.  Two kinds of page:
+    fewer than 4 KV heads of whole lanes (rows of (position, KV head), what
+    the decode kernel reads: above), and **heads of half a lane tile** (D
+    = 64: two heads of a position side by side in a row of 128 lanes, a
+    position's Hkv / 2 rows one after the other; narrower heads are the
+    test presets' alone and stay by position).
+    Kept by position, a pool of heads of 64 is stored with the blocks' axis
+    innermost (`bf16[3,8193,16,8,64]{1,4,3,2,0}`) and every program that
+    reads it copies it whole into tiles of (8, 128) with half of every
+    lane row empty, and back: K and V, 0.41 GB each way at LFM2-8B-A1B's
+    three full layers, a burst and a launch (AOT for a v5e, PR 67:
+    `copy.214` / `.215` / `.220` / `.221`, 1.63 GB of temporaries).  As
+    rows it is handed over and aliased as it is, and **a head of 64 gets
+    the decode kernel too** (`_paged_decode_side_by_side`: the kernel told
+    of Hkv / 2 heads of 128, a query zero in the other head's lanes); a
+    chunk's loop regroups each gathered group by head, never the pool.  A
+    pool of heads of 64 that is not kept so (an odd count of KV heads, a
+    page that is not whole sublanes, a pool split over a mesh) gets the
+    loop for a decode step too, and `paged_attention` says so once a call
+    site.
     `models.decoding.init_paged_cache` asks, for a pool on one chip."""
     sublanes = 32 // jnp.dtype(dtype).itemsize
-    return (n_kv_heads < 4 and head_dim % _LANES == 0
-            and (block_size * n_kv_heads) % sublanes == 0)
+    rows, lanes = page_rows(n_kv_heads, head_dim, block_size)
+    if head_dim % _LANES == 0:
+        return n_kv_heads < 4 and rows % sublanes == 0
+    return (2 * head_dim == _LANES and n_kv_heads % 2 == 0
+            and rows % sublanes == 0)
+
+
+def page_rows(n_kv_heads: int, head_dim: int, block_size: int):
+    """(rows, lanes) of a page kept as rows (`pages_as_rows`): a row is a
+    position's KV head of whole lanes, or two of a position's half-lane
+    heads side by side."""
+    lanes = max(head_dim, _LANES)
+    return block_size * n_kv_heads * head_dim // lanes, lanes
 
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
@@ -701,10 +731,12 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
     stored: no slice of it is taken out and no copy in another dtype is
     made.  A page is kept in one of two layouts, and whoever allocates the
     pool decides (`models.decoding.init_paged_cache`, by `pages_as_rows`):
-    by position, (L, N, block_size, Hkv, D), or as the rows the decode
-    kernel reads, (L, N, block_size x Hkv, D), row t x Hkv + g position t
-    of KV head g.  `kv_heads` is Hkv, the caller's (its configuration's):
-    the second layout does not show it.  Lane s owns the blocks
+    by position, (L, N, block_size, Hkv, D), or as rows of whole lanes:
+    (L, N, block_size x Hkv, D), row t x Hkv + g position t of KV head g
+    (the rows the decode kernel reads), or, of heads of half a lane tile,
+    (L, N, block_size x Hkv / 2, 128), two of a position's heads side by
+    side.  `kv_heads` is Hkv, the caller's (its configuration's): the
+    second layout does not show it.  Lane s owns the blocks
     `block_tables[s]` (S, B) in sequence order; its query i stands at
     `positions[s, i]` and sees the kv positions <= that, or <=
     `sees[s, i]` where given (the end of the query's block, K >= 2:
@@ -735,7 +767,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
     s, k_w, h, d = q.shape
     hkv = kv_heads
     rows = k_pool.ndim == 4                # `pages_as_rows`
-    bs = k_pool.shape[2] // hkv if rows else k_pool.shape[2]
+    bs = k_pool.shape[2] * k_pool.shape[3] // (hkv * d) if rows \
+        else k_pool.shape[2]
     if scale is None:
         scale = d ** -0.5
 
@@ -754,9 +787,15 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
             (hkv, h // hkv), d, block_size=bs)
         return out.reshape(s, k_w, h, d)
 
+    # KV heads a stored row holds side by side: 2 of half-lane heads kept
+    # as rows (`pages_as_rows`), else 1.
+    pack = k_pool.shape[3] // d if rows else 1
+
     def kernel(q, k_pool, v_pool, layer, block_tables, positions, kv_len):
-        return _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables,
-                                    kv_len, scale=scale, kv_heads=hkv)
+        read = _paged_decode_kernel if pack == 1 \
+            else _paged_decode_side_by_side
+        return read(q, k_pool, v_pool, layer, block_tables, kv_len,
+                    scale=scale, kv_heads=hkv)
 
     positions = positions if sees is None else sees    # the loop's mask
     args = (q, k_pool, v_pool, layer, block_tables, positions, kv_len)
@@ -765,15 +804,17 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, kv_len,
         # the compiler partitions, and it cannot partition a kernel.
         return loop(*args)
     sublanes = 32 // k_pool.dtype.itemsize
-    if d % _LANES or (bs * hkv) % sublanes:
+    if (pack * d) % _LANES or (bs * hkv // pack) % sublanes:
         # Decided at trace time, as `paged_latent_attention`'s.
+        what = (f"head_dim {d} against {_LANES} lanes" if d % _LANES else
+                f"{bs} positions x {hkv} KV heads against {sublanes} "
+                f"sublanes")
         warnings.warn(
             f"paged_attention: a page of {k_pool.shape[2:]} {k_pool.dtype} "
             f"is not whole tiles of {sublanes} x {_LANES} as rows of "
-            f"(position, KV head), so a decode step over pool{k_pool.shape} "
-            f"takes the block loop on a TPU too, not the Pallas kernel (a "
-            f"step of 8 lanes takes over a quarter as long again at "
-            f"Laguna-XS.2's widths: 8.4 ms against 6.5)",
+            f"(position, KV head): {what}.  A decode step over "
+            f"pool{k_pool.shape} takes the block loop on a TPU too, not "
+            f"the Pallas kernel",
             stacklevel=2)
         return loop(*args)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=loop)
@@ -1428,6 +1469,31 @@ def _paged_decode_kernel(q, k_pool, v_pool, layer, block_tables, kv_len, *,
       q[:, 0].astype(k_pool.dtype), k_pool.reshape(stored),
       v_pool.reshape(stored))
     return out[:, None]
+
+
+def _paged_decode_side_by_side(q, k_pool, v_pool, layer, block_tables,
+                               kv_len, *, scale, kv_heads):
+    """`_paged_decode_kernel` over a pool of half-lane heads kept two a row
+    (`pages_as_rows`: (L, N, block_size x Hkv / 2, 128), a row KV heads 2r
+    and 2r + 1 of a position side by side): the kernel as it is, told that
+    the pool has Hkv / 2 KV heads of 128.  A query head goes in with its
+    own KV head's half of the lanes and zeros in the other's, so its
+    product with a row is its product with its own head's key; what comes
+    back for it is the probabilities applied to both heads' values, of
+    which its own half is taken.  The products are twice the needed ones
+    and the bytes exactly the live pages', which is what a decode step
+    pays for.  `q` (S, 1, H, D), D half a lane tile; returns (S, 1, H, D)
+    float32."""
+    s, _, h, d = q.shape
+    pack = k_pool.shape[-1] // d
+    own = (jnp.arange(h) // (h // kv_heads)) % pack        # a head's half
+    place = jax.nn.one_hot(own, pack, dtype=jnp.float32)   # (H, pack)
+    wide = (q[..., None, :] * place.astype(q.dtype)[:, :, None]).reshape(
+        s, 1, h, pack * d)
+    out = _paged_decode_kernel(wide, k_pool, v_pool, layer, block_tables,
+                               kv_len, scale=scale,
+                               kv_heads=kv_heads // pack)
+    return jnp.einsum("sqhpd,hp->sqhd", out.reshape(s, 1, h, pack, d), place)
 
 
 # How a chunk reads a selection on a TPU, and why not by its rows (PR 50;

@@ -144,6 +144,10 @@ _SELECT_MASKED = "select_masked"
 # prefill launches carried (rows over `linear_chunk`, a launch's padded
 # tail counted: the program runs it).
 _LINEAR_STATE = ("linear_state_rows", "delta_chunks")
+# Behind them in the records of a model with conv layers, and of no other:
+# the rows of state (a lane's kept rows in one conv layer) one step of the
+# tick's burst read, and wrote as many.
+_CONV_STATE = "conv_state_rows"
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -162,7 +166,7 @@ class _TickAccounts:
                  "starved_s",
                  "index_scored_tokens", "kv_selected_tokens", "blocks",
                  "passes", "block_tokens", "ring_slots", "select_masked",
-                 "linear_state_rows", "delta_chunks")
+                 "linear_state_rows", "delta_chunks", "conv_state_rows")
 
     def __init__(self):
         self.decode_s = self.prefill_s = self.sample_s = self.reset_s = \
@@ -172,6 +176,7 @@ class _TickAccounts:
         self.index_scored_tokens = self.kv_selected_tokens = 0
         self.blocks = self.passes = self.block_tokens = self.ring_slots = 0
         self.linear_state_rows = self.delta_chunks = 0
+        self.conv_state_rows = 0
         # Where the tick's `routed_here` and `moe_tiles` are summed on the
         # device (`_count_routed`); -1: the tick launched nothing that
         # counts.
@@ -750,6 +755,11 @@ class PagedLLMEngine:
             and self._recurrent else 0)
         if self._linear_layers:
             self.tick_fields += _LINEAR_STATE
+        self._conv_layers = (
+            cfg.n_of("conv") if getattr(cfg, "conv_kernel", 0)
+            and self._recurrent else 0)
+        if self._conv_layers:
+            self.tick_fields += (_CONV_STATE,)
         # A model of several residual streams: the largest defect of a
         # tick's mixes, kept on the device as the sums above are.
         self._defects = None
@@ -1568,6 +1578,7 @@ class PagedLLMEngine:
                 [int(self._lengths[i]) for i in idx])
             self._acct.ring_slots = self._ring_slots[w]
             self._acct.linear_state_rows = self._linear_layers * len(idx)
+            self._acct.conv_state_rows = self._conv_layers * len(idx)
             first = self._burst_input(idx, w)
             tables = np.zeros((w, self._b_max), np.int32)
             lengths = np.zeros((w,), np.int32)
@@ -1947,6 +1958,10 @@ class PagedLLMEngine:
         reads, and writes as many: live lanes x linear layers; and the
         chunks of the delta rule the tick's prefill launches carried, over
         the linear layers (a launch's rows over `linear_chunk`).
+        `conv_state_rows` (behind them, in the records of a model with
+        conv layers and of no other): the lanes' kept rows one step of the
+        tick's burst reads, and writes as many: live lanes x conv layers
+        (each `conv_kernel` - 1 rows of the model's width).
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -1995,6 +2010,8 @@ class PagedLLMEngine:
                     row.append(acct.select_masked)
                 if self._linear_layers:
                     row += [acct.linear_state_rows, acct.delta_chunks]
+                if self._conv_layers:
+                    row.append(acct.conv_state_rows)
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -50,7 +50,8 @@ def pytest_configure(config):
 # would start last; every other file keeps its place.
 _LONG_FIRST = (
     "test_moe", "test_tpu_compile", "test_dsa_moe_serving",
-    "test_gated_delta_serving", "test_mamba2_moe_serving",
+    "test_gated_delta_serving", "test_short_conv_serving",
+    "test_mamba2_moe_serving",
     "test_mla_moe_serving", "test_paged_attention",
     "test_window_moe_serving", "test_hybrid_serving",
     "test_gated_moe_serving", "test_mhc_mla_serving",

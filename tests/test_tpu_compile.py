@@ -1273,3 +1273,83 @@ def test_gated_delta_served_programs_fit_one_chip(topo, program):
     assert fam.state_operand(config).search(text) \
         if program == "paged_decode_burst" \
         else re.search(r"f32\[1,8,32,64,64\]", text)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_short_conv_served_programs_fit_one_chip(topo, program):
+    """LFM2-8B-A1B at the benchmark's cut (layers 0-13: two leading conv
+    layers with a dense FFN, three periods of one full layer to three conv
+    layers; every expert, the whole vocabulary, the head tied) and serving
+    shape (32 slots x 4096, block 16: three layers' pool 0.81 GB, eleven
+    conv layers' two rows of 2048 for 33 slots 3 MB, beside 9.33 GB of
+    weights): the width-32 burst and the chunk of 512 rows compile for one
+    v5e chip and fit its 15.75 GB usable.  Pool and conv rows are updated
+    in place (their bytes are aliased).  **The full layers' heads are 64
+    wide and their pool is kept as rows of whole lanes**, two of a
+    position's eight KV heads side by side, bf16[3,8193,64,128]
+    (`ops.attention.pages_as_rows`; kept by position, (16, 8, 64), the
+    compiler stores it with the blocks' axis innermost and both programs
+    copied it whole into half-empty tiles and back, K and V, 1.63 GB of
+    temporaries: PR 67): nothing of the pool's shape is made but by the
+    in-place scatter; the burst reads it by the decode kernel, one call
+    site in the scan over the periods, told of 4 KV heads of 128 and
+    handed K and V as stored (`_paged_decode_side_by_side`), the chunk
+    reads groups of pages by the loop (no kernel call), and the
+    temporaries stay under a tenth of the pool.  The experts are 22 MB
+    each, over what the visit's kernel holds in VMEM twice: a burst visits
+    by the loop's fused products over the stacks in place, a chunk groups
+    its rows for the tile kernel."""
+    import json
+    import re
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "lfm2-8b-a1b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    state, params = resident["sequence_state"], resident["params"]
+    assert state.k.shape == (3, 8193, 64, 128) and state.wk is None
+    assert state.lconv.shape == (11, 33, 2, 2048) and state.lstate is None
+    assert state.lconv.dtype == jnp.bfloat16
+    assert params["kinds"]["full"]["wq"].shape == (3, 2048, 32 * 64)
+    assert params["kinds"]["full"]["wk"].shape == (3, 2048, 8 * 64)
+    assert params["kinds"]["conv"]["in_proj"].shape == (9, 2048, 6144)
+    assert params["lead"][1]["in_proj"].shape == (2048, 6144)
+    assert params["lead"][0]["w_gate"].shape == (2048, 7168)
+    assert params["blocks"]["w_gate"].shape == (12, 32, 2048, 1792)
+    assert params["blocks"]["router_bias"].shape == (12, 32)
+    assert params["embed"].shape == (65536, 2048) and "lm_head" not in params
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    resident_bytes = state_bytes + sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(params))
+    assert abs(resident_bytes - 10.14e9) < 0.1e9, resident_bytes
+    assert resident_bytes > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert _device_bytes(compiled) < 15.75e9
+    assert mem.temp_size_in_bytes < 0.1 * state.k.size * 2 * 2, \
+        mem.temp_size_in_bytes
+    memory = config["memory"]
+    assert abs(memory["parameters_GB"] * 1e9 - (
+        resident_bytes - state_bytes)) < 0.01e9
+    assert abs(memory["resident_GB"] * 1e9 - resident_bytes) < 0.01e9
+    _assert_pool_read_by_the_kernel(
+        text, state.k.shape, 1 if program == "paged_decode_burst" else 0)
+    _assert_experts_read_in_place(text, fam.expert_operand(config), program)
+    # the lanes' three chunks and the slots' kept rows are there
+    assert re.search(r"bf16\[(?:32,1|1,512|512),6144\]", text)
+    assert re.search(r"bf16\[11,33,2,2048\]", text)
+    assert fam.mixer_operand(config).search(text)
