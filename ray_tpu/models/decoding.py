@@ -127,17 +127,23 @@ def _gated_norm(o, z, gain, eps):
     return rms_norm(o, gain, eps=eps) * jax.nn.silu(z.astype(jnp.float32))
 
 
-def _linear_mixer(bp, x, conv_rows, state, valid, cfg):
+def _linear_mixer(bp, x, conv_rows, states, rows, valid, cfg):
     """A linear layer's mixer (Gated DeltaNet, `ops.gated_delta`) over
-    x (S, K, d), from the lanes' conv rows (S, J - 1, c) and state
-    (S, Hv, d_k, d_v).  K = 1 is the rule's step, K > 1 its chunk form over
-    chunks of `cfg.linear_chunk` with the state handed on inside.  A row
-    that is not `valid` (S, K; the valid ones are a prefix) takes beta = 0
-    and g = 0, so the state passes it, and the conv rows kept are the
-    last valid ones'.  Returns (out (S, K, d), conv rows, state
-    float32)."""
+    x (S, K, d), from the lanes' conv rows (S, J - 1, c) and their states,
+    the rows `rows` (S,) of `states` (R, Hv, d_k, d_v): the slots' states
+    of every linear layer as they lie in the cache, viewed as rows.  K = 1
+    is the rule's step on those rows where they lie
+    (`gated_delta_step_rows`: lowered for a TPU one kernel that reads a
+    lane's state out of `states` once and writes it back once, elsewhere
+    the plain step between a gather and a scatter of the lanes' rows);
+    K > 1 gathers them once, runs the chunk form over chunks of
+    `cfg.linear_chunk` with the state handed on inside, and scatters them
+    back.  A row that is not `valid` (S, K; the valid ones are a prefix)
+    takes beta = 0 and g = 0, so the state passes it, and the conv rows
+    kept are the last valid ones'.  Returns (out (S, K, d), conv rows,
+    `states` with the lanes' rows replaced)."""
     from ray_tpu.ops.gated_delta import (
-        causal_conv, gated_delta_chunks, gated_delta_step)
+        causal_conv, gated_delta_chunks, gated_delta_step_rows)
 
     cd, f32 = cfg.compute_dtype, jnp.float32
     hk, hv = cfg.linear_k_heads, cfg.linear_v_heads
@@ -167,19 +173,20 @@ def _linear_mixer(bp, x, conv_rows, state, valid, cfg):
                                         + bp["dt_bias"].astype(f32)), 0.0)
     if k_w == 1:
         with jax.named_scope("gdn_step"):
-            o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                        beta[:, 0], state)
+            o, states = gated_delta_step_rows(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], states, rows)
             o = o[:, None]
     else:
         with jax.named_scope("gdn_chunk"):
-            o, state = gated_delta_chunks(q, k, v, g, beta, state,
+            o, state = gated_delta_chunks(q, k, v, g, beta, states[rows],
                                           chunk=cfg.linear_chunk, cd=cd)
+            states = states.at[rows].set(state.astype(states.dtype))
     with jax.named_scope("gdn_gate_norm"):
         y = _gated_norm(o, qkvz[..., n_conv:].reshape(s_w, k_w, hv, dv),
                         bp["gate_norm"], cfg.norm_eps)
     out = jnp.einsum("ske,ed->skd", y.reshape(s_w, k_w, hv * dv).astype(cd),
                      bp["out_proj"].astype(cd))
-    return out, conv_rows, state
+    return out, conv_rows, states
 
 
 def _conv_mixer(bp, x, conv_rows, valid, cfg):
@@ -624,10 +631,15 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
                         (lconv.at[at, slots].set(rows), None)), bp, li)
         if kind == "linear":
             lconv, lstate = lin
-            out, rows, state = _linear_mixer(
-                bp, x, lconv[at, slots], lstate[at, slots], valid_rows, cfg)
+            # (L_linear, S + 1, ..) as rows: layer `at`'s slots lie at
+            # at x (S + 1) + slot.  A reshape of leading dimensions: a
+            # bitcast, no copy.
+            out, rows, states = _linear_mixer(
+                bp, x, lconv[at, slots],
+                lstate.reshape(-1, *lstate.shape[2:]),
+                at * lstate.shape[1] + slots, valid_rows, cfg)
             lin = (lconv.at[at, slots].set(rows),
-                   lstate.at[at, slots].set(state.astype(lstate.dtype)))
+                   states.reshape(lstate.shape))
             return ffn((x + out, k_pool, v_pool, wk, wv, visited, routed,
                         lin), bp, li)
         q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)

@@ -11,8 +11,25 @@ decay g_t <= 0:
     S <- S + k_t d^T
     o_t = S^T q_t
 
-`gated_delta_step` is that, one position a lane (a decode step: the state
-read and written once).  `gated_delta_chunks` is the same recurrence over
+`gated_delta_step` is that, one position a lane, in plain array ops on the
+lanes' states handed over as an array of their own.  **A decode step of
+the served path is `gated_delta_step_rows`: the same position on the
+states where they live**, rows of any array of state rows (the cache's
+`lstate`, every linear layer's slots, viewed as rows) named by an index a
+lane.  Lowered for a TPU that is one Pallas kernel a layer, this file's
+own (`_step_kernel`): the grid runs over (lane, block of value heads), a
+grid step's DMA is the lane's block of heads straight out of the rows'
+array and straight back (the array is the call's result too, aliased to
+its operand: never copied, rows no lane names never touched), and the
+four lines above run head by head in VMEM and registers, float32, in that
+order; the state is read once and written once, where the plain form
+between a gather and a scatter passed over the lanes' 17 MB five or six
+times a layer.  On every other platform, and for a state that is not
+float32 in whole (8, 128) tiles (`_step_kernel_takes`), it is the plain
+form between the gather and the scatter: the CPU's path, the chunk form's
+companion and the kernel's oracle.  `jax.lax.platform_dependent` chooses,
+so a program compiled ahead of time for a described chip holds what the
+chip runs.  `gated_delta_chunks` is the same recurrence over
 T = m x `chunk` positions as matrix products, in the WY form of the paper
 (section 3.3): inside a chunk of C positions, with G_t the running sum of g
 and D[t, r] = exp(G_t - G_r) for r <= t,
@@ -40,10 +57,15 @@ over the rows a slot kept and the launch's own.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+_LANES, _SUBLANES = 128, 8
 
 
 def causal_conv(rows, x, w, n_valid):
@@ -71,6 +93,146 @@ def gated_delta_step(q, k, v, g, beta, state):
     delta = beta.astype(F32)[..., None] * (v - answered)
     state = state + k[..., :, None] * delta[..., None, :]
     return jnp.einsum("shkv,shk->shv", state, q), state
+
+
+# Value heads a grid step of `_step_kernel` takes.  Measured on a v5e at
+# Qwen3-Next's widths (PR 68, call AB: six linear layers x 8 steps on the
+# slots' states f32[6,9,32,128,128] as 54 rows, a burst of 8 lanes, 2.1 MB a
+# lane and layer; us a layer and step, the default scoped VMEM, no margin
+# asked for):
+#
+#                                        8 lanes live   6 live, 2 idle
+#   gather, plain step, scatter (XLA)       133.3           133.4
+#   the kernel, 8 heads a block (0.5 MB)     59.0            59.0
+#   16 heads (1 MB)                          58.2            58.7
+#   32 heads (2 MB)                          56.5            52.6
+#   32 heads, idle lanes skipped             58.1            45.2
+#
+# A live lane's 2 x 2.1 MB pass in 7.1-7.4 us: 570-595 GB/s, what a plain
+# elementwise pass over an array reaches on this chip (590), so the kernel
+# is bound by its copies at every block size and the largest block has the
+# fewest grid steps (of two idle lanes side by side at 32 heads a block the
+# second repeats the first's block, which the pipeline does not copy
+# again).  **Skipping idle lanes was built and taken out**: a skipped lane
+# repeated the block of the step before it under `pl.when`, 7.4 us a layer
+# and idle lane bare, but in `qwen3next-agent` (0.9-1.9 idle lanes of 8)
+# `decode_step_dev_ms` read 3.15 against 3.17 and the replica's start took
+# 4.4 s longer, every run, all of it tracing and lowering
+# (`setup_compile_s.trace_lower` 16.9 against 12.5, the parent's 11.4; call
+# D, three rounds): `setup_s` +10.7% where its bound is 10%.  AOT for a
+# described v5e: the burst holds one call a linear layer of the period's
+# body, `(f32[54,32,128,128], f32[8,32,128]) custom-call` with the state
+# aliased, between bitcasts of f32[6,9,32,128,128]; nothing copies, gathers
+# or scatters an array of either shape (tests/test_tpu_compile.py holds it).
+_STEP_HEAD_BLOCK = 32
+# The state's blocks the pipeline keeps in VMEM: one coming in, one being
+# computed from, one computed and one going out.
+_STEP_STATE_VMEM = 8 * 2**20
+
+
+def _step_head_block(hv: int, dk: int, dv: int) -> int:
+    """Value heads a grid step of `_step_kernel` takes: the most whole
+    sublanes of them, `_STEP_HEAD_BLOCK` at most, that divide `hv` and whose
+    states fit in `_STEP_STATE_VMEM` four times; 0 where there is none."""
+    fit = [hb for hb in range(_SUBLANES, _STEP_HEAD_BLOCK + 1, _SUBLANES)
+           if hv % hb == 0 and 4 * hb * dk * dv * 4 <= _STEP_STATE_VMEM]
+    return max(fit, default=0)
+
+
+def _step_kernel_takes(states) -> bool:
+    """Whether a step over state rows takes `_step_kernel` where it is
+    lowered for a TPU: a float32 state whose (d_k, d_v) is whole (8, 128)
+    tiles, a block of heads that fits (`_step_head_block`), on one device
+    (an array split over a mesh is the plain form's)."""
+    hv, dk, dv = states.shape[1:]
+    return (states.dtype == F32 and dv % _LANES == 0 and dk % _SUBLANES == 0
+            and _step_head_block(hv, dk, dv) > 0
+            and jax.typeof(states).sharding.mesh.size <= 1)
+
+
+def _step_body(rows_ref, decay_ref, beta_ref, q_ref, k_ref, v_ref, s_ref,
+               s_out, o_ref, *, hb: int, hv: int):
+    """A grid step of `_step_kernel`: one lane's `hb` heads, head after
+    head in `gated_delta_step`'s order, a head's (d_k, d_v) state whole in
+    registers.  Keys and queries come as rows and are wanted as columns (a
+    state's d_k lies along the sublanes): both blocks are turned at once."""
+    del rows_ref                               # the index maps read it
+    base = pl.program_id(0) * hv + pl.program_id(1) * hb
+    dk = q_ref.shape[-1]
+    kq = jnp.concatenate(
+        [k_ref[...], q_ref[...], jnp.zeros((_LANES - 2 * hb, dk), F32)],
+        axis=0).T              # column j: head j's key, hb + j: its query
+    for j in range(hb):
+        k, q = kq[:, j:j + 1], kq[:, hb + j:hb + j + 1]
+        state = decay_ref[base + j] * s_ref[j]
+        answered = jnp.sum(state * k, axis=0, keepdims=True)
+        delta = beta_ref[base + j] * (v_ref[j:j + 1, :] - answered)
+        state = state + k * delta
+        s_out[j] = state
+        o_ref[j:j + 1, :] = jnp.sum(state * q, axis=0, keepdims=True)
+
+
+def _step_kernel(q, k, v, g, beta, states, rows, *, head_block=None):
+    """`gated_delta_step` on the rows `rows` of `states`, in place: one
+    kernel whose grid is (lane, block of `head_block` value heads).  `rows`
+    is prefetched and the state's index map reads it, so a grid step copies
+    a lane's (head_block, d_k, d_v) block out of `states` where it lies and
+    back to where it lay (`states` is the call's first result, aliased to
+    its operand: rows no lane names are never touched); decay and beta are
+    scalars a head, read from SMEM.  Everything is float32 on the VPU: a
+    multiply and a sum over d_k for each of the two products.  An idle
+    lane is computed like any other: exp(0) S + k 0 leaves its row to the
+    bit, however many idle lanes name it.  It asks for no VMEM beyond the
+    default: four blocks of the state, 8 MiB at 32 heads of 128 x 128."""
+    s_w, hv, dk = q.shape
+    dv = v.shape[-1]
+    hb = head_block or _step_head_block(hv, dk, dv)
+
+    def per_lane(width):
+        return pl.BlockSpec((None, hb, width), lambda s, h, *_: (s, h, 0))
+
+    state = pl.BlockSpec((None, hb, dk, dv),
+                         lambda s, h, rows, *_: (rows[s], h, 0, 0))
+    states, o = pl.pallas_call(
+        functools.partial(_step_body, hb=hb, hv=hv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(s_w, hv // hb),
+            in_specs=[per_lane(dk), per_lane(dk), per_lane(dv), state],
+            out_specs=[state, per_lane(dv)]),
+        out_shape=[jax.ShapeDtypeStruct(states.shape, F32),
+                   jax.ShapeDtypeStruct(v.shape, F32)],
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="gated_delta_step_rows",
+    )(rows.astype(jnp.int32), jnp.exp(g.astype(F32)).reshape(-1),
+      beta.astype(F32).reshape(-1), q.astype(F32), k.astype(F32),
+      v.astype(F32), states)
+    return o, states
+
+
+def gated_delta_step_rows(q, k, v, g, beta, states, rows):
+    """`gated_delta_step` where the lanes' states live: `states`
+    (R, H, d_k, d_v), any array of state rows, and `rows` (S,) int32, the
+    row each lane's state is.  Returns (o (S, H, d_v) float32, `states`
+    with the rows `rows` replaced).  Live lanes name rows of their own;
+    lanes with beta = 0 and g = 0 (idle: their `o` is nobody's to read)
+    may share one, which stays as it was to the bit, as does every row no
+    lane names.
+
+    Lowered for a TPU it is one kernel (`_step_kernel`: a lane's state read
+    out of `states` once and written back once) where `_step_kernel_takes`;
+    everywhere else the plain form between a gather and a scatter of the
+    lanes' rows: one algorithm, what differs is where the state's bytes
+    are taken from."""
+    def plain(q, k, v, g, beta, states, rows):
+        o, state = gated_delta_step(q, k, v, g, beta, states[rows])
+        return o, states.at[rows].set(state.astype(states.dtype))
+
+    args = (q, k, v, g, beta, states, rows)
+    if not _step_kernel_takes(states):
+        return plain(*args)
+    return jax.lax.platform_dependent(*args, tpu=_step_kernel, default=plain)
 
 
 def gated_delta_chunks(q, k, v, g, beta, state, *, chunk: int, cd):

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import served_contract as contract
 from ray_tpu.models import configs, decoding, init_params
@@ -212,6 +213,146 @@ def test_rows_that_are_not_valid_leave_the_state_to_the_bit():
     cat = jnp.concatenate([rows, x], 1)
     np.testing.assert_allclose(
         out[:, 2], sum(w[j] * cat[:, 2 + j] for j in range(4)), atol=1e-6)
+
+
+# -- (i') a step on the slots' state rows, where they lie ---------------------------
+# What a TPU runs in place of gather, plain step and scatter
+# (`gated_delta._step_kernel`), here in Pallas's interpreter: 22 cases, ~55
+# CPU-seconds together.
+def _row_inputs(lanes, idle=(), seed=0, heads=32, dk=8, dv=128, n_rows=12):
+    """A step's inputs at whole (8, 128) tiles over `n_rows` state rows,
+    the lanes' rows in any order; the lanes `idle` idle (beta = 0, g = 0)
+    on one row that no live lane names."""
+    q, k, v, g, beta, _ = (a[:, 0] for a in _rule_inputs(
+        1, seed=seed, lanes=lanes, heads=heads, dk=dk, dv=dv))
+    states = jax.random.normal(jax.random.key(seed + 100),
+                               (n_rows, heads, dk, dv))
+    order = jax.random.permutation(jax.random.key(seed + 200), n_rows)
+    rows = order[:lanes].astype(jnp.int32)
+    if idle:
+        dead = jnp.isin(jnp.arange(lanes), jnp.asarray(idle))
+        g, beta = (jnp.where(dead[:, None], 0.0, a) for a in (g, beta))
+        rows = jnp.where(dead, order[lanes], rows)
+    return q, k, v, g, beta, states, rows
+
+
+def _as_the_plain_step(got, q, k, v, g, beta, states, rows, live=None):
+    """`got` = (o, states) of a step over rows against gather, plain step
+    and scatter; the rows no `live` lane names are `states`' to the bit."""
+    live = np.ones(len(rows), bool) if live is None else live
+    o, after = gated_delta.gated_delta_step(q, k, v, g, beta, states[rows])
+    np.testing.assert_allclose(got[0][live], o[live], atol=2e-6)
+    named = np.asarray(rows)[live]
+    np.testing.assert_allclose(got[1][named], after[live], atol=2e-6)
+    others = np.setdiff1d(np.arange(states.shape[0]), named)
+    assert np.array_equal(np.asarray(got[1])[others],
+                          np.asarray(states)[others])
+
+
+@pytest.mark.parametrize("head_block", [8, 16, 32])
+@pytest.mark.parametrize("lanes", [1, 5, 8])
+def test_the_step_kernel_agrees_with_the_plain_step(lanes, head_block):
+    """Rows in any order out of 12, 32 heads in blocks of `head_block`: the
+    lanes' outputs and rows are the plain step's, and the rows no lane
+    names are unchanged to the bit."""
+    args = _row_inputs(lanes, seed=lanes)
+    with pltpu.force_tpu_interpret_mode():
+        got = gated_delta._step_kernel(*args, head_block=head_block)
+    _as_the_plain_step(got, *args)
+
+
+@pytest.mark.parametrize("head_block", [8, 32])
+@pytest.mark.parametrize("idle", [(6, 7), (2, 5, 6), (0, 3, 4)],
+                         ids=lambda lanes: "".join(map(str, lanes)))
+def test_idle_lanes_share_a_row_and_leave_it_to_the_bit(idle, head_block):
+    """A burst's idle lanes all name the spare slot's row, side by side or
+    with live lanes between: it is written once an idle lane and stays
+    what it was to the bit; the live lanes are right."""
+    args = _row_inputs(8, idle=idle, seed=len(idle))
+    with pltpu.force_tpu_interpret_mode():
+        got = gated_delta._step_kernel(*args, head_block=head_block)
+    _as_the_plain_step(got, *args, live=~np.isin(np.arange(8), idle))
+
+
+def _takes_its_tpu_branch(monkeypatch, calls):
+    """`jax.lax.platform_dependent` as a program lowered for a TPU has it,
+    the kernels in Pallas's interpreter."""
+    def as_for_a_tpu(*args, tpu, default):
+        calls.append(tpu)
+        return tpu(*args)
+
+    monkeypatch.setattr(jax.lax, "platform_dependent", as_for_a_tpu)
+    return pltpu.force_tpu_interpret_mode()
+
+
+@pytest.mark.parametrize("heads,dk,dv,dtype,takes", [
+    (32, 128, 128, jnp.float32, True), (8, 8, 128, jnp.float32, True),
+    (32, 8, 128, jnp.bfloat16, False), (32, 8, 64, jnp.float32, False),
+    (32, 4, 128, jnp.float32, False), (4, 8, 128, jnp.float32, False)],
+    ids=["the published widths", "whole tiles, 8 heads", "a bfloat16 state",
+         "a d_v of 64", "a d_k of 4", "4 heads"])
+def test_which_step_takes_the_kernel(heads, dk, dv, dtype, takes,
+                                     monkeypatch):
+    """The predicate reads what the call sees: a float32 state of whole
+    (8, 128) tiles in blocks of whole sublanes of heads.  Anything else is
+    the plain form between a gather and a scatter, on a TPU too
+    (`platform_dependent` is not asked)."""
+    states = jax.ShapeDtypeStruct((12, heads, dk, dv), dtype)
+    assert gated_delta._step_kernel_takes(states) == takes
+    if dk == 128:                  # the sweep's choice; too large to run here
+        assert gated_delta._step_head_block(heads, dk, dv) == 32
+        return
+    *args, states, rows = _row_inputs(3, heads=heads, dk=dk, dv=dv)
+    states, calls = states.astype(dtype), []
+    with _takes_its_tpu_branch(monkeypatch, calls):
+        got = gated_delta.gated_delta_step_rows(*args, states, rows)
+    assert len(calls) == takes and got[1].dtype == dtype
+    o, after = gated_delta.gated_delta_step(*args, states[rows])
+    atol = 2e-6 if dtype == jnp.float32 else 0.0
+    np.testing.assert_allclose(got[0], o, atol=atol)
+    np.testing.assert_allclose(
+        np.asarray(got[1][rows], np.float32),
+        np.asarray(after.astype(dtype), np.float32), atol=atol)
+
+
+def test_a_burst_through_the_kernel_equals_its_plain_steps(monkeypatch):
+    """The served burst at a state of whole tiles (8 value heads of
+    8 x 128): 8 steps whose 6 linear layers each take the kernel on
+    `lstate` viewed as rows, lanes on slots of their own with an idle lane
+    between, against the same burst in the plain form."""
+    cfg = dataclasses.replace(configs.get("tiny-gated-delta-moe"),
+                              linear_v_heads=8, linear_d_v=128)
+    params = init_params(jax.random.key(0), cfg)
+    state = decoding.init_sequence_state(cfg, 17, 8, num_slots=4,
+                                         prefill_chunk=32)
+    # states that are not zero, but the spare slot's, as an engine keeps it
+    state = dataclasses.replace(state, lstate=(0.3 * jax.random.normal(
+        jax.random.key(1), state.lstate.shape)).at[:, 4].set(0.0))
+    args = (jnp.asarray([5, 0, 7, 9], jnp.int32),
+            jnp.asarray(np.arange(1, 17, dtype=np.int32).reshape(4, 4)),
+            jnp.asarray([3, 0, 9, 1], jnp.int32),
+            jnp.asarray([True, False, True, True]),
+            jnp.zeros((4,), jnp.float32), jax.random.key(0))
+
+    def burst():
+        return jax.jit(decoding._bind_cfg(decoding.paged_decode_burst, cfg),
+                       static_argnames=("n_steps",))(
+            params, state, *args, n_steps=8,
+            slots=jnp.asarray([2, 4, 0, 3], jnp.int32))
+
+    plain, p_toks, *_ = burst()
+    calls = []
+    with _takes_its_tpu_branch(monkeypatch, calls):
+        kernel, k_toks, *_ = burst()
+    assert len(calls) == 3       # the period's three, traced once
+    live = np.asarray(args[3])
+    assert np.array_equal(np.asarray(k_toks)[:, live],
+                          np.asarray(p_toks)[:, live])
+    # a step's 2e-6, handed through 8 steps of 8 layers
+    contract.leaves_agree(kernel, plain, atol=5e-5)
+    assert np.array_equal(np.asarray(kernel.lstate[:, (1, 4)]),
+                          np.asarray(state.lstate[:, (1, 4)]))
+    assert np.abs(np.asarray(kernel.lstate - state.lstate)[:, 2]).max() > 0.1
 
 
 # -- (ii) through the cache, against the full forward -------------------------------

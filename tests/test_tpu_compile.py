@@ -1268,11 +1268,41 @@ def test_gated_delta_served_programs_fit_one_chip(topo, program):
         text, state.k.shape, 1 if program == "paged_decode_burst" else 0)
     _assert_experts_read_in_place(text, fam.expert_operand(config), program,
                                   visit_sites=4)
-    # the lanes' state (the burst) or the slot's (the chunk) is there in
-    # float32, and the rule's triangular system in the chunk alone
+    # the slots' state as rows (the burst) or the slot's (the chunk) is there
+    # in float32, and the rule's triangular system in the chunk alone
     assert fam.state_operand(config).search(text) \
         if program == "paged_decode_burst" \
         else re.search(r"f32\[1,8,32,64,64\]", text)
+    if program == "paged_decode_burst":
+        _assert_state_stepped_in_place(text, state.lstate.shape,
+                                       fam.state_operand(config), calls=3)
+
+
+def _assert_state_stepped_in_place(text, lstate_shape, state_operand,
+                                   calls: int):
+    """`calls` sites of `text` (one a linear layer of the period's body) run
+    the delta rule's step as the kernel of `ops.gated_delta._step_kernel`
+    on the slots' states viewed as rows, `f32[layers x slots, Hv, dk, dv]`,
+    the call's first result: what the benchmark's `ssm_state_roofline`
+    looks for (`state_operand`) in the 600 characters a trace keeps of an
+    op's text.  Nothing makes an array of the slots' states, in either
+    shape, but a bitcast between the two: no gather, no scatter, no copy,
+    inside or around the scans over steps and periods."""
+    import re
+
+    layers, slots, *head = lstate_shape
+    shape = ",".join(map(str, head))
+    steps = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "gated_delta_step_rows" in line]
+    assert len(steps) == calls, (len(steps), calls)
+    for call in steps:
+        assert f"= (f32[{layers * slots},{shape}]" in call[:600], call[:300]
+        assert state_operand.search(call[:600]), call[:300]
+    makers = set(re.findall(
+        r"= f32\[(?:\d+|\d+,\d+)," + re.escape(shape) + r"\]\S* ([a-z-]+)\(",
+        text))
+    assert makers <= {"parameter", "get-tuple-element", "bitcast"}, makers
 
 
 @pytest.mark.parametrize("program", ["paged_decode_burst",
