@@ -335,6 +335,30 @@ LFM2_8B_A1B = dataclasses.replace(
     rope_theta=1000000.0, max_seq_len=128000, param_dtype=jnp.bfloat16,
     compute_dtype=jnp.bfloat16)
 
+# A stack run more than once, at test size: 3 layers x 3 passes over one
+# set of weights (a position keeps 9 planes of the pool), 4 heads of 16
+# ungrouped, a norm behind each sub-block, the final norm after every
+# pass and an exit gate behind it, threshold 0.6: rows of one launch
+# leave at different passes.  float32, so that a test holds it to the
+# reference's logits.
+TINY_LOOPED = TransformerConfig(
+    name="tiny-looped", vocab_size=512, d_model=64, n_layers=3, n_heads=4,
+    n_kv_heads=4, d_head=16, d_ff=160, loop_passes=3, post_norm=True,
+    exit_threshold=0.6, rope_theta=10000.0, norm_eps=1e-6, max_seq_len=512,
+    remat=False, param_dtype=jnp.float32, compute_dtype=jnp.float32,
+)
+
+# Ouro-2.6B's published sizes (2.67 B parameters): 48 full layers of 16
+# query and 16 KV heads of 128, SwiGLU 5632, applied four times in a row
+# (`total_ut_steps`); a position keeps 192 planes of K and V, 1.5 MiB in
+# bfloat16; `early_exit_threshold` 1: the head reads the last pass.
+OURO_2_6B = dataclasses.replace(
+    TINY_LOOPED, name="ouro-2.6b", vocab_size=49152, d_model=2048,
+    n_layers=48, n_heads=16, n_kv_heads=16, d_head=128, d_ff=5632,
+    loop_passes=4, exit_threshold=1.0, rope_theta=1000000.0,
+    max_seq_len=65536, param_dtype=jnp.bfloat16,
+    compute_dtype=jnp.bfloat16)
+
 REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 LLAMA2_7B,
                                 LLAMA3_8B, TINY_MOE, MIXTRAL_8X7B,
@@ -348,7 +372,8 @@ REGISTRY = {c.name: c for c in [TINY, GPT2_124M, BENCH_350M, BENCH_1B4,
                                 TINY_GATED_MOE, LAGUNA_XS_2,
                                 TINY_BLOCK_DIFFUSION_MOE, SDAR_30B_A3B,
                                 TINY_GATED_DELTA_MOE, QWEN3_NEXT_80B_A3B,
-                                TINY_SHORT_CONV_MOE, LFM2_8B_A1B]}
+                                TINY_SHORT_CONV_MOE, LFM2_8B_A1B,
+                                TINY_LOOPED, OURO_2_6B]}
 
 
 def get(name: str):

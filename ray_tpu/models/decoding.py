@@ -49,6 +49,16 @@ rows a lane that rewrite the block's K / V in place, the last of them
 over the finished block.  (A pool block, or page, is `block_size`
 positions; a block of the model is B of them, and B divides a page.)
 
+A model whose stack runs more than once (`TransformerConfig.loop_passes`
+= R) keeps R planes of the pool a full layer: pass r of layer l writes
+and reads plane r x L_full + l and no other (the keys of pass 2 are made
+of pass 2's hidden states).  The block operations index [:, block], so a
+block carries every pass's KV wherever it goes: a shared prefix, a copy
+on write, a shipped frame.  The passes are a scan around the layers'
+scan, under the scopes `loop_pass` (the layers) and `loop_exit` (the norm
+after the pass, the exit gate and the choice of the state the head
+reads).
+
 Convention: pool block 0 is the NULL block.  The allocator never hands it
 out; unallocated table entries and inactive slots point at it, so every
 gather/scatter is in-bounds without conditionals.  Writes routed to block
@@ -255,9 +265,22 @@ def _mlp(bp, x, cfg, experts=None, li=None, live=None, routing=False):
     return _swiglu(bp, h, cd), jnp.int32(0), None, None
 
 
+def _post_norm(out, bp, name, cfg):
+    """A sub-block's output as it is added to the residual: under its
+    second norm, `bp[name]`, where the model has one (`cfg.post_norm`)."""
+    if not cfg.post_norm:
+        return out
+    return rms_norm(out, gain_of(bp[name], cfg), eps=cfg.norm_eps)
+
+
 def _final_logits(params, x, cfg):
+    """The head over x (S, K, d): under the final norm, but for a model
+    whose stack runs more than once, whose every pass ends in that norm
+    (`_paged_forward` hands out the chosen pass's normed state)."""
     cd = cfg.compute_dtype
-    x = rms_norm(x, gain_of(params["final_norm"], cfg), eps=cfg.norm_eps)
+    if getattr(cfg, "loop_passes", 1) == 1:
+        x = rms_norm(x, gain_of(params["final_norm"], cfg),
+                     eps=cfg.norm_eps)
     if cfg.tie_embeddings:
         return jnp.einsum("btd,vd->btv", x, params["embed"].astype(cd))
     return jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(cd))
@@ -346,12 +369,14 @@ class PagedKVCache:
     operations below ask `pooled_leaves` and `resident_bytes()`
     (`kv_paged`: the pooled leaves' bytes, which the allocator's
     `bytes_per_block` is taken from) and never a leaf by name."""
-    # (L_full, N_blocks, block_size, Hkv, D), or, where such a page is not
-    # whole tiles as the compiler stores it and is as the rows the decode
-    # kernel reads (`ops.attention.pages_as_rows`; `init_paged_cache`
-    # decides), (L_full, N_blocks, block_size x Hkv, D): row t x Hkv + g
-    # is position t of KV head g; of heads of half a lane tile,
-    # (L_full, N_blocks, block_size x Hkv / 2, 128), two of a position's
+    # (P, N_blocks, block_size, Hkv, D), P = `cfg.kv_planes`: the full
+    # layers, times the passes of a stack run more than once (plane
+    # r x L_full + l is pass r of full layer l); or, where such a page is
+    # not whole tiles as the compiler stores it and is as the rows the
+    # decode kernel reads (`ops.attention.pages_as_rows`;
+    # `init_paged_cache` decides), (P, N_blocks, block_size x Hkv, D): row
+    # t x Hkv + g is position t of KV head g; of heads of half a lane
+    # tile, (P, N_blocks, block_size x Hkv / 2, 128), two of a position's
     # heads side by side.  `_paged_forward`'s write and
     # `paged_attention` know which; the block operations index [:, block].
     k: jax.Array
@@ -399,7 +424,8 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
                      shardings: Optional[PagedKVCache] = None, *,
                      num_slots: int = 0, prefill_chunk: int = 0
                      ) -> PagedKVCache:
-    """Zero pool of the full layers; with `shardings`
+    """Zero pool of the full layers (a plane a layer and pass of the
+    stack: `cfg.kv_planes`); with `shardings`
     (`paged_cache_shardings`) it is allocated directly sharded: a pool
     that fits only across chips never exists whole on chip 0.  On one
     chip (no `shardings`: they split the KV heads' axis) a page is kept
@@ -416,7 +442,7 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int,
     conv layers their rows, likewise."""
     dtype = dtype or cfg.compute_dtype
     row = (cfg.n_kv_heads, cfg.head_dim)
-    shape = (cfg.n_of("full"), num_blocks, block_size, *row)
+    shape = (cfg.kv_planes, num_blocks, block_size, *row)
     if shardings is None and pages_as_rows(*row, block_size, dtype):
         shape = (*shape[:2], *page_rows(*row, block_size))
     k_sh, v_sh = (shardings.k, shardings.v) if shardings else (None, None)
@@ -579,7 +605,10 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
     the layer pattern (`cfg.period`), its body the period's layers; the
     leading layers (`cfg.lead_pattern`: blocks of their own, a dense FFN)
     run before it and the layers behind the last whole period after it,
-    through the same body (`one`).
+    through the same body (`one`).  A model whose stack runs more than
+    once (`cfg.loop_passes`) scans all of that once a pass (`loop_pass`),
+    each pass on planes of the pool of its own, and hands out the normed
+    state of the pass each row leaves at: the head norms nothing more.
     """
     if cfg.state_by_slot and slots is None:
         raise ValueError(f"{cfg.name!r} keeps state by slot: a served call "
@@ -618,15 +647,17 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         read_ring = slot_ring_reader(window_attention, slots, positions,
                                      kv_len, cfg.window, cache.wk.shape[1])
 
-    def one(carry, bp, kind, at, li=None):
+    def one(carry, bp, kind, at, li=None, plane0=None):
         """One layer of `kind` with the weights `bp`, the `at`-th of its
         kind (its layer of the pool or of the rings), the `li`-th of the
-        expert stacks (None: a leading layer)."""
+        expert stacks (None: a leading layer); `plane0`: the first plane
+        of the pool of this pass of the stack (None: a stack run once)."""
         x, k_pool, v_pool, wk, wv, visited, routed, lin = carry
         if kind == "conv":
             lconv, _ = lin
             out, rows = _conv_mixer(bp, x, lconv[at, slots], valid_rows,
                                     cfg)
+            out = _post_norm(out, bp, "attn_post_norm", cfg)
             return ffn((x + out, k_pool, v_pool, wk, wv, visited, routed,
                         (lconv.at[at, slots].set(rows), None)), bp, li)
         if kind == "linear":
@@ -640,10 +671,13 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
                 at * lstate.shape[1] + slots, valid_rows, cfg)
             lin = (lconv.at[at, slots].set(rows),
                    states.reshape(lstate.shape))
+            out = _post_norm(out, bp, "attn_post_norm", cfg)
             return ffn((x + out, k_pool, v_pool, wk, wv, visited, routed,
                         lin), bp, li)
         q, k, v = _qkv(bp, x, cfg, positions, kind)        # (S,K,H,D)
         if kind == "full":
+            if plane0 is not None:
+                at = plane0 + at
             with jax.named_scope("full_attn"):
                 if as_rows and k_pool.shape[3] != cfg.head_dim:
                     # two half-lane heads side by side in a row
@@ -666,8 +700,9 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         if cfg.attn_gate:
             attn = attn * _head_gate(bp, x, cfg)
         attn = attn.reshape(*tokens.shape, cfg.heads(kind) * cfg.head_dim)
-        x = x + jnp.einsum("bth,hd->btd", attn.astype(cd),
-                           bp["wo"].astype(cd))
+        x = x + _post_norm(jnp.einsum("bth,hd->btd", attn.astype(cd),
+                                      bp["wo"].astype(cd)), bp,
+                           "attn_post_norm", cfg)
         return ffn((x, k_pool, v_pool, wk, wv, visited, routed, lin), bp,
                    li)
 
@@ -680,8 +715,8 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
         else:
             out, n, idx, r = _mlp(bp, x, cfg, experts, li, routed_rows,
                                   routing)
-        return (x + out, *state, visited + n,
-                routed if r is None else routed + r, lin), idx
+        return (x + _post_norm(out, bp, "mlp_post_norm", cfg), *state,
+                visited + n, routed if r is None else routed + r, lin), idx
 
     def behind(i, j, kind, bps=None, among=period):
         """Layer `j` of period `i` behind the leading layers (a traced
@@ -701,29 +736,74 @@ def _paged_forward(params, cache: PagedKVCache, tokens: jax.Array,
             at = at + lead.count(kind)
         return bp, kind, at, li
 
-    def layer(carry, layer_in):
+    def layer(plane0, carry, layer_in):
         bps, i = layer_in
         taken = []
         for j, kind in enumerate(period):
-            carry, idx = one(carry, *behind(i, j, kind, bps))
+            carry, idx = one(carry, *behind(i, j, kind, bps), plane0=plane0)
             taken.append(idx)
         return carry, (jnp.stack(taken) if routing else None)
+
+    def stack(carry, plane0=None):
+        """Every layer once, from the carry's x: the leading layers, the
+        scan over periods, the tail."""
+        for j, kind in enumerate(lead):
+            carry, _ = one(carry, params["lead"][j], kind,
+                           lead[:j].count(kind), plane0=plane0)
+        carry, taken = jax.lax.scan(
+            functools.partial(layer, plane0), carry,
+            (blocks if len(period) == 1 else None,
+             jnp.arange(cfg.n_periods)))
+        if routing:                  # (periods, p, S, K, k) -> (L, S, K, k)
+            taken = taken.reshape(cfg.n_periods * len(period),
+                                  *taken.shape[2:])
+        for j, kind in enumerate(tail):
+            carry, idx = one(carry, *behind(cfg.n_periods, j, kind,
+                                            among=tail), plane0=plane0)
+            if routing:
+                taken = jnp.concatenate([taken, idx[None]])
+        return carry, taken
+
+    def loop_pass(carry, r):
+        """Pass `r` of a stack run more than once: the layers over this
+        pass's planes of the pool, the final norm (the next pass's
+        input), and the exit gate's choice.  `held`: the state the head
+        reads, (S, K, d), and, where the model has a gate, what is left
+        of each row's probability, the share that has left, and whether
+        the row has."""
+        inner, held = carry
+        with jax.named_scope("loop_pass"):
+            inner, _ = stack(inner, r * cfg.n_of("full"))
+        with jax.named_scope("loop_exit"):
+            x = rms_norm(inner[0], gain_of(params["final_norm"], cfg),
+                         eps=cfg.norm_eps)
+            if not cfg.exit_threshold:
+                return ((x, *inner[1:]), (x,)), None
+            chosen, left, gone, done = held
+            gate = params["exit_gate"]
+            lam = jax.nn.sigmoid(
+                jnp.einsum("btd,d->bt", x, gate["w"].astype(cd)).astype(
+                    jnp.float32) + gate["b"].astype(jnp.float32))
+            last = r == cfg.loop_passes - 1
+            gone = gone + jnp.where(last, left, lam * left)
+            leaves = ~done & (last | (gone >= cfg.exit_threshold))
+            held = (jnp.where(leaves[..., None], x, chosen),
+                    left * (1.0 - lam), gone, done | leaves)
+        return ((x, *inner[1:]), held), None
 
     blocks, experts = _layer_xs(params["blocks"], cfg)
     carry = (x, cache.k, cache.v, cache.wk, cache.wv, jnp.int32(0),
              _routed_zero(tokens.size, cfg) if counted else None,
              (cache.lconv, cache.lstate) if cfg.recurrent else None)
-    for j, kind in enumerate(lead):
-        carry, _ = one(carry, params["lead"][j], kind, lead[:j].count(kind))
-    carry, taken = jax.lax.scan(
-        layer, carry, (blocks if len(period) == 1 else None,
-                       jnp.arange(cfg.n_periods)))
-    if routing:                      # (periods, p, S, K, k) -> (L, S, K, k)
-        taken = taken.reshape(cfg.n_periods * len(period), *taken.shape[2:])
-    for j, kind in enumerate(tail):
-        carry, idx = one(carry, *behind(cfg.n_periods, j, kind, among=tail))
-        if routing:
-            taken = jnp.concatenate([taken, idx[None]])
+    if cfg.loop_passes == 1:
+        carry, taken = stack(carry)
+    else:
+        rows = jnp.zeros(tokens.shape, jnp.float32)
+        held = (x, rows + 1.0, rows, rows > 0) if cfg.exit_threshold \
+            else (x,)
+        (carry, held), taken = jax.lax.scan(
+            loop_pass, (carry, held), jnp.arange(cfg.loop_passes))
+        carry = (held[0], *carry[1:])
     x, k_pool, v_pool, wk, wv, visited, routed, lin = carry
     lconv, lstate = lin or (None, None)
     return (PagedKVCache(k=k_pool, v=v_pool, wk=wk, wv=wv, lconv=lconv,

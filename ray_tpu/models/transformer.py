@@ -34,6 +34,15 @@ reference: python/ray/train/torch/train_loop_utils.py:158):
   normed input, a depth-wise causal convolution of `conv_kernel` rows over
   `B * z`, times `C`.  By the engine's slot it keeps the convolution's
   last inputs and nothing else; it may lead the stack.  Served only.
+- **A stack run more than once**: `loop_passes` = R applies the whole
+  stack R times to the hidden state with the same weights (a looped
+  language model, arXiv:2510.25741), the final norm after every pass and
+  its output the next pass's input.  A pass of a layer has keys and
+  values of its own, so a position keeps R x the full layers' planes of
+  the pool (`kv_planes`: the pool's leading axis is no longer the count
+  of layers that have weights), and a gate after each pass says which
+  pass's state a row's logits are read from (`exit_threshold`).  Served
+  only.
 - **What differs by layer lives outside the layers' stacks**: leading
   layers whose FFN is dense (`lead_pattern`) are blocks of their own
   before the scan; where the kinds differ in query heads
@@ -178,6 +187,26 @@ class TransformerConfig:
     # the top-k are taken of the sigmoid scores + it, the gates are the
     # scores' (`ops.moe.MoEConfig.scoring` "sigmoid"); served only.
     router_bias: bool = False
+    # The whole stack is applied `loop_passes` times in a row to the
+    # hidden state, the same weights in every pass, `final_norm` after
+    # every pass (the last pass's is the one before the head); pass r of
+    # full layer l keeps its keys and values in plane r x L_full + l of
+    # the pool.  1: a stack run once, what every other model is.  Served
+    # only.
+    loop_passes: int = 1
+    # A second RMSNorm a sub-block, on its output before it is added to
+    # the residual (`blocks["attn_post_norm"]` / `["mlp_post_norm"]`,
+    # gains drawn about (2 L)^-1/2): x + N(Attn(N(x))), x + N(FFN(N(x))).
+    # Served only.
+    post_norm: bool = False
+    # An exit gate behind every pass (`params["exit_gate"]`: `w` (d,) and
+    # a bias `b`): lam_r = sigmoid(h_{r+1} . w + b) of the pass's normed
+    # output, p_r = lam_r prod_{s<r} (1 - lam_s), the last pass taking
+    # what is left; a row's logits are read from the first pass at which
+    # the summed p reaches `exit_threshold` (every pass still runs for
+    # every row and writes its KV: later positions read all of them).  At
+    # 1 that is the last pass.  0: no gate, the head reads the last pass.
+    exit_threshold: float = 0.0
 
     def __post_init__(self):
         pattern = tuple(self.layer_pattern)
@@ -254,6 +283,27 @@ class TransformerConfig:
                              "scores: expert_scoring is "
                              f"{self.expert_scoring!r}, n_experts "
                              f"{self.n_experts}")
+        if self.loop_passes < 1 or not 0.0 <= self.exit_threshold <= 1.0:
+            raise ValueError(f"loop_passes {self.loop_passes} is 1 or more "
+                             f"and exit_threshold {self.exit_threshold} a "
+                             f"share of 1")
+        if self.loop_passes == 1 and self.exit_threshold:
+            raise ValueError("exit_threshold chooses among the passes of a "
+                             "stack run more than once: loop_passes is 1")
+        if self.loop_passes > 1:
+            beside = [name for name, there in (
+                ("window", self.window), ("layer_pattern 'linear'",
+                                          "linear" in pattern),
+                ("layer_pattern 'conv'", "conv" in pattern + lead),
+                ("diffusion_block", self.diffusion_block),
+                ("n_experts", self.n_experts > 0)) if there]
+            if beside:
+                raise ValueError(
+                    f"loop_passes {self.loop_passes} beside {beside}: a "
+                    f"pass of a full layer keeps a plane of the pool; a "
+                    f"ring or a recurrent state by slot a pass, a block's "
+                    f"passes inside a pass of the stack and the experts' "
+                    f"counts a pass have no form here")
         block = self.diffusion_block
         if block:
             if not (block >= 2 and 1 <= self.denoise_steps <= block
@@ -371,11 +421,17 @@ class TransformerConfig:
             for name in ("lconv", "lstate")
             if getattr(cache, name) is not None})
 
+    @property
+    def kv_planes(self) -> int:
+        """Planes of K and of V a position keeps in the pool (its leading
+        axis): one a full layer and pass of the stack."""
+        return self.loop_passes * self.n_of("full")
+
     def kv_read_tokens(self, lengths) -> int:
         """KV positions one decode step sees over lanes of `lengths`:
-        every position in a full layer, at most the window in a window
-        layer."""
-        return int(self.n_of("full") * sum(lengths) + self.n_of("window")
+        every position in a full layer, once a pass of the stack, at most
+        the window in a window layer."""
+        return int(self.kv_planes * sum(lengths) + self.n_of("window")
                    * sum(min(int(n), self.window) for n in lengths))
 
     def rope(self, kind: str) -> dict:
@@ -422,12 +478,13 @@ class TransformerConfig:
         for i, kind in enumerate(self.kinds):
             h = self.heads(kind)
             dense = self.n_experts <= 0 or i < len(self.lead_pattern)
-            total += 2 * d + (3 * d * f if dense else experts)
+            total += (4 if self.post_norm else 2) * d \
+                + (3 * d * f if dense else experts)
             attention = d * h * self.head_dim * 2 + d * kv * 2 \
                 + d * h * self.attn_gate \
                 + (2 * self.head_dim if self.qk_norm else 0)
             total += {"linear": linear, "conv": conv}.get(kind, attention)
-        return total
+        return total + (d + 1 if self.exit_threshold else 0)
 
 
 def init_params(rng: jax.Array, cfg: TransformerConfig):
@@ -443,7 +500,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     layer's `in_qkvz` ([q | k | v | z]), `in_ba` ([b | a]), `conv_w`,
     `A_log`, `dt_bias` (float32 both), `gate_norm` and `out_proj`, a conv
     layer's `in_proj` ([B | C | z]), `conv_w` and `out_proj`; a leading
-    conv layer's block holds those in place of an attention's."""
+    conv layer's block holds those in place of an attention's.  With
+    `cfg.post_norm` the norms gain `attn_post_norm` / `mlp_post_norm`, with
+    `cfg.exit_threshold` the tree gains `exit_gate` (`w` (d,), `b` ());
+    the passes of `cfg.loop_passes` share every weight."""
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     nkv = cfg.n_kv_heads
@@ -472,8 +532,20 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
         return out
 
     def norms(k, l):
-        return {"attn_norm": gain(jax.random.fold_in(k[0], 1), (*l, d)),
-                "mlp_norm": gain(jax.random.fold_in(k[0], 2), (*l, d))}
+        out = {"attn_norm": gain(jax.random.fold_in(k[0], 1), (*l, d)),
+               "mlp_norm": gain(jax.random.fold_in(k[0], 2), (*l, d))}
+        if cfg.post_norm:
+            # Drawn about (2 L)^-1/2, the scale GPT-2 gives its residual
+            # branches: a pass of 2 L normed sub-blocks then adds a unit of
+            # variance to the stream.  About 1, every sub-block's output
+            # is as large as the stream it joins and seeded weights make
+            # the stack chaotic: a rounding grows 2.6-fold a pass of 48
+            # layers (`bench/families/ouro.py`, beside `TOLERANCES`).
+            for i, name in enumerate(("attn_post_norm", "mlp_post_norm")):
+                out[name] = ((2.0 * cfg.n_layers) ** -0.5 * (1.0 + 0.1 * (
+                    jax.random.normal(jax.random.fold_in(k[0], 4 + i),
+                                      (*l, d), jnp.float32)))).astype(dt)
+        return out
 
     def narrow(k, l):
         """`l` attention layers' keys, values and QK-norm: what no kind
@@ -555,7 +627,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
     params = {
         "embed": dense(keys[0], (cfg.vocab_size, d), d ** 0.5 * d),  # ~N(0, 1/sqrt(d))
         "blocks": blocks,
-        "final_norm": gain(jax.random.fold_in(keys[0], 3), (d,)),
+        # After every pass of a stack run more than once: drawn about 1,
+        # so that a comparison notices it applied twice before the head.
+        "final_norm": gain(jax.random.fold_in(keys[0], 3), (d,),
+                           neutral=cfg.loop_passes == 1),
     }
     if n_lead:
         params["lead"] = [
@@ -581,6 +656,15 @@ def init_params(rng: jax.Array, cfg: TransformerConfig):
             for i, kind in enumerate(sorted(set(behind)))}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
+    if cfg.exit_threshold:
+        # Three quarters of a projection's scale and a small bias: over
+        # normed rows lam lies in (0.1, 0.9) (2.9 standard deviations),
+        # so that at a threshold under 1 rows leave at every pass.
+        k = jax.random.split(jax.random.fold_in(rng, 95))
+        params["exit_gate"] = {
+            "w": (0.75 * d ** -0.5 * jax.random.normal(
+                k[0], (d,), jnp.float32)).astype(dt),
+            "b": (0.1 * jax.random.normal(k[1], (), jnp.float32)).astype(dt)}
     return params
 
 
@@ -593,7 +677,9 @@ def param_logical_axes(cfg: TransformerConfig):
         return out
 
     def norms(l):
-        return {"attn_norm": (*l, "embed"), "mlp_norm": (*l, "embed")}
+        return {name: (*l, "embed") for name in (
+            "attn_norm", "mlp_norm", *(("attn_post_norm", "mlp_post_norm")
+                                       if cfg.post_norm else ()))}
 
     def narrow(l):
         out = {"wk": (*l, "embed", "kv_heads"),
@@ -659,6 +745,8 @@ def param_logical_axes(cfg: TransformerConfig):
         axes["kinds"] = {kind: wide(l) for kind in behind}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.exit_threshold:
+        axes["exit_gate"] = {"w": ("embed",), "b": ()}
     return axes
 
 
@@ -751,7 +839,10 @@ def forward(params, tokens, cfg: TransformerConfig, *,
         ("layer_pattern 'conv'", "conv" in cfg.kinds),
         ("router_bias", cfg.router_bias),
         ("norm_plus_one", cfg.norm_plus_one),
-        ("shared_gate", cfg.shared_gate)) if differs]
+        ("shared_gate", cfg.shared_gate),
+        ("loop_passes", cfg.loop_passes > 1),
+        ("post_norm", cfg.post_norm),
+        ("exit_threshold", cfg.exit_threshold)) if differs]
     if served_only:
         raise ValueError(
             f"{cfg.name!r} is a served model (`models.decoding`): the train "

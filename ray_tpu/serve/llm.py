@@ -148,6 +148,10 @@ _LINEAR_STATE = ("linear_state_rows", "delta_chunks")
 # the rows of state (a lane's kept rows in one conv layer) one step of the
 # tick's burst read, and wrote as many.
 _CONV_STATE = "conv_state_rows"
+# Behind them in the records of a model whose stack runs more than once,
+# and of no other: the layers one step of the tick's burst ran a lane, the
+# passes x the layers (0 for a tick without a burst).
+_LOOP_PASSES = "loop_passes"
 # What a request's record gains at its end (None until then).
 _DECODE_KEYS = ("decode_s", "n_out", "burst_read_s", "first_read_s",
                 "host_s", "lanes_seen")
@@ -702,7 +706,9 @@ class PagedLLMEngine:
                              if self._recurrent else None)
         self._score_step = self._score_chunk = None
         # KV positions one decode step sees over lanes of given lengths:
-        # the model's own count, or every layer over every position.
+        # the model's own count (the planes a position keeps, a full
+        # layer's once a pass of the stack), or one plane a layer over
+        # every position.
         self._kv_read_tokens = getattr(cfg, "kv_read_tokens", None) or (
             lambda lengths: cfg.n_layers * sum(lengths))
         # A model whose full layers attend to a learned selection of their
@@ -760,6 +766,13 @@ class PagedLLMEngine:
             and self._recurrent else 0)
         if self._conv_layers:
             self.tick_fields += (_CONV_STATE,)
+        # A model whose stack runs more than once: the layers a step of
+        # its burst runs a lane.
+        self._loop_passes = (
+            cfg.loop_passes * cfg.n_layers
+            if getattr(cfg, "loop_passes", 1) > 1 else 0)
+        if self._loop_passes:
+            self.tick_fields += (_LOOP_PASSES,)
         # A model of several residual streams: the largest defect of a
         # tick's mixes, kept on the device as the sums above are.
         self._defects = None
@@ -1898,9 +1911,11 @@ class PagedLLMEngine:
         neither waited for the device nor launched: with a burst ahead
         it is no longer time the device stood still for.
         `kv_read_tokens`: KV positions one step of the burst sees, summed
-        over its lanes and the layers that read (the model's count;
-        n_layers x the lanes' lengths where every layer keeps every
-        position).  `reset_s`: launches that zeroed the recurrent state
+        over its lanes and the planes that are read (the model's count:
+        the planes a position keeps x the lanes' lengths where every
+        layer keeps every position, one plane a layer, or one a layer
+        and pass of a stack run more than once).  `reset_s`: launches
+        that zeroed the recurrent state
         of slots this tick admitted to or preempted (0 for a model that
         has none).  `experts_read`: distinct experts the live lanes of
         the burst this tick launched were routed to, and so read, per
@@ -1962,6 +1977,11 @@ class PagedLLMEngine:
         conv layers and of no other): the lanes' kept rows one step of the
         tick's burst reads, and writes as many: live lanes x conv layers
         (each `conv_kernel` - 1 rows of the model's width).
+        `loop_passes` (behind them, in the records of a model whose stack
+        runs more than once and of no other): the layers one step of the
+        tick's burst runs a lane, `loop_passes` x `n_layers` (0 for a tick
+        without a burst): each a stream of that layer's weights and a read
+        of a plane of the pool.
         `hc_res_defect` (behind them, in the records of a model whose
         residual is several streams and of no other: `tick_fields` of
         the stats names a log's fields): the largest |row sum - 1|
@@ -2012,6 +2032,8 @@ class PagedLLMEngine:
                     row += [acct.linear_state_rows, acct.delta_chunks]
                 if self._conv_layers:
                     row.append(acct.conv_state_rows)
+                if self._loop_passes:
+                    row.append(self._loop_passes if acct.lanes else 0)
                 if self._defects is not None:
                     row.append(acct.defect_at)
                 b = self._inflight

@@ -55,7 +55,7 @@ _LONG_FIRST = (
     "test_mla_moe_serving", "test_paged_attention",
     "test_window_moe_serving", "test_hybrid_serving",
     "test_gated_moe_serving", "test_mhc_mla_serving",
-    "test_group_moe_serving", "test_tune_breadth")
+    "test_group_moe_serving", "test_looped_serving", "test_tune_breadth")
 
 
 def pytest_collection_modifyitems(items):

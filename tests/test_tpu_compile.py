@@ -1383,3 +1383,70 @@ def test_short_conv_served_programs_fit_one_chip(topo, program):
     assert re.search(r"bf16\[(?:32,1|1,512|512),6144\]", text)
     assert re.search(r"bf16\[11,33,2,2048\]", text)
     assert fam.mixer_operand(config).search(text)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_burst",
+                                     "paged_prefill_chunk"])
+def test_looped_served_programs_fit_one_chip(topo, program):
+    """Ouro-2.6B whole (48 layers applied 4 times over one set of weights,
+    16 / 16 heads of 128, the whole vocabulary: 5.34 GB) at the benchmark's
+    serving shape (8 slots x 512, block 16: a pool of 192 planes a position,
+    bf16[192,257,16,16,128] K and V, 6.47 GB): the width-8 burst and the
+    chunk of 128 rows compile for one v5e chip and fit its 15.75 GB usable
+    beside 11.8 GB resident.  The pool is updated in place (its bytes are
+    aliased) and nothing of its shape is made but by the in-place scatter;
+    the burst reads it by the decode kernel, **one call site in the scan
+    over the layers inside the scan over the passes** (192 launches a step,
+    the plane a traced scalar the kernel prefetches), the chunk by the
+    loop.  The passes and what follows each stand under their scopes
+    (`loop_pass`, `loop_exit`).  The temporaries are the q, k and v
+    weights of all layers in the layout the one-row products read (3 x
+    0.40 GB, copied once a launch before the loops), under a fifth of the
+    pool; the configuration's `memory` block holds these figures."""
+    import json
+
+    from bench.harness import spec
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "ouro-2.6b-serve-1chip.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    fam = spec.family(config)
+    resident, programs = fam.serve_programs(config, place)
+    (lowered,) = [low for name, low in programs if name.startswith(program)]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    pool, params = resident["pool"], resident["params"]
+    assert pool.k.shape == pool.v.shape == (192, 257, 16, 16, 128)
+    assert pool.wk is None and pool.lconv is None and pool.lstate is None
+    assert params["blocks"]["wq"].shape == (48, 2048, 2048) \
+        == params["blocks"]["wk"].shape
+    assert params["blocks"]["w_gate"].shape == (48, 2048, 5632)
+    assert params["blocks"]["attn_post_norm"].shape == (48, 2048) \
+        == params["blocks"]["mlp_post_norm"].shape
+    assert params["exit_gate"]["w"].shape == (2048,)
+    assert params["lm_head"].shape == (2048, 49152)
+    pool_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(pool))
+    param_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(params))
+    assert pool_bytes == 257 * 16 * 1572864             # 1.5 MiB a position
+    assert (pool_bytes + param_bytes) > 0.25 * V5E_HBM_BYTES
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert _device_bytes(compiled) < 15.75e9 - 2.0e9    # the check's room
+    assert mem.temp_size_in_bytes < 0.2 * pool_bytes, mem.temp_size_in_bytes
+    memory = config["memory"]
+    assert abs(memory["parameters_GB"] * 1e9 - param_bytes) < 0.01e9
+    assert abs(memory["kv_pool_GB"] * 1e9 - pool_bytes) < 0.01e9
+    assert abs(memory["resident_GB"] * 1e9 - pool_bytes - param_bytes) \
+        < 0.01e9
+    assert abs(memory["largest_program_temporaries_GB"] * 1e9
+               - mem.temp_size_in_bytes) < 0.01e9
+    _assert_pool_read_by_the_kernel(
+        text, pool.k.shape, 1 if program == "paged_decode_burst" else 0)
+    assert "/loop_pass/" in text and "/loop_exit/" in text
